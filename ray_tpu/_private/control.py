@@ -1935,6 +1935,9 @@ class ControlServer:
             "events": events,
             "tracing": tracing,
             "nodes": {"alive": nodes_alive, "total": nodes_total},
+            # which selection engine is live: the C++ one built from
+            # native/sched.cc, or its Python twin (no compiler here)
+            "scheduler": "native" if self.nsched is not None else "python",
         }
 
     # -- state dump (state API source of truth) ---------------------------

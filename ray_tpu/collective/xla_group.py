@@ -22,7 +22,7 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ray_tpu._private.jax_compat import shard_map
+from jax import shard_map
 
 from ray_tpu.collective.compression import (CompressionConfig,
                                             auto_pipeline_chunks,

@@ -28,8 +28,10 @@ import jax.numpy as jnp
 from ray_tpu.ops import (apply_rope, attention, blockwise_attention,
                          fused_softmax_cross_entropy, gelu_mlp, layer_norm,
                          rms_norm, rope_table, softmax_cross_entropy, swiglu)
+from ray_tpu.ops.attention import resolve_impl
 from ray_tpu.ops.ring_attention import ring_attention_sharded
-from ray_tpu.parallel.sharding import Logical, spec_from_logical
+from ray_tpu.parallel.sharding import (ACTIVATION_RULES, Logical,
+                                       spec_from_logical)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,15 +220,20 @@ def _constrain(x, *axes):
 
 def _attention_op(q, k, v, cfg: GPTConfig, mesh, allow_manual: bool = True):
     """Pick the attention path: ring over sp when the mesh has an sp axis,
-    otherwise flash/blockwise on the whole (possibly tp-sharded) arrays.
+    otherwise flash/blockwise on the whole (possibly sharded) arrays.
 
     The sp region is *partial-manual* shard_map (axis_names={'sp'}): dp/tp
-    stay automatic.  Inside the pp pipeline region (allow_manual=False)
-    shardy cannot nest another manual region, so attention falls back to
-    GSPMD partitioning there (exact, all-gathers KV over sp)."""
+    stay automatic.  The Pallas kernel is a Mosaic custom call that GSPMD
+    cannot partition, so on a mesh of more than one device it runs inside
+    a (fully manual) shard_map over the batch axes and the head axis —
+    each device runs the kernel on its own [B/n, H/tp, S, dh] shard.
+    Inside the pp pipeline region (allow_manual=False) shardy cannot nest
+    another manual region, so attention falls back to the GSPMD-
+    partitionable XLA path there (exact, all-gathers KV over sp)."""
+    from jax import shard_map
+
     if (allow_manual and mesh is not None and mesh.shape.get("sp", 1) > 1
             and cfg.sp_mode == "ring"):
-        from ray_tpu._private.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(None, None, "sp", None)
@@ -237,7 +244,26 @@ def _attention_op(q, k, v, cfg: GPTConfig, mesh, allow_manual: bool = True):
         return shard_map(fn, check_vma=False,
                          in_specs=(spec, spec, spec), out_specs=spec,
                          axis_names=frozenset({"sp"}))(q, k, v)
-    return attention(q, k, v, causal=True, impl=cfg.attention_impl)
+    impl = resolve_impl(cfg.attention_impl, q.shape[-2], k.shape[-2], True)
+    if impl.startswith("pallas") and mesh is not None and mesh.size > 1:
+        spec = spec_from_logical(("batch", "heads", None, None),
+                                 ACTIVATION_RULES, mesh)
+        if allow_manual and all(d % _ways(mesh, entry) == 0
+                                for d, entry in zip(q.shape, spec)):
+            fn = lambda q_, k_, v_: attention(q_, k_, v_, causal=True,
+                                              impl=impl)
+            return shard_map(fn, check_vma=False, mesh=mesh,
+                             in_specs=(spec, spec, spec),
+                             out_specs=spec)(q, k, v)
+        impl = "xla"
+    return attention(q, k, v, causal=True, impl=impl)
+
+
+def _ways(mesh, entry) -> int:
+    """How many ways one PartitionSpec entry splits its dimension."""
+    names = () if entry is None else \
+        (entry,) if isinstance(entry, str) else entry
+    return math.prod(mesh.shape[a] for a in names)
 
 
 def _qkv_proj(x, layer, cfg: GPTConfig, rope, positions=None):

@@ -137,7 +137,7 @@ def _moe_op(h, router_w, w_in, w_out, cfg: MoEConfig, mesh,
     B, S, D = h.shape
     x2 = h.reshape(B * S, D)
     if allow_manual and mesh is not None and mesh.shape.get("ep", 1) > 1:
-        from ray_tpu._private.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         n_ep = mesh.shape["ep"]

@@ -121,6 +121,15 @@ def make_train_step(cfg: gpt.GPTConfig, mesh: Mesh, tx=None,
         with _use_mesh(mesh):
             return step_fn(state, batch)
 
+    def lower(state, batch):
+        """`train.step` lowered under the mesh, for reading the program
+        (kernels, collectives, memory) without running it; arguments may
+        be shapes."""
+        with _use_mesh(mesh):
+            return step_fn.lower(state, batch)
+
+    wrapped_step.lower = lower
+
     def wrapped_init(key):
         with _use_mesh(mesh):
             return init_state_fn(key)

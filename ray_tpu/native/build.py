@@ -1,13 +1,18 @@
 """Lazy g++ build + cache for native components.
 
 The reference ships prebuilt native binaries (bazel); we compile on first
-use instead — a few hundred ms once per machine — and cache the .so next to
-the sources keyed by source mtime, so edits rebuild automatically.
+use instead — a few hundred ms once per machine — and cache the object
+next to the sources under a name that carries the hash of what it was
+built from (sources + flags), so an edit rebuilds and a stale object that
+rode along in a copied tree (file times mean nothing there) is never
+loaded: its name simply is not the one asked for.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -21,14 +26,25 @@ _lock = threading.Lock()
 _cache: dict = {}
 
 
-def _compile(out: str, srcs: list, flags: list, timeout: float) -> str:
-    """mtime-cached g++ compile-and-swap shared by every build target.
-    Raises RuntimeError on any failure mode (missing compiler included)."""
+def _compile(stem: str, suffix: str, srcs: list, flags: list,
+             timeout: float) -> str:
+    """Content-addressed g++ compile-and-swap shared by every build
+    target: the output is _build/<stem>.<hash><suffix>.  Raises
+    RuntimeError on any failure mode (missing compiler included)."""
+    h = hashlib.sha256(" ".join(flags).encode())
+    for path in srcs:
+        with open(path, "rb") as f:
+            h.update(b"\0" + os.path.basename(path).encode() + b"\0")
+            h.update(f.read())
+    out = os.path.join(_BUILD_DIR, f"{stem}.{h.hexdigest()[:16]}{suffix}")
     if os.path.exists(out):
-        out_mtime = os.path.getmtime(out)
-        if all(os.path.getmtime(s) <= out_mtime for s in srcs):
-            return out
+        return out
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    for old in glob.glob(os.path.join(_BUILD_DIR, f"{stem}.*{suffix}")):
+        try:
+            os.unlink(old)  # built from other sources; never loadable again
+        except OSError:
+            pass
     tmp = out + ".tmp.%d" % os.getpid()
     cmd = ["g++", *flags, "-o", tmp, *srcs, "-lpthread"]
     try:
@@ -45,13 +61,13 @@ def _compile(out: str, srcs: list, flags: list, timeout: float) -> str:
 
 
 def build_extension(name: str, sources: list, extra_flags: list = ()) -> str:
-    """Compile sources into _build/lib<name>.so; returns the path.
+    """Compile sources into _build/lib<name>.<hash>.so; returns the path.
 
-    Rebuilds when any source is newer than the cached .so.  Raises
-    RuntimeError if the compiler fails.
+    Rebuilds when the sources (or flags) differ from what the cached
+    object was built from.  Raises RuntimeError if the compiler fails.
     """
     return _compile(
-        os.path.join(_BUILD_DIR, f"lib{name}.so"),
+        f"lib{name}", ".so",
         [os.path.join(_SRC_DIR, s) for s in sources],
         ["-O2", "-g", "-std=c++17", "-shared", "-fPIC", *extra_flags],
         timeout=120)
@@ -60,10 +76,10 @@ def build_extension(name: str, sources: list, extra_flags: list = ()) -> str:
 def build_sanitized_selftest() -> str:
     """Build the ASAN+UBSAN self-test binary (reference: the C++ tests'
     bazel asan/tsan configs in .bazelrc); returns the binary path.
-    Rebuilds when any native source is newer."""
+    Rebuilds when any native source changed."""
     sources = ["selftest.cc", "shm_arena.cc", "shm_channel.cc", "sched.cc"]
     return _compile(
-        os.path.join(_BUILD_DIR, "native_selftest_san"),
+        "native_selftest_san", "",
         [os.path.join(_SRC_DIR, s) for s in sources],
         ["-std=c++17", "-g", "-O1", "-fno-omit-frame-pointer",
          "-fsanitize=address,undefined", "-fno-sanitize-recover=all"],

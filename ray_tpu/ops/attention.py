@@ -528,6 +528,30 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, q_offset,
 flash_attention_with_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+def resolve_impl(impl: str, sq: int, sk: int, causal: bool) -> str:
+    """The concrete path `attention(impl=...)` takes for these shapes on
+    this backend ("auto" resolved; anything else returned as given)."""
+    if impl != "auto":
+        return impl
+    # v5e measurements (GPT-2-small training, tokens/s), with the
+    # native FlashAttention-2 dq/dk/dv bwd kernels: pallas beats XLA
+    # blockwise at EVERY seq — 512 B=16: 99.5k vs 75.7k (+31%, MFU
+    # .40 vs .31); 4096: 59.5k vs 19.8k (3.0x, MFU .37); 8192: 37.0k
+    # vs 11.3k (3.3x, MFU .32).  (Before the bwd kernels existed the
+    # custom_vjp fell back to a full blockwise recompute and lost
+    # everywhere — that's why this dispatch was XLA-only through
+    # round 4.)  XLA remains the portable path: CPU meshes, seqs not
+    # a multiple of 128, and anything interpret-mode.
+    # causal rectangular with sq > sk still routes to XLA (a
+    # negative q_offset has no causal interpretation here); sk >= sq
+    # runs in pallas with the bottom-right anchor via q_offset
+    if (jax.default_backend() == "tpu"
+            and sq % 128 == 0 and sk % 128 == 0
+            and not (causal and sq > sk)):
+        return "pallas"
+    return "xla"
+
+
 def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
               impl: str = "auto", block_q: Optional[int] = None,
               block_k: Optional[int] = None):
@@ -538,27 +562,13 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
     Block defaults are per-path (v5e-measured optima differ 4x): the
     XLA scan wants small KV blocks (256 — deeper fusion per step), the
     pallas grid wants fat ones (512x1024 — fewer sequential programs).
+
+    The Pallas kernel is a Mosaic custom call, which GSPMD cannot
+    partition: on sharded arrays call it from inside a shard_map
+    (models/gpt.py `_attention_op` does).
     """
     sq, sk = q.shape[-2], k.shape[-2]
-    if impl == "auto":
-        # v5e measurements (GPT-2-small training, tokens/s), with the
-        # native FlashAttention-2 dq/dk/dv bwd kernels: pallas beats XLA
-        # blockwise at EVERY seq — 512 B=16: 99.5k vs 75.7k (+31%, MFU
-        # .40 vs .31); 4096: 59.5k vs 19.8k (3.0x, MFU .37); 8192: 37.0k
-        # vs 11.3k (3.3x, MFU .32).  (Before the bwd kernels existed the
-        # custom_vjp fell back to a full blockwise recompute and lost
-        # everywhere — that's why this dispatch was XLA-only through
-        # round 4.)  XLA remains the portable path: CPU meshes, seqs not
-        # a multiple of 128, and anything interpret-mode.
-        # causal rectangular with sq > sk still routes to XLA (a
-        # negative q_offset has no causal interpretation here); sk >= sq
-        # runs in pallas with the bottom-right anchor via q_offset
-        if (jax.default_backend() == "tpu"
-                and sq % 128 == 0 and sk % 128 == 0
-                and not (causal and sq > sk)):
-            impl = "pallas"
-        else:
-            impl = "xla"
+    impl = resolve_impl(impl, sq, sk, causal)
     # bottom-right-aligned causal mask for rectangular inputs, matching
     # mha_reference's tril(k=sk-sq) decode semantics
     qoff = (sk - sq) if (causal and sk > sq) else 0

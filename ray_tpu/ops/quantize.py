@@ -101,9 +101,11 @@ def _quantize_kernel(seed_ref, x_ref, q_ref, s_ref, *, stochastic: bool):
     y = x * (1.0 / scale)   # lockstep with _quantize_xla / host codec
     if stochastic:
         pltpu.prng_seed(seed_ref[0] + pl.program_id(0))
-        bits = pltpu.bitcast(pltpu.prng_random_bits(y.shape), jnp.uint32)
-        # top 24 bits -> u in [0, 1); floor(y + u) is unbiased
-        u = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+        bits = pltpu.bitcast(pltpu.prng_random_bits(y.shape), jnp.int32)
+        # top 24 bits -> u in [0, 1); floor(y + u) is unbiased.  Mosaic
+        # has no uint32 -> f32 cast, so the shift is a signed (sign-
+        # extending) one and the mask puts the value back in [0, 2^24)
+        u = ((bits >> 8) & 0xFFFFFF).astype(jnp.float32) * (1.0 / (1 << 24))
         y = jnp.floor(y + u)
     else:
         y = jnp.round(y)
@@ -143,6 +145,10 @@ def _quantize_pallas(blocks, stochastic: bool, seed, interpret: bool):
     rows = blocks.shape[0]
     kernel = functools.partial(_quantize_kernel, stochastic=stochastic)
     seed_arr = jnp.asarray([seed], jnp.int32)
+    if interpret and stochastic:
+        # the plain interpreter has no rule for the on-core PRNG; the
+        # TPU interpreter emulates it
+        interpret = pltpu.InterpretParams()
     q, s = pl.pallas_call(
         kernel,
         grid=(rows // _KERNEL_ROWS,),
@@ -399,7 +405,7 @@ def fused_reduce_scatter(x2d, axis: str, block_size: int = 256,
         ],
         # no DCE risk (o_ref is a consumed output), so only the
         # collective id for the cross-device barrier semaphore is needed
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_FUSED_COLLECTIVE_ID),
         interpret=interpret,
     )(x2d)

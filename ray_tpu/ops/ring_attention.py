@@ -21,7 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ray_tpu._private.jax_compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .attention import (DEFAULT_MASK_VALUE, _block_stats_update,

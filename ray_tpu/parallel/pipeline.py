@@ -22,7 +22,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from ray_tpu._private.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

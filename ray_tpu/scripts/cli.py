@@ -662,7 +662,10 @@ def cmd_device_stats(args):
     for wid, wsnap in sorted((snap.get("workers") or {}).items()):
         mem = wsnap.get("memory") or {}
         live = mem.get("live") or {}
-        line = (f"worker {wid[:16]}: live {_mb(live.get('total_bytes', 0))}"
+        line = (f"worker {wid[:16]} [{wsnap.get('platform') or 'no backend'}"
+                f" {wsnap.get('device_kind') or ''}"
+                f" x{wsnap.get('device_count') or 0}]: "
+                f"live {_mb(live.get('total_bytes', 0))}"
                 f" in {live.get('count', 0)} buffer(s)")
         owners = mem.get("owners") or {}
         for tag, rep in sorted(owners.items()):

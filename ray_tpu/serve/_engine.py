@@ -548,7 +548,12 @@ class ContinuousEngine:
                 f"({active} active, {queued} queued)")
         now = time.monotonic()
         snap = self._health_snap
-        if active == 0 or snap is None or snap[0] != steps:
+        # a program's first compile is not a stall: the step counter
+        # stands still for as long as the compiler takes, and restarting
+        # the replica would only compile again from nothing
+        compiling = any(getattr(fn, "first_compile_in_flight", False)
+                        for fn in list(self._fns.values()))
+        if active == 0 or snap is None or snap[0] != steps or compiling:
             self._health_snap = (steps, now)
         elif now - snap[1] > self.stall_s:
             raise RuntimeError(

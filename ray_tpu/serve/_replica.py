@@ -154,6 +154,12 @@ class Replica:
                 eng = stats_fn()
                 if eng:
                     out["engine"] = eng
+                # a replica that hosts a decode engine runs device
+                # programs: the controller's probe period doubles as the
+                # (rate-limited) heartbeat of its device snapshot
+                from ..telemetry.device import flush_device_snapshot
+
+                flush_device_snapshot()
             except Exception:
                 pass  # a metrics probe must never take the replica down
         return out

@@ -253,6 +253,11 @@ def diff_signatures(old: List[Dict[str, Any]],
 
 _monitoring_lock = threading.Lock()
 _monitoring_installed = False
+# persistent compile cache (JAX_COMPILATION_CACHE_DIR) traffic, counted
+# on the process ledger: whether a restarted process found its programs
+# or paid for them again
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
 
 
 def _frame_stack() -> List[Dict[str, Any]]:
@@ -282,7 +287,15 @@ def _install_monitoring() -> None:
                     d = st[-1]["durations"]
                     d[key] = d.get(key, 0.0) + float(duration)
 
+            def on_event(event: str, **kw) -> None:
+                key = _CACHE_EVENTS.get(event)
+                if key is not None:
+                    led = get_ledger()
+                    with led._lock:
+                        led._persistent_cache[key] += 1
+
             monitoring.register_event_duration_secs_listener(on_duration)
+            monitoring.register_event_listener(on_event)
             _monitoring_installed = True
         except Exception:
             # jax absent or too old: cache-size deltas still detect
@@ -354,6 +367,7 @@ class CompilationLedger:
         self._advisories: List[Dict[str, Any]] = []  # guarded-by: _lock
         self._total_compiles = 0   # guarded-by: _lock
         self._total_recompiles = 0  # guarded-by: _lock
+        self._persistent_cache = {"hits": 0, "misses": 0}  # guarded-by: _lock
         self._drain_idx = 0  # guarded-by: _lock
         self._last_flush = 0.0  # rate limiter state (monotonic)
 
@@ -564,6 +578,7 @@ class CompilationLedger:
             return {
                 "total_compiles": self._total_compiles,
                 "total_recompiles": self._total_recompiles,
+                "persistent_cache": dict(self._persistent_cache),
                 "programs": programs,
                 "records": list(self._records),
                 "advisories": list(self._advisories),
@@ -580,6 +595,7 @@ class CompilationLedger:
             self._drain_idx = 0
             self._total_compiles = 0
             self._total_recompiles = 0
+            self._persistent_cache = {"hits": 0, "misses": 0}
 
 
 def _analyze_executable(jitted: Any, args: Tuple,
@@ -638,6 +654,8 @@ class InstrumentedProgram:
             or getattr(jitted, "__name__", None) or repr(jitted)
         self._ledger = ledger
         self._analysis = analysis
+        self._cold_calls = 0   # calls in flight that found no executable
+        self._cold_lock = threading.Lock()
         try:
             self._sig: Optional[inspect.Signature] = \
                 inspect.signature(wrapped if wrapped is not None else jitted)
@@ -655,11 +673,18 @@ class InstrumentedProgram:
         frame = {"durations": {}}
         stack = _frame_stack()
         stack.append(frame)
+        cold = before == 0
+        if cold:
+            with self._cold_lock:
+                self._cold_calls += 1
         t0 = time.perf_counter()
         try:
             out = self._fn(*args, **kwargs)
         finally:
             stack.pop()
+            if cold:
+                with self._cold_lock:
+                    self._cold_calls -= 1
         if before is not None:
             try:
                 compiled_new = self._fn._cache_size() > before
@@ -670,6 +695,13 @@ class InstrumentedProgram:
                                     time.perf_counter() - t0,
                                     frame["durations"])
         return out
+
+    @property
+    def first_compile_in_flight(self) -> bool:
+        """A call is running that found this program never compiled:
+        it is in trace/lower/compile (seconds to minutes on a cold
+        cache), not hung — liveness probes read this."""
+        return self._cold_calls > 0
 
     def __getattr__(self, item):
         return getattr(self._fn, item)
@@ -721,7 +753,10 @@ class DeviceMemoryCensus:
         try:
             import jax
 
-            for a in jax.live_arrays():
+            # observing must never be what opens the chip: a process
+            # that has not touched its backend holds no buffers
+            arrays = jax.live_arrays() if backend_initialized() else ()
+            for a in arrays:
                 try:
                     nbytes = int(a.nbytes)
                     dt = str(a.dtype)
@@ -859,10 +894,37 @@ def reset_for_tests() -> None:
 # ---------------------------------------------------------------------------
 
 
+def backend_initialized() -> bool:
+    """True once this process has initialised a jax backend (asking is
+    free; `jax.devices()` / `jax.live_arrays()` would initialise one —
+    and on a TPU host take the chip)."""
+    try:
+        from jax._src import xla_bridge
+
+        return bool(xla_bridge.backends_are_initialized())
+    except Exception:
+        return False
+
+
+def backend_identity() -> Dict[str, Any]:
+    """Which device this process computes on, as jax reports it:
+    platform / device_kind / device_count, all None while the process
+    has not initialised a backend."""
+    if not backend_initialized():
+        return {"platform": None, "device_kind": None, "device_count": None}
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
 def device_snapshot() -> Dict[str, Any]:
     """The local process's full device-observability snapshot."""
     return {
         "ts": time.time(),
+        **backend_identity(),
         "ledger": get_ledger().snapshot(),
         "memory": get_census().census(),
     }

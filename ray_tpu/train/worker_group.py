@@ -35,11 +35,14 @@ class RayTrainWorker:
         return fn(*args, **kwargs)
 
     def node_metadata(self) -> Dict[str, Any]:
-        # TPU presence detected on THIS worker's node (libtpu device files /
-        # explicit platform pin), not the driver's environment.
-        has_tpu = (os.path.exists("/dev/accel0")
-                   or os.path.exists("/dev/vfio/0")
-                   or os.environ.get("JAX_PLATFORMS", "") == "tpu")
+        # can THIS worker compute on a TPU: its node has chips by the
+        # count the raylet advertises as the TPU resource (never by
+        # initialising jax), and the raylet did not pin this process to
+        # the CPU for want of a TPU lease
+        from ray_tpu._private.accelerators import num_tpu_chips
+
+        has_tpu = (num_tpu_chips() > 0
+                   and os.environ.get("JAX_PLATFORMS") != "cpu")
         return {
             "hostname": socket.gethostname(),
             "pid": os.getpid(),

@@ -1,0 +1,255 @@
+"""Compile-only rehearsal for the TPU: the main path's programs, at their
+real widths, handed to the chip's compiler for a *described* v5e 2x2 —
+no chip attached, nothing runs, so nothing here is a chip result.  What
+the compiler refuses here (a cast Mosaic lacks, a kernel GSPMD cannot
+partition, an API the installed jax dropped) is what the first chip run
+would have died on; interpret-mode tests see none of it.
+
+One file on purpose: only one process may load libtpu, the topology is
+described inside a module-scoped fixture (never at import), and every
+compile runs in this test process.  Code that asks
+`jax.default_backend()` sees the CPU here, so the kernel choice is
+steered from the test (`impl="pallas"`, `attention_impl="pallas"`).
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
+                          SingleDeviceSharding)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("x",))
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(tree, sharding):
+    """Shapes of `tree`, every leaf placed on `sharding`."""
+    return jax.tree.map(lambda x: _sds(x.shape, x.dtype, sharding), tree)
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# -- flash attention ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape", [(16, 12, 512, 64), (2, 12, 8192, 64)],
+                         ids=["b16s512", "b2s8192"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles(one_chip, shape, direction):
+    from ray_tpu.ops.attention import attention
+
+    def fwd(q, k, v):
+        return attention(q, k, v, causal=True, impl="pallas")
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    x = _sds(shape, jnp.bfloat16, one_chip)
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    # fwd is one kernel; bwd re-runs it and adds the dq and dk/dv kernels
+    assert _kernel_calls(compiled) >= (1 if direction == "fwd" else 3)
+
+
+# -- int8 quantization kernels ----------------------------------------------
+
+
+_QN, _QBLOCK = 1 << 20, 256
+
+
+@pytest.mark.parametrize("stochastic", [False, True],
+                         ids=["deterministic", "stochastic"])
+def test_quantize_kernel_compiles(one_chip, stochastic):
+    from ray_tpu.ops.quantize import quantize_blockwise
+
+    fn = functools.partial(quantize_blockwise, block_size=_QBLOCK,
+                           stochastic=stochastic, seed=7, impl="pallas")
+    compiled = jax.jit(fn).lower(
+        _sds((_QN,), jnp.float32, one_chip)).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+def test_dequantize_kernel_compiles(one_chip):
+    from ray_tpu.ops.quantize import dequantize_blockwise
+
+    fn = functools.partial(dequantize_blockwise, shape=(_QN,),
+                           dtype=jnp.float32, block_size=_QBLOCK,
+                           impl="pallas")
+    compiled = jax.jit(fn).lower(
+        _sds((_QN,), jnp.int8, one_chip),
+        _sds((_QN // _QBLOCK,), jnp.float32, one_chip)).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+def test_dequant_accumulate_kernel_compiles(one_chip):
+    from ray_tpu.ops.quantize import dequantize_accumulate
+
+    world = 4
+    fn = functools.partial(dequantize_accumulate, world=world,
+                           block_size=_QBLOCK, impl="pallas")
+    compiled = jax.jit(fn).lower(
+        _sds((world * _QN,), jnp.int8, one_chip),
+        _sds((world * _QN // _QBLOCK,), jnp.float32, one_chip)).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("sub", [4096, 65536])
+def test_fused_reduce_scatter_compiles(mesh4, sub):
+    """The one-kernel quantize -> remote-DMA exchange -> accumulate hop,
+    for four chips (the interpret-mode twin lives in
+    test_collective_pipeline.py)."""
+    from jax import shard_map
+
+    from ray_tpu.ops.quantize import fused_reduce_scatter
+
+    def body(x):                      # [1, world, sub] per device
+        return fused_reduce_scatter(x[0], "x")[None]
+
+    fn = shard_map(body, mesh=mesh4, in_specs=P("x"), out_specs=P("x"),
+                   check_vma=False)
+    x = _sds((4, 4, sub), jnp.float32, NamedSharding(mesh4, P("x")))
+    compiled = jax.jit(fn).lower(x).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+def test_int8_mesh_allreduce_fused_compiles(mesh4):
+    from ray_tpu.collective import xla_group
+
+    fn = functools.partial(xla_group.mesh_allreduce, mesh=mesh4,
+                           compression="int8", impl="fused")
+    x = _sds((4, 1 << 20), jnp.float32, NamedSharding(mesh4, P("x")))
+    compiled = jax.jit(fn).lower(x).compile()
+    # the fused hop is the one Mosaic kernel that talks to other chips
+    # (chip_smoke.py --chips 4 looks for the same marker on hardware)
+    assert '"has_communication":true' in compiled.as_text()
+
+
+# -- serving programs, GPT-2-small as LLMServer(preset="gpt2_small",
+# -- max_seq=1024) builds them ----------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def serve_engine(one_chip):
+    """A ContinuousEngine at the default serving shapes whose programs
+    are lowered, never run: params and cache are shapes on the described
+    chip."""
+    from ray_tpu.models import gpt
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    cfg = gpt.GPTConfig.gpt2_small(max_seq=1024)
+    eng = ContinuousEngine(gpt, cfg, None)
+    params = _on(jax.eval_shape(functools.partial(gpt.init, cfg=cfg),
+                                jax.random.PRNGKey(0)), one_chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        gpt.init_paged_cache, cfg, eng.num_pages, eng.page_size)), one_chip)
+    yield eng, cfg, params, cache
+    eng.stop()
+
+
+def test_serve_step_compiles(serve_engine, one_chip):
+    eng, cfg, params, cache = serve_engine
+    B = eng.max_slots
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    compiled = eng._fn("step").lower(
+        params, cache, s((B, cfg.vocab_size), jnp.float32),
+        s((B, 2), jnp.uint32), s((B,), jnp.float32), s((B,), jnp.int32),
+        s((B, eng.max_pages_per_seq), jnp.int32),
+        s((B,), jnp.int32)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("bucket", [32, 128])
+def test_serve_prefill_compiles(serve_engine, one_chip, bucket):
+    eng, cfg, params, cache = serve_engine
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    eng._fn(("prefill", bucket)).lower(
+        params, cache, s((bucket,), jnp.int32),
+        s((eng.max_pages_per_seq,), jnp.int32), s((), jnp.int32),
+        s((), jnp.int32)).compile()
+
+
+def test_serve_setrow_and_copy_page_compile(serve_engine, one_chip):
+    eng, cfg, params, cache = serve_engine
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    eng._fn("setrow").lower(
+        s((eng.max_slots, cfg.vocab_size), jnp.float32),
+        s((cfg.vocab_size,), jnp.bfloat16), s((), jnp.int32)).compile()
+    eng._fn("copy_page").lower(cache, s((), jnp.int32),
+                               s((), jnp.int32)).compile()
+
+
+# -- the train step ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("axes,batch", [({}, 16), ({"dp": 2, "fsdp": 2}, 32)],
+                         ids=["one_chip", "dp2_fsdp2"])
+def test_gpt2_small_train_step_compiles_with_kernel(topo, axes, batch):
+    """make_train_step's own jitted step (B=16 a chip, S=512, dots remat),
+    through make_mesh on the described devices.  On four chips the Pallas
+    kernel must be IN the program — inside a shard_map, since GSPMD cannot
+    partition a Mosaic call — not swapped for the blockwise XLA scan."""
+    import optax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.models.training import make_train_step
+    from ray_tpu.parallel.mesh import make_mesh
+
+    n = int(np.prod(list(axes.values()) or [1]))
+    mesh = make_mesh(devices=topo.devices[:n], **axes)
+    cfg = gpt.GPTConfig.gpt2_small(max_seq=512, remat_policy="dots",
+                                   attention_impl="pallas")
+    tx = optax.adamw(3e-4, weight_decay=0.1)
+    _, step_fn = make_train_step(cfg, mesh, tx)
+
+    def init_state(key):            # the state init_fn builds, as shapes
+        params = gpt.init(key, cfg)
+        return {"params": params, "opt_state": tx.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    b = _sds((batch, 512), jnp.int32,
+             NamedSharding(mesh, P(("dp", "fsdp", "ep"), None)))
+    compiled = step_fn.lower(state, {"inputs": b, "targets": b}).compile()
+    assert _kernel_calls(compiled) >= 3      # fwd + dq + dk/dv, per layer scan
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9

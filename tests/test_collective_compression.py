@@ -98,6 +98,33 @@ def test_stochastic_rounding_is_unbiased():
     assert _rel(np.mean(outs, axis=0), x) < 2e-3
 
 
+def test_stochastic_kernel_interpret_agrees_with_xla():
+    """The Pallas stochastic kernel (its random bits reach f32 through a
+    signed 32-bit intermediate, because Mosaic has no uint32 -> f32 cast;
+    tests/test_chip_compile.py holds it to the chip's compiler) against
+    _quantize_xla on the same blocks: the same scales, and every value
+    floor(y) or floor(y)+1 of the XLA path's y — never further, whatever
+    the bits.  The interpreter's PRNG hands out zero bits, so how the
+    draws are DISTRIBUTED is checked where there is a real one: on the
+    chip, by chip_smoke.py's train phase."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.quantize import (_as_blocks, _block_scales,
+                                      _quantize_xla, quantize_blockwise)
+
+    x = np.random.default_rng(5).standard_normal(64 * 256).astype(np.float32)
+    blocks = _as_blocks(jnp.asarray(x), 256)
+    _, s_xla = _quantize_xla(blocks, False, None)
+    y = np.asarray(blocks * (1.0 / _block_scales(blocks))).reshape(-1)
+    q, s = quantize_blockwise(jnp.asarray(x), 256, stochastic=True,
+                              seed=3, impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_xla), rtol=1e-6)
+    q = np.asarray(q, np.float32)
+    # 1e-3 of slack: the two paths' scales may differ in the last ulp
+    assert np.all(q >= np.clip(np.floor(y - 1e-3), -127, 127))
+    assert np.all(q <= np.clip(np.floor(y + 1e-3) + 1, -127, 127))
+
+
 def test_host_codec_matches_jax_numerics():
     """compress_array (numpy, kv wire path) and the XLA-lowered kernels
     must agree bit-for-bit with deterministic rounding — error feedback

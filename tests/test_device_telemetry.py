@@ -214,6 +214,72 @@ def _gauge_value(name, tags=None):
     return None
 
 
+def test_first_compile_in_flight_flag():
+    """A call that finds its program never compiled flags itself while
+    it runs (trace/lower/compile) and clears the flag when it returns;
+    a warm call never raises it."""
+    seen = []
+
+    def f(x):
+        seen.append(prog.first_compile_in_flight)   # at trace time
+        return x + 1
+
+    prog = devtel.instrument(jax.jit(f), name="flag.prog")
+    assert not prog.first_compile_in_flight
+    prog(jnp.ones(3))
+    assert seen == [True] and not prog.first_compile_in_flight
+    prog(jnp.ones(3))                    # cache hit: f is not re-traced
+    prog(jnp.ones(4))                    # a REcompile is not a first one
+    assert seen == [True, False]
+
+
+def test_engine_stall_probe_exempts_first_compile():
+    """serve_engine_stall_s counts seconds without a decode step while
+    slots are active.  A program's first compile (19.9 s for serve.step
+    on a cold v5e, chip_smoke PR 21) is such a stretch and is not a
+    stall: restarting the replica would only compile again."""
+    from ray_tpu.models import gpt
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    class Prog:
+        first_compile_in_flight = True
+
+    cfg = gpt.GPTConfig.nano(max_seq=64)
+    eng = ContinuousEngine(gpt, cfg, None, stall_s=0.05)
+    try:
+        eng._slots[0] = object()          # an active slot, no step yet
+        eng._fns["step"] = Prog()
+        assert eng.check_health()
+        time.sleep(0.12)
+        assert eng.check_health()         # compiling: the clock restarts
+        Prog.first_compile_in_flight = False
+        assert eng.check_health()
+        time.sleep(0.12)
+        with pytest.raises(RuntimeError, match="stalled"):
+            eng.check_health()
+    finally:
+        eng._slots[0] = None
+        eng.stop()
+
+
+def test_snapshot_names_the_device_without_opening_one(monkeypatch):
+    """platform / device_kind / device_count ride in the per-process
+    snapshot — and reading them (or the live-buffer census) never
+    initialises a backend: on a TPU host that would take the chip."""
+    jnp.ones(2).block_until_ready()
+    snap = devtel.device_snapshot()
+    assert snap["platform"] == "cpu" and snap["device_kind"] == "cpu"
+    assert snap["device_count"] == jax.device_count()
+    assert set(snap["ledger"]["persistent_cache"]) == {"hits", "misses"}
+
+    monkeypatch.setattr(devtel, "backend_initialized", lambda: False)
+    monkeypatch.setattr(jax, "devices", lambda *a: pytest.fail("opened"))
+    monkeypatch.setattr(jax, "live_arrays", lambda *a: pytest.fail("opened"))
+    cold = devtel.device_snapshot()
+    assert cold["platform"] is None and cold["device_count"] is None
+    assert cold["memory"]["live"]["count"] == 0
+
+
 def test_census_counts_live_buffers_and_sets_hbm_gauge():
     keep = jnp.ones((64, 64), jnp.float32) + 0    # a live device buffer
     census = devtel.get_census()

@@ -68,7 +68,7 @@ def test_moe_sharded_matches_dense(k):
     # capacity per local shard of T/n tokens, same for dense on full T/n:
     cap = expert_capacity(T // n, E, k, 1000.0)  # no drops -> exact match
 
-    from ray_tpu._private.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sharded = shard_map(

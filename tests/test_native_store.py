@@ -175,3 +175,25 @@ def test_stats(tmp_path):
     assert st["used"] >= 10000
     assert st["capacity"] > 0
     s.destroy()
+
+
+def test_native_object_is_named_by_its_sources(tmp_path, monkeypatch):
+    """The cached object carries the hash of what it was built from: a
+    stale object that rode along in a copied tree is never the one asked
+    for, whatever its mtime says, and an edit rebuilds."""
+    import ctypes
+
+    from ray_tpu.native import build
+
+    monkeypatch.setattr(build, "_SRC_DIR", str(tmp_path))
+    monkeypatch.setattr(build, "_BUILD_DIR", str(tmp_path / "_build"))
+    src = tmp_path / "x.cc"
+    src.write_text('extern "C" int v() { return 1; }\n')
+    a = build.build_extension("x", ["x.cc"])
+    assert ctypes.CDLL(a).v() == 1
+    assert build.build_extension("x", ["x.cc"]) == a    # cached
+    src.write_text('extern "C" int v() { return 2; }\n')
+    os.utime(src, (0, 0))       # older than the object: mtime says fresh
+    b = build.build_extension("x", ["x.cc"])
+    assert b != a and ctypes.CDLL(b).v() == 2
+    assert not os.path.exists(a)    # built from other sources: removed

@@ -114,7 +114,7 @@ def test_multi_axis_collective():
             s = jax.lax.psum(shard, "dp")
             return jax.lax.psum(s, "tp")
 
-        from ray_tpu._private.jax_compat import shard_map
+        from jax import shard_map
         return shard_map(f, mesh=mesh, in_specs=P(("dp",), "tp"),
                          out_specs=P(("dp",), "tp"))(v)
 
@@ -144,7 +144,7 @@ def test_multislice_mesh_layout():
 
     # a dp-psum over the multislice mesh compiles and runs
     import jax.numpy as jnp
-    from ray_tpu._private.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def f(x):
