@@ -1,0 +1,140 @@
+"""The general generator and the percentile arithmetic."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+from benchmarks.lib import manifest, stats, traffic
+from benchmarks.metrics.readers import itl_percentile, ttft_percentile
+
+
+def _traffic(name):
+    with open(os.path.join(manifest.BENCH_DIR, "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_same_seed_same_schedule_and_lengths():
+    tr = _traffic("open-chat")
+    a = traffic.open_schedule(tr, 3000000019, 50, 50304)
+    b = traffic.open_schedule(tr, 3000000019, 50, 50304)
+    assert a == b
+
+
+def _shape(r):
+    return (len(r["tokens"]), r["max_new_tokens"])
+
+
+def _gaps(win, seconds):
+    due = [r["due"] for r in win] + [seconds]
+    return [round(b - a, 9) for a, b in zip(due, due[1:])]
+
+
+def _rotated(xs, k):
+    return xs[k:] + xs[:k]
+
+
+def test_other_seed_same_cycle_entered_elsewhere_other_token_ids():
+    tr = dict(_traffic("open-chat"), entry_after_idle_s=0)   # any arrival
+    n = round(tr["arrivals"]["rate_per_s"] * 50)
+    seeds = (1, 2, 2147483659, 3000000019)
+    wins = {s: traffic.open_schedule(tr, s, 50, 50304) for s in seeds}
+    a = wins[1]
+    assert len(a) == n and a[0]["due"] == 0.0 and a[-1]["due"] < 50
+    assert all(x["due"] <= y["due"] for x, y in zip(a, a[1:]))
+    assert len({_shape(r) for r in a}) > n // 2        # sizes do vary
+    lo, hi = tr["prompt_len"]["min"], tr["prompt_len"]["max"]
+    assert all(lo <= len(r["tokens"]) <= hi and max(r["tokens"]) < 50257
+               for r in a)
+    shapes = {s: [_shape(r) for r in wins[s]] for s in seeds}
+    assert len({tuple(v) for v in shapes.values()}) > 1   # the seed moves it
+    for s in seeds:        # the same requests and gaps, in cyclic order
+        k = next(k for k in range(n) if _rotated(shapes[1], k) == shapes[s])
+        assert _gaps(wins[s], 50) == pytest.approx(
+            _rotated(_gaps(a, 50), k), abs=1e-6)
+    assert wins[1][0]["tokens"] != wins[2][0]["tokens"]   # ids are the seed's
+
+
+def test_the_window_opens_after_an_idle_stretch():
+    tr = _traffic("open-chat")
+    idle = tr["entry_after_idle_s"]
+    firsts = set()
+    for seed in range(40):
+        win = traffic.open_schedule(tr, seed, 50, 50304)
+        assert 50 - win[-1]["due"] >= idle        # the cycle's last gap
+        firsts.add(_shape(win[0]))
+    assert len(firsts) >= 2                       # more than one such place
+    short = dict(tr, entry_after_idle_s=1e9)      # none: the longest is taken
+    win = traffic.open_schedule(short, 3, 50, 50304)
+    assert 50 - win[-1]["due"] == pytest.approx(max(_gaps(win, 50)))
+
+
+def test_gamma_arrivals_keep_the_mean_rate():
+    tr = dict(_traffic("open-chat"),
+              arrivals={"process": "gamma", "cv": 3, "rate_per_s": 5.0})
+    s = [r["due"] for r in traffic.open_schedule(tr, 7, 100, 50304)]
+    assert len(s) == 500 and s[-1] < 100
+    gaps = np.diff(s)
+    assert gaps.std() / gaps.mean() > 2.0       # burstier than Poisson
+
+
+def test_packed_batches_are_full_rows_from_the_seed():
+    tr = _traffic("packed-1024")
+    a = next(traffic.packed_batches(tr, 5, 4, 50304))
+    b = next(traffic.packed_batches(tr, 5, 4, 50304))
+    c = next(traffic.packed_batches(tr, 6, 4, 50304))
+    assert a["inputs"].shape == a["targets"].shape == (4, 1024)
+    assert np.array_equal(a["inputs"], b["inputs"])
+    assert not np.array_equal(a["inputs"], c["inputs"])
+    assert np.array_equal(a["inputs"][:, 1:], a["targets"][:, :-1])
+    assert (a["inputs"] == tr["eos_id"]).sum() >= 1      # document ends
+    assert a["inputs"].max() < 50257
+    it = traffic.packed_batches(tr, 5, 4, 50304)
+    first, second = next(it), next(it)
+    assert not np.array_equal(first["inputs"], second["inputs"])
+
+
+def test_percentile_is_nearest_rank():
+    v = list(range(1, 101))
+    assert stats.percentile(v, 90) == 90
+    assert stats.percentile(v, 95) == 95
+    assert stats.percentile(v, 100) == 100
+    assert stats.percentile([5.0], 90) == 5.0
+    assert stats.percentile([1, 2, 3, 4], 50) == 2
+    with pytest.raises(ValueError):
+        stats.percentile([], 50)
+    with pytest.raises(ValueError):
+        stats.percentile([1], 0)
+
+
+def test_iqr_spread_is_the_contracts():
+    import statistics
+
+    v = [10.0, 10.2, 9.9, 10.1, 10.4, 9.8]
+    q = statistics.quantiles(v, n=4)
+    assert stats.iqr_spread(v) == (q[2] - q[0]) / statistics.median(v)
+
+
+def _obs(reqs):
+    return {"serve": {"requests": reqs, "timeout_ms": 160000.0}}
+
+
+def test_a_stalled_sender_is_timed_from_the_due_time():
+    # due at 10.0, the sender only got to it at 10.4, first token at 10.5:
+    # the user waited 500 ms, not 100
+    r = {"due": 10.0, "sent": 10.4, "times": [10.5, 10.6, 10.8],
+         "error": None}
+    assert ttft_percentile.read(_obs([r]), {"p": 90}, {}) == \
+        pytest.approx(500.0)
+    assert itl_percentile.read(_obs([r]), {"p": 95}, {}) == \
+        pytest.approx(200.0)
+
+
+def test_a_failed_request_is_worse_than_every_success():
+    ok = [{"due": 0.0, "sent": 0.0, "times": [0.1 + i * 0.001], "error": None}
+          for i in range(8)]
+    bad = [{"due": 0.0, "sent": 0.0, "times": [], "error": "AdmissionRejected"}
+           for _ in range(2)]
+    assert ttft_percentile.read(_obs(ok + bad), {"p": 90}, {}) == 160000.0
+    assert ttft_percentile.read(_obs(ok + bad), {"p": 80}, {}) < 200.0
