@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextvars
 import inspect
 import logging
 import os
@@ -767,10 +768,16 @@ class WorkerMain:
                                     # actor: stream from the dedicated
                                     # stream pool, not the loop (acks
                                     # block) nor the 8-thread default
-                                    # executor (streams are long-lived)
+                                    # executor (streams are long-lived).
+                                    # run_in_executor carries no
+                                    # contextvars: hand the stream thread
+                                    # this task's, so that what the
+                                    # generator body opens or records
+                                    # stays in the request's trace
                                     loop = asyncio.get_running_loop()
                                     reply = await loop.run_in_executor(
                                         self._stream_executor,
+                                        contextvars.copy_context().run,
                                         self._run_generator,
                                         spec, out, t0)
                                 else:

@@ -256,6 +256,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return (res[0], res[1]) if with_lse else res[0]
 
@@ -436,6 +437,7 @@ def _flash_backward(q, k, v, o, lse128, do, dlse, causal: bool,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse128, di128)
 
     dq_kernel = functools.partial(
@@ -450,6 +452,7 @@ def _flash_backward(q, k, v, o, lse128, do, dlse, causal: bool,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse128, di128)
     return dq, dk, dv
 

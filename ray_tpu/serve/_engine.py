@@ -34,12 +34,13 @@ concurrent Future (request/response).
 
 from __future__ import annotations
 
-import functools
 import queue
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from ..util import tracing
 
 __all__ = ["AdmissionRejected", "ContinuousEngine", "PageAllocator"]
 
@@ -257,8 +258,10 @@ class _Sequence:
 
     __slots__ = ("rid", "tokens", "max_new", "temperature", "top_k",
                  "seed", "eos_id", "out_q", "result", "slot", "pages",
-                 "pos", "generated", "keys", "t_submit", "t_first",
-                 "peak", "stream", "request_id", "key_offset")
+                 "pos", "generated", "keys", "t_submit", "t_admit",
+                 "t_prefill", "t_ready", "t_first", "t_last", "shared",
+                 "scanned", "trace_ctx", "peak", "stream", "request_id",
+                 "key_offset")
 
     def __init__(self, rid, tokens, max_new, temperature, top_k, seed,
                  eos_id, stream, request_id=None, key_offset=0):
@@ -281,8 +284,17 @@ class _Sequence:
         self.pos = 0
         self.generated: List[int] = []
         self.keys = None            # np [max_new, 2] uint32, set at admit
+        # clock reads at the request's phase boundaries (perf_counter):
+        # submit -> popped from _waiting -> prefill dispatched -> prefill
+        # ready -> first token out -> last token out.  They feed the
+        # ring's `requests` entry and the retro engine.* spans.
         self.t_submit = time.perf_counter()
+        self.t_admit = self.t_prefill = self.t_ready = 0.0
         self.t_first: Optional[float] = None
+        self.t_last = 0.0
+        self.shared = 0             # prompt tokens found in shared pages
+        self.scanned = 0            # tokens the prefill scanned (bucket)
+        self.trace_ctx = None       # submitter's sampled span context
         self.peak = 0               # max co-resident active slots seen
 
 
@@ -352,7 +364,19 @@ class ContinuousEngine:
         self._t_window: "deque[Tuple[float, int]]" = deque(maxlen=512)  # guarded-by: _lock
         self._totals = {"requests": 0, "rejected": 0, "tokens": 0,
                         "steps": 0, "prefills": 0, "cow_copies": 0,
-                        "shared_pages": 0}
+                        "shared_pages": 0,
+                        # cumulative sums of the ring's records, so two
+                        # engine_stats() snapshots give shares over any
+                        # interval whatever ring_size forgot
+                        "queue_wait_s": 0.0, "prefill_s": 0.0,
+                        "decode_s": 0.0, "host_s": 0.0,
+                        "device_wait_s": 0.0, "blocked_slot_s": 0.0,
+                        "prefill_tokens": 0, "prefill_scanned_tokens": 0}
+        # per-iteration scratch of the engine thread (_iteration resets)
+        self._last_prefill_s = 0.0
+        self._wait_s = 0.0           # blocked on the device
+        self._blocked = 0            # streaming slots an admission held up
+        self._first: List[_Sequence] = []   # first token this iteration
 
         # device-memory census: report this engine's page-arena
         # occupancy under a per-instance tag (unregistered in stop())
@@ -419,6 +443,10 @@ class ContinuousEngine:
             seq = _Sequence(self._rid, tokens, max_new, temperature,
                             top_k, seed, eos_id, stream,
                             request_id=request_id, key_offset=key_offset)
+            # the submitter's span context (None unless tracing is on
+            # and its trace sampled): _finish parents the request's
+            # engine.* spans to it, the worker.queue_wait pattern
+            seq.trace_ctx = tracing.sampled_context()
             self._waiting.append(seq)
             self._totals["requests"] += 1
             self._ensure_thread()
@@ -443,7 +471,12 @@ class ContinuousEngine:
         return seq.result.result(timeout=timeout)
 
     def engine_stats(self) -> Dict[str, Any]:
-        """Scheduler snapshot for admission control and autoscaling."""
+        """Scheduler snapshot for admission control and autoscaling:
+        what the router (`accepting`, `retry_after_s`), the controller
+        and the autoscaler (`queue_depth`, `active`, `free_pages`,
+        `ttft_p99_s`, `tokens_per_s`) read, plus the running totals —
+        counters and the ring's cumulative sums, so two snapshots give
+        rates and phase shares over any interval."""
         now = time.perf_counter()
         with self._lock:
             active = sum(1 for s in self._slots if s is not None)
@@ -451,33 +484,22 @@ class ContinuousEngine:
             ttfts = sorted(self._ttfts)
             window = [(t, n) for t, n in self._t_window if now - t <= 10.0]
             draining = self._draining
-            req_ids = [s.request_id
-                       for s in list(self._slots) + list(self._waiting)
-                       if s is not None and s.request_id]
+            totals = dict(self._totals)
         toks = sum(n for _, n in window)
         span = (now - window[0][0]) if window else 0.0
         free_pages = self._alloc.free_pages if self._alloc else \
             (self.max_slots - active) * self.max_pages_per_seq
-
-        def pct(p):
-            return ttfts[min(len(ttfts) - 1, int(p * len(ttfts)))] \
-                if ttfts else 0.0
-
         return {
-            "cache": self.cache_mode,
             "active": active,
-            "free_slots": self.max_slots - active,
             "queue_depth": qd,
             "free_pages": free_pages,
-            "num_pages": self.num_pages,
             "accepting": (not draining) and qd < self.shed_queue_depth,
-            "draining": draining,
-            "active_request_ids": req_ids,
             "retry_after_s": self.retry_after_s,
-            "ttft_p50_s": pct(0.50),
-            "ttft_p99_s": pct(0.99),
+            "ttft_p99_s": ttfts[min(len(ttfts) - 1,
+                                    int(0.99 * len(ttfts)))]
+            if ttfts else 0.0,
             "tokens_per_s": (toks / span) if span > 0 else 0.0,
-            **self._totals,
+            **totals,
         }
 
     def _census_report(self) -> Dict[str, Any]:
@@ -637,35 +659,77 @@ class ContinuousEngine:
                     self._finish(s, error=e)
 
     def _iteration(self):
+        """One scheduler iteration: admit, step, account.  Every phase
+        boundary is one `perf_counter` read that feeds three outputs:
+        the ring record (and `_totals`), the `serve.engine.*`
+        annotations on the profiler's clock — a flag test each while no
+        profiler session is open — and, per request, the retro
+        `engine.*` spans emitted by `_finish`."""
+        ann = self._jax.profiler.TraceAnnotation
         t0 = time.perf_counter()
-        admitted = self._admit()
+        self._wait_s = 0.0
+        self._blocked = 0
+        self._first = []
+        with ann("serve.engine.admit"):
+            admitted = self._admit()
         t1 = time.perf_counter()
         stepped = 0
         if any(s is not None for s in self._slots):
             stepped = self._step()
         t2 = time.perf_counter()
-        rec = {"swap_s": (t1 - t0) if admitted else 0.0,
-               "prefill_s": self._last_prefill_s if admitted else 0.0,
-               "decode_s": (t2 - t1) if stepped else 0.0,
-               "active": stepped, "admitted": admitted, "ts": t2}
-        with self._lock:
-            self._ring.append(rec)
-            qd = len(self._waiting)
-        m = _m_phase()
-        if m:
-            if admitted:
-                m.observe(max(0.0, rec["swap_s"] - rec["prefill_s"]),
-                          tags={"phase": "swap"})
-                m.observe(rec["prefill_s"], tags={"phase": "prefill"})
-            if stepped:
-                m.observe(rec["decode_s"], tags={"phase": "decode"})
-        for which, val in (("active", stepped),
-                           ("queue", qd),
-                           ("free_pages",
-                            self._alloc.free_pages if self._alloc else 0)):
-            g = _m_gauge(which)
-            if g:
-                g.set(val)
+        with ann("serve.engine.account"):
+            rec = {"swap_s": (t1 - t0) if admitted else 0.0,
+                   "prefill_s": self._last_prefill_s if admitted else 0.0,
+                   "decode_s": (t2 - t1) if stepped else 0.0,
+                   "active": stepped, "admitted": admitted, "ts": t2,
+                   "t0": t0, "device_wait_s": self._wait_s,
+                   "blocked_slots": self._blocked,
+                   "requests": [self._request_record(s)
+                                for s in self._first]}
+            m = _m_phase()
+            if m:
+                if admitted:
+                    m.observe(max(0.0, rec["swap_s"] - rec["prefill_s"]),
+                              tags={"phase": "swap"})
+                    m.observe(rec["prefill_s"], tags={"phase": "prefill"})
+                if stepped:
+                    m.observe(rec["decode_s"], tags={"phase": "decode"})
+            with self._lock:
+                qd = len(self._waiting)
+            for which, val in (("active", stepped),
+                               ("queue", qd),
+                               ("free_pages", self._alloc.free_pages
+                                if self._alloc else 0)):
+                g = _m_gauge(which)
+                if g:
+                    g.set(val)
+            # the record closes here: only its append comes after
+            rec["host_s"] = time.perf_counter() - t0 - self._wait_s
+            tot = self._totals
+            with self._lock:
+                self._ring.append(rec)
+                for k in ("prefill_s", "decode_s", "host_s",
+                          "device_wait_s"):
+                    tot[k] += rec[k]
+                tot["blocked_slot_s"] += rec["swap_s"] * rec["blocked_slots"]
+                for r in rec["requests"]:
+                    tot["queue_wait_s"] += r["queue_wait_s"]
+                    tot["prefill_tokens"] += (r["prompt_tokens"]
+                                              - r["shared_tokens"])
+                    tot["prefill_scanned_tokens"] += r["scanned_tokens"]
+
+    @staticmethod
+    def _request_record(s: _Sequence) -> Dict[str, Any]:
+        """The TTFT of one request by parts, as the ring keeps it: the
+        three parts sum to `ttft_s` up to the bookkeeping between
+        admission and the prefill's dispatch (page table, operands)."""
+        return {"rid": s.rid, "request_id": s.request_id,
+                "queue_wait_s": s.t_admit - s.t_submit,
+                "prefill_s": s.t_ready - s.t_prefill,
+                "first_step_wait_s": s.t_first - s.t_ready,
+                "ttft_s": s.t_first - s.t_submit,
+                "prompt_tokens": len(s.tokens),
+                "shared_tokens": s.shared, "scanned_tokens": s.scanned}
 
     # -- admission ----------------------------------------------------------
 
@@ -695,8 +759,15 @@ class ContinuousEngine:
                                             self._pages_needed(seq))
                     if plan is None:
                         break               # page-starved: wait for evicts
+                if not admitted:
+                    # streams this round's prefills stall: slots whose
+                    # sequence has already put a token out
+                    self._blocked = sum(
+                        1 for s in self._slots
+                        if s is not None and s.t_first is not None)
                 self._waiting.popleft()
                 self._slots[slot] = seq
+            seq.t_admit = time.perf_counter()
             self._admit_one(seq, slot, plan)
             admitted += 1
         if admitted:
@@ -726,13 +797,8 @@ class ContinuousEngine:
             shared_len = 0
         seq.slot = slot
         seq.pos = plen
-        # key_offset: a resumed continuation (router replay) re-derives
-        # the ORIGINAL request's key schedule and skips the keys its
-        # already-delivered tokens consumed — sampled decode stays
-        # bitwise-identical across the resume, same as greedy
-        seq.keys = np.asarray(jax.random.split(
-            jax.random.PRNGKey(seq.seed),
-            seq.key_offset + seq.max_new))[seq.key_offset:]
+        seq.shared = shared_len
+        ann = jax.profiler.TraceAnnotation
         self._pos[slot] = plen                  # first decode write pos
         self._temps[slot] = seq.temperature
         self._topks[slot] = int(seq.top_k or 0)
@@ -740,21 +806,38 @@ class ContinuousEngine:
         # prefill the non-shared prompt suffix as one padded program
         count = plen - shared_len
         T = -(-count // self.prefill_bucket) * self.prefill_bucket
+        seq.scanned = T
         chunk = np.zeros(T, np.int32)
         chunk[:count] = seq.tokens[shared_len:]
-        tp = time.perf_counter()
-        if self._alloc is not None:
-            logits, self._cache = self._fn(("prefill", T))(
-                self._params, self._cache, chunk, self._ptab[slot],
-                np.int32(shared_len), np.int32(count - 1))
-        else:
-            logits, self._cache = self._fn(("prefill", T))(
-                self._params, self._cache, chunk, np.int32(shared_len),
-                np.int32(count - 1), np.int32(slot))
-        self._logits = self._fn("setrow")(self._logits, logits,
-                                          np.int32(slot))
-        jax.block_until_ready(self._logits)
-        self._last_prefill_s += time.perf_counter() - tp
+        with ann("serve.engine.prefill", request_id=seq.request_id or "",
+                 tokens=count, bucket=T):
+            seq.t_prefill = time.perf_counter()
+            if self._alloc is not None:
+                logits, self._cache = self._fn(("prefill", T))(
+                    self._params, self._cache, chunk, self._ptab[slot],
+                    np.int32(shared_len), np.int32(count - 1))
+            else:
+                logits, self._cache = self._fn(("prefill", T))(
+                    self._params, self._cache, chunk, np.int32(shared_len),
+                    np.int32(count - 1), np.int32(slot))
+            with ann("serve.engine.setrow"):
+                self._logits = self._fn("setrow")(self._logits, logits,
+                                                  np.int32(slot))
+            jax.block_until_ready(self._logits)
+            seq.t_ready = time.perf_counter()
+        # key_offset: a resumed continuation (router replay) re-derives
+        # the ORIGINAL request's key schedule and skips the keys its
+        # already-delivered tokens consumed — sampled decode stays
+        # bitwise-identical across the resume, same as greedy.  Split
+        # after the prefill, so that nothing but page-table bookkeeping
+        # lies between a request's queue wait and its prefill
+        with ann("serve.engine.keys"):
+            seq.keys = np.asarray(jax.random.split(
+                jax.random.PRNGKey(seq.seed),
+                seq.key_offset + seq.max_new))[seq.key_offset:]
+            tk = time.perf_counter()
+        self._last_prefill_s += seq.t_ready - seq.t_prefill
+        self._wait_s += tk - seq.t_prefill
         self._totals["prefills"] += 1
 
         # register this prompt's full pages for live prefix sharing
@@ -770,44 +853,51 @@ class ContinuousEngine:
         """One fused sample+decode step over every slot.  Inactive slots
         ride along at pos 0 against the null page; their tokens are
         discarded here on the host."""
-        np = self._np
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None]
-        for i, s in active:
-            self._toks_keys[i] = s.keys[len(s.generated)]
-        toks, self._logits, self._cache = self._fn("step")(
-            self._params, self._cache, self._logits, self._toks_keys,
-            self._temps, self._topks, self._ptab, self._pos)
-        toks = np.asarray(toks)
-        self._totals["steps"] += 1
-        now = time.perf_counter()
-        emitted = 0
-        finished = []
-        for i, s in active:
-            tok = int(toks[i])
-            s.generated.append(tok)
-            emitted += 1
-            if s.t_first is None:
-                s.t_first = now
-                ttft = now - s.t_submit
-                with self._lock:
-                    self._ttfts.append(ttft)
-                m = _m_ttft()
-                if m:
-                    m.observe(ttft)
-            s.out_q.put(tok)
-            self._pos[i] += 1
-            if (len(s.generated) >= s.max_new
-                    or (s.eos_id is not None and tok == s.eos_id)):
-                finished.append((i, s))
-        self._totals["tokens"] += emitted
-        with self._lock:
-            self._t_window.append((now, emitted))
-        m = _m_tokens()
-        if m and emitted:
-            m.inc(emitted)
-        for i, s in finished:
-            self._evict(i, s)
+        np, ann = self._np, self._jax.profiler.TraceAnnotation
+        with ann("serve.engine.step"):
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None]
+            for i, s in active:
+                self._toks_keys[i] = s.keys[len(s.generated)]
+            td = time.perf_counter()
+            toks, self._logits, self._cache = self._fn("step")(
+                self._params, self._cache, self._logits, self._toks_keys,
+                self._temps, self._topks, self._ptab, self._pos)
+        with ann("serve.engine.fetch"):
+            toks = np.asarray(toks)
+            now = time.perf_counter()
+        self._wait_s += now - td
+        with ann("serve.engine.emit"):
+            self._totals["steps"] += 1
+            emitted = 0
+            finished = []
+            for i, s in active:
+                tok = int(toks[i])
+                s.generated.append(tok)
+                emitted += 1
+                s.t_last = now
+                if s.t_first is None:
+                    s.t_first = now
+                    self._first.append(s)
+                    ttft = now - s.t_submit
+                    with self._lock:
+                        self._ttfts.append(ttft)
+                    m = _m_ttft()
+                    if m:
+                        m.observe(ttft)
+                s.out_q.put(tok)
+                self._pos[i] += 1
+                if (len(s.generated) >= s.max_new
+                        or (s.eos_id is not None and tok == s.eos_id)):
+                    finished.append((i, s))
+            self._totals["tokens"] += emitted
+            with self._lock:
+                self._t_window.append((now, emitted))
+            m = _m_tokens()
+            if m and emitted:
+                m.inc(emitted)
+            for i, s in finished:
+                self._evict(i, s)
         return len(active)
 
     def _evict(self, slot: int, seq: _Sequence):
@@ -823,6 +913,8 @@ class ContinuousEngine:
         self._wake.set()          # page/slot freed: retry page-starved head
 
     def _finish(self, seq: _Sequence, error: Optional[Exception] = None):
+        if seq.trace_ctx is not None and seq.t_first is not None:
+            self._record_spans(seq)     # before the caller hears of the end
         if error is not None:
             if not seq.result.done():
                 seq.result.set_exception(error)
@@ -841,6 +933,23 @@ class ContinuousEngine:
             if m:
                 m.inc(tags={"outcome": "ok"})
         seq.out_q.put(self._END)
+
+    @staticmethod
+    def _record_spans(seq: _Sequence):
+        """The request's phases as children of the span that submitted
+        it, from the clock reads the engine thread took anyway (only
+        reached for a request whose trace is sampled)."""
+        wall = time.time_ns() - int(time.perf_counter() * 1e9)
+        for name, a, b in (
+                ("engine.queue_wait", seq.t_submit, seq.t_admit),
+                ("engine.prefill", seq.t_prefill, seq.t_ready),
+                ("engine.first_step", seq.t_ready, seq.t_first),
+                ("engine.decode", seq.t_first, seq.t_last)):
+            tracing.record_span(
+                name, "INTERNAL", wall + int(a * 1e9), wall + int(b * 1e9),
+                seq.trace_ctx, request_id=seq.request_id, rid=seq.rid,
+                prompt_tokens=len(seq.tokens),
+                generated_tokens=len(seq.generated))
 
     # -- compiled programs --------------------------------------------------
 
@@ -893,44 +1002,48 @@ class ContinuousEngine:
                 return jnp.where(temps > 0, sampled,
                                  greedy).astype(jnp.int32)
 
+            # named apart from the train step: `jit_serve_step(...)` on
+            # the device trace's `XLA Modules` line
             if paged:
-                def step(params, cache, logits, keys, temps, topks,
-                         ptab, pos):
+                def serve_step(params, cache, logits, keys, temps, topks,
+                               ptab, pos):
                     toks = sample(logits, keys, temps, topks)
                     new_logits, cache = gpt.paged_decode_step(
                         params, cache, toks, ptab, pos, cfg)
                     return toks, new_logits.astype(jnp.float32), cache
             else:
-                def step(params, cache, logits, keys, temps, topks,
-                         ptab, pos):
+                def serve_step(params, cache, logits, keys, temps, topks,
+                               ptab, pos):
                     toks = sample(logits, keys, temps, topks)
                     new_logits, cache = gpt.slot_decode_step(
                         params, cache, toks, pos, cfg)
                     return toks, new_logits.astype(jnp.float32), cache
 
             fn = self._fns[key] = devtel.instrument(
-                jax.jit(step), name="serve.step")
+                jax.jit(serve_step), name="serve.step")
         elif key == "setrow":
-            fn = self._fns[key] = devtel.instrument(jax.jit(
-                lambda L, row, slot: L.at[slot].set(
-                    row.astype(jnp.float32))), name="serve.setrow")
-        elif key == "copy_page":
+            def serve_setrow(L, row, slot):
+                return L.at[slot].set(row.astype(jnp.float32))
+
             fn = self._fns[key] = devtel.instrument(
-                jax.jit(gpt.copy_page), name="serve.copy_page")
+                jax.jit(serve_setrow), name="serve.setrow")
+        elif key == "copy_page":
+            def serve_copy_page(cache, dst, src):
+                return gpt.copy_page(cache, dst, src)
+
+            fn = self._fns[key] = devtel.instrument(
+                jax.jit(serve_copy_page), name="serve.copy_page")
         elif isinstance(key, tuple) and key[0] == "prefill":
-            # per-bucket program name: a healthy engine compiles each
+            # per-bucket ledger name: a healthy engine compiles each
             # padded-length bucket once; the SAME bucket recompiling is
             # the storm signal, a new bucket is not
-            if paged:
-                fn = self._fns[key] = devtel.instrument(
-                    jax.jit(functools.partial(
-                        gpt.paged_prefill, cfg=cfg)),
-                    name=f"serve.prefill:{key[1]}")
-            else:
-                fn = self._fns[key] = devtel.instrument(
-                    jax.jit(functools.partial(
-                        gpt.slot_prefill, cfg=cfg)),
-                    name=f"serve.prefill:{key[1]}")
+            prefill = gpt.paged_prefill if paged else gpt.slot_prefill
+
+            def serve_prefill(params, cache, toks, *operands):
+                return prefill(params, cache, toks, *operands, cfg=cfg)
+
+            fn = self._fns[key] = devtel.instrument(
+                jax.jit(serve_prefill), name=f"serve.prefill:{key[1]}")
         else:
             raise KeyError(key)
         return fn

@@ -102,6 +102,17 @@ def _current() -> Optional[Dict[str, Any]]:
     return _ctx.get()
 
 
+def sampled_context() -> Optional[Dict[str, Any]]:
+    """The caller's span context if tracing is on and its trace is
+    sampled, else None: what a component keeps with a queued request so
+    that another thread can parent retro spans (``record_span``) to it
+    later — for an untraced request this is one flag test."""
+    if not _enabled:
+        return None
+    ctx = _ctx.get()
+    return ctx if ctx is not None and ctx.get("sampled", True) else None
+
+
 # shared sampled-out context/carrier: the 99% path under ratio sampling
 # allocates no ids and formats no strings — suppression is the only
 # information that has to propagate, so one constant serves every trace

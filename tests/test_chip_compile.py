@@ -218,6 +218,59 @@ def test_serve_setrow_and_copy_page_compile(serve_engine, one_chip):
                                s((), jnp.int32)).compile()
 
 
+@pytest.mark.parametrize("key,module", [
+    ("step", "jit_serve_step"), (("prefill", 32), "jit_serve_prefill"),
+    ("setrow", "jit_serve_setrow"), ("copy_page", "jit_serve_copy_page")],
+    ids=["step", "prefill", "setrow", "copy_page"])
+def test_serve_programs_carry_their_names(serve_engine, one_chip, key, module):
+    """What the device trace's `XLA Modules` line calls a program is its
+    jitted function's name: the serve programs have names of their own
+    (the train step stays `jit_step`), and the benchmark's
+    `engine.decode_step_device_ms.chat` matches `^jit_serve_step`."""
+    eng, cfg, params, cache = serve_engine
+    B, V, maxp = eng.max_slots, cfg.vocab_size, eng.max_pages_per_seq
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    i32 = s((), jnp.int32)
+    args = {
+        "step": (params, cache, s((B, V), jnp.float32), s((B, 2), jnp.uint32),
+                 s((B,), jnp.float32), s((B,), jnp.int32),
+                 s((B, maxp), jnp.int32), s((B,), jnp.int32)),
+        "prefill": (params, cache, s((32,), jnp.int32),
+                    s((maxp,), jnp.int32), i32, i32),
+        "setrow": (s((B, V), jnp.float32), s((V,), jnp.bfloat16), i32),
+        "copy_page": (cache, i32, i32),
+    }[key if isinstance(key, str) else key[0]]
+    text = eng._fn(key).lower(*args).as_text()
+    assert f"module @{module} " in text.split("\n", 1)[0], text[:200]
+
+
+def test_flash_attention_kernels_carry_their_names(one_chip):
+    """The three Pallas kernels are named in the compiled program (the
+    instruction name and `op_name` are what the trace's `XLA Ops` line
+    shows); target and operand counts, which the benchmark's readers
+    match, stay as they were."""
+    from ray_tpu.ops.attention import attention
+
+    def loss(q, k, v):
+        return attention(q, k, v, causal=True,
+                         impl="pallas").astype(jnp.float32).sum()
+
+    x = _sds((2, 4, 1024, 64), jnp.bfloat16, one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    operands = lambda ln: ln.split("custom-call(", 1)[1].split(
+        "), custom_call_target", 1)[0].count("%")
+    for name, n_operands in (("flash_attention_fwd", 3),
+                             ("flash_attention_bwd_dkv", 6),
+                             ("flash_attention_bwd_dq", 6)):
+        named = [ln for ln in calls if f"({name})" in ln]
+        assert len(named) == 1, (name, len(named))
+        assert name in named[0].split(" = ", 1)[0]      # the instruction
+        assert operands(named[0]) == n_operands
+
+
 # -- the train step ----------------------------------------------------------
 
 

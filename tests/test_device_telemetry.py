@@ -325,7 +325,7 @@ def test_page_allocator_occupancy_flows_to_census_and_gauges():
         assert rep["pages"]["shared"] == st["shared_pages"]
         assert rep["pages"]["cow"] == st["cow_copies"]
         assert rep["pages"]["free"] + rep["pages"]["used"] \
-            == st["num_pages"] - 1               # page 0 reserved
+            == eng.num_pages - 1                 # page 0 reserved
         for state in ("free", "used", "shared", "cow"):
             assert _gauge_value("ray_tpu_kv_pages",
                                 {"state": state}) is not None

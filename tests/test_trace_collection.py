@@ -333,6 +333,63 @@ def test_trace_collected_centrally_with_critical_path(tmp_path):
     assert res["chrome_valid"]
 
 
+def test_serve_request_trace_shows_the_engine_phases():
+    """`ray-tpu trace <id>` of a serve request: the engine's
+    queue_wait / prefill / first_step / decode spans hang under the
+    replica's execute span — on the request/response route and on the
+    streamed one, whose generator body runs on a stream-pool thread that
+    has to be handed the task's context."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["RAY_TPU_TRACE_SAMPLE"] = "1.0"
+    env["JAX_PLATFORMS"] = "cpu"
+    body = """
+        import json, time
+        import ray_tpu
+        from ray_tpu import serve
+        from ray_tpu.serve.llm import LLMServer
+        ray_tpu.init(num_cpus=2)
+        h = serve.run(LLMServer().bind(
+            preset="nano", max_seq=64, engine="paged",
+            engine_kwargs={"max_slots": 2, "page_size": 8,
+                           "prefill_bucket": 8}),
+            name="t", route_prefix=None, blocking_timeout_s=300)
+        prompt = [3, 14, 15, 92, 6, 5]
+        list(h.options(stream=True).stream_tokens.remote(prompt, 4))
+        h.remote({"tokens": prompt, "max_new_tokens": 4}).result(
+            timeout_s=120)
+        from ray_tpu._private import core as core_mod
+        from ray_tpu.telemetry import trace_assembly as ta
+        control = core_mod._current_core.control
+        want = {"handle_request_streaming", "handle_request"}
+        found = {}
+        deadline = time.time() + 30
+        while time.time() < deadline and set(found) != want:
+            for tid in ta.list_trace_ids(control):
+                spans = ta.fetch_trace(control, tid)
+                by = {s["name"]: s for s in spans}
+                for route in want:
+                    ex = by.get("actor.execute " + route)
+                    eng = [s for s in spans
+                           if s["name"].startswith("engine.")]
+                    if ex is not None and len(eng) == 4:
+                        found[route] = all(
+                            s["parent_id"] == ex["span_id"] for s in eng)
+            time.sleep(0.4)
+        print("RESULT " + json.dumps(found))
+        serve.shutdown()
+        ray_tpu.shutdown()
+    """
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
+                         capture_output=True, text=True, timeout=300,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = next(l for l in out.stdout.splitlines()
+                if l.startswith("RESULT "))
+    assert json.loads(line[len("RESULT "):]) == {
+        "handle_request_streaming": True, "handle_request": True}
+
+
 def test_report_spans_collector_merges_and_serves_kv(ray_cluster):
     """Direct collector contract: a report_spans notify lands in the
     per-trace store and is served back through plain kv_get, with
