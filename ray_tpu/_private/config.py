@@ -182,8 +182,10 @@ CONFIG_DEFS: List[Tuple[str, type, Any, str]] = [
     ("serve_retry_after_s", float, 1.0,
      "Retry-After hint attached to shed/rejected serve requests"),
     ("serve_prefill_bucket", int, 32,
-     "prefill token chunks are padded to multiples of this (bounds "
-     "prefill compile variants to max_total/bucket)"),
+     "a prompt's chunk is padded to a multiple of this and prefilled "
+     "in one pass: it bounds the one-pass program's compile variants "
+     "to max_total/bucket, and a pad token costs a matmul row, not a "
+     "sequential pass"),
     ("serve_replay_budget", int, 2,
      "replays per request after a replica dies mid-call (actor-died / "
      "unreachable); exhausting the budget surfaces the ORIGINAL error"),

@@ -693,24 +693,30 @@ def _decode_hidden_fast(view, cfg: GPTConfig, kcache, vcache, pos, toks):
 #     is reserved as the null page: inactive slots write there and their
 #     outputs are discarded host-side, so the compiled step program
 #     never changes shape as sequences come and go.
+#
+# A sequence joins through a prefill program (slot_prefill /
+# paged_prefill): its padded prompt chunk in ONE pass through the layers
+# (_prefill_chunk), T query rows through the _slot_attention that a
+# decode step feeds one row a slot.
 
 
 def _slot_rope(x, cos, sin, positions):
-    """Per-slot rotary embedding: x [B, H, 1, dh], positions [B] (each
-    batch row at its own decode position, unlike ops.apply_rope whose
+    """Per-slot rotary embedding: x [B, H, T, dh], positions [B, T] (each
+    batch row at its own positions, unlike ops.apply_rope whose
     positions are shared across the batch)."""
-    c = cos[positions][:, None, None]           # [B, 1, 1, dh/2]
-    sn = sin[positions][:, None, None]
+    c = cos[positions][:, None]                 # [B, 1, T, dh/2]
+    sn = sin[positions][:, None]
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * c - x2 * sn, x2 * c + x1 * sn],
                            axis=-1).astype(x.dtype)
 
 
 def _slot_embed(params, tokens, pos, cfg: GPTConfig):
-    x = params["embed"][tokens].astype(cfg.dtype)          # [B, D]
+    """tokens [B, T] at positions pos [B, T] -> x [B, T, D]."""
+    x = params["embed"][tokens].astype(cfg.dtype)
     if cfg.pos == "learned":
-        x = x + params["pos_embed"][pos].astype(cfg.dtype)  # per-slot row
-    return x[:, None]                                      # [B, 1, D]
+        x = x + params["pos_embed"][pos].astype(cfg.dtype)  # per-slot rows
+    return x
 
 
 def _slot_qkv(x, layer, cfg: GPTConfig, rope, pos):
@@ -722,14 +728,16 @@ def _slot_qkv(x, layer, cfg: GPTConfig, rope, pos):
 
 
 def _slot_attention(q, kc, vc, pos, cfg: GPTConfig):
-    """q [B,H,1,dh] against a per-slot cache view kc/vc [B,H,S,dh] with
-    per-slot causal masks (<= pos[b]).  This is the ONE attention recipe
-    both cache layouts feed — the paged path gathers its pages into
-    exactly this [B,H,S,dh] view, which is what makes paged==contiguous
-    a structural identity rather than a numerical accident."""
+    """q [B,H,T,dh] at positions pos [B,T] against a per-slot cache view
+    kc/vc [B,H,S,dh], masked causally by position (key <= pos[b, t]).
+    This is the ONE attention recipe both cache layouts and both serve
+    programs feed — a decode step is its T = 1 case, a prefill its
+    B = 1 case, and the paged path gathers its pages into exactly this
+    [B,H,S,dh] view, which is what makes paged==contiguous a structural
+    identity rather than a numerical accident."""
     S = kc.shape[2]
     mask = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, S), 3)
-            <= pos[:, None, None, None])
+            <= pos[:, None, :, None])
     s = jnp.einsum("bhqk,bhsk->bhqs", q.astype(jnp.float32),
                    kc.astype(jnp.float32)) * (cfg.d_head ** -0.5)
     s = jnp.where(mask, s, -1e30)
@@ -759,15 +767,16 @@ def _slot_decode_hidden(params, kcache, vcache, tokens, pos, cfg: GPTConfig,
         rope = None
     elif rope is None:
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
-    x = _slot_embed(params, tokens, pos, cfg)
+    qpos = pos[:, None]                        # one query row a slot
+    x = _slot_embed(params, tokens[:, None], qpos, cfg)     # [B, 1, D]
     bidx = jnp.arange(B)
 
     def block(x, inp):
         layer, kc, vc = inp                    # kc/vc [B, H, S, dh]
-        q, k, v = _slot_qkv(x, layer, cfg, rope, pos)
+        q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)
         kc = kc.at[bidx, :, pos, :].set(k[:, :, 0, :].astype(kc.dtype))
         vc = vc.at[bidx, :, pos, :].set(v[:, :, 0, :].astype(vc.dtype))
-        o = _slot_attention(q, kc, vc, pos, cfg)
+        o = _slot_attention(q, kc, vc, qpos, cfg)
         return _attn_out_and_mlp(x, o, layer, cfg), (kc, vc)
 
     x, (k_new, v_new) = jax.lax.scan(
@@ -786,33 +795,66 @@ def slot_decode_step(params, cache, tokens, pos, cfg: GPTConfig, rope=None):
     return logits, {"k": k_new, "v": v_new}
 
 
+def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
+                   write, view, cfg: GPTConfig, rope=None):
+    """One pass of a padded prompt chunk through the stack, shared by
+    both cache layouts: toks [T] sit at positions start..start+T-1 of
+    ONE sequence whose cache holds S positions; logits are taken at row
+    `last_idx` (the last REAL prompt token).  Per layer the chunk's T
+    rows of K and V are written with one `write(c, rows [T,H,dh], wpos)`
+    a side, then the chunk attends to `view(c)` [1,H,S,dh] — whatever
+    the cache held before `start` and itself — through _slot_attention.
+    kcache/vcache are stacked per layer on axis 0.
+
+    Pad rows sit behind every real token, so no real query sees them;
+    their K/V land where decode overwrites before it attends, and a row
+    at or past S (a chunk padded over the end of the cache) must be
+    written nowhere a real row lives: `write` sees the unclamped wpos.
+    Returns (logits [V], kcache, vcache)."""
+    T = toks.shape[0]
+    wpos = start + jnp.arange(T, dtype=jnp.int32)
+    pos = jnp.minimum(wpos, S - 1)[None]                   # [1, T]
+    if cfg.pos == "learned":
+        rope = None
+    elif rope is None:
+        rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
+    x = _slot_embed(params, toks[None], pos, cfg)          # [1, T, D]
+
+    def block(x, inp):
+        layer, kc, vc = inp
+        q, k, v = _slot_qkv(x, layer, cfg, rope, pos)      # [1, H, T, dh]
+        kc = write(kc, jnp.swapaxes(k[0], 0, 1).astype(kc.dtype), wpos)
+        vc = write(vc, jnp.swapaxes(v[0], 0, 1).astype(vc.dtype), wpos)
+        o = _slot_attention(q, view(kc), view(vc), pos, cfg)
+        return _attn_out_and_mlp(x, o, layer, cfg), (kc, vc)
+
+    # a rolled scan: at T rows a layer the matmuls dwarf the per-op
+    # fixed cost that makes the decode step unroll, and the program
+    # stays one layer long to compile and to load
+    x, (k_new, v_new) = jax.lax.scan(
+        block, x, (params["layers"], kcache, vcache))
+    x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
+    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    logits = jnp.einsum("d,dv->v", x.astype(cfg.dtype),
+                        _unembed_table(params, cfg))
+    return logits, k_new, v_new
+
+
 def slot_prefill(params, cache, toks, start, last_idx, slot,
                  cfg: GPTConfig, rope=None):
-    """Prefill ONE slot while the rest of the batch is frozen: toks [T]
-    (padded; positions are clamped so pad steps never overflow the
-    row — pad writes land at positions decode overwrites before it
-    attends them), starting at position `start`; logits are taken at
-    scanned index `last_idx` (the last REAL prompt token).  Returns
-    (logits [V], cache)."""
+    """Prefill ONE slot while the rest of the batch is frozen: the
+    padded chunk toks [T], starting at position `start`, goes through
+    the layers in one pass (_prefill_chunk); logits are taken at chunk
+    row `last_idx`.  Returns (logits [V], cache)."""
     S = cache["k"].shape[3]
     kc = jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, 1)
     vc = jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, 1)
-    T = toks.shape[0]
-    positions = jnp.minimum(start + jnp.arange(T, dtype=jnp.int32), S - 1)
-    if cfg.pos != "learned" and rope is None:
-        rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
 
-    def body(carry, inp):
-        kc, vc = carry
-        tok, p = inp
-        x, kc, vc = _slot_decode_hidden(params, kc, vc, tok[None],
-                                        p[None], cfg, rope)
-        return (kc, vc), x[0]
+    def write(c, rows, wpos):                  # c [1, H, S, dh]
+        return c.at[0, :, wpos, :].set(rows, mode="drop")
 
-    (kc, vc), xs = jax.lax.scan(body, (kc, vc), (toks, positions))
-    x = jax.lax.dynamic_index_in_dim(xs, last_idx, 0, keepdims=False)
-    logits = jnp.einsum("d,dv->v", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
+    logits, kc, vc = _prefill_chunk(params, kc, vc, toks, start, last_idx,
+                                    S, write, lambda c: c, cfg, rope)
     cache = {
         "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], kc, slot, 1),
         "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, slot, 1),
@@ -833,6 +875,15 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
+def _gather_pages(pages, ptab):
+    """One layer's arena [P, H, ps, dh] through page tables ptab
+    [B, maxp] -> the contiguous per-slot view [B, H, maxp * ps, dh]."""
+    B, maxp = ptab.shape
+    _, H, ps, dh = pages.shape
+    g = pages[ptab]                            # [B, maxp, H, ps, dh]
+    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, H, maxp * ps, dh)
+
+
 def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
                          cfg: GPTConfig, rope=None):
     """One decode position for every slot against the page arena:
@@ -841,30 +892,25 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     scatter into each slot's current page; attention gathers the slot's
     pages into the contiguous [B, H, S, dh] view and runs the shared
     _slot_attention recipe."""
-    B = tokens.shape[0]
-    H, dh = cfg.n_heads, cfg.d_head
     ps = kpages.shape[3]
-    maxp = ptab.shape[1]
-    S = maxp * ps
+    S = ptab.shape[1] * ps
     pos = jnp.minimum(pos, S - 1)
     if cfg.pos == "learned":
         rope = None
     elif rope is None:
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
-    x = _slot_embed(params, tokens, pos, cfg)
+    qpos = pos[:, None]                        # one query row a slot
+    x = _slot_embed(params, tokens[:, None], qpos, cfg)     # [B, 1, D]
     pidx = jnp.take_along_axis(ptab, (pos // ps)[:, None], axis=1)[:, 0]
     poff = pos % ps
 
-    def gather(pages):
-        g = pages[ptab]                        # [B, maxp, H, ps, dh]
-        return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, H, S, dh)
-
     def block(x, inp):
         layer, kc, vc = inp                    # kc/vc [P, H, ps, dh]
-        q, k, v = _slot_qkv(x, layer, cfg, rope, pos)
+        q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)
         kc = kc.at[pidx, :, poff, :].set(k[:, :, 0, :].astype(kc.dtype))
         vc = vc.at[pidx, :, poff, :].set(v[:, :, 0, :].astype(vc.dtype))
-        o = _slot_attention(q, gather(kc), gather(vc), pos, cfg)
+        o = _slot_attention(q, _gather_pages(kc, ptab),
+                            _gather_pages(vc, ptab), qpos, cfg)
         return _attn_out_and_mlp(x, o, layer, cfg), (kc, vc)
 
     x, (k_new, v_new) = jax.lax.scan(
@@ -885,30 +931,24 @@ def paged_decode_step(params, cache, tokens, ptab, pos, cfg: GPTConfig,
 
 def paged_prefill(params, cache, toks, ptab_row, start, last_idx,
                   cfg: GPTConfig, rope=None):
-    """Prefill one slot's pages: toks [T] (padded) starting at position
-    `start` (positions before `start` are prefix-shared pages already
-    holding valid K/V); logits at scanned index `last_idx`.  Returns
-    (logits [V], cache)."""
-    kc, vc = cache["k"], cache["v"]
-    ps = kc.shape[3]
+    """Prefill one sequence's pages: the padded chunk toks [T], starting
+    at position `start` (positions before `start` are prefix-shared
+    pages already holding valid K/V), goes through the layers in one
+    pass (_prefill_chunk); logits at chunk row `last_idx`.  A row's K/V
+    scatter into page ptab_row[p // ps] at offset p % ps: pad rows reach
+    this sequence's own later pages or, past its allocation and past
+    the end of the table, the null page 0 — never another sequence's.
+    Returns (logits [V], cache)."""
+    ps = cache["k"].shape[3]
     S = ptab_row.shape[0] * ps
-    T = toks.shape[0]
-    positions = jnp.minimum(start + jnp.arange(T, dtype=jnp.int32), S - 1)
-    if cfg.pos != "learned" and rope is None:
-        rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
 
-    def body(carry, inp):
-        kc, vc = carry
-        tok, p = inp
-        x, kc, vc = _paged_decode_hidden(params, kc, vc, tok[None],
-                                         ptab_row[None], p[None], cfg,
-                                         rope)
-        return (kc, vc), x[0]
+    def write(c, rows, wpos):                  # c [P, H, ps, dh]
+        pidx = ptab_row.at[wpos // ps].get(mode="fill", fill_value=0)
+        return c.at[pidx, :, wpos % ps, :].set(rows)
 
-    (kc, vc), xs = jax.lax.scan(body, (kc, vc), (toks, positions))
-    x = jax.lax.dynamic_index_in_dim(xs, last_idx, 0, keepdims=False)
-    logits = jnp.einsum("d,dv->v", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
+    logits, kc, vc = _prefill_chunk(
+        params, cache["k"], cache["v"], toks, start, last_idx, S, write,
+        lambda c: _gather_pages(c, ptab_row[None]), cfg, rope)
     return logits, {"k": kc, "v": vc}
 
 

@@ -9,6 +9,17 @@ PAPERS.md): a fixed-shape compiled step program runs over a batch of
 *every* decode step, so the step program never recompiles as traffic
 comes and goes.
 
+A request joins through a **prefill program** (models/gpt.py
+paged_prefill / slot_prefill): the prompt's own tokens — whatever the
+prefix table did not find in shared pages — padded to a multiple of
+`prefill_bucket`, go through the layers in ONE pass: per layer one
+chunk-wide QKV projection, one scatter of the chunk's K and V rows into
+the sequence's pages, and the same masked attention the decode step
+runs (a decode step is its one-row case).  The program is a function of
+the padded length alone; where the chunk starts, which row yields the
+logits and the page-table row are operands.  It runs on the engine
+thread inside `_admit`, so every streaming slot waits for it.
+
 Memory is a **paged arena** (models/gpt.py init_paged_cache): fixed-size
 pages in one preallocated device array, per-slot page tables gathered
 inside the decode step.  Pages are refcounted through a free list;
@@ -803,7 +814,8 @@ class ContinuousEngine:
         self._temps[slot] = seq.temperature
         self._topks[slot] = int(seq.top_k or 0)
 
-        # prefill the non-shared prompt suffix as one padded program
+        # prefill the non-shared prompt suffix: one padded program, one
+        # pass through the layers (pad rows cost matmul rows, not steps)
         count = plen - shared_len
         T = -(-count // self.prefill_bucket) * self.prefill_bucket
         seq.scanned = T

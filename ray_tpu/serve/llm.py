@@ -6,9 +6,11 @@ wiring it into Serve; here decoding is the framework's own jit program
 batching engine** over a **paged KV cache** (serve/_engine.py): one
 fixed-shape compiled step program over a slot batch, sequences joining
 at prefill and leaving at EOS/max-tokens at every decode step, pages
-refcounted with live prompt-prefix sharing and copy-on-write.  Both the
-request/response route and token streaming ride the same engine, so a
-short request never waits behind a long one.
+refcounted with live prompt-prefix sharing and copy-on-write.  A
+prompt is prefilled in one pass of its padded chunk through the layers
+(gpt.paged_prefill).  Both the request/response route and token
+streaming ride the same engine, so a short request never waits behind a
+long one.
 
 Engine selection (``RAY_TPU_SERVE_ENGINE`` or ``engine=`` at bind time):
 
