@@ -266,13 +266,11 @@ def _ways(mesh, entry) -> int:
     return math.prod(mesh.shape[a] for a in names)
 
 
-def _qkv_proj(x, layer, cfg: GPTConfig, rope, positions=None):
-    """Pre-norm + QKV projection + rope — the one source of truth shared
-    by the training forward and the KV-cache decode path (a recipe tweak
-    made in only one of them would silently break decode==forward
-    parity, which test_gpt_decode_matches_full_forward enforces)."""
-    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
-    h = h.astype(cfg.dtype)
+def _qkv_of_normed(h, layer, cfg):
+    """Q, K and V of an already normed input h [B, S, D]: [B, H, S, dh]
+    each (K and V with as many heads as `wk` / `wv` hold: fewer than Q
+    under grouped-query attention), biases where the config has them, no
+    position signal.  Shared by every block recipe of the family."""
     q = jnp.einsum("bsd,dhk->bhsk", h, layer["wq"].astype(cfg.dtype))
     k = jnp.einsum("bsd,dhk->bhsk", h, layer["wk"].astype(cfg.dtype))
     v = jnp.einsum("bsd,dhk->bhsk", h, layer["wv"].astype(cfg.dtype))
@@ -280,19 +278,34 @@ def _qkv_proj(x, layer, cfg: GPTConfig, rope, positions=None):
         q = q + layer["wq_b"].astype(cfg.dtype)[None, :, None]
         k = k + layer["wk_b"].astype(cfg.dtype)[None, :, None]
         v = v + layer["wv_b"].astype(cfg.dtype)[None, :, None]
+    return q, k, v
+
+
+def _qkv_proj(x, layer, cfg: GPTConfig, rope, positions=None):
+    """Pre-norm + QKV projection + rope — the one source of truth shared
+    by the training forward and the KV-cache decode path (a recipe tweak
+    made in only one of them would silently break decode==forward
+    parity, which test_gpt_decode_matches_full_forward enforces)."""
+    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
+    q, k, v = _qkv_of_normed(h.astype(cfg.dtype), layer, cfg)
     if rope is not None:
         q = apply_rope(q, *rope, positions=positions)
         k = apply_rope(k, *rope, positions=positions)
     return q, k, v
 
 
-def _attn_out_and_mlp(x, o, layer, cfg: GPTConfig):
-    """Output projection + residual + MLP sublayer (shared, see
-    _qkv_proj)."""
+def _attn_out(o, layer, cfg):
+    """Attention's output projection: o [B, H, S, dh] -> [B, S, D]."""
     att = jnp.einsum("bhsk,hkd->bsd", o, layer["wo"].astype(cfg.dtype))
     if cfg.attn_bias:
         att = att + layer["wo_b"].astype(cfg.dtype)
-    x = x + att
+    return att
+
+
+def _attn_out_and_mlp(x, o, layer, cfg: GPTConfig):
+    """Output projection + residual + MLP sublayer (shared, see
+    _qkv_proj)."""
+    x = x + _attn_out(o, layer, cfg)
     h2 = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm)
     h2 = h2.astype(cfg.dtype)
     if cfg.act == "swiglu":
@@ -865,11 +878,26 @@ def slot_prefill(params, cache, toks, start, last_idx, slot,
 # -- paged variant ----------------------------------------------------------
 
 
-def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int
+def cache_kinds(cfg: GPTConfig) -> Dict[str, Optional[int]]:
+    """The kinds of KV state this model's layers keep, as the serving
+    engine's page pools: name -> window (None: every position is kept).
+    Every layer here attends to the whole context, so there is one pool
+    and one page table a sequence.  The engine hands a model its page
+    counts and page tables keyed by these names; a one-kind model also
+    takes them bare (`_only`)."""
+    return {"full": None}
+
+
+def _only(x):
+    return x["full"] if isinstance(x, dict) else x
+
+
+def init_paged_cache(cfg: GPTConfig, num_pages, page_size: int
                      ) -> Dict[str, Any]:
     """Paged KV arena: [L, num_pages, H, page_size, d_head] per side.
     Page 0 is the reserved null page (inactive-slot writes land there;
     the allocator never hands it out)."""
+    num_pages = _only(num_pages)
     shape = (cfg.n_layers, num_pages, cfg.n_heads, page_size, cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
@@ -923,7 +951,8 @@ def paged_decode_step(params, cache, tokens, ptab, pos, cfg: GPTConfig,
                       rope=None):
     """Slot-batch decode on the paged cache: -> (logits [B, V], cache)."""
     x, k_new, v_new = _paged_decode_hidden(params, cache["k"], cache["v"],
-                                           tokens, ptab, pos, cfg, rope)
+                                           tokens, _only(ptab), pos, cfg,
+                                           rope)
     logits = jnp.einsum("bd,dv->bv", x.astype(cfg.dtype),
                         _unembed_table(params, cfg))
     return logits, {"k": k_new, "v": v_new}
@@ -939,6 +968,7 @@ def paged_prefill(params, cache, toks, ptab_row, start, last_idx,
     this sequence's own later pages or, past its allocation and past
     the end of the table, the null page 0 — never another sequence's.
     Returns (logits [V], cache)."""
+    ptab_row = _only(ptab_row)
     ps = cache["k"].shape[3]
     S = ptab_row.shape[0] * ps
 

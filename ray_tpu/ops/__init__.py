@@ -1,6 +1,8 @@
 from .attention import (attention, blockwise_attention, flash_attention,
-                        flash_attention_with_lse, mha_reference)
-from .layers import (apply_rope, fused_softmax_cross_entropy, gelu_mlp,
+                        flash_attention_with_lse, mha_reference,
+                        streamed_attention)
+from .layers import (apply_rope, apply_rope_interleaved,
+                     fused_softmax_cross_entropy, gelu_mlp,
                      layer_norm, rms_norm, rope_table,
                      softmax_cross_entropy, swiglu)
 from .quantize import (dequantize_blockwise, quantization_error,
@@ -11,9 +13,9 @@ from .ulysses import ulysses_attention, ulysses_attention_sharded
 __all__ = [
     "quantize_blockwise", "dequantize_blockwise", "quantization_error",
     "attention", "flash_attention", "flash_attention_with_lse",
-    "blockwise_attention", "mha_reference",
+    "blockwise_attention", "mha_reference", "streamed_attention",
     "ring_attention", "ring_attention_sharded",
     "ulysses_attention", "ulysses_attention_sharded",
-    "rms_norm", "layer_norm", "rope_table", "apply_rope", "swiglu",
+    "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_interleaved", "swiglu",
     "gelu_mlp", "softmax_cross_entropy", "fused_softmax_cross_entropy",
 ]

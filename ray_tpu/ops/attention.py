@@ -118,6 +118,47 @@ def blockwise_attention(q, k, v, causal: bool = False,
 # ---------------------------------------------------------------------------
 
 
+def streamed_attention(q, qpos, fetch, n_blocks, *, window=None):
+    """Causal attention over keys that arrive block by block, with an
+    online softmax: the serve path's recipe for caches too long to score
+    at once, grouped-query heads and a sliding window included.
+
+    q [B, Hkv, G, T, dh] (G query heads a K/V head) at positions qpos
+    [B, T]; `fetch(i)` gives block i's keys and values [B, Hkv, S, dh]
+    and their positions kpos [B, S] (negative: no key there).  Query t
+    sees key s iff 0 <= qpos - kpos (< window, where a window is given).
+    `n_blocks` may be traced: only blocks 0..n_blocks-1 are fetched.
+    A row that sees no key at all comes out zero.  Returns [B, Hkv, G, T,
+    dh] in q's dtype; scores and statistics are f32."""
+    B, Hkv, G, T, dh = q.shape
+    scale = dh ** -0.5
+
+    def body(i, carry):
+        m, l, acc = carry
+        k, v, kpos = fetch(i)
+        s = jnp.einsum("bhgtd,bhsd->bhgts", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        d = qpos[:, :, None] - kpos[:, None, :]             # [B, T, S]
+        ok = (d >= 0) & (kpos[:, None, :] >= 0)
+        if window is not None:
+            ok = ok & (d < window)
+        ok = ok[:, None, None]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(ok, s, DEFAULT_MASK_VALUE), axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bhgts,bhsd->bhgtd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    init = (jnp.full((B, Hkv, G, T), DEFAULT_MASK_VALUE, jnp.float32),
+            jnp.zeros((B, Hkv, G, T), jnp.float32),
+            jnp.zeros((B, Hkv, G, T, dh), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
 def _fit_block(block, seq):
     # shrink to a divisor so seq lengths like 768 (divisible by 256
     # but not the 512/1024 defaults) keep working — but never below

@@ -22,12 +22,14 @@ def rms_norm(x, weight, eps: float = 1e-6):
 
 
 def layer_norm(x, weight, bias, eps: float = 1e-5):
+    """LayerNorm in f32; `bias` None is the weight-only form."""
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
-    y = (x32 - mu) * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(x.dtype)
+    y = (x32 - mu) * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)
 
 
 def rope_table(seq_len: int, head_dim: int, base: float = 10000.0,
@@ -57,6 +59,21 @@ def apply_rope(x, cos, sin, positions: Optional[jnp.ndarray] = None):
     y1 = x1 * c - x2 * sn
     y2 = x2 * c + x1 * sn
     return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
+
+
+def apply_rope_interleaved(x, positions, theta: float = 10000.0):
+    """Rotary embedding over interleaved pairs (x[2i], x[2i+1]) — the
+    GPT-J convention — for x [B, H, T, D] at positions [B, T], every row
+    of the batch at its own positions.  Angles are computed in f32 from
+    the positions (no table: contexts run to hundreds of thousands)."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None, :, None] * freqs
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    x1, x2 = xp[..., 0], xp[..., 1]
+    y = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return y.reshape(x.shape).astype(x.dtype)
 
 
 def swiglu(x, w_gate, w_up, w_down):

@@ -133,3 +133,83 @@ def moe_ffn_sharded(x: jax.Array, router_w: jax.Array, w_in_local: jax.Array,
     aux = jax.lax.pmean(r.aux_loss, axis_name)
     z = jax.lax.pmean(r.z_loss, axis_name)
     return out, aux, z
+
+
+# ---------------------------------------------------------------------------
+# A chip's share of an expert layer (inference).  Under wide expert
+# parallelism a chip holds `count` of `n_experts` experts, routes over all
+# of them and computes what its own experts add for the token-expert pairs
+# that fall on them; what the absent experts would add is left out here and
+# summed by the exchange that a one-chip share runs without.  No capacity:
+# every pair on a held expert is computed (`route_topk`'s [T, E, C] dispatch
+# drops pairs past C and scores by softmax, so it is not used here).
+
+
+def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, k: int
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores over ALL experts in f32, top-k, weights normalised
+    over the chosen k.  h [N, D], router_w [D, E] -> (weights [N, k] f32,
+    expert ids [N, k] int32)."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    w, idx = jax.lax.top_k(s, k)
+    return w / jnp.sum(w, axis=-1, keepdims=True), idx.astype(jnp.int32)
+
+
+def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
+                    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                    *, first: int, tile: int = 512,
+                    live: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a routed SwiGLU layer, dropless.
+
+    h [N, D]; (weights, idx) [N, k] from the router over all experts;
+    w_gate / w_up [count, D, F], w_down [count, F, D] are experts
+    first..first+count-1.  The N*k pairs are sorted by held expert (pairs
+    on absent experts last) and go through grouped matrix products
+    (`jax.lax.ragged_dot`) `tile` sorted rows at a time, for as many tiles
+    as hold a pair of a held expert — a traced trip count, so a chunk whose
+    pairs mostly fall elsewhere costs what falls here.  `live` [N] bool
+    takes rows out of the routing (a slot batch's empty slots).
+    Returns (out [N, D] f32, loads [count] int32: pairs per held expert)."""
+    N, D = h.shape
+    k = idx.shape[1]
+    count = w_gate.shape[0]
+    local = idx - first
+    held = (local >= 0) & (local < count)
+    if live is not None:
+        held = held & live[:, None]
+    key = jnp.where(held, local, count).reshape(N * k)
+    order = jnp.argsort(key, stable=True)                  # held pairs first
+    loads = jnp.zeros(count + 1, jnp.int32).at[key].add(1)[:count]
+    ends = jnp.cumsum(loads)
+    starts = ends - loads
+    n_held = ends[-1]
+    M = min(int(tile), N * k)
+    n_tiles = -(-(N * k) // M)
+    pad = n_tiles * M - N * k
+    tok_of = jnp.pad(order // k, (0, pad))                 # sorted row -> token
+    w_of = jnp.pad(weights.reshape(N * k)[order], (0, pad))
+    dt = h.dtype
+
+    def one_tile(t, out):
+        lo = t * M
+        tok = jax.lax.dynamic_slice_in_dim(tok_of, lo, M)
+        wt = jax.lax.dynamic_slice_in_dim(w_of, lo, M)
+        gs = (jnp.clip(ends, lo, lo + M) - jnp.clip(starts, lo, lo + M))
+        x = h[tok]
+        g = jax.lax.ragged_dot(x, w_gate.astype(dt), gs,
+                               preferred_element_type=jnp.float32)
+        u = jax.lax.ragged_dot(x, w_up.astype(dt), gs,
+                               preferred_element_type=jnp.float32)
+        y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(dt),
+                               w_down.astype(dt), gs,
+                               preferred_element_type=jnp.float32)
+        valid = (lo + jnp.arange(M)) < n_held      # rows of no group: zero
+        y = jnp.where(valid[:, None], y * wt[:, None], 0.0)
+        return out.at[tok].add(y)
+
+    out = jax.lax.fori_loop(0, -(-n_held // M), one_tile,
+                            jnp.zeros((N, D), jnp.float32))
+    return out, loads

@@ -20,11 +20,31 @@ the padded length alone; where the chunk starts, which row yields the
 logits and the page-table row are operands.  It runs on the engine
 thread inside `_admit`, so every streaming slot waits for it.
 
+A prompt longer than `prefill_chunk` (0: off) is prefilled in chunks of
+that size through one program, the tail padded to `prefill_bucket`: one
+chunk an iteration, between two decode steps, while its slot rides the
+step as empty; nothing is admitted behind it until it decodes (first
+come, first served).  Prompts no longer than a chunk keep the schedule
+above.
+
+The engine serves whatever module implements its interface
+(`cache_kinds`, `init_paged_cache`, `paged_decode_step`, `paged_prefill`,
+`copy_page` where pages are shared; models/gpt.py, models/cohere2_moe.py).
+A model's layers may keep several **kinds of KV state**: `cache_kinds`
+names them with their window, and the engine keeps a pool of pages, an
+allocator and a page table per kind.  A full kind's pages are taken at
+admission, in sequence order.  A windowed kind's table is a ring as wide
+as the window plus the longest prefill program: pages are taken as the
+sequence grows, out of a reservation made at admission, and returned once
+every position in them is a window or more behind the next query.
+
 Memory is a **paged arena** (models/gpt.py init_paged_cache): fixed-size
 pages in one preallocated device array, per-slot page tables gathered
 inside the decode step.  Pages are refcounted through a free list;
 full prompt pages register in a prefix table so live sequences with a
-common prompt prefix share pages, with copy-on-write when a new
+common prompt prefix share pages (not for a model with a windowed kind:
+a shared prefix is not prefilled, and its window pages may be gone),
+with copy-on-write when a new
 sequence must write into a shared page (the exact-duplicate-prompt
 case: everything is shared but the last prompt position must be
 recomputed to produce logits).  Page 0 is the reserved null page —
@@ -156,10 +176,21 @@ class PageAllocator:
         self._refs: Dict[int, int] = {}
         self._prefix: Dict[Tuple[int, ...], int] = {}
         self._page_keys: Dict[int, List[Tuple[int, ...]]] = {}
+        # pages promised to admitted sequences that take them one at a
+        # time (a windowed pool): admission counts them as gone
+        self.reserved = 0
 
     @property
     def free_pages(self) -> int:
         return len(self._free)
+
+    @property
+    def available(self) -> int:
+        return len(self._free) - self.reserved
+
+    @property
+    def used_pages(self) -> int:
+        return len(self._refs)
 
     def alloc(self) -> int:
         if not self._free:
@@ -199,8 +230,8 @@ class PageAllocator:
     def lookup_prefix(self, tokens: Tuple[int, ...]) -> Optional[int]:
         return self._prefix.get(tokens)
 
-    def plan(self, tokens: List[int], n_pages_needed: int
-             ) -> Optional[Dict[str, Any]]:
+    def plan(self, tokens: List[int], n_pages_needed: int,
+             share: bool = True) -> Optional[Dict[str, Any]]:
         """Plan the page set for a prompt: walk the registry for fully
         shared leading pages (clamped so the LAST prompt position is
         always recomputed — it must produce logits), then check the free
@@ -209,12 +240,13 @@ class PageAllocator:
         returns {pages, shared_len, copies} with all refcounts taken —
         `copies` lists (src, dst) device page copies the caller must
         apply before prefill (copy-on-write out of a shared page).
+        `share=False` skips the registry (every page fresh).
         """
         ps = self.page_size
         plen = len(tokens)
         shared: List[int] = []
         i = 0
-        while (i + 1) * ps <= plen:
+        while share and (i + 1) * ps <= plen:
             page = self._prefix.get(tuple(tokens[:(i + 1) * ps]))
             if page is None:
                 break
@@ -226,7 +258,7 @@ class PageAllocator:
         if cow:                          # shared page must be re-written
             cow_src = shared.pop()
         n_fresh = n_pages_needed - len(shared)
-        if n_fresh > len(self._free):
+        if n_fresh > self.available:
             return None
         for p in shared:
             self.ref(p)
@@ -272,7 +304,8 @@ class _Sequence:
                  "pos", "generated", "keys", "t_submit", "t_admit",
                  "t_prefill", "t_ready", "t_first", "t_last", "shared",
                  "scanned", "trace_ctx", "peak", "stream", "request_id",
-                 "key_offset")
+                 "key_offset", "tabs", "win", "reserved", "next_start",
+                 "chunks", "prefill_s", "prefilling")
 
     def __init__(self, rid, tokens, max_new, temperature, top_k, seed,
                  eos_id, stream, request_id=None, key_offset=0):
@@ -307,6 +340,16 @@ class _Sequence:
         self.scanned = 0            # tokens the prefill scanned (bucket)
         self.trace_ctx = None       # submitter's sampled span context
         self.peak = 0               # max co-resident active slots seen
+        # paged state by kind of pool (cache_kinds): this sequence's page
+        # table rows; a windowed pool's live pages {logical page: page}
+        # and how many more it may still take (its reservation)
+        self.tabs: Dict[str, Any] = {}
+        self.win: Dict[str, Dict[int, int]] = {}
+        self.reserved: Dict[str, int] = {}
+        self.next_start = 0         # first prompt position not prefilled
+        self.chunks = 0             # prefill programs run for it
+        self.prefill_s = 0.0        # their seconds, dispatch -> ready
+        self.prefilling = False     # holds a slot, not yet decoding
 
 
 class ContinuousEngine:
@@ -316,9 +359,10 @@ class ContinuousEngine:
 
     def __init__(self, gpt_mod, cfg, params, *, cache: str = "paged",
                  max_slots: int = 8, page_size: int = 16,
-                 num_pages: int = 0, max_total: int = 0,
+                 num_pages=0, max_total: int = 0,
                  queue_cap: int = 32, shed_queue_depth: int = 16,
                  retry_after_s: float = 1.0, prefill_bucket: int = 32,
+                 prefill_chunk: int = 0,
                  ring_size: int = 256, stall_s: float = 10.0):
         import jax
         import numpy as np
@@ -333,18 +377,60 @@ class ContinuousEngine:
         self.max_total = int(max_total) or cfg.max_seq
         self.max_pages_per_seq = -(-self.max_total // self.page_size)
         self.max_total = self.max_pages_per_seq * self.page_size
-        self.num_pages = (int(num_pages)
-                          or 1 + self.max_slots * self.max_pages_per_seq)
         self.queue_cap = int(queue_cap)
         self.shed_queue_depth = int(shed_queue_depth)
         self.retry_after_s = float(retry_after_s)
         self.prefill_bucket = int(prefill_bucket)
+        # a prompt longer than this is prefilled in chunks of this size
+        # (the tail padded to prefill_bucket), one chunk an iteration;
+        # 0: every prompt is one program of its padded length
+        self.prefill_chunk = int(prefill_chunk)
+
+        # one page pool per kind of KV state the model's layers keep
+        # (gpt_mod.cache_kinds: name -> window, None = every position).
+        # A full kind's table is the sequence's pages in order, taken at
+        # admission; a windowed kind's is a ring as wide as the window
+        # plus the longest prefill program, filled as the sequence grows
+        # and emptied as the window passes.
+        self._kinds: Dict[str, Optional[int]] = (
+            dict(gpt_mod.cache_kinds(cfg)) if cache == "paged" else {})
+        longest = self.prefill_chunk or self.max_total
+        self._widths = {
+            k: self.max_pages_per_seq if w is None else min(
+                self.max_pages_per_seq,
+                -(-(w + longest) // self.page_size) + 1)
+            for k, w in self._kinds.items()}
+        self._pool_pages = {
+            k: int(num_pages[k] if isinstance(num_pages, dict)
+                   else num_pages) or 1 + self.max_slots * self._widths[k]
+            for k in self._kinds}
+        # the pool the scheduler's public numbers describe: the first
+        # kind that keeps every position (the only one, for most models)
+        self._main = next((k for k, w in self._kinds.items() if w is None),
+                          next(iter(self._kinds), None))
+        self.num_pages = (self._pool_pages[self._main] if self._kinds else
+                          int(num_pages)
+                          or 1 + self.max_slots * self.max_pages_per_seq)
+        # live prefix sharing skips a shared prefix's prefill, so it needs
+        # every layer's K/V of that prefix to be kept: with a windowed
+        # kind nothing is shared (the window's pages may be gone)
+        self._windowed = [k for k, w in self._kinds.items() if w is not None]
+        self._share = not self._windowed
 
         self._lock = threading.Lock()
         self._waiting: "deque[_Sequence]" = deque()   # guarded-by: _lock
         self._slots: List[Optional[_Sequence]] = [None] * self.max_slots
-        self._alloc = (PageAllocator(self.num_pages, self.page_size)
-                       if cache == "paged" else None)
+        self._allocs = {k: PageAllocator(n, self.page_size)
+                        for k, n in self._pool_pages.items()}
+        self._alloc = self._allocs.get(self._main)
+        self._prefilling: Optional[_Sequence] = None  # mid-prompt, FCFS
+        # a model's serve programs may return counters of their own (its
+        # module's STEP_STATS names them): a step's go into the
+        # iteration's record under these names, a prefill chunk's under
+        # `chunk_<name>`
+        self._stat_names = tuple(getattr(gpt_mod, "STEP_STATS", ()))
+        self._stat_keys = self._stat_names + tuple(
+            "chunk_" + n for n in self._stat_names)
         self._fns: Dict[Any, Any] = {}   # bounded by construction: one
         # step program + one prefill per padded-length bucket + setrow +
         # copy_page — not the LRU _gen_cache (evicting the step program
@@ -365,6 +451,9 @@ class ContinuousEngine:
         B, maxp = self.max_slots, self.max_pages_per_seq
         self._pos = np.zeros(B, np.int32)
         self._ptab = np.zeros((B, maxp), np.int32)
+        self._ptabs = {k: (self._ptab if k == self._main else
+                           np.zeros((B, w), np.int32))
+                       for k, w in self._widths.items()}
         self._toks_keys = np.zeros((B, 2), np.uint32)
         self._temps = np.zeros(B, np.float32)
         self._topks = np.zeros(B, np.int32)
@@ -375,7 +464,8 @@ class ContinuousEngine:
         self._t_window: "deque[Tuple[float, int]]" = deque(maxlen=512)  # guarded-by: _lock
         self._totals = {"requests": 0, "rejected": 0, "tokens": 0,
                         "steps": 0, "prefills": 0, "cow_copies": 0,
-                        "shared_pages": 0,
+                        "shared_pages": 0, "chunks": 0,
+                        "window_pages_returned": 0,
                         # cumulative sums of the ring's records, so two
                         # engine_stats() snapshots give shares over any
                         # interval whatever ring_size forgot
@@ -388,6 +478,10 @@ class ContinuousEngine:
         self._wait_s = 0.0           # blocked on the device
         self._blocked = 0            # streaming slots an admission held up
         self._first: List[_Sequence] = []   # first token this iteration
+        self._chunks = 0             # prefill programs run
+        self._chunk_tokens = 0       # prompt tokens they computed
+        self._returned = 0           # window pages returned
+        self._stats: Dict[str, float] = {}  # the programs' own counters
 
         # device-memory census: report this engine's page-arena
         # occupancy under a per-instance tag (unregistered in stop())
@@ -419,12 +513,13 @@ class ContinuousEngine:
                 f"prompt ({plen}) + max_new_tokens ({max_new}) exceeds "
                 f"engine capacity ({self.max_total})")
         need = -(-min(plen + max_new, self.max_total) // self.page_size)
-        if self._alloc is not None and need > self.num_pages - 1:
-            # can never fit even with the arena idle — reject now rather
-            # than park it at the head of the queue forever
-            raise ValueError(
-                f"request needs {need} pages but the arena only has "
-                f"{self.num_pages - 1}")
+        for k, n in self._pool_pages.items():
+            if min(need, self._widths[k]) > n - 1:
+                # can never fit even with the arena idle — reject now
+                # rather than park it at the head of the queue forever
+                raise ValueError(
+                    f"request needs {min(need, self._widths[k])} pages but "
+                    f"the arena only has {n - 1}")
         if (self._cfg.pos == "learned"
                 and plen + max_new > self._cfg.max_seq):
             raise ValueError(
@@ -534,6 +629,8 @@ class ContinuousEngine:
                 "live_shared": occ["live_shared"],
             }
             rep["prefix_keys"] = occ["prefix_keys"]
+            rep["pools"] = {k: a.occupancy()
+                            for k, a in self._allocs.items()}
         return rep
 
     def phase_ring(self) -> List[Dict[str, float]]:
@@ -592,8 +689,7 @@ class ContinuousEngine:
             raise RuntimeError(
                 f"engine stalled: {active} active slots but no decode "
                 f"step for {now - snap[1]:.1f}s (> {self.stall_s:g}s)")
-        if self._alloc is not None:
-            a = self._alloc
+        for a in self._allocs.values():
             in_use = len(a._refs)
             if len(a._free) + in_use != a.num_pages - 1:
                 raise RuntimeError(
@@ -623,10 +719,10 @@ class ContinuousEngine:
             active = [s for s in self._slots if s is not None]
             self._slots = [None] * self.max_slots
         self._pos[:] = 0
-        self._ptab[:] = 0
-        if self._alloc is not None:
-            for s in active:
-                self._alloc.release(s.pages)
+        for tab in self._ptabs.values():
+            tab[:] = 0
+        for s in active:
+            self._release(s)
         err = RuntimeError("engine stopped")
         # in-slot sequences must resolve too: a stream consumer blocked
         # on out_q and a request/response caller blocked on the future
@@ -662,11 +758,11 @@ class ContinuousEngine:
                     self._waiting.clear()
                     self._slots = [None] * self.max_slots
                     self._pos[:] = 0
-                    self._ptab[:] = 0
-                if self._alloc is not None:
-                    for s in seqs:
-                        self._alloc.release(s.pages)
+                    for tab in self._ptabs.values():
+                        tab[:] = 0
+                self._prefilling = None
                 for s in seqs:
+                    self._release(s)
                     self._finish(s, error=e)
 
     def _iteration(self):
@@ -681,25 +777,35 @@ class ContinuousEngine:
         self._wait_s = 0.0
         self._blocked = 0
         self._first = []
+        self._chunks = self._chunk_tokens = self._returned = 0
+        self._stats = dict.fromkeys(self._stat_keys, 0.0)
         with ann("serve.engine.admit"):
             admitted = self._admit()
         t1 = time.perf_counter()
         stepped = 0
-        if any(s is not None for s in self._slots):
+        if any(s is not None and not s.prefilling for s in self._slots):
             stepped = self._step()
         t2 = time.perf_counter()
         with ann("serve.engine.account"):
-            rec = {"swap_s": (t1 - t0) if admitted else 0.0,
-                   "prefill_s": self._last_prefill_s if admitted else 0.0,
+            # a chunk of a prompt already admitted is admission work too
+            worked = admitted or self._chunks
+            rec = {"swap_s": (t1 - t0) if worked else 0.0,
+                   "prefill_s": self._last_prefill_s if worked else 0.0,
                    "decode_s": (t2 - t1) if stepped else 0.0,
                    "active": stepped, "admitted": admitted, "ts": t2,
                    "t0": t0, "device_wait_s": self._wait_s,
                    "blocked_slots": self._blocked,
+                   "chunks": self._chunks,
+                   "chunk_tokens": self._chunk_tokens,
+                   "pages_returned": self._returned,
+                   **{"pages_" + k: a.used_pages
+                      for k, a in self._allocs.items()},
+                   **self._stats,
                    "requests": [self._request_record(s)
                                 for s in self._first]}
             m = _m_phase()
             if m:
-                if admitted:
+                if worked:
                     m.observe(max(0.0, rec["swap_s"] - rec["prefill_s"]),
                               tags={"phase": "swap"})
                     m.observe(rec["prefill_s"], tags={"phase": "prefill"})
@@ -715,7 +821,8 @@ class ContinuousEngine:
                 if g:
                     g.set(val)
             # the record closes here: only its append comes after
-            rec["host_s"] = time.perf_counter() - t0 - self._wait_s
+            rec["iter_s"] = time.perf_counter() - t0
+            rec["host_s"] = rec["iter_s"] - self._wait_s
             tot = self._totals
             with self._lock:
                 self._ring.append(rec)
@@ -732,11 +839,14 @@ class ContinuousEngine:
     @staticmethod
     def _request_record(s: _Sequence) -> Dict[str, Any]:
         """The TTFT of one request by parts, as the ring keeps it: the
-        three parts sum to `ttft_s` up to the bookkeeping between
+        four parts sum to `ttft_s` up to the bookkeeping between
         admission and the prefill's dispatch (page table, operands)."""
         return {"rid": s.rid, "request_id": s.request_id,
                 "queue_wait_s": s.t_admit - s.t_submit,
-                "prefill_s": s.t_ready - s.t_prefill,
+                # its own prefill programs; what lay between a chunked
+                # prompt's chunks (the others' decode steps) is apart
+                "prefill_s": s.prefill_s, "chunks": s.chunks,
+                "chunk_wait_s": s.t_ready - s.t_prefill - s.prefill_s,
                 "first_step_wait_s": s.t_first - s.t_ready,
                 "ttft_s": s.t_first - s.t_submit,
                 "prompt_tokens": len(s.tokens),
@@ -752,10 +862,17 @@ class ContinuousEngine:
         """Admit waiting sequences into free slots while pages last —
         FIFO (a too-big head request waits for evictions rather than
         being overtaken; admission-order fairness beats packing here).
+        A prompt longer than `prefill_chunk` takes one chunk now and one
+        an iteration from then on, and nothing is admitted behind it
+        until it decodes (first come, first served).
         """
         self._last_prefill_s = 0.0
+        if self._prefilling is not None:
+            self._blocked = self._streaming()
+            self._prefill_next(self._prefilling)
+            return 0
         admitted = 0
-        while True:
+        while self._prefilling is None:
             with self._lock:
                 if not self._waiting:
                     break
@@ -764,18 +881,13 @@ class ContinuousEngine:
                 except ValueError:
                     break
                 seq = self._waiting[0]
-                plan = None
-                if self._alloc is not None:
-                    plan = self._alloc.plan(seq.tokens,
-                                            self._pages_needed(seq))
-                    if plan is None:
-                        break               # page-starved: wait for evicts
+                plan = self._plan_pages(seq)
+                if plan is None and self._allocs:
+                    break                   # page-starved: wait for evicts
                 if not admitted:
                     # streams this round's prefills stall: slots whose
                     # sequence has already put a token out
-                    self._blocked = sum(
-                        1 for s in self._slots
-                        if s is not None and s.t_first is not None)
+                    self._blocked = self._streaming()
                 self._waiting.popleft()
                 self._slots[slot] = seq
             seq.t_admit = time.perf_counter()
@@ -788,55 +900,112 @@ class ContinuousEngine:
                     s.peak = max(s.peak, n)
         return admitted
 
+    def _streaming(self) -> int:
+        return sum(1 for s in self._slots
+                   if s is not None and s.t_first is not None)
+
+    def _plan_pages(self, seq: _Sequence) -> Optional[Dict[str, Any]]:
+        """Pages of every pool for `seq`, or None while some pool cannot
+        take it.  A full kind's pages are taken here (allocator.plan, with
+        its prefix sharing where the model allows it); a windowed kind
+        only reserves the most it will hold at once."""
+        if not self._allocs:
+            return None
+        need = self._pages_needed(seq)
+        windowed = {k: min(need, self._widths[k]) for k in self._windowed}
+        if any(self._allocs[k].available < n for k, n in windowed.items()):
+            return None
+        plan = {"pages": [], "shared_len": 0, "copies": [], "n_shared": 0}
+        if self._main not in windowed:
+            plan = self._alloc.plan(seq.tokens, need, share=self._share)
+            if plan is None:
+                return None
+        for k, n in windowed.items():
+            self._allocs[k].reserved += n
+            seq.reserved[k] = n
+        return plan
+
     def _admit_one(self, seq: _Sequence, slot: int, plan):
-        jax, np = self._jax, self._np
+        np = self._np
         self._ensure_device_state()
         plen = len(seq.tokens)
+        shared_len = 0
         if plan is not None:
             seq.pages = plan["pages"]
             shared_len = plan["shared_len"]
-            row = np.zeros(self.max_pages_per_seq, np.int32)
-            row[:len(seq.pages)] = seq.pages
-            self._ptab[slot] = row
+            for k, w in self._widths.items():
+                seq.tabs[k] = np.zeros(w, np.int32)
+                seq.win[k] = {}
+            seq.tabs[self._main][:len(seq.pages)] = seq.pages
             self._totals["cow_copies"] += len(plan["copies"])
             self._totals["shared_pages"] += plan["n_shared"]
             for src, dst in plan["copies"]:
                 self._cache = self._fn("copy_page")(self._cache,
                                                     np.int32(dst),
                                                     np.int32(src))
-        else:
-            shared_len = 0
         seq.slot = slot
         seq.pos = plen
-        seq.shared = shared_len
-        ann = jax.profiler.TraceAnnotation
-        self._pos[slot] = plen                  # first decode write pos
+        seq.shared = seq.next_start = shared_len
         self._temps[slot] = seq.temperature
         self._topks[slot] = int(seq.top_k or 0)
+        self._prefill_next(seq)
 
-        # prefill the non-shared prompt suffix: one padded program, one
-        # pass through the layers (pad rows cost matmul rows, not steps)
-        count = plen - shared_len
-        T = -(-count // self.prefill_bucket) * self.prefill_bucket
-        seq.scanned = T
+    def _prefill_next(self, seq: _Sequence):
+        """The next prefill program of `seq`: the rest of its prompt
+        padded to a multiple of `prefill_bucket` (pad rows cost matmul
+        rows, not steps), or one `prefill_chunk` of it.  After the last
+        one the sequence's tables and position enter the step's operands
+        and it decodes; until then its slot rides the step as empty."""
+        jax, np = self._jax, self._np
+        ann = jax.profiler.TraceAnnotation
+        plen, start, slot = len(seq.tokens), seq.next_start, seq.slot
+        n = plen - start
+        if self.prefill_chunk and n > self.prefill_chunk:
+            n = self.prefill_chunk
+        T = -(-n // self.prefill_bucket) * self.prefill_bucket
+        last = start + n == plen
+        self._grow_windows(seq, start, start + n)
         chunk = np.zeros(T, np.int32)
-        chunk[:count] = seq.tokens[shared_len:]
+        chunk[:n] = seq.tokens[start:start + n]
         with ann("serve.engine.prefill", request_id=seq.request_id or "",
-                 tokens=count, bucket=T):
-            seq.t_prefill = time.perf_counter()
-            if self._alloc is not None:
-                logits, self._cache = self._fn(("prefill", T))(
-                    self._params, self._cache, chunk, self._ptab[slot],
-                    np.int32(shared_len), np.int32(count - 1))
+                 tokens=n, bucket=T):
+            t0 = time.perf_counter()
+            if not seq.chunks:
+                seq.t_prefill = t0
+            if self._allocs:
+                logits, self._cache, stats = self._fn(("prefill", T))(
+                    self._params, self._cache, chunk, seq.tabs,
+                    np.int32(start), np.int32(n - 1))
             else:
-                logits, self._cache = self._fn(("prefill", T))(
-                    self._params, self._cache, chunk, np.int32(shared_len),
-                    np.int32(count - 1), np.int32(slot))
-            with ann("serve.engine.setrow"):
-                self._logits = self._fn("setrow")(self._logits, logits,
-                                                  np.int32(slot))
-            jax.block_until_ready(self._logits)
-            seq.t_ready = time.perf_counter()
+                logits, self._cache, stats = self._fn(("prefill", T))(
+                    self._params, self._cache, chunk, np.int32(start),
+                    np.int32(n - 1), np.int32(slot))
+            if last:
+                with ann("serve.engine.setrow"):
+                    self._logits = self._fn("setrow")(self._logits, logits,
+                                                      np.int32(slot))
+                logits = self._logits
+            jax.block_until_ready(logits)
+            t1 = time.perf_counter()
+        self._note_stats(stats, "chunk_")
+        seq.prefill_s += t1 - t0
+        seq.scanned += T
+        seq.chunks += 1
+        seq.next_start = start + n
+        self._chunks += 1
+        self._chunk_tokens += n
+        self._last_prefill_s += t1 - t0
+        self._wait_s += t1 - t0
+        self._totals["chunks"] += 1
+        self._shrink_windows(seq, seq.next_start)
+        if not last:
+            seq.prefilling, self._prefilling = True, seq
+            return
+        seq.prefilling, self._prefilling = False, None
+        seq.t_ready = t1
+        for k, tab in self._ptabs.items():
+            tab[slot] = seq.tabs[k]
+        self._pos[slot] = plen                  # first decode write pos
         # key_offset: a resumed continuation (router replay) re-derives
         # the ORIGINAL request's key schedule and skips the keys its
         # already-delivered tokens consumed — sampled decode stays
@@ -848,16 +1017,75 @@ class ContinuousEngine:
                 jax.random.PRNGKey(seq.seed),
                 seq.key_offset + seq.max_new))[seq.key_offset:]
             tk = time.perf_counter()
-        self._last_prefill_s += seq.t_ready - seq.t_prefill
-        self._wait_s += tk - seq.t_prefill
+        self._wait_s += tk - t1
         self._totals["prefills"] += 1
 
         # register this prompt's full pages for live prefix sharing
-        if self._alloc is not None:
+        if self._share and self._alloc is not None:
             for i in range(plen // self.page_size):
                 self._alloc.register_prefix(
                     tuple(seq.tokens[:(i + 1) * self.page_size]),
                     seq.pages[i])
+
+    def _note_stats(self, stats, prefix: str = ""):
+        """The model's own counters of one program (`STEP_STATS` of its
+        module: names of the f32 vector its serve programs return),
+        summed into this iteration's record."""
+        if stats:
+            for k, v in zip(self._stat_names, self._np.asarray(stats[0])):
+                self._stats[prefix + k] += float(v)
+
+    # -- windowed pools -----------------------------------------------------
+
+    def _set_entry(self, seq: _Sequence, kind: str, entry: int, page: int):
+        seq.tabs[kind][entry] = page
+        if seq.t_ready:             # decoding: its rows are the step's
+            self._ptabs[kind][seq.slot, entry] = page
+
+    def _grow_windows(self, seq: _Sequence, lo: int, hi: int):
+        """Pages for positions lo..hi-1 in every windowed pool, out of
+        the sequence's reservation: logical page lp sits in ring entry
+        lp % width."""
+        ps = self.page_size
+        for k in self._windowed:
+            a, live = self._allocs[k], seq.win[k]
+            for lp in range(lo // ps, (hi - 1) // ps + 1):
+                if lp in live:
+                    continue
+                a.reserved -= 1
+                seq.reserved[k] -= 1
+                live[lp] = a.alloc()
+                self._set_entry(seq, k, lp % self._widths[k], live[lp])
+
+    def _shrink_windows(self, seq: _Sequence, next_pos: int):
+        """Return the pages no query at or after `next_pos` can see: those
+        whose every position is a window or more behind it."""
+        ps = self.page_size
+        for k in self._windowed:
+            a, live, w = self._allocs[k], seq.win[k], self._kinds[k]
+            for lp in [lp for lp in live if (lp + 1) * ps - 1 <= next_pos - w]:
+                page = live.pop(lp)
+                a.unref(page)
+                a.reserved += 1
+                seq.reserved[k] += 1
+                if seq.tabs[k][lp % self._widths[k]] == page:
+                    self._set_entry(seq, k, lp % self._widths[k], 0)
+                self._returned += 1
+                self._totals["window_pages_returned"] += 1
+
+    def _release(self, seq: _Sequence):
+        """Everything `seq` holds or has reserved goes back to its pools
+        (once: the sequence is left holding nothing)."""
+        if self._alloc is not None:
+            self._alloc.release(seq.pages)
+        seq.pages = []
+        for k, live in seq.win.items():
+            a = self._allocs[k]
+            a.release(list(live.values()))
+            live.clear()
+            a.reserved -= seq.reserved.pop(k, 0)
+        if self._prefilling is seq:
+            self._prefilling = None
 
     # -- decode -------------------------------------------------------------
 
@@ -868,15 +1096,19 @@ class ContinuousEngine:
         np, ann = self._np, self._jax.profiler.TraceAnnotation
         with ann("serve.engine.step"):
             active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None]
+                      if s is not None and not s.prefilling]
             for i, s in active:
                 self._toks_keys[i] = s.keys[len(s.generated)]
+                self._grow_windows(s, int(self._pos[i]),
+                                   int(self._pos[i]) + 1)
             td = time.perf_counter()
-            toks, self._logits, self._cache = self._fn("step")(
+            toks, self._logits, self._cache, stats = self._fn("step")(
                 self._params, self._cache, self._logits, self._toks_keys,
-                self._temps, self._topks, self._ptab, self._pos)
+                self._temps, self._topks,
+                self._ptabs if self._allocs else self._ptab, self._pos)
         with ann("serve.engine.fetch"):
             toks = np.asarray(toks)
+            self._note_stats(stats)
             now = time.perf_counter()
         self._wait_s += now - td
         with ann("serve.engine.emit"):
@@ -899,6 +1131,7 @@ class ContinuousEngine:
                         m.observe(ttft)
                 s.out_q.put(tok)
                 self._pos[i] += 1
+                self._shrink_windows(s, int(self._pos[i]))
                 if (len(s.generated) >= s.max_new
                         or (s.eos_id is not None and tok == s.eos_id)):
                     finished.append((i, s))
@@ -916,11 +1149,11 @@ class ContinuousEngine:
         with self._lock:
             self._slots[slot] = None
         self._pos[slot] = 0
-        self._ptab[slot] = 0
+        for tab in self._ptabs.values():
+            tab[slot] = 0
         self._temps[slot] = 0.0
         self._topks[slot] = 0
-        if self._alloc is not None:
-            self._alloc.release(seq.pages)
+        self._release(seq)
         self._finish(seq)
         self._wake.set()          # page/slot freed: retry page-starved head
 
@@ -971,7 +1204,7 @@ class ContinuousEngine:
         jnp = self._jax.numpy
         if self.cache_mode == "paged":
             self._cache = self._gpt.init_paged_cache(
-                self._cfg, self.num_pages, self.page_size)
+                self._cfg, self._pool_pages, self.page_size)
         else:
             self._cache = self._gpt.init_slot_cache(
                 self._cfg, self.max_slots, self.max_total)
@@ -1020,16 +1253,19 @@ class ContinuousEngine:
                 def serve_step(params, cache, logits, keys, temps, topks,
                                ptab, pos):
                     toks = sample(logits, keys, temps, topks)
-                    new_logits, cache = gpt.paged_decode_step(
+                    # a model may return its own counters third (the
+                    # vector its module's STEP_STATS names)
+                    new_logits, cache, *stats = gpt.paged_decode_step(
                         params, cache, toks, ptab, pos, cfg)
-                    return toks, new_logits.astype(jnp.float32), cache
+                    return (toks, new_logits.astype(jnp.float32), cache,
+                            tuple(stats))
             else:
                 def serve_step(params, cache, logits, keys, temps, topks,
                                ptab, pos):
                     toks = sample(logits, keys, temps, topks)
                     new_logits, cache = gpt.slot_decode_step(
                         params, cache, toks, pos, cfg)
-                    return toks, new_logits.astype(jnp.float32), cache
+                    return toks, new_logits.astype(jnp.float32), cache, ()
 
             fn = self._fns[key] = devtel.instrument(
                 jax.jit(serve_step), name="serve.step")
@@ -1052,7 +1288,9 @@ class ContinuousEngine:
             prefill = gpt.paged_prefill if paged else gpt.slot_prefill
 
             def serve_prefill(params, cache, toks, *operands):
-                return prefill(params, cache, toks, *operands, cfg=cfg)
+                logits, cache, *stats = prefill(params, cache, toks,
+                                                *operands, cfg=cfg)
+                return logits, cache, tuple(stats)
 
             fn = self._fns[key] = devtel.instrument(
                 jax.jit(serve_prefill), name=f"serve.prefill:{key[1]}")

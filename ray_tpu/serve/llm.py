@@ -8,7 +8,9 @@ fixed-shape compiled step program over a slot batch, sequences joining
 at prefill and leaving at EOS/max-tokens at every decode step, pages
 refcounted with live prompt-prefix sharing and copy-on-write.  A
 prompt is prefilled in one pass of its padded chunk through the layers
-(gpt.paged_prefill).  Both the request/response route and token
+(gpt.paged_prefill), a long one in chunks of `prefill_chunk` tokens
+between decode steps.  A loaded config is served by its own module
+(models/gpt.py, models/cohere2_moe.py: the engine's interface).  Both the request/response route and token
 streaming ride the same engine, so a short request never waits behind a
 long one.
 
@@ -32,6 +34,7 @@ out of scope (bring your own; nothing here depends on one).
 from __future__ import annotations
 
 import functools
+import sys
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional
 
@@ -85,6 +88,12 @@ class _LLMServerImpl:
             self._params = loaded
         else:
             self._params = gpt.init(jax.random.PRNGKey(0), self._cfg)
+        # a loaded config is served by its own module where that module
+        # implements the engine's interface (models/cohere2_moe.py beside
+        # models/gpt.py): which model runs is the loader's data
+        mod = sys.modules.get(type(self._cfg).__module__)
+        if hasattr(mod, "paged_decode_step"):
+            self._gpt = mod
         self._jax = jax
         # per-instance (NOT lru_cache on the method: a class-level cache
         # keyed by self would pin replaced replicas' full weights), and

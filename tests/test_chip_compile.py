@@ -306,3 +306,65 @@ def test_gpt2_small_train_step_compiles_with_kernel(topo, axes, batch):
     assert _kernel_calls(compiled) >= 3      # fwd + dq + dk/dv, per layer scan
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+# -- the second served model at its published widths: Command A+'s share of
+# -- benchmarks/configs/command-a-plus-l4-e16.json ---------------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512)],
+                         ids=["step", "prefill512"])
+def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key):
+    """The mixed-pool serve programs (grouped expert products, streamed
+    attention over two page pools) at the benchmark configuration's sizes:
+    the chip's compiler takes them, and weights + both arenas (held twice:
+    the programs do not donate them) + temporaries stay under the chip's
+    15.75 GB."""
+    import json
+
+    from benchmarks.lib.cohere2cfg import model_config
+    from ray_tpu.models import cohere2_moe as cm
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "command-a-plus-l4-e16.json")) as f:
+        conf = json.load(f)
+    cfg = model_config(conf)
+    eng = ContinuousEngine(cm, cfg, None, **conf["serve"]["engine_kwargs"])
+    try:
+        params = _on(jax.eval_shape(lambda k: {
+            "embed": jnp.zeros((cfg.vocab_size, cfg.d_model),
+                               cfg.param_dtype),
+            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
+            "layers": [cm.init_layer(k, cfg) for _ in cfg.layer_types]},
+            jax.random.PRNGKey(0)), one_chip)
+        cache = _on(jax.eval_shape(functools.partial(
+            cm.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B, V = eng.max_slots, cfg.vocab_size
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (params, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32),
+                    {k: s((B, w), jnp.int32)
+                     for k, w in eng._widths.items()}, s((B,), jnp.int32))
+        else:
+            args = (params, cache, s((key[1],), jnp.int32),
+                    {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
+                    i32, i32)
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert eng._widths == {"full": 128, "sliding": 37}
+    assert 9.4e9 < sum(a.size * a.dtype.itemsize
+                       for a in jax.tree.leaves(params)) < 9.6e9
+    assert total < 15.75 * 1024 ** 3, total
+    # the grouped products are the chip's own kernel, not a dense fallback,
+    # for a chunk's rows and a step's alike
+    assert "ragged-dot" in compiled.as_text()
