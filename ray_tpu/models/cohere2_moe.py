@@ -268,7 +268,9 @@ def init_paged_cache(cfg: Cohere2MoEConfig, num_pages: Dict[str, int],
     (a position's K/V heads lie together: that is the order the chip's
     compiler wants for the row scatter and the page gather, and given any
     other it copies the whole arena into this one and back, every
-    program); page 0 of every pool is the null page."""
+    program); page 0 of every pool is the null page.  The serve programs
+    are given the arenas to keep (the engine donates them) and each
+    layer's scatter writes its rows where the arena stands."""
     def arena(kind):
         shape = (num_pages[kind], page_size, cfg.n_kv_heads, cfg.d_head)
         return {"k": jnp.zeros(shape, cfg.dtype),

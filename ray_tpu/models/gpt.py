@@ -701,7 +701,7 @@ def _decode_hidden_fast(view, cfg: GPTConfig, kcache, vcache, pos, toks):
 #
 #   * contiguous slot cache [L, B, H, S, dh] — one row per slot (kept for
 #     bitwise parity tests against the paged path);
-#   * paged cache: a device arena of fixed-size pages [L, P, H, ps, dh]
+#   * paged cache: a device arena of fixed-size pages [L, P, ps, H * dh]
 #     plus per-slot page tables gathered inside the decode step.  Page 0
 #     is reserved as the null page: inactive slots write there and their
 #     outputs are discarded host-side, so the compiled step program
@@ -711,6 +711,14 @@ def _decode_hidden_fast(view, cfg: GPTConfig, kcache, vcache, pos, toks):
 # paged_prefill): its padded prompt chunk in ONE pass through the layers
 # (_prefill_chunk), T query rows through the _slot_attention that a
 # decode step feeds one row a slot.
+#
+# Every program takes the cache of ALL layers and hands it back: it is
+# the layer loop's carry, a layer scatters its new rows at [l, ...] and
+# reads its own slab at [l], and nothing else of it is touched.  The
+# serve engine donates the cache to each of these programs, so the rows
+# are written into the buffer it came in.  (Not a scan's xs -> ys: that
+# slices each layer's slab out and stacks it back, and the whole cache
+# moves to write a few rows.)
 
 
 def _slot_rope(x, cos, sin, positions):
@@ -730,6 +738,12 @@ def _slot_embed(params, tokens, pos, cfg: GPTConfig):
     if cfg.pos == "learned":
         x = x + params["pos_embed"][pos].astype(cfg.dtype)  # per-slot rows
     return x
+
+
+def _numbered(layers, cfg: GPTConfig):
+    """The stacked layers as a scan's xs, each with its index into the
+    stacked cache."""
+    return layers, jnp.arange(cfg.n_layers, dtype=jnp.int32)
 
 
 def _slot_qkv(x, layer, cfg: GPTConfig, rope, pos):
@@ -784,16 +798,18 @@ def _slot_decode_hidden(params, kcache, vcache, tokens, pos, cfg: GPTConfig,
     x = _slot_embed(params, tokens[:, None], qpos, cfg)     # [B, 1, D]
     bidx = jnp.arange(B)
 
-    def block(x, inp):
-        layer, kc, vc = inp                    # kc/vc [B, H, S, dh]
+    def block(carry, inp):
+        x, kc, vc = carry                      # kc/vc [L, B, H, S, dh]
+        layer, l = inp
         q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)
-        kc = kc.at[bidx, :, pos, :].set(k[:, :, 0, :].astype(kc.dtype))
-        vc = vc.at[bidx, :, pos, :].set(v[:, :, 0, :].astype(vc.dtype))
-        o = _slot_attention(q, kc, vc, qpos, cfg)
-        return _attn_out_and_mlp(x, o, layer, cfg), (kc, vc)
+        kc = kc.at[l, bidx, :, pos, :].set(k[:, :, 0, :].astype(kc.dtype))
+        vc = vc.at[l, bidx, :, pos, :].set(v[:, :, 0, :].astype(vc.dtype))
+        o = _slot_attention(q, kc[l], vc[l], qpos, cfg)
+        return (_attn_out_and_mlp(x, o, layer, cfg), kc, vc), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        block, x, (params["layers"], kcache, vcache), unroll=cfg.n_layers)
+    (x, k_new, v_new), _ = jax.lax.scan(
+        block, (x, kcache, vcache), _numbered(params["layers"], cfg),
+        unroll=cfg.n_layers)
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
     return x[:, 0], k_new, v_new
 
@@ -813,11 +829,12 @@ def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
     """One pass of a padded prompt chunk through the stack, shared by
     both cache layouts: toks [T] sit at positions start..start+T-1 of
     ONE sequence whose cache holds S positions; logits are taken at row
-    `last_idx` (the last REAL prompt token).  Per layer the chunk's T
-    rows of K and V are written with one `write(c, rows [T,H,dh], wpos)`
-    a side, then the chunk attends to `view(c)` [1,H,S,dh] — whatever
-    the cache held before `start` and itself — through _slot_attention.
-    kcache/vcache are stacked per layer on axis 0.
+    `last_idx` (the last REAL prompt token).  Per layer l the chunk's T
+    rows of K and V are written with one `write(c, l, rows [T,H,dh],
+    wpos)` a side, then the chunk attends to `view(c, l)` [1,H,S,dh] —
+    whatever the cache held before `start` and itself — through
+    _slot_attention.  kcache/vcache are stacked per layer on axis 0 and
+    are the layer loop's carry: `write` scatters into them.
 
     Pad rows sit behind every real token, so no real query sees them;
     their K/V land where decode overwrites before it attends, and a row
@@ -833,19 +850,20 @@ def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
     x = _slot_embed(params, toks[None], pos, cfg)          # [1, T, D]
 
-    def block(x, inp):
-        layer, kc, vc = inp
+    def block(carry, inp):
+        x, kc, vc = carry
+        layer, l = inp
         q, k, v = _slot_qkv(x, layer, cfg, rope, pos)      # [1, H, T, dh]
-        kc = write(kc, jnp.swapaxes(k[0], 0, 1).astype(kc.dtype), wpos)
-        vc = write(vc, jnp.swapaxes(v[0], 0, 1).astype(vc.dtype), wpos)
-        o = _slot_attention(q, view(kc), view(vc), pos, cfg)
-        return _attn_out_and_mlp(x, o, layer, cfg), (kc, vc)
+        kc = write(kc, l, jnp.swapaxes(k[0], 0, 1).astype(kc.dtype), wpos)
+        vc = write(vc, l, jnp.swapaxes(v[0], 0, 1).astype(vc.dtype), wpos)
+        o = _slot_attention(q, view(kc, l), view(vc, l), pos, cfg)
+        return (_attn_out_and_mlp(x, o, layer, cfg), kc, vc), None
 
     # a rolled scan: at T rows a layer the matmuls dwarf the per-op
     # fixed cost that makes the decode step unroll, and the program
     # stays one layer long to compile and to load
-    x, (k_new, v_new) = jax.lax.scan(
-        block, x, (params["layers"], kcache, vcache))
+    (x, k_new, v_new), _ = jax.lax.scan(
+        block, (x, kcache, vcache), _numbered(params["layers"], cfg))
     x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
     logits = jnp.einsum("d,dv->v", x.astype(cfg.dtype),
@@ -860,19 +878,14 @@ def slot_prefill(params, cache, toks, start, last_idx, slot,
     the layers in one pass (_prefill_chunk); logits are taken at chunk
     row `last_idx`.  Returns (logits [V], cache)."""
     S = cache["k"].shape[3]
-    kc = jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, 1)
-    vc = jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, 1)
 
-    def write(c, rows, wpos):                  # c [1, H, S, dh]
-        return c.at[0, :, wpos, :].set(rows, mode="drop")
+    def write(c, l, rows, wpos):               # c [L, B, H, S, dh]
+        return c.at[l, slot, :, wpos, :].set(rows, mode="drop")
 
-    logits, kc, vc = _prefill_chunk(params, kc, vc, toks, start, last_idx,
-                                    S, write, lambda c: c, cfg, rope)
-    cache = {
-        "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], kc, slot, 1),
-        "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, slot, 1),
-    }
-    return logits, cache
+    logits, kc, vc = _prefill_chunk(
+        params, cache["k"], cache["v"], toks, start, last_idx, S, write,
+        lambda c, l: c[l, slot][None], cfg, rope)
+    return logits, {"k": kc, "v": vc}
 
 
 # -- paged variant ----------------------------------------------------------
@@ -894,22 +907,28 @@ def _only(x):
 
 def init_paged_cache(cfg: GPTConfig, num_pages, page_size: int
                      ) -> Dict[str, Any]:
-    """Paged KV arena: [L, num_pages, H, page_size, d_head] per side.
+    """Paged KV arena: [L, num_pages, page_size, H * d_head] per side (a
+    position's heads lie together, as one row: the shape the chip keeps
+    as it is written, unpadded, and the order its compiler wants for the
+    row scatter and the page gather; given [.., H, page_size, d_head] it
+    stored the PAGE axis innermost, at twice the bytes, and every
+    program converted the whole arena on the way in and on the way out).
     Page 0 is the reserved null page (inactive-slot writes land there;
-    the allocator never hands it out)."""
+    the allocator never hands it out).  The programs below scatter their
+    rows into it and gather a slot's pages out of it where it stands."""
     num_pages = _only(num_pages)
-    shape = (cfg.n_layers, num_pages, cfg.n_heads, page_size, cfg.d_head)
+    shape = (cfg.n_layers, num_pages, page_size, cfg.n_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def _gather_pages(pages, ptab):
-    """One layer's arena [P, H, ps, dh] through page tables ptab
+def _gather_pages(pages, l, ptab, H: int):
+    """Layer l of the arena [L, P, ps, H * dh] through page tables ptab
     [B, maxp] -> the contiguous per-slot view [B, H, maxp * ps, dh]."""
     B, maxp = ptab.shape
-    _, H, ps, dh = pages.shape
-    g = pages[ptab]                            # [B, maxp, H, ps, dh]
-    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, H, maxp * ps, dh)
+    ps = pages.shape[2]
+    g = pages[l, ptab]                         # [B, maxp, ps, H * dh]
+    return jnp.swapaxes(g.reshape(B, maxp * ps, H, -1), 1, 2)
 
 
 def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
@@ -920,7 +939,8 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     scatter into each slot's current page; attention gathers the slot's
     pages into the contiguous [B, H, S, dh] view and runs the shared
     _slot_attention recipe."""
-    ps = kpages.shape[3]
+    B, H = tokens.shape[0], cfg.n_heads
+    ps = kpages.shape[2]
     S = ptab.shape[1] * ps
     pos = jnp.minimum(pos, S - 1)
     if cfg.pos == "learned":
@@ -932,17 +952,19 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     pidx = jnp.take_along_axis(ptab, (pos // ps)[:, None], axis=1)[:, 0]
     poff = pos % ps
 
-    def block(x, inp):
-        layer, kc, vc = inp                    # kc/vc [P, H, ps, dh]
-        q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)
-        kc = kc.at[pidx, :, poff, :].set(k[:, :, 0, :].astype(kc.dtype))
-        vc = vc.at[pidx, :, poff, :].set(v[:, :, 0, :].astype(vc.dtype))
-        o = _slot_attention(q, _gather_pages(kc, ptab),
-                            _gather_pages(vc, ptab), qpos, cfg)
-        return _attn_out_and_mlp(x, o, layer, cfg), (kc, vc)
+    def block(carry, inp):
+        x, kc, vc = carry                      # kc/vc [L, P, ps, H * dh]
+        layer, l = inp
+        q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)      # [B, H, 1, dh]
+        kc = kc.at[l, pidx, poff].set(k.reshape(B, -1).astype(kc.dtype))
+        vc = vc.at[l, pidx, poff].set(v.reshape(B, -1).astype(vc.dtype))
+        o = _slot_attention(q, _gather_pages(kc, l, ptab, H),
+                            _gather_pages(vc, l, ptab, H), qpos, cfg)
+        return (_attn_out_and_mlp(x, o, layer, cfg), kc, vc), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        block, x, (params["layers"], kpages, vpages), unroll=cfg.n_layers)
+    (x, k_new, v_new), _ = jax.lax.scan(
+        block, (x, kpages, vpages), _numbered(params["layers"], cfg),
+        unroll=cfg.n_layers)
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
     return x[:, 0], k_new, v_new
 
@@ -969,16 +991,17 @@ def paged_prefill(params, cache, toks, ptab_row, start, last_idx,
     the end of the table, the null page 0 — never another sequence's.
     Returns (logits [V], cache)."""
     ptab_row = _only(ptab_row)
-    ps = cache["k"].shape[3]
+    ps = cache["k"].shape[2]
     S = ptab_row.shape[0] * ps
 
-    def write(c, rows, wpos):                  # c [P, H, ps, dh]
+    def write(c, l, rows, wpos):               # c [L, P, ps, H * dh]
         pidx = ptab_row.at[wpos // ps].get(mode="fill", fill_value=0)
-        return c.at[pidx, :, wpos % ps, :].set(rows)
+        return c.at[l, pidx, wpos % ps].set(rows.reshape(rows.shape[0], -1))
 
     logits, kc, vc = _prefill_chunk(
         params, cache["k"], cache["v"], toks, start, last_idx, S, write,
-        lambda c: _gather_pages(c, ptab_row[None]), cfg, rope)
+        lambda c, l: _gather_pages(c, l, ptab_row[None], cfg.n_heads),
+        cfg, rope)
     return logits, {"k": kc, "v": vc}
 
 

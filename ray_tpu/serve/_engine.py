@@ -40,7 +40,15 @@ every position in them is a window or more behind the next query.
 
 Memory is a **paged arena** (models/gpt.py init_paged_cache): fixed-size
 pages in one preallocated device array, per-slot page tables gathered
-inside the decode step.  Pages are refcounted through a free list;
+inside the decode step.  The arena and the logits carried between steps
+are the engine's device state, and every program that returns a new
+version of it **consumes** the one it was given (`_fn` donates them): a
+step writes its rows, a prefill its chunk's, `serve.copy_page` one page
+and `serve.setrow` one row into the buffer that came in, and the array
+the engine held before the call is gone after it.  Should a program
+raise, the state may be gone with it: the loop drops it and the next
+admission makes it anew (every request in flight has failed by then).
+Pages are refcounted through a free list;
 full prompt pages register in a prefix table so live sequences with a
 common prompt prefix share pages (not for a model with a windowed kind:
 a shared prefix is not prefilled, and its window pages may be gone),
@@ -761,6 +769,9 @@ class ContinuousEngine:
                     for tab in self._ptabs.values():
                         tab[:] = 0
                 self._prefilling = None
+                # the program that raised may have consumed the state
+                # it was given: _ensure_device_state makes it anew
+                self._cache = self._logits = None
                 for s in seqs:
                     self._release(s)
                     self._finish(s, error=e)
@@ -1268,19 +1279,22 @@ class ContinuousEngine:
                     return toks, new_logits.astype(jnp.float32), cache, ()
 
             fn = self._fns[key] = devtel.instrument(
-                jax.jit(serve_step), name="serve.step")
+                jax.jit(serve_step, donate_argnums=(1, 2)),
+                name="serve.step")
         elif key == "setrow":
             def serve_setrow(L, row, slot):
                 return L.at[slot].set(row.astype(jnp.float32))
 
             fn = self._fns[key] = devtel.instrument(
-                jax.jit(serve_setrow), name="serve.setrow")
+                jax.jit(serve_setrow, donate_argnums=(0,)),
+                name="serve.setrow")
         elif key == "copy_page":
             def serve_copy_page(cache, dst, src):
                 return gpt.copy_page(cache, dst, src)
 
             fn = self._fns[key] = devtel.instrument(
-                jax.jit(serve_copy_page), name="serve.copy_page")
+                jax.jit(serve_copy_page, donate_argnums=(0,)),
+                name="serve.copy_page")
         elif isinstance(key, tuple) and key[0] == "prefill":
             # per-bucket ledger name: a healthy engine compiles each
             # padded-length bucket once; the SAME bucket recompiling is
@@ -1293,7 +1307,8 @@ class ContinuousEngine:
                 return logits, cache, tuple(stats)
 
             fn = self._fns[key] = devtel.instrument(
-                jax.jit(serve_prefill), name=f"serve.prefill:{key[1]}")
+                jax.jit(serve_prefill, donate_argnums=(1,)),
+                name=f"serve.prefill:{key[1]}")
         else:
             raise KeyError(key)
         return fn

@@ -17,7 +17,9 @@ and HBM occupancy is *the* capacity signal for TPU serving
     compiles, and is stable on a cache hit), stamped with trace / lower
     / backend-compile wall times from the monitooring events, a
     fingerprint of the triggering signature, optional executable
-    cost/memory analysis, and — on a *re*compile — a **cause diff**
+    cost/memory analysis (``argument_bytes`` / ``output_bytes`` /
+    ``alias_bytes``: what of the output lives in a donated argument's
+    buffer / ``temp_bytes``), and — on a *re*compile — a **cause diff**
     against the previous compile of the same program: which argument
     changed shape, dtype, weak-type, static value or tree structure.
     A sliding window per program detects **recompile storms** (the
@@ -628,6 +630,10 @@ def _analyze_executable(jitted: Any, args: Tuple,
                     ma, "argument_size_in_bytes", 0)),
                 "output_bytes": int(getattr(
                     ma, "output_size_in_bytes", 0)),
+                # bytes of output written into a donated argument's
+                # buffer: 0 where a program returns its state as a copy
+                "alias_bytes": int(getattr(
+                    ma, "alias_size_in_bytes", 0)),
                 "temp_bytes": int(getattr(ma, "temp_size_in_bytes", 0)),
                 "code_bytes": int(getattr(
                     ma, "generated_code_size_in_bytes", 0)),

@@ -75,7 +75,8 @@ _QUICK_FILES = {
     "test_parallel.py", "test_partition.py", "test_podracer.py",
     "test_remediation.py",
     "test_resource_sync.py", "test_runtime_env.py",
-    "test_serve.py", "test_serve_continuous.py", "test_serve_fault.py",
+    "test_serve.py", "test_serve_continuous.py", "test_serve_donation.py",
+    "test_serve_fault.py",
     "test_serve_prefill.py", "test_serve_mixed_pools.py",
     "test_serve_grpc.py",
     "test_state.py",

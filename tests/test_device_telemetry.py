@@ -197,6 +197,24 @@ def test_executable_analysis_opt_in():
     assert rec["analysis"].get("cost") or rec["analysis"].get("memory")
 
 
+@pytest.mark.parametrize("donate", [False, True], ids=["kept", "donated"])
+def test_analysis_alias_bytes_say_whether_the_state_is_updated_in_place(
+        donate):
+    """`alias_bytes` of a program's record: the bytes of its output that
+    live in a donated argument's buffer — all of the state where it is
+    donated, none where the program returns a copy.  The analysis runs
+    after the call, on arguments the call has consumed."""
+    led, _ = _ledger(analysis=True)
+    prog = led.jit(lambda c, i: c.at[i].set(1.0), name="al.prog",
+                   donate_argnums=(0,) if donate else ())
+    state = jnp.zeros((256, 128), jnp.float32)
+    out = prog(state, 3)
+    assert state.is_deleted() == donate
+    mem = led.snapshot()["records"][-1]["analysis"]["memory"]
+    assert mem["alias_bytes"] == (out.nbytes if donate else 0)
+    assert mem["output_bytes"] >= out.nbytes
+
+
 # ---------------------------------------------------------------------------
 # memory census: live buffers, PageAllocator pages, gauges, watermark
 # ---------------------------------------------------------------------------

@@ -89,7 +89,7 @@ def test_one_pass_prefill_matches_token_walk(model, layout, case):
     cfg, params = model
     start, count, bucket = CASES[case]
     prefix, toks = _prompt(start, seed=2), _prompt(count)
-    shape = (cfg.n_layers, NUM_PAGES, cfg.n_heads, PS, cfg.d_head)
+    shape = (cfg.n_layers, NUM_PAGES, PS, cfg.n_heads * cfg.d_head)
     if layout == "paged":
         cache = {"k": _noise(shape, 3), "v": _noise(shape, 4)}
         # positions before `start` hold valid K/V, as shared pages do
@@ -100,9 +100,9 @@ def test_one_pass_prefill_matches_token_walk(model, layout, case):
             jnp.int32(start), jnp.int32(count - 1), cfg)
 
         def rows(c, side):                     # [L, H, S, dh] of ROW
-            g = np.asarray(c[side])[:, ROW]    # [L, maxp, H, ps, dh]
-            return g.transpose(0, 2, 1, 3, 4).reshape(
-                cfg.n_layers, cfg.n_heads, S, cfg.d_head)
+            g = np.asarray(c[side])[:, ROW]    # [L, maxp, ps, H * dh]
+            return g.reshape(cfg.n_layers, S, cfg.n_heads,
+                             cfg.d_head).transpose(0, 2, 1, 3)
     else:
         slot = 1
         shape = (cfg.n_layers, SLOTS, cfg.n_heads, S, cfg.d_head)
@@ -153,7 +153,7 @@ def test_padded_prefill_leaves_other_sequences_pages_alone(model, start,
                                                            count, bucket,
                                                            own):
     cfg, params = model
-    shape = (cfg.n_layers, NUM_PAGES, cfg.n_heads, PS, cfg.d_head)
+    shape = (cfg.n_layers, NUM_PAGES, PS, cfg.n_heads * cfg.d_head)
     cache = {"k": _noise(shape, 5), "v": _noise(shape, 6)}
     row = ROW[:own] + [0] * (MAXP - own)       # unused entries: page 0
     _, got = gpt.paged_prefill(

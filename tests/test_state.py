@@ -122,13 +122,16 @@ def test_timeline_export(cluster, tmp_path):
 
     ray_tpu.get([traced.remote() for _ in range(3)])
     _flush()
-    time.sleep(1.5)  # worker-side buffers flush on a 1 s cadence
     out = tmp_path / "trace.json"
-    ray_tpu.timeline(str(out))
-    events = json.loads(out.read_text())
-    names = {e["name"] for e in events}
-    assert any(n.endswith("traced") for n in names)
-    assert "inner_span" in names
+
+    def exported():
+        ray_tpu.timeline(str(out))
+        events = json.loads(out.read_text())
+        names = {e["name"] for e in events}
+        if any(n.endswith("traced") for n in names) and "inner_span" in names:
+            return events
+
+    events = _wait_for(exported)
     for e in events:
         assert e["ph"] == "X" and e["dur"] > 0
 
