@@ -153,15 +153,6 @@ CONFIG_DEFS: List[Tuple[str, type, Any, str]] = [
     # -- serving (the LLM engine knobs live here, not as hardcoded
     # constants in serve/llm.py, so one RAY_TPU_SERVE_* env var reaches
     # every replica the bootstrapper spawns)
-    ("serve_engine", str, "paged",
-     "LLM decode engine: 'paged' (continuous batching over the paged "
-     "KV arena), 'contiguous' (continuous batching over per-slot "
-     "contiguous caches; the parity baseline), or 'static' (legacy "
-     "serve.batch micro-batching)"),
-    ("serve_gen_cache_cap", int, 8,
-     "compiled-program LRU entries per LLM replica (generate/prefill/"
-     "stream-step variants; the engine's own step programs are bounded "
-     "by construction and not counted)"),
     ("serve_max_slots", int, 8,
      "decode slots per replica = the fixed batch width of the compiled "
      "continuous-batching step program"),

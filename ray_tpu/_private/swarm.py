@@ -16,8 +16,8 @@ reports on:
 Everything runs in one process except the control daemon itself
 (``bootstrap.Cluster.start_control`` subprocess), so the numbers isolate
 the control plane: no workers, no object store, no scheduler churn.
-Used by ``bench.py --control-only`` (BENCH_CONTROL.json) and the tier-1
-swarm smoke test at N=50.
+Used by the tier-1 swarm smoke test at N=50
+(tests/test_control_stats.py).
 """
 
 from __future__ import annotations

@@ -822,8 +822,7 @@ def build_parser() -> argparse.ArgumentParser:
         help="reassemble a distributed trace (span tree + critical-path "
              "attribution) from the control-plane span collector")
     sp.add_argument("trace_id", nargs="?", default=None,
-                    help="32-hex trace id (from a span record or "
-                         "BENCH_TASKS.json critical_path row)")
+                    help="32-hex trace id (from a span record)")
     sp.add_argument("--summary", action="store_true",
                     help="aggregate phase attribution across all stored "
                          "traces instead of showing one")

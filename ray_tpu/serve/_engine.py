@@ -1,16 +1,16 @@
 """Continuous-batching decode engine with a paged KV cache.
 
-The static `serve.batch` path admits requests only at batch boundaries:
-one long sequence stalls every short one, and the device idles between
-batches.  This module is the iteration-level scheduler that replaces it
-(the vLLM/Orca recipe, per the Gemma-on-TPU serving comparison in
+Batching whole requests admits them only at batch boundaries: one long
+sequence stalls every short one, and the device idles between batches.
+This module is the iteration-level scheduler used instead (the
+vLLM/Orca recipe, per the Gemma-on-TPU serving comparison in
 PAPERS.md): a fixed-shape compiled step program runs over a batch of
 **slots**; sequences join at prefill and leave at EOS/max-tokens, at
 *every* decode step, so the step program never recompiles as traffic
 comes and goes.
 
 A request joins through a **prefill program** (models/gpt.py
-paged_prefill / slot_prefill): the prompt's own tokens — whatever the
+paged_prefill): the prompt's own tokens — whatever the
 prefix table did not find in shared pages — padded to a multiple of
 `prefill_bucket`, go through the layers in ONE pass: per layer one
 chunk-wide QKV projection, one scatter of the chunk's K and V rows into
@@ -58,12 +58,6 @@ case: everything is shared but the last prompt position must be
 recomputed to produce logits).  Page 0 is the reserved null page —
 inactive slots write there and their sampled tokens are discarded
 host-side, which is what lets the step program keep one static shape.
-
-A contiguous slot-cache mode (`cache="contiguous"`) runs the same
-scheduler over models/gpt.init_slot_cache; the paged path gathers its
-pages into the identical [B, H, S, dh] attention view, so greedy decode
-is bitwise-identical between the two — the parity tests in
-tests/test_serve_continuous.py pin that.
 
 Everything device-facing runs on one daemon thread (the engine loop);
 `submit` is thread-safe and hands back a `_Sequence` whose results are
@@ -365,8 +359,7 @@ class ContinuousEngine:
 
     _END = object()
 
-    def __init__(self, gpt_mod, cfg, params, *, cache: str = "paged",
-                 max_slots: int = 8, page_size: int = 16,
+    def __init__(self, gpt_mod, cfg, params, *, max_slots: int = 8, page_size: int = 16,
                  num_pages=0, max_total: int = 0,
                  queue_cap: int = 32, shed_queue_depth: int = 16,
                  retry_after_s: float = 1.0, prefill_bucket: int = 32,
@@ -375,11 +368,8 @@ class ContinuousEngine:
         import jax
         import numpy as np
 
-        if cache not in ("paged", "contiguous"):
-            raise ValueError(f"unknown cache mode {cache!r}")
         self._jax, self._np, self._gpt = jax, np, gpt_mod
         self._cfg, self._params = cfg, params
-        self.cache_mode = cache
         self.max_slots = int(max_slots)
         self.page_size = int(page_size)
         self.max_total = int(max_total) or cfg.max_seq
@@ -400,8 +390,8 @@ class ContinuousEngine:
         # admission; a windowed kind's is a ring as wide as the window
         # plus the longest prefill program, filled as the sequence grows
         # and emptied as the window passes.
-        self._kinds: Dict[str, Optional[int]] = (
-            dict(gpt_mod.cache_kinds(cfg)) if cache == "paged" else {})
+        self._kinds: Dict[str, Optional[int]] = dict(
+            gpt_mod.cache_kinds(cfg))
         longest = self.prefill_chunk or self.max_total
         self._widths = {
             k: self.max_pages_per_seq if w is None else min(
@@ -415,10 +405,8 @@ class ContinuousEngine:
         # the pool the scheduler's public numbers describe: the first
         # kind that keeps every position (the only one, for most models)
         self._main = next((k for k, w in self._kinds.items() if w is None),
-                          next(iter(self._kinds), None))
-        self.num_pages = (self._pool_pages[self._main] if self._kinds else
-                          int(num_pages)
-                          or 1 + self.max_slots * self.max_pages_per_seq)
+                          next(iter(self._kinds)))
+        self.num_pages = self._pool_pages[self._main]
         # live prefix sharing skips a shared prefix's prefill, so it needs
         # every layer's K/V of that prefix to be kept: with a windowed
         # kind nothing is shared (the window's pages may be gone)
@@ -430,7 +418,7 @@ class ContinuousEngine:
         self._slots: List[Optional[_Sequence]] = [None] * self.max_slots
         self._allocs = {k: PageAllocator(n, self.page_size)
                         for k, n in self._pool_pages.items()}
-        self._alloc = self._allocs.get(self._main)
+        self._alloc = self._allocs[self._main]
         self._prefilling: Optional[_Sequence] = None  # mid-prompt, FCFS
         # a model's serve programs may return counters of their own (its
         # module's STEP_STATS names them): a step's go into the
@@ -441,8 +429,7 @@ class ContinuousEngine:
             "chunk_" + n for n in self._stat_names)
         self._fns: Dict[Any, Any] = {}   # bounded by construction: one
         # step program + one prefill per padded-length bucket + setrow +
-        # copy_page — not the LRU _gen_cache (evicting the step program
-        # mid-traffic would recompile the hot loop)
+        # copy_page
         self._thread: Optional[threading.Thread] = None
         self._wake = threading.Event()
         self._stopped = False
@@ -456,11 +443,9 @@ class ContinuousEngine:
         self._logits = None          # [B, V] carried across steps
 
         # host mirrors of the per-slot step operands
-        B, maxp = self.max_slots, self.max_pages_per_seq
+        B = self.max_slots
         self._pos = np.zeros(B, np.int32)
-        self._ptab = np.zeros((B, maxp), np.int32)
-        self._ptabs = {k: (self._ptab if k == self._main else
-                           np.zeros((B, w), np.int32))
+        self._ptabs = {k: np.zeros((B, w), np.int32)
                        for k, w in self._widths.items()}
         self._toks_keys = np.zeros((B, 2), np.uint32)
         self._temps = np.zeros(B, np.float32)
@@ -601,12 +586,10 @@ class ContinuousEngine:
             totals = dict(self._totals)
         toks = sum(n for _, n in window)
         span = (now - window[0][0]) if window else 0.0
-        free_pages = self._alloc.free_pages if self._alloc else \
-            (self.max_slots - active) * self.max_pages_per_seq
         return {
             "active": active,
             "queue_depth": qd,
-            "free_pages": free_pages,
+            "free_pages": self._alloc.free_pages,
             "accepting": (not draining) and qd < self.shed_queue_depth,
             "retry_after_s": self.retry_after_s,
             "ttft_p99_s": ttfts[min(len(ttfts) - 1,
@@ -620,26 +603,24 @@ class ContinuousEngine:
         """Owner callback for telemetry/device.DeviceMemoryCensus: the
         ``pages`` sub-dict feeds ``ray_tpu_kv_pages{state=…}`` — free /
         used are live arena occupancy, shared / cow are the engine's
-        cumulative prefix-sharing totals (the serve bench row's
+        cumulative prefix-sharing totals (``engine_stats()``'s
         ``shared_pages`` / ``cow_copies``)."""
         with self._lock:
             totals = dict(self._totals)
-        rep: Dict[str, Any] = {"cache": self.cache_mode,
-                               "num_pages": self.num_pages,
-                               "max_slots": self.max_slots}
-        if self._alloc is not None:
-            occ = self._alloc.occupancy()
-            rep["pages"] = {
-                "free": occ["free"],
-                "used": occ["used"],
-                "shared": totals["shared_pages"],
-                "cow": totals["cow_copies"],
-                "live_shared": occ["live_shared"],
-            }
-            rep["prefix_keys"] = occ["prefix_keys"]
-            rep["pools"] = {k: a.occupancy()
-                            for k, a in self._allocs.items()}
-        return rep
+        occ = self._alloc.occupancy()
+        return {"cache": "paged",
+                "num_pages": self.num_pages,
+                "max_slots": self.max_slots,
+                "pages": {
+                    "free": occ["free"],
+                    "used": occ["used"],
+                    "shared": totals["shared_pages"],
+                    "cow": totals["cow_copies"],
+                    "live_shared": occ["live_shared"],
+                },
+                "prefix_keys": occ["prefix_keys"],
+                "pools": {k: a.occupancy()
+                          for k, a in self._allocs.items()}}
 
     def phase_ring(self) -> List[Dict[str, float]]:
         with self._lock:
@@ -826,8 +807,7 @@ class ContinuousEngine:
                 qd = len(self._waiting)
             for which, val in (("active", stepped),
                                ("queue", qd),
-                               ("free_pages", self._alloc.free_pages
-                                if self._alloc else 0)):
+                               ("free_pages", self._alloc.free_pages)):
                 g = _m_gauge(which)
                 if g:
                     g.set(val)
@@ -893,7 +873,7 @@ class ContinuousEngine:
                     break
                 seq = self._waiting[0]
                 plan = self._plan_pages(seq)
-                if plan is None and self._allocs:
+                if plan is None:
                     break                   # page-starved: wait for evicts
                 if not admitted:
                     # streams this round's prefills stall: slots whose
@@ -920,8 +900,6 @@ class ContinuousEngine:
         take it.  A full kind's pages are taken here (allocator.plan, with
         its prefix sharing where the model allows it); a windowed kind
         only reserves the most it will hold at once."""
-        if not self._allocs:
-            return None
         need = self._pages_needed(seq)
         windowed = {k: min(need, self._widths[k]) for k in self._windowed}
         if any(self._allocs[k].available < n for k, n in windowed.items()):
@@ -940,20 +918,18 @@ class ContinuousEngine:
         np = self._np
         self._ensure_device_state()
         plen = len(seq.tokens)
-        shared_len = 0
-        if plan is not None:
-            seq.pages = plan["pages"]
-            shared_len = plan["shared_len"]
-            for k, w in self._widths.items():
-                seq.tabs[k] = np.zeros(w, np.int32)
-                seq.win[k] = {}
-            seq.tabs[self._main][:len(seq.pages)] = seq.pages
-            self._totals["cow_copies"] += len(plan["copies"])
-            self._totals["shared_pages"] += plan["n_shared"]
-            for src, dst in plan["copies"]:
-                self._cache = self._fn("copy_page")(self._cache,
-                                                    np.int32(dst),
-                                                    np.int32(src))
+        seq.pages = plan["pages"]
+        shared_len = plan["shared_len"]
+        for k, w in self._widths.items():
+            seq.tabs[k] = np.zeros(w, np.int32)
+            seq.win[k] = {}
+        seq.tabs[self._main][:len(seq.pages)] = seq.pages
+        self._totals["cow_copies"] += len(plan["copies"])
+        self._totals["shared_pages"] += plan["n_shared"]
+        for src, dst in plan["copies"]:
+            self._cache = self._fn("copy_page")(self._cache,
+                                                np.int32(dst),
+                                                np.int32(src))
         seq.slot = slot
         seq.pos = plen
         seq.shared = seq.next_start = shared_len
@@ -983,14 +959,9 @@ class ContinuousEngine:
             t0 = time.perf_counter()
             if not seq.chunks:
                 seq.t_prefill = t0
-            if self._allocs:
-                logits, self._cache, stats = self._fn(("prefill", T))(
-                    self._params, self._cache, chunk, seq.tabs,
-                    np.int32(start), np.int32(n - 1))
-            else:
-                logits, self._cache, stats = self._fn(("prefill", T))(
-                    self._params, self._cache, chunk, np.int32(start),
-                    np.int32(n - 1), np.int32(slot))
+            logits, self._cache, stats = self._fn(("prefill", T))(
+                self._params, self._cache, chunk, seq.tabs,
+                np.int32(start), np.int32(n - 1))
             if last:
                 with ann("serve.engine.setrow"):
                     self._logits = self._fn("setrow")(self._logits, logits,
@@ -1032,7 +1003,7 @@ class ContinuousEngine:
         self._totals["prefills"] += 1
 
         # register this prompt's full pages for live prefix sharing
-        if self._share and self._alloc is not None:
+        if self._share:
             for i in range(plen // self.page_size):
                 self._alloc.register_prefix(
                     tuple(seq.tokens[:(i + 1) * self.page_size]),
@@ -1087,8 +1058,7 @@ class ContinuousEngine:
     def _release(self, seq: _Sequence):
         """Everything `seq` holds or has reserved goes back to its pools
         (once: the sequence is left holding nothing)."""
-        if self._alloc is not None:
-            self._alloc.release(seq.pages)
+        self._alloc.release(seq.pages)
         seq.pages = []
         for k, live in seq.win.items():
             a = self._allocs[k]
@@ -1115,8 +1085,7 @@ class ContinuousEngine:
             td = time.perf_counter()
             toks, self._logits, self._cache, stats = self._fn("step")(
                 self._params, self._cache, self._logits, self._toks_keys,
-                self._temps, self._topks,
-                self._ptabs if self._allocs else self._ptab, self._pos)
+                self._temps, self._topks, self._ptabs, self._pos)
         with ann("serve.engine.fetch"):
             toks = np.asarray(toks)
             self._note_stats(stats)
@@ -1213,12 +1182,8 @@ class ContinuousEngine:
         if self._cache is not None:
             return
         jnp = self._jax.numpy
-        if self.cache_mode == "paged":
-            self._cache = self._gpt.init_paged_cache(
-                self._cfg, self._pool_pages, self.page_size)
-        else:
-            self._cache = self._gpt.init_slot_cache(
-                self._cfg, self.max_slots, self.max_total)
+        self._cache = self._gpt.init_paged_cache(
+            self._cfg, self._pool_pages, self.page_size)
         self._logits = jnp.zeros(
             (self.max_slots, self._cfg.vocab_size), jnp.float32)
 
@@ -1228,10 +1193,10 @@ class ContinuousEngine:
             return fn
         jax, gpt, cfg = self._jax, self._gpt, self._cfg
         jnp = jax.numpy
-        paged = self.cache_mode == "paged"
         # every engine program routes through the compilation ledger:
         # "the step program never recompiles" (module docstring) is now
-        # a measured claim — bench gates steady-state recompiles at 0
+        # a measured claim — a benchmark cell is `correct` only if
+        # nothing compiled inside its window
         from ..telemetry import device as devtel
 
         if key == "step":
@@ -1260,23 +1225,15 @@ class ContinuousEngine:
 
             # named apart from the train step: `jit_serve_step(...)` on
             # the device trace's `XLA Modules` line
-            if paged:
-                def serve_step(params, cache, logits, keys, temps, topks,
-                               ptab, pos):
-                    toks = sample(logits, keys, temps, topks)
-                    # a model may return its own counters third (the
-                    # vector its module's STEP_STATS names)
-                    new_logits, cache, *stats = gpt.paged_decode_step(
-                        params, cache, toks, ptab, pos, cfg)
-                    return (toks, new_logits.astype(jnp.float32), cache,
-                            tuple(stats))
-            else:
-                def serve_step(params, cache, logits, keys, temps, topks,
-                               ptab, pos):
-                    toks = sample(logits, keys, temps, topks)
-                    new_logits, cache = gpt.slot_decode_step(
-                        params, cache, toks, pos, cfg)
-                    return toks, new_logits.astype(jnp.float32), cache, ()
+            def serve_step(params, cache, logits, keys, temps, topks,
+                           ptab, pos):
+                toks = sample(logits, keys, temps, topks)
+                # a model may return its own counters third (the
+                # vector its module's STEP_STATS names)
+                new_logits, cache, *stats = gpt.paged_decode_step(
+                    params, cache, toks, ptab, pos, cfg)
+                return (toks, new_logits.astype(jnp.float32), cache,
+                        tuple(stats))
 
             fn = self._fns[key] = devtel.instrument(
                 jax.jit(serve_step, donate_argnums=(1, 2)),
@@ -1299,11 +1256,9 @@ class ContinuousEngine:
             # per-bucket ledger name: a healthy engine compiles each
             # padded-length bucket once; the SAME bucket recompiling is
             # the storm signal, a new bucket is not
-            prefill = gpt.paged_prefill if paged else gpt.slot_prefill
-
             def serve_prefill(params, cache, toks, *operands):
-                logits, cache, *stats = prefill(params, cache, toks,
-                                                *operands, cfg=cfg)
+                logits, cache, *stats = gpt.paged_prefill(
+                    params, cache, toks, *operands, cfg=cfg)
                 return logits, cache, tuple(stats)
 
             fn = self._fns[key] = devtel.instrument(

@@ -2,7 +2,7 @@
 
 The reference serves LLMs by delegating to an external engine (vLLM) and
 wiring it into Serve; here decoding is the framework's own jit program
-(models/gpt.py), and by default each replica hosts a **continuous-
+(models/gpt.py), and each replica hosts a **continuous-
 batching engine** over a **paged KV cache** (serve/_engine.py): one
 fixed-shape compiled step program over a slot batch, sequences joining
 at prefill and leaving at EOS/max-tokens at every decode step, pages
@@ -14,16 +14,6 @@ between decode steps.  A loaded config is served by its own module
 streaming ride the same engine, so a short request never waits behind a
 long one.
 
-Engine selection (``RAY_TPU_SERVE_ENGINE`` or ``engine=`` at bind time):
-
-  * ``paged`` (default) — continuous batching, paged KV arena;
-  * ``contiguous`` — continuous batching over per-slot contiguous
-    caches (the bitwise-parity baseline for the paged path);
-  * ``static`` — the legacy ``serve.batch`` micro-batching path:
-    requests grouped by (prompt_len, max_new, sampling params, seed),
-    each group one stacked ``generate()`` call, streaming via a
-    dedicated per-request prefill + fused sample/decode step loop.
-
 All engine sizing knobs (slots, page size, arena pages, admission
 watermarks) are the ``RAY_TPU_SERVE_*`` flags in _private/config.py.
 
@@ -33,21 +23,14 @@ out of scope (bring your own; nothing here depends on one).
 
 from __future__ import annotations
 
-import functools
 import sys
-from collections import OrderedDict
 from typing import Any, Dict, List, Optional
 
 from .._private.config import cfg as _config
 from ._deployment import deployment
 from .api import run
-from .batching import batch
 
 __all__ = ["LLMServer", "build_llm_app"]
-
-
-def _bucket(n: int, step: int = 128) -> int:
-    return ((n + step - 1) // step) * step
 
 
 class _LLMServerImpl:
@@ -66,6 +49,12 @@ class _LLMServerImpl:
 
         from ray_tpu.models import gpt
 
+        # before the loader runs: a bad argument costs no weights
+        if engine not in (None, "paged"):
+            raise ValueError(
+                f"unknown engine {engine!r}: every replica serves through "
+                f"the paged continuous-batching engine (the 'static' and "
+                f"'contiguous' modes were removed)")
         self._gpt = gpt
         user_cfg_kwargs = dict(cfg_kwargs or {})
         cfg_kwargs = dict(user_cfg_kwargs)
@@ -94,16 +83,6 @@ class _LLMServerImpl:
         mod = sys.modules.get(type(self._cfg).__module__)
         if hasattr(mod, "paged_decode_step"):
             self._gpt = mod
-        self._jax = jax
-        # per-instance (NOT lru_cache on the method: a class-level cache
-        # keyed by self would pin replaced replicas' full weights), and
-        # bounded: a long-lived replica facing varied (max_new, temp,
-        # top_k) tuples must not grow compile-cache memory without limit
-        self._gen_cache: "OrderedDict[tuple, Any]" = OrderedDict()
-        self._gen_cache_cap = _config().serve_gen_cache_cap
-        self._engine_mode = engine or _config().serve_engine
-        if self._engine_mode not in ("paged", "contiguous", "static"):
-            raise ValueError(f"unknown engine {self._engine_mode!r}")
         self._engine_kwargs = dict(engine_kwargs or {})
         self._engine = None   # built lazily: direct construction (tests,
         #                       tooling) must not allocate the device arena
@@ -113,8 +92,7 @@ class _LLMServerImpl:
             from ._engine import ContinuousEngine
 
             c = _config()
-            kw = dict(cache=self._engine_mode,
-                      max_slots=c.serve_max_slots,
+            kw = dict(max_slots=c.serve_max_slots,
                       page_size=c.serve_page_size,
                       num_pages=c.serve_num_pages,
                       max_total=c.serve_max_total,
@@ -130,7 +108,7 @@ class _LLMServerImpl:
 
     def engine_stats(self) -> Optional[Dict[str, Any]]:
         """Scheduler snapshot for the replica metrics poll (None until
-        the engine has processed its first request, or in static mode)."""
+        the engine has processed its first request)."""
         if self._engine is None:
             return None
         return self._engine.engine_stats()
@@ -160,120 +138,6 @@ class _LLMServerImpl:
         return (ctx or {}).get("request_id") if isinstance(ctx, dict) \
             else None
 
-    def _cached(self, key, build):
-        """LRU-bounded compiled-program cache (every jitted variant a
-        replica ever builds goes through here, so the cap holds no
-        matter which routes a client exercises)."""
-        fn = self._gen_cache.get(key)
-        if fn is None:
-            fn = self._gen_cache[key] = build()
-            while len(self._gen_cache) > self._gen_cache_cap:
-                self._gen_cache.popitem(last=False)
-        else:
-            self._gen_cache.move_to_end(key)
-        return fn
-
-    def _gen_fn(self, max_new: int, temperature: float,
-                top_k: Optional[int], max_seq: int):
-        return self._cached(
-            (max_new, temperature, top_k, max_seq),
-            lambda: self._jax.jit(functools.partial(
-                self._gpt.generate, cfg=self._cfg, max_new_tokens=max_new,
-                temperature=temperature, top_k=top_k, max_seq=max_seq)))
-
-    def _check_capacity(self, plen: int, max_new: int):
-        if self._cfg.pos == "learned" and plen + max_new > self._cfg.max_seq:
-            raise ValueError(
-                f"prompt ({plen}) + max_new_tokens ({max_new}) exceeds "
-                f"the model's learned-position capacity "
-                f"({self._cfg.max_seq})")
-
-    async def generate_batch(self, requests: List[Dict[str, Any]]
-                             ) -> List[Dict[str, Any]]:
-        """Group by (prompt_len, max_new, temperature, top_k): each group
-        is one stacked generate() call."""
-        import numpy as np
-
-        groups: Dict[tuple, List[int]] = {}
-        for i, r in enumerate(requests):
-            key = (len(r["tokens"]), int(r.get("max_new_tokens", 16)),
-                   float(r.get("temperature", 0.0)),
-                   r.get("top_k"), int(r.get("seed", 0)))
-            groups.setdefault(key, []).append(i)
-        out: List[Optional[Dict[str, Any]]] = [None] * len(requests)
-        for (plen, max_new, temp, top_k, seed), idxs in groups.items():
-            self._check_capacity(plen, max_new)
-            prompts = np.asarray([requests[i]["tokens"] for i in idxs],
-                                 np.int32)
-            fn = self._gen_fn(max_new, temp, top_k,
-                              _bucket(plen + max_new))
-            toks = np.asarray(fn(self._params, prompt=prompts,
-                                 rng=self._jax.random.PRNGKey(seed)))
-            for row, i in enumerate(idxs):
-                out[i] = {"tokens": toks[row].tolist(),
-                          "completion": toks[row, plen:].tolist(),
-                          "batch_size": len(idxs)}
-        return out
-
-    def _prefill_fn(self, total: int):
-        """One jit program for the whole prompt (a per-token Python
-        prefill loop costs one dispatch + host sync per position; on the
-        attached chip that cost is not measured).  Hidden-only through the stack; the D x V vocab
-        projection (the fattest matmul in a small-model decode step)
-        runs once, on the final position."""
-        jax, gpt, cfg = self._jax, self._gpt, self._cfg
-
-        def build():
-            def prefill(params, cache, toks):        # toks [S] int32
-                def body(c, t):
-                    x, c = gpt._decode_hidden(params, c, t[None], cfg)
-                    return c, x
-
-                cache, xs = jax.lax.scan(body, cache, toks)
-                logits = jax.numpy.einsum(
-                    "bd,dv->bv", xs[-1].astype(cfg.dtype),
-                    gpt._unembed_table(params, cfg))
-                return logits, cache
-
-            return jax.jit(prefill)
-
-        return self._cached(("prefill", total), build)
-
-    def _sample_body(self, logits, rkey, temperature, top_k):
-        # gpt.sample_logits is the one sampling recipe — sharing it is
-        # what makes stream/batched seed parity structural, not luck
-        return self._gpt.sample_logits(logits, rkey, temperature, top_k)
-
-    def _stream_step_fn(self, temperature: float, top_k: Optional[int],
-                        total: int):
-        """Fused sample+decode step: returns (token [1], next logits,
-        cache).  Sampling runs ON DEVICE so the stream loop transfers a
-        4-byte token id per step, not [1, V] logits; the sample recipe
-        mirrors gpt.generate's exactly (same key schedule => identical
-        completions for the same seed)."""
-        jax, gpt, cfg = self._jax, self._gpt, self._cfg
-
-        def build():
-            def step(params, cache, logits, rkey):
-                tok = self._sample_body(logits, rkey, temperature, top_k)
-                new_logits, cache = gpt.decode_step(params, cache, tok,
-                                                    cfg)
-                return tok, new_logits, cache
-
-            return jax.jit(step)
-
-        return self._cached(("stream_step", temperature, top_k, total),
-                            build)
-
-    def _sample_fn(self, temperature: float, top_k: Optional[int]):
-        """Sample-only program for the LAST token of a stream — it
-        needs no further forward pass or cache write."""
-        return self._cached(
-            ("sample", temperature, top_k),
-            lambda: self._jax.jit(functools.partial(
-                self._sample_body, temperature=temperature,
-                top_k=top_k)))
-
     def stream_tokens(self, tokens: List[int], max_new_tokens: int = 16,
                       temperature: float = 0.0, seed: int = 0,
                       top_k: Optional[int] = None,
@@ -281,47 +145,17 @@ class _LLMServerImpl:
                       key_offset: int = 0):
         """Yield one sampled token id at a time (generator => Serve
         streams it as SSE/chunked over HTTP, itemwise over handles).
-        Under the continuous engine the stream is fed by the shared
-        slot-batch step loop (tokens appear as the scheduler emits
-        them); in static mode it is a dedicated per-request decode
-        loop.  Sampling shares gpt.sample_logits and the batched
-        route's key schedule either way (token-exact in f32; at bf16,
-        fusion-order rounding can flip near-tie logits)."""
-        import numpy as np
-
-        if self._engine_mode != "static":
-            eng = self._get_engine()
-            seq = eng.submit(tokens, max_new_tokens, temperature, seed,
-                             top_k, eos_id=eos_id, stream=True,
-                             request_id=self._request_id(),
-                             key_offset=key_offset)
-            yield from eng.stream(seq)
-            return
-        jax, gpt, cfg = self._jax, self._gpt, self._cfg
-        if not tokens:
-            raise ValueError("empty prompt: stream_tokens needs at "
-                             "least one prompt token")
-        self._check_capacity(len(tokens), max_new_tokens)
-        total = _bucket(len(tokens) + max_new_tokens)
-        cache = gpt.init_cache(cfg, 1, total)
-        logits, cache = self._prefill_fn(total)(
-            self._params, cache, np.asarray(tokens, np.int32))
-        # same key schedule as the batched route (gpt.generate splits
-        # rng into max_new_tokens keys up front): seed parity holds for
-        # sampled decodes, not just greedy.  key_offset (router resume
-        # continuation) re-derives the original request's schedule and
-        # skips the keys its delivered tokens consumed.
-        keys = jax.random.split(jax.random.PRNGKey(seed),
-                                key_offset + max_new_tokens)[key_offset:]
-        step = self._stream_step_fn(temperature, top_k, total)
-        for i in range(max_new_tokens - 1):
-            tok, logits, cache = step(self._params, cache, logits,
-                                      keys[i])
-            yield int(tok[0])
-        if max_new_tokens > 0:   # the last sample needs no further
-            tok = self._sample_fn(temperature, top_k)(  # forward pass
-                logits, keys[max_new_tokens - 1])
-            yield int(tok[0])
+        The stream is fed by the engine's shared slot-batch step loop
+        (tokens appear as the scheduler emits them).  Sampling shares
+        gpt.sample_logits and the request/response route's key schedule
+        (token-exact in f32; at bf16, fusion-order rounding can flip
+        near-tie logits)."""
+        eng = self._get_engine()
+        seq = eng.submit(tokens, max_new_tokens, temperature, seed,
+                         top_k, eos_id=eos_id, stream=True,
+                         request_id=self._request_id(),
+                         key_offset=key_offset)
+        yield from eng.stream(seq)
 
     async def _engine_generate(self, body: Dict[str, Any]
                                ) -> Dict[str, Any]:
@@ -349,28 +183,20 @@ class _LLMServerImpl:
                 raise ValueError(
                     "token streaming over HTTP lives on the companion "
                     "'<route>-stream' endpoint; this route is the "
-                    "micro-batched JSON API")
+                    "request/response JSON API")
             return self.stream_tokens(
                 body["tokens"], int(body.get("max_new_tokens", 16)),
                 float(body.get("temperature", 0.0)),
                 int(body.get("seed", 0)), body.get("top_k"),
                 body.get("eos_id"))
-        if self._engine_mode != "static":
-            return await self._engine_generate(body)
-        return await self.generate_batch(body)
+        return await self._engine_generate(body)
 
 
 def LLMServer(**deployment_kwargs):
     """`LLMServer().bind(preset=..., ...)`-style factory: returns the
-    deployment (decorate-once so serve.batch wraps generate_batch)."""
+    deployment."""
     cls = type("LLMServer", (_LLMServerImpl,), {})
-    cls.generate_batch = batch(
-        _LLMServerImpl.generate_batch,
-        max_batch_size=deployment_kwargs.pop("max_batch_size", 8),
-        batch_wait_timeout_s=deployment_kwargs.pop(
-            "batch_wait_timeout_s", 0.02))
-    return deployment(cls, **deployment_kwargs) \
-        if deployment_kwargs else deployment(cls)
+    return deployment(cls, **deployment_kwargs)
 
 
 class _LLMStreamIngress:
@@ -408,7 +234,7 @@ class _LLMStreamIngress:
 def build_llm_app(preset: str = "nano", *, route_prefix: str = "/llm",
                   name: str = "llm", stream: bool = True, **init_kwargs):
     """Deploy a generation endpoint: POST {tokens, max_new_tokens, ...}
-    -> {tokens, completion} at `route_prefix` (micro-batched), plus a
+    -> {tokens, completion} at `route_prefix`, plus a
     token-streaming endpoint at `route_prefix`-stream."""
     dep = LLMServer()
     h = run(dep.bind(preset=preset, **init_kwargs), name=name,

@@ -9,8 +9,7 @@ proxy) for each.  Availability actually changes on ~10% of beats
 (steady-state clusters mostly idle between scheduling bursts).
 
 Usage: python scripts/bench_resource_sync.py [--nodes 50] [--secs 15]
-Prints one JSON line (the BENCH_TABLE.json resource_sync_delta entry is
-pasted from this output by hand when refreshed).
+Prints one JSON line.
 """
 
 import argparse

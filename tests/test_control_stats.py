@@ -102,6 +102,11 @@ def test_budget_exceeded_counter():
     cli = Client(s.addr, name="t-budget")
     try:
         cli.call("ping", None, timeout=10.0)
+        # the server records a completion after it has sent the reply
+        deadline = time.monotonic() + 5.0
+        while (s.stats()["ping"]["budget_exceeded"] == 0
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         st = s.stats()["ping"]
         assert st["budget_ms"] == 5.0
         assert st["budget_exceeded"] == 1

@@ -319,7 +319,7 @@ def test_page_allocator_occupancy_flows_to_census_and_gauges():
 
     cfg = gpt.GPTConfig.nano(max_seq=64)
     params = gpt.init(jax.random.PRNGKey(0), cfg)
-    eng = ContinuousEngine(gpt, cfg, params, cache="paged", max_slots=4,
+    eng = ContinuousEngine(gpt, cfg, params, max_slots=4,
                            page_size=8, prefill_bucket=8)
     # page-aligned prefix (2 full pages of 8): sharing needs fully
     # registered prompt pages, and the shared_len clamp to plen-1 forces

@@ -1,7 +1,8 @@
 """Continuous-batching engine (serve/_engine.py) + paged KV cache
-(models/gpt.py paged_* / slot_*): scheduler correctness, paged vs
-contiguous parity, prefix sharing / copy-on-write, admission control,
-and the serve.batch / router regression fixes that rode along.
+(models/gpt.py paged_*): scheduler correctness, parity with
+gpt.generate, prefix sharing / copy-on-write, admission control, the
+one served mode of serve/llm.py, and the serve.batch / router
+regression fixes that rode along.
 
 Everything here is in-process (no cluster): the engine is a plain
 object plus a daemon thread, and the jit programs run on CPU.
@@ -35,10 +36,9 @@ def model():
     return cfg, params
 
 
-def _make_engine(model, cache="paged", **kw):
+def _make_engine(model, **kw):
     cfg, params = model
-    defaults = dict(cache=cache, max_slots=4, page_size=8,
-                    prefill_bucket=8)
+    defaults = dict(max_slots=4, page_size=8, prefill_bucket=8)
     defaults.update(kw)
     return ContinuousEngine(gpt, cfg, params, **defaults)
 
@@ -57,20 +57,16 @@ def _expected(model, prompt, max_new, temperature=0.0, seed=0,
 
 
 def test_paged_matches_contiguous_and_generate_greedy(model):
+    """Three prompts side by side in the slot batch decode what
+    gpt.generate decodes for each alone over its contiguous cache."""
     prompts = [PROMPT, [7, 9, 2], list(range(1, 18))]
-    outs = {}
-    for mode in ("paged", "contiguous"):
-        eng = _make_engine(model, cache=mode)
-        try:
-            seqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
-            outs[mode] = [eng.collect(s, timeout=120)["completion"]
-                          for s in seqs]
-        finally:
-            eng.stop()
-    # paged gathers its pages into the same [B, H, S, dh] attention
-    # view the contiguous cache holds natively: bitwise-identical
-    assert outs["paged"] == outs["contiguous"]
-    for p, got in zip(prompts, outs["paged"]):
+    eng = _make_engine(model)
+    try:
+        seqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        outs = [eng.collect(s, timeout=120)["completion"] for s in seqs]
+    finally:
+        eng.stop()
+    for p, got in zip(prompts, outs):
         assert got == _expected(model, p, 6)
 
 
@@ -320,7 +316,7 @@ def test_engine_stats_shape(model):
                 "prefill_tokens", "prefill_scanned_tokens"):
         assert key in st, key
     assert st["active"] == 0 and st["accepting"]
-    assert eng.cache_mode == "paged"
+    assert eng._census_report()["cache"] == "paged"
     assert st["requests"] == 1 and st["tokens"] == 4
     assert st["ttft_p99_s"] > 0
     assert eng.phase_ring()                      # phases were recorded
@@ -563,32 +559,126 @@ def test_serve_knobs_resolve_from_env(monkeypatch):
 
     monkeypatch.setenv("RAY_TPU_SERVE_MAX_SLOTS", "3")
     monkeypatch.setenv("RAY_TPU_SERVE_PAGE_SIZE", "4")
-    monkeypatch.setenv("RAY_TPU_SERVE_GEN_CACHE_CAP", "2")
-    monkeypatch.setenv("RAY_TPU_SERVE_ENGINE", "contiguous")
+    monkeypatch.setenv("RAY_TPU_SERVE_PREFILL_BUCKET", "16")
     monkeypatch.delenv("RAY_TPU_SYSTEM_CONFIG", raising=False)
     c = Config()
     assert c.serve_max_slots == 3
     assert c.serve_page_size == 4
-    assert c.serve_gen_cache_cap == 2
-    assert c.serve_engine == "contiguous"
+    assert c.serve_prefill_bucket == 16
     assert c.is_set("serve_max_slots")
     assert not c.is_set("serve_queue_cap")       # default untouched
 
 
-def test_llm_impl_reads_serve_knobs(monkeypatch, model):
+def _impl(monkeypatch, **kw):
+    """A directly built deployment body on the `model` fixture's weights
+    (nano, f32, PRNGKey(0)), with no system config pinned."""
     from ray_tpu._private import config as _c
     from ray_tpu.serve.llm import _LLMServerImpl
 
-    monkeypatch.setenv("RAY_TPU_SERVE_GEN_CACHE_CAP", "3")
-    monkeypatch.setenv("RAY_TPU_SERVE_ENGINE", "contiguous")
     monkeypatch.delenv("RAY_TPU_SYSTEM_CONFIG", raising=False)
     monkeypatch.setattr(_c, "_current", None)    # un-pin any system cfg
-    srv = _LLMServerImpl(preset="nano", max_seq=MAX_SEQ)
-    assert srv._gen_cache_cap == 3
-    assert srv._engine_mode == "contiguous"
-    # bind-time engine= beats the env knob
-    srv2 = _LLMServerImpl(preset="nano", max_seq=MAX_SEQ,
-                          engine="static")
-    assert srv2._engine_mode == "static"
+    kw.setdefault("engine_kwargs", dict(max_slots=4, page_size=8,
+                                        prefill_bucket=8))
+    return _LLMServerImpl(preset="nano", max_seq=MAX_SEQ,
+                          cfg_kwargs={"dtype": jnp.float32}, **kw)
+
+
+def _stop(srv):
+    if srv._engine is not None:
+        srv._engine.stop()
+
+
+def test_llm_impl_reads_serve_knobs(monkeypatch, model):
+    monkeypatch.setenv("RAY_TPU_SERVE_MAX_SLOTS", "3")
+    monkeypatch.setenv("RAY_TPU_SERVE_PAGE_SIZE", "4")
+    srv = _impl(monkeypatch, engine_kwargs={})
+    try:
+        eng = srv._get_engine()
+        assert (eng.max_slots, eng.page_size) == (3, 4)
+    finally:
+        _stop(srv)
+    # bind-time engine_kwargs beat the env knobs
+    srv2 = _impl(monkeypatch, engine="paged",
+                 engine_kwargs={"max_slots": 2})
+    try:
+        eng = srv2._get_engine()
+        assert (eng.max_slots, eng.page_size) == (2, 4)
+    finally:
+        _stop(srv2)
     with pytest.raises(ValueError):
-        _LLMServerImpl(preset="nano", max_seq=MAX_SEQ, engine="bogus")
+        _impl(monkeypatch, engine="bogus")
+
+
+# ---------------------------------------------------------------------------
+# one served mode
+
+
+@pytest.mark.parametrize("mode", ["static", "contiguous"])
+def test_llm_impl_refuses_the_removed_modes(monkeypatch, mode):
+    with pytest.raises(ValueError, match="static.*contiguous"):
+        _impl(monkeypatch, engine=mode)
+
+
+def test_engine_takes_no_cache_argument(model):
+    cfg, params = model
+    with pytest.raises(TypeError):
+        ContinuousEngine(gpt, cfg, params, cache="contiguous")
+
+
+def test_llm_impl_ignores_the_removed_engine_flag(monkeypatch, model):
+    """RAY_TPU_SERVE_ENGINE used to pick the served mode; a leftover
+    setting changes nothing: the stream comes from the engine."""
+    monkeypatch.setenv("RAY_TPU_SERVE_ENGINE", "static")
+    srv = _impl(monkeypatch)
+    try:
+        toks = list(srv.stream_tokens(PROMPT, max_new_tokens=6))
+        st = srv.engine_stats()
+    finally:
+        _stop(srv)
+    assert toks == _expected(model, PROMPT, 6)
+    assert st is not None and st["requests"] == 1 and st["steps"] >= 6
+
+
+def test_llm_impl_call_returns_the_models_greedy_tokens(monkeypatch, model):
+    """The request/response route on a directly built body (no cluster):
+    `tokens`, `completion` and `batch_size` of one request alone."""
+    srv = _impl(monkeypatch)
+    try:
+        got = asyncio.run(srv({"tokens": PROMPT, "max_new_tokens": 7}))
+    finally:
+        _stop(srv)
+    want = _expected(model, PROMPT, 7)
+    assert got["completion"] == want
+    assert got["tokens"] == PROMPT + want
+    assert got["batch_size"] == 1
+
+
+@pytest.mark.parametrize("route", ["call", "stream_tokens"])
+def test_llm_impl_varied_requests_compile_nothing(monkeypatch, model, route):
+    """What a replica compiles is bounded by construction: one step
+    program and one prefill program a padded length, whatever
+    `max_new_tokens`, `temperature` and `top_k` its requests carry."""
+    from ray_tpu.telemetry import device as devtel
+
+    srv = _impl(monkeypatch)
+
+    def ask(max_new, temperature, top_k):
+        if route == "call":
+            out = asyncio.run(srv({
+                "tokens": PROMPT, "max_new_tokens": max_new,
+                "temperature": temperature, "top_k": top_k, "seed": 3}))
+            return out["completion"]
+        return list(srv.stream_tokens(PROMPT, max_new, temperature, 3,
+                                      top_k))
+
+    try:
+        ask(4, 0.0, None)                        # warm-up
+        counts0 = devtel.get_ledger().counts()
+        for i in range(12):
+            t, k = 0.25 * (i % 4), (None, 5, 17)[i % 3]
+            assert ask(3 + i, t, k) == _expected(
+                model, PROMPT, 3 + i, temperature=t, seed=3, top_k=k)
+        assert devtel.get_ledger().compiles_since(counts0) == {}
+        assert set(srv._engine._fns) == {"step", "setrow", ("prefill", 8)}
+    finally:
+        _stop(srv)

@@ -28,9 +28,8 @@ from ray_tpu.telemetry import device as devtel
 
 MAX_SEQ, PS, BUCKET, CHUNK = 64, 8, 8, 8
 
-# (module, cache mode): gpt serves both layouts, cohere2_moe pages only
-ENGINES = {"gpt-paged": (gpt, "paged"), "gpt-contiguous": (gpt, "contiguous"),
-           "cohere2_moe-paged": (cm, "paged")}
+# the served models (both through the paged arena)
+ENGINES = {"gpt-paged": gpt, "cohere2_moe-paged": cm}
 
 
 @pytest.fixture(scope="module")
@@ -43,9 +42,9 @@ def models():
 
 
 def _engine(models, which, **kw):
-    mod, mode = ENGINES[which]
+    mod = ENGINES[which]
     cfg, params = models[mod]
-    defaults = dict(cache=mode, max_slots=3, page_size=PS,
+    defaults = dict(max_slots=3, page_size=PS,
                     max_total=MAX_SEQ, prefill_bucket=BUCKET)
     defaults.update(kw)
     return ContinuousEngine(mod, cfg, params, **defaults)
@@ -75,19 +74,15 @@ def _operands(eng, program):
     eng._ensure_device_state()
     i32 = np.int32
     if program == "step":
-        tabs = eng._ptabs if eng._allocs else eng._ptab
         return "step", (eng._params, eng._cache, eng._logits,
-                        eng._toks_keys, eng._temps, eng._topks, tabs,
-                        eng._pos), (eng._cache, eng._logits)
+                        eng._toks_keys, eng._temps, eng._topks,
+                        eng._ptabs, eng._pos), (eng._cache, eng._logits)
     if program == "prefill":
         chunk = np.zeros(BUCKET, np.int32)
-        if eng._allocs:
-            rows = {k: np.zeros(w, np.int32)
-                    for k, w in eng._widths.items()}
-            args = (eng._params, eng._cache, chunk, rows, i32(0), i32(4))
-        else:
-            args = (eng._params, eng._cache, chunk, i32(0), i32(4), i32(1))
-        return ("prefill", BUCKET), args, eng._cache
+        rows = {k: np.zeros(w, np.int32) for k, w in eng._widths.items()}
+        return (("prefill", BUCKET),
+                (eng._params, eng._cache, chunk, rows, i32(0), i32(4)),
+                eng._cache)
     if program == "copy_page":
         return "copy_page", (eng._cache, i32(2), i32(1)), eng._cache
     row = jnp.zeros((eng._cfg.vocab_size,), jnp.float32)
@@ -150,7 +145,7 @@ def test_engine_calls_consume_their_state_and_go_on(models, which):
         out = seq.result.result(timeout=0)["completion"]
     finally:
         eng.stop()
-    assert out == _expected(models, ENGINES[which][0], prompt, out)
+    assert out == _expected(models, ENGINES[which], prompt, out)
 
 
 # -- (c) a program that fails after taking its input --------------------------
@@ -184,7 +179,7 @@ def test_failed_program_costs_the_requests_in_flight_not_the_engine(
             assert alloc.used_pages == 0 and alloc.reserved == 0
     finally:
         eng.stop()
-    assert out == _expected(models, ENGINES[which][0], prompt, out)
+    assert out == _expected(models, ENGINES[which], prompt, out)
 
 
 # -- (d) the tokens of a mixed batch are the model's --------------------------
@@ -196,9 +191,8 @@ def test_mixed_batch_greedy_tokens_are_the_models(models, which):
     exact duplicate (where pages are shared, the duplicate takes a copy
     of the last one to write into), a prompt prefilled in four chunks, and
     a plain short one."""
-    mod, mode = ENGINES[which]
-    eng = _engine(models, which, max_slots=4,
-                  **({"prefill_chunk": CHUNK} if mode == "paged" else {}))
+    mod = ENGINES[which]
+    eng = _engine(models, which, max_slots=4, prefill_chunk=CHUNK)
     twice = _tokens(2 * PS, seed=4)
     prompts = [twice, list(twice), _tokens(29, seed=5), _tokens(5, seed=6)]
     try:
@@ -207,9 +201,8 @@ def test_mixed_batch_greedy_tokens_are_the_models(models, which):
         st = eng.engine_stats()
     finally:
         eng.stop()
-    if eng._share and eng._allocs:
+    if eng._share:
         assert st["cow_copies"] == 1 and st["shared_pages"] == 1
-    if mode == "paged":
-        assert seqs[2].chunks == 4 and st["chunks"] > st["prefills"]
+    assert seqs[2].chunks == 4 and st["chunks"] > st["prefills"]
     for p, out in zip(prompts, outs):
         assert len(out) == 9 and out == _expected(models, mod, p, out)

@@ -1,4 +1,4 @@
-"""LLM serving (serve/llm.py): batched KV-cache generation + token
+"""LLM serving (serve/llm.py): continuous-batching generation + token
 streaming behind a Serve deployment, on the nano GPT config.
 
 Reference shape: the reference integrates an external engine into
@@ -53,13 +53,13 @@ def test_llm_handle_completion_matches_direct(serve_instance):
 def test_llm_concurrent_requests_batch_together(serve_instance):
     h = serve.run(LLMServer().bind(preset="nano", max_seq=256),
                   name="llm_b", route_prefix=None)
-    # warm the compile cache so the batch window isn't serialized by it
+    # warm the programs so the six land in the engine's slots together
     h.remote({"tokens": PROMPT, "max_new_tokens": 4}).result(timeout_s=180)
     rs = [h.remote({"tokens": PROMPT, "max_new_tokens": 4})
           for _ in range(6)]
     results = [r.result(timeout_s=180) for r in rs]
-    # same shape+params requests fired together: at least one got
-    # micro-batched with a peer (first may run alone while compiling)
+    # requests fired together: at least one shared the slot batch with a
+    # peer (batch_size is the most slots occupied while it decoded)
     assert max(r["batch_size"] for r in results) >= 2
     assert all(r["tokens"] == results[0]["tokens"] for r in results)
     serve.delete("llm_b")
@@ -71,7 +71,7 @@ def test_llm_streaming_tokens(serve_instance):
     toks = list(h.options(stream=True).remote(
         {"stream": True, "tokens": PROMPT, "max_new_tokens": 6}))
     assert len(toks) == 6
-    # streamed greedy tokens == batched greedy completion
+    # streamed greedy tokens == the request/response route's completion
     full = h.remote({"tokens": PROMPT, "max_new_tokens": 6}).result(
         timeout_s=180)
     assert toks == full["completion"]
@@ -100,21 +100,3 @@ def test_llm_http_endpoint_and_stream_route(serve_instance):
     assert [d["token"] for d in lines] == out["completion"]
     serve.delete("llm_http")
     serve.delete("llm_http-stream")
-
-
-def test_llm_compile_cache_is_bounded():
-    """Every jitted variant a replica builds (generate, prefill, stream
-    step, sampler) goes through one LRU-bounded cache — a long-lived
-    replica facing varied request shapes must not grow compile-cache
-    memory without limit."""
-    from ray_tpu.serve.llm import _LLMServerImpl
-
-    srv = _LLMServerImpl(preset="nano", max_seq=128)
-    cap = srv._gen_cache_cap
-    for i in range(cap * 3):
-        srv._gen_fn(max_new=4 + i, temperature=0.0, top_k=None,
-                    max_seq=128)
-        srv._stream_step_fn(0.5 + i, None, 128)
-    assert len(srv._gen_cache) <= cap
-    # LRU: the most recent entries survive
-    assert (4 + cap * 3 - 1, 0.0, None, 128) in srv._gen_cache

@@ -175,7 +175,7 @@ def test_same_bucket_other_start_and_length_compiles_nothing(model):
     alone: `start`, `last_idx` and the page-table row are operands."""
     cfg, params = model
     mark = devtel.get_ledger().counts()
-    eng = ContinuousEngine(gpt, cfg, params, cache="paged", max_slots=4,
+    eng = ContinuousEngine(gpt, cfg, params, max_slots=4,
                            page_size=PS, prefill_bucket=8)
     try:
         long = list(range(100, 120))           # 20 tokens, bucket 24
