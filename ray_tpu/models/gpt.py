@@ -69,6 +69,11 @@ class GPTConfig:
     z_loss: float = 1e-4
     tie_embeddings: bool = True
     num_microbatches: Optional[int] = None  # pp microbatches; default = pp
+    # key positions one loop turn of the paged serve programs' attention
+    # gathers and scores (whole pages of the KV arena).  One value is in
+    # use, chosen from a sweep on the chip (PERF.md section 5); no
+    # engine argument, flag or environment variable sets another.
+    kv_block: int = 128
 
     @classmethod
     def gpt2_small(cls, **kw):
@@ -697,20 +702,22 @@ def _decode_hidden_fast(view, cfg: GPTConfig, kcache, vcache, pos, toks):
 # Slot-batch decoding (continuous batching).  The serving engine keeps a
 # fixed-shape batch of B "slots"; sequences join at prefill and leave at
 # EOS/max-tokens, so every slot sits at its OWN position.  Two cache
-# layouts share the identical attention math:
+# layouts apply the same causal mask by position and take the same sums:
 #
-#   * contiguous slot cache [L, B, H, S, dh] — one row per slot (kept for
-#     bitwise parity tests against the paged path);
+#   * contiguous slot cache [L, B, H, S, dh] — one row per slot, every
+#     position scored at once (_slot_attention): the plain recipe, kept
+#     for the tests that hold the paged path to it;
 #   * paged cache: a device arena of fixed-size pages [L, P, ps, H * dh]
-#     plus per-slot page tables gathered inside the decode step.  Page 0
-#     is reserved as the null page: inactive slots write there and their
+#     plus per-slot page tables, read inside the programs block by block
+#     as far as the contexts are live (_paged_attention).  Page 0 is
+#     reserved as the null page: inactive slots write there and their
 #     outputs are discarded host-side, so the compiled step program
 #     never changes shape as sequences come and go.
 #
 # A sequence joins through a prefill program (slot_prefill /
 # paged_prefill): its padded prompt chunk in ONE pass through the layers
-# (_prefill_chunk), T query rows through the _slot_attention that a
-# decode step feeds one row a slot.
+# (_prefill_chunk), T query rows through the attention that a decode
+# step feeds one row a slot.
 #
 # Every program takes the cache of ALL layers and hands it back: it is
 # the layer loop's carry, a layer scatters its new rows at [l, ...] and
@@ -757,11 +764,15 @@ def _slot_qkv(x, layer, cfg: GPTConfig, rope, pos):
 def _slot_attention(q, kc, vc, pos, cfg: GPTConfig):
     """q [B,H,T,dh] at positions pos [B,T] against a per-slot cache view
     kc/vc [B,H,S,dh], masked causally by position (key <= pos[b, t]).
-    This is the ONE attention recipe both cache layouts and both serve
-    programs feed — a decode step is its T = 1 case, a prefill its
-    B = 1 case, and the paged path gathers its pages into exactly this
-    [B,H,S,dh] view, which is what makes paged==contiguous a structural
-    identity rather than a numerical accident."""
+    This is the PLAIN recipe — every position of the view scored at once
+    under the mask, one softmax over all of them — that the contiguous
+    cache's programs run (a decode step is its T = 1 case, a prefill its
+    B = 1 case) and that the paged programs are held to: they apply the
+    same mask and take the same sums block by block over the live part
+    of the page table (_paged_attention), so paged == contiguous is what
+    tests/test_serve_prefill.py and tests/test_serve_live_blocks.py
+    measure (logits within 1e-4 in f32), no longer an identity of the
+    program text."""
     S = kc.shape[2]
     mask = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, S), 3)
             <= pos[:, None, :, None])
@@ -825,15 +836,16 @@ def slot_decode_step(params, cache, tokens, pos, cfg: GPTConfig, rope=None):
 
 
 def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
-                   write, view, cfg: GPTConfig, rope=None):
+                   write, attend, cfg: GPTConfig, rope=None):
     """One pass of a padded prompt chunk through the stack, shared by
     both cache layouts: toks [T] sit at positions start..start+T-1 of
     ONE sequence whose cache holds S positions; logits are taken at row
     `last_idx` (the last REAL prompt token).  Per layer l the chunk's T
     rows of K and V are written with one `write(c, l, rows [T,H,dh],
-    wpos)` a side, then the chunk attends to `view(c, l)` [1,H,S,dh] —
-    whatever the cache held before `start` and itself — through
-    _slot_attention.  kcache/vcache are stacked per layer on axis 0 and
+    wpos)` a side, then the chunk attends to what the cache held before
+    `start` and to itself: `attend(q [1,H,T,dh], kc, vc, l, pos [1,T])`
+    -> [1,H,T,dh], the layout's own reading of layer l under the causal
+    mask by position.  kcache/vcache are stacked per layer on axis 0 and
     are the layer loop's carry: `write` scatters into them.
 
     Pad rows sit behind every real token, so no real query sees them;
@@ -856,7 +868,7 @@ def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
         q, k, v = _slot_qkv(x, layer, cfg, rope, pos)      # [1, H, T, dh]
         kc = write(kc, l, jnp.swapaxes(k[0], 0, 1).astype(kc.dtype), wpos)
         vc = write(vc, l, jnp.swapaxes(v[0], 0, 1).astype(vc.dtype), wpos)
-        o = _slot_attention(q, view(kc, l), view(vc, l), pos, cfg)
+        o = attend(q, kc, vc, l, pos)
         return (_attn_out_and_mlp(x, o, layer, cfg), kc, vc), None
 
     # a rolled scan: at T rows a layer the matmuls dwarf the per-op
@@ -882,9 +894,13 @@ def slot_prefill(params, cache, toks, start, last_idx, slot,
     def write(c, l, rows, wpos):               # c [L, B, H, S, dh]
         return c.at[l, slot, :, wpos, :].set(rows, mode="drop")
 
+    def attend(q, kc, vc, l, pos):             # the slot's own row, whole
+        return _slot_attention(q, kc[l, slot][None], vc[l, slot][None],
+                               pos, cfg)
+
     logits, kc, vc = _prefill_chunk(
         params, cache["k"], cache["v"], toks, start, last_idx, S, write,
-        lambda c, l: c[l, slot][None], cfg, rope)
+        attend, cfg, rope)
     return logits, {"k": kc, "v": vc}
 
 
@@ -915,20 +931,115 @@ def init_paged_cache(cfg: GPTConfig, num_pages, page_size: int
     program converted the whole arena on the way in and on the way out).
     Page 0 is the reserved null page (inactive-slot writes land there;
     the allocator never hands it out).  The programs below scatter their
-    rows into it and gather a slot's pages out of it where it stands."""
+    rows into it and read a slot's live pages out of it where it stands,
+    a block of pages a loop turn (_paged_attention)."""
     num_pages = _only(num_pages)
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def _gather_pages(pages, l, ptab, H: int):
-    """Layer l of the arena [L, P, ps, H * dh] through page tables ptab
-    [B, maxp] -> the contiguous per-slot view [B, H, maxp * ps, dh]."""
-    B, maxp = ptab.shape
-    ps = pages.shape[2]
-    g = pages[l, ptab]                         # [B, maxp, ps, H * dh]
-    return jnp.swapaxes(g.reshape(B, maxp * ps, H, -1), 1, 2)
+def kv_block_pages(cfg: GPTConfig, page_size: int, max_pages: int) -> int:
+    """Pages of a slot's table that one loop turn of _paged_attention
+    reads: cfg.kv_block positions' worth, whole pages, at most the
+    table."""
+    return max(1, min(cfg.kv_block // page_size, max_pages))
+
+
+def _live_blocks(last_pos, block: int):
+    """Key blocks of `block` positions from 0 to the one that holds
+    position `last_pos` (a number on the host, a traced scalar in a
+    program: the two count alike)."""
+    return last_pos // block + 1
+
+
+def step_kv_read(cfg: GPTConfig, pos, page_size: int, max_pages: int):
+    """The host's side of a decode step's trip count, for the engine's
+    iteration record: (key positions a layer's attention reads in a step
+    at per-slot positions `pos` [B] — slots x live blocks x block — and
+    the span of the page tables, slots x max_pages x page_size, which
+    is what a gather of every slot's whole table read)."""
+    block = kv_block_pages(cfg, page_size, max_pages) * page_size
+    span = max_pages * page_size
+    n = _live_blocks(min(int(pos.max()), span - 1), block)
+    return len(pos) * n * block, len(pos) * span
+
+
+def _live_table(ptab, last_pos, ps: int, cfg: GPTConfig):
+    """What _paged_attention's loop walks: page tables ptab [B, maxp]
+    padded with the null page to whole blocks [B, n * npb], the pages a
+    block npb, and the blocks that are live up to position `last_pos`
+    (a traced scalar)."""
+    maxp = ptab.shape[1]
+    npb = kv_block_pages(cfg, ps, maxp)
+    return (jnp.pad(ptab, ((0, 0), (0, -maxp % npb))), npb,
+            _live_blocks(last_pos, npb * ps))
+
+
+def _paged_attention(q, kc, vc, l, live, pos, cfg: GPTConfig):
+    """q [B,H,T,dh] at positions pos [B,T] against layer l of the page
+    arena kc/vc [L,P,ps,H*dh] through `live` (_live_table: the block
+    table, npb, n_blocks), causal by position (key <= pos[b, t]) ->
+    [B,H,T,dh].  The ONE attention of both paged programs: a decode
+    step is its T = 1 case, a prefill its B = 1 case.
+
+    The keys are read where they stand and only as far as they are
+    live: a loop of n_blocks turns (traced: up to the block that holds
+    the furthest query position) gathers npb pages a slot a turn,
+    scores them and folds them into an online softmax — the mask and
+    the sums of _slot_attention, taken block by block (statistics f32,
+    the values' weights cfg.dtype).  A block is left
+    out only if every key in it lies past every query.
+
+    A gathered block stays [B, npb*ps, H*dh], the arena's own row: dh
+    = 64 as a minor dimension pads to the chip's 128 lanes, so nothing
+    of a block's size is split by heads.  The heads are contracted on
+    the unsplit row instead: the query is laid out block-diagonally
+    ([B, T*H, H*dh], head h's dh values in its own columns, zeros
+    elsewhere), so one product with the block gives every head's scores;
+    the weighted values come out [B, T*H, H*dh] and each head keeps its
+    own columns.  K goes in as stored (bf16 x bf16 products are exact
+    in the f32 they accumulate in), never as an f32 copy."""
+    B, H, T, dh = q.shape
+    tab, npb, n_blocks = live
+    HD, ps = H * dh, kc.shape[2]
+    Sb = npb * ps
+    own = (jnp.arange(HD, dtype=jnp.int32)[None] // dh
+           == jnp.arange(H, dtype=jnp.int32)[:, None])     # [H, HD]
+    qrow = jnp.swapaxes(q, 1, 2).reshape(B, T, 1, HD)
+    qbd = jnp.where(own, qrow, 0).reshape(B, T * H, HD)
+    scale = dh ** -0.5
+
+    def body(i, carry):
+        m, den, acc = carry                    # [B,T,H] x2, [B,T,HD], f32
+        t = jax.lax.dynamic_slice_in_dim(tab, i * npb, npb, 1)
+        kb = kc[l, t].reshape(B, Sb, HD)
+        vb = vc[l, t].reshape(B, Sb, HD).astype(cfg.dtype)
+        s = jnp.einsum("bqk,bsk->bqs", qbd, kb,
+                       preferred_element_type=jnp.float32)
+        s = s.reshape(B, T, H, Sb) * scale
+        kpos = i * Sb + jnp.arange(Sb, dtype=jnp.int32)
+        ok = (kpos <= pos[:, :, None])[:, :, None]         # [B,T,1,Sb]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(ok, s, -1e30), axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        den = den * corr + jnp.sum(p, axis=-1)
+        pv = jnp.einsum("bqs,bsk->bqk",
+                        p.reshape(B, T * H, Sb).astype(cfg.dtype), vb,
+                        preferred_element_type=jnp.float32)
+        # acc * corr + pv, head h in its own columns of the row
+        acc = jnp.sum(jnp.where(own, acc[:, :, None] * corr[..., None]
+                                + pv.reshape(B, T, H, HD), 0.0), axis=2)
+        return m_new, den, acc
+
+    init = (jnp.full((B, T, H), -1e30, jnp.float32),
+            jnp.zeros((B, T, H), jnp.float32),
+            jnp.zeros((B, T, HD), jnp.float32))
+    _, den, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    # every query sees key 0, so den > 0
+    o = jnp.sum(jnp.where(own, acc[:, :, None] / den[..., None], 0.0),
+                axis=2).astype(cfg.dtype)
+    return jnp.swapaxes(o.reshape(B, T, H, dh), 1, 2)
 
 
 def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
@@ -936,10 +1047,12 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     """One decode position for every slot against the page arena:
     tokens [B], ptab [B, max_pages] (page ids in sequence order; unused
     entries 0), pos [B] -> (hidden [B, D], kpages, vpages).  Writes
-    scatter into each slot's current page; attention gathers the slot's
-    pages into the contiguous [B, H, S, dh] view and runs the shared
-    _slot_attention recipe."""
-    B, H = tokens.shape[0], cfg.n_heads
+    scatter into each slot's current page; attention reads the pages
+    where they stand, block by block up to the block that holds the
+    furthest slot's position (_paged_attention): the same mask and the
+    same sums as the contiguous cache's _slot_attention, to which the
+    tests hold it."""
+    B = tokens.shape[0]
     ps = kpages.shape[2]
     S = ptab.shape[1] * ps
     pos = jnp.minimum(pos, S - 1)
@@ -951,6 +1064,7 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     x = _slot_embed(params, tokens[:, None], qpos, cfg)     # [B, 1, D]
     pidx = jnp.take_along_axis(ptab, (pos // ps)[:, None], axis=1)[:, 0]
     poff = pos % ps
+    live = _live_table(ptab, jnp.max(pos), ps, cfg)
 
     def block(carry, inp):
         x, kc, vc = carry                      # kc/vc [L, P, ps, H * dh]
@@ -958,8 +1072,7 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
         q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)      # [B, H, 1, dh]
         kc = kc.at[l, pidx, poff].set(k.reshape(B, -1).astype(kc.dtype))
         vc = vc.at[l, pidx, poff].set(v.reshape(B, -1).astype(vc.dtype))
-        o = _slot_attention(q, _gather_pages(kc, l, ptab, H),
-                            _gather_pages(vc, l, ptab, H), qpos, cfg)
+        o = _paged_attention(q, kc, vc, l, live, qpos, cfg)
         return (_attn_out_and_mlp(x, o, layer, cfg), kc, vc), None
 
     (x, k_new, v_new), _ = jax.lax.scan(
@@ -989,7 +1102,8 @@ def paged_prefill(params, cache, toks, ptab_row, start, last_idx,
     scatter into page ptab_row[p // ps] at offset p % ps: pad rows reach
     this sequence's own later pages or, past its allocation and past
     the end of the table, the null page 0 — never another sequence's.
-    Returns (logits [V], cache)."""
+    The chunk attends through _paged_attention, block by block up to
+    the block that holds its last row.  Returns (logits [V], cache)."""
     ptab_row = _only(ptab_row)
     ps = cache["k"].shape[2]
     S = ptab_row.shape[0] * ps
@@ -998,10 +1112,15 @@ def paged_prefill(params, cache, toks, ptab_row, start, last_idx,
         pidx = ptab_row.at[wpos // ps].get(mode="fill", fill_value=0)
         return c.at[l, pidx, wpos % ps].set(rows.reshape(rows.shape[0], -1))
 
+    live = _live_table(ptab_row[None],
+                       jnp.minimum(start + toks.shape[0], S) - 1, ps, cfg)
+
+    def attend(q, kc, vc, l, pos):             # up to the chunk's last row
+        return _paged_attention(q, kc, vc, l, live, pos, cfg)
+
     logits, kc, vc = _prefill_chunk(
         params, cache["k"], cache["v"], toks, start, last_idx, S, write,
-        lambda c, l: _gather_pages(c, l, ptab_row[None], cfg.n_heads),
-        cfg, rope)
+        attend, cfg, rope)
     return logits, {"k": kc, "v": vc}
 
 

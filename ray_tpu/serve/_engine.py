@@ -427,6 +427,13 @@ class ContinuousEngine:
         self._stat_names = tuple(getattr(gpt_mod, "STEP_STATS", ()))
         self._stat_keys = self._stat_names + tuple(
             "chunk_" + n for n in self._stat_names)
+        # a model whose step reads its page tables only as far as the
+        # contexts are live says how far, from the positions the engine
+        # thread already holds (`step_kv_read` of its module: no fetch):
+        # `kv_read` / `kv_span` of an iteration's record
+        self._kv_read = getattr(gpt_mod, "step_kv_read", None)
+        if self._kv_read is not None:
+            self._stat_keys += ("kv_read", "kv_span")
         self._fns: Dict[Any, Any] = {}   # bounded by construction: one
         # step program + one prefill per padded-length bucket + setrow +
         # copy_page
@@ -1082,6 +1089,12 @@ class ContinuousEngine:
                 self._toks_keys[i] = s.keys[len(s.generated)]
                 self._grow_windows(s, int(self._pos[i]),
                                    int(self._pos[i]) + 1)
+            if self._kv_read is not None:
+                read, span = self._kv_read(self._cfg, self._pos,
+                                           self.page_size,
+                                           self.max_pages_per_seq)
+                self._stats["kv_read"] += read
+                self._stats["kv_span"] += span
             td = time.perf_counter()
             toks, self._logits, self._cache, stats = self._fn("step")(
                 self._params, self._cache, self._logits, self._toks_keys,

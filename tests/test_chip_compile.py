@@ -14,6 +14,7 @@ steered from the test (`impl="pallas"`, `attention_impl="pallas"`).
 
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -205,16 +206,57 @@ def _assert_arena_in_place(compiled, cache):
     assert not moved, moved
 
 
-def test_serve_step_compiles(serve_engine, one_chip):
+@pytest.fixture(scope="module")
+def serve_step_compiled(serve_engine, one_chip):
     eng, cfg, params, cache = serve_engine
     B = eng.max_slots
     s = lambda shape, dt: _sds(shape, dt, one_chip)
-    compiled = eng._fn("step").lower(
+    return eng._fn("step").lower(
         params, cache, s((B, cfg.vocab_size), jnp.float32),
         s((B, 2), jnp.uint32), s((B,), jnp.float32), s((B,), jnp.int32),
         s((B, eng.max_pages_per_seq), jnp.int32),
         s((B,), jnp.int32)).compile()
-    _assert_arena_in_place(compiled, cache)
+
+
+def test_serve_step_compiles(serve_engine, serve_step_compiled):
+    _assert_arena_in_place(serve_step_compiled, serve_engine[3])
+
+
+def _shapes(text):
+    """Every array type the text names, as (dtype, dims)."""
+    return {(dt, tuple(int(d) for d in dims.split(",")))
+            for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]+)\]", text)}
+
+
+def test_serve_step_reads_live_blocks_where_they_stand(serve_engine,
+                                                       serve_step_compiled):
+    """The step's attention as the chip's compiler leaves it: a loop a
+    layer whose trip count is an operand's doing (the contexts' live
+    length), whose turn gathers one block of pages a slot as the arena
+    keeps them — bf16, [.., H*dh] — and nothing the size of `slots x
+    max_total` positions, no f32 copy of a block of keys and no block
+    split by heads (dh = 64 as a minor dimension pads to 128 lanes)."""
+    from ray_tpu.models import gpt
+
+    eng, cfg, params, cache = serve_engine
+    B, ps, maxp = eng.max_slots, eng.page_size, eng.max_pages_per_seq
+    H, dh, HD = cfg.n_heads, cfg.d_head, cfg.n_heads * cfg.d_head
+    npb = gpt.kv_block_pages(cfg, ps, maxp)
+    assert npb * ps == cfg.kv_block < eng.max_total
+    text = serve_step_compiled.as_text()
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert len(loops) >= cfg.n_layers
+    assert not [ln[:160] for ln in loops if "known_trip_count" in ln]
+    shapes = _shapes(text)
+    assert ("bf16", (B * npb, ps, HD)) in shapes       # a turn's gather
+    wide = sorted(x for x in shapes
+                  if B in x[1] and eng.max_total in x[1])
+    assert not wide, wide
+    block = {(B * npb, ps, HD), (B, npb * ps, HD), (B, npb, ps, HD)}
+    assert not [x for x in shapes if x[0] == "f32" and x[1] in block]
+    split = [x for x in shapes if x[1][0] in (B, B * npb)
+             and x[1][-2:] == (H, dh) and np.prod(x[1]) >= B * npb * ps * HD]
+    assert not split, split
 
 
 @pytest.mark.parametrize("bucket", [32, 128])
