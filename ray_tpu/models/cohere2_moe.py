@@ -44,11 +44,11 @@ from ray_tpu.ops.layers import apply_rope_interleaved, swiglu
 from ray_tpu.ops.moe import held_expert_ffn, route_sigmoid_topk
 
 from .gpt import (_attn_out, _norm, _qkv_of_normed, _slot_embed,
-                  _unembed_table, sample_logits)
+                  _unembed_table, sample_logits, serve_view as _cast_leaves)
 
 __all__ = ["Cohere2MoEConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
-           "sample_logits", "STEP_STATS"]
+           "sample_logits", "serve_view", "STEP_STATS"]
 
 # what a serve program returns beside logits and cache, in this order
 # (f32 scalars, summed over the layers): token-expert pairs that fell on
@@ -386,3 +386,16 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx, cfg):
         (start + t)[None], (t <= last_idx)[None], cfg)
     x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
     return _logits(params, x, cfg), cache, stats
+
+
+# the leaves the programs cast to cfg.dtype where they use them (gpt's
+# shared helpers, _ffn's shared experts, held_expert_ffn); the router and
+# the norms are used as they are kept
+_SERVE_CAST = frozenset({"embed", "wq", "wk", "wv", "wo", "wg", "wu", "wd",
+                         "shared_gate", "shared_up", "shared_down"})
+
+
+def serve_view(params, cfg: Cohere2MoEConfig):
+    """gpt.serve_view over this model's leaves: a tree kept in cfg.dtype
+    (the published configuration's) comes back as the same arrays."""
+    return _cast_leaves(params, cfg, _SERVE_CAST)

@@ -1132,6 +1132,36 @@ def copy_page(cache, dst, src):
             "v": cache["v"].at[:, dst].set(cache["v"][:, src])}
 
 
+# the leaves the serve programs cast with `.astype(cfg.dtype)` where they
+# use them (_slot_embed, _qkv_of_normed, _attn_out, _attn_out_and_mlp,
+# _unembed_table); the norms' scales and biases are used as they are kept
+_SERVE_CAST = frozenset({
+    "embed", "pos_embed", "unembed", "wq", "wk", "wv", "wo", "wq_b", "wk_b",
+    "wv_b", "wo_b", "mlp_in", "mlp_in_b", "mlp_out", "mlp_out_b", "mlp_gate",
+    "mlp_up"})
+
+
+def serve_view(params, cfg, cast=_SERVE_CAST):
+    """The tree a serving engine hands to paged_decode_step /
+    paged_prefill in place of `params`, made once at its set-up: every
+    leaf named in `cast` goes through the `astype(cfg.dtype)` the
+    programs apply at each use, so inside them that cast is the identity
+    and a decode step reads its weights in the dtype it multiplies in (a
+    float32 tree served in bf16 was read whole, at twice the bytes, and
+    cast again in every step and every prefill).  The operands of every
+    product are bit for bit what the programs computed for themselves.
+    Every other leaf, and a leaf already in cfg.dtype, is the same array:
+    nothing is copied, so a tree kept in cfg.dtype costs no memory."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def leaf(path, w):
+        name = next((k.key for k in reversed(path)
+                     if isinstance(k, jax.tree_util.DictKey)), None)
+        return w.astype(dt) if name in cast and w.dtype != dt else w
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
 def sample_logits(logits, key, temperature: float = 0.0,
                   top_k: Optional[int] = None, dtype=jnp.int32):
     """The ONE sampling recipe (greedy argmax at temperature 0, else

@@ -369,7 +369,14 @@ class ContinuousEngine:
         import numpy as np
 
         self._jax, self._np, self._gpt = jax, np, gpt_mod
-        self._cfg, self._params = cfg, params
+        # the programs read the model's serve view of the caller's tree,
+        # made here once (gpt_mod.serve_view: the leaves they would cast
+        # at every use, cast; every other leaf the caller's own array)
+        self._cfg, self._params = cfg, gpt_mod.serve_view(params, cfg)
+        leaves = jax.tree_util.tree_leaves(self._params)
+        self._param_stats = {
+            "param_count": sum(int(w.size) for w in leaves),
+            "param_bytes": sum(int(w.nbytes) for w in leaves)}
         self.max_slots = int(max_slots)
         self.page_size = int(page_size)
         self.max_total = int(max_total) or cfg.max_seq
@@ -580,9 +587,12 @@ class ContinuousEngine:
         """Scheduler snapshot for admission control and autoscaling:
         what the router (`accepting`, `retry_after_s`), the controller
         and the autoscaler (`queue_depth`, `active`, `free_pages`,
-        `ttft_p99_s`, `tokens_per_s`) read, plus the running totals —
-        counters and the ring's cumulative sums, so two snapshots give
-        rates and phase shares over any interval."""
+        `ttft_p99_s`, `tokens_per_s`) read, the size of the tree the
+        programs read (`param_count`, `param_bytes`: 2 bytes a parameter
+        where bf16 is served, whatever the caller's tree is kept in),
+        plus the running totals — counters and the ring's cumulative
+        sums, so two snapshots give rates and phase shares over any
+        interval."""
         now = time.perf_counter()
         with self._lock:
             active = sum(1 for s in self._slots if s is not None)
@@ -603,6 +613,7 @@ class ContinuousEngine:
                                     int(0.99 * len(ttfts)))]
             if ttfts else 0.0,
             "tokens_per_s": (toks / span) if span > 0 else 0.0,
+            **self._param_stats,
             **totals,
         }
 

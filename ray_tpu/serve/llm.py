@@ -102,6 +102,9 @@ class _LLMServerImpl:
                       prefill_bucket=c.serve_prefill_bucket,
                       stall_s=c.serve_engine_stall_s)
             kw.update(self._engine_kwargs)
+            # the engine serves its own view of the tree (the model's
+            # serve_view: compute-dtype weights); self._params stays what
+            # the loader delivered
             self._engine = ContinuousEngine(self._gpt, self._cfg,
                                             self._params, **kw)
         return self._engine

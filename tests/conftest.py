@@ -79,7 +79,7 @@ _QUICK_FILES = {
     "test_serve.py", "test_serve_continuous.py", "test_serve_donation.py",
     "test_serve_fault.py",
     "test_serve_prefill.py", "test_serve_live_blocks.py",
-    "test_serve_mixed_pools.py",
+    "test_serve_mixed_pools.py", "test_serve_weights_view.py",
     "test_serve_grpc.py",
     "test_state.py",
     "test_submit_batching.py", "test_telemetry.py", "test_tune.py",

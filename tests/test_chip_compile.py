@@ -169,6 +169,14 @@ def test_int8_mesh_allreduce_fused_compiles(mesh4):
 # -- max_seq=1024) builds them ----------------------------------------------
 
 
+def _serve_view_shapes(gpt, cfg):
+    """What the engine hands its programs, as shapes: the serve view of
+    the f32 tree a loader delivers."""
+    return jax.eval_shape(
+        lambda k: gpt.serve_view(gpt.init(k, cfg), cfg),
+        jax.random.PRNGKey(0))
+
+
 @pytest.fixture(scope="module")
 def serve_engine(one_chip):
     """A ContinuousEngine at the default serving shapes whose programs
@@ -179,8 +187,7 @@ def serve_engine(one_chip):
 
     cfg = gpt.GPTConfig.gpt2_small(max_seq=1024)
     eng = ContinuousEngine(gpt, cfg, None)
-    params = _on(jax.eval_shape(functools.partial(gpt.init, cfg=cfg),
-                                jax.random.PRNGKey(0)), one_chip)
+    params = _on(_serve_view_shapes(gpt, cfg), one_chip)
     cache = _on(jax.eval_shape(functools.partial(
         gpt.init_paged_cache, cfg, eng.num_pages, eng.page_size)), one_chip)
     yield eng, cfg, params, cache
@@ -331,6 +338,82 @@ def test_flash_attention_kernels_carry_their_names(one_chip):
         assert len(named) == 1, (name, len(named))
         assert name in named[0].split(" = ", 1)[0]      # the instruction
         assert operands(named[0]) == n_operands
+
+
+# -- the benchmark's served GPT-2: benchmarks/configs/gpt2-large.json ---------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 32)],
+                         ids=["step", "prefill32"])
+def test_gpt2_large_serve_programs_read_compute_dtype_weights(one_chip, key):
+    """`gpt2-large` keeps float32 parameters and multiplies in bf16.  The
+    engine's programs take the serve view: every weight arrives as bf16,
+    and in the text the chip's compiler leaves nothing reads a float32
+    array of a weight's shape, whole or one layer's, or casts one — so
+    no step and no prefill reads 3.1 GB of float32 to cast it again."""
+    import json
+
+    from benchmarks.lib.modelcfg import gpt_config
+    from ray_tpu.models import gpt
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "gpt2-large.json")) as f:
+        conf = json.load(f)
+    cfg = gpt_config(conf)
+    assert cfg.param_dtype == jnp.float32 and cfg.dtype == jnp.bfloat16
+    eng = ContinuousEngine(gpt, cfg, None, **conf["serve"]["engine_kwargs"])
+    try:
+        view = _serve_view_shapes(gpt, cfg)
+        params = _on(view, one_chip)
+        cache = _on(jax.eval_shape(functools.partial(
+            gpt.init_paged_cache, cfg, eng.num_pages, eng.page_size)),
+            one_chip)
+        B, V, maxp = eng.max_slots, cfg.vocab_size, eng.max_pages_per_seq
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (params, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32), s((B, maxp), jnp.int32),
+                    s((B,), jnp.int32))
+        else:
+            args = (params, cache, s((key[1],), jnp.int32),
+                    s((maxp,), jnp.int32), i32, i32)
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    leaves = jax.tree.leaves(view)
+    n = sum(w.size for w in leaves)
+    assert n == gpt.num_params(cfg) == 774_090_240
+    assert sum(w.size * w.dtype.itemsize for w in leaves) == 1_548_554_240
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):].split("\n", 1)[0]
+    L = cfg.n_layers
+    weights = {w.shape for w in leaves if w.dtype == jnp.bfloat16}
+    assert len(weights) == 9
+    for shape in weights:
+        assert f"bf16[{','.join(map(str, shape))}]" in entry, shape
+    # the matrices, whole and as one layer's slice of a stacked one (with
+    # or without the unit axis a dynamic-slice leaves); the biases are
+    # left out: a norm's scale has their shape and stays f32
+    dims = set()
+    for shape in weights:
+        if shape[0] != L:
+            dims.add(shape)
+        elif np.prod(shape[1:]) > cfg.d_ff:
+            dims |= {shape, shape[1:], (1,) + shape[1:]}
+    assert len(dims) == 2 + 3 * 4          # embed, pos_embed; wq.. mlp_out
+    # neither the program nor a fusion inside it is handed a float32 array
+    # of such a shape or casts what it is handed to one in bf16
+    handed = re.findall(r"= f32\[([\d,]+)\]\S* parameter\(", text)
+    cast = re.findall(r"= bf16\[([\d,]+)\]\S* convert\(%param", text)
+    assert handed
+    bad = sorted({d for d in handed + cast
+                  if tuple(map(int, d.split(","))) in dims})
+    assert not bad, bad
+    assert compiled.memory_analysis().argument_size_in_bytes < 2.1e9
 
 
 # -- the train step ----------------------------------------------------------
