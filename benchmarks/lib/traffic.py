@@ -29,7 +29,10 @@ def _rng(*parts: int) -> np.random.Generator:
 def draw_lengths(spec: Dict, n: int, rng: np.random.Generator) -> np.ndarray:
     """n integer lengths from {"dist": "lognormal", "median", "sigma",
     "min", "max"} or {"dist": "fixed", "value"} or {"dist": "uniform",
-    "min", "max"}."""
+    "min", "max"}.  A lognormal spec may carry "multiple_of": each drawn
+    length is then rounded to the nearest multiple of it that lies inside
+    the clip (the same draws from the generator, so a spec without the key
+    gives what it always gave)."""
     dist = spec["dist"]
     if dist == "fixed":
         return np.full(n, int(spec["value"]), np.int64)
@@ -37,6 +40,10 @@ def draw_lengths(spec: Dict, n: int, rng: np.random.Generator) -> np.ndarray:
         return rng.integers(int(spec["min"]), int(spec["max"]) + 1, n)
     if dist == "lognormal":
         x = rng.lognormal(math.log(spec["median"]), spec["sigma"], n)
+        if "multiple_of" in spec:
+            m = int(spec["multiple_of"])
+            lo, hi = -(-int(spec["min"]) // m) * m, int(spec["max"]) // m * m
+            return np.clip(np.rint(x / m) * m, lo, hi).astype(np.int64)
         return np.clip(np.rint(x), spec["min"], spec["max"]).astype(np.int64)
     raise ValueError(f"unknown length distribution {dist!r}")
 

@@ -109,17 +109,23 @@ def main():
         def sds(shape, dt):
             return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-        params = jax.tree.map(
-            lambda a: sds(a.shape, a.dtype),
-            jax.eval_shape(functools.partial(gpt.init, cfg=cfg),
-                           jax.random.PRNGKey(0)))
-        cshape = (cfg.n_layers, pages, cfg.n_heads, ps, cfg.d_head)
-        cache = {"k": sds(cshape, cfg.dtype), "v": sds(cshape, cfg.dtype)}
+        # what the engine hands its programs: the serve view of the tree
+        # (weights in the compute dtype) and the arena as the model lays
+        # it out
+        def shapes(tree):
+            return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+        params = shapes(jax.eval_shape(
+            lambda k: gpt.serve_view(gpt.init(k, cfg), cfg),
+            jax.random.PRNGKey(0)))
+        cache = shapes(jax.eval_shape(functools.partial(
+            gpt.init_paged_cache, cfg, pages, ps)))
+        arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                          for a in jax.tree.leaves(cache))
         from ray_tpu.serve._engine import ContinuousEngine
 
         eng = ContinuousEngine.__new__(ContinuousEngine)
-        eng._jax, eng._gpt, eng._cfg = jax, gpt, cfg
-        eng._fns, eng.cache_mode = {}, "paged"
+        eng._jax, eng._gpt, eng._cfg, eng._fns = jax, gpt, cfg, {}
         V = cfg.vocab_size
         t0 = time.time()
         step = eng._fn("step")
@@ -130,7 +136,7 @@ def main():
             sds((slots,), jnp.int32)).compile()
         print(json.dumps({"config": args.config, "program": "serve.step",
                           "max_slots": slots, "num_pages": pages,
-                          "arena_bytes": 2 * int(np.prod(cshape)) * 2,
+                          "arena_bytes": arena_bytes,
                           "compile_s": round(time.time() - t0, 1),
                           "per_device": _mem(c)}), flush=True)
         for T in [int(x) for x in args.buckets.split(",")]:

@@ -36,6 +36,18 @@ def test_repo_manifest_is_valid_and_every_cell_resolves(man):
             manifest.BENCH_DIR, "drivers", cell["traffic"]["kind"] + ".py"))
 
 
+def test_nothing_names_a_cell_or_a_traffic_file_that_is_gone():
+    man = manifest.load()
+    files = {f[:-5] for f in os.listdir(os.path.join(manifest.BENCH_DIR,
+                                                     "traffic"))}
+    assert {w["traffic"] for w in man["workloads"]} == files
+    for m in man["end_to_end"] + man["per_layer"]:   # load() checked the names
+        assert m.get("workloads", True), m["name"]     # none left empty
+    text = json.dumps(man)
+    assert '"serve-large-chat"' not in text and '"open-chat"' not in text
+    assert "open-chat" not in files
+
+
 def test_contract_limits(man):
     cells = len(man["workloads"])
     assert sum(w["chips"] == 4 for w in man["workloads"]) <= max(1, cells // 4)

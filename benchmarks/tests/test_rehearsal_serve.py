@@ -19,7 +19,7 @@ RING_METRICS = ("engine.ttft_queue_share.chat",
 def test_traced_serve_rehearsal_prints_the_ring_metrics():
     out = subprocess.run(
         [sys.executable, os.path.join(manifest.BENCH_DIR, "run.py"),
-         "--workload", "serve-large-chat", "--seed", "2147483659",
+         "--workload", "serve-large-chat-loaded", "--seed", "2147483659",
          "--seconds", "3", "--trace", "1", "--rehearse"],
         capture_output=True, text=True, timeout=600, cwd=manifest.ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -31,6 +31,8 @@ def test_traced_serve_rehearsal_prints_the_ring_metrics():
         v = metrics["rehearsal." + name]["value"]
         assert 0.0 <= v < (100.0 if name.endswith("share.chat") else 1e4), name
     assert metrics["rehearsal.engine.prefill_pad_share.chat"]["value"] > 0
+    # the toy's max_total is one block of keys: every step reads all of it
+    assert metrics["rehearsal.model.kv_read_share.chat"]["value"] == 100.0
     assert "rehearsal.engine.decode_step_device_ms.chat" not in metrics
     checks = [json.loads(ln) for ln in out.stdout.splitlines()
               if ln.startswith('{"phase": "checks"')][-1]["checks"]

@@ -16,7 +16,7 @@ def _traffic(name):
 
 
 def test_same_seed_same_schedule_and_lengths():
-    tr = _traffic("open-chat")
+    tr = _traffic("open-chat-loaded")
     a = traffic.open_schedule(tr, 3000000019, 50, 50304)
     b = traffic.open_schedule(tr, 3000000019, 50, 50304)
     assert a == b
@@ -36,7 +36,8 @@ def _rotated(xs, k):
 
 
 def test_other_seed_same_cycle_entered_elsewhere_other_token_ids():
-    tr = dict(_traffic("open-chat"), entry_after_idle_s=0)   # any arrival
+    tr = dict(_traffic("open-chat-loaded"),
+              entry_after_idle_s=0)                      # any arrival
     n = round(tr["arrivals"]["rate_per_s"] * 50)
     seeds = (1, 2, 2147483659, 3000000019)
     wins = {s: traffic.open_schedule(tr, s, 50, 50304) for s in seeds}
@@ -57,7 +58,7 @@ def test_other_seed_same_cycle_entered_elsewhere_other_token_ids():
 
 
 def test_the_window_opens_after_an_idle_stretch():
-    tr = _traffic("open-chat")
+    tr = _traffic("open-chat-loaded")
     idle = tr["entry_after_idle_s"]
     firsts = set()
     for seed in range(40):
@@ -70,8 +71,67 @@ def test_the_window_opens_after_an_idle_stretch():
     assert 50 - win[-1]["due"] == pytest.approx(max(_gaps(win, 50)))
 
 
+def test_the_cycle_offers_at_least_eight_entries():
+    """At this rate no stretch of the cycle is idle for seconds; a rule
+    that found none would open every seed's window at ONE arrival."""
+    tr = _traffic("open-chat-loaded")
+    entries = set()
+    for seed in range(64):
+        win = traffic.open_schedule(tr, seed, 50, 50304)
+        assert 50 - win[-1]["due"] >= tr["entry_after_idle_s"]
+        entries.add((_shape(win[0]), round(50 - win[-1]["due"], 9)))
+    assert len(entries) >= 8
+
+
+def test_output_lengths_are_multiples_of_eight_inside_the_clip():
+    tr = _traffic("open-chat-loaded")
+    spec = tr["output_len"]
+    assert spec["multiple_of"] == 8
+    n = round(tr["arrivals"]["rate_per_s"] * 50)
+    outs = [r["max_new_tokens"] for r in traffic.serve_population(tr, n)]
+    assert all(o % 8 == 0 and spec["min"] <= o <= spec["max"] for o in outs)
+    assert len(set(outs)) <= 31 and len(set(outs)) > 16
+    # a clip that is no multiple itself is drawn in, never passed
+    odd = dict(spec, min=13, max=61)
+    got = traffic.draw_lengths(odd, 4000, np.random.default_rng(0))
+    assert got.min() == 16 and got.max() == 56 and not (got % 8).any()
+    # without the key: the plain rounding, as ever
+    plain = {k: v for k, v in spec.items() if k != "multiple_of"}
+    a = traffic.draw_lengths(plain, 4000, np.random.default_rng(0))
+    assert (a % 8).any() and a.min() == spec["min"] and a.max() == spec["max"]
+
+
+@pytest.mark.parametrize("name,seed,want", [
+    ("open-mixedctx", 1, "9aa2d4cbe5355d35"),
+    ("open-mixedctx", 3000000019, "4010113c000e8ae7"),
+    ("packed-1024", 1, "896707878b440c2e"),
+    ("packed-1024", 3000000019, "a414c38bc762d5b0"),
+])
+def test_the_kept_cells_schedules_are_the_parents_bit_for_bit(name, seed,
+                                                              want):
+    """Digests taken with commit 4ec08fa's `lib/traffic.py` (before the
+    `multiple_of` key): neither kept traffic file carries the key, so the
+    generator gives their cells what it gave them."""
+    import hashlib
+
+    tr = _traffic(name)
+    assert "multiple_of" not in json.dumps(tr)
+    if tr["kind"] == "train_packed":
+        it, h = traffic.packed_batches(tr, seed, 8, 50304), hashlib.sha256()
+        for _ in range(3):
+            b = next(it)
+            h.update(b["inputs"].tobytes())
+            h.update(b["targets"].tobytes())
+        got = h.hexdigest()
+    else:
+        got = hashlib.sha256(json.dumps(
+            traffic.open_schedule(tr, seed, 50, 32768),
+            sort_keys=True).encode()).hexdigest()
+    assert got[:16] == want
+
+
 def test_gamma_arrivals_keep_the_mean_rate():
-    tr = dict(_traffic("open-chat"),
+    tr = dict(_traffic("open-chat-loaded"),
               arrivals={"process": "gamma", "cv": 3, "rate_per_s": 5.0})
     s = [r["due"] for r in traffic.open_schedule(tr, 7, 100, 50304)]
     assert len(s) == 500 and s[-1] < 100
