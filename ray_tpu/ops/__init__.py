@@ -1,12 +1,13 @@
 from .attention import (attention, blockwise_attention, flash_attention,
                         flash_attention_with_lse, mha_reference,
                         streamed_attention)
-from .layers import (apply_rope, apply_rope_interleaved,
+from .layers import (apply_rope, apply_rope_halves, apply_rope_interleaved,
                      fused_softmax_cross_entropy, gelu_mlp,
                      layer_norm, rms_norm, rope_table,
                      softmax_cross_entropy, swiglu)
 from .quantize import (dequantize_blockwise, quantization_error,
                        quantize_blockwise)
+from .retention import retention_chunk, retention_step
 from .ring_attention import ring_attention, ring_attention_sharded
 from .ulysses import ulysses_attention, ulysses_attention_sharded
 
@@ -16,6 +17,8 @@ __all__ = [
     "blockwise_attention", "mha_reference", "streamed_attention",
     "ring_attention", "ring_attention_sharded",
     "ulysses_attention", "ulysses_attention_sharded",
-    "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_interleaved", "swiglu",
+    "retention_chunk", "retention_step",
+    "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_halves",
+    "apply_rope_interleaved", "swiglu",
     "gelu_mlp", "softmax_cross_entropy", "fused_softmax_cross_entropy",
 ]

@@ -76,6 +76,20 @@ def apply_rope_interleaved(x, positions, theta: float = 10000.0):
     return y.reshape(x.shape).astype(x.dtype)
 
 
+def apply_rope_halves(x, positions, theta: float = 10000.0):
+    """Rotary embedding over the pairs (x[i], x[i + D/2]) — the
+    rotate-half convention of `apply_rope` — for x [B, H, T, D] at
+    positions [B, T], every row of the batch at its own positions; angles
+    in f32 from the positions (no table), as `apply_rope_interleaved`."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None, :, None] * freqs
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """SwiGLU MLP: silu(x@Wg) * (x@Wu) @ Wd, bf16-friendly."""
     g = jnp.einsum("...d,df->...f", x, w_gate)

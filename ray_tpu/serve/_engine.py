@@ -36,7 +36,15 @@ allocator and a page table per kind.  A full kind's pages are taken at
 admission, in sequence order.  A windowed kind's table is a ring as wide
 as the window plus the longest prefill program: pages are taken as the
 sequence grows, out of a reservation made at admission, and returned once
-every position in them is a window or more behind the next query.
+every position in them is a window or more behind the next query.  A kind
+may also be a **state**: what its layers keep of a sequence does not grow
+with it (a recurrent or linear-attention layer's state matrix), so the
+pool holds one entry of fixed size a sequence — taken at admission,
+emptied by the sequence's first prefill chunk, carried from chunk to chunk
+and step to step, returned at eviction — and the kind's table is that one
+entry's index.  A model all of whose kinds are states has no page to run
+out of: it is admitted while a slot and an entry are free, and `max_total`
+bounds its positions only.
 
 Memory is a **paged arena** (models/gpt.py init_paged_cache): fixed-size
 pages in one preallocated device array, per-slot page tables gathered
@@ -51,7 +59,8 @@ admission makes it anew (every request in flight has failed by then).
 Pages are refcounted through a free list;
 full prompt pages register in a prefix table so live sequences with a
 common prompt prefix share pages (not for a model with a windowed kind:
-a shared prefix is not prefilled, and its window pages may be gone),
+a shared prefix is not prefilled, and its window pages may be gone; nor
+for one with a state kind, whose state after a prefix is in no page),
 with copy-on-write when a new
 sequence must write into a shared page (the exact-duplicate-prompt
 case: everything is shared but the last prompt position must be
@@ -306,7 +315,8 @@ class _Sequence:
                  "pos", "generated", "keys", "t_submit", "t_admit",
                  "t_prefill", "t_ready", "t_first", "t_last", "shared",
                  "scanned", "trace_ctx", "peak", "stream", "request_id",
-                 "key_offset", "tabs", "win", "reserved", "next_start",
+                 "key_offset", "tabs", "win", "reserved", "states",
+                 "next_start",
                  "chunks", "prefill_s", "prefilling")
 
     def __init__(self, rid, tokens, max_new, temperature, top_k, seed,
@@ -348,6 +358,7 @@ class _Sequence:
         self.tabs: Dict[str, Any] = {}
         self.win: Dict[str, Dict[int, int]] = {}
         self.reserved: Dict[str, int] = {}
+        self.states: Dict[str, int] = {}    # a state kind's one entry
         self.next_start = 0         # first prompt position not prefilled
         self.chunks = 0             # prefill programs run for it
         self.prefill_s = 0.0        # their seconds, dispatch -> ready
@@ -391,19 +402,21 @@ class ContinuousEngine:
         # 0: every prompt is one program of its padded length
         self.prefill_chunk = int(prefill_chunk)
 
-        # one page pool per kind of KV state the model's layers keep
-        # (gpt_mod.cache_kinds: name -> window, None = every position).
+        # one pool per kind of state the model's layers keep
+        # (gpt_mod.cache_kinds: name -> window, None = every position,
+        # "state" = one entry of fixed size a sequence).
         # A full kind's table is the sequence's pages in order, taken at
         # admission; a windowed kind's is a ring as wide as the window
         # plus the longest prefill program, filled as the sequence grows
-        # and emptied as the window passes.
-        self._kinds: Dict[str, Optional[int]] = dict(
-            gpt_mod.cache_kinds(cfg))
+        # and emptied as the window passes; a state kind's is its entry.
+        self._kinds: Dict[str, Any] = dict(gpt_mod.cache_kinds(cfg))
+        self._state_kinds = [k for k, w in self._kinds.items()
+                             if w == "state"]
         longest = self.prefill_chunk or self.max_total
         self._widths = {
-            k: self.max_pages_per_seq if w is None else min(
-                self.max_pages_per_seq,
-                -(-(w + longest) // self.page_size) + 1)
+            k: 1 if w == "state" else self.max_pages_per_seq if w is None
+            else min(self.max_pages_per_seq,
+                     -(-(w + longest) // self.page_size) + 1)
             for k, w in self._kinds.items()}
         self._pool_pages = {
             k: int(num_pages[k] if isinstance(num_pages, dict)
@@ -416,9 +429,11 @@ class ContinuousEngine:
         self.num_pages = self._pool_pages[self._main]
         # live prefix sharing skips a shared prefix's prefill, so it needs
         # every layer's K/V of that prefix to be kept: with a windowed
-        # kind nothing is shared (the window's pages may be gone)
-        self._windowed = [k for k, w in self._kinds.items() if w is not None]
-        self._share = not self._windowed
+        # kind nothing is shared (the window's pages may be gone), nor
+        # with a state kind (the state after a prefix is in no page)
+        self._windowed = [k for k, w in self._kinds.items()
+                          if w is not None and w != "state"]
+        self._share = not self._windowed and not self._state_kinds
 
         self._lock = threading.Lock()
         self._waiting: "deque[_Sequence]" = deque()   # guarded-by: _lock
@@ -455,6 +470,7 @@ class ContinuousEngine:
         # device state (built lazily on the engine thread)
         self._cache = None
         self._logits = None          # [B, V] carried across steps
+        self._state_bytes = 0        # the arena of a model's state kinds
 
         # host mirrors of the per-slot step operands
         B = self.max_slots
@@ -614,8 +630,22 @@ class ContinuousEngine:
             if ttfts else 0.0,
             "tokens_per_s": (toks / span) if span > 0 else 0.0,
             **self._param_stats,
+            **self._state_stats(),
             **totals,
         }
+
+    def _state_stats(self) -> Dict[str, int]:
+        """A model with a state kind: its entries in use and free, and
+        the bytes of the arena that holds them (once it is made)."""
+        if not self._state_kinds:
+            return {}
+        return {"states_live": self._states_live(),
+                "states_free": sum(self._allocs[k].free_pages
+                                   for k in self._state_kinds),
+                "state_arena_bytes": self._state_bytes}
+
+    def _states_live(self) -> int:
+        return sum(self._allocs[k].used_pages for k in self._state_kinds)
 
     def _census_report(self) -> Dict[str, Any]:
         """Owner callback for telemetry/device.DeviceMemoryCensus: the
@@ -638,7 +668,8 @@ class ContinuousEngine:
                 },
                 "prefix_keys": occ["prefix_keys"],
                 "pools": {k: a.occupancy()
-                          for k, a in self._allocs.items()}}
+                          for k, a in self._allocs.items()},
+                **self._state_stats()}
 
     def phase_ring(self) -> List[Dict[str, float]]:
         with self._lock:
@@ -810,6 +841,8 @@ class ContinuousEngine:
                    "pages_returned": self._returned,
                    **{"pages_" + k: a.used_pages
                       for k, a in self._allocs.items()},
+                   **({"states_live": self._states_live()}
+                      if self._state_kinds else {}),
                    **self._stats,
                    "requests": [self._request_record(s)
                                 for s in self._first]}
@@ -917,19 +950,24 @@ class ContinuousEngine:
         """Pages of every pool for `seq`, or None while some pool cannot
         take it.  A full kind's pages are taken here (allocator.plan, with
         its prefix sharing where the model allows it); a windowed kind
-        only reserves the most it will hold at once."""
+        only reserves the most it will hold at once; a state kind gives
+        its one entry."""
         need = self._pages_needed(seq)
         windowed = {k: min(need, self._widths[k]) for k in self._windowed}
         if any(self._allocs[k].available < n for k, n in windowed.items()):
             return None
+        if any(self._allocs[k].available < 1 for k in self._state_kinds):
+            return None
         plan = {"pages": [], "shared_len": 0, "copies": [], "n_shared": 0}
-        if self._main not in windowed:
+        if self._kinds[self._main] is None:
             plan = self._alloc.plan(seq.tokens, need, share=self._share)
             if plan is None:
                 return None
         for k, n in windowed.items():
             self._allocs[k].reserved += n
             seq.reserved[k] = n
+        for k in self._state_kinds:
+            seq.states[k] = self._allocs[k].alloc()
         return plan
 
     def _admit_one(self, seq: _Sequence, slot: int, plan):
@@ -942,6 +980,8 @@ class ContinuousEngine:
             seq.tabs[k] = np.zeros(w, np.int32)
             seq.win[k] = {}
         seq.tabs[self._main][:len(seq.pages)] = seq.pages
+        for k, entry in seq.states.items():
+            seq.tabs[k][0] = entry
         self._totals["cow_copies"] += len(plan["copies"])
         self._totals["shared_pages"] += plan["n_shared"]
         for src, dst in plan["copies"]:
@@ -1083,6 +1123,9 @@ class ContinuousEngine:
             a.release(list(live.values()))
             live.clear()
             a.reserved -= seq.reserved.pop(k, 0)
+        for k, entry in seq.states.items():
+            self._allocs[k].unref(entry)
+        seq.states = {}
         if self._prefilling is seq:
             self._prefilling = None
 
@@ -1210,6 +1253,12 @@ class ContinuousEngine:
             self._cfg, self._pool_pages, self.page_size)
         self._logits = jnp.zeros(
             (self.max_slots, self._cfg.vocab_size), jnp.float32)
+        # where every kind is a state the whole cache is the state arena (a
+        # model that mixed states with pages would have to say which part)
+        if self._state_kinds and len(self._state_kinds) == len(self._kinds):
+            self._state_bytes = sum(
+                int(a.nbytes)
+                for a in self._jax.tree_util.tree_leaves(self._cache))
 
     def _fn(self, key):
         fn = self._fns.get(key)

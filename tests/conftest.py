@@ -63,7 +63,7 @@ def _collect_cycles_after_test(request):
 # config/runtime-env basics — for surfacing regressions before the full
 # ~20-minute run.  Files not listed get `slow`.
 _QUICK_FILES = {
-    "test_asyncio_api.py", "test_chip_compile.py",
+    "test_asyncio_api.py", "test_brumby.py", "test_chip_compile.py",
     "test_chip_ownership.py",
     "test_collective_compression.py", "test_collective_pipeline.py",
     "test_config.py", "test_control_stats.py", "test_core_actors.py",
@@ -75,11 +75,13 @@ _QUICK_FILES = {
     "test_native_sched.py", "test_native_store.py", "test_ops.py",
     "test_parallel.py", "test_partition.py", "test_podracer.py",
     "test_remediation.py",
-    "test_resource_sync.py", "test_runtime_env.py",
+    "test_resource_sync.py", "test_retention_ops.py",
+    "test_runtime_env.py",
     "test_serve.py", "test_serve_continuous.py", "test_serve_donation.py",
     "test_serve_fault.py",
     "test_serve_prefill.py", "test_serve_live_blocks.py",
-    "test_serve_mixed_pools.py", "test_serve_weights_view.py",
+    "test_serve_mixed_pools.py", "test_serve_state_kind.py",
+    "test_serve_weights_view.py",
     "test_serve_grpc.py",
     "test_state.py",
     "test_submit_batching.py", "test_telemetry.py", "test_tune.py",

@@ -515,3 +515,76 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key):
     # the grouped products are the chip's own kernel, not a dense fallback,
     # for a chunk's rows and a step's alike
     assert "ragged-dot" in compiled.as_text()
+
+
+# -- the third served model at its published widths: a pipeline stage of
+# -- benchmarks/configs/brumby-14b-l8.json ------------------------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512)],
+                         ids=["step", "prefill512"])
+def test_brumby_serve_programs_fit_one_chip(one_chip, key):
+    """The state-kind serve programs (the retention step kernel, the
+    chunked retention) at the benchmark configuration's sizes, 16 slots:
+    the chip's compiler takes them, weights + the state arena +
+    temporaries stay under the chip's 15.75 GB, and the arena is held
+    once: the programs are given it to keep, the step's kernel and the
+    chunk's write-back update it where it stands, and no instruction
+    copies an arena-sized array."""
+    import json
+
+    from benchmarks.lib.brumbycfg import model_config
+    from ray_tpu.models import brumby as bm
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "brumby-14b-l8.json")) as f:
+        conf = json.load(f)
+    cfg = model_config(conf, retention_impl="pallas")
+    eng = ContinuousEngine(bm, cfg, None, **conf["serve"]["engine_kwargs"])
+    try:
+        V = cfg.vocab_size
+        params = _on(jax.eval_shape(lambda k: bm.init(k, cfg),
+                                    jax.random.PRNGKey(0)), one_chip)
+        cache = _on(jax.eval_shape(functools.partial(
+            bm.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B = eng.max_slots
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (params, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32), {"ret": s((B, 1), jnp.int32)},
+                    s((B,), jnp.int32))
+        else:
+            args = (params, cache, s((key[1],), jnp.int32),
+                    {"ret": s((1,), jnp.int32)}, i32, i32)
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    assert (B, eng._pool_pages, eng.max_total) == (16, {"ret": 17}, 32768)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert arena == 8 * 17 * 8 * 136 * 8320 * 4 and 8.39e9 < weights < 8.41e9
+    assert m.alias_size_in_bytes >= arena
+    assert total < 15.75 * 1024 ** 3, total
+    # a second copy of the arena would show here (a chunk keeps one
+    # sequence's states of all layers, read and new: 2 x 290 MB)
+    assert m.temp_size_in_bytes < arena // 4, m.temp_size_in_bytes
+    text = compiled.as_text()
+    dims = ",".join(map(str, cache.shape))
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if f"= f32[{dims}]" in ln
+             and any(f" {op}(" in ln for op in ("copy", "transpose"))]
+    assert not moved, moved
+    if key == "step":       # one kernel for every layer, under its name
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert len(calls) == 1
+        assert "retention_step" in calls[0].split(" = ", 1)[0]
+    print(key, "total", total, "temp", m.temp_size_in_bytes)
