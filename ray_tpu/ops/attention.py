@@ -12,8 +12,9 @@ Three tiers:
   * blockwise_attention — flash-style streaming softmax as a lax.scan; runs
     anywhere XLA runs, differentiable, memory O(S·block).
   * flash_attention   — Pallas TPU kernels, forward AND backward (MXU-tiled,
-    VMEM-resident blocks, causal block skipping; FlashAttention-2-style
-    dq/dk/dv backward, so no XLA recompute anywhere).
+    VMEM-resident blocks, only the causal part of a block computed;
+    FlashAttention-2-style dq/dk/dv backward, so no XLA recompute
+    anywhere).
   * flash_attention_with_lse — (out, logsumexp) variant whose partial
     results compose across KV chunks (the ring-attention building block).
 """
@@ -21,6 +22,7 @@ Three tiers:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -177,73 +179,278 @@ def _fit_block(block, seq):
     return block
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
-                  causal: bool, block_q: int, block_k: int,
-                  q_offset: int, with_lse: bool):
+# A program stays fat (one [block_q, block_k] block: few grid steps, few
+# DMAs) and computes only the causal part of it.  Where the diagonal lies
+# in a block depends on the block's position alone — `delta`, the global
+# position of its first query row minus that of its first key — and a
+# call's grid meets few values of it (one at 1024 x 1024 blocks).  So a
+# kernel carries one straight-line body per value, picked by
+# `pl.when(delta == d)`.  In a body every extent is static: each group of
+# sub_q query rows (sub_k keys in dkv, which walks the other way) meets
+# exactly the sub-blocks it sees, those wholly below the diagonal in one
+# matmul with no iota / compare / select, those the diagonal crosses in
+# another with the mask, and nothing above the diagonal is computed.
+# Blocks wholly below the diagonal share one unmasked body; blocks above
+# it run none, and their index_map names the block already resident, so
+# skipped steps fetch nothing.  (A loop over sub-blocks with a traced
+# trip count does the same arithmetic and was measured slower than no
+# skipping at all on v5e: PERF.md section 6, PR 41.)
+_SUB = 256          # rows of a sub-block, queries' and keys' alike
+# the backward's programs are capped at 1024 x 1024 (a group's four f32
+# intermediates s, p, dp, ds span the block's other side, and lse and di
+# ride as [block_q, 128] f32 planes)
+_BWD_BLOCK = 1024
+
+
+def _sub_blocks(block_q: int, block_k: int):
+    """Rows of queries and of keys in the sub-blocks a program divides
+    its [block_q, block_k] block into; from the block sizes alone."""
+    return (_fit_block(min(_SUB, block_q), block_q),
+            _fit_block(min(_SUB, block_k), block_k))
+
+
+def _split_scale(scale: float):
+    """`scale` as (the factor of a group's matmul operand, [rows, d_head]
+    once a group; the factor of its f32 scores, [rows, keys]), one of
+    them None.  A power of two (d_head 64: 0.125) goes into the operand,
+    which no dtype rounds; any other would round a bf16 operand a second
+    time, and stays on the scores."""
+    return (scale, None) if math.frexp(scale)[0] == 0.5 else (None, scale)
+
+
+def _times(x, factor):
+    return x if factor is None else x * factor
+
+
+def _add(acc, term):
+    return term if acc is None else acc + term
+
+
+def _visible_keys(row0: int, rows: int, sub_k: int, n_sub: int):
+    """Which of n_sub key sub-blocks [c*sub_k, (c+1)*sub_k) the query
+    rows [row0, row0 + rows) see under the causal mask: [0, n_full)
+    whole, no mask needed; [n_full, n_end) in part, masked; the rest not
+    at all.  Positions relative to the first key; row0 may be negative."""
+    return (max(0, min((row0 + 1) // sub_k, n_sub)),
+            max(0, min((row0 + rows - 1) // sub_k + 1, n_sub)))
+
+
+def _visible_queries(col0: int, cols: int, row0: int, sub_q: int,
+                     n_sub: int):
+    """The other way round, for the kernel that walks queries under the
+    keys [col0, col0 + cols): of n_sub query sub-blocks (rows row0 +
+    [r*sub_q, (r+1)*sub_q)) those below r_start see none of them,
+    [r_start, r_full) part (masked), r_full and up all of them."""
+    t = col0 - row0
+    return (max(0, min(t // sub_q, n_sub)),
+            max(0, min((t + cols + sub_q - 2) // sub_q, n_sub)))
+
+
+def _diagonals(sq: int, sk: int, block_q: int, block_k: int, q_offset: int):
+    """The deltas (first row's position minus first key's) of the blocks
+    of a call's grid that the diagonal crosses, and whether any block
+    lies wholly below it."""
+    deltas = {q_offset + i * block_q - j * block_k
+              for i in range(sq // block_q) for j in range(sk // block_k)}
+    return (sorted(d for d in deltas if -block_q < d < block_k - 1),
+            any(d >= block_k - 1 for d in deltas))
+
+
+def causal_work(sq: int, sk: int, block_q: int = 1024, block_k: int = 1024,
+                causal: bool = True, q_offset: int = 0,
+                backward: bool = False) -> dict:
+    """How much of the [sq, sk] score square one head of a
+    flash_attention call computes: `total` sub-blocks in the square,
+    `run` of them computed, `masked` of those built with the causal
+    mask.  A pure function of the shapes, from the arithmetic the
+    kernels lay their bodies out with: what they do, not an estimate."""
+    if backward:
+        block_q, block_k = min(block_q, _BWD_BLOCK), min(block_k, _BWD_BLOCK)
+    block_q, block_k = _fit_block(block_q, sq), _fit_block(block_k, sk)
+    sub_q, sub_k = _sub_blocks(block_q, block_k)
+    nq, nk = sq // sub_q, sk // sub_k
+    run, masked = nq * nk, 0
+    if causal:
+        seen = [_visible_keys(q_offset + r * sub_q, sub_q, sub_k, nk)
+                for r in range(nq)]
+        run = sum(n_end for _, n_end in seen)
+        masked = sum(n_end - n_full for n_full, n_end in seen)
+    return {"sub_q": sub_q, "sub_k": sub_k, "total": nq * nk, "run": run,
+            "masked": masked}
+
+
+def _key_parts(d: int, masked: bool, g: int, sub_q: int, sub_k: int,
+               block_k: int):
+    """The keys of a block at delta d that its g-th group of sub_q query
+    rows multiplies against, as (lo, hi, needs the mask) extents."""
+    if not masked:
+        return [(0, block_k, False)]
+    n_full, n_end = _visible_keys(d + g * sub_q, sub_q, sub_k,
+                                  block_k // sub_k)
+    parts = [(0, n_full * sub_k, False), (n_full * sub_k, n_end * sub_k, True)]
+    return [p for p in parts if p[0] < p[1]]
+
+
+def _query_parts(d: int, masked: bool, g: int, sub_q: int, sub_k: int,
+                 block_q: int):
+    """The query rows of a block at delta d that see its g-th group of
+    sub_k keys, as (lo, hi, needs the mask) extents."""
+    if not masked:
+        return [(0, block_q, False)]
+    r_start, r_full = _visible_queries(g * sub_k, sub_k, d, sub_q,
+                                       block_q // sub_q)
+    parts = [(r_start * sub_q, r_full * sub_q, True),
+             (r_full * sub_q, block_q, False)]
+    return [p for p in parts if p[0] < p[1]]
+
+
+def _dispatch(body, delta, causal: bool, block_k: int, diagonals):
+    """Run body(d, masked) for this program's delta: one body per
+    diagonal position the call's grid meets (d static), one unmasked for
+    blocks below the diagonal, none above it."""
     from jax.experimental import pallas as pl
 
-    if with_lse:
-        lse_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        acc_ref, m_ref, l_ref = rest
+    if not causal:
+        return body(0, False)
+    crossing, below = diagonals
+    if below:
+        pl.when(delta >= block_k - 1)(lambda: body(0, False))
+    for d in crossing:
+        pl.when(delta == d)(functools.partial(body, d, True))
+
+
+def _causal_mask(row0, col0, shape):
+    # rows >= cols, at positions row0 + iota and col0 + iota
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1)) >= col0 - row0
+
+
+def _mask_cache():
+    """_causal_mask, built once a body for each (offset, shape): on an
+    aligned diagonal every group's mask is the same triangle."""
+    built = {}
+
+    def tril(row0, col0, shape):
+        key = (col0 - row0, shape)
+        if key not in built:
+            built[key] = _causal_mask(row0, col0, shape)
+        return built[key]
+
+    return tril
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    # operands stay in their own dtype (bf16 on the chip: the MXU runs
+    # bf16 x bf16 at full rate, casting to f32 first would halve it);
+    # accumulation is f32
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
+                  causal: bool, block_q: int, block_k: int, q_offset: int,
+                  diagonals, single: bool, with_lse: bool):
+    from jax.experimental import pallas as pl
+
+    lse_ref = rest[0] if with_lse else None
+    q_scale, s_scale = _split_scale(scale)
+    # single: the block holds every key, so a group of rows is finished
+    # where it is computed and no statistics are carried in scratch
+    acc_ref, m_ref, l_ref = (None,) * 3 if single else rest[-3:]
 
     i = pl.program_id(2)  # q block
     j = pl.program_id(3)  # k block
     nk = pl.num_programs(3)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    run = True
-    if causal:
-        # whole block above the diagonal contributes nothing; q_offset
-        # shifts local q rows to their global positions (decode-style
-        # rectangular causal: q_offset = sk - sq anchors bottom-right)
-        run = (j * block_k) <= (q_offset + i * block_q + block_q - 1)
-
-    @pl.when(run if causal else True)
-    def _compute():
-        # inputs stay bf16 — the MXU runs bf16 x bf16 at full rate with
-        # f32 accumulation via preferred_element_type; casting to f32
-        # first would halve matmul throughput for zero extra precision
-        q = q_ref[0, 0]                               # [bq, d]
-        k = k_ref[0, 0]                               # [bk, d]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk] f32
-        if causal:
-            rows = q_offset + i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, DEFAULT_MASK_VALUE)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[:] /
-                       jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+    def finish(rows, acc, m, l):
+        o_ref[0, 0, rows, :] = (acc / jnp.maximum(l, 1e-30)).astype(
+            o_ref.dtype)
         if with_lse:
             # logsumexp per query row, broadcast across the 128 lanes
             # (sublane->lane transposes don't lower, so LSE lives as a
             # lane-replicated [.., 128] plane end to end)
-            lse_ref[0, 0] = m_ref[:] + jnp.log(
-                jnp.maximum(l_ref[:], 1e-30))
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(
+                m + jnp.log(jnp.maximum(l, 1e-30)), (acc.shape[0], 128))
+
+    def body(d, masked):
+        sub_q, sub_k = _sub_blocks(block_q, block_k)
+        tril = _mask_cache()
+        for g in range(block_q // sub_q):
+            parts = _key_parts(d, masked, g, sub_q, sub_k, block_k)
+            if not parts:
+                continue
+            rows = slice(g * sub_q, (g + 1) * sub_q)
+            q = _times(q_ref[0, 0, rows, :], q_scale)        # [sub_q, d]
+            scores = []
+            for lo, hi, mask in parts:
+                s = _times(_dot(q, k_ref[0, 0, lo:hi, :], _NT), s_scale)
+                if mask:
+                    s = jnp.where(tril(d + g * sub_q, lo, s.shape), s,
+                                  DEFAULT_MASK_VALUE)
+                scores.append(s)
+            m_new = functools.reduce(jnp.maximum, [
+                jnp.max(s, axis=-1, keepdims=True) for s in scores])
+            l_new = acc = None
+            if not single:
+                m_prev = m_ref[rows, :1]
+                m_new = jnp.maximum(m_prev, m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = alpha * l_ref[rows, :1]
+                acc = acc_ref[rows, :] * alpha
+            for (lo, hi, _), s in zip(parts, scores):
+                p = jnp.exp(s - m_new)
+                v = v_ref[0, 0, lo:hi, :]
+                l_new = _add(l_new, jnp.sum(p, axis=-1, keepdims=True))
+                acc = _add(acc, _dot(p.astype(v.dtype), v, _NN))
+            if single:
+                finish(rows, acc, m_new, l_new)
+            else:
+                acc_ref[rows, :] = acc
+                m_ref[rows, :] = jnp.broadcast_to(m_new, (sub_q, 128))
+                l_ref[rows, :] = jnp.broadcast_to(l_new, (sub_q, 128))
+
+    if not single:
+        @pl.when(j == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    # q_offset shifts local q rows to their global positions (decode-
+    # style rectangular causal: q_offset = sk - sq anchors bottom-right)
+    _dispatch(body, q_offset + i * block_q - j * block_k, causal, block_k,
+              diagonals)
+
+    if not single:
+        @pl.when(j == nk - 1)
+        def _finalize():
+            finish(slice(None), acc_ref[:], m_ref[:, :1], l_ref[:, :1])
+
+
+def _index_maps(causal: bool, block_q: int, block_k: int, sq: int,
+                q_offset: int):
+    """Block index of the q-side and k-side operands at grid step (i, j)
+    (i the q block, j the k block, whichever is outer).  Under the causal
+    mask the inner axis is clamped to the blocks the outer block sees: a
+    step that computes nothing names the block already resident and
+    fetches nothing."""
+    def q_of(i, j, q_inner):
+        if causal and q_inner:
+            first = jnp.maximum(j * block_k - q_offset, 0) // block_q
+            i = jnp.maximum(i, jnp.minimum(first, sq // block_q - 1))
+        return i
+
+    def k_of(i, j, q_inner):
+        if causal and not q_inner:
+            j = jnp.minimum(j, (q_offset + (i + 1) * block_q - 1) // block_k)
+        return j
+
+    return q_of, k_of
 
 
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
@@ -271,27 +478,36 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
     grid = (b, h, sq // block_q, sk // block_k)
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               q_offset=q_offset, with_lse=with_lse)
-    out_specs = [pl.BlockSpec((1, 1, block_q, d),
-                              lambda b_, h_, i, j: (b_, h_, i, 0))]
+    single = sk == block_k      # a program holds every key
+    kernel = functools.partial(
+        _flash_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, q_offset=q_offset,
+        diagonals=_diagonals(sq, sk, block_q, block_k, q_offset),
+        single=single, with_lse=with_lse)
+    q_of, k_of = _index_maps(causal, block_q, block_k, sq, q_offset)
+
+    def q_map(b_, h_, i, j):
+        return (b_, h_, q_of(i, j, False), 0)
+
+    def k_map(b_, h_, i, j):
+        return (b_, h_, k_of(i, j, False), 0)
+
+    out_specs = [pl.BlockSpec((1, 1, block_q, d), q_map)]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, 1, block_q, 128),
-                                      lambda b_, h_, i, j: (b_, h_, i, 0)))
+        out_specs.append(pl.BlockSpec((1, 1, block_q, 128), q_map))
         out_shape.append(jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32))
     res = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_q, d), q_map),
+            pl.BlockSpec((1, 1, block_k, d), k_map),
+            pl.BlockSpec((1, 1, block_k, d), k_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
+        scratch_shapes=[] if single else [
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -315,111 +531,138 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
 # with ds = p * (dp - di), dp = do v^T, di = rowsum(do * o) - dlse (the
 # dlse term folds the cotangent of the lse output into the same kernel:
 # d lse_i / d s_ik = p_ik).  di and lse ride as lane-replicated
-# [B, H, S, 128] planes (see _flash_kernel._finalize).
+# [B, H, S, 128] planes (see _flash_kernel._finalize).  Both lay their
+# block out as the forward does: dkv's groups of keys meet the query
+# rows from the diagonal down, dq's groups of queries the keys up to it.
+# `scale` is folded into the group's operand (k in dkv, q in dq) where
+# that is exact (_split_scale), and into the accumulator once at the end.
+
+
+def _bwd_tile(q, k, v, do, lse, di, mask, s_scale):
+    """p and ds of one tile, as matmul operands; q or k carries the
+    scale where the scores (s_scale) do not."""
+    p = jnp.exp(_times(_dot(q, k, _NT), s_scale) - lse)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    ds = p * (_dot(do, v, _NT) - di)
+    return p.astype(do.dtype), ds.astype(q.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                          causal: bool, block_q: int, block_k: int,
-                          q_offset: int):
+                          dk_ref, dv_ref, *acc, scale: float, causal: bool,
+                          block_q: int, block_k: int, q_offset: int,
+                          diagonals, single: bool):
     from jax.experimental import pallas as pl
+
+    # single (the block holds every query): a group of keys is finished
+    # where it is computed, nothing accumulates in scratch
+    dk_acc, dv_acc = (None, None) if single else acc
+    k_scale, s_scale = _split_scale(scale)
 
     j = pl.program_id(2)   # k block (outer)
     i = pl.program_id(3)   # q block (inner, sequential accumulation)
     nq = pl.num_programs(3)
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def finish(cols, dk, dv):
+        dk_ref[0, 0, cols, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
 
-    run = True
-    if causal:
-        run = (j * block_k) <= (q_offset + i * block_q + block_q - 1)
+    def body(d, masked):
+        sub_q, sub_k = _sub_blocks(block_q, block_k)
+        tril = _mask_cache()
+        for g in range(block_k // sub_k):
+            parts = _query_parts(d, masked, g, sub_q, sub_k, block_q)
+            cols = slice(g * sub_k, (g + 1) * sub_k)
+            if not parts:       # never where single: the last row sees
+                continue        # every key
+            k = _times(k_ref[0, 0, cols, :], k_scale)        # [sub_k, d]
+            v = v_ref[0, 0, cols, :]
+            dk, dv = (None, None) if single else (dk_acc[cols, :],
+                                                  dv_acc[cols, :])
+            for lo, hi, mask in parts:
+                q = q_ref[0, 0, lo:hi, :]
+                do = do_ref[0, 0, lo:hi, :]
+                p, ds = _bwd_tile(
+                    q, k, v, do, lse_ref[0, 0, lo:hi, :1],
+                    di_ref[0, 0, lo:hi, :1],
+                    tril(d + lo, g * sub_k, (hi - lo, sub_k))
+                    if mask else None, s_scale)
+                # dv += p^T do, dk += ds^T q (contract the q axis); both
+                # products before either sum: emitted product, sum,
+                # product, sum the kernel reads 0.5404 ms for 0.5225 at
+                # the train cells' shape (PERF.md section 6, PR 41)
+                dv_p, dk_p = _dot(p, do, _TN), _dot(ds, q, _TN)
+                dv, dk = _add(dv, dv_p), _add(dk, dk_p)
+            if single:
+                finish(cols, dk, dv)
+            else:
+                dk_acc[cols, :], dv_acc[cols, :] = dk, dv
 
-    @pl.when(run if causal else True)
-    def _compute():
-        q = q_ref[0, 0]                                 # [bq, d]
-        k = k_ref[0, 0]                                 # [bk, d]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]                               # [bq, d]
-        lse = lse_ref[0, 0][:, :1]                      # [bq, 1] f32
-        di = di_ref[0, 0][:, :1]                        # [bq, 1] f32
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        p = jnp.exp(s - lse)
-        if causal:
-            rows = q_offset + i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            p = jnp.where(rows >= cols, p, 0.0)
-        # dv += p^T do  (contract the q axis of both)
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = (p * (dp - di) * scale).astype(q.dtype)
-        # dk += ds^T q
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    if not single:
+        @pl.when(i == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(i == nq - 1)
-    def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+    _dispatch(body, q_offset + i * block_q - j * block_k, causal, block_k,
+              diagonals)
+
+    if not single:
+        @pl.when(i == nq - 1)
+        def _finalize():
+            finish(slice(None), dk_acc[:], dv_acc[:])
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                         dq_ref, dq_acc, *, scale: float, causal: bool,
-                         block_q: int, block_k: int, q_offset: int):
+                         dq_ref, *acc, scale: float, causal: bool,
+                         block_q: int, block_k: int, q_offset: int,
+                         diagonals, single: bool):
     from jax.experimental import pallas as pl
+
+    dq_acc = None if single else acc[0]
+    q_scale, s_scale = _split_scale(scale)
 
     i = pl.program_id(2)   # q block (outer)
     j = pl.program_id(3)   # k block (inner, sequential accumulation)
     nk = pl.num_programs(3)
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+    def body(d, masked):
+        sub_q, sub_k = _sub_blocks(block_q, block_k)
+        tril = _mask_cache()
+        for g in range(block_q // sub_q):
+            parts = _key_parts(d, masked, g, sub_q, sub_k, block_k)
+            if not parts:
+                continue
+            rows = slice(g * sub_q, (g + 1) * sub_q)
+            q = _times(q_ref[0, 0, rows, :], q_scale)
+            do = do_ref[0, 0, rows, :]
+            lse = lse_ref[0, 0, rows, :1]                    # [sub_q, 1] f32
+            di = di_ref[0, 0, rows, :1]
+            dq = None if single else dq_acc[rows, :]
+            for lo, hi, mask in parts:
+                k = k_ref[0, 0, lo:hi, :]
+                _, ds = _bwd_tile(
+                    q, k, v_ref[0, 0, lo:hi, :], do, lse, di,
+                    tril(d + g * sub_q, lo, (sub_q, hi - lo))
+                    if mask else None, s_scale)
+                dq = _add(dq, _dot(ds, k, _NN))
+            if single:
+                dq_ref[0, 0, rows, :] = (dq * scale).astype(dq_ref.dtype)
+            else:
+                dq_acc[rows, :] = dq
 
-    run = True
-    if causal:
-        run = (j * block_k) <= (q_offset + i * block_q + block_q - 1)
+    if not single:
+        @pl.when(j == 0)
+        def _init():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(run if causal else True)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        di = di_ref[0, 0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)
-        if causal:
-            rows = q_offset + i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            p = jnp.where(rows >= cols, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - di) * scale).astype(q.dtype)
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _dispatch(body, q_offset + i * block_q - j * block_k, causal, block_k,
+              diagonals)
 
-    @pl.when(j == nk - 1)
-    def _finalize():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+    if not single:
+        @pl.when(j == nk - 1)
+        def _finalize():
+            dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_backward(q, k, v, o, lse128, do, dlse, causal: bool,
@@ -432,66 +675,67 @@ def _flash_backward(q, k, v, o, lse128, do, dlse, causal: bool,
 
     b, h, sq, d = q.shape
     sk = k.shape[-2]
-    # bwd blocks are capped at 512x512: four [bq, bk] f32 intermediates
-    # live at once (s, p, dp, ds), twice the fwd's VMEM appetite
-    block_q = _fit_block(min(block_q, 512), sq)
-    block_k = _fit_block(min(block_k, 512), sk)
+    block_q = _fit_block(min(block_q, _BWD_BLOCK), sq)
+    block_k = _fit_block(min(block_k, _BWD_BLOCK), sk)
+    # a program of dq holds every key / of dkv every query (and no key
+    # lies past the last row: every group of keys meets a part that
+    # writes it)
+    dq_single = sk == block_k
+    dkv_single = sq == block_q and (not causal or q_offset + sq >= sk)
 
     di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
         di = di - dlse
     di128 = jnp.broadcast_to(di[..., None], (b, h, sq, 128))
 
-    def qspec(rev):
+    q_of, k_of = _index_maps(causal, block_q, block_k, sq, q_offset)
+
+    def spec(rows, width, of, rev):
         # rev: grid is (b, h, kblock, qblock); else (b, h, qblock, kblock)
         if rev:
-            return pl.BlockSpec((1, 1, block_q, d),
-                                lambda b_, h_, j, i: (b_, h_, i, 0))
-        return pl.BlockSpec((1, 1, block_q, d),
-                            lambda b_, h_, i, j: (b_, h_, i, 0))
+            return pl.BlockSpec(
+                (1, 1, rows, width),
+                lambda b_, h_, j, i: (b_, h_, of(i, j, True), 0))
+        return pl.BlockSpec(
+            (1, 1, rows, width),
+            lambda b_, h_, i, j: (b_, h_, of(i, j, False), 0))
+
+    def qspec(rev):
+        return spec(block_q, d, q_of, rev)
 
     def kspec(rev):
-        if rev:
-            return pl.BlockSpec((1, 1, block_k, d),
-                                lambda b_, h_, j, i: (b_, h_, j, 0))
-        return pl.BlockSpec((1, 1, block_k, d),
-                            lambda b_, h_, i, j: (b_, h_, j, 0))
+        return spec(block_k, d, k_of, rev)
 
     def lanespec(rev):
-        if rev:
-            return pl.BlockSpec((1, 1, block_q, 128),
-                                lambda b_, h_, j, i: (b_, h_, i, 0))
-        return pl.BlockSpec((1, 1, block_q, 128),
-                            lambda b_, h_, i, j: (b_, h_, i, 0))
+        return spec(block_q, 128, q_of, rev)
 
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, q_offset=q_offset)
+    kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+              q_offset=q_offset,
+              diagonals=_diagonals(sq, sk, block_q, block_k, q_offset))
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_flash_bwd_dkv_kernel, single=dkv_single, **kw),
         grid=(b, h, sk // block_k, sq // block_q),
         in_specs=[qspec(True), kspec(True), kspec(True), qspec(True),
                   lanespec(True), lanespec(True)],
         out_specs=[kspec(True), kspec(True)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[] if dkv_single else [
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse128, di128)
 
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, q_offset=q_offset)
     dq = pl.pallas_call(
-        dq_kernel,
+        functools.partial(_flash_bwd_dq_kernel, single=dq_single, **kw),
         grid=(b, h, sq // block_q, sk // block_k),
         in_specs=[qspec(False), kspec(False), kspec(False), qspec(False),
                   lanespec(False), lanespec(False)],
         out_specs=qspec(False),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[] if dq_single else [
+            pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_attention_bwd_dq",
     )(q, k, v, do, lse128, di128)
@@ -500,7 +744,7 @@ def _flash_backward(q, k, v, o, lse128, do, dlse, causal: bool,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None, block_q: int = 512,
+                    scale: Optional[float] = None, block_q: int = 1024,
                     block_k: int = 1024, interpret: bool = False,
                     q_offset: int = 0):
     """Pallas TPU flash attention, forward AND backward kernels (the
@@ -538,7 +782,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention_with_lse(q, k, v, causal: bool = False,
                              scale: Optional[float] = None,
-                             block_q: int = 512, block_k: int = 1024,
+                             block_q: int = 1024, block_k: int = 1024,
                              interpret: bool = False, q_offset: int = 0):
     """(out, lse) variant for partial-softmax composition (ring
     attention): lse is [B, H, Sq] f32 logsumexp of the scaled scores.
@@ -577,15 +821,13 @@ def resolve_impl(impl: str, sq: int, sk: int, causal: bool) -> str:
     this backend ("auto" resolved; anything else returned as given)."""
     if impl != "auto":
         return impl
-    # v5e measurements (GPT-2-small training, tokens/s), with the
-    # native FlashAttention-2 dq/dk/dv bwd kernels: pallas beats XLA
-    # blockwise at EVERY seq — 512 B=16: 99.5k vs 75.7k (+31%, MFU
-    # .40 vs .31); 4096: 59.5k vs 19.8k (3.0x, MFU .37); 8192: 37.0k
-    # vs 11.3k (3.3x, MFU .32).  (Before the bwd kernels existed the
-    # custom_vjp fell back to a full blockwise recompute and lost
-    # everywhere — that's why this dispatch was XLA-only through
-    # round 4.)  XLA remains the portable path: CPU meshes, seqs not
-    # a multiple of 128, and anything interpret-mode.
+    # On a TPU the Pallas kernels are the path wherever they apply: the
+    # ledger's train cells run them (`train-medium-1024`,
+    # `train-xl-fsdp4`: forward, dk/dv and dq kernels, no XLA recompute
+    # anywhere; 18.8% of medium's step at 24.8% of their roofline after
+    # PR 41 taught them to skip what the causal mask hides, 31.2% at
+    # 12.6% before).  XLA remains the portable path: CPU meshes, seqs
+    # not a multiple of 128, and anything interpret-mode.
     # causal rectangular with sq > sk still routes to XLA (a
     # negative q_offset has no causal interpretation here); sk >= sq
     # runs in pallas with the bottom-right anchor via q_offset
@@ -603,9 +845,16 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
 
     q,k,v: [batch, heads, seq, head_dim]
 
-    Block defaults are per-path (v5e-measured optima differ 4x): the
-    XLA scan wants small KV blocks (256 — deeper fusion per step), the
-    pallas grid wants fat ones (512x1024 — fewer sequential programs).
+    Block defaults are per-path: the XLA scan wants small KV blocks
+    (256 — deeper fusion per step); the pallas grid wants fat ones
+    (1024 x 1024: at the train cells' S = 1024 one program holds a
+    head's whole square, every grid step costs its fixed overhead and
+    its DMA set-up, and a program that holds every key finishes its
+    rows where it computes them, with no statistics carried in
+    scratch).  The causal skipping happens inside the program, in
+    sub-blocks whose extents are static (see `causal_work`): PERF.md
+    section 6, PR 41, has the chip's readings at 512- and 1024-row
+    blocks and 128- to 512-row sub-blocks.
 
     The Pallas kernel is a Mosaic custom call, which GSPMD cannot
     partition: on sharded arrays call it from inside a shard_map
@@ -617,10 +866,10 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
     # mha_reference's tril(k=sk-sq) decode semantics
     qoff = (sk - sq) if (causal and sk > sq) else 0
     if impl == "pallas":
-        return flash_attention(q, k, v, causal, scale, block_q or 512,
+        return flash_attention(q, k, v, causal, scale, block_q or 1024,
                                block_k or 1024, False, qoff)
     if impl == "pallas_interpret":
-        return flash_attention(q, k, v, causal, scale, block_q or 512,
+        return flash_attention(q, k, v, causal, scale, block_q or 1024,
                                block_k or 1024, True, qoff)
     if impl == "xla":
         return blockwise_attention(q, k, v, causal=causal, scale=scale,

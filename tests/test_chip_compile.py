@@ -73,8 +73,15 @@ def _kernel_calls(compiled) -> int:
 # -- flash attention ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(16, 12, 512, 64), (2, 12, 8192, 64)],
-                         ids=["b16s512", "b2s8192"])
+# b8h25s1024 is `train-xl-fsdp4`'s shard a chip: an odd head count, and
+# the one diagonal position of a whole-sequence block laid out statically.
+# d128: twice the bytes a block in VMEM (the backward's 1024 x 1024 cap),
+# one program a head at 1024 and scratch across key blocks at 8192
+@pytest.mark.parametrize("shape", [(16, 12, 512, 64), (2, 12, 8192, 64),
+                                   (8, 25, 1024, 64), (4, 8, 1024, 128),
+                                   (1, 8, 8192, 128)],
+                         ids=["b16s512", "b2s8192", "b8h25s1024",
+                              "b4s1024d128", "b1s8192d128"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_compiles(one_chip, shape, direction):
     from ray_tpu.ops.attention import attention

@@ -136,6 +136,121 @@ def test_flash_bwd_kernels_match_reference(causal):
         assert np.allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
+# (sq, sk, block_q, block_k, causal, q_offset): where the kernels'
+# static extents can be wrong
+_FLASH_LAYOUTS = {
+    # one program a head, four sub-blocks a side, the diagonal on their
+    # corners (the train cells' layout: 1024 x 1024 blocks, sub-blocks 256)
+    "s1024-default-blocks": (1024, 1024, 1024, 1024, True, 0),
+    # two programs a head, key blocks wider than query blocks
+    "s1024-blocks-512x1024": (1024, 1024, 512, 1024, True, 0),
+    # sub_q 256 against sub_k 128 and an anchor of 128: the diagonal
+    # crosses query sub-blocks in their middle
+    "diagonal-mid-sub-block": (512, 640, 512, 1024, True, 128),
+    # rectangular, bottom-right anchored: the first rows already see
+    # whole key sub-blocks unmasked, and whole key blocks below them
+    "q-offset-rectangular": (256, 1024, 256, 512, True, 768),
+    "non-causal": (512, 512, 512, 512, False, 0),
+    # blocks shrink through _fit_block (512 -> 256, 1024 -> 768)
+    "s768-fitted-blocks": (768, 768, 512, 1024, True, 0),
+    # a 4 x 2 grid: blocks below, on and above the diagonal, the last
+    # fetched never (clamped index maps)
+    "grid-4x2": (1024, 1024, 256, 512, True, 0),
+    # eight diagonal positions, so eight static bodies a kernel
+    "eight-diagonal-positions": (1024, 1024, 128, 1024, True, 0),
+    # an anchor short of bottom-right: the last key block is seen by no
+    # row, and its dk / dv are zeros that something has to write
+    "keys-past-the-last-row": (256, 1024, 256, 512, True, 256),
+}
+
+
+@pytest.mark.parametrize("layout", list(_FLASH_LAYOUTS))
+def test_flash_sub_block_extents(layout):
+    """Forward and all three gradients against the dense oracle where a
+    program holds several sub-blocks, and the count of them computed
+    (`causal_work`) against the mask itself."""
+    from ray_tpu.ops.attention import causal_work
+
+    sq, sk, bq, bk, causal, off = _FLASH_LAYOUTS[layout]
+    # d_head 64, the train cells': its scale (0.125) rides in an operand
+    q, _, _ = _qkv(b=1, h=2, s=sq, d=64)
+    k, v = _qkv(b=1, h=2, s=sk, d=64, seed=1)[1:]
+
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal, None, bq, bk, True, off)
+
+    seen = np.ones((sq, sk), bool)
+    if causal:      # mha_reference's mask where off == sk - sq
+        seen = (np.arange(sq)[:, None] + off) >= np.arange(sk)[None, :]
+
+    def ref(q_, k_, v_):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) * q_.shape[-1] ** -0.5
+        p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v_)
+
+    assert np.allclose(np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)),
+                       atol=2e-4)
+    g_f = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(g_f, g_ref):
+        assert np.allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+    for backward in (False, True):
+        w = causal_work(sq, sk, bq, bk, causal, off, backward)
+        tiles = seen.reshape(sq // w["sub_q"], w["sub_q"],
+                             sk // w["sub_k"], w["sub_k"])
+        assert w["total"] == tiles.shape[0] * tiles.shape[2]
+        assert w["run"] == int(tiles.any(axis=(1, 3)).sum())
+        assert w["masked"] == int((tiles.any(axis=(1, 3))
+                                   & ~tiles.all(axis=(1, 3))).sum())
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+def test_flash_bf16_holds_the_scale_exact(d_head):
+    """bf16 operands, f32 accumulation: forward and gradients against
+    the f32 oracle on the same (bf16-valued) inputs.  A scale that is no
+    power of two (d_head 128) multiplied into a bf16 operand rounds it a
+    second time: the output's rms error then reads 2.3e-3 of its rms,
+    where scaling the f32 scores reads 2.0e-3 at either d_head, as does
+    0.125 in the operand at d_head 64."""
+    q, k, v = _qkv(b=1, h=2, s=512, d=d_head, dtype=jnp.bfloat16)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, True, None, 512, 512, True
+                               ).astype(jnp.float32)
+
+    def ref(q_, k_, v_):
+        return mha_reference(q_, k_, v_, causal=True)
+
+    def rms(x):
+        return float(jnp.sqrt(jnp.mean(x.astype(jnp.float32) ** 2)))
+
+    o, o_ref = flash(q, k, v), ref(*f32)
+    assert rms(o - o_ref) < 2.15e-3 * rms(o_ref)
+    g_f = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(
+        *f32)
+    for a, b in zip(g_f, g_ref):
+        assert rms(a.astype(jnp.float32) - b) < 3.3e-3 * rms(b)
+        assert float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) \
+            < 1e-2 * float(jnp.max(jnp.abs(b)))
+
+
+def test_flash_causal_work_at_the_train_cells_shape():
+    """1024 x 1024 at the default blocks (one program a head): ten of
+    sixteen sub-blocks computed, four of them under the mask, forward
+    and backward."""
+    from ray_tpu.ops.attention import causal_work
+
+    for backward in (False, True):
+        assert causal_work(1024, 1024, backward=backward) == {
+            "sub_q": 256, "sub_k": 256, "total": 16, "run": 10, "masked": 4}
+
+
 def test_flash_with_lse_value_and_grads():
     """(out, lse) variant: lse equals dense logsumexp of scaled scores,
     and gradients flow through BOTH outputs (the dlse term folds into
