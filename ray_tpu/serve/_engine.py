@@ -71,11 +71,17 @@ host-side, which is what lets the step program keep one static shape.
 Everything device-facing runs on one daemon thread (the engine loop);
 `submit` is thread-safe and hands back a `_Sequence` whose results are
 consumed either as a blocking token iterator (streaming) or a
-concurrent Future (request/response).
+concurrent Future (request/response).  The loop hands every program to
+the chip through one helper and waits through one other (`_launch`,
+`_wait`), each boundary one clock read — call entered, call returned,
+result ready — that feeds the iteration's ring record, the running
+totals, the `serve.engine.*` profiler annotations and the phase
+histogram alike.
 """
 
 from __future__ import annotations
 
+import gc
 import queue
 import threading
 import time
@@ -125,7 +131,8 @@ def _m_phase():
     from ..util import metrics as mm
     return _metric("phase", lambda: mm.Histogram(
         "ray_tpu_serve_step_phase_seconds",
-        description="Engine loop phase durations (swap/prefill/decode)",
+        description="Engine loop phase durations "
+                    "(swap/prefill/decode/dispatch)",
         boundaries=_PHASE_BOUNDARIES, tag_keys=("phase",)))
 
 
@@ -495,10 +502,18 @@ class ContinuousEngine:
                         "queue_wait_s": 0.0, "prefill_s": 0.0,
                         "decode_s": 0.0, "host_s": 0.0,
                         "device_wait_s": 0.0, "blocked_slot_s": 0.0,
-                        "prefill_tokens": 0, "prefill_scanned_tokens": 0}
+                        "dispatch_s": 0.0, "ready_wait_s": 0.0,
+                        "launches": 0,
+                        "prefill_tokens": 0, "prefill_scanned_tokens": 0,
+                        # garbage collections of this process while the
+                        # engine thread lived (_on_gc alone writes them)
+                        "gc_s": 0.0, "gc_collections": 0, "gc_max_s": 0.0}
+        self._gc_t0: Optional[float] = None   # a collection under way
+        self._iter = 0               # iterations since the engine started
+        self._t_call = self._t_ret = 0.0    # the last launch: entered, returned
         # per-iteration scratch of the engine thread (_iteration resets)
         self._last_prefill_s = 0.0
-        self._wait_s = 0.0           # blocked on the device
+        self._launched: Dict[str, float] = {}   # _launch / _wait's sums
         self._blocked = 0            # streaming slots an admission held up
         self._first: List[_Sequence] = []   # first token this iteration
         self._chunks = 0             # prefill programs run
@@ -777,6 +792,29 @@ class ContinuousEngine:
             self._thread.start()
 
     def _loop(self):
+        # the interpreter tells this hook of every collection, whichever
+        # thread triggers it, for as long as the engine thread lives
+        gc.callbacks.append(self._on_gc)
+        try:
+            self._run()
+        finally:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]):
+        """`gc.callbacks` hook: two clock reads a collection.  It runs on
+        the collecting thread with the interpreter held and collections
+        do not nest, so nothing else writes these three sums — and it
+        takes no lock (the thread it interrupts may hold `_lock`)."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            dt, self._gc_t0 = time.perf_counter() - self._gc_t0, None
+            tot = self._totals
+            tot["gc_s"] += dt
+            tot["gc_collections"] += 1
+            tot["gc_max_s"] = max(tot["gc_max_s"], dt)
+
+    def _run(self):
         while True:
             with self._lock:
                 if self._stopped:
@@ -784,7 +822,9 @@ class ContinuousEngine:
                 busy = bool(self._waiting) or any(
                     s is not None for s in self._slots)
             if not busy:
-                self._wake.wait(timeout=0.2)
+                # nothing to run: the device's idle time here is nobody's
+                with self._jax.profiler.TraceAnnotation("serve.engine.idle"):
+                    self._wake.wait(timeout=0.2)
                 self._wake.clear()
                 continue
             try:
@@ -808,19 +848,23 @@ class ContinuousEngine:
 
     def _iteration(self):
         """One scheduler iteration: admit, step, account.  Every phase
-        boundary is one `perf_counter` read that feeds three outputs:
+        boundary is one `perf_counter` read that feeds every output:
         the ring record (and `_totals`), the `serve.engine.*`
         annotations on the profiler's clock — a flag test each while no
-        profiler session is open — and, per request, the retro
-        `engine.*` spans emitted by `_finish`."""
+        profiler session is open — the phase histogram and, per request,
+        the retro `engine.*` spans emitted by `_finish`."""
         ann = self._jax.profiler.TraceAnnotation
         t0 = time.perf_counter()
-        self._wait_s = 0.0
+        self._iter += 1
+        gc0 = self._totals["gc_s"]
+        it = self._launched = {"dispatch_s": 0.0, "ready_wait_s": 0.0,
+                               "launches": 0, "step_dispatch_s": 0.0,
+                               "step_wait_s": 0.0}
         self._blocked = 0
         self._first = []
         self._chunks = self._chunk_tokens = self._returned = 0
         self._stats = dict.fromkeys(self._stat_keys, 0.0)
-        with ann("serve.engine.admit"):
+        with ann("serve.engine.admit", iter=self._iter):
             admitted = self._admit()
         t1 = time.perf_counter()
         stepped = 0
@@ -834,7 +878,11 @@ class ContinuousEngine:
                    "prefill_s": self._last_prefill_s if worked else 0.0,
                    "decode_s": (t2 - t1) if stepped else 0.0,
                    "active": stepped, "admitted": admitted, "ts": t2,
-                   "t0": t0, "device_wait_s": self._wait_s,
+                   "t0": t0, "iter": self._iter,
+                   # blocked on the device: inside a launch, or waiting
+                   # for what the last one returns
+                   "device_wait_s": it["dispatch_s"] + it["ready_wait_s"],
+                   **it,
                    "blocked_slots": self._blocked,
                    "chunks": self._chunks,
                    "chunk_tokens": self._chunk_tokens,
@@ -854,6 +902,8 @@ class ContinuousEngine:
                     m.observe(rec["prefill_s"], tags={"phase": "prefill"})
                 if stepped:
                     m.observe(rec["decode_s"], tags={"phase": "decode"})
+                if it["launches"]:
+                    m.observe(it["dispatch_s"], tags={"phase": "dispatch"})
             with self._lock:
                 qd = len(self._waiting)
             for which, val in (("active", stepped),
@@ -863,13 +913,15 @@ class ContinuousEngine:
                 if g:
                     g.set(val)
             # the record closes here: only its append comes after
+            rec["gc_s"] = self._totals["gc_s"] - gc0
             rec["iter_s"] = time.perf_counter() - t0
-            rec["host_s"] = rec["iter_s"] - self._wait_s
+            rec["host_s"] = rec["iter_s"] - rec["device_wait_s"]
             tot = self._totals
             with self._lock:
                 self._ring.append(rec)
                 for k in ("prefill_s", "decode_s", "host_s",
-                          "device_wait_s"):
+                          "device_wait_s", "dispatch_s", "ready_wait_s",
+                          "launches"):
                     tot[k] += rec[k]
                 tot["blocked_slot_s"] += rec["swap_s"] * rec["blocked_slots"]
                 for r in rec["requests"]:
@@ -877,6 +929,38 @@ class ContinuousEngine:
                     tot["prefill_tokens"] += (r["prompt_tokens"]
                                               - r["shared_tokens"])
                     tot["prefill_scanned_tokens"] += r["scanned_tokens"]
+
+    # -- launch and wait ----------------------------------------------------
+
+    def _launch(self, program: str, fn, *args):
+        """Hand one program to the chip -> what `fn(*args)` returns.  Two
+        clock reads, kept for the caller: call entered (`_t_call`), call
+        returned (`_t_ret`) — operands converted and put on the device,
+        the program enqueued; the device may or may not have started, the
+        host can do nothing else.  `program` is the compilation ledger's
+        name (`serve.keys`: the one expression that seeds and splits a
+        request's keys, three small programs of jax's own)."""
+        with self._jax.profiler.TraceAnnotation("serve.engine.dispatch",
+                                                program=program):
+            self._t_call = time.perf_counter()
+            out = fn(*args)
+            self._t_ret = time.perf_counter()
+        self._launched["dispatch_s"] += self._t_ret - self._t_call
+        self._launched["launches"] += 1
+        return out
+
+    def _wait(self, name: str, *fetch, ready=None) -> Tuple[float, list]:
+        """Wait, under annotation `name`, for what the last launch
+        returns: `ready` on the device, each of `fetch` on the host ->
+        (the clock then, the fetched arrays).  One clock read; the wait
+        counts from the last launch's return."""
+        with self._jax.profiler.TraceAnnotation(name):
+            if ready is not None:
+                self._jax.block_until_ready(ready)
+            out = [self._np.asarray(x) for x in fetch]
+            t = time.perf_counter()
+        self._launched["ready_wait_s"] += t - self._t_ret
+        return t, out
 
     @staticmethod
     def _request_record(s: _Sequence) -> Dict[str, Any]:
@@ -887,7 +971,7 @@ class ContinuousEngine:
                 "queue_wait_s": s.t_admit - s.t_submit,
                 # its own prefill programs; what lay between a chunked
                 # prompt's chunks (the others' decode steps) is apart
-                "prefill_s": s.prefill_s, "chunks": s.chunks,
+                "prefill_s": s.prefill_s,
                 "chunk_wait_s": s.t_ready - s.t_prefill - s.prefill_s,
                 "first_step_wait_s": s.t_first - s.t_ready,
                 "ttft_s": s.t_first - s.t_submit,
@@ -985,9 +1069,9 @@ class ContinuousEngine:
         self._totals["cow_copies"] += len(plan["copies"])
         self._totals["shared_pages"] += plan["n_shared"]
         for src, dst in plan["copies"]:
-            self._cache = self._fn("copy_page")(self._cache,
-                                                np.int32(dst),
-                                                np.int32(src))
+            self._cache = self._launch(
+                "serve.copy_page", self._fn("copy_page"), self._cache,
+                np.int32(dst), np.int32(src))
         seq.slot = slot
         seq.pos = plen
         seq.shared = seq.next_start = shared_len
@@ -1014,19 +1098,20 @@ class ContinuousEngine:
         chunk[:n] = seq.tokens[start:start + n]
         with ann("serve.engine.prefill", request_id=seq.request_id or "",
                  tokens=n, bucket=T):
-            t0 = time.perf_counter()
-            if not seq.chunks:
-                seq.t_prefill = t0
-            logits, self._cache, stats = self._fn(("prefill", T))(
+            logits, self._cache, stats = self._launch(
+                f"serve.prefill:{T}", self._fn(("prefill", T)),
                 self._params, self._cache, chunk, seq.tabs,
                 np.int32(start), np.int32(n - 1))
+            t0 = self._t_call
+            if not seq.chunks:
+                seq.t_prefill = t0
             if last:
                 with ann("serve.engine.setrow"):
-                    self._logits = self._fn("setrow")(self._logits, logits,
-                                                      np.int32(slot))
+                    self._logits = self._launch(
+                        "serve.setrow", self._fn("setrow"), self._logits,
+                        logits, np.int32(slot))
                 logits = self._logits
-            jax.block_until_ready(logits)
-            t1 = time.perf_counter()
+            t1, stats = self._wait("serve.engine.wait", *stats, ready=logits)
         self._note_stats(stats, "chunk_")
         seq.prefill_s += t1 - t0
         seq.scanned += T
@@ -1035,7 +1120,6 @@ class ContinuousEngine:
         self._chunks += 1
         self._chunk_tokens += n
         self._last_prefill_s += t1 - t0
-        self._wait_s += t1 - t0
         self._totals["chunks"] += 1
         self._shrink_windows(seq, seq.next_start)
         if not last:
@@ -1053,11 +1137,12 @@ class ContinuousEngine:
         # after the prefill, so that nothing but page-table bookkeeping
         # lies between a request's queue wait and its prefill
         with ann("serve.engine.keys"):
-            seq.keys = np.asarray(jax.random.split(
-                jax.random.PRNGKey(seq.seed),
-                seq.key_offset + seq.max_new))[seq.key_offset:]
-            tk = time.perf_counter()
-        self._wait_s += tk - t1
+            keys = self._launch(
+                "serve.keys", lambda: jax.random.split(
+                    jax.random.PRNGKey(seq.seed),
+                    seq.key_offset + seq.max_new))
+            _, (keys,) = self._wait("serve.engine.wait", keys)
+        seq.keys = keys[seq.key_offset:]
         self._totals["prefills"] += 1
 
         # register this prompt's full pages for live prefix sharing
@@ -1069,10 +1154,10 @@ class ContinuousEngine:
 
     def _note_stats(self, stats, prefix: str = ""):
         """The model's own counters of one program (`STEP_STATS` of its
-        module: names of the f32 vector its serve programs return),
-        summed into this iteration's record."""
+        module: names of the f32 vector its serve programs return, here
+        as `_wait` fetched it), summed into this iteration's record."""
         if stats:
-            for k, v in zip(self._stat_names, self._np.asarray(stats[0])):
+            for k, v in zip(self._stat_names, stats[0]):
                 self._stats[prefix + k] += float(v)
 
     # -- windowed pools -----------------------------------------------------
@@ -1135,8 +1220,8 @@ class ContinuousEngine:
         """One fused sample+decode step over every slot.  Inactive slots
         ride along at pos 0 against the null page; their tokens are
         discarded here on the host."""
-        np, ann = self._np, self._jax.profiler.TraceAnnotation
-        with ann("serve.engine.step"):
+        ann = self._jax.profiler.TraceAnnotation
+        with ann("serve.engine.step", iter=self._iter):
             active = [(i, s) for i, s in enumerate(self._slots)
                       if s is not None and not s.prefilling]
             for i, s in active:
@@ -1149,15 +1234,15 @@ class ContinuousEngine:
                                            self.max_pages_per_seq)
                 self._stats["kv_read"] += read
                 self._stats["kv_span"] += span
-            td = time.perf_counter()
-            toks, self._logits, self._cache, stats = self._fn("step")(
+            toks, self._logits, self._cache, stats = self._launch(
+                "serve.step", self._fn("step"),
                 self._params, self._cache, self._logits, self._toks_keys,
                 self._temps, self._topks, self._ptabs, self._pos)
-        with ann("serve.engine.fetch"):
-            toks = np.asarray(toks)
-            self._note_stats(stats)
-            now = time.perf_counter()
-        self._wait_s += now - td
+        now, (toks, *stats) = self._wait("serve.engine.fetch", toks, *stats)
+        self._note_stats(stats)
+        # the step program's own two parts, apart from an admission's
+        self._launched["step_dispatch_s"] = self._t_ret - self._t_call
+        self._launched["step_wait_s"] = now - self._t_ret
         with ann("serve.engine.emit"):
             self._totals["steps"] += 1
             emitted = 0

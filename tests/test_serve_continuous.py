@@ -9,6 +9,7 @@ object plus a daemon thread, and the jit programs run on CPU.
 """
 
 import asyncio
+import gc
 import threading
 import time
 
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gpt
+from ray_tpu.serve import _engine
 from ray_tpu.serve._engine import (AdmissionRejected, ContinuousEngine,
                                    PageAllocator)
 
@@ -313,7 +315,9 @@ def test_engine_stats_shape(model):
                 "retry_after_s", "ttft_p99_s", "tokens_per_s", "requests",
                 "tokens", "steps", "prefills", "queue_wait_s", "prefill_s",
                 "decode_s", "host_s", "device_wait_s", "blocked_slot_s",
-                "prefill_tokens", "prefill_scanned_tokens"):
+                "prefill_tokens", "prefill_scanned_tokens", "dispatch_s",
+                "ready_wait_s", "launches", "gc_s", "gc_collections",
+                "gc_max_s"):
         assert key in st, key
     assert st["active"] == 0 and st["accepting"]
     assert eng._census_report()["cache"] == "paged"
@@ -339,10 +343,52 @@ def test_stop_fails_waiting_requests(model):
 # ---------------------------------------------------------------------------
 # the engine measures itself: ring records, annotations, request spans
 
-_ANNOTATIONS = ["serve.engine.admit", "serve.engine.prefill",
-                "serve.engine.setrow", "serve.engine.keys",
-                "serve.engine.step", "serve.engine.fetch",
-                "serve.engine.emit", "serve.engine.account"]
+# an iteration that admits one unchunked prompt, each annotation under
+# the one it lies in: every launch is a `dispatch` inside the annotation
+# that was there, and nothing between two launches is unannotated
+_D, _W = "serve.engine.dispatch", "serve.engine.wait"
+_ANNOTATIONS = [("serve.engine.admit", None),
+                ("serve.engine.prefill", "serve.engine.admit"),
+                (_D, "serve.engine.prefill"),
+                ("serve.engine.setrow", "serve.engine.prefill"),
+                (_D, "serve.engine.setrow"),
+                (_W, "serve.engine.prefill"),
+                ("serve.engine.keys", "serve.engine.admit"),
+                (_D, "serve.engine.keys"), (_W, "serve.engine.keys"),
+                ("serve.engine.step", None), (_D, "serve.engine.step"),
+                ("serve.engine.fetch", None), ("serve.engine.emit", None),
+                ("serve.engine.account", None)]
+# the launches of that iteration as the engine counts them (`launches`):
+# the key expression is one launch, of three small programs of jax's own
+_PROGRAMS = ["serve.prefill:8", "serve.setrow", "serve.keys", "serve.step"]
+
+
+class _Recorder:
+    """Stands in for `jax.profiler.TraceAnnotation`: what was entered,
+    with its stats and the annotation it was entered under."""
+
+    seen: list = []
+    stack: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        self.seen.append((self.name, self.kwargs,
+                          self.stack[-1] if self.stack else None))
+        self.stack.append(self.name)
+
+    def __exit__(self, *exc):
+        self.stack.pop()
+        return False
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    monkeypatch.setattr(_Recorder, "seen", [])
+    monkeypatch.setattr(_Recorder, "stack", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    return _Recorder
 
 
 def _second_request_while_first_decodes(model):
@@ -357,9 +403,10 @@ def _second_request_while_first_decodes(model):
                             request_id="req-2")
         eng.collect(first, timeout=120)
         eng.collect(second, timeout=120)
-        return eng.engine_stats(), eng.phase_ring(), second.rid
     finally:
         eng.stop()
+    # read once the engine thread has ended: the last record is in
+    return eng.engine_stats(), eng.phase_ring(), second.rid
 
 
 def test_ring_decomposes_ttft_and_iteration_time(model):
@@ -377,7 +424,8 @@ def test_ring_decomposes_ttft_and_iteration_time(model):
         assert [r["blocked_slots"] for r in ring
                 if not r["admitted"]] == [0] * (len(ring) - 2)
         assert sum(len(r["requests"]) for r in ring) == 2
-        for key in ("prefill_s", "decode_s", "host_s", "device_wait_s"):
+        for key in ("prefill_s", "decode_s", "host_s", "device_wait_s",
+                    "dispatch_s", "ready_wait_s", "launches"):
             assert st[key] == pytest.approx(sum(r[key] for r in ring))
         assert st["blocked_slot_s"] == pytest.approx(
             sum(r["swap_s"] * r["blocked_slots"] for r in ring))
@@ -400,36 +448,152 @@ def test_ring_decomposes_ttft_and_iteration_time(model):
     assert all(r["host_s"] > 0 and r["device_wait_s"] > 0 for r in ring)
 
 
-def test_iteration_places_annotations_in_order(model, monkeypatch):
-    seen = []
+def test_ring_splits_device_wait_into_dispatch_and_ready_wait(model):
+    """Every launch is stamped entered -> returned, every wait from the
+    last launch's return: the two sums ARE `device_wait_s` (same stamps),
+    the step's own two parts lie inside `decode_s`, and a record carries
+    its iteration's ordinal and the launches it made."""
+    st, ring, rid = _second_request_while_first_decodes(model)
+    assert [r["iter"] for r in ring] == list(range(1, len(ring) + 1))
+    # a plain iteration launches the step; one that admits an unchunked
+    # prompt its prefill, its row, its keys, and the step
+    assert [r["launches"] for r in ring] == [
+        len(_PROGRAMS) if r["admitted"] else 1 for r in ring]
+    assert sum(r["admitted"] for r in ring) == 2
+    for r in ring:
+        assert r["dispatch_s"] + r["ready_wait_s"] == pytest.approx(
+            r["device_wait_s"], abs=1e-9)
+        assert r["host_s"] + r["device_wait_s"] == pytest.approx(
+            r["iter_s"], abs=1e-9)
+        assert 0 < r["step_dispatch_s"] and 0 < r["step_wait_s"]
+        assert r["step_dispatch_s"] + r["step_wait_s"] <= r["decode_s"]
+        if r["admitted"]:       # the admission's programs are apart
+            assert r["step_dispatch_s"] < r["dispatch_s"]
+            assert r["step_wait_s"] < r["ready_wait_s"]
+        else:
+            assert r["step_dispatch_s"] == r["dispatch_s"]
+            assert r["step_wait_s"] == r["ready_wait_s"]
+    assert st["launches"] == 2 * len(_PROGRAMS) + len(ring) - 2
 
-    class Recorder:
-        def __init__(self, name, **kwargs):
-            self.item = (name, kwargs)
 
-        def __enter__(self):
-            seen.append(self.item)
+def test_chunk_iterations_launch_one_program_and_no_step(model):
+    """A prompt of three chunks alone in the engine: two iterations run
+    one prefill program each and no step (`step_dispatch_s` 0), the third
+    the last chunk, the row, the keys and the first step."""
+    eng = _make_engine(model, prefill_chunk=8)
+    try:
+        eng.collect(eng.submit(list(range(1, 21)), max_new_tokens=2),
+                    timeout=120)
+    finally:
+        eng.stop()
+    ring = eng.phase_ring()         # whole: the engine thread has ended
+    assert [r["launches"] for r in ring] == [1, 1, len(_PROGRAMS), 1]
+    assert [r["chunks"] for r in ring] == [1, 1, 1, 0]
+    for r in ring[:2]:
+        assert r["active"] == 0 and r["decode_s"] == 0
+        assert r["step_dispatch_s"] == 0 and r["step_wait_s"] == 0
+        assert 0 < r["dispatch_s"] < r["device_wait_s"] <= r["swap_s"]
 
-        def __exit__(self, *exc):
-            return False
 
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+def test_iteration_nests_annotations_and_names_every_launch(model, recorder):
     eng = _make_engine(model)
     try:
         eng.collect(eng.submit(PROMPT, max_new_tokens=3,
                                request_id="req-7"), timeout=120)
     finally:
         eng.stop()
-    names = [n for n, _ in seen]
-    first = names.index("serve.engine.account") + 1
-    assert names[:first] == _ANNOTATIONS         # the admitting iteration
-    decode_only = [n for n in _ANNOTATIONS if n.split(".")[-1]
-                   not in ("keys", "prefill", "setrow")]
-    assert names[first:first + len(decode_only)] == decode_only
-    kwargs = dict(seen)
-    assert kwargs["serve.engine.prefill"] == {
-        "request_id": "req-7", "tokens": len(PROMPT), "bucket": 8}
-    assert not any(kw for n, kw in seen if n != "serve.engine.prefill")
+    seen, ring = recorder.seen, eng.phase_ring()
+    nest = [(n, parent) for n, _, parent in seen]
+    first = nest.index(("serve.engine.account", None)) + 1
+    assert nest[:first] == _ANNOTATIONS          # the admitting iteration
+    decode_only = _ANNOTATIONS[_ANNOTATIONS.index(("serve.engine.step",
+                                                   None)):]
+    plain = [("serve.engine.admit", None)] + decode_only
+    assert nest[first:first + len(plain)] == plain
+    # a launch names its program as the compilation ledger does
+    assert [kw["program"] for n, kw, _ in seen[:first]
+            if n == _D] == _PROGRAMS
+    (prefill,) = [kw for n, kw, _ in seen if n == "serve.engine.prefill"]
+    assert prefill == {"request_id": "req-7", "tokens": len(PROMPT),
+                       "bucket": 8}
+    # `admit` and `step` carry the ordinal of their iteration's record
+    for name in ("serve.engine.admit", "serve.engine.step"):
+        assert [kw for n, kw, _ in seen if n == name] == [
+            {"iter": r["iter"]} for r in ring]
+    assert not any(kw for n, kw, _ in seen if n in (
+        _W, "serve.engine.setrow", "serve.engine.keys",
+        "serve.engine.fetch", "serve.engine.emit", "serve.engine.account"))
+
+
+def test_copy_on_write_is_a_launch_inside_admit(model, recorder):
+    """The page copy of an exact-duplicate prompt goes through the same
+    helper: a `dispatch` named `serve.copy_page` under `admit`, counted
+    in that iteration's `launches` and `dispatch_s`."""
+    prompt = list(range(40, 56))               # two full pages of 8
+    eng = _make_engine(model)
+    try:
+        a = eng.submit(prompt, max_new_tokens=24)
+        next(eng.stream(a))                     # a's pages are registered
+        eng.collect(eng.submit(prompt, max_new_tokens=2), timeout=120)
+        eng.collect(a, timeout=120)
+    finally:
+        eng.stop()
+    st, ring = eng.engine_stats(), eng.phase_ring()
+    assert st["cow_copies"] == 1
+    assert [(kw["program"], parent) for n, kw, parent in recorder.seen
+            if n == _D and kw["program"] == "serve.copy_page"] == [
+        ("serve.copy_page", "serve.engine.admit")]
+    assert [r["launches"] for r in ring if r["admitted"]] == [
+        len(_PROGRAMS), len(_PROGRAMS) + 1]
+
+
+def test_gc_hook_times_collections_and_leaves_with_the_thread(model):
+    """A collection made by another thread while the engine iterates is
+    in some iteration's `gc_s` and in the totals; the hook is there while
+    the engine thread lives and gone once it has stopped."""
+    eng = _make_engine(model)
+    assert eng._on_gc not in gc.callbacks       # no thread yet, no hook
+    try:
+        seq = eng.submit(PROMPT, max_new_tokens=40, stream=True)
+        collected = 0
+        for _ in eng.stream(seq):               # this thread, not its own
+            assert eng._on_gc in gc.callbacks
+            gc.collect()
+            collected += 1
+    finally:
+        eng.stop()
+    st, ring = eng.engine_stats(), eng.phase_ring()
+    assert eng._on_gc not in gc.callbacks
+    assert collected == 40 and st["gc_collections"] >= collected
+    assert any(r["gc_s"] > 0 for r in ring)
+    assert all(r["gc_s"] >= 0 for r in ring)
+    # what the records hold is part of what the process spent
+    assert 0 < sum(r["gc_s"] for r in ring) <= st["gc_s"] * (1 + 1e-9)
+    assert 0 < st["gc_max_s"] <= st["gc_s"]
+
+
+def test_phase_histogram_observes_dispatch_once_an_iteration(model,
+                                                             monkeypatch):
+    seen = []
+
+    class Histogram:
+        @staticmethod
+        def observe(value, tags=None):
+            seen.append((tags["phase"], value))
+
+    monkeypatch.setattr(_engine, "_m_phase", Histogram)
+    eng = _make_engine(model)
+    try:
+        eng.collect(eng.submit(PROMPT, max_new_tokens=3), timeout=120)
+    finally:
+        eng.stop()
+    ring = eng.phase_ring()
+    assert [v for ph, v in seen if ph == "dispatch"] == [
+        r["dispatch_s"] for r in ring]
+    assert [v for ph, v in seen if ph == "decode"] == [
+        r["decode_s"] for r in ring]
+    assert {ph for ph, _ in seen} == {"swap", "prefill", "decode",
+                                      "dispatch"}
 
 
 def test_finished_request_emits_engine_spans(model, monkeypatch):
