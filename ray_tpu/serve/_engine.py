@@ -17,8 +17,9 @@ chunk-wide QKV projection, one scatter of the chunk's K and V rows into
 the sequence's pages, and the same masked attention the decode step
 runs (a decode step is its one-row case).  The program is a function of
 the padded length alone; where the chunk starts, which row yields the
-logits and the page-table row are operands.  It runs on the engine
-thread inside `_admit`, so every streaming slot waits for it.
+logits and the page-table row are operands.  The engine thread launches
+it inside `_admit` and the step right behind it, so every streaming slot
+waits for it on the chip — and the thread waits once, for both.
 
 A prompt longer than `prefill_chunk` (0: off) is prefilled in chunks of
 that size through one program, the tail padded to `prefill_bucket`: one
@@ -76,7 +77,11 @@ the chip through one helper and waits through one other (`_launch`,
 `_wait`), each boundary one clock read — call entered, call returned,
 result ready — that feeds the iteration's ring record, the running
 totals, the `serve.engine.*` profiler annotations and the phase
-histogram alike.
+histogram alike.  Within an iteration it enqueues, then waits: an
+admission's programs and the step are launched back to back on arrays
+that are not ready yet, and only then does the thread block — first on
+what each prefill returned, for the clock read that says when it was
+done, then on the step's tokens.
 """
 
 from __future__ import annotations
@@ -346,7 +351,7 @@ class _Sequence:
         self.pages: List[int] = []
         self.pos = 0
         self.generated: List[int] = []
-        self.keys = None            # np [max_new, 2] uint32, set at admit
+        self.keys = None            # np [max_new, 2] uint32 if it samples
         # clock reads at the request's phase boundaries (perf_counter):
         # submit -> popped from _waiting -> prefill dispatched -> prefill
         # ready -> first token out -> last token out.  They feed the
@@ -369,7 +374,7 @@ class _Sequence:
         self.next_start = 0         # first prompt position not prefilled
         self.chunks = 0             # prefill programs run for it
         self.prefill_s = 0.0        # their seconds, dispatch -> ready
-        self.prefilling = False     # holds a slot, not yet decoding
+        self.prefilling = False     # holds a slot, its rows not the step's
 
 
 class ContinuousEngine:
@@ -510,9 +515,14 @@ class ContinuousEngine:
                         "gc_s": 0.0, "gc_collections": 0, "gc_max_s": 0.0}
         self._gc_t0: Optional[float] = None   # a collection under way
         self._iter = 0               # iterations since the engine started
-        self._t_call = self._t_ret = 0.0    # the last launch: entered, returned
+        self._t_call = 0.0           # the last launch was entered
+        self._t_free = 0.0           # the last launch returned, or wait ended
         # per-iteration scratch of the engine thread (_iteration resets)
         self._last_prefill_s = 0.0
+        # prefill programs launched and not yet stamped ready: (sequence,
+        # call entered, its logits row, its counters, the prompt's last?)
+        self._pending: List[Tuple[_Sequence, float, Any, tuple, bool]] = []
+        self._step_out: Any = None   # the launched step's tokens, counters
         self._launched: Dict[str, float] = {}   # _launch / _wait's sums
         self._blocked = 0            # streaming slots an admission held up
         self._first: List[_Sequence] = []   # first token this iteration
@@ -841,7 +851,8 @@ class ContinuousEngine:
                 self._prefilling = None
                 # the program that raised may have consumed the state
                 # it was given: _ensure_device_state makes it anew
-                self._cache = self._logits = None
+                self._cache = self._logits = self._step_out = None
+                self._pending = []
                 for s in seqs:
                     self._release(s)
                     self._finish(s, error=e)
@@ -858,18 +869,25 @@ class ContinuousEngine:
         self._iter += 1
         gc0 = self._totals["gc_s"]
         it = self._launched = {"dispatch_s": 0.0, "ready_wait_s": 0.0,
-                               "launches": 0, "step_dispatch_s": 0.0,
-                               "step_wait_s": 0.0}
+                               "launches": 0, "waits": 0,
+                               "step_dispatch_s": 0.0, "step_wait_s": 0.0}
         self._blocked = 0
         self._first = []
         self._chunks = self._chunk_tokens = self._returned = 0
         self._stats = dict.fromkeys(self._stat_keys, 0.0)
+        # enqueue: the admission's programs, the step behind them ...
         with ann("serve.engine.admit", iter=self._iter):
             admitted = self._admit()
         t1 = time.perf_counter()
-        stepped = 0
+        active = None
         if any(s is not None and not s.prefilling for s in self._slots):
-            stepped = self._step()
+            active = self._step()
+        # ... then wait: the admission's work is done on the chip where
+        # its last prefill is (the step already runs behind it), the
+        # iteration where the step's tokens are on the host
+        if self._pending:
+            t1 = self._stamp_prefills()
+        stepped = self._emit(active) if active else 0
         t2 = time.perf_counter()
         with ann("serve.engine.account"):
             # a chunk of a prompt already admitted is admission work too
@@ -933,40 +951,47 @@ class ContinuousEngine:
     # -- launch and wait ----------------------------------------------------
 
     def _launch(self, program: str, fn, *args):
-        """Hand one program to the chip -> what `fn(*args)` returns.  Two
-        clock reads, kept for the caller: call entered (`_t_call`), call
-        returned (`_t_ret`) — operands converted and put on the device,
-        the program enqueued; the device may or may not have started, the
-        host can do nothing else.  `program` is the compilation ledger's
-        name (`serve.keys`: the one expression that seeds and splits a
-        request's keys, three small programs of jax's own)."""
+        """Hand one program to the chip -> what `fn(*args)` returns, which
+        may not be ready and may be handed straight to the next launch.
+        Two clock reads, kept for the caller: call entered (`_t_call`),
+        call returned (`_t_free`) — operands converted and put on the
+        device, the program enqueued; the device may or may not have
+        started, the host can do nothing else.  `program` is the
+        compilation ledger's name (`serve.keys`: the one expression that
+        seeds and splits a request's keys, three small programs of jax's
+        own).  A program that fails may say so only where its result is
+        waited for."""
         with self._jax.profiler.TraceAnnotation("serve.engine.dispatch",
                                                 program=program):
             self._t_call = time.perf_counter()
             out = fn(*args)
-            self._t_ret = time.perf_counter()
-        self._launched["dispatch_s"] += self._t_ret - self._t_call
+            self._t_free = time.perf_counter()
+        self._launched["dispatch_s"] += self._t_free - self._t_call
         self._launched["launches"] += 1
         return out
 
     def _wait(self, name: str, *fetch, ready=None) -> Tuple[float, list]:
-        """Wait, under annotation `name`, for what the last launch
-        returns: `ready` on the device, each of `fetch` on the host ->
-        (the clock then, the fetched arrays).  One clock read; the wait
-        counts from the last launch's return."""
+        """Block, under annotation `name`, until `ready` is done on the
+        device and each of `fetch` is on the host -> (the clock then, the
+        fetched arrays).  One clock read; the wait counts from where the
+        thread was last let go: the last launch's return, or the wait
+        before this one."""
         with self._jax.profiler.TraceAnnotation(name):
             if ready is not None:
                 self._jax.block_until_ready(ready)
             out = [self._np.asarray(x) for x in fetch]
             t = time.perf_counter()
-        self._launched["ready_wait_s"] += t - self._t_ret
+        self._launched["ready_wait_s"] += t - self._t_free
+        self._launched["waits"] += 1
+        self._t_free = t
         return t, out
 
     @staticmethod
     def _request_record(s: _Sequence) -> Dict[str, Any]:
         """The TTFT of one request by parts, as the ring keeps it: the
         four parts sum to `ttft_s` up to the bookkeeping between
-        admission and the prefill's dispatch (page table, operands)."""
+        admission and the prefill's dispatch (page table, operands; a
+        sampling request's key launch)."""
         return {"rid": s.rid, "request_id": s.request_id,
                 "queue_wait_s": s.t_admit - s.t_submit,
                 # its own prefill programs; what lay between a chunked
@@ -1075,16 +1100,20 @@ class ContinuousEngine:
         seq.slot = slot
         seq.pos = plen
         seq.shared = seq.next_start = shared_len
+        seq.prefilling = True
         self._temps[slot] = seq.temperature
         self._topks[slot] = int(seq.top_k or 0)
         self._prefill_next(seq)
 
     def _prefill_next(self, seq: _Sequence):
-        """The next prefill program of `seq`: the rest of its prompt
-        padded to a multiple of `prefill_bucket` (pad rows cost matmul
-        rows, not steps), or one `prefill_chunk` of it.  After the last
-        one the sequence's tables and position enter the step's operands
-        and it decodes; until then its slot rides the step as empty."""
+        """Launch the next prefill program of `seq`: the rest of its
+        prompt padded to a multiple of `prefill_bucket` (pad rows cost
+        matmul rows, not steps), or one `prefill_chunk` of it.  After the
+        last one the sequence's tables and position enter the step's
+        operands and it decodes; until then its slot rides the step as
+        empty.  Nothing is waited for here: the step is launched behind
+        what this returns, and `_stamp_prefills` reads the clock where
+        the program is done."""
         jax, np = self._jax, self._np
         ann = jax.profiler.TraceAnnotation
         plen, start, slot = len(seq.tokens), seq.next_start, seq.slot
@@ -1093,56 +1122,57 @@ class ContinuousEngine:
             n = self.prefill_chunk
         T = -(-n // self.prefill_bucket) * self.prefill_bucket
         last = start + n == plen
+        # a request that samples draws its keys on the chip, ahead of
+        # its last prefill program: they are done before it starts
+        keys = self._launch_keys(seq) if last and seq.temperature > 0 \
+            else None
         self._grow_windows(seq, start, start + n)
         chunk = np.zeros(T, np.int32)
         chunk[:n] = seq.tokens[start:start + n]
+        # the program may read a host operand after its launch returns,
+        # and the sequence's tables change before it is waited for (a
+        # window's pages go back, the step takes the next): it gets its
+        # own copy of them
+        tabs = {k: tab.copy() for k, tab in seq.tabs.items()}
         with ann("serve.engine.prefill", request_id=seq.request_id or "",
                  tokens=n, bucket=T):
             logits, self._cache, stats = self._launch(
                 f"serve.prefill:{T}", self._fn(("prefill", T)),
-                self._params, self._cache, chunk, seq.tabs,
+                self._params, self._cache, chunk, tabs,
                 np.int32(start), np.int32(n - 1))
-            t0 = self._t_call
             if not seq.chunks:
-                seq.t_prefill = t0
+                seq.t_prefill = self._t_call
+            # what no later program consumes: the row and the counters
+            self._pending.append((seq, self._t_call, logits, stats, last))
             if last:
                 with ann("serve.engine.setrow"):
                     self._logits = self._launch(
                         "serve.setrow", self._fn("setrow"), self._logits,
                         logits, np.int32(slot))
-                logits = self._logits
-            t1, stats = self._wait("serve.engine.wait", *stats, ready=logits)
-        self._note_stats(stats, "chunk_")
-        seq.prefill_s += t1 - t0
         seq.scanned += T
         seq.chunks += 1
         seq.next_start = start + n
         self._chunks += 1
         self._chunk_tokens += n
-        self._last_prefill_s += t1 - t0
         self._totals["chunks"] += 1
         self._shrink_windows(seq, seq.next_start)
         if not last:
-            seq.prefilling, self._prefilling = True, seq
+            self._prefilling = seq
             return
+        # the hand-over: from here the sequence's rows are the step's
         seq.prefilling, self._prefilling = False, None
-        seq.t_ready = t1
         for k, tab in self._ptabs.items():
             tab[slot] = seq.tabs[k]
         self._pos[slot] = plen                  # first decode write pos
-        # key_offset: a resumed continuation (router replay) re-derives
-        # the ORIGINAL request's key schedule and skips the keys its
-        # already-delivered tokens consumed — sampled decode stays
-        # bitwise-identical across the resume, same as greedy.  Split
-        # after the prefill, so that nothing but page-table bookkeeping
-        # lies between a request's queue wait and its prefill
-        with ann("serve.engine.keys"):
-            keys = self._launch(
-                "serve.keys", lambda: jax.random.split(
-                    jax.random.PRNGKey(seq.seed),
-                    seq.key_offset + seq.max_new))
-            _, (keys,) = self._wait("serve.engine.wait", keys)
-        seq.keys = keys[seq.key_offset:]
+        if keys is not None:
+            with ann("serve.engine.keys"):
+                _, (keys,) = self._wait("serve.engine.wait", keys)
+            # key_offset: a resumed continuation (router replay)
+            # re-derives the ORIGINAL request's key schedule and skips
+            # the keys its already-delivered tokens consumed — sampled
+            # decode stays bitwise-identical across the resume, same as
+            # greedy
+            seq.keys = keys[seq.key_offset:]
         self._totals["prefills"] += 1
 
         # register this prompt's full pages for live prefix sharing
@@ -1151,6 +1181,39 @@ class ContinuousEngine:
                 self._alloc.register_prefix(
                     tuple(seq.tokens[:(i + 1) * self.page_size]),
                     seq.pages[i])
+
+    def _launch_keys(self, seq: _Sequence):
+        """A sampling request's keys, launched and on their way to the
+        host: the expression `gpt.generate` splits its keys with, one
+        program of jax's a length.  A request with no temperature draws
+        none — `sample` takes the argmax wherever `temps <= 0` and reads
+        no key."""
+        jax = self._jax
+        with jax.profiler.TraceAnnotation("serve.engine.keys"):
+            keys = self._launch(
+                "serve.keys", lambda: jax.random.split(
+                    jax.random.PRNGKey(seq.seed),
+                    seq.key_offset + seq.max_new))
+        keys.copy_to_host_async()
+        return keys
+
+    def _stamp_prefills(self) -> float:
+        """The iteration's blocking stretch, first part: one clock read
+        where each launched prefill program is done -> the last read.  A
+        program's `prefill_s` is dispatch -> ready; behind another of the
+        same iteration it counts from that one's read, so the sums count
+        no stretch twice."""
+        t = 0.0
+        for seq, t0, logits, stats, last in self._pending:
+            since = max(t0, t)
+            t, stats = self._wait("serve.engine.wait", *stats, ready=logits)
+            self._note_stats(stats, "chunk_")
+            seq.prefill_s += t - since
+            self._last_prefill_s += t - since
+            if last:
+                seq.t_ready = t
+        self._pending = []          # let go before any consumer is woken
+        return t
 
     def _note_stats(self, stats, prefix: str = ""):
         """The model's own counters of one program (`STEP_STATS` of its
@@ -1164,7 +1227,7 @@ class ContinuousEngine:
 
     def _set_entry(self, seq: _Sequence, kind: str, entry: int, page: int):
         seq.tabs[kind][entry] = page
-        if seq.t_ready:             # decoding: its rows are the step's
+        if not seq.prefilling:      # decoding: its rows are the step's
             self._ptabs[kind][seq.slot, entry] = page
 
     def _grow_windows(self, seq: _Sequence, lo: int, hi: int):
@@ -1216,16 +1279,20 @@ class ContinuousEngine:
 
     # -- decode -------------------------------------------------------------
 
-    def _step(self) -> int:
-        """One fused sample+decode step over every slot.  Inactive slots
-        ride along at pos 0 against the null page; their tokens are
-        discarded here on the host."""
+    def _step(self):
+        """Launch one fused sample+decode step over every slot, behind
+        whatever this iteration's admission launched -> the decoding
+        slots; its tokens and counters, not yet ready, wait in
+        `_step_out` for `_emit`.  Inactive slots ride along at pos 0
+        against the null page; `_emit` discards their tokens on the
+        host."""
         ann = self._jax.profiler.TraceAnnotation
         with ann("serve.engine.step", iter=self._iter):
             active = [(i, s) for i, s in enumerate(self._slots)
                       if s is not None and not s.prefilling]
             for i, s in active:
-                self._toks_keys[i] = s.keys[len(s.generated)]
+                if s.keys is not None:
+                    self._toks_keys[i] = s.keys[len(s.generated)]
                 self._grow_windows(s, int(self._pos[i]),
                                    int(self._pos[i]) + 1)
             if self._kv_read is not None:
@@ -1238,11 +1305,26 @@ class ContinuousEngine:
                 "serve.step", self._fn("step"),
                 self._params, self._cache, self._logits, self._toks_keys,
                 self._temps, self._topks, self._ptabs, self._pos)
+        self._launched["step_dispatch_s"] = self._t_free - self._t_call
+        self._step_out = (toks, stats)
+        return active
+
+    def _emit(self, active) -> int:
+        """The iteration's blocking stretch, second part: the step's
+        tokens on the host, each to its sequence; sequences that are done
+        leave -> how many slots decoded."""
+        ann = self._jax.profiler.TraceAnnotation
+        t_free = self._t_free
+        # the device arrays are let go here, before any consumer is woken:
+        # freeing one gives up the interpreter, and a consumer that takes
+        # it then holds the engine thread up with nothing launched (seen
+        # on the chip as 0.3-0.8 ms between one iteration and the next)
+        (toks, stats), self._step_out = self._step_out, None
         now, (toks, *stats) = self._wait("serve.engine.fetch", toks, *stats)
         self._note_stats(stats)
-        # the step program's own two parts, apart from an admission's
-        self._launched["step_dispatch_s"] = self._t_ret - self._t_call
-        self._launched["step_wait_s"] = now - self._t_ret
+        # the step program's own wait, apart from an admission's: from the
+        # step's launch, or from where the last prefill was stamped done
+        self._launched["step_wait_s"] = now - t_free
         with ann("serve.engine.emit"):
             self._totals["steps"] += 1
             emitted = 0
@@ -1285,6 +1367,7 @@ class ContinuousEngine:
             tab[slot] = 0
         self._temps[slot] = 0.0
         self._topks[slot] = 0
+        self._toks_keys[slot] = 0
         self._release(seq)
         self._finish(seq)
         self._wake.set()          # page/slot freed: retry page-starved head

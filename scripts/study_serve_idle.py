@@ -12,10 +12,15 @@ Two readings of the same iterations, which should agree:
   a running program (between two ops of one `XLA Modules` event) and
   between programs apart;
 * the ring (`result.json`, the whole window): an iteration's wall as
-  Python (`host_s`), launches (`dispatch_s`) and waits (`ready_wait_s`),
-  plain iterations and admitting ones apart, the step's own two parts
-  beside the step's device time — joined to the trace by the `iter=` stat
-  of `serve.engine.admit`, not by clock.
+  Python (`host_s`), launches (`dispatch_s`) and waits (`ready_wait_s`,
+  `waits` of them), plain iterations and admitting ones apart, the
+  step's own two parts beside the step's device time — joined to the
+  trace by the `iter=` stat of `serve.engine.admit`, not by clock.  An
+  iteration that admits (or runs a chunk) is launches -> stamp -> fetch:
+  every program enqueued (`dispatch_s`), then one blocking stretch, the
+  prefill's ready stamp (`admission_wait_ms` = `ready_wait_s` less
+  `step_wait_s`, ending `swap_s` into the iteration) and the tokens'
+  fetch (`step_wait_s`).
 
 It also lists the window's iterations that stand out from their kind by
 50 ms or more with their `gc_s` (was that stall a collection?).  Prints
@@ -98,7 +103,18 @@ def join_launches(launches, notes, mods):
     by_name = {}
     for s, e, nm in mods:
         by_name.setdefault(nm.split("(")[0], []).append((s, e))
-    waits = sorted((s, e) for s, e, nm in notes if nm in ("fetch", "wait"))
+    # what ends a program's wait: a step's tokens are fetched; the keys'
+    # `wait` lies under a `keys` annotation; a prefill's is the first
+    # other `wait` behind its launch — its ready stamp, behind the step's
+    # launch (before it, in a tree from before PR 43)
+    keyed = [(s, e) for s, e, n in notes if n == "keys"]
+    waits = {"serve.step": [], "serve.keys": [], "serve.prefill": []}
+    for s, e, n in sorted(notes):
+        if n == "fetch":
+            waits["serve.step"].append((s, e))
+        elif n == "wait":
+            under = any(ks <= s and e <= ke for ks, ke in keyed)
+            waits["serve.keys" if under else "serve.prefill"].append((s, e))
     out = {}
     for ls, le, pr in sorted(launches):
         if pr not in MODULES:
@@ -109,15 +125,14 @@ def join_launches(launches, notes, mods):
             continue
         a = min(first, key=lambda m: abs(m[0] - le))
         z = min(last, key=lambda m: abs(m[0] - le))
-        w = next(((s, e) for s, e in waits if s >= le), None)
+        w = next(((s, e) for s, e in waits.get(pr, ()) if s >= le), None)
         row = out.setdefault(pr, {"start_after_entered": [],
                                   "returned_after_start": [],
                                   "wait_end_after_program_end": []})
         row["start_after_entered"].append((a[0] - ls) * 1e-6)
         row["returned_after_start"].append((le - a[0]) * 1e-6)
-        # the wait that follows a step at once; a prefill's follows its
-        # row's launch, a row's wait is its prefill's
-        if w is not None and pr in ("serve.step", "serve.keys"):
+        # a row's wait is its prefill's; a copy has none of its own
+        if w is not None:
             row["wait_end_after_program_end"].append((w[1] - z[1]) * 1e-6)
     return {pr: {"n": len(r["start_after_entered"]), **{
         k: {"median": statistics.median(v), "min": min(v), "max": max(v)}
@@ -235,10 +250,11 @@ def ring_side(ring, only=None):
         return {"iterations": 0}
     have = [k for k in ("iter_s", "host_s", "dispatch_s", "ready_wait_s",
                         "step_dispatch_s", "step_wait_s", "decode_s",
-                        "swap_s") if k in ring[0]]
+                        "swap_s", "prefill_s") if k in ring[0]]
     kinds = {"plain": [r for r in ring if r["active"] and not r["admitted"]
                        and not r["chunks"]],
              "admitting": [r for r in ring if r["admitted"]],
+             "admitting_one": [r for r in ring if r["admitted"] == 1],
              "chunk_only": [r for r in ring if r["chunks"]
                             and not r["admitted"]]}
     out = {"iterations": len(ring)}
@@ -246,7 +262,12 @@ def ring_side(ring, only=None):
         out[kind] = {"n": len(rs), **{
             k + "_ms": 1e3 * _med([r[k] for r in rs]) if rs else None
             for k in have},
-            "launches": _med([r.get("launches", 0) for r in rs])}
+            "launches": _med([r.get("launches", 0) for r in rs]),
+            # the parent's ring has no `waits`: None there
+            "waits": _med([r["waits"] for r in rs if "waits" in r])}
+        if rs and "step_wait_s" in have:
+            out[kind]["admission_wait_ms"] = 1e3 * _med(
+                [r["ready_wait_s"] - r["step_wait_s"] for r in rs])
     # what lies between one record's close and the next one's start: the
     # loop's own turn, or the engine asleep with nothing to run
     ends = [(r["t0"], r["t0"] + r["iter_s"]) for r in ring]

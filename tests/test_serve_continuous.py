@@ -343,24 +343,33 @@ def test_stop_fails_waiting_requests(model):
 # ---------------------------------------------------------------------------
 # the engine measures itself: ring records, annotations, request spans
 
-# an iteration that admits one unchunked prompt, each annotation under
-# the one it lies in: every launch is a `dispatch` inside the annotation
-# that was there, and nothing between two launches is unannotated
+# an iteration that admits one unchunked greedy prompt, each annotation
+# under the one it lies in: every launch is a `dispatch` inside the
+# annotation that was there, nothing between two launches is unannotated,
+# and the thread first blocks (`wait`: the prefill's ready stamp) once the
+# step is launched
 _D, _W = "serve.engine.dispatch", "serve.engine.wait"
-_ANNOTATIONS = [("serve.engine.admit", None),
-                ("serve.engine.prefill", "serve.engine.admit"),
-                (_D, "serve.engine.prefill"),
-                ("serve.engine.setrow", "serve.engine.prefill"),
-                (_D, "serve.engine.setrow"),
-                (_W, "serve.engine.prefill"),
-                ("serve.engine.keys", "serve.engine.admit"),
-                (_D, "serve.engine.keys"), (_W, "serve.engine.keys"),
-                ("serve.engine.step", None), (_D, "serve.engine.step"),
-                ("serve.engine.fetch", None), ("serve.engine.emit", None),
-                ("serve.engine.account", None)]
-# the launches of that iteration as the engine counts them (`launches`):
-# the key expression is one launch, of three small programs of jax's own
-_PROGRAMS = ["serve.prefill:8", "serve.setrow", "serve.keys", "serve.step"]
+_STEP = [("serve.engine.step", None), (_D, "serve.engine.step")]
+_TAIL = [("serve.engine.fetch", None), ("serve.engine.emit", None),
+         ("serve.engine.account", None)]
+_PREFILL = [("serve.engine.prefill", "serve.engine.admit"),
+            (_D, "serve.engine.prefill"),
+            ("serve.engine.setrow", "serve.engine.prefill"),
+            (_D, "serve.engine.setrow")]
+_ANNOTATIONS = ([("serve.engine.admit", None)] + _PREFILL + _STEP
+                + [(_W, None)] + _TAIL)
+# the launches of that iteration as the engine counts them (`launches`)
+_PROGRAMS = ["serve.prefill:8", "serve.setrow", "serve.step"]
+# a request with a temperature draws its keys: launched ahead of the
+# prefill (one launch, of three small programs of jax's own), fetched
+# behind the row — the one wait before the step's launch, for keys that
+# were done before the prefill began
+_KEYS = ("serve.engine.keys", "serve.engine.admit")
+_SAMPLED_ANNOTATIONS = (
+    [("serve.engine.admit", None), _KEYS, (_D, "serve.engine.keys")]
+    + _PREFILL + [_KEYS, (_W, "serve.engine.keys")] + _STEP
+    + [(_W, None)] + _TAIL)
+_SAMPLED_PROGRAMS = ["serve.keys"] + _PROGRAMS
 
 
 class _Recorder:
@@ -449,16 +458,20 @@ def test_ring_decomposes_ttft_and_iteration_time(model):
 
 
 def test_ring_splits_device_wait_into_dispatch_and_ready_wait(model):
-    """Every launch is stamped entered -> returned, every wait from the
-    last launch's return: the two sums ARE `device_wait_s` (same stamps),
-    the step's own two parts lie inside `decode_s`, and a record carries
-    its iteration's ordinal and the launches it made."""
+    """Every launch is stamped entered -> returned, every wait from where
+    the thread was last let go: the two sums ARE `device_wait_s` (same
+    stamps), a plain iteration's step lies inside `decode_s`, and a record
+    carries its iteration's ordinal, the launches it made and the times it
+    blocked."""
     st, ring, rid = _second_request_while_first_decodes(model)
     assert [r["iter"] for r in ring] == list(range(1, len(ring) + 1))
-    # a plain iteration launches the step; one that admits an unchunked
-    # prompt its prefill, its row, its keys, and the step
+    # a plain iteration launches the step and waits for its tokens; one
+    # that admits an unchunked greedy prompt launches its prefill, its
+    # row and the step, and only then waits: the stamp, the tokens
     assert [r["launches"] for r in ring] == [
         len(_PROGRAMS) if r["admitted"] else 1 for r in ring]
+    assert [r["waits"] for r in ring] == [
+        2 if r["admitted"] else 1 for r in ring]
     assert sum(r["admitted"] for r in ring) == 2
     for r in ring:
         assert r["dispatch_s"] + r["ready_wait_s"] == pytest.approx(
@@ -466,20 +479,25 @@ def test_ring_splits_device_wait_into_dispatch_and_ready_wait(model):
         assert r["host_s"] + r["device_wait_s"] == pytest.approx(
             r["iter_s"], abs=1e-9)
         assert 0 < r["step_dispatch_s"] and 0 < r["step_wait_s"]
-        assert r["step_dispatch_s"] + r["step_wait_s"] <= r["decode_s"]
-        if r["admitted"]:       # the admission's programs are apart
+        if r["admitted"]:       # the admission's programs are apart, and
+            # the step is launched before the admission's work is done
             assert r["step_dispatch_s"] < r["dispatch_s"]
             assert r["step_wait_s"] < r["ready_wait_s"]
+            assert r["step_wait_s"] <= r["decode_s"]
+            assert r["dispatch_s"] < r["swap_s"]
+            assert 0 < r["prefill_s"] <= r["swap_s"]
         else:
             assert r["step_dispatch_s"] == r["dispatch_s"]
             assert r["step_wait_s"] == r["ready_wait_s"]
+            assert r["step_dispatch_s"] + r["step_wait_s"] <= r["decode_s"]
     assert st["launches"] == 2 * len(_PROGRAMS) + len(ring) - 2
 
 
 def test_chunk_iterations_launch_one_program_and_no_step(model):
     """A prompt of three chunks alone in the engine: two iterations run
     one prefill program each and no step (`step_dispatch_s` 0), the third
-    the last chunk, the row, the keys and the first step."""
+    the last chunk, the row and the first step; each waits for its chunk
+    once, the third for the tokens too."""
     eng = _make_engine(model, prefill_chunk=8)
     try:
         eng.collect(eng.submit(list(range(1, 21)), max_new_tokens=2),
@@ -489,30 +507,43 @@ def test_chunk_iterations_launch_one_program_and_no_step(model):
     ring = eng.phase_ring()         # whole: the engine thread has ended
     assert [r["launches"] for r in ring] == [1, 1, len(_PROGRAMS), 1]
     assert [r["chunks"] for r in ring] == [1, 1, 1, 0]
+    assert [r["waits"] for r in ring] == [1, 1, 2, 1]
     for r in ring[:2]:
         assert r["active"] == 0 and r["decode_s"] == 0
         assert r["step_dispatch_s"] == 0 and r["step_wait_s"] == 0
         assert 0 < r["dispatch_s"] < r["device_wait_s"] <= r["swap_s"]
 
 
-def test_iteration_nests_annotations_and_names_every_launch(model, recorder):
+@pytest.mark.parametrize("temperature, annotations, programs", [
+    (0.0, _ANNOTATIONS, _PROGRAMS),
+    (0.8, _SAMPLED_ANNOTATIONS, _SAMPLED_PROGRAMS)],
+    ids=["greedy", "sampled"])
+def test_iteration_nests_annotations_and_names_every_launch(
+        model, recorder, temperature, annotations, programs):
     eng = _make_engine(model)
     try:
-        eng.collect(eng.submit(PROMPT, max_new_tokens=3,
+        eng.collect(eng.submit(PROMPT, max_new_tokens=3, seed=5,
+                               temperature=temperature,
                                request_id="req-7"), timeout=120)
     finally:
         eng.stop()
     seen, ring = recorder.seen, eng.phase_ring()
     nest = [(n, parent) for n, _, parent in seen]
     first = nest.index(("serve.engine.account", None)) + 1
-    assert nest[:first] == _ANNOTATIONS          # the admitting iteration
-    decode_only = _ANNOTATIONS[_ANNOTATIONS.index(("serve.engine.step",
-                                                   None)):]
-    plain = [("serve.engine.admit", None)] + decode_only
+    assert nest[:first] == annotations           # the admitting iteration
+    plain = [("serve.engine.admit", None)] + _STEP + _TAIL
     assert nest[first:first + len(plain)] == plain
     # a launch names its program as the compilation ledger does
     assert [kw["program"] for n, kw, _ in seen[:first]
-            if n == _D] == _PROGRAMS
+            if n == _D] == programs
+    assert (ring[0]["launches"], ring[0]["waits"]) == (
+        len(programs), 2 + (temperature > 0))
+    # nothing waits for the prefill between its launch and the step's:
+    # its ready stamp is the first thing after the step's `dispatch`
+    step = nest.index((_D, "serve.engine.step"))
+    assert nest[step + 1] == (_W, None)
+    assert [parent for n, parent in nest[:step] if n == _W] == (
+        ["serve.engine.keys"] if temperature > 0 else [])
     (prefill,) = [kw for n, kw, _ in seen if n == "serve.engine.prefill"]
     assert prefill == {"request_id": "req-7", "tokens": len(PROMPT),
                        "bucket": 8}
@@ -523,6 +554,117 @@ def test_iteration_nests_annotations_and_names_every_launch(model, recorder):
     assert not any(kw for n, kw, _ in seen if n in (
         _W, "serve.engine.setrow", "serve.engine.keys",
         "serve.engine.fetch", "serve.engine.emit", "serve.engine.account"))
+
+
+@pytest.mark.parametrize("joined", [False, True],
+                         ids=["alone", "joined_mid_stream"])
+@pytest.mark.parametrize("temperature", [0.0, 0.7],
+                         ids=["greedy", "sampled"])
+def test_tokens_are_generates_with_and_without_a_newcomer(model, temperature,
+                                                          joined):
+    """A request's tokens are `gpt.generate`'s on the same seed whether it
+    decodes alone or a second request (one that samples, so that keys are
+    drawn beside its rows) is admitted while it streams — and so are the
+    newcomer's.  A greedy request draws no keys at all."""
+    eng = _make_engine(model, max_slots=2)
+    try:
+        a = eng.submit(PROMPT, max_new_tokens=16, temperature=temperature,
+                       seed=11, top_k=12, stream=joined)
+        if joined:
+            it = eng.stream(a)
+            head = [next(it), next(it)]
+            b = eng.submit([7, 9, 2, 30], max_new_tokens=8, temperature=0.9,
+                           seed=29)
+            got_a = head + list(it)
+            got_b = eng.collect(b, timeout=120)["completion"]
+        else:
+            got_a = eng.collect(a, timeout=120)["completion"]
+    finally:
+        eng.stop()
+    assert got_a == _expected(model, PROMPT, 16, temperature=temperature,
+                              seed=11, top_k=12)
+    assert (a.keys is None) == (temperature == 0)
+    if joined:
+        assert got_b == _expected(model, [7, 9, 2, 30], 8, temperature=0.9,
+                                  seed=29)
+        ring = eng.phase_ring()
+        assert [r["launches"] for r in ring if r["admitted"]] == [
+            len(_PROGRAMS) + (temperature > 0), len(_SAMPLED_PROGRAMS)]
+        # a slot a sampling request has left reads no stale key
+        assert not eng._toks_keys.any()
+
+
+def test_a_failing_prefill_is_reported_at_the_wait_and_holds_nothing(
+        model, monkeypatch):
+    """A program that fails says so where its result is waited for, after
+    the row and the step were launched behind it: every request the
+    iteration touched fails with that error, no slot or page stays held,
+    the device state is dropped — and the next request is served."""
+    launched = []
+    real = jax.block_until_ready
+
+    def fail_once(x):
+        if not launched:
+            launched.append(dict(eng._launched))
+            raise RuntimeError("prefill failed on the chip")
+        return real(x)
+
+    eng = _make_engine(model, max_slots=2)
+    monkeypatch.setattr(jax, "block_until_ready", fail_once)
+    try:
+        a = eng.submit(PROMPT, max_new_tokens=6)
+        with pytest.raises(RuntimeError, match="failed on the chip"):
+            eng.collect(a, timeout=120)
+        # the wait came after the iteration's three launches, first of all
+        assert (launched[0]["launches"], launched[0]["waits"]) == (3, 0)
+        st = eng.engine_stats()
+        assert st["active"] == 0 and st["queue_depth"] == 0
+        assert st["free_pages"] == eng.num_pages - 1
+        assert eng._cache is None and eng._logits is None
+        assert eng._prefilling is None and eng.check_health()
+        b = eng.submit(PROMPT, max_new_tokens=6)
+        assert eng.collect(b, timeout=120)["completion"] == _expected(
+            model, PROMPT, 6)
+    finally:
+        eng.stop()
+
+
+def test_two_admissions_in_one_iteration_count_no_stretch_twice(model):
+    """Two requests admitted by ONE iteration: five launches, then three
+    waits (a stamp each, the tokens); the second's prefill counts from the
+    first's ready stamp, so the prefill seconds stay inside `swap_s`, and
+    its wait behind the first is `chunk_wait_s`."""
+    eng = _make_engine(model, max_slots=2)
+    t = threading.Thread(target=lambda: None)
+    t.start()
+    t.join()
+    eng._thread = t                 # the test drives the iterations
+    try:
+        seqs = [eng.submit(p, max_new_tokens=2)
+                for p in (PROMPT, list(range(20, 31)))]
+        eng._iteration()
+        (rec,) = eng.phase_ring()
+        assert rec["admitted"] == 2 and rec["active"] == 2
+        assert (rec["launches"], rec["waits"]) == (5, 3)
+        first, second = rec["requests"]
+        assert [q["rid"] for q in rec["requests"]] == [s.rid for s in seqs]
+        assert first["prefill_s"] + second["prefill_s"] == pytest.approx(
+            rec["prefill_s"])
+        assert 0 < rec["prefill_s"] <= rec["swap_s"]
+        assert first["chunk_wait_s"] == 0 < second["chunk_wait_s"]
+        assert seqs[0].t_prefill < seqs[1].t_prefill < seqs[0].t_ready \
+            <= seqs[1].t_ready < seqs[1].t_first
+        for q, s in zip(rec["requests"], seqs):
+            parts = (q["queue_wait_s"] + (s.t_prefill - s.t_admit)
+                     + q["prefill_s"] + q["chunk_wait_s"]
+                     + q["first_step_wait_s"])
+            assert parts == pytest.approx(q["ttft_s"], abs=1e-9)
+        eng._iteration()
+        assert all(s.result.done() for s in seqs)
+    finally:
+        eng.stop()
+    for s, p in zip(seqs, (PROMPT, list(range(20, 31)))):
+        assert s.result.result()["completion"] == _expected(model, p, 2)
 
 
 def test_copy_on_write_is_a_launch_inside_admit(model, recorder):
