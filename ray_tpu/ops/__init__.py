@@ -4,7 +4,7 @@ from .attention import (attention, blockwise_attention, flash_attention,
 from .layers import (apply_rope, apply_rope_halves, apply_rope_interleaved,
                      fused_softmax_cross_entropy, gelu_mlp,
                      layer_norm, rms_norm, rope_table,
-                     softmax_cross_entropy, swiglu)
+                     softmax_cross_entropy, swiglu, yarn_frequencies)
 from .quantize import (dequantize_blockwise, quantization_error,
                        quantize_blockwise)
 from .retention import retention_chunk, retention_step
@@ -19,6 +19,6 @@ __all__ = [
     "ulysses_attention", "ulysses_attention_sharded",
     "retention_chunk", "retention_step",
     "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_halves",
-    "apply_rope_interleaved", "swiglu",
+    "apply_rope_interleaved", "yarn_frequencies", "swiglu",
     "gelu_mlp", "softmax_cross_entropy", "fused_softmax_cross_entropy",
 ]

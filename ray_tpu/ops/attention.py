@@ -120,7 +120,8 @@ def blockwise_attention(q, k, v, causal: bool = False,
 # ---------------------------------------------------------------------------
 
 
-def streamed_attention(q, qpos, fetch, n_blocks, *, window=None):
+def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
+                       v_dim=None):
     """Causal attention over keys that arrive block by block, with an
     online softmax: the serve path's recipe for caches too long to score
     at once, grouped-query heads and a sliding window included.
@@ -131,9 +132,18 @@ def streamed_attention(q, qpos, fetch, n_blocks, *, window=None):
     sees key s iff 0 <= qpos - kpos (< window, where a window is given).
     `n_blocks` may be traced: only blocks 0..n_blocks-1 are fetched.
     A row that sees no key at all comes out zero.  Returns [B, Hkv, G, T,
-    dh] in q's dtype; scores and statistics are f32."""
+    dh] in q's dtype; scores and statistics are f32.
+
+    `scale` multiplies the scores (dh ** -0.5 where none is given) and
+    `v_dim` is the width of the values where it is not the keys' (latent
+    attention: keys [.., 576] — latent and rope part — against values that
+    are the latent alone; or 192-wide keys with 128-wide values): the
+    result is then [B, Hkv, G, T, v_dim]."""
     B, Hkv, G, T, dh = q.shape
-    scale = dh ** -0.5
+    if scale is None:
+        scale = dh ** -0.5
+    if v_dim is None:
+        v_dim = dh
 
     def body(i, carry):
         m, l, acc = carry
@@ -156,7 +166,7 @@ def streamed_attention(q, qpos, fetch, n_blocks, *, window=None):
 
     init = (jnp.full((B, Hkv, G, T), DEFAULT_MASK_VALUE, jnp.float32),
             jnp.zeros((B, Hkv, G, T), jnp.float32),
-            jnp.zeros((B, Hkv, G, T, dh), jnp.float32))
+            jnp.zeros((B, Hkv, G, T, v_dim), jnp.float32))
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 

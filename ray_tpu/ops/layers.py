@@ -7,10 +7,12 @@ under any sharding.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
@@ -61,13 +63,41 @@ def apply_rope(x, cos, sin, positions: Optional[jnp.ndarray] = None):
     return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
 
 
-def apply_rope_interleaved(x, positions, theta: float = 10000.0):
+def yarn_frequencies(dim: int, theta: float = 10000.0, factor: float = 40.0,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0,
+                     original_max: int = 4096):
+    """YaRN's angular frequencies (arXiv:2309.00071) for a rope part of
+    `dim` values, [dim/2] float32 (numpy: a constant of the program).
+    Pair i of the plain recipe turns at theta^(-2i/dim) a position.  A
+    pair that completes more than `beta_fast` turns over the
+    `original_max` positions the model was trained on keeps that
+    frequency; one that completes fewer than `beta_slow` is slowed by
+    `factor` (positions interpolated); between the two pair indices — the
+    floor and the ceiling of dim ln(original_max / (2 pi turns)) /
+    (2 ln theta) — the blend is linear in the index."""
+    half = dim // 2
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_of(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / (max(high - low, 0.001)), 0, 1)
+    return (plain * (1 - ramp) + plain / factor * ramp).astype(np.float32)
+
+
+def apply_rope_interleaved(x, positions, theta: float = 10000.0, freqs=None):
     """Rotary embedding over interleaved pairs (x[2i], x[2i+1]) — the
     GPT-J convention — for x [B, H, T, D] at positions [B, T], every row
     of the batch at its own positions.  Angles are computed in f32 from
-    the positions (no table: contexts run to hundreds of thousands)."""
+    the positions (no table: contexts run to hundreds of thousands).
+    `freqs` [D/2] takes the place of the plain theta^(-2i/D) (scaled
+    rotary embeddings: `yarn_frequencies`)."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[:, None, :, None] * freqs
     c, s = jnp.cos(ang), jnp.sin(ang)
     xp = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)

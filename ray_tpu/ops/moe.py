@@ -157,6 +157,36 @@ def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, k: int
     return w / jnp.sum(w, axis=-1, keepdims=True), idx.astype(jnp.int32)
 
 
+def route_sigmoid_grouped(h: jax.Array, router_w: jax.Array, bias: jax.Array,
+                          k: int, *, n_group: int, topk_group: int,
+                          scale: float = 1.0
+                          ) -> Tuple[jax.Array, jax.Array]:
+    """Group-limited routing with a correction bias (auxiliary-loss-free
+    balancing): sigmoid scores s over ALL experts in f32; the scores plus
+    `bias` [E] CHOOSE, the scores alone WEIGH.  The experts lie in
+    `n_group` groups of E / n_group; a group's rank is the sum of its two
+    largest biased scores, the `topk_group` best groups are kept, and the
+    k largest biased scores inside them are the token's experts (a tie
+    goes to the lower index, in both rankings).  Weights are s over the
+    chosen k, normalised (+1e-20) and multiplied by `scale`.
+    h [N, D], router_w [D, E] -> (weights [N, k] f32, ids [N, k] int32)."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    N, E = s.shape
+    c = s + bias.astype(jnp.float32)
+    by_group = c.reshape(N, n_group, E // n_group)
+    rank = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)  # [N, n_group]
+    _, kept = jax.lax.top_k(rank, topk_group)
+    keep = jnp.zeros((N, n_group), bool).at[
+        jnp.arange(N)[:, None], kept].set(True)
+    c = jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(N, E)
+    _, idx = jax.lax.top_k(c, k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return w, idx.astype(jnp.int32)
+
+
 def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                     *, first: int, tile: int = 512,

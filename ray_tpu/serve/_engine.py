@@ -30,11 +30,18 @@ above.
 
 The engine serves whatever module implements its interface
 (`cache_kinds`, `init_paged_cache`, `paged_decode_step`, `paged_prefill`,
-`copy_page` where pages are shared; models/gpt.py, models/cohere2_moe.py).
+`copy_page` where pages are shared; models/gpt.py, models/cohere2_moe.py,
+models/brumby.py, models/deepseek_v3.py).
 A model's layers may keep several **kinds of KV state**: `cache_kinds`
 names them with their window, and the engine keeps a pool of pages, an
 allocator and a page table per kind.  A full kind's pages are taken at
-admission, in sequence order.  A windowed kind's table is a ring as wide
+admission, in sequence order.  What a page HOLDS is the model's: keys and
+values a head wide (gpt, cohere2_moe), or one **latent row** a position
+with no K side, no V side and no head axis (deepseek_v3: 576 values, from
+which its programs form every head's keys and values, or which they score
+as it lies); the engine counts pages and hands tables over, and never
+looks inside the arena `init_paged_cache` gave it — a latent kind is a
+full kind, shared and copied on write like any other.  A windowed kind's table is a ring as wide
 as the window plus the longest prefill program: pages are taken as the
 sequence grows, out of a reservation made at admission, and returned once
 every position in them is a window or more behind the next query.  A kind
@@ -399,7 +406,7 @@ class ContinuousEngine:
         leaves = jax.tree_util.tree_leaves(self._params)
         self._param_stats = {
             "param_count": sum(int(w.size) for w in leaves),
-            "param_bytes": sum(int(w.nbytes) for w in leaves)}
+            "param_bytes": sum(int(w.size) * w.dtype.itemsize for w in leaves)}
         self.max_slots = int(max_slots)
         self.page_size = int(page_size)
         self.max_total = int(max_total) or cfg.max_seq

@@ -68,7 +68,8 @@ _QUICK_FILES = {
     "test_collective_compression.py", "test_collective_pipeline.py",
     "test_config.py", "test_control_stats.py", "test_core_actors.py",
     "test_core_objects.py", "test_core_tasks.py", "test_data.py",
-    "test_data_remote_io.py", "test_device_telemetry.py",
+    "test_data_remote_io.py", "test_deepseek_v3.py",
+    "test_device_telemetry.py",
     "test_docs_paths.py", "test_elastic.py",
     "test_label_scheduling.py",
     "test_mpmd.py",

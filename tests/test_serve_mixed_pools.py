@@ -291,3 +291,54 @@ def test_chunked_and_unchunked_prompts_give_the_same_tokens(model, plen):
         assert seq.chunks == want, (chunk, seq.chunks)
         eng.stop()
     assert outs[0] == outs[1]
+
+
+# -- (f) a full kind whose pages hold latent rows, not keys and values --------
+
+
+def test_a_latent_page_is_copied_on_write():
+    """models/deepseek_v3 keeps one full kind, so the engine shares a
+    common prompt's pages: two live sequences with ONE prompt of two whole
+    pages share the first page, and the second — shared too, but the last
+    prompt position must be computed again to give logits — is copied
+    (`copy_page` over [pages, 576-like, page] arenas: no K side, no V
+    side) before the newcomer writes into it.  Both are served what a
+    sequence alone is served, and the programs' counters reach the
+    engine's ring."""
+    from ray_tpu.models import deepseek_v3 as dm
+
+    cfg = dm.DeepSeekV3Config.nano(dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
+    params = dm.init(jax.random.PRNGKey(0), cfg)
+    kw = dict(max_slots=3, page_size=8, max_total=64, prefill_bucket=4,
+              prefill_chunk=8)
+    prompt = [int(t) for t in _tokens(16, seed=3)]
+    alone = ContinuousEngine(dm, cfg, params, **kw)
+    want = alone.collect(alone.submit(prompt, 12), timeout=120)["completion"]
+    alone.stop()
+
+    eng = _by_hand(ContinuousEngine(dm, cfg, params, **kw))
+    assert eng._share and eng._kinds == {"full": None}
+    a = eng.submit(prompt, 12)
+    for _ in range(3):              # two chunks, then it decodes
+        eng._iteration()
+    b = eng.submit(prompt, 12)
+    eng._iteration()
+    assert eng._totals["cow_copies"] == 1 and eng._totals["shared_pages"] == 1
+    assert b.pages[0] == a.pages[0] and b.pages[1] != a.pages[1]
+    assert b.shared == 15           # all but the last prompt position
+    cache = [np.asarray(c) for c in eng._cache]
+    for arena in cache:             # the copy, before b's own row went in
+        assert arena.shape[1:] == (cfg.d_latent, 8)
+        np.testing.assert_array_equal(arena[b.pages[1]][:, :7],
+                                      arena[a.pages[1]][:, :7])
+    while not (a.result.done() and b.result.done()):
+        eng._iteration()
+    assert a.result.result()["completion"] == want
+    assert b.result.result()["completion"] == want
+    rec = eng.phase_ring()
+    assert sum(r["mla_keys"] for r in rec) > 0
+    assert sum(r["chunk_mla_pairs"] for r in rec) > 0
+    assert sum(r["chunk_moe_pairs"] + r["moe_pairs"] for r in rec) > 0
+    _pools_idle(eng)
+    eng.stop()
