@@ -39,7 +39,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import streamed_attention
+from ray_tpu.ops.attention import (streamed_attention,
+                                   streamed_attention_uses_kernel)
 from ray_tpu.ops.layers import apply_rope_interleaved, swiglu
 from ray_tpu.ops.moe import held_expert_ffn, route_sigmoid_topk
 
@@ -54,6 +55,11 @@ __all__ = ["Cohere2MoEConfig", "init", "apply", "cache_kinds",
 # (f32 scalars, summed over the layers): token-expert pairs that fell on
 # held experts, the largest load of a held expert, held experts touched
 STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched")
+
+# whether a prefill program of `rows` rows attends through the Pallas
+# block kernel: the predicate streamed_attention itself picks by, for the
+# engine to stamp its chunk launches with
+chunk_attn_kernel = streamed_attention_uses_kernel
 
 
 @dataclasses.dataclass(frozen=True)

@@ -57,7 +57,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import streamed_attention
+from ray_tpu.ops.attention import (streamed_attention,
+                                   streamed_attention_uses_kernel)
 from ray_tpu.ops.layers import (apply_rope_interleaved, rms_norm, swiglu,
                                 yarn_frequencies)
 from ray_tpu.ops.moe import held_expert_ffn, route_sigmoid_grouped
@@ -77,6 +78,11 @@ __all__ = ["DeepSeekV3Config", "init", "apply", "cache_kinds",
 # rows one context
 STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "mla_pairs",
               "mla_keys")
+
+# whether a prefill program of `rows` rows attends through the Pallas
+# block kernel: the predicate streamed_attention itself picks by, for the
+# engine to stamp its chunk launches with
+chunk_attn_kernel = streamed_attention_uses_kernel
 
 KIND = "full"
 

@@ -120,31 +120,31 @@ def blockwise_attention(q, k, v, causal: bool = False,
 # ---------------------------------------------------------------------------
 
 
-def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
-                       v_dim=None):
-    """Causal attention over keys that arrive block by block, with an
-    online softmax: the serve path's recipe for caches too long to score
-    at once, grouped-query heads and a sliding window included.
+def _streamed_xla(q, qpos, fetch, n_blocks, window, scale, v_dim):
+    """`streamed_attention` (at the end of this file: it says what the
+    operands are, and chooses) with a block's scores formed by XLA: what
+    a decode step's call takes (one row a head, slots in B) and every
+    call off the TPU.  A block's f32 scores [B, Hkv, G, T, S] go through
+    memory between the two products; for the rows of a prefill chunk
+    that is most of the call's time, and the block kernel at the end of
+    this file keeps them on the chip.
 
-    q [B, Hkv, G, T, dh] (G query heads a K/V head) at positions qpos
-    [B, T]; `fetch(i)` gives block i's keys and values [B, Hkv, S, dh]
-    and their positions kpos [B, S] (negative: no key there).  Query t
-    sees key s iff 0 <= qpos - kpos (< window, where a window is given).
-    `n_blocks` may be traced: only blocks 0..n_blocks-1 are fetched.
-    A row that sees no key at all comes out zero.  Returns [B, Hkv, G, T,
-    dh] in q's dtype; scores and statistics are f32.
+    This body stands where `streamed_attention` stood before PR 46, in
+    as many lines, and everything PR 46 added is at the file's end: a
+    Pallas kernel's serialised module carries its source lines, so a
+    line added above the flash-attention kernels below changes the text,
+    and the compile-cache key, of every train program that holds them.
 
-    `scale` multiplies the scores (dh ** -0.5 where none is given) and
-    `v_dim` is the width of the values where it is not the keys' (latent
-    attention: keys [.., 576] — latent and rope part — against values that
-    are the latent alone; or 192-wide keys with 128-wide values): the
-    result is then [B, Hkv, G, T, v_dim]."""
+    Query t sees key s iff 0 <= qpos - kpos (< window, where one is
+    given) and kpos >= 0; a row that sees no key comes out zero (p is
+    zeroed where the mask hides a score, so an all-hidden row sums to
+    l = 0 and its accumulator stays 0).  `n_blocks` may be traced.
+    Scores, statistics (m, l) and the accumulator are f32; p is cast to
+    the values' dtype for the second product.
+    """
     B, Hkv, G, T, dh = q.shape
-    if scale is None:
-        scale = dh ** -0.5
-    if v_dim is None:
-        v_dim = dh
 
+    # one key block: the flash recurrence on XLA's own products
     def body(i, carry):
         m, l, acc = carry
         k, v, kpos = fetch(i)
@@ -888,3 +888,197 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
     if impl == "reference":
         return mha_reference(q, k, v, causal=causal, scale=scale)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+# ---------------------------------------------------------------------------
+# streamed attention: the serve path's attention over keys that arrive
+# block by block (its XLA body is `_streamed_xla`, above)
+# ---------------------------------------------------------------------------
+
+# A query head must bring this many rows (and a multiple of them) for
+# the block kernel: a prefill chunk does (512), a decode step (1) does not
+_KERNEL_ROWS = 128
+# query rows a program of the block kernel scores against a key block
+_BLOCK_ROWS = 1024
+# "no window", as the kernel's int32 operand: a window no context reaches
+_NO_WINDOW = np.iinfo(np.int32).max
+
+
+def streamed_attention_uses_kernel(rows: int, platform: Optional[str] = None
+                                   ) -> bool:
+    """Whether `streamed_attention` scores a call whose query heads bring
+    `rows` rows each (T) with the Pallas block kernel: on a TPU, for 128
+    rows or more (a prefill chunk).  Rows and platform decide, nothing
+    else; `platform` None means the backend this process computes on."""
+    if platform is None:
+        platform = jax.default_backend()
+    return (platform == "tpu" and rows >= _KERNEL_ROWS
+            and rows % _KERNEL_ROWS == 0)
+
+
+def _streamed_block_kernel(scale_ref, window_ref, q_ref, k_ref, v_ref,
+                           qpos_ref, kpos_ref, m_ref, l_ref, acc_ref,
+                           m_out, l_out, acc_out):
+    """One key block's online-softmax update for one K/V head's block of
+    query rows.  Keys lie along the sublanes and queries along the lanes
+    (scores [S, R]), so a row's statistics are [1, R] rows: they reduce
+    over sublanes, broadcast over sublanes, and travel as they lie in
+    [.., T] arrays, with no transpose and no lane-replicated plane.  The
+    accumulator is kept the same way round, [v_dim, R]."""
+    q = q_ref[0, 0]                                       # [R, dh]
+    k = k_ref[0, 0]                                       # [S, dh]
+    v = v_ref[0, 0]                                       # [S, dv]
+    s = _dot(k, q, _NT) * scale_ref[0]                    # [S, R] f32
+    kpos = kpos_ref[0, :, :1]                             # [S, 1]
+    # a hole among the keys (kpos < 0) lies past every query; and
+    # 0 <= d < window is ONE unsigned comparison
+    d = qpos_ref[0] - jnp.where(kpos >= 0, kpos, _NO_WINDOW)   # [S, R]
+    ok = (jax.lax.bitcast_convert_type(d, jnp.uint32)
+          < window_ref[0].astype(jnp.uint32))
+    s = jnp.where(ok, s, DEFAULT_MASK_VALUE)
+    m = m_ref[0, 0]                                       # [1, R]
+    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+    # a hidden score is DEFAULT_MASK_VALUE, so its p is exp(-huge) = 0
+    # wherever the row has seen a key (m_new is a real score); a row
+    # that has seen none (m_new still DEFAULT_MASK_VALUE: p = 1
+    # throughout) is taken out a row at a time, not a score at a time
+    seen = (m_new > DEFAULT_MASK_VALUE).astype(jnp.float32)
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    m_out[0, 0] = m_new
+    l_out[0, 0] = l_ref[0, 0] * corr + seen * jnp.sum(p, axis=0,
+                                                      keepdims=True)
+    acc_out[0, 0] = acc_ref[0, 0] * corr + seen * _dot(
+        v, p.astype(v.dtype), _TN)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _streamed_block(scale, window, q, k, v, qpos, kpos, m, l, acc,
+                    interpret=False):
+    """(m, l, acc) after key block (k, v, kpos): q [B, H, R, dh] at qpos
+    [B, R], k [B, H, S, dh], v [B, H, S, dv], kpos [B, S]; m, l [B, H, R]
+    and acc [B, H, dv, R] float32.  ONE jitted function, with the scale
+    and the window as operands: every call site of a program whose
+    shapes agree (a chunk program's layers, windowed or not) shares one
+    traced jaxpr and one lowered function — a Pallas call is traced and
+    lowered to Mosaic in Python at every call site of every process,
+    before the compile cache is asked, so a copy a layer is paid in warm
+    set-up (PERF.md section 6, PR 46)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, R, dh = q.shape
+    S, dv = v.shape[-2:]
+    rows = _fit_block(_BLOCK_ROWS, R)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    def per_head(width, last):      # a K/V head's whole block
+        return pl.BlockSpec((1, 1, width, last),
+                            lambda b, h, r: (b, h, 0, 0))
+
+    def per_rows(height):           # a program's rows, along the lanes
+        return pl.BlockSpec((1, 1, height, rows),
+                            lambda b, h, r: (b, h, 0, r))
+
+    stat, acc_spec = per_rows(1), per_rows(dv)
+    m, l = m[:, :, None], l[:, :, None]
+    m, l, acc = pl.pallas_call(
+        _streamed_block_kernel,
+        grid=(B, H, R // rows),
+        in_specs=[
+            smem, smem,
+            pl.BlockSpec((1, 1, rows, dh), lambda b, h, r: (b, h, r, 0)),
+            per_head(S, dh), per_head(S, dv),
+            pl.BlockSpec((1, 1, rows), lambda b, h, r: (b, 0, r)),
+            pl.BlockSpec((1, S, 128), lambda b, h, r: (b, 0, 0)),
+            stat, stat, acc_spec],
+        out_specs=[stat, stat, acc_spec],
+        out_shape=[jax.ShapeDtypeStruct(m.shape, m.dtype),
+                   jax.ShapeDtypeStruct(l.shape, l.dtype),
+                   jax.ShapeDtypeStruct(acc.shape, acc.dtype)],
+        input_output_aliases={7: 0, 8: 1, 9: 2},
+        interpret=interpret,
+        name="streamed_attention_block",
+    )(scale, window, q, k, v, qpos[:, None],
+      jnp.broadcast_to(kpos[:, :, None], kpos.shape + (128,)), m, l, acc)
+    return m[:, :, 0], l[:, :, 0], acc
+
+
+def _streamed_kernel_loop(q, qpos, fetch, n_blocks, window, scale, v_dim,
+                          interpret=False):
+    """streamed_attention's loop with the block kernel as its body.  A
+    K/V head's G query heads are rows of one array (R = G * T rows at
+    positions qpos tiled G times), so a program's products are as tall
+    as the head count allows whatever the grouping."""
+    B, Hkv, G, T, dh = q.shape
+    R = G * T
+    q = q.reshape(B, Hkv, R, dh)
+    qpos = jnp.tile(qpos.astype(jnp.int32), (1, G))
+    scale = jnp.full((1,), scale, jnp.float32)
+    window = jnp.full((1,), _NO_WINDOW if window is None else window,
+                      jnp.int32)
+
+    def body(carry):
+        i, *stats = carry
+        k, v, kpos = fetch(i)
+        return (i + 1, *_streamed_block(scale, window, q, k, v, qpos,
+                                        kpos.astype(jnp.int32), *stats,
+                                        interpret=interpret))
+
+    init = (jnp.int32(0),
+            jnp.full((B, Hkv, R), DEFAULT_MASK_VALUE, jnp.float32),
+            jnp.zeros((B, Hkv, R), jnp.float32),
+            jnp.zeros((B, Hkv, v_dim, R), jnp.float32))
+    # a while loop whatever the trip count: `fori_loop` makes a scan of a
+    # static one, whose body jax rewrites (a new jaxpr object for the
+    # same block function), and the program then holds a second lowered
+    # copy of the kernel beside the traced loops'
+    _, _, l, acc = jax.lax.while_loop(lambda c: c[0] < n_blocks, body, init)
+    out = acc / jnp.maximum(l, 1e-30)[:, :, None]
+    return jnp.swapaxes(out, 2, 3).reshape(B, Hkv, G, T, v_dim).astype(
+        q.dtype)
+
+
+def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
+                       v_dim=None):
+    """Causal attention over keys that arrive block by block, with an
+    online softmax: the serve path's recipe for caches too long to score
+    at once, grouped-query heads and a sliding window included.
+
+    q [B, Hkv, G, T, dh] (G query heads a K/V head) at positions qpos
+    [B, T]; `fetch(i)` gives block i's keys and values [B, Hkv, S, dh]
+    and their positions kpos [B, S] (negative: no key there).  Query t
+    sees key s iff 0 <= qpos - kpos (< window, where a window is given).
+    `n_blocks` may be traced: only blocks 0..n_blocks-1 are fetched.
+    A row that sees no key at all comes out zero.  Returns [B, Hkv, G, T,
+    dh] in q's dtype; scores and statistics are f32.
+
+    `scale` multiplies the scores (dh ** -0.5 where none is given) and
+    `v_dim` is the width of the values where it is not the keys' (latent
+    attention: keys [.., 576] — latent and rope part — against values that
+    are the latent alone; or 192-wide keys with 128-wide values): the
+    result is then [B, Hkv, G, T, v_dim].
+
+    One algorithm, two bodies for a block, chosen by what the call can
+    see (`streamed_attention_uses_kernel`: the rows a head brings and the
+    platform).  A prefill chunk on a TPU (T >= 128) takes a Pallas
+    kernel, `streamed_attention_block`: a block's scores, its masks and p
+    live in VMEM only, where the XLA body (`_streamed_xla`) sends f32
+    scores [heads, T, S] through memory between its two products (134 MB
+    a block of a 512-row chunk of 128 heads: 40% of the chip's time in
+    `serve-commandaplus-mixedctx` and 55% in `serve-deepseekv3-longctx`
+    before, PERF.md section 6, PR 46).  A decode step (T = 1, slots in
+    B: DeepSeek's absorbed form too) and every other platform take the
+    XLA body: a row a head gives a kernel nothing to keep on the chip.
+    The mathematics and the precision are the same: products on operands
+    as they come, f32 scores, statistics and accumulator, p cast to the
+    values' dtype."""
+    T, dh = q.shape[-2:]
+    if scale is None:
+        scale = dh ** -0.5
+    if v_dim is None:
+        v_dim = dh
+    if streamed_attention_uses_kernel(T):
+        return _streamed_kernel_loop(q, qpos, fetch, n_blocks, window, scale,
+                                     v_dim)
+    return _streamed_xla(q, qpos, fetch, n_blocks, window, scale, v_dim)

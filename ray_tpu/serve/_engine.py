@@ -176,6 +176,10 @@ def _m_gauge(which: str):
         "active": ("ray_tpu_serve_active_slots", "Occupied decode slots"),
         "queue": ("ray_tpu_serve_queue_depth", "Waiting (unadmitted) requests"),
         "free_pages": ("ray_tpu_serve_free_pages", "Free KV-cache pages"),
+        "chunk_attn_kernel": (
+            "ray_tpu_serve_chunk_attn_kernel_share",
+            "Share of prefill chunk launches whose attention took the "
+            "Pallas block kernel"),
     }
     name, desc = names[which]
     return _metric(which, lambda: mm.Gauge(name, description=desc))
@@ -475,6 +479,11 @@ class ContinuousEngine:
         self._kv_read = getattr(gpt_mod, "step_kv_read", None)
         if self._kv_read is not None:
             self._stat_keys += ("kv_read", "kv_span")
+        # a model whose chunks attend through ops.attention's
+        # streamed_attention hands on the predicate that function picks
+        # its body by (`chunk_attn_kernel(rows)` of its module): a chunk
+        # launch is stamped with it, no program's text is touched
+        self._chunk_attn = getattr(gpt_mod, "chunk_attn_kernel", None)
         self._fns: Dict[Any, Any] = {}   # bounded by construction: one
         # step program + one prefill per padded-length bucket + setrow +
         # copy_page
@@ -507,6 +516,7 @@ class ContinuousEngine:
         self._totals = {"requests": 0, "rejected": 0, "tokens": 0,
                         "steps": 0, "prefills": 0, "cow_copies": 0,
                         "shared_pages": 0, "chunks": 0,
+                        "chunk_attn_kernel": 0,
                         "window_pages_returned": 0,
                         # cumulative sums of the ring's records, so two
                         # engine_stats() snapshots give shares over any
@@ -535,6 +545,7 @@ class ContinuousEngine:
         self._first: List[_Sequence] = []   # first token this iteration
         self._chunks = 0             # prefill programs run
         self._chunk_tokens = 0       # prompt tokens they computed
+        self._chunk_kernel = 0       # of them, attention took the kernel
         self._returned = 0           # window pages returned
         self._stats: Dict[str, float] = {}  # the programs' own counters
 
@@ -664,7 +675,15 @@ class ContinuousEngine:
             **self._param_stats,
             **self._state_stats(),
             **totals,
+            **({"chunk_attn_kernel_share": self._chunk_share(totals)}
+               if self._chunk_attn else {}),
         }
+
+    def _chunk_share(self, totals=None) -> float:
+        """Of the prefill chunks launched so far, the share whose
+        attention took the block kernel (1.0 on a TPU, 0.0 elsewhere)."""
+        totals = totals or self._totals
+        return totals["chunk_attn_kernel"] / max(1, totals["chunks"])
 
     def _state_stats(self) -> Dict[str, int]:
         """A model with a state kind: its entries in use and free, and
@@ -881,6 +900,7 @@ class ContinuousEngine:
         self._blocked = 0
         self._first = []
         self._chunks = self._chunk_tokens = self._returned = 0
+        self._chunk_kernel = 0
         self._stats = dict.fromkeys(self._stat_keys, 0.0)
         # enqueue: the admission's programs, the step behind them ...
         with ann("serve.engine.admit", iter=self._iter):
@@ -911,6 +931,8 @@ class ContinuousEngine:
                    "blocked_slots": self._blocked,
                    "chunks": self._chunks,
                    "chunk_tokens": self._chunk_tokens,
+                   **({"chunk_attn_kernel": self._chunk_kernel}
+                      if self._chunk_attn else {}),
                    "pages_returned": self._returned,
                    **{"pages_" + k: a.used_pages
                       for k, a in self._allocs.items()},
@@ -931,9 +953,11 @@ class ContinuousEngine:
                     m.observe(it["dispatch_s"], tags={"phase": "dispatch"})
             with self._lock:
                 qd = len(self._waiting)
-            for which, val in (("active", stepped),
-                               ("queue", qd),
-                               ("free_pages", self._alloc.free_pages)):
+            gauges = [("active", stepped), ("queue", qd),
+                      ("free_pages", self._alloc.free_pages)]
+            if self._chunk_attn and self._chunks:
+                gauges.append(("chunk_attn_kernel", self._chunk_share()))
+            for which, val in gauges:
                 g = _m_gauge(which)
                 if g:
                     g.set(val)
@@ -1162,6 +1186,9 @@ class ContinuousEngine:
         self._chunks += 1
         self._chunk_tokens += n
         self._totals["chunks"] += 1
+        if self._chunk_attn and self._chunk_attn(T):
+            self._chunk_kernel += 1
+            self._totals["chunk_attn_kernel"] += 1
         self._shrink_windows(seq, seq.next_start)
         if not last:
             self._prefilling = seq

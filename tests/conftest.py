@@ -84,7 +84,7 @@ _QUICK_FILES = {
     "test_serve_mixed_pools.py", "test_serve_state_kind.py",
     "test_serve_weights_view.py",
     "test_serve_grpc.py",
-    "test_state.py",
+    "test_state.py", "test_streamed_attention.py",
     "test_submit_batching.py", "test_telemetry.py", "test_tune.py",
 }
 

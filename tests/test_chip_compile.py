@@ -70,6 +70,13 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _streamed_kernels(compiled) -> int:
+    """Instructions of the compiled program that run streamed_attention's
+    block kernel (ops/attention.py, PR 46)."""
+    return len(re.findall(r"custom-call\(.*streamed_attention_block",
+                          compiled.as_text()))
+
+
 # -- flash attention ---------------------------------------------------------
 
 
@@ -466,7 +473,7 @@ def test_gpt2_small_train_step_compiles_with_kernel(topo, axes, batch):
 
 @pytest.mark.parametrize("key", ["step", ("prefill", 512)],
                          ids=["step", "prefill512"])
-def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key):
+def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     """The mixed-pool serve programs (grouped expert products, streamed
     attention over two page pools) at the benchmark configuration's sizes:
     the chip's compiler takes them, weights + both arenas + temporaries
@@ -507,9 +514,14 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key):
             args = (params, cache, s((key[1],), jnp.int32),
                     {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
                     i32, i32)
+        # as on the chip: streamed_attention picks its body by the backend
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         compiled = eng._fn(key).lower(*args).compile()
     finally:
         eng.stop()
+    # a chunk's four layers attend through ONE lowered block kernel (the
+    # compiler inlines it a layer); a step's rows take the XLA body
+    assert _streamed_kernels(compiled) == (0 if key == "step" else 4)
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
@@ -603,7 +615,7 @@ def test_brumby_serve_programs_fit_one_chip(one_chip, key):
 
 @pytest.mark.parametrize("key", ["step", ("prefill", 512)],
                          ids=["step", "prefill512"])
-def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key):
+def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     """The latent-page serve programs (absorbed attention in the step,
     expanded in the 512-row chunk, grouped routing, grouped expert
     products) from the benchmark's own `engine_kwargs`, 32 slots: the
@@ -645,10 +657,12 @@ def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key):
             args = (view, cache, s((key[1],), jnp.int32),
                     {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
                     i32, i32)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         compiled = eng._fn(key).lower(*args).compile()
         stats = eng.engine_stats()
     finally:
         eng.stop()
+    assert _streamed_kernels(compiled) == (0 if key == "step" else 5)
     assert (B, eng._pool_pages, eng.max_total, eng._widths, eng._share) == (
         32, {"full": 4353}, 17408, {"full": 136}, True)
     m = compiled.memory_analysis()

@@ -1,0 +1,261 @@
+"""`ops/attention.streamed_attention`: the Pallas block kernel a prefill
+chunk takes on a TPU against the XLA body everything else takes (PR 46).
+
+The kernel runs here under `interpret=True`; what is lowered for the chip
+is lowered for the platform "tpu" from the CPU (Pallas lowers to Mosaic in
+Python), with `jax.default_backend` patched where the predicate asks it.
+`tests/test_chip_compile.py` compiles the kernel for a described v5e."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+A = importlib.import_module("ray_tpu.ops.attention")
+
+T = S = 128
+
+
+def _operands(Hkv, G, dh, dv, dtype, n_blocks, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (1, Hkv, G, T, dh), dtype)
+    K = jax.random.normal(ks[1], (n_blocks, 1, Hkv, S, dh), dtype)
+    V = jax.random.normal(ks[2], (n_blocks, 1, Hkv, S, dv), dtype)
+    return q, K, V
+
+
+def _both(q, K, V, qpos, kpos_of, n_blocks, window, dv):
+    """(XLA body, kernel in interpret mode) on the same operands, the
+    trip count traced."""
+    def fetch(i):
+        return K[i], V[i], kpos_of(i)
+
+    def xla(n):
+        return A.streamed_attention(q, qpos, fetch, n, window=window,
+                                    v_dim=dv)
+
+    def kernel(n):
+        return A._streamed_kernel_loop(q, qpos, fetch, n, window,
+                                       q.shape[-1] ** -0.5, dv,
+                                       interpret=True)
+
+    n = jnp.int32(n_blocks)
+    return jax.jit(xla)(n), jax.jit(kernel)(n)
+
+
+# the two chunk callers' (Hkv, G, dh, v_dim) at reduced head counts
+COHERE = (2, 4, 128, 128)
+DEEPSEEK = (4, 1, 192, 128)
+CASES = {
+    # name: (heads, dtype, window, n_blocks, mask)
+    "cohere-f32-full": (COHERE, jnp.float32, None, 3, "causal"),
+    "cohere-f32-window-in-block": (COHERE, jnp.float32, 40, 3, "causal"),
+    "cohere-f32-window-past-context": (COHERE, jnp.float32, 4096, 3,
+                                       "causal"),
+    "cohere-f32-holes": (COHERE, jnp.float32, 200, 3, "holes"),
+    "cohere-f32-dead-block": (COHERE, jnp.float32, None, 3, "dead-block"),
+    "cohere-f32-masked-rows": (COHERE, jnp.float32, None, 2, "masked-rows"),
+    "cohere-f32-no-key": (COHERE, jnp.float32, None, 2, "no-key"),
+    "cohere-f32-zero-blocks": (COHERE, jnp.float32, 64, 0, "causal"),
+    "cohere-bf16-full": (COHERE, jnp.bfloat16, None, 3, "causal"),
+    "cohere-bf16-window": (COHERE, jnp.bfloat16, 100, 3, "holes"),
+    "deepseek-f32-full": (DEEPSEEK, jnp.float32, None, 3, "causal"),
+    "deepseek-f32-holes": (DEEPSEEK, jnp.float32, None, 3, "holes"),
+    "deepseek-f32-masked-rows": (DEEPSEEK, jnp.float32, None, 2,
+                                 "masked-rows"),
+    "deepseek-f32-zero-blocks": (DEEPSEEK, jnp.float32, None, 0, "causal"),
+    "deepseek-bf16-full": (DEEPSEEK, jnp.bfloat16, None, 3, "causal"),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_matches_the_xla_body(name):
+    (Hkv, G, dh, dv), dtype, window, n_blocks, mask = CASES[name]
+    q, K, V = _operands(Hkv, G, dh, dv, dtype, max(n_blocks, 1))
+    # the chunk is the context's last T positions
+    qpos = (max(n_blocks, 1) * S - T + jnp.arange(T, dtype=jnp.int32))[None]
+    zero_rows = np.zeros(T, bool)
+
+    def kpos_of(i):
+        kpos = (i * S + jnp.arange(S, dtype=jnp.int32))[None]
+        if mask == "holes":             # entries that hold no key
+            kpos = jnp.where(kpos % 7 == 3, -1, kpos)
+        if mask == "dead-block":        # block 1 holds nothing at all
+            kpos = jnp.where(i == 1, -1, kpos)
+        if mask == "no-key":            # no block holds anything
+            kpos = jnp.full_like(kpos, -1)
+        return kpos
+
+    if mask == "masked-rows":
+        # the first 16 rows stand before every key: they see none
+        qpos = qpos.at[0, :16].set(-5 - jnp.arange(16))
+        zero_rows[:16] = True
+    if mask == "no-key" or n_blocks == 0:
+        zero_rows[:] = True
+
+    want, got = _both(q, K, V, qpos, kpos_of, n_blocks, window, dv)
+    assert got.shape == want.shape == (1, Hkv, G, T, dv)
+    assert got.dtype == want.dtype == dtype
+    want, got = (np.asarray(a, np.float32) for a in (want, got))
+    # f32: the same sums in another order; bf16: one unit in the last
+    # place of a value of order one
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 if dtype == jnp.float32 else 1e-2)
+    assert np.all(got[:, :, :, zero_rows] == 0.0)
+    assert np.all(np.any(got[:, :, :, ~zero_rows] != 0.0, axis=-1))
+
+
+def test_predicate_is_rows_and_platform():
+    uses = A.streamed_attention_uses_kernel
+    assert jax.default_backend() == "cpu"
+    assert not uses(512) and not uses(1)
+    assert uses(512, "tpu") and uses(128, "tpu")
+    assert not uses(1, "tpu") and not uses(127, "tpu")
+    assert not uses(512, "cpu") and not uses(512, "gpu")
+    # the models hand the engine the same function
+    from ray_tpu.models import cohere2_moe, deepseek_v3
+    assert cohere2_moe.chunk_attn_kernel is uses
+    assert deepseek_v3.chunk_attn_kernel is uses
+
+
+def _two_layers(rows, B=1):
+    """A program of two attention layers as Command A+'s chunk has them
+    (one windowed, one full), lowered for the platform "tpu"."""
+    Hkv, G, dh = 2, 4, 128
+    q = jax.ShapeDtypeStruct((B, Hkv, G, rows, dh), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((4, B, Hkv, S, dh), jnp.bfloat16)
+    qpos = jax.ShapeDtypeStruct((B, rows), jnp.int32)
+
+    def program(q, K, V, qpos, n):
+        def fetch(i):
+            kpos = (i * S + jnp.arange(S, dtype=jnp.int32))[None]
+            return K[i], V[i], jnp.broadcast_to(kpos, (B, S))
+        x = A.streamed_attention(q, qpos, fetch, n, window=64)
+        return A.streamed_attention(x, qpos, fetch, n)
+
+    traced = jax.jit(program).trace(q, kv, kv, qpos,
+                                    jax.ShapeDtypeStruct((), jnp.int32))
+    return traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_a_decode_step_lowers_to_no_custom_call(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _two_layers(rows=1, B=8)
+    assert "custom_call" not in text and "_streamed_block" not in text
+    # ... and is the text the CPU's choice lowers to: one XLA body
+    monkeypatch.undo()
+    assert _two_layers(rows=1, B=8) == text
+
+
+def test_a_chunk_program_holds_the_kernel_once(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _two_layers(rows=T)
+    # two call sites (a windowed layer, a full one: the window is an
+    # operand), ONE lowered function, one Mosaic module in it
+    assert text.count("call @_streamed_block") == 2
+    assert text.count("func.func private @_streamed_block") == 1
+    assert text.count("tpu_custom_call") == 1
+    assert "streamed_attention_block" in text
+
+
+def test_a_model_chunk_program_holds_the_kernel_once(monkeypatch):
+    """Command A+'s chunk program at toy size, 128 rows: three sliding
+    layers (their loop's trip count static) and a full one (traced), both
+    layer kinds through ONE lowered kernel function; its step program
+    holds none."""
+    from ray_tpu.models import cohere2_moe as cm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = cm.Cohere2MoEConfig.nano(max_seq=512, kv_block=128,
+                                   sliding_window=128, d_head=128)
+    params = jax.eval_shape(
+        lambda: cm.serve_view(cm.init(jax.random.PRNGKey(0), cfg), cfg))
+    kinds = cm.cache_kinds(cfg)
+    cache = jax.eval_shape(
+        lambda: cm.init_paged_cache(cfg, {k: 64 for k in kinds}, 16))
+    widths = {k: (512 if w is None else w + 128) // 16
+              for k, w in kinds.items()}
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def lowered(fn, *args):
+        return jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    chunk = lowered(
+        lambda p, c, t, tabs, s, l: cm.paged_prefill(p, c, t, tabs, s, l,
+                                                     cfg),
+        params, cache, jax.ShapeDtypeStruct((128,), jnp.int32),
+        {k: jax.ShapeDtypeStruct((w,), jnp.int32)
+         for k, w in widths.items()}, i32, i32)
+    assert chunk.count("call @_streamed_block") == cfg.n_layers
+    assert chunk.count("func.func private @_streamed_block") == 1
+    assert chunk.count("tpu_custom_call") == 1
+    step = lowered(
+        lambda p, c, t, tabs, pos: cm.paged_decode_step(p, c, t, tabs, pos,
+                                                        cfg),
+        params, cache, jax.ShapeDtypeStruct((4,), jnp.int32),
+        {k: jax.ShapeDtypeStruct((4, w), jnp.int32)
+         for k, w in widths.items()}, jax.ShapeDtypeStruct((4,), jnp.int32))
+    assert "custom_call" not in step and "_streamed_block" not in step
+
+
+def test_a_chunk_launch_is_stamped_with_the_predicate(monkeypatch):
+    """The engine asks the model's module (`chunk_attn_kernel`) at every
+    prefill program it launches: the ring record of an iteration that ran
+    chunks counts those whose attention took the kernel (none on the CPU),
+    `engine_stats()` gives the share, and a model whose chunks do not
+    attend through streamed_attention carries neither."""
+    import threading
+
+    from ray_tpu.models import cohere2_moe as cm
+    from ray_tpu.models import gpt
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    def by_hand(eng):
+        t = threading.Thread(target=lambda: None)
+        t.start()
+        t.join()
+        eng._thread = t
+        return eng
+
+    def serve(eng):
+        seq = eng.submit(list(range(1, 22)), 2)
+        for _ in range(50):
+            eng._iteration()
+            if seq.result.done():
+                break
+        assert seq.result.done()
+        return [r for r in eng.phase_ring() if r["chunks"]]
+
+    cfg = cm.Cohere2MoEConfig.nano(dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
+    kw = dict(max_slots=2, page_size=4, max_total=64, prefill_bucket=4,
+              prefill_chunk=8)
+    eng = by_hand(ContinuousEngine(cm, cfg, cm.init(jax.random.PRNGKey(0),
+                                                    cfg), **kw))
+    recs = serve(eng)
+    assert sum(r["chunks"] for r in recs) == 3
+    assert all(r["chunk_attn_kernel"] == 0 for r in recs)
+    stats = eng.engine_stats()
+    assert stats["chunks"] == 3 and stats["chunk_attn_kernel"] == 0
+    assert stats["chunk_attn_kernel_share"] == 0.0
+    # where the predicate holds (the chip: rows >= 128 there) every chunk
+    # launch counts; the stamp reads the predicate, no program is touched
+    seen = []
+    monkeypatch.setattr(eng, "_chunk_attn",
+                        lambda rows: seen.append(rows) or True)
+    recs = serve(eng)[len(recs):]
+    assert seen == [8, 8, 8]        # the rows of each prefill program
+    assert all(r["chunk_attn_kernel"] == r["chunks"] for r in recs)
+    assert eng.engine_stats()["chunk_attn_kernel_share"] == 0.5
+    eng.stop()
+
+    gcfg = gpt.GPTConfig.nano(max_seq=64, dtype=jnp.float32)
+    geng = by_hand(ContinuousEngine(
+        gpt, gcfg, gpt.init(jax.random.PRNGKey(0), gcfg), max_slots=2,
+        page_size=8, prefill_bucket=8))
+    recs = serve(geng)
+    assert recs and all("chunk_attn_kernel" not in r for r in recs)
+    assert "chunk_attn_kernel_share" not in geng.engine_stats()
+    geng.stop()
