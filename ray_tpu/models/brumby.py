@@ -51,7 +51,8 @@ from .gpt import sample_logits, serve_view as _cast_leaves
 
 __all__ = ["BrumbyConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
-           "copy_page", "sample_logits", "serve_view", "STEP_STATS"]
+           "copy_page", "sample_logits", "serve_view", "state_leaves",
+           "STEP_STATS"]
 
 # what a serve program returns beside logits and cache (an f32 vector):
 # the states its retention read and wrote in a layer — for a step the live
@@ -299,6 +300,11 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
         cache, states.astype(cache.dtype), idx, 1)
     x = jax.lax.dynamic_index_in_dim(x, last_idx, 0, keepdims=False)
     return _logits(params, x, cfg), cache, jnp.ones((1,), jnp.float32)
+
+
+def state_leaves(cache):
+    """The leaves of `cache` that are the state kind's arena: all of it."""
+    return [cache]
 
 
 def copy_page(cache, dst, src):

@@ -454,24 +454,21 @@ def init_paged_cache(cfg: DeepSeekV3Config, num_pages, page_size: int
     return [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)]
 
 
-def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
-    """Tokens toks [B, T] at CONSECUTIVE positions pos [B, T] (a row's are
-    pos[b, 0] + t) through the layers against the paged latents; `real`
-    [B, T] marks the rows whose latent is kept (the others leave their
-    page as it was and do not route); ptab [B, R] in sequence order.
-    Attention is absorbed where T <= ABSORB_ROWS; `absorbed` (a test, the
-    timing study) names the form instead.  Returns (x [B, T, D], cache,
-    stats).
+def _page_io(ptab, pos, real, d: int, ps: int, cfg):
+    """How the rows of a program at CONSECUTIVE positions pos [B, T] (a
+    row's are pos[b, 0] + t; `real` [B, T] marks the rows whose latent is
+    kept) meet the pages of ptab [B, R] whose positions are `d` values by
+    `ps` lanes: `bind(arena)` -> (`_block`'s write and fetch over that
+    layer's arena, the box whose "arena" the write leaves), beside the
+    number of key blocks the live contexts reach (models/ling3.py's
+    latent-attention layers take their pages through this too).
 
     A layer's rows are written a whole page at a time: the pages the rows
     fall in are read, the rows laid over them, the pages written back.  A
     scatter of single rows — a sixteenth of a tile each — makes the chip's
     compiler re-lay the whole arena around it, in and out, every program
     (3.2 GB a step); whole pages are whole tiles."""
-    B, T = toks.shape
-    if absorbed is None:
-        absorbed = T <= ABSORB_ROWS
-    d, ps = cache[0].shape[1:]
+    B, T = pos.shape
     npb = max(1, cfg.kv_block // ps)
     R = ptab.shape[1]
     width = -(-R // npb) * npb
@@ -490,25 +487,46 @@ def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
     last = jnp.max(jnp.where(real, pos, 0))
     n_blocks = jnp.minimum(last // (npb * ps) + 1, width // npb)
     block_pos = jnp.arange(npb * ps, dtype=jnp.int32)
-    x = _slot_embed(params, toks, pos, cfg)
-    new_cache, loads = [], []
-    for layer, arena in zip(params["layers"], cache):
+
+    def bind(arena):
         box = {}
 
-        def write(rows, arena=arena, box=box):
+        def write(rows):
             old = jnp.swapaxes(arena[pages], 2, 3).reshape(B, n_pg * ps, d)
             new = jnp.where(lay, jnp.take_along_axis(
                 rows, row_of[..., None], axis=1), old)
             box["arena"] = arena.at[pages.reshape(B * n_pg)].set(
                 jnp.swapaxes(new.reshape(B * n_pg, ps, d), 1, 2))
 
-        def fetch(i, box=box):
+        def fetch(i):
             t = jax.lax.dynamic_slice_in_dim(tabp, i * npb, npb, 1)
             rows = jnp.swapaxes(box["arena"][t], 2, 3).reshape(
                 B, npb * ps, d)
             kpos = jnp.broadcast_to(i * npb * ps + block_pos, (B, npb * ps))
             return rows, kpos
 
+        return write, fetch, box
+
+    return bind, n_blocks
+
+
+def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
+    """Tokens toks [B, T] at CONSECUTIVE positions pos [B, T] (a row's are
+    pos[b, 0] + t) through the layers against the paged latents; `real`
+    [B, T] marks the rows whose latent is kept (the others leave their
+    page as it was and do not route); ptab [B, R] in sequence order.
+    Attention is absorbed where T <= ABSORB_ROWS; `absorbed` (a test, the
+    timing study) names the form instead.  Returns (x [B, T, D], cache,
+    stats)."""
+    T = toks.shape[1]
+    if absorbed is None:
+        absorbed = T <= ABSORB_ROWS
+    d, ps = cache[0].shape[1:]
+    bind, n_blocks = _page_io(ptab, pos, real, d, ps, cfg)
+    x = _slot_embed(params, toks, pos, cfg)
+    new_cache, loads = [], []
+    for layer, arena in zip(params["layers"], cache):
+        write, fetch, box = bind(arena)
         x, ld = _block(x, layer, pos, write, fetch, n_blocks, absorbed, cfg,
                        live=real)
         new_cache.append(box["arena"])

@@ -5,6 +5,7 @@ from .layers import (apply_rope, apply_rope_halves, apply_rope_interleaved,
                      fused_softmax_cross_entropy, gelu_mlp,
                      layer_norm, rms_norm, rope_table,
                      softmax_cross_entropy, swiglu, yarn_frequencies)
+from .kda import kda_chunk, kda_step
 from .quantize import (dequantize_blockwise, quantization_error,
                        quantize_blockwise)
 from .retention import retention_chunk, retention_step
@@ -17,7 +18,7 @@ __all__ = [
     "blockwise_attention", "mha_reference", "streamed_attention",
     "ring_attention", "ring_attention_sharded",
     "ulysses_attention", "ulysses_attention_sharded",
-    "retention_chunk", "retention_step",
+    "retention_chunk", "retention_step", "kda_chunk", "kda_step",
     "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_halves",
     "apply_rope_interleaved", "yarn_frequencies", "swiglu",
     "gelu_mlp", "softmax_cross_entropy", "fused_softmax_cross_entropy",

@@ -1,0 +1,265 @@
+"""Kimi Delta Attention (KDA; the Kimi Linear report, arXiv 2510.26692): a
+gated delta rule whose decay is a vector, one factor a KEY CHANNEL — in
+its two recurrent forms, one chunk of one sequence (prefill) and one
+token of every slot (decode), against a state of fixed size — and the
+short causal convolution that stands before it.
+
+For a head with keys and values dk and dv wide, per token t:
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T     [dk, dv]
+    o_t = S_t^T q_t
+
+with a_t in (0, 1)^dk (handed over as log a_t) and b_t in (0, 1).  Unlike
+ops/retention.py's accumulation the update SUBTRACTS what the decayed
+state already holds along k_t; written with S' = Diag(a_t) S_{t-1}:
+
+    u_t = b_t (v_t - S'^T k_t)        S_t = S' + k_t u_t^T
+
+How the state lies.  A head's state is kept TRANSPOSED, [dv, dk]: the key
+channels run along the lanes, so the decay, the read along k and the
+read-out along q are a row broadcast over the sublanes and a sum over the
+lanes, and the update a column times a row — the step's kernel takes q, k,
+v, a as rows and needs no operand a column wide.  An arena is
+[layers, entries, H, dv, dk] float32, entry 0 the null one.
+
+A chunk (the WY / UT form).  With G_t the running sum of log a inside a
+block of C rows and E_tj = exp(G_t - G_j) (j <= t, per channel):
+
+    A_tj = b_t sum_c k_t[c] k_j[c] E_tj[c]   (j <  t)
+    B_tj =     sum_c q_t[c] k_j[c] E_tj[c]   (j <= t)
+    U    = (I + A)^-1 b (V - (K exp G) S_0)
+    O    = (Q exp G) S_0 + B U
+    S_C  = Diag(exp G_C) S_0 + (K exp(G_C - G))^T U
+
+The exponent G_t - G_j is formed as a DIFFERENCE before the exponential:
+at the gate's lower bound (log a = -5) a block of 64 rows reaches -320,
+and the two factors exp(G_t), exp(-G_j) formed apart leave float32.
+(I + A)^-1 is the product (I - A)(I + A^2)(I + A^4).. — A is strictly
+lower triangular, so the series ends at A^C.  Everything is float32 and
+the products take float32 operands at the highest precision: a layer's
+chunk is 2 GFLOP, and bf16 operands would round the carried state at
+every block's read.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from .retention import _visit, resolve_impl
+
+__all__ = ["kda_chunk", "kda_step", "conv_chunk", "conv_step", "BLOCK",
+           "resolve_impl"]
+
+BLOCK = 64          # rows of one WY block of a chunk
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _inverse_unit_lower(a):
+    """(I + a)^-1 for strictly lower triangular a [.., C, C]."""
+    C = a.shape[-1]
+    eye = jnp.eye(C, dtype=a.dtype)
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    inv, power, n = eye - a, a, 2
+    while n < C:
+        power = mm(power, power)
+        inv = mm(inv, eye + power)
+        n *= 2
+    return inv
+
+
+def _block(state, xs):
+    """One WY block of every head: state [H, dv, dk]; q, k [H, C, dk],
+    v [H, C, dv], log_a [H, C, dk], beta [H, C] -> (state, o [H, C, dv])."""
+    q, k, v, log_a, beta = xs
+    C = q.shape[1]
+    es = functools.partial(jnp.einsum, precision=_HI)
+    g = jnp.cumsum(log_a, axis=1)
+    t = jnp.arange(C)
+    see = (t[:, None] >= t[None, :])[None, :, :, None]
+    e = jnp.exp(jnp.where(see, g[:, :, None, :] - g[:, None, :, :], -jnp.inf))
+    ke = k[:, None, :, :] * e                                   # [H, t, j, dk]
+    a = (beta[:, :, None] * (k[:, :, None, :] * ke).sum(-1)
+         * (t[:, None] > t[None, :]))
+    b = (q[:, :, None, :] * ke).sum(-1)
+    into = jnp.exp(g)
+    u = jnp.matmul(_inverse_unit_lower(a), beta[..., None] * (
+        v - es("htc,hvc->htv", k * into, state)), precision=_HI)
+    o = es("htc,hvc->htv", q * into, state) + jnp.matmul(b, u, precision=_HI)
+    out_of = jnp.exp(g[:, -1:, :] - g)
+    state = (state * jnp.exp(g[:, -1])[:, None, :]
+             + es("htv,htc->hvc", u, k * out_of))
+    return state, o
+
+
+@functools.partial(jax.jit, static_argnames="block")
+def kda_chunk(q, k, v, log_a, beta, state, block: int = BLOCK):
+    """One chunk of one sequence.  q, k [H, T, dk] (normalised as the
+    layer says), v [H, T, dv], log_a [H, T, dk] (<= 0), beta [H, T], all
+    float32; state [H, dv, dk] float32 (zeros for a sequence's first
+    chunk).  A pad row carries k = 0, beta = 0 and log_a = 0: it adds
+    nothing and forgets nothing.  Returns (o [H, T, dv] float32, the
+    state after the chunk).  Jitted here, so that a program that calls it
+    a layer traces and lowers it once (as `_step_pallas`: ROADMAP S11)."""
+    H, T, _ = q.shape
+    C = min(block, T)
+    pad = -T % C
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def blocks(x):      # [H, T, ..] -> [T/C, H, C, ..], pad rows zero
+        x = jnp.pad(f32(x), ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((H, (T + pad) // C, C) + x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    with jax.named_scope("kda_chunk"):
+        state, o = jax.lax.scan(
+            _block, f32(state), tuple(map(blocks, (q, k, v, log_a, beta))))
+        o = jnp.moveaxis(o, 0, 1).reshape(H, T + pad, -1)
+        return o[:, :T], state
+
+
+def _step_kernel(layer_ref, ent_ref, flag_ref, r_ref, s_ref, o_ref,
+                 s_out_ref):
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    H, dv, _ = s_ref.shape
+
+    @pl.when(flag_ref[b] > 0)
+    def _():
+        i = jax.lax.broadcasted_iota(jnp.int32, (dv, dv), 0)
+        j = jax.lax.broadcasted_iota(jnp.int32, (dv, dv), 1)
+        eye = (i == j).astype(jnp.float32)
+        for h in range(H):
+            q, k, kb, v, a = (r_ref[n, h:h + 1, :] for n in range(5))
+            s = s_ref[h] * a                                  # Diag(a) S
+            held = jnp.sum(s * k, axis=1, keepdims=True)      # S'^T k, a column
+            v_col = jnp.sum(eye * v, axis=1, keepdims=True)
+            s = s + (v_col - held) * kb
+            s_out_ref[h] = s
+            o_col = jnp.sum(s * q, axis=1, keepdims=True)
+            o_ref[h:h + 1, :] = jnp.sum(eye * o_col, axis=0, keepdims=True)
+
+    @pl.when(flag_ref[b] <= 0)
+    def _():
+        # an empty slot's turn points at a live neighbour's block
+        # (retention._visit) and must leave it alone; with no live slot at
+        # all it points at the null entry, which goes back as it came
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(flag_ref[flag_ref.shape[0] - 1] < 0)
+        def _():
+            s_out_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _step_pallas(rows, state, layer, idx, live, interpret: bool):
+    """The step as one kernel: a grid turn is one slot; the slot's block —
+    its H heads' states, 2 MB at the published widths — is read where its
+    entry stands in the layer's part of the arena, updated, read out and
+    written back to the same place (the arena is aliased to the output).
+    Only live slots' blocks are moved."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, _, H, dk = rows.shape
+    dv = state.shape[-2]
+    entry, _, flag = _visit(idx, live, 1)
+    at_slot = lambda b, *_: (b, 0, 0, 0)
+    at_entry = lambda b, layer, entry, flag: (layer[0], entry[b], 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(B,),
+        in_specs=[pl.BlockSpec((None, 5, H, dk), at_slot),
+                  pl.BlockSpec((None, None, H, dv, dk), at_entry)],
+        out_specs=[pl.BlockSpec((None, H, dv), lambda b, *_: (b, 0, 0)),
+                   pl.BlockSpec((None, None, H, dv, dk), at_entry)])
+    return pl.pallas_call(
+        _step_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret, name="kda_step",
+    )(layer.reshape(1), entry, flag, rows, state)
+
+
+def _step_xla(rows, state, layer, idx, live):
+    q, k, kb, v, a = (rows[:, n] for n in range(5))           # [B, H, d]
+    es = functools.partial(jnp.einsum, precision=_HI)
+    old = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)[idx]
+    s = old.astype(jnp.float32) * a[:, :, None, :]
+    s = s + (v - es("bhvc,bhc->bhv", s, k))[..., None] * kb[:, :, None, :]
+    alive = (live != 0)[:, None, None]
+    o = jnp.where(alive, es("bhvc,bhc->bhv", s, q), 0.0)
+    new = jnp.where(alive[..., None], s.astype(state.dtype), old)
+    return o, state.at[layer, idx].set(new)
+
+
+def kda_step(q, k, v, log_a, beta, state, layer, idx, live,
+             impl: Optional[str] = None):
+    """One token of every slot in one layer.  q, k [B, H, dk], v
+    [B, H, dv], log_a [B, H, dk], beta [B, H]; `state` is the arena
+    [L, N, H, dv, dk], `layer` (a scalar, traced or not) the part of it
+    this call reads and writes, and slot b's state its entry idx[b] there;
+    a slot with live[b] == 0 leaves its entry as it is (empty slots ride
+    on the null entry).  Returns (o [B, H, dv] float32, the arena).
+
+    `impl`: "pallas" (the chip's path: in place, one read and one write of
+    each LIVE slot's state and none of an empty slot's),
+    "pallas_interpret", or "xla" (gather, update, scatter of every slot's:
+    the CPU's path); None picks by backend."""
+    impl = resolve_impl(impl)
+    with jax.named_scope("kda_step"):
+        f32 = lambda x: x.astype(jnp.float32)
+        k = f32(k)
+        rows = jnp.stack([f32(q), k, k * f32(beta)[..., None], f32(v),
+                          jnp.exp(f32(log_a))], axis=1)       # [B, 5, H, d]
+        idx, live = idx.astype(jnp.int32), live.astype(jnp.int32)
+        layer = jnp.asarray(layer, jnp.int32)
+        if impl == "xla":
+            return _step_xla(rows, state, layer, idx, live)
+        if impl not in ("pallas", "pallas_interpret"):
+            raise ValueError(f"unknown kda impl {impl!r}")
+        if state.dtype != jnp.float32:
+            raise ValueError("the kernel keeps its state in float32")
+        if q.shape[-1] != v.shape[-1]:
+            raise ValueError("the kernel stacks q, k, v as rows of one "
+                             "width: dk must equal dv")
+        return _step_pallas(rows, state, layer, idx, live,
+                            impl == "pallas_interpret")
+
+
+# ---------------------------------------------------------------------------
+# the short convolution before it: depthwise, causal, `W` taps, then SiLU
+
+
+def conv_chunk(rows, tail, w, b):
+    """rows [T, ..] one sequence's pre-conv rows in order, tail [W-1, ..]
+    the W-1 rows before them (zeros before a sequence's start), w [W, ..]
+    (tap W-1 meets the row itself), b [..] -> SiLU(conv) [T, ..] float32:
+    a sum over W shifted copies."""
+    with jax.named_scope("kda_conv"):
+        T, W = rows.shape[0], w.shape[0]
+        ext = jnp.concatenate([tail.astype(rows.dtype), rows], axis=0)
+        acc = b.astype(jnp.float32) + sum(
+            w[i].astype(jnp.float32)
+            * jax.lax.slice_in_dim(ext, i, i + T, axis=0).astype(jnp.float32)
+            for i in range(W))
+        return jax.nn.silu(acc)
+
+
+def conv_step(row, tail, w, b):
+    """One token of every slot: row [B, ..] the new pre-conv rows, tail
+    [B, W-1, ..] each slot's last W-1 -> (SiLU(conv) [B, ..] float32, the
+    tails after: the oldest row out, the new one in)."""
+    with jax.named_scope("kda_conv"):
+        ext = jnp.concatenate([tail, row[:, None].astype(tail.dtype)], axis=1)
+        acc = b.astype(jnp.float32) + jnp.einsum(
+            "bw...,w...->b...", ext.astype(jnp.float32),
+            w.astype(jnp.float32))
+        return jax.nn.silu(acc), ext[:, 1:]

@@ -30,8 +30,9 @@ above.
 
 The engine serves whatever module implements its interface
 (`cache_kinds`, `init_paged_cache`, `paged_decode_step`, `paged_prefill`,
-`copy_page` where pages are shared; models/gpt.py, models/cohere2_moe.py,
-models/brumby.py, models/deepseek_v3.py).
+`copy_page` where pages are shared, `state_leaves` where a kind is a state;
+models/gpt.py, models/cohere2_moe.py, models/brumby.py,
+models/deepseek_v3.py, models/ling3.py).
 A model's layers may keep several **kinds of KV state**: `cache_kinds`
 names them with their window, and the engine keeps a pool of pages, an
 allocator and a page table per kind.  A full kind's pages are taken at
@@ -52,7 +53,12 @@ emptied by the sequence's first prefill chunk, carried from chunk to chunk
 and step to step, returned at eviction — and the kind's table is that one
 entry's index.  A model all of whose kinds are states has no page to run
 out of: it is admitted while a slot and an entry are free, and `max_total`
-bounds its positions only.
+bounds its positions only.  A model may MIX a state kind with paged kinds
+(ling3: delta-rule layers beside latent attention): an admission then takes
+a slot, an entry AND its pages, waits while any of the three is missing and
+returns all of them at eviction; which leaves of the cache are the state
+arena the model says (`state_leaves(cache)`), so the entries' bytes are
+counted apart from the pages'.
 
 Memory is a **paged arena** (models/gpt.py init_paged_cache): fixed-size
 pages in one preallocated device array, per-slot page tables gathered
@@ -499,6 +505,8 @@ class ContinuousEngine:
         self._cache = None
         self._logits = None          # [B, V] carried across steps
         self._state_bytes = 0        # the arena of a model's state kinds
+        self._entry_bytes = 0        # of it, one entry's
+        self._page_bytes = 0         # one page's, of the cache's other leaves
 
         # host mirrors of the per-slot step operands
         B = self.max_slots
@@ -693,7 +701,20 @@ class ContinuousEngine:
         return {"states_live": self._states_live(),
                 "states_free": sum(self._allocs[k].free_pages
                                    for k in self._state_kinds),
-                "state_arena_bytes": self._state_bytes}
+                "state_arena_bytes": self._state_bytes,
+                **self._live_bytes()}
+
+    def _live_bytes(self) -> Dict[str, int]:
+        """Of a model with a state kind: the bytes its live entries hold
+        (`state_bytes`) and those with the bytes of its used pages
+        (`cache_bytes`; the null entry and the null pages are no one's).
+        The engine does not look inside a page: a page's bytes are the
+        paged leaves' over the pools' pages."""
+        held = self._states_live() * self._entry_bytes
+        used = sum(a.used_pages for k, a in self._allocs.items()
+                   if k not in self._state_kinds)
+        return {"state_bytes": held,
+                "cache_bytes": held + used * self._page_bytes}
 
     def _states_live(self) -> int:
         return sum(self._allocs[k].used_pages for k in self._state_kinds)
@@ -936,7 +957,8 @@ class ContinuousEngine:
                    "pages_returned": self._returned,
                    **{"pages_" + k: a.used_pages
                       for k, a in self._allocs.items()},
-                   **({"states_live": self._states_live()}
+                   **({"states_live": self._states_live(),
+                       **self._live_bytes()}
                       if self._state_kinds else {}),
                    **self._stats,
                    "requests": [self._request_record(s)
@@ -1455,12 +1477,18 @@ class ContinuousEngine:
             self._cfg, self._pool_pages, self.page_size)
         self._logits = jnp.zeros(
             (self.max_slots, self._cfg.vocab_size), jnp.float32)
-        # where every kind is a state the whole cache is the state arena (a
-        # model that mixed states with pages would have to say which part)
-        if self._state_kinds and len(self._state_kinds) == len(self._kinds):
-            self._state_bytes = sum(
-                int(a.nbytes)
-                for a in self._jax.tree_util.tree_leaves(self._cache))
+        # a model with a state kind says which leaves of its cache are
+        # that kind's arena; the others hold its pages
+        if self._state_kinds:
+            nbytes = lambda leaves: sum(int(a.nbytes) for a in leaves)
+            pools = lambda kinds: max(1, sum(self._pool_pages[k]
+                                             for k in kinds))
+            self._state_bytes = nbytes(self._gpt.state_leaves(self._cache))
+            self._entry_bytes = self._state_bytes // pools(self._state_kinds)
+            self._page_bytes = (
+                nbytes(self._jax.tree_util.tree_leaves(self._cache))
+                - self._state_bytes) // pools(
+                    k for k in self._kinds if k not in self._state_kinds)
 
     def _fn(self, key):
         fn = self._fns.get(key)

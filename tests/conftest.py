@@ -70,8 +70,8 @@ _QUICK_FILES = {
     "test_core_objects.py", "test_core_tasks.py", "test_data.py",
     "test_data_remote_io.py", "test_deepseek_v3.py",
     "test_device_telemetry.py",
-    "test_docs_paths.py", "test_elastic.py",
-    "test_label_scheduling.py",
+    "test_docs_paths.py", "test_elastic.py", "test_engine_mixed_state.py",
+    "test_kda.py", "test_label_scheduling.py", "test_ling3.py",
     "test_mpmd.py",
     "test_native_sched.py", "test_native_store.py", "test_ops.py",
     "test_parallel.py", "test_partition.py", "test_podracer.py",

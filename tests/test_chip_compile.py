@@ -691,3 +691,88 @@ def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
                                  text).group(0)
     assert "ragged-dot" in text
     print(key, "total", total, "temp", m.temp_size_in_bytes)
+
+
+# -- the fifth served model at its published widths: Ling-3.0-flash's share of
+# -- benchmarks/configs/ling-3.0-flash-l7-e128.json ---------------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512)],
+                         ids=["step", "prefill512"])
+def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
+    """The mixed-cache serve programs (six KDA layers: the step's kernel
+    over a state arena, the chunked WY form; one latent-attention layer
+    over pages; 128 held experts) at the configuration's widths with an
+    engine sized HERE — 64 slots of 18,432 positions — and not by the
+    benchmark's `engine_kwargs`: the chip's compiler takes them; weights +
+    latent pages + states + tails + temporaries stay under 15.75 GiB; the
+    cache is donated and held once, the state arena moved by no copy."""
+    import json
+
+    from benchmarks.lib.ling3cfg import model_config
+    from ray_tpu.models import ling3 as lm
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ling-3.0-flash-l7-e128.json")) as f:
+        conf = json.load(f)
+    cfg = model_config(conf, kda_impl="pallas")
+    view = _on(jax.eval_shape(
+        lambda k: lm.serve_view(lm.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one_chip)
+    eng = ContinuousEngine(lm, cfg, view, max_slots=64, page_size=128,
+                           max_total=18432,
+                           num_pages={"full": 9217, "kda": 65},
+                           prefill_bucket=512, prefill_chunk=512)
+    try:
+        cache = _on(jax.eval_shape(functools.partial(
+            lm.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B, V = eng.max_slots, cfg.vocab_size
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (view, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32),
+                    {k: s((B, w), jnp.int32)
+                     for k, w in eng._widths.items()}, s((B,), jnp.int32))
+        else:
+            args = (view, cache, s((key[1],), jnp.int32),
+                    {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
+                    i32, i32)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    assert (eng._widths, eng._share, eng._main, eng._state_kinds) == (
+        {"full": 144, "kda": 1}, False, "full", ["kda"])
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(view))
+    assert cache["latent"][0].shape == (9217, 576, 128)
+    assert cache["state"].shape == (6, 65, 32, 128, 128)
+    assert cache["tail"].shape == (6, 65, 3, 96, 128)
+    assert arena == (9217 * 128 * 1152 + 6 * 65 * (32 * 128 * 128 * 4
+                                                   + 3 * 96 * 128 * 2))
+    assert 10.46e9 < weights < 10.50e9
+    assert m.alias_size_in_bytes >= arena
+    assert total < 15.75 * 1024 ** 3, total
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"= f32\[6,65,32,128,128\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)
+             or re.search(r"= bf16\[9217,576,128\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)]
+    assert not moved, moved
+    assert "ragged-dot" in text
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    if key == "step":       # one kernel a KDA layer, under its name
+        assert sum("kda_step" in c.split(" = ", 1)[0] for c in calls) == 6
+    else:                   # the chunk: the latent layer's block kernel
+        assert _streamed_kernels(compiled) == 1
+    print(key, "total", total, "temp", m.temp_size_in_bytes)
