@@ -33,7 +33,10 @@ Attention reads it in two forms, by the rows a program carries
     (q_lat_h = q_nope_h W_UK_h^T, kv_rank wide) and its value half into
     the output (o_h = (sum_j p_j c_kv[j]) W_UV_h), so the H heads score
     ONE shared key — the latent row as it lies, 576 wide — and nothing a
-    head wide is formed per key;
+    head wide is formed per key.  On a TPU a step (one row a slot) walks
+    each live slot's own pages with `ops/attention.latent_decode_attention`
+    and touches no other; anywhere else, and for a short chunk, blocks of
+    every slot's pages go through `streamed_attention`'s XLA body;
   * expanded (a prefill chunk): each block of cached latents goes through
     `Wkvb` once for all the chunk's queries (the published form; cheaper
     from about 170 query rows a program on, PERF.md section 6).
@@ -57,7 +60,9 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import (streamed_attention,
+from ray_tpu.ops.attention import (latent_decode_attention,
+                                   latent_decode_uses_kernel,
+                                   latent_walked_keys, streamed_attention,
                                    streamed_attention_uses_kernel)
 from ray_tpu.ops.layers import (apply_rope_interleaved, rms_norm, swiglu,
                                 yarn_frequencies)
@@ -75,9 +80,11 @@ __all__ = ["DeepSeekV3Config", "init", "apply", "cache_kinds",
 # experts, the largest load of a held expert, held experts touched (as
 # cohere2_moe); (query, visible key) pairs of one head, and keys visible
 # to the program — a step's live rows see their own contexts, a chunk's
-# rows one context
+# rows one context; key positions its attention FETCHED (`mla_keys` is what
+# it needed: the block loop fetches every row of the batch every block up
+# to the longest context, the decode kernel each live slot's own pages)
 STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "mla_pairs",
-              "mla_keys")
+              "mla_keys", "mla_walked_keys")
 
 # whether a prefill program of `rows` rows attends through the Pallas
 # block kernel: the predicate streamed_attention itself picks by, for the
@@ -300,7 +307,11 @@ def _attend(q_nope, q_pe, qpos, fetch, n_blocks, layer, absorbed: bool,
             cfg: DeepSeekV3Config):
     """Heads' outputs [B, H, T, dv] of queries at positions qpos [B, T]
     against cached latents: `fetch(i)` -> (block i's rows [B, S, rkv + dr],
-    their positions [B, S], negative where there is none)."""
+    their positions [B, S], negative where there is none).  Where the
+    latents lie in pages, `fetch.pages()` -> (arena, table [B, R], the
+    keys each slot sees [B]), and an absorbed step on a TPU (one row a
+    slot: `latent_decode_uses_kernel`) walks them with the decode kernel
+    and fetches no block."""
     B, H, T, _ = q_nope.shape
     rkv = cfg.kv_rank
     w_uk, w_uv = _kv_up(layer, cfg)
@@ -308,6 +319,11 @@ def _attend(q_nope, q_pe, qpos, fetch, n_blocks, layer, absorbed: bool,
         with jax.named_scope("mla_attend_step"):
             q_lat = jnp.einsum("bhtn,hnr->bhtr", q_nope, w_uk)
             q = jnp.concatenate([q_lat.astype(q_pe.dtype), q_pe], axis=-1)
+            if hasattr(fetch, "pages") and latent_decode_uses_kernel(T):
+                o = latent_decode_attention(
+                    q[:, :, 0], *fetch.pages(), scale=cfg.softmax_scale,
+                    v_dim=rkv)[:, :, None]
+                return jnp.einsum("bhtr,hrv->bhtv", o, w_uv)
 
             def one_key(i):                 # the latent as it lies
                 rows, kpos = fetch(i)
@@ -388,11 +404,14 @@ def _logits(params, x, cfg: DeepSeekV3Config):
                           preferred_element_type=jnp.float32)
 
 
-def _stats(loads: List[jax.Array], pos, real, cfg: DeepSeekV3Config):
+def _stats(loads: List[jax.Array], pos, real, walked,
+           cfg: DeepSeekV3Config):
     """The STEP_STATS vector of one program: `loads` of its expert layers,
-    its rows' positions and which of them are real."""
+    its rows' positions and which of them are real, the key positions its
+    layers fetched."""
     seen = jnp.where(real, pos + 1, 0).astype(jnp.float32)     # [B, T]
-    mla = [seen.sum() * cfg.n_layers, seen.max(axis=1).sum() * cfg.n_layers]
+    mla = [seen.sum() * cfg.n_layers, seen.max(axis=1).sum() * cfg.n_layers,
+           jnp.asarray(walked, jnp.float32)]
     if not loads:
         return jnp.stack([jnp.zeros(())] * 3 + mla)
     ld = jnp.stack(loads).astype(jnp.float32)                  # [L_moe, held]
@@ -459,9 +478,11 @@ def _page_io(ptab, pos, real, d: int, ps: int, cfg):
     row's are pos[b, 0] + t; `real` [B, T] marks the rows whose latent is
     kept) meet the pages of ptab [B, R] whose positions are `d` values by
     `ps` lanes: `bind(arena)` -> (`_block`'s write and fetch over that
-    layer's arena, the box whose "arena" the write leaves), beside the
-    number of key blocks the live contexts reach (models/ling3.py's
-    latent-attention layers take their pages through this too).
+    layer's arena, the box whose "arena" the write leaves and whose
+    "walked" says how many key positions the layer's attention fetched,
+    through `fetch` or through `fetch.pages`), beside the number of key
+    blocks the live contexts reach (models/ling3.py's latent-attention
+    layers take their pages through this too).
 
     A layer's rows are written a whole page at a time: the pages the rows
     fall in are read, the rows laid over them, the pages written back.  A
@@ -487,6 +508,10 @@ def _page_io(ptab, pos, real, d: int, ps: int, cfg):
     last = jnp.max(jnp.where(real, pos, 0))
     n_blocks = jnp.minimum(last // (npb * ps) + 1, width // npb)
     block_pos = jnp.arange(npb * ps, dtype=jnp.int32)
+    # the keys a slot's one row sees (0: an empty slot); the key positions
+    # the block loop fetches (formed here: `fetch` is traced inside it)
+    ctx = jnp.where(real[:, 0], first + 1, 0) if T == 1 else None
+    by_block = B * npb * ps * n_blocks
 
     def bind(arena):
         box = {}
@@ -499,12 +524,19 @@ def _page_io(ptab, pos, real, d: int, ps: int, cfg):
                 jnp.swapaxes(new.reshape(B * n_pg, ps, d), 1, 2))
 
         def fetch(i):
+            box["walked"] = by_block
             t = jax.lax.dynamic_slice_in_dim(tabp, i * npb, npb, 1)
             rows = jnp.swapaxes(box["arena"][t], 2, 3).reshape(
                 B, npb * ps, d)
             kpos = jnp.broadcast_to(i * npb * ps + block_pos, (B, npb * ps))
             return rows, kpos
 
+        def in_place():             # a step's keys, where they lie
+            box["walked"] = latent_walked_keys(ctx, ps)
+            return box["arena"], ptab, ctx
+
+        if T == 1:
+            fetch.pages = in_place
         return write, fetch, box
 
     return bind, n_blocks
@@ -524,15 +556,16 @@ def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
     d, ps = cache[0].shape[1:]
     bind, n_blocks = _page_io(ptab, pos, real, d, ps, cfg)
     x = _slot_embed(params, toks, pos, cfg)
-    new_cache, loads = [], []
+    new_cache, loads, walked = [], [], 0
     for layer, arena in zip(params["layers"], cache):
         write, fetch, box = bind(arena)
         x, ld = _block(x, layer, pos, write, fetch, n_blocks, absorbed, cfg,
                        live=real)
         new_cache.append(box["arena"])
+        walked += box["walked"]
         if ld is not None:
             loads.append(ld)
-    return x, new_cache, _stats(loads, pos, real, cfg)
+    return x, new_cache, _stats(loads, pos, real, walked, cfg)
 
 
 def paged_decode_step(params, cache, tokens, ptabs, pos, cfg, absorbed=None):

@@ -1,6 +1,6 @@
 from .attention import (attention, blockwise_attention, flash_attention,
-                        flash_attention_with_lse, mha_reference,
-                        streamed_attention)
+                        flash_attention_with_lse, latent_decode_attention,
+                        mha_reference, streamed_attention)
 from .layers import (apply_rope, apply_rope_halves, apply_rope_interleaved,
                      fused_softmax_cross_entropy, gelu_mlp,
                      layer_norm, rms_norm, rope_table,
@@ -16,6 +16,7 @@ __all__ = [
     "quantize_blockwise", "dequantize_blockwise", "quantization_error",
     "attention", "flash_attention", "flash_attention_with_lse",
     "blockwise_attention", "mha_reference", "streamed_attention",
+    "latent_decode_attention",
     "ring_attention", "ring_attention_sharded",
     "ulysses_attention", "ulysses_attention_sharded",
     "retention_chunk", "retention_step", "kda_chunk", "kda_step",

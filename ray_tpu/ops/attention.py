@@ -1067,9 +1067,12 @@ def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
     scores [heads, T, S] through memory between its two products (134 MB
     a block of a 512-row chunk of 128 heads: 40% of the chip's time in
     `serve-commandaplus-mixedctx` and 55% in `serve-deepseekv3-longctx`
-    before, PERF.md section 6, PR 46).  A decode step (T = 1, slots in
-    B: DeepSeek's absorbed form too) and every other platform take the
-    XLA body: a row a head gives a kernel nothing to keep on the chip.
+    before, PERF.md section 6, PR 46).  A decode step that comes here
+    (T = 1, slots in B) and every other platform take the XLA body: a
+    row a head gives THIS kernel nothing to keep on the chip.  Not every
+    decode step comes here: DeepSeek's absorbed step over latent pages
+    walks them with `latent_decode_attention` (below) on a TPU and
+    fetches no block at all.
     The mathematics and the precision are the same: products on operands
     as they come, f32 scores, statistics and accumulator, p cast to the
     values' dtype."""
@@ -1082,3 +1085,171 @@ def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
         return _streamed_kernel_loop(q, qpos, fetch, n_blocks, window, scale,
                                      v_dim)
     return _streamed_xla(q, qpos, fetch, n_blocks, window, scale, v_dim)
+
+
+# ---------------------------------------------------------------------------
+# latent decode attention: the absorbed decode step's attention over latent
+# pages, a walk of each live slot's own pages and of nothing else
+# ---------------------------------------------------------------------------
+
+# pages of one compute block of the walk: one online-softmax update, and
+# one double-buffered fetch, for every so many pages (4 pages of 128
+# positions are the XLA body's block of 512 keys)
+_WALK_PAGES = 4
+
+
+def latent_decode_uses_kernel(rows: int, platform: Optional[str] = None
+                              ) -> bool:
+    """Whether an absorbed call over latent pages whose slots bring `rows`
+    query rows each walks the pages with `latent_decode_attention`: on a
+    TPU, for one row a slot (the decode step).  Rows and platform decide,
+    nothing else; `platform` None means the backend this process computes
+    on."""
+    if platform is None:
+        platform = jax.default_backend()
+    return platform == "tpu" and rows == 1
+
+
+def latent_walked_keys(ctx, page_size: int):
+    """Key positions `latent_decode_attention` fetches for contexts ctx
+    [B]: every page a context reaches, whole."""
+    return jnp.sum(-(-ctx // page_size)) * page_size
+
+
+def _latent_decode_kernel(tab_ref, ctx_ref, scale_ref, q_ref, arena_ref,
+                          o_ref, buf, sem, acc_ref, *, v_dim: int,
+                          width: int):
+    """One slot's walk.  A page [d, ps] IS its keys transposed: a block
+    of pages lies side by side along the lanes of `buf` [2, d, S], scores
+    are q [H, d] . block [d, S] with no transposing copy, and the weighted
+    sum contracts p [H, S] with the block's first `v_dim` rows over S.
+    `width`: entries of a slot's row of the (flattened) table."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    _, d, S = buf.shape
+    ps = arena_ref.shape[-1]
+    npb = S // ps
+    ctx = ctx_ref[b]
+    n_pages = (ctx + ps - 1) // ps
+    n_blocks = (n_pages + npb - 1) // npb
+
+    # what a DMA has not filled must still be finite: a key past the
+    # context is hidden from the scores, and its p = 0 meets its value
+    @pl.when(b == 0)
+    def _():
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+    def pages(blk, half, act):
+        """`act` (start, or wait for) the copy of each page of block
+        `blk` that the context reaches into half `half` of the buffer."""
+        for j in range(npb):
+            at = blk * npb + j
+
+            @pl.when(at < n_pages)
+            def _():
+                act(pltpu.make_async_copy(
+                    arena_ref.at[tab_ref[b * width + at]],
+                    buf.at[half, :, pl.ds(j * ps, ps)], sem.at[half]))
+
+    @pl.when(ctx == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(ctx > 0)
+    def _():
+        q = q_ref[0]                                          # [H, d]
+        H = q.shape[0]
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        pages(0, 0, lambda cp: cp.start())
+
+        def block(blk, carry):
+            m, l = carry                                      # [H, 1]
+            half = blk % 2
+
+            @pl.when(blk + 1 < n_blocks)
+            def _():
+                pages(blk + 1, 1 - half, lambda cp: cp.start())
+
+            pages(blk, half, lambda cp: cp.wait())
+            kv = buf[half]                                    # [d, S]
+            s = _dot(q, kv, _NN) * scale_ref[0]               # [H, S] f32
+            kpos = blk * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+            s = jnp.where(kpos < ctx, s, DEFAULT_MASK_VALUE)
+            # a block's first key is inside the context, so m_new is a
+            # real score and a hidden key's p is exp(-huge) = 0
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            acc_ref[...] = acc_ref[...] * corr + _dot(
+                p.astype(kv.dtype), kv[:v_dim], _NT)
+            return m_new, l * corr + jnp.sum(p, axis=1, keepdims=True)
+
+        _, l = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (jnp.full((H, 1), DEFAULT_MASK_VALUE, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32)))
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("v_dim", "interpret"))
+def _latent_decode(scale, q, arena, ptab, ctx, v_dim, interpret=False):
+    """The kernel's one lowered function, the scale an operand: every
+    latent layer of a step program calls this one (see `_streamed_block`
+    for what a lowering a layer costs in warm set-up)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, d = q.shape
+    ps = arena.shape[-1]
+    at_slot = lambda b, *_: (b, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, H, d), at_slot),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, v_dim), at_slot),
+        scratch_shapes=[pltpu.VMEM((2, d, _WALK_PAGES * ps), arena.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((H, v_dim), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, v_dim=v_dim,
+                          width=ptab.shape[1]),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="latent_decode_attention",
+    )(ptab.reshape(-1), ctx, scale, q, arena)
+
+
+def latent_decode_attention(q, arena, ptab, ctx, *, scale: float,
+                            v_dim: int, interpret: bool = False):
+    """The absorbed decode step's attention, as work in proportion to the
+    live slots' own contexts: slot b's query heads q[b] [H, d] against
+    the first ctx[b] positions of its own pages — page ptab[b, i] of
+    `arena` [pages, d, page_size] holds positions i * page_size onward,
+    one position a column (`deepseek_v3.init_paged_cache`), keys d wide
+    whose first `v_dim` values are the values too.  Returns [B, H, v_dim]
+    in q's dtype.
+
+    One Pallas kernel, a grid turn a slot.  A slot with ctx 0 (an empty
+    one) fetches nothing and comes out zero.  A live slot walks pages
+    0 .. (ctx - 1) // page_size of its own table row and stops: the
+    kernel's own double-buffered DMA copies them, `_WALK_PAGES` a compute
+    block, from where they lie in HBM (the arena is never gathered, and
+    what lies past a context's last page — the table's other entries,
+    the null page — is not read); an online softmax across the blocks
+    keeps (m, l, acc) on the chip for the whole walk, the last block
+    masked by the context.  The mathematics and the precision are
+    `_streamed_xla`'s: operands as they come, f32 scores, statistics and
+    accumulator, p cast to the values' dtype for the second product.
+
+    What it replaces: the XLA body gives every slot of the batch, empty
+    or not, every block up to the LONGEST live context, each block
+    gathered and transposed first (26% of the chip's time in
+    `serve-deepseekv3-longctx`, PERF.md section 6, PR 48)."""
+    return _latent_decode(jnp.full((1,), scale, jnp.float32), q, arena,
+                          ptab.astype(jnp.int32), ctx.astype(jnp.int32),
+                          v_dim=v_dim, interpret=interpret)

@@ -537,7 +537,9 @@ class ContinuousEngine:
                         "prefill_tokens": 0, "prefill_scanned_tokens": 0,
                         # garbage collections of this process while the
                         # engine thread lived (_on_gc alone writes them)
-                        "gc_s": 0.0, "gc_collections": 0, "gc_max_s": 0.0}
+                        "gc_s": 0.0, "gc_collections": 0, "gc_max_s": 0.0,
+                        # the model's own counters (its STEP_STATS names)
+                        **dict.fromkeys(self._stat_keys, 0.0)}
         self._gc_t0: Optional[float] = None   # a collection under way
         self._iter = 0               # iterations since the engine started
         self._t_call = 0.0           # the last launch was entered
@@ -992,7 +994,7 @@ class ContinuousEngine:
                 self._ring.append(rec)
                 for k in ("prefill_s", "decode_s", "host_s",
                           "device_wait_s", "dispatch_s", "ready_wait_s",
-                          "launches"):
+                          "launches") + self._stat_keys:
                     tot[k] += rec[k]
                 tot["blocked_slot_s"] += rec["swap_s"] * rec["blocked_slots"]
                 for r in rec["requests"]:

@@ -77,6 +77,24 @@ def _streamed_kernels(compiled) -> int:
                           compiled.as_text()))
 
 
+def _latent_kernels(compiled) -> int:
+    """... that run the decode step's walk over latent pages
+    (`latent_decode_attention`, PR 48)."""
+    return len(re.findall(r"%latent_decode_attention[.\d]* = \S+ custom-call\(",
+                          compiled.as_text()))
+
+
+def _gathered_blocks(compiled, slots: int):
+    """Instructions of a step program's attention that gather, copy or
+    transpose a block of cached latents for every slot ([slots, 4 pages,
+    576, 128] or [slots, 512, 576], as the XLA body's `fetch` makes
+    them)."""
+    return [ln.strip()[:120] for ln in compiled.as_text().splitlines()
+            if "mla_attend_step" in ln and re.search(
+                r"= bf16\[%d,(4,576,128|512,576)\]\S* (gather|copy|transpose)\("
+                % slots, ln)]
+
+
 # -- flash attention ---------------------------------------------------------
 
 
@@ -522,6 +540,7 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     # a chunk's four layers attend through ONE lowered block kernel (the
     # compiler inlines it a layer); a step's rows take the XLA body
     assert _streamed_kernels(compiled) == (0 if key == "step" else 4)
+    assert _latent_kernels(compiled) == 0
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
@@ -663,6 +682,11 @@ def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     finally:
         eng.stop()
     assert _streamed_kernels(compiled) == (0 if key == "step" else 5)
+    # the step's five latent layers walk their pages through ONE lowered
+    # kernel (inlined a layer), and no block of every slot's latents is
+    # gathered, copied or transposed for them
+    assert _latent_kernels(compiled) == (5 if key == "step" else 0)
+    assert not _gathered_blocks(compiled, B)
     assert (B, eng._pool_pages, eng.max_total, eng._widths, eng._share) == (
         32, {"full": 4353}, 17408, {"full": 136}, True)
     m = compiled.memory_analysis()
@@ -771,8 +795,13 @@ def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     assert "ragged-dot" in text
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    if key == "step":       # one kernel a KDA layer, under its name
+    if key == "step":       # one kernel a KDA layer, under its name, and
+        #                     the latent layer's walk over its pages
         assert sum("kda_step" in c.split(" = ", 1)[0] for c in calls) == 6
+        assert (_latent_kernels(compiled), _streamed_kernels(compiled)) == (
+            1, 0)
+        assert not _gathered_blocks(compiled, B)
     else:                   # the chunk: the latent layer's block kernel
-        assert _streamed_kernels(compiled) == 1
+        assert (_latent_kernels(compiled), _streamed_kernels(compiled)) == (
+            0, 1)
     print(key, "total", total, "temp", m.temp_size_in_bytes)

@@ -13,6 +13,7 @@ to a loop-written one here too: `tests/test_moe.py` is not in the quick
 tier."""
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -132,21 +133,45 @@ def test_reference_in_blocks_changes_nothing(model, drawn, heads, rows):
         np.testing.assert_array_equal(part[16:], x[16:])
 
 
-@pytest.mark.parametrize("forms", [(False, True), (False, False), (None, None)],
-                         ids=["as-served", "all-expanded", "all-absorbed"])
-def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms):
+def _walk_pages_as_on_the_chip(monkeypatch):
+    """The absorbed step takes `latent_decode_attention` as it does on a
+    TPU — the kernel interpreted here: the predicate and the kernel are
+    the names deepseek_v3 calls them by."""
+    monkeypatch.setattr(dm, "latent_decode_uses_kernel",
+                        lambda rows, platform=None: rows == 1)
+    monkeypatch.setattr(dm, "latent_decode_attention", functools.partial(
+        dm.latent_decode_attention, interpret=True))
+
+
+@pytest.mark.parametrize("forms", [(False, True), (False, False), (None, None),
+                                   (False, "walked")],
+                         ids=["as-served", "all-expanded", "all-absorbed",
+                              "as-served-on-the-chip"])
+def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms,
+                                                          monkeypatch):
     """A prompt in chunks of 16, 13 (+3 pad rows) and 3 (+1), then eight
     decode steps beside an empty slot, every row's logits against the
     reference's full forward.  As served at the real sizes the chunks
     expand the latents and the steps absorb: the two 16-row chunks are
     told to expand here (at most `ABSORB_ROWS` = 128 rows would absorb);
     the other two cases run every program through one form — the last as
-    the rows decide at this size."""
+    the rows decide at this size; the fourth is the first with the step's
+    attention the kernel that walks the pages (what a TPU takes).  A
+    program's last counter is the key positions it fetched: every row of
+    the batch every block of 16 to the longest context, or, walked, the
+    live slot's own pages."""
     cfg, params = model
     long_form, short_form = forms
+    walked = short_form == "walked"
+    if walked:
+        _walk_pages_as_on_the_chip(monkeypatch)
+        short_form = True
     assert dm.ABSORB_ROWS == 128
     prefill = jax.jit(dm.paged_prefill, static_argnames=("cfg", "absorbed"))
-    step = jax.jit(dm.paged_decode_step, static_argnames=("cfg", "absorbed"))
+    # a program of this case's own: what the step calls is looked up when
+    # it is traced
+    step = jax.jit(functools.partial(dm.paged_decode_step),
+                   static_argnames=("cfg", "absorbed"))
     view = dm.serve_view(params, cfg)
     assert "wkv_b" not in view["layers"][0]
     seq = _tokens(40)
@@ -167,6 +192,7 @@ def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms):
         np.testing.assert_allclose(row, want[start - 1], atol=TOL, rtol=0)
         pairs = sum(range(start - n + 1, start + 1)) * cfg.n_layers
         assert (stats[3], stats[4]) == (pairs, start * cfg.n_layers)
+        assert stats[5] == -(-start // 16) * 16 * cfg.n_layers
     ptab = np.zeros((2, maxp), np.int32)
     ptab[1] = tab
     for i in range(start, 40):
@@ -175,6 +201,8 @@ def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms):
             jnp.asarray([0, i], jnp.int32), cfg=cfg, absorbed=short_form)
         np.testing.assert_allclose(lg[1], want[i], atol=TOL, rtol=0)
         assert stats[3] == stats[4] == (i + 1) * cfg.n_layers
+        assert stats[5] == cfg.n_layers * (
+            (i // PS + 1) * PS if walked else 2 * (i // 16 + 1) * 16)
     # the null page is as it was made: pad rows and the empty slot wrote
     # nothing anywhere
     assert not any(np.asarray(a[0]).any() for a in cache)
@@ -185,6 +213,56 @@ def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms):
                              _sizes(cfg))
     np.testing.assert_allclose(cache[0][3].T,
                                jnp.concatenate([c_kv, k_pe], -1), atol=1e-5)
+
+
+@pytest.mark.parametrize("walked", [False, True],
+                         ids=["block-loop", "walked-pages"])
+def test_the_ring_counts_the_keys_a_step_fetched(model, walked, monkeypatch):
+    """Two live slots of 8 through a `ContinuousEngine`: a step's ring
+    record carries `mla_walked_keys` beside `mla_keys` — on the XLA path
+    slots x blocks to the longest context x block size a layer, on the
+    kernel's at most a page a live slot a layer past what was needed —
+    `engine_stats()` sums both, and both paths serve the same tokens."""
+    import threading
+
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    if walked:
+        _walk_pages_as_on_the_chip(monkeypatch)
+    cfg, params = model
+    eng = ContinuousEngine(dm, cfg, params, max_slots=8, page_size=PS,
+                           max_total=96, prefill_bucket=4, prefill_chunk=16)
+    t = threading.Thread(target=lambda: None)
+    t.start()
+    t.join()
+    eng._thread = t                 # the test drives the iterations
+    seqs = [eng.submit(_tokens(n, seed=n).tolist(), 24) for n in (37, 6)]
+    for _ in range(200):
+        eng._iteration()
+        if all(s.result.done() for s in seqs):
+            break
+    steps = [r for r in eng.phase_ring() if r["active"] == 2]
+    assert len(steps) >= 20
+    L, block = cfg.n_layers, cfg.kv_block
+    for r in steps:
+        if walked:
+            assert r["mla_keys"] <= r["mla_walked_keys"] < (
+                r["mla_keys"] + 2 * PS * L)
+            assert r["mla_walked_keys"] % (PS * L) == 0
+        else:
+            assert r["mla_walked_keys"] % (8 * block * L) == 0
+            assert r["mla_walked_keys"] >= 8 * block * L > r["mla_keys"] / 4
+    stats = eng.engine_stats()
+    assert stats["mla_walked_keys"] >= sum(r["mla_walked_keys"]
+                                           for r in steps)
+    assert 0 < stats["mla_keys"] <= stats["mla_walked_keys"]
+    assert stats["chunk_mla_walked_keys"] >= stats["chunk_mla_keys"] > 0
+    eng.stop()
+    served = tuple(tuple(s.result.result()["completion"]) for s in seqs)
+    assert _SERVED.setdefault("tokens", served) == served
+
+
+_SERVED = {}
 
 
 def test_expert_shares_add_up_to_the_uncut_layer(model):

@@ -6,6 +6,7 @@ is lowered for the platform "tpu" from the CPU (Pallas lowers to Mosaic in
 Python), with `jax.default_backend` patched where the predicate asks it.
 `tests/test_chip_compile.py` compiles the kernel for a described v5e."""
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -259,3 +260,227 @@ def test_a_chunk_launch_is_stamped_with_the_predicate(monkeypatch):
     assert recs and all("chunk_attn_kernel" not in r for r in recs)
     assert "chunk_attn_kernel_share" not in geng.engine_stats()
     geng.stop()
+
+
+# -- latent_decode_attention: the absorbed decode step's walk over each
+# -- live slot's own latent pages (PR 48) ------------------------------------
+
+PS, WIDTH, PAGES = 8, 12, 64        # positions a page, a table row, the arena
+FULL = WIDTH * PS
+
+
+def _latent_operands(B, dtype, seed=0):
+    """q [B, H, d], an arena [PAGES, d, PS] whose null page is zeros, and
+    a table of distinct pages a slot (slot b + 5 has slot b's)."""
+    H, d = 8, 20
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, H, d), dtype)
+    arena = jax.random.normal(ks[1], (PAGES, d, PS), dtype).at[0].set(0)
+    ptab = 1 + jax.random.permutation(ks[2], PAGES - 1)[:WIDTH * min(B, 5)]
+    ptab = jnp.tile(ptab.reshape(-1, WIDTH), (-(-B // 5), 1))[:B]
+    return q, arena, ptab.astype(jnp.int32)
+
+
+def _latent_xla(q, arena, ptab, ctx, scale, v_dim):
+    """The XLA body on the same operands, its blocks gathered as
+    `deepseek_v3._page_io` gathers them, every slot to the longest
+    context."""
+    B, H, d = q.shape
+    npb = A._WALK_PAGES
+    tabp = jnp.pad(ptab, ((0, 0), (0, -ptab.shape[1] % npb)))
+
+    def fetch(i):
+        t = jax.lax.dynamic_slice_in_dim(tabp, i * npb, npb, 1)
+        rows = jnp.swapaxes(arena[t], 2, 3).reshape(B, npb * PS, d)
+        kpos = jnp.broadcast_to(i * npb * PS + jnp.arange(npb * PS), (B, npb * PS))
+        return rows[:, None], rows[:, None, :, :v_dim], kpos
+
+    n_blocks = -(-jnp.max(ctx) // (npb * PS))
+    o = A._streamed_xla(q[:, None, :, None], ctx[:, None] - 1, fetch,
+                        n_blocks, None, scale, v_dim)
+    return o[:, 0, :, 0]
+
+
+# name: (slots, their contexts (a dict: the others are empty), dtype)
+LATENT_CASES = {
+    "every-slot-empty": (4, {}, jnp.float32),
+    "one-live-slot-of-32": (32, {17: 37}, jnp.float32),
+    "one-key": (3, {1: 1}, jnp.float32),
+    "ends-mid-page": (3, {0: 3 * PS + 5}, jnp.float32),
+    "ends-on-a-pages-last-position": (3, {2: 4 * PS}, jnp.float32),
+    "ends-on-a-pages-first-position": (3, {1: 4 * PS + 1}, jnp.float32),
+    "the-tables-full-width": (2, {0: FULL, 1: FULL - 1}, jnp.float32),
+    "shared-prefix-pages": (4, {0: 41, 2: 30, 3: 17}, jnp.float32),
+    "very-different-lengths": (8, {0: 1, 1: FULL, 3: 9, 4: 50, 6: 33, 7: 2},
+                               jnp.float32),
+    "very-different-lengths-bf16": (8, {0: 1, 1: FULL, 3: 9, 4: 50, 6: 33,
+                                        7: 2}, jnp.bfloat16),
+    "garbage-past-the-contexts": (5, {0: 13, 2: FULL - 3, 3: 32, 4: 1},
+                                  jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_CASES))
+def test_latent_decode_kernel_matches_the_xla_body(name):
+    B, live, dtype = LATENT_CASES[name]
+    q, arena, ptab = _latent_operands(B, dtype)
+    ctx = jnp.zeros(B, jnp.int32).at[jnp.asarray(list(live), jnp.int32)].set(
+        jnp.asarray(list(live.values()), jnp.int32))
+    v_dim, scale = 16, 0.3
+    if name == "shared-prefix-pages":
+        # copy-on-write prefixes: slots 2 and 3 begin with slot 0's pages
+        ptab = ptab.at[2, :3].set(ptab[0, :3]).at[3, :2].set(ptab[0, :2])
+    dirty = arena
+    if name == "garbage-past-the-contexts":
+        # what a walk must not reach: NaN in the null page and in every
+        # page past a context's last (the table still names them), and —
+        # finite, for the XLA body multiplies it by p = 0 too — a large
+        # value in the last page's positions past the context
+        last = -(-ctx // PS)                                    # [B]
+        entry = jnp.arange(WIDTH)[None]
+        arena = arena.at[jnp.where(entry >= last[:, None], ptab, 0)].set(0)
+        tail = (entry[..., None] * PS + jnp.arange(PS) >= ctx[:, None, None]
+                ) & (entry < last[:, None])[..., None]          # [B, W, PS]
+        big = jnp.where(tail[:, :, None], 1e4, arena[ptab])
+        arena = arena.at[jnp.where(entry < last[:, None], ptab, 0)].set(
+            jnp.where((entry < last[:, None])[..., None, None], big, 0))
+        dirty = arena.at[jnp.where(entry >= last[:, None], ptab, 0)].set(
+            jnp.nan)
+        assert bool(jnp.isnan(dirty[0]).all()) and float(arena.max()) == 1e4
+    got = A.latent_decode_attention(q, dirty, ptab, ctx, scale=scale,
+                                    v_dim=v_dim, interpret=True)
+    want = _latent_xla(q, arena, ptab, ctx, scale, v_dim)
+    assert got.shape == want.shape == (B, 8, v_dim)
+    assert got.dtype == want.dtype == dtype
+    want, got = (np.asarray(a, np.float32) for a in (want, got))
+    # the tolerance of test_kernel_matches_the_xla_body
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 if dtype == jnp.float32 else 1e-2)
+    empty = np.asarray(ctx) == 0
+    assert np.all(got[empty] == 0.0)
+    assert np.all(np.any(got[~empty] != 0.0, axis=-1))
+    assert int(A.latent_walked_keys(ctx, PS)) == sum(
+        -(-c // PS) * PS for c in live.values())
+
+
+def test_latent_decode_predicate_is_rows_and_platform():
+    uses = A.latent_decode_uses_kernel
+    assert jax.default_backend() == "cpu"
+    assert not uses(1) and not uses(1, "cpu") and not uses(1, "gpu")
+    assert uses(1, "tpu")
+    assert not uses(2, "tpu") and not uses(128, "tpu")
+    # a chunk's rows never take it, a step's rows never the block kernel
+    assert not any(uses(r, "tpu") and A.streamed_attention_uses_kernel(r, "tpu")
+                   for r in (1, 2, 127, 128, 512))
+
+
+def _toy_programs(mod, cfg, rows=None, slots=4, page=16):
+    """A model's step program (and its chunk program of `rows` rows),
+    lowered for the platform "tpu" from shapes alone: tables as wide as
+    `max_seq`, a sliding kind's as its window and a chunk, a state kind's
+    one entry."""
+    params = jax.eval_shape(
+        lambda: mod.serve_view(mod.init(jax.random.PRNGKey(0), cfg), cfg))
+    kinds = mod.cache_kinds(cfg)
+    cache = jax.eval_shape(
+        lambda: mod.init_paged_cache(cfg, {k: 64 for k in kinds}, page))
+    widths = {k: 1 if w == "state" else
+              (cfg.max_seq if w is None else w + 128) // page
+              for k, w in kinds.items()}
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def lowered(fn, *args):
+        return jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    out = {"step": lowered(
+        lambda p, c, t, tabs, pos: mod.paged_decode_step(p, c, t, tabs, pos,
+                                                         cfg),
+        params, cache, ints(slots),
+        {k: ints(slots, w) for k, w in widths.items()}, ints(slots))}
+    if rows:
+        out["chunk"] = lowered(
+            lambda p, c, t, tabs, s, l: mod.paged_prefill(p, c, t, tabs, s,
+                                                          l, cfg),
+            params, cache, ints(rows), {k: ints(w) for k, w in widths.items()},
+            i32, i32)
+    return out
+
+
+def _toy(name):
+    """(module, config) of a served model at toy size, the widths its
+    kernels need on a TPU (128 positions a page's lanes)."""
+    from ray_tpu.models import brumby, cohere2_moe, deepseek_v3, gpt, ling3
+    return {
+        "gpt2": lambda: (gpt, gpt.GPTConfig.nano(max_seq=512)),
+        "command-a-plus": lambda: (cohere2_moe, cohere2_moe.Cohere2MoEConfig
+                                   .nano(max_seq=512, kv_block=128,
+                                         sliding_window=128, d_head=128)),
+        "brumby": lambda: (brumby, brumby.BrumbyConfig.nano()),
+        "deepseek-v3": lambda: (deepseek_v3, deepseek_v3.DeepSeekV3Config
+                                .nano(max_seq=512, kv_block=128)),
+        "ling-3": lambda: (ling3, ling3.Ling3Config.nano(max_seq=512,
+                                                         kv_block=128)),
+    }[name]()
+
+
+@pytest.mark.parametrize("name,latent_layers", [("deepseek-v3", 3),
+                                                ("ling-3", 1)])
+def test_a_latent_step_program_holds_the_decode_kernel_once(
+        monkeypatch, name, latent_layers):
+    """A step program's latent layers all walk their pages through ONE
+    lowered function with one Mosaic module in it, and gather no block;
+    the chunk program holds the block kernel as before and no walk; off
+    the TPU neither program holds either."""
+    mod, cfg = _toy(name)
+    on_cpu = _toy_programs(mod, cfg, rows=128)
+    assert "custom_call" not in on_cpu["step"] + on_cpu["chunk"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _toy_programs(mod, cfg, rows=128, page=128)
+    step, chunk = text["step"], text["chunk"]
+    assert step.count("call @_latent_decode") == latent_layers
+    assert step.count("func.func private @_latent_decode") == 1
+    assert step.count('kernel_name = "latent_decode_attention"') == 1
+    assert "_streamed_block" not in step
+    # the write gathers a page a slot; a block of a step's keys was
+    # [slots, 128 positions, latent] gathered under the scope
+    assert not re.findall(r"gather.*mla_attend_step", step)
+    assert "_latent_decode" not in chunk
+    assert chunk.count("call @_streamed_block") == latent_layers
+    assert chunk.count("func.func private @_streamed_block") == 1
+
+
+def _digest(text):
+    """sha256 of a lowered program's text, less what a Mosaic module is
+    serialised to (it names the source's path and the lowering's call
+    stack, which differ from one process to the next)."""
+    import hashlib
+    return hashlib.sha256(re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', "",
+                                 text).encode()).hexdigest()[:16]
+
+
+# a program's `_digest`, lowered for the platform "tpu", at the parent
+# commit of PR 48 (67e7002): the programs that call nothing PR 48 changed
+# keep their text (and, their kernels' source lines unmoved, their
+# compile-cache keys).  A PR that edits one of these programs finds the
+# new digest in the failure and pins it.
+PARENT_TEXT = {
+    ("gpt2", "step"): "1921a8ec3c8503f9",
+    ("gpt2", "chunk"): "74eee7fd9dc8f6cb",
+    ("command-a-plus", "step"): "30fc5bbb68f8c6c1",
+    ("command-a-plus", "chunk"): "f067a09b3c247b56",
+    ("brumby", "step"): "c95c739da7c26ef7",
+    ("brumby", "chunk"): "634ac703009d25eb",
+    ("ling-3", "chunk"): "85bf7fa576820a74",
+}
+
+
+@pytest.mark.parametrize("name", sorted({n for n, _ in PARENT_TEXT}))
+def test_untouched_programs_lower_to_the_parents_text(monkeypatch, name):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mod, cfg = _toy(name)
+    text = _toy_programs(mod, cfg, rows=128, page=128)
+    got = {(name, k): _digest(t) for k, t in text.items()
+           if (name, k) in PARENT_TEXT}
+    assert got == {k: v for k, v in PARENT_TEXT.items() if k[0] == name}
