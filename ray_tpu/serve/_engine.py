@@ -485,6 +485,10 @@ class ContinuousEngine:
         self._kv_read = getattr(gpt_mod, "step_kv_read", None)
         if self._kv_read is not None:
             self._stat_keys += ("kv_read", "kv_span")
+        # steps launched with a temperature in some slot, the ones whose
+        # sampler draws (the others take the argmax and nothing else):
+        # read off the host's `_temps`, no operand and no fetch
+        self._stat_keys += ("sampled_steps",)
         # a model whose chunks attend through ops.attention's
         # streamed_attention hands on the predicate that function picks
         # its body by (`chunk_attn_kernel(rows)` of its module): a chunk
@@ -1156,8 +1160,6 @@ class ContinuousEngine:
         seq.pos = plen
         seq.shared = seq.next_start = shared_len
         seq.prefilling = True
-        self._temps[slot] = seq.temperature
-        self._topks[slot] = int(seq.top_k or 0)
         self._prefill_next(seq)
 
     def _prefill_next(self, seq: _Sequence):
@@ -1222,6 +1224,9 @@ class ContinuousEngine:
         for k, tab in self._ptabs.items():
             tab[slot] = seq.tabs[k]
         self._pos[slot] = plen                  # first decode write pos
+        # (a slot still prefilling asks the step's sampler for no draw)
+        self._temps[slot] = seq.temperature
+        self._topks[slot] = int(seq.top_k or 0)
         if keys is not None:
             with ann("serve.engine.keys"):
                 _, (keys,) = self._wait("serve.engine.wait", keys)
@@ -1244,8 +1249,9 @@ class ContinuousEngine:
         """A sampling request's keys, launched and on their way to the
         host: the expression `gpt.generate` splits its keys with, one
         program of jax's a length.  A request with no temperature draws
-        none — `sample` takes the argmax wherever `temps <= 0` and reads
-        no key."""
+        none — `sample` takes the argmax wherever `temps <= 0`, and a step
+        in which no slot has a temperature reads no key at all (it runs
+        the argmax branch of the sampler's one `cond`)."""
         jax = self._jax
         with jax.profiler.TraceAnnotation("serve.engine.keys"):
             keys = self._launch(
@@ -1359,6 +1365,7 @@ class ContinuousEngine:
                                            self.max_pages_per_seq)
                 self._stats["kv_read"] += read
                 self._stats["kv_span"] += span
+            self._stats["sampled_steps"] += bool((self._temps > 0).any())
             toks, self._logits, self._cache, stats = self._launch(
                 "serve.step", self._fn("step"),
                 self._params, self._cache, self._logits, self._toks_keys,
@@ -1505,34 +1512,18 @@ class ContinuousEngine:
         from ..telemetry import device as devtel
 
         if key == "step":
-            def sample(logits, keys, temps, topks):
-                V = logits.shape[-1]
-                # mirrors gpt.sample_logits exactly, vectorized per
-                # slot: scale FIRST, then top-k truncate at -1e30 (0 =
-                # top-k off; greedy rows take the argmax branch).  The
-                # whole recipe runs in cfg.dtype even though the engine
-                # carries logits as f32: categorical draws its gumbel
-                # noise in the logits dtype, so sampling in f32 would
-                # draw different noise than generate()'s bf16 path and
-                # break seed parity
-                lg = logits.astype(cfg.dtype)
-                t = jnp.where(temps > 0, temps, 1.0).astype(cfg.dtype)
-                scaled = lg / t[:, None]
-                k_eff = jnp.where(topks > 0, topks, V)
-                srt = jnp.sort(scaled, axis=-1)
-                kth = jnp.take_along_axis(srt, (V - k_eff)[:, None],
-                                          axis=-1)
-                filt = jnp.where(scaled < kth, -1e30, scaled)
-                sampled = jax.vmap(jax.random.categorical)(keys, filt)
-                greedy = jnp.argmax(lg, axis=-1)
-                return jnp.where(temps > 0, sampled,
-                                 greedy).astype(jnp.int32)
+            from ..ops.sampling import sample
 
             # named apart from the train step: `jit_serve_step(...)` on
             # the device trace's `XLA Modules` line
             def serve_step(params, cache, logits, keys, temps, topks,
                            ptab, pos):
-                toks = sample(logits, keys, temps, topks)
+                # one token a slot at the cost the operands ask for
+                # (ops/sampling.py): where no slot has a temperature the
+                # argmax and nothing else, decided on the chip from
+                # `temps`; where one draws, gpt.sample_logits a row in
+                # cfg.dtype, its top-k threshold selected, not sorted
+                toks = sample(logits, keys, temps, topks, cfg.dtype)
                 # a model may return its own counters third (the
                 # vector its module's STEP_STATS names)
                 new_logits, cache, *stats = gpt.paged_decode_step(

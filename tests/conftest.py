@@ -77,7 +77,7 @@ _QUICK_FILES = {
     "test_parallel.py", "test_partition.py", "test_podracer.py",
     "test_remediation.py",
     "test_resource_sync.py", "test_retention_ops.py",
-    "test_runtime_env.py",
+    "test_runtime_env.py", "test_sampling.py",
     "test_serve.py", "test_serve_continuous.py", "test_serve_donation.py",
     "test_serve_fault.py",
     "test_serve_prefill.py", "test_serve_live_blocks.py",

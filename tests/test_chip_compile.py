@@ -245,20 +245,94 @@ def _assert_arena_in_place(compiled, cache):
     assert not moved, moved
 
 
+def _serve_args(serve_engine, one_chip, key):
+    """The shapes `serve_engine`'s program `key` is lowered on (a prefill
+    bucket's key is `("prefill", rows)`)."""
+    eng, cfg, params, cache = serve_engine
+    B, V, maxp = eng.max_slots, cfg.vocab_size, eng.max_pages_per_seq
+    s = lambda shape, dt: _sds(shape, dt, one_chip)
+    i32 = s((), jnp.int32)
+    if key == "step":
+        return (params, cache, s((B, V), jnp.float32), s((B, 2), jnp.uint32),
+                s((B,), jnp.float32), s((B,), jnp.int32),
+                s((B, maxp), jnp.int32), s((B,), jnp.int32))
+    if key == "setrow":
+        return (s((B, V), jnp.float32), s((V,), jnp.bfloat16), i32)
+    if key == "copy_page":
+        return (cache, i32, i32)
+    return (params, cache, s((key[1],), jnp.int32), s((maxp,), jnp.int32),
+            i32, i32)
+
+
 @pytest.fixture(scope="module")
 def serve_step_compiled(serve_engine, one_chip):
-    eng, cfg, params, cache = serve_engine
-    B = eng.max_slots
-    s = lambda shape, dt: _sds(shape, dt, one_chip)
-    return eng._fn("step").lower(
-        params, cache, s((B, cfg.vocab_size), jnp.float32),
-        s((B, 2), jnp.uint32), s((B,), jnp.float32), s((B,), jnp.int32),
-        s((B, eng.max_pages_per_seq), jnp.int32),
-        s((B,), jnp.int32)).compile()
+    return serve_engine[0]._fn("step").lower(
+        *_serve_args(serve_engine, one_chip, "step")).compile()
 
 
 def test_serve_step_compiles(serve_engine, serve_step_compiled):
     _assert_arena_in_place(serve_step_compiled, serve_engine[3])
+
+
+def _assert_sampler_asks_its_operands(compiled, slots: int, vocab: int):
+    """The step's sampler as the chip's compiler leaves it (PR 49,
+    `ops/sampling.py`): no instruction sorts the vocabulary, in either
+    branch (what sorts are left are a router's, over its experts, under
+    the model's `moe_*` scopes), and ONE `conditional`, whose predicate
+    is computed from the `temps` operand and nothing else, so a batch in
+    which nobody samples runs the branch that reads the logits alone (its
+    argmax); the other takes all four operands."""
+    text = compiled.as_text()
+    sorts = [ln.strip()[:160] for ln in text.splitlines()
+             if " sort(" in ln and (re.search(r"\[(\d+,)*%d\]" % vocab, ln)
+                                    or not re.search(r'op_name="[^"]*/moe_', ln))]
+    assert not sorts, sorts
+    conds = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert len(conds) == 1, [ln[:160] for ln in conds]
+    defs = {m.group(1): ln for ln in text.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?(%\S+) = ", ln))}
+    operands = lambda ln: re.search(
+        r" [\w\-]+\((%[\w.\-]+(?:, %[\w.\-]+)*)\)", ln).group(1).split(", ")
+    name = operands(conds[0])[0]
+    while " parameter(" not in defs[name]:      # predicate <- ... <- temps
+        (name,) = operands(defs[name])
+    assert re.search(r"= f32\[%d\]\S* parameter\(" % slots, defs[name])
+    assert 'op_name="temps"' in defs[name], defs[name][:300]
+    # the branches: one takes the logits alone, the other all four operands
+    branches = re.search(r"branch_computations=\{([^}]*)\}", conds[0]).group(1)
+    heads = [next(ln for ln in text.splitlines()
+                  if ln.startswith(b.strip() + " "))
+             for b in branches.split(",")]
+    takes = sorted(len(re.findall(r"\w+\[[\d,]*\]", h.split("->")[0]))
+                   for h in heads)
+    assert takes == [1, 4], heads
+    assert all(f"f32[{slots},{vocab}]" in h for h in heads)
+
+
+def test_serve_step_sampler_asks_its_operands(serve_engine,
+                                              serve_step_compiled):
+    eng, cfg = serve_engine[:2]
+    _assert_sampler_asks_its_operands(serve_step_compiled, eng.max_slots,
+                                      cfg.vocab_size)
+
+
+# sha256[:16] of the lowered text of the serve programs PR 49 left alone,
+# at its parent commit (bdb81ff): the sampler is the step's, and the
+# programs beside it lower to what they were
+_PARENT_LOWERED = {"prefill": "f8c2e40c8944d752", "setrow": "f6c143d1011d4ab3",
+                   "copy_page": "ca29b7a02da42e34"}
+
+
+@pytest.mark.parametrize("key", [("prefill", 32), "setrow", "copy_page"],
+                         ids=["prefill32", "setrow", "copy_page"])
+def test_serve_programs_beside_the_step_lower_to_the_parents_text(
+        serve_engine, one_chip, key):
+    import hashlib
+
+    text = serve_engine[0]._fn(key).lower(
+        *_serve_args(serve_engine, one_chip, key)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_LOWERED[key if isinstance(key, str) else key[0]]
 
 
 def _shapes(text):
@@ -300,23 +374,16 @@ def test_serve_step_reads_live_blocks_where_they_stand(serve_engine,
 
 @pytest.mark.parametrize("bucket", [32, 128])
 def test_serve_prefill_compiles(serve_engine, one_chip, bucket):
-    eng, cfg, params, cache = serve_engine
-    s = lambda shape, dt: _sds(shape, dt, one_chip)
-    compiled = eng._fn(("prefill", bucket)).lower(
-        params, cache, s((bucket,), jnp.int32),
-        s((eng.max_pages_per_seq,), jnp.int32), s((), jnp.int32),
-        s((), jnp.int32)).compile()
-    _assert_arena_in_place(compiled, cache)
+    key = ("prefill", bucket)
+    compiled = serve_engine[0]._fn(key).lower(
+        *_serve_args(serve_engine, one_chip, key)).compile()
+    _assert_arena_in_place(compiled, serve_engine[3])
 
 
 def test_serve_setrow_and_copy_page_compile(serve_engine, one_chip):
-    eng, cfg, params, cache = serve_engine
-    s = lambda shape, dt: _sds(shape, dt, one_chip)
-    eng._fn("setrow").lower(
-        s((eng.max_slots, cfg.vocab_size), jnp.float32),
-        s((cfg.vocab_size,), jnp.bfloat16), s((), jnp.int32)).compile()
-    eng._fn("copy_page").lower(cache, s((), jnp.int32),
-                               s((), jnp.int32)).compile()
+    for key in ("setrow", "copy_page"):
+        serve_engine[0]._fn(key).lower(
+            *_serve_args(serve_engine, one_chip, key)).compile()
 
 
 @pytest.mark.parametrize("key,module", [
@@ -328,20 +395,8 @@ def test_serve_programs_carry_their_names(serve_engine, one_chip, key, module):
     jitted function's name: the serve programs have names of their own
     (the train step stays `jit_step`), and the benchmark's
     `engine.decode_step_device_ms.chat` matches `^jit_serve_step`."""
-    eng, cfg, params, cache = serve_engine
-    B, V, maxp = eng.max_slots, cfg.vocab_size, eng.max_pages_per_seq
-    s = lambda shape, dt: _sds(shape, dt, one_chip)
-    i32 = s((), jnp.int32)
-    args = {
-        "step": (params, cache, s((B, V), jnp.float32), s((B, 2), jnp.uint32),
-                 s((B,), jnp.float32), s((B,), jnp.int32),
-                 s((B, maxp), jnp.int32), s((B,), jnp.int32)),
-        "prefill": (params, cache, s((32,), jnp.int32),
-                    s((maxp,), jnp.int32), i32, i32),
-        "setrow": (s((B, V), jnp.float32), s((V,), jnp.bfloat16), i32),
-        "copy_page": (cache, i32, i32),
-    }[key if isinstance(key, str) else key[0]]
-    text = eng._fn(key).lower(*args).as_text()
+    text = serve_engine[0]._fn(key).lower(
+        *_serve_args(serve_engine, one_chip, key)).as_text()
     assert f"module @{module} " in text.split("\n", 1)[0], text[:200]
 
 
@@ -553,6 +608,8 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     # the grouped products are the chip's own kernel, not a dense fallback,
     # for a chunk's rows and a step's alike
     assert "ragged-dot" in compiled.as_text()
+    if key == "step":
+        _assert_sampler_asks_its_operands(compiled, B, V)
 
 
 # -- the third served model at its published widths: a pipeline stage of
@@ -625,6 +682,7 @@ def test_brumby_serve_programs_fit_one_chip(one_chip, key):
                  if 'custom_call_target="tpu_custom_call"' in ln]
         assert len(calls) == 1
         assert "retention_step" in calls[0].split(" = ", 1)[0]
+        _assert_sampler_asks_its_operands(compiled, B, V)
     print(key, "total", total, "temp", m.temp_size_in_bytes)
 
 
@@ -714,6 +772,8 @@ def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     assert "{2,1,0" in re.search(r"bf16\[4353,576,128\]\{[^}]*\}",
                                  text).group(0)
     assert "ragged-dot" in text
+    if key == "step":
+        _assert_sampler_asks_its_operands(compiled, B, V)
     print(key, "total", total, "temp", m.temp_size_in_bytes)
 
 
@@ -798,6 +858,7 @@ def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     if key == "step":       # one kernel a KDA layer, under its name, and
         #                     the latent layer's walk over its pages
         assert sum("kda_step" in c.split(" = ", 1)[0] for c in calls) == 6
+        _assert_sampler_asks_its_operands(compiled, B, V)
         assert (_latent_kernels(compiled), _streamed_kernels(compiled)) == (
             1, 0)
         assert not _gathered_blocks(compiled, B)

@@ -594,6 +594,37 @@ def test_tokens_are_generates_with_and_without_a_newcomer(model, temperature,
         assert not eng._toks_keys.any()
 
 
+@pytest.mark.parametrize("temps, sampled_steps", [
+    ((0.0,), 0), ((0.8,), 6), ((0.0, 0.8), 6), ((0.0, 0.8, 0.0), 6)],
+    ids=["greedy", "sampled", "sampled_beside_greedy", "sampled_between"])
+def test_sampled_steps_counts_the_steps_that_draw(model, temps,
+                                                  sampled_steps):
+    """`sampled_steps`: the steps launched with a temperature in some slot
+    — the ones whose sampler takes its drawing branch — in the ring's
+    records and `engine_stats()`, read off the host's operands.  A greedy
+    request answers 24 tokens, one that samples 6: it is live for the six
+    steps that emit them, from the hand-over of its prompt to its
+    eviction, and the steps before and after it count nothing."""
+    eng = _make_engine(model)
+    try:
+        seqs = [eng.submit([3 + i, 14, 15, 9], temperature=t, seed=5 + i,
+                           top_k=12 if t else None,
+                           max_new_tokens=6 if t else 24)
+                for i, t in enumerate(temps)]
+        outs = [eng.collect(s, timeout=120)["completion"] for s in seqs]
+        stats, ring = eng.engine_stats(), eng.phase_ring()
+    finally:
+        eng.stop()
+    assert [len(o) for o in outs] == [6 if t else 24 for t in temps]
+    assert stats["sampled_steps"] == sampled_steps
+    assert stats["steps"] >= (24 if 0.0 in temps else 6)
+    assert (stats["sampled_steps"] == stats["steps"]) == (0.0 not in temps)
+    assert sum(r["sampled_steps"] for r in ring) == sampled_steps
+    assert {r["sampled_steps"] for r in ring} <= {0.0, 1.0}
+    # a record that counts a step that draws has a sampling slot decoding
+    assert all(r["active"] for r in ring if r["sampled_steps"])
+
+
 def test_a_failing_prefill_is_reported_at_the_wait_and_holds_nothing(
         model, monkeypatch):
     """A program that fails says so where its result is waited for, after
