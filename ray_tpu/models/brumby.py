@@ -47,12 +47,11 @@ from ray_tpu.ops.layers import apply_rope_halves, rms_norm, swiglu
 from ray_tpu.ops.retention import (resolve_impl, retention_chunk,
                                    retention_step, state_shape)
 
-from .gpt import sample_logits, serve_view as _cast_leaves
+from .gpt import cast_leaves
 
 __all__ = ["BrumbyConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
-           "copy_page", "sample_logits", "serve_view", "state_leaves",
-           "STEP_STATS"]
+           "serve_view", "state_leaves", "STEP_STATS"]
 
 # what a serve program returns beside logits and cache (an f32 vector):
 # the states its retention read and wrote in a layer — for a step the live
@@ -307,13 +306,6 @@ def state_leaves(cache):
     return [cache]
 
 
-def copy_page(cache, dst, src):
-    """Entry `src` into `dst` in every layer (the interface's copy; the
-    engine shares nothing of a model with a state kind, so it does not
-    call it)."""
-    return cache.at[:, dst].set(cache[:, src])
-
-
 # the leaves the programs cast to cfg.dtype where they use them; the norms
 # and the gate (f32) are used as they are kept
 _SERVE_CAST = frozenset({"embed", "unembed", "wq", "wk", "wv", "wo",
@@ -321,6 +313,6 @@ _SERVE_CAST = frozenset({"embed", "unembed", "wq", "wk", "wv", "wo",
 
 
 def serve_view(params, cfg: BrumbyConfig):
-    """gpt.serve_view over this model's leaves: a tree kept in cfg.dtype
+    """gpt.cast_leaves over this model's leaves: a tree kept in cfg.dtype
     (the published configuration's) comes back as the same arrays."""
-    return _cast_leaves(params, cfg, _SERVE_CAST)
+    return cast_leaves(params, cfg, _SERVE_CAST)

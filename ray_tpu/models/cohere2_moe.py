@@ -19,10 +19,10 @@ the parallel block, the expert layer's call, and two kinds of paged KV
 state (`cache_kinds`): a full layer keeps every position, a sliding layer
 only the last `sliding_window`, in a ring of pages the engine refills and
 returns as the window passes.  What the family shares is called, not
-copied: gpt's `_norm`, `_qkv_of_normed`, `_attn_out`, `_slot_embed`,
-`_unembed_table` and `sample_logits`; `ops.layers.swiglu` and
+copied: gpt's `apply_norm`, `qkv_of_normed`, `attn_out`, `slot_embed`,
+`unembed_table` and `cast_leaves`; `ops.layers.swiglu` and
 `apply_rope_interleaved`; `ops.attention.streamed_attention`;
-`ops.moe.route_sigmoid_topk` / `held_expert_ffn`.
+`ops.moe.route_sigmoid_topk` / `held_expert_ffn` / `held_load_stats`.
 
 A chip may hold a *share* of the model: `experts_held` of `n_experts`
 experts from `experts_first` on (the router keeps its full width; what the
@@ -39,27 +39,22 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import (streamed_attention,
-                                   streamed_attention_uses_kernel)
+from ray_tpu.ops.attention import streamed_attention
 from ray_tpu.ops.layers import apply_rope_interleaved, swiglu
-from ray_tpu.ops.moe import held_expert_ffn, route_sigmoid_topk
+from ray_tpu.ops.moe import (held_expert_ffn, held_load_stats,
+                             route_sigmoid_topk)
 
-from .gpt import (_attn_out, _norm, _qkv_of_normed, _slot_embed,
-                  _unembed_table, sample_logits, serve_view as _cast_leaves)
+from .gpt import (apply_norm, attn_out, cast_leaves, qkv_of_normed,
+                  slot_embed, unembed_table)
 
 __all__ = ["Cohere2MoEConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
-           "sample_logits", "serve_view", "STEP_STATS"]
+           "serve_view", "STEP_STATS"]
 
 # what a serve program returns beside logits and cache, in this order
 # (f32 scalars, summed over the layers): token-expert pairs that fell on
 # held experts, the largest load of a held expert, held experts touched
 STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched")
-
-# whether a prefill program of `rows` rows attends through the Pallas
-# block kernel: the predicate streamed_attention itself picks by, for the
-# engine to stamp its chunk launches with
-chunk_attn_kernel = streamed_attention_uses_kernel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,14 +187,14 @@ def _block(x, layer, kind: str, pos, attend, cfg: Cohere2MoEConfig,
     rows that are real (pad rows and empty slots do not route)."""
     B, T, D = x.shape
     G = cfg.n_heads // cfg.n_kv_heads
-    h = _norm(x, layer["attn_norm"], None, cfg.norm).astype(cfg.dtype)
-    q, k, v = _qkv_of_normed(h, layer, cfg)
+    h = apply_norm(x, layer["attn_norm"], None, cfg.norm).astype(cfg.dtype)
+    q, k, v = qkv_of_normed(h, layer, cfg)
     if kind == "sliding":
         q = apply_rope_interleaved(q, pos, cfg.rope_theta)
         k = apply_rope_interleaved(k, pos, cfg.rope_theta)
     with jax.named_scope("attn_window" if kind == "sliding" else "attn_full"):
         o = attend(q.reshape(B, cfg.n_kv_heads, G, T, cfg.d_head), k, v)
-    att = _attn_out(o.reshape(B, cfg.n_heads, T, cfg.d_head), layer, cfg)
+    att = attn_out(o.reshape(B, cfg.n_heads, T, cfg.d_head), layer, cfg)
     ffn, loads = _ffn(h.reshape(B * T, D), layer, cfg,
                       None if live is None else live.reshape(B * T))
     x = x + att + ffn.reshape(B, T, D).astype(x.dtype)
@@ -211,17 +206,11 @@ def _window(kind: str, cfg: Cohere2MoEConfig) -> Optional[int]:
 
 
 def _logits(params, x, cfg: Cohere2MoEConfig):
-    x = _norm(x, params["final_norm"], None, cfg.norm)
+    x = apply_norm(x, params["final_norm"], None, cfg.norm)
     lg = jnp.einsum("...d,dv->...v", x.astype(cfg.dtype),
-                    _unembed_table(params, cfg),
+                    unembed_table(params, cfg),
                     preferred_element_type=jnp.float32)
     return lg * cfg.logit_scale
-
-
-def _stats(loads: List[jax.Array]):
-    ld = jnp.stack(loads).astype(jnp.float32)              # [L, held]
-    return jnp.stack([ld.sum(), ld.max(axis=1).sum(),
-                      (ld > 0).sum().astype(jnp.float32)])
 
 
 def apply(params, tokens, cfg: Cohere2MoEConfig):
@@ -232,7 +221,7 @@ def apply(params, tokens, cfg: Cohere2MoEConfig):
     kb = min(cfg.kv_block, S)
     nb = -(-S // kb)
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = _slot_embed(params, tokens, pos, cfg)
+    x = slot_embed(params, tokens, pos, cfg)
 
     def attend_for(kind):
         def attend(q, k, v):
@@ -341,7 +330,7 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg):
     B, T = toks.shape
     ps = cache[0]["k"].shape[1]
     npb = max(1, cfg.kv_block // ps)
-    x = _slot_embed(params, toks, pos, cfg)
+    x = slot_embed(params, toks, pos, cfg)
     last = jnp.max(pos, axis=1)                            # [B]
     flat_pos = pos.reshape(B * T)
     per_kind = {}
@@ -367,7 +356,7 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg):
         x, ld = _block(x, layer, kind, pos, attend, cfg, live=real)
         new_cache.append(box["arena"])
         loads.append(ld)
-    return x, new_cache, _stats(loads)
+    return x, new_cache, jnp.stack(held_load_stats(loads))
 
 
 def paged_decode_step(params, cache, tokens, ptabs, pos, cfg):
@@ -402,6 +391,6 @@ _SERVE_CAST = frozenset({"embed", "wq", "wk", "wv", "wo", "wg", "wu", "wd",
 
 
 def serve_view(params, cfg: Cohere2MoEConfig):
-    """gpt.serve_view over this model's leaves: a tree kept in cfg.dtype
+    """gpt.cast_leaves over this model's leaves: a tree kept in cfg.dtype
     (the published configuration's) comes back as the same arrays."""
-    return _cast_leaves(params, cfg, _SERVE_CAST)
+    return cast_leaves(params, cfg, _SERVE_CAST)

@@ -62,18 +62,23 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (latent_decode_attention,
                                    latent_decode_uses_kernel,
-                                   latent_walked_keys, streamed_attention,
-                                   streamed_attention_uses_kernel)
+                                   latent_walked_keys, streamed_attention)
 from ray_tpu.ops.layers import (apply_rope_interleaved, rms_norm, swiglu,
                                 yarn_frequencies)
-from ray_tpu.ops.moe import held_expert_ffn, route_sigmoid_grouped
+from ray_tpu.ops.moe import (held_expert_ffn, held_load_stats,
+                             route_sigmoid_grouped)
 
-from .gpt import (_slot_embed, _unembed_table, sample_logits,
-                  serve_view as _cast_leaves)
+from .gpt import cast_leaves, slot_embed, unembed_table
 
 __all__ = ["DeepSeekV3Config", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
-           "copy_page", "sample_logits", "serve_view", "STEP_STATS"]
+           "copy_page", "serve_view", "STEP_STATS",
+           # what models/ling3.py builds on: its latent-attention layers
+           # ARE these (the cached row, both forms of attention over it,
+           # how a program's rows meet the pages, the re-laid `Wkvb`), as
+           # its feed-forward and its head are
+           "latent_rows", "latent_attend", "page_io", "latent_arenas",
+           "kv_up", "with_kv_up", "layer_ffn", "head_logits"]
 
 # what a serve program returns beside logits and cache, in this order (f32
 # scalars, summed over the layers): token-expert pairs that fell on held
@@ -85,11 +90,6 @@ __all__ = ["DeepSeekV3Config", "init", "apply", "cache_kinds",
 # to the longest context, the decode kernel each live slot's own pages)
 STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "mla_pairs",
               "mla_keys", "mla_walked_keys")
-
-# whether a prefill program of `rows` rows attends through the Pallas
-# block kernel: the predicate streamed_attention itself picks by, for the
-# engine to stamp its chunk launches with
-chunk_attn_kernel = streamed_attention_uses_kernel
 
 KIND = "full"
 
@@ -268,7 +268,7 @@ def init(key, cfg: DeepSeekV3Config) -> Dict[str, Any]:
 # the block
 
 
-def _kv_up(layer, cfg: DeepSeekV3Config):
+def kv_up(layer, cfg: DeepSeekV3Config):
     """(W_UK [H, dn, rkv], W_UV [H, rkv, dv]) in cfg.dtype: the serve
     view's own leaves, or `Wkvb` re-laid where a program is handed the
     plain tree (a test, `apply`)."""
@@ -291,7 +291,7 @@ def _queries(h, layer, pos, cfg: DeepSeekV3Config):
         return q[..., :cfg.d_nope], q_pe
 
 
-def _latent_rows(h, layer, pos, cfg: DeepSeekV3Config):
+def latent_rows(h, layer, pos, cfg: DeepSeekV3Config):
     """h [B, T, D] normed -> the rows the cache keeps, [B, T, rkv + dr] in
     cfg.dtype: the normed latent beside the turned rope key."""
     dt = cfg.dtype
@@ -303,8 +303,8 @@ def _latent_rows(h, layer, pos, cfg: DeepSeekV3Config):
         return jnp.concatenate([c_kv, k_pe], axis=-1).astype(dt)
 
 
-def _attend(q_nope, q_pe, qpos, fetch, n_blocks, layer, absorbed: bool,
-            cfg: DeepSeekV3Config):
+def latent_attend(q_nope, q_pe, qpos, fetch, n_blocks, layer,
+                  absorbed: bool, cfg: DeepSeekV3Config):
     """Heads' outputs [B, H, T, dv] of queries at positions qpos [B, T]
     against cached latents: `fetch(i)` -> (block i's rows [B, S, rkv + dr],
     their positions [B, S], negative where there is none).  Where the
@@ -314,7 +314,7 @@ def _attend(q_nope, q_pe, qpos, fetch, n_blocks, layer, absorbed: bool,
     and fetches no block."""
     B, H, T, _ = q_nope.shape
     rkv = cfg.kv_rank
-    w_uk, w_uv = _kv_up(layer, cfg)
+    w_uk, w_uv = kv_up(layer, cfg)
     if absorbed:
         with jax.named_scope("mla_attend_step"):
             q_lat = jnp.einsum("bhtn,hnr->bhtr", q_nope, w_uk)
@@ -349,7 +349,7 @@ def _attend(q_nope, q_pe, qpos, fetch, n_blocks, layer, absorbed: bool,
         return o[:, :, 0]
 
 
-def _ffn(h, layer, cfg: DeepSeekV3Config, live=None):
+def layer_ffn(h, layer, cfg: DeepSeekV3Config, live=None):
     """The layer's feed-forward on the normed input h [N, D] -> ([N, D]
     f32, loads [held] or None for a dense layer)."""
     dt = cfg.dtype
@@ -385,22 +385,23 @@ def _block(x, layer, pos, write, fetch, n_blocks, absorbed: bool,
     dt = cfg.dtype
     h = rms_norm(x, layer["attn_norm"], cfg.rms_eps).astype(dt)
     q_nope, q_pe = _queries(h, layer, pos, cfg)
-    write(_latent_rows(h, layer, pos, cfg))
-    o = _attend(q_nope, q_pe, pos, fetch, n_blocks, layer, absorbed, cfg)
+    write(latent_rows(h, layer, pos, cfg))
+    o = latent_attend(q_nope, q_pe, pos, fetch, n_blocks, layer, absorbed,
+                      cfg)
     with jax.named_scope("mla_out"):
         x = x + jnp.einsum("bhtv,hvd->btd", o.astype(dt),
                            layer["wo"].astype(dt)).astype(x.dtype)
     h2 = rms_norm(x, layer["mlp_norm"], cfg.rms_eps).astype(dt)
-    ffn, loads = _ffn(h2.reshape(B * T, D), layer, cfg,
-                      None if live is None else live.reshape(B * T))
+    ffn, loads = layer_ffn(h2.reshape(B * T, D), layer, cfg,
+                           None if live is None else live.reshape(B * T))
     return x + ffn.reshape(B, T, D).astype(x.dtype), loads
 
 
-def _logits(params, x, cfg: DeepSeekV3Config):
+def head_logits(params, x, cfg: DeepSeekV3Config):
     with jax.named_scope("unembed"):
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         return jnp.einsum("...d,dv->...v", x.astype(cfg.dtype),
-                          _unembed_table(params, cfg),
+                          unembed_table(params, cfg),
                           preferred_element_type=jnp.float32)
 
 
@@ -412,11 +413,7 @@ def _stats(loads: List[jax.Array], pos, real, walked,
     seen = jnp.where(real, pos + 1, 0).astype(jnp.float32)     # [B, T]
     mla = [seen.sum() * cfg.n_layers, seen.max(axis=1).sum() * cfg.n_layers,
            jnp.asarray(walked, jnp.float32)]
-    if not loads:
-        return jnp.stack([jnp.zeros(())] * 3 + mla)
-    ld = jnp.stack(loads).astype(jnp.float32)                  # [L_moe, held]
-    return jnp.stack([ld.sum(), ld.max(axis=1).sum(),
-                      (ld > 0).sum().astype(jnp.float32)] + mla)
+    return jnp.stack(held_load_stats(loads) + mla)
 
 
 def apply(params, tokens, cfg: DeepSeekV3Config):
@@ -428,7 +425,7 @@ def apply(params, tokens, cfg: DeepSeekV3Config):
     nb = -(-S // kb)
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     kpos = jnp.pad(pos, ((0, 0), (0, nb * kb - S)), constant_values=-1)
-    x = _slot_embed(params, tokens, pos, cfg)
+    x = slot_embed(params, tokens, pos, cfg)
     for layer in params["layers"]:
         box = {}
 
@@ -440,7 +437,7 @@ def apply(params, tokens, cfg: DeepSeekV3Config):
             return sl(box["rows"]), sl(kpos)
 
         x, _ = _block(x, layer, pos, write, fetch, nb, False, cfg)
-    return _logits(params, x, cfg)
+    return head_logits(params, x, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -457,23 +454,30 @@ def _only(x):
     return x[KIND] if isinstance(x, dict) else x
 
 
+def latent_arenas(cfg, pages: int, page_size: int, layers: int
+                  ) -> List[jax.Array]:
+    """One arena a latent-attention layer, [pages, kv_rank + d_rope,
+    page_size] in cfg.dtype: a position is ONE latent row (`c_kv | k_pe`),
+    with no K side, no V side and no head axis, laid as a COLUMN of its
+    page — the page's 128 positions fill the lanes and the 576 values 36
+    sublane groups, so a position costs its 1,152 B and nothing more (576
+    along the lanes is padded to 640, and the chip's compiler, asked for
+    that, re-laid the whole arena into this form and back in every
+    program).  Page 0 is the null page.  `page_io` and the decode kernel
+    read and write this layout."""
+    shape = (int(pages), cfg.kv_rank + cfg.d_rope, page_size)
+    return [jnp.zeros(shape, cfg.dtype) for _ in range(layers)]
+
+
 def init_paged_cache(cfg: DeepSeekV3Config, num_pages, page_size: int
                      ) -> List[jax.Array]:
-    """One arena a layer, [pages, kv_rank + d_rope, page_size] in
-    cfg.dtype: a position is ONE latent row (`c_kv | k_pe`), with no K
-    side, no V side and no head axis, laid as a COLUMN of its page — the
-    page's 128 positions fill the lanes and the 576 values 36 sublane
-    groups, so a position costs its 1,152 B and nothing more (576 along
-    the lanes is padded to 640, and the chip's compiler, asked for that,
-    re-laid the whole arena into this form and back in every program).
-    Page 0 is the null page.  The serve programs are given the arenas to
+    """`latent_arenas`, one a layer.  The serve programs are given them to
     keep (the engine donates them) and write whole pages where the arena
     stands (`_paged_pass`)."""
-    shape = (int(_only(num_pages)), cfg.d_latent, page_size)
-    return [jnp.zeros(shape, cfg.dtype) for _ in range(cfg.n_layers)]
+    return latent_arenas(cfg, _only(num_pages), page_size, cfg.n_layers)
 
 
-def _page_io(ptab, pos, real, d: int, ps: int, cfg):
+def page_io(ptab, pos, real, d: int, ps: int, cfg):
     """How the rows of a program at CONSECUTIVE positions pos [B, T] (a
     row's are pos[b, 0] + t; `real` [B, T] marks the rows whose latent is
     kept) meet the pages of ptab [B, R] whose positions are `d` values by
@@ -481,8 +485,7 @@ def _page_io(ptab, pos, real, d: int, ps: int, cfg):
     layer's arena, the box whose "arena" the write leaves and whose
     "walked" says how many key positions the layer's attention fetched,
     through `fetch` or through `fetch.pages`), beside the number of key
-    blocks the live contexts reach (models/ling3.py's latent-attention
-    layers take their pages through this too).
+    blocks the live contexts reach.
 
     A layer's rows are written a whole page at a time: the pages the rows
     fall in are read, the rows laid over them, the pages written back.  A
@@ -554,8 +557,8 @@ def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
     if absorbed is None:
         absorbed = T <= ABSORB_ROWS
     d, ps = cache[0].shape[1:]
-    bind, n_blocks = _page_io(ptab, pos, real, d, ps, cfg)
-    x = _slot_embed(params, toks, pos, cfg)
+    bind, n_blocks = page_io(ptab, pos, real, d, ps, cfg)
+    x = slot_embed(params, toks, pos, cfg)
     new_cache, loads, walked = [], [], 0
     for layer, arena in zip(params["layers"], cache):
         write, fetch, box = bind(arena)
@@ -577,7 +580,7 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg, absorbed=None):
     x, cache, stats = _paged_pass(params, cache, tokens[:, None],
                                   _only(ptabs), pos[:, None], live, cfg,
                                   absorbed)
-    return _logits(params, x[:, 0], cfg), cache, stats
+    return head_logits(params, x[:, 0], cfg), cache, stats
 
 
 def paged_prefill(params, cache, toks, ptab_rows, start, last_idx, cfg,
@@ -592,7 +595,7 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx, cfg,
         params, cache, toks[None], _only(ptab_rows)[None], (start + t)[None],
         (t <= last_idx)[None], cfg, absorbed)
     x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
-    return _logits(params, x, cfg), cache, stats
+    return head_logits(params, x, cfg), cache, stats
 
 
 def copy_page(cache, dst, src):
@@ -609,21 +612,26 @@ _SERVE_CAST = frozenset({"embed", "unembed", "wq_a", "wq_b", "wkv_a", "w_uk",
 
 @functools.partial(jax.jit, static_argnames="cfg")
 def _relaid(wkv_b, cfg):
-    return _kv_up({"wkv_b": wkv_b}, cfg)
+    return kv_up({"wkv_b": wkv_b}, cfg)
+
+
+def with_kv_up(params, cfg):
+    """`params` with every layer's `Wkvb` re-laid ONCE into the two
+    matrices attention multiplies by (`w_uk` [H, dn, rkv], `w_uv`
+    [H, rkv, dv]: a program then derives nothing) and left out itself, so
+    the view counts what the tree counts.  A layer without `Wkvb` (a view's,
+    or one that is no latent attention) comes back as it is."""
+    layers = []
+    for layer in params["layers"]:
+        if "wkv_b" in layer:
+            w_uk, w_uv = _relaid(layer["wkv_b"], cfg=cfg)
+            layer = {**{k: v for k, v in layer.items() if k != "wkv_b"},
+                     "w_uk": w_uk, "w_uv": w_uv}
+        layers.append(layer)
+    return dict(params, layers=layers)
 
 
 def serve_view(params, cfg: DeepSeekV3Config):
-    """gpt.serve_view over this model's leaves, with every layer's `Wkvb`
-    re-laid ONCE into the two matrices attention multiplies by (`w_uk`
-    [H, dn, rkv], `w_uv` [H, rkv, dv]: a program then derives nothing) and
-    left out itself, so the view counts what the tree counts.  A view
-    comes back as it is."""
-    layers = []
-    for layer in params["layers"]:
-        if "wkv_b" not in layer:
-            layers.append(layer)
-            continue
-        w_uk, w_uv = _relaid(layer["wkv_b"], cfg=cfg)
-        layers.append({**{k: v for k, v in layer.items() if k != "wkv_b"},
-                       "w_uk": w_uk, "w_uv": w_uv})
-    return _cast_leaves(dict(params, layers=layers), cfg, _SERVE_CAST)
+    """gpt.cast_leaves over this model's leaves, `Wkvb` re-laid
+    (`with_kv_up`).  A view comes back as it is."""
+    return cast_leaves(with_kv_up(params, cfg), cfg, _SERVE_CAST)

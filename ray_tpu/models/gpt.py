@@ -33,6 +33,19 @@ from ray_tpu.ops.ring_attention import ring_attention_sharded
 from ray_tpu.parallel.sharding import (ACTIVATION_RULES, Logical,
                                        spec_from_logical)
 
+__all__ = [
+    "GPTConfig", "logical_axes", "init", "apply", "apply_hidden", "loss_fn",
+    "num_params", "generate", "sample_logits", "init_cache", "decode_step",
+    "partition_stage_params", "merge_stage_trees", "stage_hidden",
+    "stage_loss",
+    # the served GPT-2 (the engine's model interface, serve/_engine.py)
+    "cache_kinds", "init_paged_cache", "paged_decode_step", "paged_prefill",
+    "copy_page", "serve_view", "step_kv_read", "kv_block_pages",
+    # what the other model modules build on
+    "apply_norm", "constrain", "attention_op", "qkv_of_normed", "attn_out",
+    "slot_embed", "unembed_table", "cast_leaves",
+]
+
 
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
@@ -203,19 +216,16 @@ def init(key, cfg: GPTConfig) -> Dict[str, Any]:
     return params
 
 
-def _norm(x, w, b, kind):
+def apply_norm(x, w, b, kind):
     if kind == "rms":
         return rms_norm(x, w)
     return layer_norm(x, w, b)
 
 
-def _constrain(x, *axes):
+def constrain(x, *axes):
     """Activation sharding constraint (ACTIVATION_RULES: fsdp stays on
     the batch dim — params' embed-dim fsdp sharding is gathered on use,
     never propagated onto activations)."""
-    from ray_tpu.parallel.sharding import (ACTIVATION_RULES,
-                                           spec_from_logical)
-
     try:
         return jax.lax.with_sharding_constraint(
             x, spec_from_logical(axes, ACTIVATION_RULES))
@@ -223,7 +233,7 @@ def _constrain(x, *axes):
         return x  # outside jit / no mesh context
 
 
-def _attention_op(q, k, v, cfg: GPTConfig, mesh, allow_manual: bool = True):
+def attention_op(q, k, v, cfg: GPTConfig, mesh, allow_manual: bool = True):
     """Pick the attention path: ring over sp when the mesh has an sp axis,
     otherwise flash/blockwise on the whole (possibly sharded) arrays.
 
@@ -271,7 +281,7 @@ def _ways(mesh, entry) -> int:
     return math.prod(mesh.shape[a] for a in names)
 
 
-def _qkv_of_normed(h, layer, cfg):
+def qkv_of_normed(h, layer, cfg):
     """Q, K and V of an already normed input h [B, S, D]: [B, H, S, dh]
     each (K and V with as many heads as `wk` / `wv` hold: fewer than Q
     under grouped-query attention), biases where the config has them, no
@@ -291,15 +301,16 @@ def _qkv_proj(x, layer, cfg: GPTConfig, rope, positions=None):
     by the training forward and the KV-cache decode path (a recipe tweak
     made in only one of them would silently break decode==forward
     parity, which test_gpt_decode_matches_full_forward enforces)."""
-    h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
-    q, k, v = _qkv_of_normed(h.astype(cfg.dtype), layer, cfg)
+    h = apply_norm(x, layer["attn_norm"], layer.get("attn_norm_b"),
+                   cfg.norm)
+    q, k, v = qkv_of_normed(h.astype(cfg.dtype), layer, cfg)
     if rope is not None:
         q = apply_rope(q, *rope, positions=positions)
         k = apply_rope(k, *rope, positions=positions)
     return q, k, v
 
 
-def _attn_out(o, layer, cfg):
+def attn_out(o, layer, cfg):
     """Attention's output projection: o [B, H, S, dh] -> [B, S, D]."""
     att = jnp.einsum("bhsk,hkd->bsd", o, layer["wo"].astype(cfg.dtype))
     if cfg.attn_bias:
@@ -310,8 +321,9 @@ def _attn_out(o, layer, cfg):
 def _attn_out_and_mlp(x, o, layer, cfg: GPTConfig):
     """Output projection + residual + MLP sublayer (shared, see
     _qkv_proj)."""
-    x = x + _attn_out(o, layer, cfg)
-    h2 = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm)
+    x = x + attn_out(o, layer, cfg)
+    h2 = apply_norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"),
+                    cfg.norm)
     h2 = h2.astype(cfg.dtype)
     if cfg.act == "swiglu":
         m = swiglu(h2, layer["mlp_gate"].astype(cfg.dtype),
@@ -334,12 +346,12 @@ def _scan_blocks(x, layers, cfg: GPTConfig, rope, mesh=None,
 
     def block(x, layer):
         q, k, v = _qkv_proj(x, layer, cfg, rope)
-        q = _constrain(q, "batch", "heads", "seq", "head_dim")
-        k = _constrain(k, "batch", "heads", "seq", "head_dim")
-        v = _constrain(v, "batch", "heads", "seq", "head_dim")
-        o = _attention_op(q, k, v, cfg, mesh, allow_manual=allow_manual)
+        q = constrain(q, "batch", "heads", "seq", "head_dim")
+        k = constrain(k, "batch", "heads", "seq", "head_dim")
+        v = constrain(v, "batch", "heads", "seq", "head_dim")
+        o = attention_op(q, k, v, cfg, mesh, allow_manual=allow_manual)
         x = _attn_out_and_mlp(x, o, layer, cfg)
-        return _constrain(x, "batch", "seq", "embed")
+        return constrain(x, "batch", "seq", "embed")
 
     def scan_body(x, layer):
         if cfg.remat:
@@ -367,7 +379,7 @@ def apply_hidden(params, tokens, cfg: GPTConfig, mesh=None):
         rope = None
     else:
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
-    x = _constrain(x, "batch", "seq", "embed")
+    x = constrain(x, "batch", "seq", "embed")
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
 
     if pp > 1:
@@ -392,7 +404,8 @@ def apply_hidden(params, tokens, cfg: GPTConfig, mesh=None):
     else:
         x = _scan_blocks(x, params["layers"], cfg, rope, mesh,
                          allow_manual=True)
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = apply_norm(x, params["final_norm"], params.get("final_norm_b"),
+                   cfg.norm)
     return x
 
 
@@ -478,8 +491,8 @@ def stage_hidden(stage_params, x, cfg: GPTConfig, stage: int, stages: int):
             else rope_table(S, cfg.d_head, dtype=jnp.float32))
     h = _scan_blocks(h, stage_params["layers"], cfg, rope, mesh=None)
     if stage == stages - 1:
-        h = _norm(h, stage_params["final_norm"],
-                  stage_params.get("final_norm_b"), cfg.norm)
+        h = apply_norm(h, stage_params["final_norm"],
+                       stage_params.get("final_norm_b"), cfg.norm)
     return h
 
 
@@ -496,7 +509,7 @@ def stage_loss(stage_params, x, targets, cfg: GPTConfig, stage: int,
                                           z_loss=cfg.z_loss))
 
 
-def _unembed_table(params, cfg: GPTConfig):
+def unembed_table(params, cfg: GPTConfig):
     return (params["embed"].T if cfg.tie_embeddings
             else params["unembed"]).astype(cfg.dtype)
 
@@ -505,8 +518,8 @@ def apply(params, tokens, cfg: GPTConfig, mesh=None):
     """Forward pass: tokens [B, S] int32 -> logits [B, S, V]."""
     x = apply_hidden(params, tokens, cfg, mesh)
     logits = jnp.einsum("bsd,dv->bsv", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
-    return _constrain(logits, "batch", "seq", "vocab")
+                        unembed_table(params, cfg))
+    return constrain(logits, "batch", "seq", "vocab")
 
 
 def loss_fn(params, batch, cfg: GPTConfig, mesh=None):
@@ -525,7 +538,7 @@ def loss_fn(params, batch, cfg: GPTConfig, mesh=None):
         # host-side would gather; fall back to dense there
         x = apply_hidden(params, inputs, cfg, mesh)
         loss = fused_softmax_cross_entropy(
-            x.astype(cfg.dtype), _unembed_table(params, cfg), targets,
+            x.astype(cfg.dtype), unembed_table(params, cfg), targets,
             z_loss=cfg.z_loss, chunk=chunk)
     else:
         logits = apply(params, inputs, cfg, mesh)
@@ -594,7 +607,8 @@ def _decode_hidden(params, cache, tokens, cfg: GPTConfig, rope=None):
     x, (k_new, v_new) = jax.lax.scan(
         block, x, (params["layers"], cache["k"], cache["v"]),
         unroll=cfg.n_layers)
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = apply_norm(x, params["final_norm"], params.get("final_norm_b"),
+                   cfg.norm)
     return x[:, 0], {"k": k_new, "v": v_new, "pos": pos + 1}
 
 
@@ -603,7 +617,7 @@ def decode_step(params, cache, tokens, cfg: GPTConfig, rope=None):
     (logits [B, V], updated cache)."""
     x, cache = _decode_hidden(params, cache, tokens, cfg, rope)
     logits = jnp.einsum("bd,dv->bv", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
+                        unembed_table(params, cfg))
     return logits, cache
 
 
@@ -701,23 +715,19 @@ def _decode_hidden_fast(view, cfg: GPTConfig, kcache, vcache, pos, toks):
 # ---------------------------------------------------------------------------
 # Slot-batch decoding (continuous batching).  The serving engine keeps a
 # fixed-shape batch of B "slots"; sequences join at prefill and leave at
-# EOS/max-tokens, so every slot sits at its OWN position.  Two cache
-# layouts apply the same causal mask by position and take the same sums:
+# EOS/max-tokens, so every slot sits at its OWN position.  One cache
+# layout is served: a device arena of fixed-size pages [L, P, ps, H * dh]
+# plus per-slot page tables, read inside the programs block by block as
+# far as the contexts are live (_paged_attention).  Page 0 is reserved as
+# the null page: inactive slots write there and their outputs are
+# discarded host-side, so the compiled step program never changes shape
+# as sequences come and go.  The plain recipe the paged programs are held
+# to — a contiguous row a slot, every position scored at once — is
+# tests/slot_reference.py.
 #
-#   * contiguous slot cache [L, B, H, S, dh] — one row per slot, every
-#     position scored at once (_slot_attention): the plain recipe, kept
-#     for the tests that hold the paged path to it;
-#   * paged cache: a device arena of fixed-size pages [L, P, ps, H * dh]
-#     plus per-slot page tables, read inside the programs block by block
-#     as far as the contexts are live (_paged_attention).  Page 0 is
-#     reserved as the null page: inactive slots write there and their
-#     outputs are discarded host-side, so the compiled step program
-#     never changes shape as sequences come and go.
-#
-# A sequence joins through a prefill program (slot_prefill /
-# paged_prefill): its padded prompt chunk in ONE pass through the layers
-# (_prefill_chunk), T query rows through the attention that a decode
-# step feeds one row a slot.
+# A sequence joins through a prefill program (paged_prefill): its padded
+# prompt chunk in ONE pass through the layers (_prefill_chunk), T query
+# rows through the attention that a decode step feeds one row a slot.
 #
 # Every program takes the cache of ALL layers and hands it back: it is
 # the layer loop's carry, a layer scatters its new rows at [l, ...] and
@@ -739,7 +749,7 @@ def _slot_rope(x, cos, sin, positions):
                            axis=-1).astype(x.dtype)
 
 
-def _slot_embed(params, tokens, pos, cfg: GPTConfig):
+def slot_embed(params, tokens, pos, cfg: GPTConfig):
     """tokens [B, T] at positions pos [B, T] -> x [B, T, D]."""
     x = params["embed"][tokens].astype(cfg.dtype)
     if cfg.pos == "learned":
@@ -761,84 +771,11 @@ def _slot_qkv(x, layer, cfg: GPTConfig, rope, pos):
     return q, k, v
 
 
-def _slot_attention(q, kc, vc, pos, cfg: GPTConfig):
-    """q [B,H,T,dh] at positions pos [B,T] against a per-slot cache view
-    kc/vc [B,H,S,dh], masked causally by position (key <= pos[b, t]).
-    This is the PLAIN recipe — every position of the view scored at once
-    under the mask, one softmax over all of them — that the contiguous
-    cache's programs run (a decode step is its T = 1 case, a prefill its
-    B = 1 case) and that the paged programs are held to: they apply the
-    same mask and take the same sums block by block over the live part
-    of the page table (_paged_attention), so paged == contiguous is what
-    tests/test_serve_prefill.py and tests/test_serve_live_blocks.py
-    measure (logits within 1e-4 in f32), no longer an identity of the
-    program text."""
-    S = kc.shape[2]
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, S), 3)
-            <= pos[:, None, :, None])
-    s = jnp.einsum("bhqk,bhsk->bhqs", q.astype(jnp.float32),
-                   kc.astype(jnp.float32)) * (cfg.d_head ** -0.5)
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    vcd = vc if vc.dtype == cfg.dtype else vc.astype(cfg.dtype)
-    return jnp.einsum("bhqs,bhsk->bhqk", p.astype(cfg.dtype), vcd)
-
-
-def init_slot_cache(cfg: GPTConfig, slots: int, max_total: int
-                    ) -> Dict[str, Any]:
-    """Contiguous slot cache: [L, slots, H, max_total, d_head] per side.
-    Positions live with the engine (per-slot, host-driven), not in the
-    cache — unlike init_cache's scalar lockstep `pos`."""
-    shape = (cfg.n_layers, slots, cfg.n_heads, max_total, cfg.d_head)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def _slot_decode_hidden(params, kcache, vcache, tokens, pos, cfg: GPTConfig,
-                        rope=None):
-    """One decode position for every slot: tokens [B] at per-slot
-    positions pos [B] -> (hidden [B, D], kcache, vcache).  kcache/vcache
-    [L, B, H, S, dh]."""
-    B = tokens.shape[0]
-    S = kcache.shape[3]
-    if cfg.pos == "learned":
-        rope = None
-    elif rope is None:
-        rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
-    qpos = pos[:, None]                        # one query row a slot
-    x = _slot_embed(params, tokens[:, None], qpos, cfg)     # [B, 1, D]
-    bidx = jnp.arange(B)
-
-    def block(carry, inp):
-        x, kc, vc = carry                      # kc/vc [L, B, H, S, dh]
-        layer, l = inp
-        q, k, v = _slot_qkv(x, layer, cfg, rope, qpos)
-        kc = kc.at[l, bidx, :, pos, :].set(k[:, :, 0, :].astype(kc.dtype))
-        vc = vc.at[l, bidx, :, pos, :].set(v[:, :, 0, :].astype(vc.dtype))
-        o = _slot_attention(q, kc[l], vc[l], qpos, cfg)
-        return (_attn_out_and_mlp(x, o, layer, cfg), kc, vc), None
-
-    (x, k_new, v_new), _ = jax.lax.scan(
-        block, (x, kcache, vcache), _numbered(params["layers"], cfg),
-        unroll=cfg.n_layers)
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
-    return x[:, 0], k_new, v_new
-
-
-def slot_decode_step(params, cache, tokens, pos, cfg: GPTConfig, rope=None):
-    """Slot-batch decode on the contiguous cache: tokens [B] at per-slot
-    positions pos [B] -> (logits [B, V], cache)."""
-    x, k_new, v_new = _slot_decode_hidden(params, cache["k"], cache["v"],
-                                          tokens, pos, cfg, rope)
-    logits = jnp.einsum("bd,dv->bv", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
-    return logits, {"k": k_new, "v": v_new}
-
-
 def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
                    write, attend, cfg: GPTConfig, rope=None):
-    """One pass of a padded prompt chunk through the stack, shared by
-    both cache layouts: toks [T] sit at positions start..start+T-1 of
+    """One pass of a padded prompt chunk through the stack, the cache's
+    layout its caller's (paged_prefill here, the contiguous reference of
+    tests/slot_reference.py): toks [T] sit at positions start..start+T-1 of
     ONE sequence whose cache holds S positions; logits are taken at row
     `last_idx` (the last REAL prompt token).  Per layer l the chunk's T
     rows of K and V are written with one `write(c, l, rows [T,H,dh],
@@ -860,7 +797,7 @@ def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
         rope = None
     elif rope is None:
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
-    x = _slot_embed(params, toks[None], pos, cfg)          # [1, T, D]
+    x = slot_embed(params, toks[None], pos, cfg)           # [1, T, D]
 
     def block(carry, inp):
         x, kc, vc = carry
@@ -877,31 +814,11 @@ def _prefill_chunk(params, kcache, vcache, toks, start, last_idx, S,
     (x, k_new, v_new), _ = jax.lax.scan(
         block, (x, kcache, vcache), _numbered(params["layers"], cfg))
     x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = apply_norm(x, params["final_norm"], params.get("final_norm_b"),
+                   cfg.norm)
     logits = jnp.einsum("d,dv->v", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
+                        unembed_table(params, cfg))
     return logits, k_new, v_new
-
-
-def slot_prefill(params, cache, toks, start, last_idx, slot,
-                 cfg: GPTConfig, rope=None):
-    """Prefill ONE slot while the rest of the batch is frozen: the
-    padded chunk toks [T], starting at position `start`, goes through
-    the layers in one pass (_prefill_chunk); logits are taken at chunk
-    row `last_idx`.  Returns (logits [V], cache)."""
-    S = cache["k"].shape[3]
-
-    def write(c, l, rows, wpos):               # c [L, B, H, S, dh]
-        return c.at[l, slot, :, wpos, :].set(rows, mode="drop")
-
-    def attend(q, kc, vc, l, pos):             # the slot's own row, whole
-        return _slot_attention(q, kc[l, slot][None], vc[l, slot][None],
-                               pos, cfg)
-
-    logits, kc, vc = _prefill_chunk(
-        params, cache["k"], cache["v"], toks, start, last_idx, S, write,
-        attend, cfg, rope)
-    return logits, {"k": kc, "v": vc}
 
 
 # -- paged variant ----------------------------------------------------------
@@ -987,9 +904,9 @@ def _paged_attention(q, kc, vc, l, live, pos, cfg: GPTConfig):
     live: a loop of n_blocks turns (traced: up to the block that holds
     the furthest query position) gathers npb pages a slot a turn,
     scores them and folds them into an online softmax — the mask and
-    the sums of _slot_attention, taken block by block (statistics f32,
-    the values' weights cfg.dtype).  A block is left
-    out only if every key in it lies past every query.
+    the sums of the plain recipe (tests/slot_reference.py), taken block
+    by block (statistics f32, the values' weights cfg.dtype).  A block
+    is left out only if every key in it lies past every query.
 
     A gathered block stays [B, npb*ps, H*dh], the arena's own row: dh
     = 64 as a minor dimension pads to the chip's 128 lanes, so nothing
@@ -1050,8 +967,8 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     scatter into each slot's current page; attention reads the pages
     where they stand, block by block up to the block that holds the
     furthest slot's position (_paged_attention): the same mask and the
-    same sums as the contiguous cache's _slot_attention, to which the
-    tests hold it."""
+    same sums as the contiguous reference (tests/slot_reference.py), to
+    which the tests hold it."""
     B = tokens.shape[0]
     ps = kpages.shape[2]
     S = ptab.shape[1] * ps
@@ -1061,7 +978,7 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     elif rope is None:
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
     qpos = pos[:, None]                        # one query row a slot
-    x = _slot_embed(params, tokens[:, None], qpos, cfg)     # [B, 1, D]
+    x = slot_embed(params, tokens[:, None], qpos, cfg)      # [B, 1, D]
     pidx = jnp.take_along_axis(ptab, (pos // ps)[:, None], axis=1)[:, 0]
     poff = pos % ps
     live = _live_table(ptab, jnp.max(pos), ps, cfg)
@@ -1078,7 +995,8 @@ def _paged_decode_hidden(params, kpages, vpages, tokens, ptab, pos,
     (x, k_new, v_new), _ = jax.lax.scan(
         block, (x, kpages, vpages), _numbered(params["layers"], cfg),
         unroll=cfg.n_layers)
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = apply_norm(x, params["final_norm"], params.get("final_norm_b"),
+                   cfg.norm)
     return x[:, 0], k_new, v_new
 
 
@@ -1089,7 +1007,7 @@ def paged_decode_step(params, cache, tokens, ptab, pos, cfg: GPTConfig,
                                            tokens, _only(ptab), pos, cfg,
                                            rope)
     logits = jnp.einsum("bd,dv->bv", x.astype(cfg.dtype),
-                        _unembed_table(params, cfg))
+                        unembed_table(params, cfg))
     return logits, {"k": k_new, "v": v_new}
 
 
@@ -1133,22 +1051,18 @@ def copy_page(cache, dst, src):
 
 
 # the leaves the serve programs cast with `.astype(cfg.dtype)` where they
-# use them (_slot_embed, _qkv_of_normed, _attn_out, _attn_out_and_mlp,
-# _unembed_table); the norms' scales and biases are used as they are kept
+# use them (slot_embed, qkv_of_normed, attn_out, _attn_out_and_mlp,
+# unembed_table); the norms' scales and biases are used as they are kept
 _SERVE_CAST = frozenset({
     "embed", "pos_embed", "unembed", "wq", "wk", "wv", "wo", "wq_b", "wk_b",
     "wv_b", "wo_b", "mlp_in", "mlp_in_b", "mlp_out", "mlp_out_b", "mlp_gate",
     "mlp_up"})
 
 
-def serve_view(params, cfg, cast=_SERVE_CAST):
-    """The tree a serving engine hands to paged_decode_step /
-    paged_prefill in place of `params`, made once at its set-up: every
-    leaf named in `cast` goes through the `astype(cfg.dtype)` the
-    programs apply at each use, so inside them that cast is the identity
-    and a decode step reads its weights in the dtype it multiplies in (a
-    float32 tree served in bf16 was read whole, at twice the bytes, and
-    cast again in every step and every prefill).  The operands of every
+def cast_leaves(params, cfg, names):
+    """`params` with every leaf named in `names` through the
+    `astype(cfg.dtype)` the serve programs apply at each use (a model's
+    `serve_view` is this over its own leaves).  The operands of every
     product are bit for bit what the programs computed for themselves.
     Every other leaf, and a leaf already in cfg.dtype, is the same array:
     nothing is copied, so a tree kept in cfg.dtype costs no memory."""
@@ -1157,9 +1071,19 @@ def serve_view(params, cfg, cast=_SERVE_CAST):
     def leaf(path, w):
         name = next((k.key for k in reversed(path)
                      if isinstance(k, jax.tree_util.DictKey)), None)
-        return w.astype(dt) if name in cast and w.dtype != dt else w
+        return w.astype(dt) if name in names and w.dtype != dt else w
 
     return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+def serve_view(params, cfg):
+    """The tree a serving engine hands to paged_decode_step /
+    paged_prefill in place of `params`, made once at its set-up: inside
+    the programs every cast is then the identity and a decode step reads
+    its weights in the dtype it multiplies in (a float32 tree served in
+    bf16 was read whole, at twice the bytes, and cast again in every step
+    and every prefill)."""
+    return cast_leaves(params, cfg, _SERVE_CAST)
 
 
 def sample_logits(logits, key, temperature: float = 0.0,
@@ -1249,7 +1173,7 @@ def generate(params, cfg: GPTConfig, prompt, max_new_tokens: int, *,
     cache, hidden_all = jax.lax.scan(prefill, cache, prompt.T)
     last_logits = jnp.einsum("bd,dv->bv",
                              hidden_all[-1].astype(cfg.dtype),
-                             _unembed_table(params, cfg))
+                             unembed_table(params, cfg))
 
     def step(carry, key):
         cache, logits = carry

@@ -24,8 +24,8 @@ A KDA layer (H heads, keys and values d_head wide), n the normed input:
 An MLA layer is models/deepseek_v3.py's with a direct query projection
 (no query latent) and a HEAD-WISE output gate: o_h <- o_h sigmoid(n w_h)
 before Wo.  Its cached row, its absorbed step and its expanded chunk ARE
-deepseek_v3's functions (`_latent_rows`, `_attend`, `_page_io`), as the
-feed-forward is (`_ffn`): this config answers the attributes they read.
+deepseek_v3's functions (`latent_rows`, `latent_attend`, `page_io`), as the
+feed-forward is (`layer_ffn`): this config answers the attributes they read.
 
 What a sequence keeps, and `cache_kinds` says so with TWO kinds:
 
@@ -59,17 +59,17 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import streamed_attention_uses_kernel
 from ray_tpu.ops.kda import (conv_chunk, conv_step, kda_chunk, kda_step,
                              resolve_impl)
 from ray_tpu.ops.layers import apply_rope_interleaved, rms_norm
+from ray_tpu.ops.moe import held_load_stats
 
 from . import deepseek_v3 as _dm
-from .gpt import _slot_embed, sample_logits, serve_view as _cast_leaves
+from .gpt import cast_leaves, slot_embed
 
 __all__ = ["Ling3Config", "init", "apply", "cache_kinds", "init_paged_cache",
-           "paged_decode_step", "paged_prefill", "copy_page", "sample_logits",
-           "serve_view", "state_leaves", "STEP_STATS"]
+           "paged_decode_step", "paged_prefill", "serve_view", "state_leaves",
+           "STEP_STATS"]
 
 # what a serve program returns beside logits and cache, in this order (f32
 # scalars): token-expert pairs that fell on held experts, the largest load
@@ -78,8 +78,6 @@ __all__ = ["Ling3Config", "init", "apply", "cache_kinds", "init_paged_cache",
 # live slots where the kernel runs (it moves nothing for an empty slot),
 # every slot on the gather / scatter path; one for a chunk
 STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "kda_live")
-
-chunk_attn_kernel = streamed_attention_uses_kernel
 
 FULL, KDA = "full", "kda"
 ABSORB_ROWS = _dm.ABSORB_ROWS
@@ -337,9 +335,9 @@ def _mla(x, h, layer, pos, write, fetch, n_blocks, absorbed: bool,
         q = jnp.einsum("btd,dhk->bhtk", h, layer["wq"].astype(dt))
         q_pe = apply_rope_interleaved(q[..., cfg.d_nope:], pos,
                                       cfg.rope_theta)
-    write(_dm._latent_rows(h, layer, pos, cfg))
-    o = _dm._attend(q[..., :cfg.d_nope], q_pe, pos, fetch, n_blocks, layer,
-                    absorbed, cfg)
+    write(_dm.latent_rows(h, layer, pos, cfg))
+    o = _dm.latent_attend(q[..., :cfg.d_nope], q_pe, pos, fetch, n_blocks,
+                          layer, absorbed, cfg)
     with jax.named_scope("mla_out"):
         gate = jax.nn.sigmoid(jnp.einsum(
             "btd,dh->bht", h, layer["w_head_gate"].astype(dt),
@@ -357,18 +355,10 @@ def _feed_forward(x, layer, cfg: Ling3Config, live=None):
     """x [B, T, D] with the layer's feed-forward added (deepseek_v3's:
     dense, or routed over the held experts + the shared one)."""
     B, T, D = x.shape
-    ffn, loads = _dm._ffn(_normed(x, layer, "mlp_norm", cfg).reshape(B * T, D),
-                          layer, cfg,
-                          None if live is None else live.reshape(B * T))
+    ffn, loads = _dm.layer_ffn(
+        _normed(x, layer, "mlp_norm", cfg).reshape(B * T, D), layer, cfg,
+        None if live is None else live.reshape(B * T))
     return x + ffn.reshape(B, T, D).astype(x.dtype), loads
-
-
-def _stats(loads: List[jax.Array], moved):
-    if not loads:
-        return jnp.stack([jnp.zeros(())] * 3 + [moved])
-    ld = jnp.stack(loads).astype(jnp.float32)                  # [L_moe, held]
-    return jnp.stack([ld.sum(), ld.max(axis=1).sum(),
-                      (ld > 0).sum().astype(jnp.float32), moved])
 
 
 def apply(params, tokens, cfg: Ling3Config):
@@ -382,7 +372,7 @@ def apply(params, tokens, cfg: Ling3Config):
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     kpos = jnp.pad(pos, ((0, 0), (0, nb * kb - S)), constant_values=-1)
     H, dh = cfg.n_heads, cfg.d_head
-    x = _slot_embed(params, tokens, pos, cfg)
+    x = slot_embed(params, tokens, pos, cfg)
     for l, layer in enumerate(params["layers"]):
         h = _normed(x, layer, "attn_norm", cfg)
         if cfg.is_mla(l):
@@ -404,7 +394,7 @@ def apply(params, tokens, cfg: Ling3Config):
                 jnp.zeros((CONV_TAPS - 1, 3 * H, dh), cfg.dtype), cfg)[0][0]
             )(x, h)
         x, _ = _feed_forward(x, layer, cfg)
-    return _dm._logits(params, x, cfg)
+    return _dm.head_logits(params, x, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -419,18 +409,17 @@ def cache_kinds(cfg: Ling3Config) -> Dict[str, Any]:
 
 
 def init_paged_cache(cfg: Ling3Config, num_pages, page_size: int):
-    """{"latent": one arena [pages, kv_rank + d_rope, page_size] an MLA
-    layer (deepseek_v3's layout), "state": [KDA layers, entries, H, dh,
-    dh] float32, "tail": [KDA layers, entries, 3, 3H, dh] in cfg.dtype —
-    a tail is 3H whole tiles of dh lanes, not 3 rows of a 16-row tile}.
-    `num_pages` counts pages under `full` and entries under `kda`; page 0
-    and entry 0 are the null ones."""
+    """{"latent": one arena an MLA layer (`deepseek_v3.latent_arenas`),
+    "state": [KDA layers, entries, H, dh, dh] float32, "tail": [KDA
+    layers, entries, 3, 3H, dh] in cfg.dtype — a tail is 3H whole tiles of
+    dh lanes, not 3 rows of a 16-row tile}.  `num_pages` counts pages
+    under `full` and entries under `kda`; page 0 and entry 0 are the null
+    ones."""
     H, dh, n = cfg.n_heads, cfg.d_head, len(cfg.kda_layers)
     entries = int(num_pages[KDA])
     return {
-        "latent": [jnp.zeros((int(num_pages[FULL]), cfg.kv_rank + cfg.d_rope,
-                              page_size), cfg.dtype)
-                   for _ in cfg.mla_layers],
+        "latent": _dm.latent_arenas(cfg, num_pages[FULL], page_size,
+                                    len(cfg.mla_layers)),
         "state": jnp.zeros((n, entries, H, dh, dh), jnp.float32),
         "tail": jnp.zeros((n, entries, CONV_TAPS - 1, 3 * H, dh), cfg.dtype),
     }
@@ -446,7 +435,7 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg: Ling3Config,
                 kda_layer, absorbed=None):
     """Tokens toks [B, T] at CONSECUTIVE positions pos [B, T] through the
     layers; `real` [B, T] marks the rows that are kept and routed.  An MLA
-    layer meets its pages through deepseek_v3's `_page_io`; a KDA layer is
+    layer meets its pages through deepseek_v3's `page_io`; a KDA layer is
     `kda_layer(j, x, h, layer, state, tail)` -> (x, state, tail) over the
     two state arenas, j its index among the KDA layers.  Returns
     (x [B, T, D], cache, the expert layers' loads)."""
@@ -457,8 +446,8 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg: Ling3Config,
     state, tail = cache["state"], cache["tail"]
     if latent:
         d, ps = latent[0].shape[1:]
-        bind, n_blocks = _dm._page_io(ptabs[FULL], pos, real, d, ps, cfg)
-    x = _slot_embed(params, toks, pos, cfg)
+        bind, n_blocks = _dm.page_io(ptabs[FULL], pos, real, d, ps, cfg)
+    x = slot_embed(params, toks, pos, cfg)
     loads, n_kda, n_mla = [], 0, 0
     for l, layer in enumerate(params["layers"]):
         h = _normed(x, layer, "attn_norm", cfg)
@@ -502,7 +491,8 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: Ling3Config,
                                   absorbed)
     moved = (live.sum() if resolve_impl(cfg.kda_impl) != "xla"
              else jnp.asarray(B)).astype(jnp.float32)
-    return _dm._logits(params, x[:, 0], cfg), cache, _stats(loads, moved)
+    return (_dm.head_logits(params, x[:, 0], cfg), cache,
+            jnp.stack(held_load_stats(loads) + [moved]))
 
 
 def _carried(first, arena, idx):
@@ -540,15 +530,8 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
         params, cache, toks[None], {FULL: ptab_rows[FULL][None]},
         (start + t)[None], real[None], cfg, kda_layer, absorbed)
     x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
-    return (_dm._logits(params, x, cfg), cache,
-            _stats(loads, jnp.ones((), jnp.float32)))
-
-
-def copy_page(cache, dst, src):
-    """Latent page `src` into `dst` in every MLA layer (the interface's
-    copy; the engine shares nothing of a model with a state kind, so it
-    does not call it)."""
-    return dict(cache, latent=_dm.copy_page(cache["latent"], dst, src))
+    return (_dm.head_logits(params, x, cfg), cache,
+            jnp.stack(held_load_stats(loads) + [jnp.ones((), jnp.float32)]))
 
 
 # the leaves the programs cast to cfg.dtype where they use them; the norms,
@@ -561,13 +544,7 @@ _SERVE_CAST = frozenset({
 
 
 def serve_view(params, cfg: Ling3Config):
-    """deepseek_v3.serve_view over this model's leaves: every MLA layer's
-    `Wkvb` re-laid once into `w_uk` / `w_uv` and left out itself."""
-    layers = []
-    for layer in params["layers"]:
-        if "wkv_b" in layer:
-            w_uk, w_uv = _dm._relaid(layer["wkv_b"], cfg=cfg)
-            layer = {**{k: v for k, v in layer.items() if k != "wkv_b"},
-                     "w_uk": w_uk, "w_uv": w_uv}
-        layers.append(layer)
-    return _cast_leaves(dict(params, layers=layers), cfg, _SERVE_CAST)
+    """gpt.cast_leaves over this model's leaves, every MLA layer's `Wkvb`
+    re-laid once into `w_uk` / `w_uv` and left out itself
+    (`deepseek_v3.with_kv_up`)."""
+    return cast_leaves(_dm.with_kv_up(params, cfg), cfg, _SERVE_CAST)

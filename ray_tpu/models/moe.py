@@ -22,8 +22,7 @@ from ray_tpu.ops.layers import rms_norm, rope_table, apply_rope, \
 from ray_tpu.ops.moe import expert_capacity, moe_ffn, moe_ffn_sharded
 from ray_tpu.parallel.sharding import Logical
 
-from . import gpt as _gpt
-from .gpt import GPTConfig, _attention_op, _constrain, _norm
+from .gpt import GPTConfig, apply_norm, attention_op, constrain
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,10 +171,11 @@ def apply(params, tokens, cfg: MoEConfig, mesh=None
         rope = None
     else:
         rope = rope_table(S, cfg.d_head, dtype=jnp.float32)
-    x = _constrain(x, "batch", "seq", "embed")
+    x = constrain(x, "batch", "seq", "embed")
 
     def block(x, layer):
-        h = _norm(x, layer["attn_norm"], layer.get("attn_norm_b"), cfg.norm)
+        h = apply_norm(x, layer["attn_norm"], layer.get("attn_norm_b"),
+                       cfg.norm)
         h = h.astype(cfg.dtype)
         q = jnp.einsum("bsd,dhk->bhsk", h, layer["wq"].astype(cfg.dtype))
         k = jnp.einsum("bsd,dhk->bhsk", h, layer["wk"].astype(cfg.dtype))
@@ -183,20 +183,21 @@ def apply(params, tokens, cfg: MoEConfig, mesh=None
         if rope is not None:
             q = apply_rope(q, *rope)
             k = apply_rope(k, *rope)
-        q = _constrain(q, "batch", "heads", "seq", "head_dim")
-        k = _constrain(k, "batch", "heads", "seq", "head_dim")
-        v = _constrain(v, "batch", "heads", "seq", "head_dim")
-        o = _attention_op(q, k, v, cfg, mesh, allow_manual=(pp == 1))
+        q = constrain(q, "batch", "heads", "seq", "head_dim")
+        k = constrain(k, "batch", "heads", "seq", "head_dim")
+        v = constrain(v, "batch", "heads", "seq", "head_dim")
+        o = attention_op(q, k, v, cfg, mesh, allow_manual=(pp == 1))
         att = jnp.einsum("bhsk,hkd->bsd", o, layer["wo"].astype(cfg.dtype))
         x = x + att
-        h2 = _norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"), cfg.norm)
+        h2 = apply_norm(x, layer["mlp_norm"], layer.get("mlp_norm_b"),
+                        cfg.norm)
         m, aux, z = _moe_op(h2.astype(cfg.dtype),
                             layer["router"].astype(cfg.dtype),
                             layer["w_in"].astype(cfg.dtype),
                             layer["w_out"].astype(cfg.dtype), cfg, mesh,
                             allow_manual=(pp == 1))
         x = x + m
-        return _constrain(x, "batch", "seq", "embed"), aux, z
+        return constrain(x, "batch", "seq", "embed"), aux, z
 
     def scan_body(carry, layer):
         x, aux_sum, z_sum = carry
@@ -237,12 +238,13 @@ def apply(params, tokens, cfg: MoEConfig, mesh=None
         zero = jnp.zeros((), jnp.float32)
         (x, aux_sum, z_sum), _ = jax.lax.scan(
             scan_body, (x, zero, zero), params["layers"])
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg.norm)
+    x = apply_norm(x, params["final_norm"], params.get("final_norm_b"),
+                   cfg.norm)
     unembed = (params["embed"].T if cfg.tie_embeddings
                else params["unembed"]).astype(cfg.dtype)
     logits = jnp.einsum("bsd,dv->bsv", x.astype(cfg.dtype), unembed)
     losses = {"aux": aux_sum / cfg.n_layers, "z": z_sum / cfg.n_layers}
-    return _constrain(logits, "batch", "seq", "vocab"), losses
+    return constrain(logits, "batch", "seq", "vocab"), losses
 
 
 def loss_fn(params, batch, cfg: MoEConfig, mesh=None):

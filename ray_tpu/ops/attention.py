@@ -868,7 +868,7 @@ def attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
 
     The Pallas kernel is a Mosaic custom call, which GSPMD cannot
     partition: on sharded arrays call it from inside a shard_map
-    (models/gpt.py `_attention_op` does).
+    (models/gpt.py `attention_op` does).
     """
     sq, sk = q.shape[-2], k.shape[-2]
     impl = resolve_impl(impl, sq, sk, causal)

@@ -243,3 +243,17 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
     out = jax.lax.fori_loop(0, -(-n_held // M), one_tile,
                             jnp.zeros((N, D), jnp.float32))
     return out, loads
+
+
+def held_load_stats(loads) -> list:
+    """What a serve program reports of its expert layers' `loads` (a list
+    of `held_expert_ffn`'s, one a layer): [token-expert pairs that fell on
+    held experts, the largest load of a held expert summed over the layers,
+    held experts touched], f32 scalars in the order the models' STEP_STATS
+    name them (`moe_pairs`, `moe_load_max`, `moe_touched`); zeros where no
+    layer routes."""
+    if not loads:
+        return [jnp.zeros(())] * 3
+    ld = jnp.stack(loads).astype(jnp.float32)              # [L_moe, held]
+    return [ld.sum(), ld.max(axis=1).sum(),
+            (ld > 0).sum().astype(jnp.float32)]

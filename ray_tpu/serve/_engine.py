@@ -28,11 +28,28 @@ step as empty; nothing is admitted behind it until it decodes (first
 come, first served).  Prompts no longer than a chunk keep the schedule
 above.
 
-The engine serves whatever module implements its interface
-(`cache_kinds`, `init_paged_cache`, `paged_decode_step`, `paged_prefill`,
-`copy_page` where pages are shared, `state_leaves` where a kind is a state;
-models/gpt.py, models/cohere2_moe.py, models/brumby.py,
-models/deepseek_v3.py, models/ling3.py).
+The model interface
+-------------------
+The engine serves whatever module has these members (`_check_interface`
+holds it to the list when an engine is made;
+tests/test_serve_model_interface.py asks every served module the same
+questions) and whose config has `max_seq`, `vocab_size`, `dtype`, `pos`:
+
+  `cache_kinds(cfg)` -> {kind: None | window | "state"}
+  `init_paged_cache(cfg, {kind: pages}, page_size)` -> the cache
+  `paged_decode_step(params, cache, tokens [B], {kind: tables [B, W]},
+      pos [B], cfg)` -> (logits [B, V], cache[, stats])
+  `paged_prefill(params, cache, toks [T], {kind: table row [W]}, start,
+      last_idx, cfg)` -> (logits [V], cache[, stats])
+  `serve_view(params, cfg)` -> the tree the two programs are handed (a
+      view's view is that view)
+  where a kind is "state": `state_leaves(cache)` -> its arena's leaves
+  where every kind keeps every position (pages are shared only then):
+      `copy_page(cache, dst, src)` -> the cache
+  optional: `STEP_STATS`, the names of the f32 vector the two programs
+      return third; `step_kv_read(cfg, pos, page_size, max_pages)` ->
+      (key positions a step reads, the tables' span), on the host
+
 A model's layers may keep several **kinds of KV state**: `cache_kinds`
 names them with their window, and the engine keeps a pool of pages, an
 allocator and a page table per kind.  A full kind's pages are taken at
@@ -182,10 +199,6 @@ def _m_gauge(which: str):
         "active": ("ray_tpu_serve_active_slots", "Occupied decode slots"),
         "queue": ("ray_tpu_serve_queue_depth", "Waiting (unadmitted) requests"),
         "free_pages": ("ray_tpu_serve_free_pages", "Free KV-cache pages"),
-        "chunk_attn_kernel": (
-            "ray_tpu_serve_chunk_attn_kernel_share",
-            "Share of prefill chunk launches whose attention took the "
-            "Pallas block kernel"),
     }
     name, desc = names[which]
     return _metric(which, lambda: mm.Gauge(name, description=desc))
@@ -394,6 +407,21 @@ class _Sequence:
         self.prefilling = False     # holds a slot, its rows not the step's
 
 
+def _check_interface(mod, cfg) -> None:
+    """Hold a model module to the module docstring's "model interface":
+    a TypeError that names the module and the first member it lacks."""
+    kinds = getattr(mod, "cache_kinds", lambda cfg: {})(cfg).values()
+    for name in ("cache_kinds", "init_paged_cache", "paged_decode_step",
+                 "paged_prefill", "serve_view",
+                 *(["state_leaves"] if "state" in kinds else []),
+                 *(["copy_page"] if all(w is None for w in kinds) else [])):
+        if not callable(getattr(mod, name, None)):
+            raise TypeError(
+                f"{getattr(mod, '__name__', mod)} does not implement the "
+                f"serving engine's model interface: it has no `{name}` "
+                "(ray_tpu/serve/_engine.py, \"The model interface\")")
+
+
 class ContinuousEngine:
     """Per-replica continuous-batching scheduler (one per model)."""
 
@@ -408,6 +436,7 @@ class ContinuousEngine:
         import jax
         import numpy as np
 
+        _check_interface(gpt_mod, cfg)
         self._jax, self._np, self._gpt = jax, np, gpt_mod
         # the programs read the model's serve view of the caller's tree,
         # made here once (gpt_mod.serve_view: the leaves they would cast
@@ -489,11 +518,6 @@ class ContinuousEngine:
         # sampler draws (the others take the argmax and nothing else):
         # read off the host's `_temps`, no operand and no fetch
         self._stat_keys += ("sampled_steps",)
-        # a model whose chunks attend through ops.attention's
-        # streamed_attention hands on the predicate that function picks
-        # its body by (`chunk_attn_kernel(rows)` of its module): a chunk
-        # launch is stamped with it, no program's text is touched
-        self._chunk_attn = getattr(gpt_mod, "chunk_attn_kernel", None)
         self._fns: Dict[Any, Any] = {}   # bounded by construction: one
         # step program + one prefill per padded-length bucket + setrow +
         # copy_page
@@ -528,7 +552,6 @@ class ContinuousEngine:
         self._totals = {"requests": 0, "rejected": 0, "tokens": 0,
                         "steps": 0, "prefills": 0, "cow_copies": 0,
                         "shared_pages": 0, "chunks": 0,
-                        "chunk_attn_kernel": 0,
                         "window_pages_returned": 0,
                         # cumulative sums of the ring's records, so two
                         # engine_stats() snapshots give shares over any
@@ -559,7 +582,6 @@ class ContinuousEngine:
         self._first: List[_Sequence] = []   # first token this iteration
         self._chunks = 0             # prefill programs run
         self._chunk_tokens = 0       # prompt tokens they computed
-        self._chunk_kernel = 0       # of them, attention took the kernel
         self._returned = 0           # window pages returned
         self._stats: Dict[str, float] = {}  # the programs' own counters
 
@@ -689,15 +711,7 @@ class ContinuousEngine:
             **self._param_stats,
             **self._state_stats(),
             **totals,
-            **({"chunk_attn_kernel_share": self._chunk_share(totals)}
-               if self._chunk_attn else {}),
         }
-
-    def _chunk_share(self, totals=None) -> float:
-        """Of the prefill chunks launched so far, the share whose
-        attention took the block kernel (1.0 on a TPU, 0.0 elsewhere)."""
-        totals = totals or self._totals
-        return totals["chunk_attn_kernel"] / max(1, totals["chunks"])
 
     def _state_stats(self) -> Dict[str, int]:
         """A model with a state kind: its entries in use and free, and
@@ -927,7 +941,6 @@ class ContinuousEngine:
         self._blocked = 0
         self._first = []
         self._chunks = self._chunk_tokens = self._returned = 0
-        self._chunk_kernel = 0
         self._stats = dict.fromkeys(self._stat_keys, 0.0)
         # enqueue: the admission's programs, the step behind them ...
         with ann("serve.engine.admit", iter=self._iter):
@@ -958,8 +971,6 @@ class ContinuousEngine:
                    "blocked_slots": self._blocked,
                    "chunks": self._chunks,
                    "chunk_tokens": self._chunk_tokens,
-                   **({"chunk_attn_kernel": self._chunk_kernel}
-                      if self._chunk_attn else {}),
                    "pages_returned": self._returned,
                    **{"pages_" + k: a.used_pages
                       for k, a in self._allocs.items()},
@@ -981,11 +992,8 @@ class ContinuousEngine:
                     m.observe(it["dispatch_s"], tags={"phase": "dispatch"})
             with self._lock:
                 qd = len(self._waiting)
-            gauges = [("active", stepped), ("queue", qd),
-                      ("free_pages", self._alloc.free_pages)]
-            if self._chunk_attn and self._chunks:
-                gauges.append(("chunk_attn_kernel", self._chunk_share()))
-            for which, val in gauges:
+            for which, val in (("active", stepped), ("queue", qd),
+                               ("free_pages", self._alloc.free_pages)):
                 g = _m_gauge(which)
                 if g:
                     g.set(val)
@@ -1212,9 +1220,6 @@ class ContinuousEngine:
         self._chunks += 1
         self._chunk_tokens += n
         self._totals["chunks"] += 1
-        if self._chunk_attn and self._chunk_attn(T):
-            self._chunk_kernel += 1
-            self._totals["chunk_attn_kernel"] += 1
         self._shrink_windows(seq, seq.next_start)
         if not last:
             self._prefilling = seq

@@ -78,8 +78,9 @@ class _LLMServerImpl:
         else:
             self._params = gpt.init(jax.random.PRNGKey(0), self._cfg)
         # a loaded config is served by its own module where that module
-        # implements the engine's interface (models/cohere2_moe.py beside
-        # models/gpt.py): which model runs is the loader's data
+        # has the engine's paged programs (serve/_engine.py, "The model
+        # interface"; the engine holds it to the rest when it is made):
+        # which model runs is the loader's data
         mod = sys.modules.get(type(self._cfg).__module__)
         if hasattr(mod, "paged_decode_step"):
             self._gpt = mod

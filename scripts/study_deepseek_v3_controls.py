@@ -72,13 +72,13 @@ def faulty(loader, variant):
         from ray_tpu.models import deepseek_v3 as dm
 
         if variant == "latent_8bit":
-            rows = dm._latent_rows
+            rows = dm.latent_rows
 
             def rounded(h, layer, pos, cfg):
                 r = rows(h, layer, pos, cfg)
                 return _fp8(r.astype(jnp.float32)).astype(r.dtype)
 
-            dm._latent_rows = rounded
+            dm.latent_rows = rounded
         else:
             prefill = dm.paged_prefill
 

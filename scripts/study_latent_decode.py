@@ -7,7 +7,7 @@ On the chip, one process: one latent layer's attention of a step of
 over an arena of 4,353 pages of 128 positions, bf16) with the live slots
 the cell holds (3 streams of 7-15k keys; 1; and all 32 at 4k), and of
 `serve-ling3flash-reasoning` (64 slots, 144 pages wide).  The XLA body
-is `_streamed_xla` fed as `deepseek_v3._page_io` feeds it: every slot,
+is `_streamed_xla` fed as `deepseek_v3.page_io` feeds it: every slot,
 every block of 4 pages up to the longest live context, gathered and
 transposed.  Each body is jitted as `--reps` calls in a row, each call's
 queries a function of the last one's result, run once and then timed

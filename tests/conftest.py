@@ -81,7 +81,8 @@ _QUICK_FILES = {
     "test_serve.py", "test_serve_continuous.py", "test_serve_donation.py",
     "test_serve_fault.py",
     "test_serve_prefill.py", "test_serve_live_blocks.py",
-    "test_serve_mixed_pools.py", "test_serve_state_kind.py",
+    "test_serve_mixed_pools.py", "test_serve_model_interface.py",
+    "test_serve_state_kind.py",
     "test_serve_weights_view.py",
     "test_serve_grpc.py",
     "test_state.py", "test_streamed_attention.py",

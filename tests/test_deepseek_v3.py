@@ -275,7 +275,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(model):
     layer["router_bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(6),
                                                     (16,))
     h = jax.random.normal(jax.random.PRNGKey(7), (24, cfg.d_model))
-    full, loads = dm._ffn(h, layer, whole)
+    full, loads = dm.layer_ffn(h, layer, whole)
     assert int(loads.sum()) == 24 * cfg.top_k
     shared = dm.swiglu(h, layer["shared_gate"], layer["shared_up"],
                        layer["shared_down"])
@@ -284,7 +284,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(model):
         share = dataclasses.replace(cfg, experts_first=first, experts_held=4)
         part = dict(layer, **{k: layer[k][first:first + 4]
                               for k in ("wg", "wu", "wd")})
-        out, _ = dm._ffn(h, part, share)
+        out, _ = dm.layer_ffn(h, part, share)
         total = total + (out - shared)
     np.testing.assert_allclose(total, full, atol=TOL, rtol=0)
     want = ref.feed_forward(h, layer, _sizes(whole))

@@ -228,7 +228,7 @@ def test_four_chips_shares_add_up_to_the_uncut_layer(model):
                                    experts_held=held)
         mine = dict(lp, **{n: lp[n][chip * held:(chip + 1) * held]
                            for n in ("wg", "wu", "wd")})
-        out, loads = dm._ffn(h, mine, part)
+        out, loads = dm.layer_ffn(h, mine, part)
         total = total + np.asarray(out)
         pairs += float(loads.sum())
     assert pairs == 24 * cfg.top_k            # every pair fell on one chip
@@ -256,5 +256,3 @@ def test_the_view_and_the_cache_say_what_they_hold(model):
     assert cache["tail"].shape == (3, 3, 3, 3 * H, d)
     assert sum(a.nbytes for a in lm.state_leaves(cache)) == (
         3 * 3 * (H * d * d * 4 + 3 * 3 * H * d * 4))
-    moved = lm.copy_page(cache, 2, 1)
-    assert moved["state"] is cache["state"]

@@ -612,9 +612,11 @@ def test_sampled_steps_counts_the_steps_that_draw(model, temps,
                            max_new_tokens=6 if t else 24)
                 for i, t in enumerate(temps)]
         outs = [eng.collect(s, timeout=120)["completion"] for s in seqs]
-        stats, ring = eng.engine_stats(), eng.phase_ring()
     finally:
         eng.stop()
+    # read once the loop has closed the iteration that emitted the last
+    # token (stop joins it): its record and sums come after the emit
+    stats, ring = eng.engine_stats(), eng.phase_ring()
     assert [len(o) for o in outs] == [6 if t else 24 for t in temps]
     assert stats["sampled_steps"] == sampled_steps
     assert stats["steps"] >= (24 if 0.0 in temps else 6)

@@ -1,8 +1,9 @@
 """The paged serve programs' attention (models/gpt.py _paged_attention):
 the keys are read block by block where the pages stand, as far as the
 contexts are live, and folded into an online softmax.  Held here to the
-plain recipe — the contiguous cache's `slot_decode_step`, every position
-scored at once under the mask — walked token by token.
+plain recipe — the contiguous cache's `slot_decode_step`
+(tests/slot_reference.py), every position scored at once under the mask —
+walked token by token.
 
 In-process and on the CPU, f32 `nano` as tests/test_serve_prefill.py;
 `kv_block` 32 (4 pages of 8) so that a table of 16 pages is four blocks.
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gpt
 from ray_tpu.serve._engine import ContinuousEngine
+from slot_reference import slot_decode_step
 from ray_tpu.telemetry import device as devtel
 
 PS, MAXP, NUM_PAGES, BLOCK = 8, 16, 40, 32
@@ -41,7 +43,7 @@ def _tokens(n, seed):
     return np.random.default_rng(seed).integers(1, 250, n).astype(np.int32)
 
 
-_slot_step = jax.jit(gpt.slot_decode_step, static_argnames="cfg")
+_slot_step = jax.jit(slot_decode_step, static_argnames="cfg")
 _paged_step = jax.jit(gpt.paged_decode_step, static_argnames="cfg")
 _paged_prefill = jax.jit(gpt.paged_prefill, static_argnames="cfg")
 

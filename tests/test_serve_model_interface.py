@@ -1,0 +1,149 @@
+"""The serving engine's model interface (ray_tpu/serve/_engine.py, "The
+model interface"): every served module is asked the same questions, and a
+module that lacks a member is refused when an engine is made — by name,
+before a weight is cast or a request admitted.
+
+Shapes alone (`jax.eval_shape` at the `nano` sizes): nothing is compiled.
+"""
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import brumby, cohere2_moe, deepseek_v3, gpt, ling3
+from ray_tpu.serve._engine import ContinuousEngine, _check_interface
+
+MODELS = {
+    "gpt2": (gpt, gpt.GPTConfig.nano()),
+    "command-a-plus": (cohere2_moe, cohere2_moe.Cohere2MoEConfig.nano()),
+    "brumby": (brumby, brumby.BrumbyConfig.nano()),
+    "deepseek-v3": (deepseek_v3, deepseek_v3.DeepSeekV3Config.nano()),
+    "ling-3": (ling3, ling3.Ling3Config.nano()),
+}
+REQUIRED = ("cache_kinds", "init_paged_cache", "paged_decode_step",
+            "paged_prefill", "serve_view")
+SLOTS, PAGE, PAGES, ROWS = 4, 8, 9, 16
+
+model = pytest.mark.parametrize("name", sorted(MODELS))
+
+
+def _without(mod, *members):
+    """`mod`'s members in a stub module, less `members`."""
+    return types.SimpleNamespace(
+        **{k: v for k, v in vars(mod).items() if k not in members})
+
+
+def _is_state(w):
+    return w == "state"
+
+
+def _is_full(w):
+    return w is None
+
+
+def _operands(mod, cfg):
+    """(view, kinds, cache, table widths) as an engine of SLOTS slots and
+    PAGE positions a page makes them, as shapes."""
+    view = jax.eval_shape(
+        lambda: mod.serve_view(mod.init(jax.random.PRNGKey(0), cfg), cfg))
+    kinds = mod.cache_kinds(cfg)
+    cache = jax.eval_shape(
+        lambda: mod.init_paged_cache(cfg, {k: PAGES for k in kinds}, PAGE))
+    widths = {k: 1 if _is_state(w) else
+              (cfg.max_seq if _is_full(w) else w + ROWS) // PAGE
+              for k, w in kinds.items()}
+    return view, kinds, cache, widths
+
+
+def _shapes(tree):
+    return jax.tree.map(lambda a: (a.shape, a.dtype), tree)
+
+
+@model
+def test_a_served_module_passes_the_check(name):
+    assert _check_interface(*MODELS[name]) is None
+
+
+@model
+@pytest.mark.parametrize("member", REQUIRED)
+def test_a_missing_member_is_named_when_the_engine_is_made(name, member):
+    mod, cfg = MODELS[name]
+    with pytest.raises(TypeError, match=f"{mod.__name__}.*`{member}`"):
+        ContinuousEngine(_without(mod, member), cfg, None)
+
+
+@model
+def test_what_the_kinds_ask_for_is_required_and_nothing_else(name):
+    mod, cfg = MODELS[name]
+    kinds = mod.cache_kinds(cfg)
+    assert kinds and all(
+        _is_full(w) or _is_state(w) or (type(w) is int and w > 0)
+        for w in kinds.values())
+    # `state_leaves` iff a kind is a state, `copy_page` iff every kind
+    # keeps every position: the engine calls each only then
+    has_state = any(map(_is_state, kinds.values()))
+    shares = all(map(_is_full, kinds.values()))
+    assert hasattr(mod, "state_leaves") == has_state
+    assert hasattr(mod, "copy_page") == shares
+    for member, asked in (("state_leaves", has_state), ("copy_page", shares)):
+        if asked:
+            with pytest.raises(TypeError, match=f"`{member}`"):
+                ContinuousEngine(_without(mod, member), cfg, None)
+        else:
+            _check_interface(_without(mod, member), cfg)
+
+
+@model
+def test_the_cache_is_made_from_the_engines_page_counts(name):
+    mod, cfg = MODELS[name]
+    _, kinds, cache, _ = _operands(mod, cfg)
+    leaves = jax.tree.leaves(cache)
+    assert leaves and all(isinstance(a, jax.ShapeDtypeStruct) for a in leaves)
+    if hasattr(mod, "state_leaves"):
+        state = mod.state_leaves(cache)
+        assert state and all(any(a is b for b in leaves) for a in state)
+        # a model all of whose kinds are states keeps nothing else
+        assert (len(state) == len(leaves)) == all(
+            map(_is_state, kinds.values()))
+    if hasattr(mod, "copy_page"):
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        assert _shapes(jax.eval_shape(mod.copy_page, cache, i32, i32)) == \
+            _shapes(cache)
+
+
+@model
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_a_program_returns_logits_the_cache_and_its_stats(name, program):
+    mod, cfg = MODELS[name]
+    view, _, cache, widths = _operands(mod, cfg)
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "step":
+        logits, after, *stats = jax.eval_shape(
+            lambda p, c, t, tabs, pos: mod.paged_decode_step(
+                p, c, t, tabs, pos, cfg),
+            view, cache, ints(SLOTS),
+            {k: ints(SLOTS, w) for k, w in widths.items()}, ints(SLOTS))
+        assert logits.shape == (SLOTS, cfg.vocab_size)
+    else:
+        logits, after, *stats = jax.eval_shape(
+            lambda p, c, t, tabs, s, l: mod.paged_prefill(
+                p, c, t, tabs, s, l, cfg),
+            view, cache, ints(ROWS), {k: ints(w) for k, w in widths.items()},
+            ints(), ints())
+        assert logits.shape == (cfg.vocab_size,)
+    # the engine donates the cache: what comes back takes its place
+    assert _shapes(after) == _shapes(cache)
+    names = getattr(mod, "STEP_STATS", ())
+    assert [(s.shape, s.dtype) for s in stats] == (
+        [((len(names),), jnp.float32)] if names else [])
+
+
+@model
+def test_the_view_of_a_view_is_the_view(name):
+    mod, cfg = MODELS[name]
+    view = _operands(mod, cfg)[0]
+    again = jax.eval_shape(lambda: mod.serve_view(mod.serve_view(
+        mod.init(jax.random.PRNGKey(0), cfg), cfg), cfg))
+    assert jax.tree.structure(again) == jax.tree.structure(view)
+    assert _shapes(again) == _shapes(view)

@@ -1,7 +1,7 @@
-"""The serve engine's prefill program (models/gpt.py paged_prefill /
-slot_prefill): one pass of the padded chunk through the layers, held
-against a token-by-token walk that the tests build from the decode
-steps.
+"""The serve engine's prefill program (models/gpt.py paged_prefill, and
+the contiguous reference's slot_prefill, tests/slot_reference.py): one
+pass of the padded chunk through the layers, held against a
+token-by-token walk that the tests build from the decode steps.
 
 In-process and on the CPU, f32 `nano` as tests/test_serve_continuous.py:
 a chunk-wide matmul moves an f32 logit by ~1e-6, so the comparisons
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gpt
 from ray_tpu.serve._engine import ContinuousEngine
+from slot_reference import slot_decode_step, slot_prefill
 from ray_tpu.telemetry import device as devtel
 
 PS, MAXP, NUM_PAGES, SLOTS = 8, 8, 24, 3
@@ -45,7 +46,7 @@ def _prompt(n, seed=1):
 
 
 _paged_step = jax.jit(gpt.paged_decode_step, static_argnames="cfg")
-_slot_step = jax.jit(gpt.slot_decode_step, static_argnames="cfg")
+_slot_step = jax.jit(slot_decode_step, static_argnames="cfg")
 
 
 def _walk(model, cache, toks, start, row=None):
@@ -112,7 +113,7 @@ def test_one_pass_prefill_matches_token_walk(model, layout, case):
         cache = {s: cache[s].at[:, slot].set(one[s][:, 0])
                  for s in ("k", "v")}
         want_logits, want = _walk(model, one, toks, start)
-        got_logits, got = gpt.slot_prefill(
+        got_logits, got = slot_prefill(
             params, cache, _pad(toks, bucket), jnp.int32(start),
             jnp.int32(count - 1), jnp.int32(slot), cfg)
         for s in ("k", "v"):                   # the frozen slots

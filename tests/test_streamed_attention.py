@@ -114,10 +114,6 @@ def test_predicate_is_rows_and_platform():
     assert uses(512, "tpu") and uses(128, "tpu")
     assert not uses(1, "tpu") and not uses(127, "tpu")
     assert not uses(512, "cpu") and not uses(512, "gpu")
-    # the models hand the engine the same function
-    from ray_tpu.models import cohere2_moe, deepseek_v3
-    assert cohere2_moe.chunk_attn_kernel is uses
-    assert deepseek_v3.chunk_attn_kernel is uses
 
 
 def _two_layers(rows, B=1):
@@ -201,67 +197,6 @@ def test_a_model_chunk_program_holds_the_kernel_once(monkeypatch):
     assert "custom_call" not in step and "_streamed_block" not in step
 
 
-def test_a_chunk_launch_is_stamped_with_the_predicate(monkeypatch):
-    """The engine asks the model's module (`chunk_attn_kernel`) at every
-    prefill program it launches: the ring record of an iteration that ran
-    chunks counts those whose attention took the kernel (none on the CPU),
-    `engine_stats()` gives the share, and a model whose chunks do not
-    attend through streamed_attention carries neither."""
-    import threading
-
-    from ray_tpu.models import cohere2_moe as cm
-    from ray_tpu.models import gpt
-    from ray_tpu.serve._engine import ContinuousEngine
-
-    def by_hand(eng):
-        t = threading.Thread(target=lambda: None)
-        t.start()
-        t.join()
-        eng._thread = t
-        return eng
-
-    def serve(eng):
-        seq = eng.submit(list(range(1, 22)), 2)
-        for _ in range(50):
-            eng._iteration()
-            if seq.result.done():
-                break
-        assert seq.result.done()
-        return [r for r in eng.phase_ring() if r["chunks"]]
-
-    cfg = cm.Cohere2MoEConfig.nano(dtype=jnp.float32,
-                                   param_dtype=jnp.float32)
-    kw = dict(max_slots=2, page_size=4, max_total=64, prefill_bucket=4,
-              prefill_chunk=8)
-    eng = by_hand(ContinuousEngine(cm, cfg, cm.init(jax.random.PRNGKey(0),
-                                                    cfg), **kw))
-    recs = serve(eng)
-    assert sum(r["chunks"] for r in recs) == 3
-    assert all(r["chunk_attn_kernel"] == 0 for r in recs)
-    stats = eng.engine_stats()
-    assert stats["chunks"] == 3 and stats["chunk_attn_kernel"] == 0
-    assert stats["chunk_attn_kernel_share"] == 0.0
-    # where the predicate holds (the chip: rows >= 128 there) every chunk
-    # launch counts; the stamp reads the predicate, no program is touched
-    seen = []
-    monkeypatch.setattr(eng, "_chunk_attn",
-                        lambda rows: seen.append(rows) or True)
-    recs = serve(eng)[len(recs):]
-    assert seen == [8, 8, 8]        # the rows of each prefill program
-    assert all(r["chunk_attn_kernel"] == r["chunks"] for r in recs)
-    assert eng.engine_stats()["chunk_attn_kernel_share"] == 0.5
-    eng.stop()
-
-    gcfg = gpt.GPTConfig.nano(max_seq=64, dtype=jnp.float32)
-    geng = by_hand(ContinuousEngine(
-        gpt, gcfg, gpt.init(jax.random.PRNGKey(0), gcfg), max_slots=2,
-        page_size=8, prefill_bucket=8))
-    recs = serve(geng)
-    assert recs and all("chunk_attn_kernel" not in r for r in recs)
-    assert "chunk_attn_kernel_share" not in geng.engine_stats()
-    geng.stop()
-
-
 # -- latent_decode_attention: the absorbed decode step's walk over each
 # -- live slot's own latent pages (PR 48) ------------------------------------
 
@@ -283,7 +218,7 @@ def _latent_operands(B, dtype, seed=0):
 
 def _latent_xla(q, arena, ptab, ctx, scale, v_dim):
     """The XLA body on the same operands, its blocks gathered as
-    `deepseek_v3._page_io` gathers them, every slot to the longest
+    `deepseek_v3.page_io` gathers them, every slot to the longest
     context."""
     B, H, d = q.shape
     npb = A._WALK_PAGES
@@ -460,11 +395,14 @@ def _digest(text):
                                  text).encode()).hexdigest()[:16]
 
 
-# a program's `_digest`, lowered for the platform "tpu", at the parent
-# commit of PR 48 (67e7002): the programs that call nothing PR 48 changed
-# keep their text (and, their kernels' source lines unmoved, their
-# compile-cache keys).  A PR that edits one of these programs finds the
-# new digest in the failure and pins it.
+# a program's `_digest`, lowered for the platform "tpu": every served
+# model's step and chunk program.  `deepseek-v3`'s two and `ling-3`'s step
+# were pinned at d07b063 (the parent of PR 50), the other seven at 67e7002
+# (the parent of PR 48).  A PR that moves or renames Python functions
+# leaves every digest alone (the text carries no source locations; their
+# kernels' source lines unmoved, the compile-cache keys stay too).  A PR
+# that edits one of these programs finds the new digest in the failure and
+# pins it.
 PARENT_TEXT = {
     ("gpt2", "step"): "1921a8ec3c8503f9",
     ("gpt2", "chunk"): "74eee7fd9dc8f6cb",
@@ -472,6 +410,9 @@ PARENT_TEXT = {
     ("command-a-plus", "chunk"): "f067a09b3c247b56",
     ("brumby", "step"): "c95c739da7c26ef7",
     ("brumby", "chunk"): "634ac703009d25eb",
+    ("deepseek-v3", "step"): "567ab1b634a4d1ff",
+    ("deepseek-v3", "chunk"): "8fe296d520a4a6c7",
+    ("ling-3", "step"): "e01e8b44bc0ed1d5",
     ("ling-3", "chunk"): "85bf7fa576820a74",
 }
 
