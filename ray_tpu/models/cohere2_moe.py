@@ -322,6 +322,28 @@ def _paged_attend(kind, arena, tab, bases, qpos, write_at, n_blocks,
     return attend, box
 
 
+def kind_io(kind: str, tab, pos, real, last, flat_pos, ps: int, npb: int):
+    """How rows at positions pos [B, T] (`real` marks those whose K and V
+    are kept; `last` [B] their greatest, `flat_pos` [B * T] themselves)
+    meet the page table tab [B, R] of one kind ("full", or a ring): (the
+    table padded to whole blocks of `npb` pages, its entries' bases, the
+    (page, offset) each row is written at — the null page for a row that
+    is not kept —, the key blocks to stream)."""
+    R = tab.shape[1]
+    width = -(-R // npb) * npb
+    tabp = jnp.pad(tab, ((0, 0), (0, width - R)))
+    lp = pos // ps
+    entry = lp if kind == "full" else lp % R
+    page = jnp.take_along_axis(
+        tabp, jnp.minimum(entry, width - 1), axis=1)
+    page = jnp.where(real & (entry < R), page, 0).reshape(flat_pos.shape)
+    n_blocks = (width // npb if kind != "full" else
+                jnp.minimum(jnp.max(last) // (npb * ps) + 1,
+                            width // npb))
+    return (tabp, _entry_bases(kind, tab, last, ps, width),
+            (page, flat_pos % ps), n_blocks)
+
+
 def _paged_pass(params, cache, toks, ptabs, pos, real, cfg):
     """Tokens toks [B, T] at positions pos [B, T] through the layers
     against the paged cache; `real` [B, T] marks the rows whose K and V are
@@ -333,21 +355,8 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg):
     x = slot_embed(params, toks, pos, cfg)
     last = jnp.max(pos, axis=1)                            # [B]
     flat_pos = pos.reshape(B * T)
-    per_kind = {}
-    for kind, tab in ptabs.items():
-        R = tab.shape[1]
-        width = -(-R // npb) * npb
-        tabp = jnp.pad(tab, ((0, 0), (0, width - R)))
-        lp = pos // ps
-        entry = lp if kind == "full" else lp % R
-        page = jnp.take_along_axis(
-            tabp, jnp.minimum(entry, width - 1), axis=1)
-        page = jnp.where(real & (entry < R), page, 0).reshape(B * T)
-        n_blocks = (width // npb if kind != "full" else
-                    jnp.minimum(jnp.max(last) // (npb * ps) + 1,
-                                width // npb))
-        per_kind[kind] = (tabp, _entry_bases(kind, tab, last, ps, width),
-                          (page, flat_pos % ps), n_blocks)
+    per_kind = {kind: kind_io(kind, tab, pos, real, last, flat_pos, ps, npb)
+                for kind, tab in ptabs.items()}
     new_cache, loads = [], []
     for layer, kind, arena in zip(params["layers"], cfg.layer_types, cache):
         tabp, bases, write_at, n_blocks = per_kind[kind]

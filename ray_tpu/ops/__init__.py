@@ -6,6 +6,7 @@ from .layers import (apply_rope, apply_rope_halves, apply_rope_interleaved,
                      layer_norm, rms_norm, rope_table,
                      softmax_cross_entropy, swiglu, yarn_frequencies)
 from .kda import kda_chunk, kda_step
+from .mamba import selective_scan_chunk, selective_step
 from .quantize import (dequantize_blockwise, quantization_error,
                        quantize_blockwise)
 from .retention import retention_chunk, retention_step
@@ -20,6 +21,7 @@ __all__ = [
     "ring_attention", "ring_attention_sharded",
     "ulysses_attention", "ulysses_attention_sharded",
     "retention_chunk", "retention_step", "kda_chunk", "kda_step",
+    "selective_scan_chunk", "selective_step",
     "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_halves",
     "apply_rope_interleaved", "yarn_frequencies", "swiglu",
     "gelu_mlp", "softmax_cross_entropy", "fused_softmax_cross_entropy",

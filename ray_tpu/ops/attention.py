@@ -1253,3 +1253,192 @@ def latent_decode_attention(q, arena, ptab, ctx, *, scale: float,
     return _latent_decode(jnp.full((1,), scale, jnp.float32), q, arena,
                           ptab.astype(jnp.int32), ctx.astype(jnp.int32),
                           v_dim=v_dim, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention: a decode step's attention over pages of keys and
+# values a head wide, a walk of each live slot's own pages and of nothing
+# else (`latent_decode_attention`'s scheme for a cache with a K and a V side)
+# ---------------------------------------------------------------------------
+
+_ROWS = 8           # query rows a K/V head brings, padded to whole sublanes
+
+
+def _paged_decode_kernel(tab_ref, base_ref, qpos_ref, walk_ref, window_ref,
+                         scale_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                         sem, acc_ref, *, heads: int, width: int):
+    """One slot's walk.  A page [ps, heads * d] holds a position a row, a
+    head's keys d lanes of it: a block of pages lies one under the other
+    in `kbuf` / `vbuf` [2, S, heads * d], a head's scores are q [8, d] .
+    block [S, d] contracted over d, and its weighted sum p [8, S] . block
+    [S, d].  `width`: entries of a slot's row of the (flattened) table and
+    of its bases."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    _, S, hd = kbuf.shape
+    d = hd // heads
+    ps = k_hbm.shape[1]
+    npb = S // ps
+    qpos, window = qpos_ref[b], window_ref[0]
+    n_blocks = (walk_ref[b] + npb - 1) // npb
+
+    # what a DMA has not filled must still be finite: a key the query does
+    # not see is hidden from the scores, and its p = 0 meets its value
+    @pl.when(b == 0)
+    def _():
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+
+    def base_of(at):
+        """The first position entry `at` of the slot's row holds; below 0
+        where it holds nothing the query sees (no page, every position
+        past the query or a window or more behind it)."""
+        base = base_ref[b * width + jnp.minimum(at, width - 1)]
+        seen = ((at < width) & (base >= 0) & (base <= qpos)
+                & (qpos - (base + ps - 1) < window))
+        return jnp.where(seen, base, -1)
+
+    def pages(blk, half, act):
+        """`act` (start, or wait for) the copies of each page of block
+        `blk` that the query sees into half `half` of the buffers."""
+        for j in range(npb):
+            at = blk * npb + j
+
+            @pl.when(base_of(at) >= 0)
+            def _():
+                page = tab_ref[b * width + at]
+                rows = pl.ds(j * ps, ps)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[page], kbuf.at[half, rows], sem.at[0, half]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[page], vbuf.at[half, rows], sem.at[1, half]))
+
+    @pl.when(n_blocks == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        pages(0, 0, lambda cp: cp.start())
+        within = jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+
+        def block(blk, carry):
+            m, l = carry                                  # [heads * 8, 1]
+            half = blk % 2
+
+            @pl.when(blk + 1 < n_blocks)
+            def _():
+                pages(blk + 1, 1 - half, lambda cp: cp.start())
+
+            pages(blk, half, lambda cp: cp.wait())
+            # the block's key positions, page by page; a page that was not
+            # fetched lies past every query
+            kpos = jnp.concatenate([
+                jnp.where(base_of(blk * npb + j) >= 0,
+                          base_of(blk * npb + j) + within, _NO_WINDOW)
+                for j in range(npb)], axis=1)             # [1, S]
+            # 0 <= qpos - kpos < window is ONE unsigned comparison
+            seen = (jax.lax.bitcast_convert_type(qpos - kpos, jnp.uint32)
+                    < window.astype(jnp.uint32))
+            ms, ls = [], []
+            for h in range(heads):
+                rows, lanes = slice(h * _ROWS, (h + 1) * _ROWS), slice(
+                    h * d, (h + 1) * d)
+                k, v = kbuf[half, :, lanes], vbuf[half, :, lanes]
+                s = _dot(q_ref[0, rows], k, _NT) * scale_ref[0]   # [8, S]
+                s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+                m_new = jnp.maximum(m[rows], jnp.max(s, axis=1,
+                                                     keepdims=True))
+                # a row that has seen no key yet (m_new still the mask's
+                # value: p = 1 throughout) is taken out a row at a time
+                any_seen = (m_new > DEFAULT_MASK_VALUE).astype(jnp.float32)
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m[rows] - m_new)
+                acc_ref[rows] = acc_ref[rows] * corr + any_seen * _dot(
+                    p.astype(v.dtype), v, _NN)
+                ms.append(m_new)
+                ls.append(l[rows] * corr
+                          + any_seen * jnp.sum(p, axis=1, keepdims=True))
+            return jnp.concatenate(ms, 0), jnp.concatenate(ls, 0)
+
+        _, l = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (jnp.full((heads * _ROWS, 1), DEFAULT_MASK_VALUE, jnp.float32),
+             jnp.zeros((heads * _ROWS, 1), jnp.float32)))
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_decode(scale, window, q, k_arena, v_arena, ptab, bases, qpos, walk,
+                  interpret=False):
+    """The kernel's one lowered function, the scale and the window
+    operands: every layer of a step program that reads pages of one shape
+    calls this one (see `_streamed_block` for what a lowering a layer costs
+    in warm set-up)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, R, d = q.shape
+    ps, hd = k_arena.shape[1:]
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, _ROWS - R), (0, 0))).reshape(
+        B, H * _ROWS, d)
+    at_slot = lambda b, *_: (b, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(B,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, H * _ROWS, d), at_slot),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H * _ROWS, d), at_slot),
+        scratch_shapes=[pltpu.VMEM((2, _WALK_PAGES * ps, hd), k_arena.dtype),
+                        pltpu.VMEM((2, _WALK_PAGES * ps, hd), v_arena.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((H * _ROWS, d), jnp.float32)])
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, heads=H, width=ptab.shape[1]),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H * _ROWS, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_decode_attention",
+    )(ptab.reshape(-1), bases.reshape(-1), qpos, walk, window, scale, q,
+      k_arena, v_arena)
+    return out.reshape(B, H, _ROWS, d)[:, :, :R]
+
+
+def paged_decode_attention(q, k_arena, v_arena, ptab, bases, qpos, walk, *,
+                           scale: float, window: Optional[int] = None,
+                           interpret: bool = False):
+    """A decode step's attention over pages of keys and values, as work in
+    proportion to what the live slots' queries see: slot b's query rows
+    q[b] [H, R, d] (R rows a K/V head, at most 8: its grouped query heads)
+    at position qpos[b] against its own pages — entry i of its table row
+    ptab[b] is a page of `k_arena` / `v_arena` [pages, page_size, H * d]
+    that holds positions bases[b, i] onward (negative: nothing), a
+    position a row, head h's keys (values) lanes h * d onward; a full
+    kind's bases run i * page_size, a windowed kind's are its ring's.
+    The query sees key s iff 0 <= qpos - s (< window, where one is given).
+    Entries 0 .. walk[b] - 1 are walked (0: an empty slot, which fetches
+    nothing and comes out zero).  Returns [B, H, R, d] in q's dtype.
+
+    `latent_decode_attention`'s walk for a cache with two sides: one
+    Pallas kernel, a grid turn a slot; of the entries walked only the
+    pages that hold a position the query sees are fetched, the kernel's
+    own double-buffered DMA copying `_WALK_PAGES` entries of each side a
+    compute block from where they lie in HBM; an online softmax across
+    the blocks keeps (m, l, acc) on the chip.  The mathematics and the
+    precision are `_streamed_xla`'s.
+
+    What it replaces: the XLA body gives every slot of the batch, empty or
+    not, every block up to the LONGEST live context (or the whole ring),
+    each block gathered first — for phi4flash's one shared cache eight
+    times a step (PERF.md section 6, PR 51)."""
+    i32 = lambda a: a.astype(jnp.int32)
+    return _paged_decode(
+        jnp.full((1,), scale, jnp.float32),
+        jnp.full((1,), _NO_WINDOW if window is None else window, jnp.int32),
+        q, k_arena, v_arena, i32(ptab), i32(bases), i32(qpos), i32(walk),
+        interpret=interpret)

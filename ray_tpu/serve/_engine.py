@@ -48,7 +48,13 @@ questions) and whose config has `max_seq`, `vocab_size`, `dtype`, `pos`:
       `copy_page(cache, dst, src)` -> the cache
   optional: `STEP_STATS`, the names of the f32 vector the two programs
       return third; `step_kv_read(cfg, pos, page_size, max_pages)` ->
-      (key positions a step reads, the tables' span), on the host
+      (key positions a step reads, the tables' span), on the host;
+      `PREFILL_KNOWS_LAST` (true): `paged_prefill` takes one operand more
+      behind `last_idx`, `is_last` (a bool scalar): whether the chunk is
+      its prompt's last, the one whose logits the engine keeps — a model
+      whose later layers write no cache (phi4flash's cross-decoder) runs
+      them there alone; a module without the name gets the operands
+      above and nothing else
 
 A model's layers may keep several **kinds of KV state**: `cache_kinds`
 names them with their window, and the engine keeps a pool of pages, an
@@ -73,7 +79,8 @@ out of: it is admitted while a slot and an entry are free, and `max_total`
 bounds its positions only.  A model may MIX a state kind with paged kinds
 (ling3: delta-rule layers beside latent attention): an admission then takes
 a slot, an entry AND its pages, waits while any of the three is missing and
-returns all of them at eviction; which leaves of the cache are the state
+returns all of them at eviction; phi4flash: a full kind, a windowed kind and
+a state — pages, a window reservation and an entry together); which leaves of the cache are the state
 arena the model says (`state_leaves(cache)`), so the entries' bytes are
 counted apart from the pages'.
 
@@ -512,6 +519,9 @@ class ContinuousEngine:
         # thread already holds (`step_kv_read` of its module: no fetch):
         # `kv_read` / `kv_span` of an iteration's record
         self._kv_read = getattr(gpt_mod, "step_kv_read", None)
+        # a model whose prefill runs part of itself on a prompt's last
+        # chunk alone is told which chunk that is (`PREFILL_KNOWS_LAST`)
+        self._tell_last = bool(getattr(gpt_mod, "PREFILL_KNOWS_LAST", False))
         if self._kv_read is not None:
             self._stat_keys += ("kv_read", "kv_span")
         # steps launched with a temperature in some slot, the ones whose
@@ -534,7 +544,7 @@ class ContinuousEngine:
         self._logits = None          # [B, V] carried across steps
         self._state_bytes = 0        # the arena of a model's state kinds
         self._entry_bytes = 0        # of it, one entry's
-        self._page_bytes = 0         # one page's, of the cache's other leaves
+        self._page_bytes: Dict[str, int] = {}   # one page's, a paged kind
 
         # host mirrors of the per-slot step operands
         B = self.max_slots
@@ -728,13 +738,12 @@ class ContinuousEngine:
         """Of a model with a state kind: the bytes its live entries hold
         (`state_bytes`) and those with the bytes of its used pages
         (`cache_bytes`; the null entry and the null pages are no one's).
-        The engine does not look inside a page: a page's bytes are the
-        paged leaves' over the pools' pages."""
+        The engine does not look inside a page: a kind's page costs what
+        the cache grows by when its pool has one page more."""
         held = self._states_live() * self._entry_bytes
-        used = sum(a.used_pages for k, a in self._allocs.items()
-                   if k not in self._state_kinds)
-        return {"state_bytes": held,
-                "cache_bytes": held + used * self._page_bytes}
+        used = sum(self._allocs[k].used_pages * n
+                   for k, n in self._page_bytes.items())
+        return {"state_bytes": held, "cache_bytes": held + used}
 
     def _states_live(self) -> int:
         return sum(self._allocs[k].used_pages for k in self._state_kinds)
@@ -810,15 +819,23 @@ class ContinuousEngine:
         snap = self._health_snap
         # a program's first compile is not a stall: the step counter
         # stands still for as long as the compiler takes, and restarting
-        # the replica would only compile again from nothing
+        # the replica would only compile again from nothing.  The clock
+        # counts from where the last such compile ENDED: a probe may not
+        # have run while it lasted (the compiler's host work kept this
+        # process from answering one for 9 s of a cold `serve.prefill:512`
+        # of 32 layers, and the next probe read 11.3 s without a step: PR
+        # 51), so it cannot be the probes that remember it
+        fns = list(self._fns.values())
         compiling = any(getattr(fn, "first_compile_in_flight", False)
-                        for fn in list(self._fns.values()))
+                        for fn in fns)
         if active == 0 or snap is None or snap[0] != steps or compiling:
-            self._health_snap = (steps, now)
-        elif now - snap[1] > self.stall_s:
+            self._health_snap = snap = (steps, now)
+        since = max([snap[1]] + [getattr(fn, "first_compile_ended", 0.0)
+                                 for fn in fns])
+        if now - since > self.stall_s:
             raise RuntimeError(
                 f"engine stalled: {active} active slots but no decode "
-                f"step for {now - snap[1]:.1f}s (> {self.stall_s:g}s)")
+                f"step for {now - since:.1f}s (> {self.stall_s:g}s)")
         for a in self._allocs.values():
             in_use = len(a._refs)
             if len(a._free) + in_use != a.num_pages - 1:
@@ -1204,7 +1221,8 @@ class ContinuousEngine:
             logits, self._cache, stats = self._launch(
                 f"serve.prefill:{T}", self._fn(("prefill", T)),
                 self._params, self._cache, chunk, tabs,
-                np.int32(start), np.int32(n - 1))
+                np.int32(start), np.int32(n - 1),
+                *([np.bool_(last)] if self._tell_last else []))
             if not seq.chunks:
                 seq.t_prefill = self._t_call
             # what no later program consumes: the row and the counters
@@ -1494,15 +1512,23 @@ class ContinuousEngine:
         # a model with a state kind says which leaves of its cache are
         # that kind's arena; the others hold its pages
         if self._state_kinds:
-            nbytes = lambda leaves: sum(int(a.nbytes) for a in leaves)
-            pools = lambda kinds: max(1, sum(self._pool_pages[k]
-                                             for k in kinds))
+            nbytes = lambda leaves: sum(
+                int(a.size) * a.dtype.itemsize for a in leaves)
             self._state_bytes = nbytes(self._gpt.state_leaves(self._cache))
-            self._entry_bytes = self._state_bytes // pools(self._state_kinds)
-            self._page_bytes = (
-                nbytes(self._jax.tree_util.tree_leaves(self._cache))
-                - self._state_bytes) // pools(
-                    k for k in self._kinds if k not in self._state_kinds)
+            self._entry_bytes = self._state_bytes // max(1, sum(
+                self._pool_pages[k] for k in self._state_kinds))
+
+            def with_pages(pools):      # the cache's bytes, as shapes
+                return nbytes(self._jax.tree_util.tree_leaves(
+                    self._jax.eval_shape(lambda: self._gpt.init_paged_cache(
+                        self._cfg, pools, self.page_size))))
+
+            # kinds of pages may differ in layers, so in a page's bytes
+            whole = with_pages(self._pool_pages)
+            self._page_bytes = {
+                k: with_pages({**self._pool_pages, k: n + 1}) - whole
+                for k, n in self._pool_pages.items()
+                if k not in self._state_kinds}
 
     def _fn(self, key):
         fn = self._fns.get(key)

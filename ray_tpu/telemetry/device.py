@@ -662,6 +662,10 @@ class InstrumentedProgram:
         self._analysis = analysis
         self._cold_calls = 0   # calls in flight that found no executable
         self._cold_lock = threading.Lock()
+        # `time.monotonic()` where the last such call returned (0.0: none
+        # yet): a liveness probe that did not run WHILE the program
+        # compiled still learns that it did
+        self.first_compile_ended = 0.0
         try:
             self._sig: Optional[inspect.Signature] = \
                 inspect.signature(wrapped if wrapped is not None else jitted)
@@ -691,6 +695,7 @@ class InstrumentedProgram:
             if cold:
                 with self._cold_lock:
                     self._cold_calls -= 1
+                    self.first_compile_ended = time.monotonic()
         if before is not None:
             try:
                 compiled_new = self._fn._cache_size() > before

@@ -53,6 +53,11 @@ REFERENCE_SIDE = ("fp8_weights", "no_groups")
 PROGRAM_SIDE = ("state_bf16", "chunk_bf16", "tail_dropped", "stale_entry",
                 "lost_chunks")
 OUT = os.path.join(ROOT, "chiprun_out", "pr47")
+# what another cell's study exchanges (scripts/study_phi4flash_controls.py
+# sets these names and `faulty`, then calls `main`): the modules of
+# benchmarks/drivers and benchmarks/lib that hold the cell's `make_loader`
+# and `reference_shape`, and the script a variant's child runs
+REPLICA, CFG, SCRIPT = "replica_ling3", "ling3cfg", os.path.abspath(__file__)
 
 
 def say(**kw):
@@ -124,9 +129,10 @@ def cell_run(variant: str, seed: int):
     """This process as `benchmarks/run.py --child`: the cell's driver, once,
     with `variant` put in from here."""
     import benchmarks.run as R
-    from benchmarks.drivers import replica_ling3 as rep
-    from benchmarks.lib import ling3cfg, manifest
+    from benchmarks.lib import manifest
 
+    rep = importlib.import_module(f"benchmarks.drivers.{REPLICA}")
+    ling3cfg = importlib.import_module(f"benchmarks.lib.{CFG}")
     cell = manifest.resolve(manifest.load(), CELL)
     rundir = os.path.join(R.RUN_DIR, f"control-{variant}")
     os.makedirs(rundir, exist_ok=True)
@@ -159,7 +165,7 @@ def cell_runs(variants, seed: int):
     env = R._child_env(argparse.Namespace(rehearse=TOY), 1)
     readings = []
     for variant in variants:
-        cmd = [sys.executable, os.path.abspath(__file__), "--cell", variant,
+        cmd = [sys.executable, SCRIPT, "--cell", variant,
                str(seed)] + (["--toy"] if TOY else [])
         t0 = time.time()
         proc = subprocess.run(cmd, env=env, cwd=ROOT, capture_output=True,

@@ -4,6 +4,12 @@ process would spend 35 s of set-up a rate; PERF.md section 4 has the table,
 PR 47).
 
     python scripts/sweep_ling3_knee.py <rate>[,<rate>...] [seed] [--toy]
+        [--cell serve-phi4flash-longgen --out pr51]
+
+`--cell` names another cell whose driver has this one's `start_cluster`,
+`warm_up` and `window` (`serve-phi4flash-longgen`, PR 51: the driver is
+found by the traffic file's `kind`), `--out` the directory under
+chiprun_out/ its table goes to.
 
 What `benchmarks/run.py`'s child does up to the warm-up, then
 `serve_open_reasoning.window` once a rate — arrivals to the window's last
@@ -23,6 +29,7 @@ to chiprun_out/pr47/knee.json too.  `--toy` runs the cell's rehearsal sizes
 on the CPU.
 """
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -31,9 +38,21 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-CELL = "serve-ling3flash-reasoning"
+
+
+def _option(name: str, default: str) -> str:
+    """`--name value` off the command line (and out of it)."""
+    if name not in sys.argv:
+        return default
+    at = sys.argv.index(name)
+    value = sys.argv[at + 1]
+    del sys.argv[at:at + 2]
+    return value
+
+
+CELL = _option("--cell", "serve-ling3flash-reasoning")
+OUT = os.path.join(ROOT, "chiprun_out", _option("--out", "pr47"))
 TOY = "--toy" in sys.argv
-OUT = os.path.join(ROOT, "chiprun_out", "pr47")
 
 
 def say(**kw):
@@ -43,11 +62,12 @@ def say(**kw):
 def sweep(rates, seed: int):
     import benchmarks.run as R
     from benchmarks.drivers import _serve as S
-    from benchmarks.drivers import serve_open_reasoning as drv
     from benchmarks.lib import manifest
     from benchmarks.lib.stats import percentile as pct
 
     cell = manifest.resolve(manifest.load(), CELL)
+    drv = importlib.import_module(
+        f"benchmarks.drivers.{cell['traffic']['kind']}")
     rundir = os.path.join(R.RUN_DIR, "knee")
     os.makedirs(rundir, exist_ok=True)
     ctx = R._context(argparse.Namespace(
@@ -119,8 +139,9 @@ def main():
 
         env = R._child_env(argparse.Namespace(rehearse=TOY), 1)
         sys.exit(subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--child"]
-            + sys.argv[1:], env=env, cwd=ROOT).returncode)
+            [sys.executable, os.path.abspath(__file__), "--child", "--cell",
+             CELL, "--out", os.path.basename(OUT)] + sys.argv[1:], env=env,
+            cwd=ROOT).returncode)
     sweep(rates, seed)
     sys.stdout.flush()
     os._exit(0)

@@ -71,7 +71,9 @@ _QUICK_FILES = {
     "test_data_remote_io.py", "test_deepseek_v3.py",
     "test_device_telemetry.py",
     "test_docs_paths.py", "test_elastic.py", "test_engine_mixed_state.py",
+    "test_engine_three_kinds.py",
     "test_kda.py", "test_label_scheduling.py", "test_ling3.py",
+    "test_mamba.py", "test_phi4flash.py",
     "test_mpmd.py",
     "test_native_sched.py", "test_native_store.py", "test_ops.py",
     "test_parallel.py", "test_partition.py", "test_podracer.py",

@@ -866,3 +866,107 @@ def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
         assert (_latent_kernels(compiled), _streamed_kernels(compiled)) == (
             0, 1)
     print(key, "total", total, "temp", m.temp_size_in_bytes)
+
+
+# -- the sixth served model WHOLE: Phi-4-mini-flash-reasoning at its published
+# -- widths, depth and vocabulary (benchmarks/configs/...) --------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512)],
+                         ids=["step", "prefill512"])
+def test_phi4flash_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
+    """The three-kind serve programs (nine Mamba layers: the step's kernel
+    over a state arena, the chunk's scan kernel; eight windowed and one
+    full differential-attention layer over pages; seven cross layers that
+    read the one full arena; 200,064 logits a slot) of the WHOLE model with
+    an engine sized HERE — 32 slots of 20,480 positions — and not by the
+    benchmark's `engine_kwargs`: the chip's compiler takes them; weights +
+    pages + states + tails + temporaries stay under 15.75 GiB; the cache
+    is donated and held once, no arena moved by a copy."""
+    import json
+
+    from benchmarks.lib.phi4flashcfg import model_config
+    from ray_tpu.models import phi4flash as pm
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        conf = json.load(f)
+    assert conf["reduced"] == []
+    cfg = model_config(conf, mamba_impl="pallas")
+    view = _on(jax.eval_shape(
+        lambda k: pm.serve_view(pm.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one_chip)
+    eng = ContinuousEngine(pm, cfg, view, max_slots=32, page_size=128,
+                           max_total=20480,
+                           num_pages={"full": 5121, "swa": 289, "mamba": 33},
+                           prefill_bucket=512, prefill_chunk=512)
+    try:
+        cache = _on(jax.eval_shape(functools.partial(
+            pm.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B, V = eng.max_slots, cfg.vocab_size
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (view, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32),
+                    {k: s((B, w), jnp.int32)
+                     for k, w in eng._widths.items()}, s((B,), jnp.int32))
+        else:
+            args = (view, cache, s((key[1],), jnp.int32),
+                    {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
+                    i32, i32, s((), jnp.bool_))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    assert (eng._widths, eng._share, eng._main, eng._state_kinds,
+            eng._windowed) == ({"full": 160, "swa": 9, "mamba": 1}, False,
+                               "full", ["mamba"], ["swa"])
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(view))
+    assert cache["full"]["k"].shape == (5121, 128, 1280)
+    assert len(cache["swa"]) == 8
+    assert cache["swa"][0]["v"].shape == (289, 128, 1280)
+    assert cache["state"].shape == (9, 33, 16, 5120)
+    assert cache["tail"].shape == (9, 33, 3, 5120)
+    page = 2 * 128 * 1280 * 2
+    assert arena == (5121 * page + 8 * 289 * page
+                     + 9 * 33 * (16 * 5120 * 4 + 3 * 5120 * 2))
+    assert 7.68e9 < weights < 7.72e9
+    assert m.alias_size_in_bytes >= arena
+    assert total < 15.75 * 1024 ** 3, total
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"= f32\[9,33,16,5120\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)
+             or re.search(r"= bf16\[(5121|289),128,1280\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)]
+    assert not moved, moved
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    named = lambda n: sum(n in c.split(" = ", 1)[0] for c in calls)
+    gathered = [ln.strip()[:120] for ln in text.splitlines()
+                if re.search(r"= bf16\[%d,(4,128|512),1280\]\S* "
+                             r"(gather|copy|transpose)\(" % B, ln)]
+    if key == "step":       # one kernel a Mamba layer, under its name; every
+        #                     read of pages — eight of the shared cache, one
+        #                     a windowed layer — a walk of the slots' own
+        assert named("mamba_step") == 9 and named("mamba_chunk") == 0
+        assert named("paged_decode_attention") == 8 + 8
+        _assert_sampler_asks_its_operands(compiled, B, V)
+        assert _streamed_kernels(compiled) == 0 and not gathered, gathered
+    else:                   # the chunk: a scan kernel a Mamba layer, the
+        #                     block kernel in the nine self-decoder layers,
+        #                     the last chunk's seven one-row reads a walk
+        assert named("mamba_chunk") == 9 and named("mamba_step") == 0
+        assert named("paged_decode_attention") == 7
+        assert _streamed_kernels(compiled) >= 1
+        assert "conditional" in text
+    print(key, "total", total, "temp", m.temp_size_in_bytes)

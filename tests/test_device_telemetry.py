@@ -244,11 +244,14 @@ def test_first_compile_in_flight_flag():
 
     prog = devtel.instrument(jax.jit(f), name="flag.prog")
     assert not prog.first_compile_in_flight
+    assert prog.first_compile_ended == 0.0
     prog(jnp.ones(3))
     assert seen == [True] and not prog.first_compile_in_flight
+    ended = prog.first_compile_ended     # ... and says when it returned
+    assert 0.0 < ended <= time.monotonic()
     prog(jnp.ones(3))                    # cache hit: f is not re-traced
     prog(jnp.ones(4))                    # a REcompile is not a first one
-    assert seen == [True, False]
+    assert seen == [True, False] and prog.first_compile_ended == ended
 
 
 def test_engine_stall_probe_exempts_first_compile():
@@ -271,6 +274,13 @@ def test_engine_stall_probe_exempts_first_compile():
         time.sleep(0.12)
         assert eng.check_health()         # compiling: the clock restarts
         Prog.first_compile_in_flight = False
+        assert eng.check_health()
+        time.sleep(0.12)
+        with pytest.raises(RuntimeError, match="stalled"):
+            eng.check_health()
+        # a compile that began and ended between two probes (neither saw
+        # the flag): the clock counts from where it ended
+        Prog.first_compile_ended = time.monotonic()
         assert eng.check_health()
         time.sleep(0.12)
         with pytest.raises(RuntimeError, match="stalled"):

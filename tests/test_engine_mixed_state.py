@@ -92,7 +92,7 @@ def test_served_rows_are_the_reference_and_the_counters_tell_the_pools_apart(
     page = (cfg.kv_rank + cfg.d_rope) * PS * 4 * len(cfg.mla_layers)
     st = eng.engine_stats()
     assert st["state_arena_bytes"] == 4 * entry
-    assert (eng._entry_bytes, eng._page_bytes) == (entry, page)
+    assert (eng._entry_bytes, eng._page_bytes) == (entry, {"full": page})
     ring = eng.phase_ring()
     assert {r["states_live"] for r in ring} <= {0, 1, 2}
     both = [r for r in ring if r["states_live"] == 2]

@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import brumby, cohere2_moe, deepseek_v3, gpt, ling3
+from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, gpt, ling3,
+                            phi4flash)
 from ray_tpu.serve._engine import ContinuousEngine, _check_interface
 
 MODELS = {
@@ -20,6 +21,7 @@ MODELS = {
     "brumby": (brumby, brumby.BrumbyConfig.nano()),
     "deepseek-v3": (deepseek_v3, deepseek_v3.DeepSeekV3Config.nano()),
     "ling-3": (ling3, ling3.Ling3Config.nano()),
+    "phi-4-flash": (phi4flash, phi4flash.Phi4FlashConfig.nano()),
 }
 REQUIRED = ("cache_kinds", "init_paged_cache", "paged_decode_step",
             "paged_prefill", "serve_view")
@@ -126,11 +128,19 @@ def test_a_program_returns_logits_the_cache_and_its_stats(name, program):
             {k: ints(SLOTS, w) for k, w in widths.items()}, ints(SLOTS))
         assert logits.shape == (SLOTS, cfg.vocab_size)
     else:
+        # a module that asks (`PREFILL_KNOWS_LAST`) is told whether the
+        # chunk is its prompt's last; no other takes the operand
+        last = ([jax.ShapeDtypeStruct((), jnp.bool_)]
+                if getattr(mod, "PREFILL_KNOWS_LAST", False) else [])
         logits, after, *stats = jax.eval_shape(
-            lambda p, c, t, tabs, s, l: mod.paged_prefill(
-                p, c, t, tabs, s, l, cfg),
+            lambda p, c, t, tabs, *ops: mod.paged_prefill(
+                p, c, t, tabs, *ops, cfg=cfg),
             view, cache, ints(ROWS), {k: ints(w) for k, w in widths.items()},
-            ints(), ints())
+            ints(), ints(), *last)
+        if not last:
+            with pytest.raises(TypeError):
+                mod.paged_prefill(view, cache, ints(ROWS), {}, ints(), ints(),
+                                  last, cfg=cfg)
         assert logits.shape == (cfg.vocab_size,)
     # the engine donates the cache: what comes back takes its place
     assert _shapes(after) == _shapes(cache)

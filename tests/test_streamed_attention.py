@@ -335,19 +335,25 @@ def _toy_programs(mod, cfg, rows=None, slots=4, page=16):
         params, cache, ints(slots),
         {k: ints(slots, w) for k, w in widths.items()}, ints(slots))}
     if rows:
+        # a model that asks is told whether the chunk is its prompt's last
+        last = ([jax.ShapeDtypeStruct((), jnp.bool_)]
+                if getattr(mod, "PREFILL_KNOWS_LAST", False) else [])
         out["chunk"] = lowered(
-            lambda p, c, t, tabs, s, l: mod.paged_prefill(p, c, t, tabs, s,
-                                                          l, cfg),
+            lambda p, c, t, tabs, *ops: mod.paged_prefill(p, c, t, tabs,
+                                                          *ops, cfg=cfg),
             params, cache, ints(rows), {k: ints(w) for k, w in widths.items()},
-            i32, i32)
+            i32, i32, *last)
     return out
 
 
 def _toy(name):
     """(module, config) of a served model at toy size, the widths its
     kernels need on a TPU (128 positions a page's lanes)."""
-    from ray_tpu.models import brumby, cohere2_moe, deepseek_v3, gpt, ling3
+    from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, gpt, ling3,
+                                phi4flash)
     return {
+        "phi-4-flash": lambda: (phi4flash, phi4flash.Phi4FlashConfig.nano(
+            max_seq=512, kv_block=128, sliding_window=128, d_head=64)),
         "gpt2": lambda: (gpt, gpt.GPTConfig.nano(max_seq=512)),
         "command-a-plus": lambda: (cohere2_moe, cohere2_moe.Cohere2MoEConfig
                                    .nano(max_seq=512, kv_block=128,
@@ -398,7 +404,7 @@ def _digest(text):
 # a program's `_digest`, lowered for the platform "tpu": every served
 # model's step and chunk program.  `deepseek-v3`'s two and `ling-3`'s step
 # were pinned at d07b063 (the parent of PR 50), the other seven at 67e7002
-# (the parent of PR 48).  A PR that moves or renames Python functions
+# (the parent of PR 48), `phi-4-flash`'s two by PR 51, which added them.  A PR that moves or renames Python functions
 # leaves every digest alone (the text carries no source locations; their
 # kernels' source lines unmoved, the compile-cache keys stay too).  A PR
 # that edits one of these programs finds the new digest in the failure and
@@ -414,6 +420,8 @@ PARENT_TEXT = {
     ("deepseek-v3", "chunk"): "8fe296d520a4a6c7",
     ("ling-3", "step"): "e01e8b44bc0ed1d5",
     ("ling-3", "chunk"): "85bf7fa576820a74",
+    ("phi-4-flash", "step"): "d0a2de26cb6e8b1b",
+    ("phi-4-flash", "chunk"): "ce1a43a62b84b2a7",
 }
 
 
@@ -425,3 +433,63 @@ def test_untouched_programs_lower_to_the_parents_text(monkeypatch, name):
     got = {(name, k): _digest(t) for k, t in text.items()
            if (name, k) in PARENT_TEXT}
     assert got == {k: v for k, v in PARENT_TEXT.items() if k[0] == name}
+
+
+# -- paged decode attention (PR 51): a step's walk over K/V pages -------------
+
+
+def _plain_decode(q, ka, va, ptab, kpos, qpos, window, scale):
+    """One slot: q [H, R, d] against its table's pages laid end to end,
+    key positions kpos [W * ps] (negative: nothing there)."""
+    W, ps = ptab.shape[0], ka.shape[1]
+    H, _, d = q.shape
+    k = ka[ptab].reshape(W * ps, H, d)
+    v = va[ptab].reshape(W * ps, H, d)
+    dist = qpos - kpos
+    ok = (kpos >= 0) & (dist >= 0)
+    if window is not None:
+        ok = ok & (dist < window)
+    s = jnp.where(ok[None, None], jnp.einsum("hrd,shd->hrs", q, k) * scale,
+                  -jnp.inf)
+    return jnp.einsum("hrs,shd->hrd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("window", [None, 300], ids=["full", "ring"])
+def test_paged_decode_attention_walks_what_a_query_sees(window):
+    """The kernel in interpret mode against plain attention over each
+    slot's own pages: a full kind's table walked as far as the context
+    (one position, a page's edge, several blocks of four pages), a ring
+    read through its window (pages the window has passed are entries of
+    the table still), an empty slot fetching nothing and reading zero."""
+    from ray_tpu.ops.attention import paged_decode_attention
+
+    B, H, R, d, ps, P, W = 5, 2, 4, 128, 128, 48, 9
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (B, H, R, d), jnp.float32)
+    ka = jax.random.normal(ks[1], (P, ps, H * d), jnp.float32)
+    va = jax.random.normal(ks[2], (P, ps, H * d), jnp.float32)
+    ptab = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, B * W + 1)).reshape(B, W))
+    live = np.array([1, 0, 1, 1, 1])
+    if window is None:
+        qpos = np.array([299, 0, 0, 1151, 127])
+        bases = np.broadcast_to(np.arange(W) * ps, (B, W))
+        walk = np.where(live, qpos // ps + 1, 0)
+    else:       # logical page lp in entry lp % W
+        qpos = np.array([299, 0, 1500, 2047, 5])
+        hi = (qpos // ps)[:, None]
+        lp = hi - (hi - np.arange(W)[None]) % W
+        bases = np.where(lp >= 0, lp * ps, -1)
+        walk = np.where(live, W, 0)
+    got = paged_decode_attention(
+        q, ka, va, ptab, jnp.asarray(bases), jnp.asarray(qpos),
+        jnp.asarray(walk), scale=0.125, window=window, interpret=True)
+    for b in range(B):
+        if not live[b]:
+            assert float(jnp.abs(got[b]).max()) == 0.0
+            continue
+        kpos = jnp.where(bases[b][:, None] >= 0,
+                         bases[b][:, None] + jnp.arange(ps), -1).reshape(-1)
+        want = _plain_decode(q[b], ka, va, ptab[b], kpos, int(qpos[b]),
+                             window, 0.125)
+        assert float(jnp.abs(got[b] - want).max()) < 2e-6, b
