@@ -19,7 +19,8 @@ runs (a decode step is its one-row case).  The program is a function of
 the padded length alone; where the chunk starts, which row yields the
 logits and the page-table row are operands.  The engine thread launches
 it inside `_admit` and the step right behind it, so every streaming slot
-waits for it on the chip — and the thread waits once, for both.
+waits for it on the chip, between the step in flight and the next ("The
+order of an iteration", below).
 
 A prompt longer than `prefill_chunk` (0: off) is prefilled in chunks of
 that size through one program, the tail padded to `prefill_bucket`: one
@@ -114,11 +115,46 @@ the chip through one helper and waits through one other (`_launch`,
 `_wait`), each boundary one clock read — call entered, call returned,
 result ready — that feeds the iteration's ring record, the running
 totals, the `serve.engine.*` profiler annotations and the phase
-histogram alike.  Within an iteration it enqueues, then waits: an
-admission's programs and the step are launched back to back on arrays
-that are not ready yet, and only then does the thread block — first on
-what each prefill returned, for the clock read that says when it was
-done, then on the step's tokens.
+histogram alike.
+
+The order of an iteration
+-------------------------
+One step stays in flight.  Within an iteration the thread enqueues, then
+waits — and what it waits for first is the step launched an iteration AGO:
+
+    admit    launch this iteration's prefill program(s) and the row
+    step     launch this iteration's step, behind them and behind the step
+             before it, whose tokens the host has not seen
+    emit     fetch THAT step's tokens, hand them out, evict what ended
+    stamp    block on what each prefill returned, for the clock read that
+             says when it was done
+    account  the ring record
+
+so the chip has its next program queued whenever one ends, and a launch's
+way to the chip, the tokens' way back, emit, account and the consumers'
+turn at the interpreter all pass while a step runs.  Nothing has to wait
+because the step program samples its own input: it draws its tokens from
+the logits the step before it left ON THE DEVICE, and nothing the host
+learns from a token is an operand of the next step — positions advance by
+one, a window's next page is the host's own bookkeeping, a sampling
+request's keys are indexed by a count the host knows, and a sequence that
+ends by `max_new_tokens` ends at a count the host knows at the launch.  So
+all of that is counted by steps LAUNCHED for a sequence (`_step`), and a
+sequence whose last token by count is in the step just launched gives its
+slot, pages and entry back right there, for the very next admission:
+whatever is launched later runs later on the chip.  Only `eos_id` is
+learned from a token.  It is applied one step late: the step launched
+since has computed one more token for the sequence, inside the pages its
+admission reserved, and that token is dropped (`_emit`).
+
+The first step after an idle stretch is launched with nothing to fetch and
+fetched by the next iteration, which follows at once; an iteration with
+nothing to launch (no slot decodes) fetches the step in flight, so an idle
+engine holds no unfetched step, and `drain`, `stop`, the health check and
+whoever drives the programs by hand on an idle engine's `_cache` /
+`_logits` see what they would have seen with no step in flight.  A step's
+host operands are copies: the engine's own change before it is waited
+for.  The depth is one and is no setting.
 """
 
 from __future__ import annotations
@@ -366,7 +402,7 @@ class _Sequence:
                  "scanned", "trace_ctx", "peak", "stream", "request_id",
                  "key_offset", "tabs", "win", "reserved", "states",
                  "next_start",
-                 "chunks", "prefill_s", "prefilling")
+                 "chunks", "prefill_s", "prefilling", "launched", "done")
 
     def __init__(self, rid, tokens, max_new, temperature, top_k, seed,
                  eos_id, stream, request_id=None, key_offset=0):
@@ -412,6 +448,24 @@ class _Sequence:
         self.chunks = 0             # prefill programs run for it
         self.prefill_s = 0.0        # their seconds, dispatch -> ready
         self.prefilling = False     # holds a slot, its rows not the step's
+        self.launched = 0           # steps launched for it: tokens computed
+        self.done = False           # _finish has run: its caller has heard
+
+
+class _Flight:
+    """One launched step whose tokens the host has not fetched: the
+    (slot, sequence) pairs it decodes — a sequence may have left its slot
+    since (its last token by count is this step's) or ended (on an EOS an
+    earlier step drew: this step's token for it is dropped) — its tokens
+    and counters on the device, and what the host counted at its launch
+    for the record of the iteration that emits it."""
+
+    __slots__ = ("active", "out", "counted")
+
+    def __init__(self, active, out, counted):
+        self.active: List[Tuple[int, _Sequence]] = active
+        self.out: Any = out                 # (tokens, counters); None once fetched
+        self.counted: Dict[str, float] = counted
 
 
 def _check_interface(mod, cfg) -> None:
@@ -560,7 +614,10 @@ class ContinuousEngine:
         self._ttfts: "deque[float]" = deque(maxlen=256)        # guarded-by: _lock
         self._t_window: "deque[Tuple[float, int]]" = deque(maxlen=512)  # guarded-by: _lock
         self._totals = {"requests": 0, "rejected": 0, "tokens": 0,
-                        "steps": 0, "prefills": 0, "cow_copies": 0,
+                        # steps launched; of them, those launched with
+                        # the step before them still unfetched
+                        "steps": 0, "steps_ahead": 0,
+                        "prefills": 0, "cow_copies": 0,
                         "shared_pages": 0, "chunks": 0,
                         "window_pages_returned": 0,
                         # cumulative sums of the ring's records, so two
@@ -586,7 +643,10 @@ class ContinuousEngine:
         # prefill programs launched and not yet stamped ready: (sequence,
         # call entered, its logits row, its counters, the prompt's last?)
         self._pending: List[Tuple[_Sequence, float, Any, tuple, bool]] = []
-        self._step_out: Any = None   # the launched step's tokens, counters
+        # steps launched whose tokens are not on the host yet, oldest
+        # first: one between two iterations, two between an iteration's
+        # launch and its fetch, none while the engine idles
+        self._in_flight: "deque[_Flight]" = deque()
         self._launched: Dict[str, float] = {}   # _launch / _wait's sums
         self._blocked = 0            # streaming slots an admission held up
         self._first: List[_Sequence] = []   # first token this iteration
@@ -788,10 +848,7 @@ class ContinuousEngine:
         self._wake.set()
         deadline = time.monotonic() + max(0.0, timeout_s)
         while True:
-            with self._lock:
-                busy = bool(self._waiting) or any(
-                    s is not None for s in self._slots)
-            if not busy:
+            if not self._busy():
                 return True
             if time.monotonic() >= deadline:
                 return False
@@ -806,7 +863,10 @@ class ContinuousEngine:
         with self._lock:
             if self._stopped:
                 raise RuntimeError("engine stopped")
-            active = sum(1 for s in self._slots if s is not None)
+            # (a step nobody has fetched is work too: its sequences may
+            # all have left their slots by count)
+            active = (sum(1 for s in self._slots if s is not None)
+                      or len(self._in_flight))
             queued = len(self._waiting)
             steps = self._totals["steps"]
         thread = self._thread
@@ -862,19 +922,12 @@ class ContinuousEngine:
         # slots — clearing them mid-_step would double-release pages
         if self._thread is not None:
             self._thread.join(timeout=5.0)
-        with self._lock:
-            active = [s for s in self._slots if s is not None]
-            self._slots = [None] * self.max_slots
-        self._pos[:] = 0
-        for tab in self._ptabs.values():
-            tab[:] = 0
-        for s in active:
-            self._release(s)
         err = RuntimeError("engine stopped")
         # in-slot sequences must resolve too: a stream consumer blocked
         # on out_q and a request/response caller blocked on the future
-        # would otherwise hang forever
-        for s in waiting + active:
+        # would otherwise hang forever — and one whose last token was in
+        # a step nobody will fetch
+        for s in waiting + self._drop_everything():
             self._finish(s, error=err)
 
     # -- engine loop --------------------------------------------------------
@@ -913,10 +966,9 @@ class ContinuousEngine:
             with self._lock:
                 if self._stopped:
                     return
-                busy = bool(self._waiting) or any(
-                    s is not None for s in self._slots)
-            if not busy:
-                # nothing to run: the device's idle time here is nobody's
+            if not self._busy():
+                # nothing to run and nothing unfetched: the device's idle
+                # time here is nobody's
                 with self._jax.profiler.TraceAnnotation("serve.engine.idle"):
                     self._wake.wait(timeout=0.2)
                 self._wake.clear()
@@ -925,31 +977,66 @@ class ContinuousEngine:
                 self._iteration()
             except Exception as e:          # fail every in-flight request
                 with self._lock:            # rather than wedge the loop
-                    seqs = [s for s in self._slots if s is not None]
-                    seqs += list(self._waiting)
+                    waiting = list(self._waiting)
                     self._waiting.clear()
-                    self._slots = [None] * self.max_slots
-                    self._pos[:] = 0
-                    for tab in self._ptabs.values():
-                        tab[:] = 0
-                self._prefilling = None
-                # the program that raised may have consumed the state
-                # it was given: _ensure_device_state makes it anew
-                self._cache = self._logits = self._step_out = None
-                self._pending = []
-                for s in seqs:
-                    self._release(s)
+                for s in self._drop_everything() + waiting:
                     self._finish(s, error=e)
 
+    def _busy(self) -> bool:
+        """Something waits, holds a slot, or has a token in a step the
+        host has not fetched."""
+        with self._lock:
+            return (bool(self._waiting) or bool(self._in_flight)
+                    or any(s is not None for s in self._slots))
+
+    def _drop_everything(self) -> List[_Sequence]:
+        """Empty the slots and drop whatever is launched and unfetched,
+        with the device state it consumed (a program that raised may have
+        taken it along; `_ensure_device_state` makes it anew) -> the
+        sequences that held a slot or had a token in flight, each once,
+        holding nothing; their callers have yet to hear."""
+        with self._lock:
+            seqs = [s for s in self._slots if s is not None]
+            self._slots = [None] * self.max_slots
+        seqs += [s for fl in self._in_flight for _, s in fl.active
+                 if not s.done and s not in seqs]
+        self._in_flight.clear()
+        self._pending = []
+        self._prefilling = None
+        self._cache = self._logits = None
+        for operand in (self._pos, self._temps, self._topks,
+                        self._toks_keys, *self._ptabs.values()):
+            operand[:] = 0
+        for s in seqs:
+            self._release(s)
+        return seqs
+
     def _iteration(self):
-        """One scheduler iteration: admit, step, account.  Every phase
-        boundary is one `perf_counter` read that feeds every output:
-        the ring record (and `_totals`), the `serve.engine.*`
-        annotations on the profiler's clock — a flag test each while no
-        profiler session is open — the phase histogram and, per request,
-        the retro `engine.*` spans emitted by `_finish`."""
+        """One scheduler iteration, with one step kept in flight: admit
+        (launch this iteration's prefill programs), launch THIS
+        iteration's step behind them and behind the step before it, and
+        only then fetch and emit THAT step's tokens; stamp the prefills
+        done; account.  So the chip has its next program queued when one
+        ends, and a launch's way to the chip, the tokens' way back, emit,
+        account and the consumers' turn at the interpreter all pass while
+        a step runs.  The first step after an idle stretch is launched
+        with nothing to fetch (`ahead` 0) and fetched by the next
+        iteration; an iteration that launches none (nothing decodes)
+        fetches the one in flight, so an idle engine holds no step.  A
+        finished step's tokens never wait for a chunk: they are emitted
+        before the prefill is stamped.
+
+        The record describes the step whose tokens it EMITTED (`active`,
+        the model's counters, `requests`); `stepped` / `ahead` the one it
+        launched.  Every phase boundary is one `perf_counter` read that
+        feeds every output: the ring record (and `_totals`), the
+        `serve.engine.*` annotations on the profiler's clock — a flag
+        test each while no profiler session is open — the phase histogram
+        and, per request, the retro `engine.*` spans emitted by
+        `_finish`."""
         ann = self._jax.profiler.TraceAnnotation
-        t0 = time.perf_counter()
+        # (an iteration that launches nothing waits from its own start)
+        t0 = self._t_free = time.perf_counter()
         self._iter += 1
         gc0 = self._totals["gc_s"]
         it = self._launched = {"dispatch_s": 0.0, "ready_wait_s": 0.0,
@@ -959,28 +1046,32 @@ class ContinuousEngine:
         self._first = []
         self._chunks = self._chunk_tokens = self._returned = 0
         self._stats = dict.fromkeys(self._stat_keys, 0.0)
-        # enqueue: the admission's programs, the step behind them ...
+        # enqueue: the admission's programs, this iteration's step ...
         with ann("serve.engine.admit", iter=self._iter):
             admitted = self._admit()
         t1 = time.perf_counter()
-        active = None
-        if any(s is not None and not s.prefilling for s in self._slots):
-            active = self._step()
-        # ... then wait: the admission's work is done on the chip where
-        # its last prefill is (the step already runs behind it), the
-        # iteration where the step's tokens are on the host
-        if self._pending:
-            t1 = self._stamp_prefills()
-        stepped = self._emit(active) if active else 0
+        in_flight = bool(self._in_flight)
+        stepped = self._step()
+        # ... then wait: for the tokens of the step launched an iteration
+        # ago, which every stream is waiting for, and after them for the
+        # admission's work (this iteration's step runs behind it)
+        active = self._emit() if in_flight else 0
         t2 = time.perf_counter()
+        t3 = self._stamp_prefills() if self._pending else t2
         with ann("serve.engine.account"):
             # a chunk of a prompt already admitted is admission work too
             worked = admitted or self._chunks
-            rec = {"swap_s": (t1 - t0) if worked else 0.0,
+            # launch + fetch + emit: from one emit to the next, but for
+            # the account.  An admission's share of its iteration is the
+            # rest: its launches before, its prefill's end after
+            decode_s = (t2 - t1) if active else 0.0
+            rec = {"swap_s": (t3 - t0 - decode_s) if worked else 0.0,
                    "prefill_s": self._last_prefill_s if worked else 0.0,
-                   "decode_s": (t2 - t1) if stepped else 0.0,
-                   "active": stepped, "admitted": admitted, "ts": t2,
+                   "decode_s": decode_s,
+                   "active": active, "admitted": admitted, "ts": t3,
                    "t0": t0, "iter": self._iter,
+                   "stepped": int(stepped),
+                   "ahead": int(stepped and in_flight),
                    # blocked on the device: inside a launch, or waiting
                    # for what the last one returns
                    "device_wait_s": it["dispatch_s"] + it["ready_wait_s"],
@@ -1003,13 +1094,13 @@ class ContinuousEngine:
                     m.observe(max(0.0, rec["swap_s"] - rec["prefill_s"]),
                               tags={"phase": "swap"})
                     m.observe(rec["prefill_s"], tags={"phase": "prefill"})
-                if stepped:
+                if active:
                     m.observe(rec["decode_s"], tags={"phase": "decode"})
                 if it["launches"]:
                     m.observe(it["dispatch_s"], tags={"phase": "dispatch"})
             with self._lock:
                 qd = len(self._waiting)
-            for which, val in (("active", stepped), ("queue", qd),
+            for which, val in (("active", active), ("queue", qd),
                                ("free_pages", self._alloc.free_pages)):
                 g = _m_gauge(which)
                 if g:
@@ -1025,6 +1116,8 @@ class ContinuousEngine:
                           "device_wait_s", "dispatch_s", "ready_wait_s",
                           "launches") + self._stat_keys:
                     tot[k] += rec[k]
+                tot["steps"] += rec["stepped"]
+                tot["steps_ahead"] += rec["ahead"]
                 tot["blocked_slot_s"] += rec["swap_s"] * rec["blocked_slots"]
                 for r in rec["requests"]:
                     tot["queue_wait_s"] += r["queue_wait_s"]
@@ -1058,8 +1151,8 @@ class ContinuousEngine:
         """Block, under annotation `name`, until `ready` is done on the
         device and each of `fetch` is on the host -> (the clock then, the
         fetched arrays).  One clock read; the wait counts from where the
-        thread was last let go: the last launch's return, or the wait
-        before this one."""
+        thread was last let go: the last launch's return, the wait before
+        this one, or the iteration's start."""
         with self._jax.profiler.TraceAnnotation(name):
             if ready is not None:
                 self._jax.block_until_ready(ready)
@@ -1285,7 +1378,8 @@ class ContinuousEngine:
         return keys
 
     def _stamp_prefills(self) -> float:
-        """The iteration's blocking stretch, first part: one clock read
+        """The iteration's blocking stretch, second part (the tokens of
+        the step in flight went out first): one clock read
         where each launched prefill program is done -> the last read.  A
         program's `prefill_s` is dispatch -> ready; behind another of the
         same iteration it counts from that one's read, so the sums count
@@ -1366,58 +1460,97 @@ class ContinuousEngine:
 
     # -- decode -------------------------------------------------------------
 
-    def _step(self):
+    def _step(self) -> bool:
         """Launch one fused sample+decode step over every slot, behind
-        whatever this iteration's admission launched -> the decoding
-        slots; its tokens and counters, not yet ready, wait in
-        `_step_out` for `_emit`.  Inactive slots ride along at pos 0
-        against the null page; `_emit` discards their tokens on the
-        host."""
+        whatever this iteration's admission launched and behind the step
+        before it, whose tokens the host has not seen -> whether there
+        was one to launch.  Nothing a step is launched with is learned
+        from a token: a sequence is in it while fewer steps were launched
+        for it than it may answer tokens (`launched`, not `generated`,
+        indexes its keys, and its position and window advance here, at
+        the launch), and one whose last token by count this step computes
+        gives its slot, pages and entry back right behind the launch —
+        whatever is launched later runs later on the chip.  The step gets
+        its own copy of the host operands: they change before it is
+        waited for.  Its tokens start for the host as it ends and wait,
+        with the slots it decodes, at the end of `_in_flight` for the next
+        iteration's `_emit`.  Inactive slots ride along at pos 0 against
+        the null page; `_emit` discards their tokens on the host."""
         ann = self._jax.profiler.TraceAnnotation
+        active = []
+        for i, s in enumerate(self._slots):
+            if s is None or s.prefilling:
+                continue
+            # `max_new` may have been cut from outside (a driver closing
+            # its window): a sequence with a token in flight ends with
+            # that one, any other with the one launched here
+            if s.launched < max(s.max_new, len(s.generated) + 1):
+                active.append((i, s))
+            else:
+                self._vacate(i, s)
+        if not active:
+            return False
         with ann("serve.engine.step", iter=self._iter):
-            active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None and not s.prefilling]
             for i, s in active:
                 if s.keys is not None:
-                    self._toks_keys[i] = s.keys[len(s.generated)]
+                    self._toks_keys[i] = s.keys[s.launched]
                 self._grow_windows(s, int(self._pos[i]),
                                    int(self._pos[i]) + 1)
+            counted = {"sampled_steps": float((self._temps > 0).any())}
             if self._kv_read is not None:
-                read, span = self._kv_read(self._cfg, self._pos,
-                                           self.page_size,
-                                           self.max_pages_per_seq)
-                self._stats["kv_read"] += read
-                self._stats["kv_span"] += span
-            self._stats["sampled_steps"] += bool((self._temps > 0).any())
+                counted["kv_read"], counted["kv_span"] = self._kv_read(
+                    self._cfg, self._pos, self.page_size,
+                    self.max_pages_per_seq)
             toks, self._logits, self._cache, stats = self._launch(
                 "serve.step", self._fn("step"),
-                self._params, self._cache, self._logits, self._toks_keys,
-                self._temps, self._topks, self._ptabs, self._pos)
-        self._launched["step_dispatch_s"] = self._t_free - self._t_call
-        self._step_out = (toks, stats)
-        return active
+                self._params, self._cache, self._logits,
+                self._toks_keys.copy(), self._temps.copy(),
+                self._topks.copy(),
+                {k: tab.copy() for k, tab in self._ptabs.items()},
+                self._pos.copy())
+            self._launched["step_dispatch_s"] = self._t_free - self._t_call
+            for out in (toks, *stats):
+                out.copy_to_host_async()
+            self._in_flight.append(_Flight(active, (toks, stats), counted))
+            for i, s in active:
+                s.launched += 1
+                self._pos[i] += 1
+                self._shrink_windows(s, int(self._pos[i]))
+                if s.launched >= s.max_new:
+                    self._vacate(i, s)
+        return True
 
-    def _emit(self, active) -> int:
-        """The iteration's blocking stretch, second part: the step's
-        tokens on the host, each to its sequence; sequences that are done
-        leave -> how many slots decoded."""
+    def _emit(self) -> int:
+        """The iteration's blocking stretch, first part: the tokens of
+        the oldest step in flight on the host, each to its sequence;
+        sequences that are done hear of it -> how many slots that step
+        decoded.  What needs the token happens here: `generated`, the
+        stream, first and last token's clock reads, `eos_id`.  A sequence
+        that ends on its EOS is found here one step late: the step
+        launched since has computed one token more for it, inside the
+        pages its admission reserved, and that token is dropped when its
+        turn comes."""
         ann = self._jax.profiler.TraceAnnotation
+        fl = self._in_flight[0]
         t_free = self._t_free
         # the device arrays are let go here, before any consumer is woken:
         # freeing one gives up the interpreter, and a consumer that takes
         # it then holds the engine thread up with nothing launched (seen
         # on the chip as 0.3-0.8 ms between one iteration and the next)
-        (toks, stats), self._step_out = self._step_out, None
+        (toks, stats), fl.out = fl.out, None
         now, (toks, *stats) = self._wait("serve.engine.fetch", toks, *stats)
         self._note_stats(stats)
-        # the step program's own wait, apart from an admission's: from the
-        # step's launch, or from where the last prefill was stamped done
+        for k, v in fl.counted.items():
+            self._stats[k] += v
+        # how long the thread was blocked for these tokens: from where it
+        # was last let go, which is this iteration's step's launch
         self._launched["step_wait_s"] = now - t_free
         with ann("serve.engine.emit"):
-            self._totals["steps"] += 1
             emitted = 0
             finished = []
-            for i, s in active:
+            for i, s in fl.active:
+                if s.done:      # ended a step ago, or failed: no token
+                    continue
                 tok = int(toks[i])
                 s.generated.append(tok)
                 emitted += 1
@@ -1432,8 +1565,6 @@ class ContinuousEngine:
                     if m:
                         m.observe(ttft)
                 s.out_q.put(tok)
-                self._pos[i] += 1
-                self._shrink_windows(s, int(self._pos[i]))
                 if (len(s.generated) >= s.max_new
                         or (s.eos_id is not None and tok == s.eos_id)):
                     finished.append((i, s))
@@ -1444,10 +1575,16 @@ class ContinuousEngine:
             if m and emitted:
                 m.inc(emitted)
             for i, s in finished:
-                self._evict(i, s)
-        return len(active)
+                if self._slots[i] is s:     # not by count: still there
+                    self._vacate(i, s)
+                self._finish(s)
+        self._in_flight.popleft()
+        return len(fl.active)
 
-    def _evict(self, slot: int, seq: _Sequence):
+    def _vacate(self, slot: int, seq: _Sequence):
+        """`seq` leaves its slot, and everything it holds goes back: no
+        later step decodes it.  (Its last token may still be on its way:
+        `_emit` tells its caller.)"""
         with self._lock:
             self._slots[slot] = None
         self._pos[slot] = 0
@@ -1457,10 +1594,12 @@ class ContinuousEngine:
         self._topks[slot] = 0
         self._toks_keys[slot] = 0
         self._release(seq)
-        self._finish(seq)
         self._wake.set()          # page/slot freed: retry page-starved head
 
     def _finish(self, seq: _Sequence, error: Optional[Exception] = None):
+        if seq.done:
+            return
+        seq.done = True
         if seq.trace_ctx is not None and seq.t_first is not None:
             self._record_spans(seq)     # before the caller hears of the end
         if error is not None:

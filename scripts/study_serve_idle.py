@@ -15,12 +15,20 @@ Two readings of the same iterations, which should agree:
   Python (`host_s`), launches (`dispatch_s`) and waits (`ready_wait_s`,
   `waits` of them), plain iterations and admitting ones apart, the
   step's own two parts beside the step's device time — joined to the
-  trace by the `iter=` stat of `serve.engine.admit`, not by clock.  An
-  iteration that admits (or runs a chunk) is launches -> stamp -> fetch:
-  every program enqueued (`dispatch_s`), then one blocking stretch, the
-  prefill's ready stamp (`admission_wait_ms` = `ready_wait_s` less
-  `step_wait_s`, ending `swap_s` into the iteration) and the tokens'
-  fetch (`step_wait_s`).
+  trace by the `iter=` stat of `serve.engine.admit`, not by clock.  Since
+  PR 52 one step stays in flight, and an iteration is launches -> fetch ->
+  stamp: the admission's programs (`admission_dispatch_ms` = `dispatch_s`
+  less `step_dispatch_s`) and THIS iteration's step enqueued, then the
+  tokens of the step launched an iteration AGO fetched (`step_wait_s`: how
+  long the thread was blocked for them) and emitted, then the prefill's
+  ready stamp (`admission_wait_ms` = `ready_wait_s` less `step_wait_s`).
+  `steps` counts the steps launched (`stepped`) and those launched with
+  the one before them unfetched (`ahead`); `gaps` is what a stream sees:
+  emit to emit between two plain iterations, and across an iteration that
+  admits one request (its own tail behind the emit plus the next
+  iteration up to its emit).  A ring without `stepped` is a tree's from
+  before PR 52 (launches -> stamp -> fetch, nothing in flight between
+  iterations) and is read by that order.
 
 It also lists the window's iterations that stand out from their kind by
 50 ms or more with their `gc_s` (was that stall a collection?).  Prints
@@ -251,13 +259,26 @@ def ring_side(ring, only=None):
     have = [k for k in ("iter_s", "host_s", "dispatch_s", "ready_wait_s",
                         "step_dispatch_s", "step_wait_s", "decode_s",
                         "swap_s", "prefill_s") if k in ring[0]]
+    # one step in flight (PR 52)?  A plain iteration then launches a step
+    # AND emits one; the first after an idle stretch only launches
+    # (`filling`), the last only emits (`draining`)
+    ahead = "stepped" in ring[0]
     kinds = {"plain": [r for r in ring if r["active"] and not r["admitted"]
-                       and not r["chunks"]],
+                       and not r["chunks"] and r.get("stepped", 1)],
              "admitting": [r for r in ring if r["admitted"]],
              "admitting_one": [r for r in ring if r["admitted"] == 1],
              "chunk_only": [r for r in ring if r["chunks"]
                             and not r["admitted"]]}
     out = {"iterations": len(ring)}
+    if ahead:
+        kinds["filling"] = [r for r in ring if r["stepped"]
+                            and not r["ahead"]]
+        kinds["draining"] = [r for r in ring if r["active"]
+                             and not r["stepped"]]
+        n, a = (sum(r[k] for r in ring) for k in ("stepped", "ahead"))
+        out["steps"] = {"stepped": n, "ahead": a,
+                        "ahead_share": a / n if n else None}
+    out["gaps"] = stream_gaps(ring, ahead)
     for kind, rs in kinds.items():
         out[kind] = {"n": len(rs), **{
             k + "_ms": 1e3 * _med([r[k] for r in rs]) if rs else None
@@ -268,6 +289,8 @@ def ring_side(ring, only=None):
         if rs and "step_wait_s" in have:
             out[kind]["admission_wait_ms"] = 1e3 * _med(
                 [r["ready_wait_s"] - r["step_wait_s"] for r in rs])
+            out[kind]["admission_dispatch_ms"] = 1e3 * _med(
+                [r["dispatch_s"] - r["step_dispatch_s"] for r in rs])
     # what lies between one record's close and the next one's start: the
     # loop's own turn, or the engine asleep with nothing to run
     ends = [(r["t0"], r["t0"] + r["iter_s"]) for r in ring]
@@ -292,6 +315,46 @@ def ring_side(ring, only=None):
             _med([r["decode_s"] for r in plain])
             - _med([r["step_dispatch_s"] + r["step_wait_s"] for r in plain]))
     return out
+
+
+def stream_gaps(ring, ahead):
+    """Emit to emit, in ms, as a stream sees it: between two plain
+    iterations, and across an iteration that admits ONE request (from its
+    emit to the next iteration's).  An iteration's emit ends at `ts`, but
+    for one that admits with a step in flight: there the prefill's stamp
+    comes behind the emit (`ts` less that wait)."""
+    def quiet(r):
+        return not r["admitted"] and not r["chunks"]
+
+    def emitted(r):
+        if ahead and not quiet(r):
+            return r["ts"] - (r["ready_wait_s"] - r["step_wait_s"])
+        return r["ts"]
+
+    plain, across = [], []
+    for p, r in zip(ring, ring[1:]):
+        if not (p["active"] and r["active"]
+                and r.get("iter", 0) == p.get("iter", -2) + 1
+                # (the engine did not sleep between the two)
+                and r["t0"] - p["t0"] - p["iter_s"] < 1e-3):
+            continue
+        gap = 1e3 * (emitted(r) - emitted(p))
+        if quiet(p) and quiet(r):
+            plain.append(gap)
+        # with a step in flight the admission's prefill runs between the
+        # emit of the iteration that launched it and the next one's; with
+        # none it ran inside the admitting iteration, ahead of its emit
+        one = p if ahead else r
+        if one["admitted"] == 1 and quiet(r if ahead else p):
+            across.append(gap)
+
+    def dist(xs):
+        xs = sorted(xs)
+        return {"n": len(xs), **({} if not xs else {
+            "median": statistics.median(xs),
+            "p90": xs[int(0.9 * (len(xs) - 1))], "max": xs[-1]})}
+
+    return {"plain_ms": dist(plain), "across_one_admission_ms": dist(across)}
 
 
 def stalls(ring, by_ms=50.0):

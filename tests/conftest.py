@@ -81,7 +81,7 @@ _QUICK_FILES = {
     "test_resource_sync.py", "test_retention_ops.py",
     "test_runtime_env.py", "test_sampling.py",
     "test_serve.py", "test_serve_continuous.py", "test_serve_donation.py",
-    "test_serve_fault.py",
+    "test_serve_fault.py", "test_serve_launch_ahead.py",
     "test_serve_prefill.py", "test_serve_live_blocks.py",
     "test_serve_mixed_pools.py", "test_serve_model_interface.py",
     "test_serve_state_kind.py",

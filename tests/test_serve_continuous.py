@@ -343,21 +343,22 @@ def test_stop_fails_waiting_requests(model):
 # ---------------------------------------------------------------------------
 # the engine measures itself: ring records, annotations, request spans
 
-# an iteration that admits one unchunked greedy prompt, each annotation
-# under the one it lies in: every launch is a `dispatch` inside the
-# annotation that was there, nothing between two launches is unannotated,
-# and the thread first blocks (`wait`: the prefill's ready stamp) once the
-# step is launched
+# an iteration that admits one unchunked greedy prompt into an idle
+# engine, each annotation under the one it lies in: every launch is a
+# `dispatch` inside the annotation that was there, nothing between two
+# launches is unannotated, the thread first blocks (`wait`: the prefill's
+# ready stamp) once the step is launched, and there is nothing to fetch:
+# the step's tokens are the NEXT iteration's, behind that one's launch
 _D, _W = "serve.engine.dispatch", "serve.engine.wait"
 _STEP = [("serve.engine.step", None), (_D, "serve.engine.step")]
-_TAIL = [("serve.engine.fetch", None), ("serve.engine.emit", None),
-         ("serve.engine.account", None)]
+_EMIT = [("serve.engine.fetch", None), ("serve.engine.emit", None)]
+_TAIL = _EMIT + [("serve.engine.account", None)]
 _PREFILL = [("serve.engine.prefill", "serve.engine.admit"),
             (_D, "serve.engine.prefill"),
             ("serve.engine.setrow", "serve.engine.prefill"),
             (_D, "serve.engine.setrow")]
 _ANNOTATIONS = ([("serve.engine.admit", None)] + _PREFILL + _STEP
-                + [(_W, None)] + _TAIL)
+                + [(_W, None), ("serve.engine.account", None)])
 # the launches of that iteration as the engine counts them (`launches`)
 _PROGRAMS = ["serve.prefill:8", "serve.setrow", "serve.step"]
 # a request with a temperature draws its keys: launched ahead of the
@@ -368,7 +369,7 @@ _KEYS = ("serve.engine.keys", "serve.engine.admit")
 _SAMPLED_ANNOTATIONS = (
     [("serve.engine.admit", None), _KEYS, (_D, "serve.engine.keys")]
     + _PREFILL + [_KEYS, (_W, "serve.engine.keys")] + _STEP
-    + [(_W, None)] + _TAIL)
+    + [(_W, None), ("serve.engine.account", None)])
 _SAMPLED_PROGRAMS = ["serve.keys"] + _PROGRAMS
 
 
@@ -429,7 +430,11 @@ def test_ring_decomposes_ttft_and_iteration_time(model):
         assert req["request_id"] == "req-2"
         assert req["prompt_tokens"] == 11 and req["shared_tokens"] == 0
         assert req["scanned_tokens"] == 16          # two buckets of 8
-        assert rec["admitted"] == 1 and rec["blocked_slots"] == 1
+        # a request's first token is the step's that its admitting
+        # iteration launched: the next iteration emits it
+        assert rec["admitted"] == 0 and rec["active"] == 2
+        adm = ring[ring.index(rec) - 1]
+        assert adm["admitted"] == 1 and adm["blocked_slots"] == 1
         assert [r["blocked_slots"] for r in ring
                 if not r["admitted"]] == [0] * (len(ring) - 2)
         assert sum(len(r["requests"]) for r in ring) == 2
@@ -460,44 +465,62 @@ def test_ring_decomposes_ttft_and_iteration_time(model):
 def test_ring_splits_device_wait_into_dispatch_and_ready_wait(model):
     """Every launch is stamped entered -> returned, every wait from where
     the thread was last let go: the two sums ARE `device_wait_s` (same
-    stamps), a plain iteration's step lies inside `decode_s`, and a record
-    carries its iteration's ordinal, the launches it made and the times it
-    blocked."""
+    stamps), a plain iteration's launch and fetch lie inside `decode_s`,
+    and a record carries its iteration's ordinal, the launches it made,
+    the times it blocked, whether it launched a step and whether that step
+    went out with the one before it unfetched."""
     st, ring, rid = _second_request_while_first_decodes(model)
     assert [r["iter"] for r in ring] == list(range(1, len(ring) + 1))
-    # a plain iteration launches the step and waits for its tokens; one
-    # that admits an unchunked greedy prompt launches its prefill, its
-    # row and the step, and only then waits: the stamp, the tokens
+    # every iteration but the last launches a step, every one but the
+    # first (the idle engine's: nothing in flight) ahead of a fetch; the
+    # last fetches the last step and launches nothing
+    assert [r["stepped"] for r in ring] == [1] * (len(ring) - 1) + [0]
+    assert [r["ahead"] for r in ring] == [0] + [1] * (len(ring) - 2) + [0]
+    assert (st["steps"], st["steps_ahead"]) == (len(ring) - 1, len(ring) - 2)
+    assert [bool(r["active"]) for r in ring] == [False] + [True] * (
+        len(ring) - 1)
+    # a plain iteration launches the step and waits for the tokens of the
+    # step before; one that admits an unchunked greedy prompt launches its
+    # prefill, its row and the step, and only then waits: the tokens (if
+    # a step was in flight), the stamp
     assert [r["launches"] for r in ring] == [
-        len(_PROGRAMS) if r["admitted"] else 1 for r in ring]
+        (len(_PROGRAMS) if r["admitted"] else 1) * r["stepped"]
+        for r in ring]
     assert [r["waits"] for r in ring] == [
-        2 if r["admitted"] else 1 for r in ring]
+        bool(r["admitted"]) + bool(r["active"]) for r in ring]
     assert sum(r["admitted"] for r in ring) == 2
     for r in ring:
         assert r["dispatch_s"] + r["ready_wait_s"] == pytest.approx(
             r["device_wait_s"], abs=1e-9)
         assert r["host_s"] + r["device_wait_s"] == pytest.approx(
             r["iter_s"], abs=1e-9)
-        assert 0 < r["step_dispatch_s"] and 0 < r["step_wait_s"]
+        assert (0 < r["step_dispatch_s"]) == bool(r["stepped"])
+        assert (0 < r["step_wait_s"]) == bool(r["active"])
+        assert (0 < r["decode_s"]) == bool(r["active"])
+        assert r["step_dispatch_s"] + r["step_wait_s"] <= r["decode_s"] \
+            or not r["active"]
         if r["admitted"]:       # the admission's programs are apart, and
-            # the step is launched before the admission's work is done
-            assert r["step_dispatch_s"] < r["dispatch_s"]
-            assert r["step_wait_s"] < r["ready_wait_s"]
-            assert r["step_wait_s"] <= r["decode_s"]
-            assert r["dispatch_s"] < r["swap_s"]
-            assert 0 < r["prefill_s"] <= r["swap_s"]
+            # the step is launched before the admission's work is done;
+            # the admission's share and the step's tile the iteration
+            assert r["step_dispatch_s"] < r["dispatch_s"] < r["swap_s"]
+            assert r["swap_s"] + r["decode_s"] == pytest.approx(
+                r["ts"] - r["t0"], abs=1e-9)
+            assert 0 < r["prefill_s"] <= r["swap_s"] + r["decode_s"]
+            if r["active"]:     # the tokens are fetched before the stamp
+                assert r["step_wait_s"] < r["ready_wait_s"]
         else:
+            assert r["swap_s"] == 0
             assert r["step_dispatch_s"] == r["dispatch_s"]
             assert r["step_wait_s"] == r["ready_wait_s"]
-            assert r["step_dispatch_s"] + r["step_wait_s"] <= r["decode_s"]
-    assert st["launches"] == 2 * len(_PROGRAMS) + len(ring) - 2
+    assert st["launches"] == 2 * len(_PROGRAMS) + len(ring) - 3
 
 
 def test_chunk_iterations_launch_one_program_and_no_step(model):
     """A prompt of three chunks alone in the engine: two iterations run
     one prefill program each and no step (`step_dispatch_s` 0), the third
     the last chunk, the row and the first step; each waits for its chunk
-    once, the third for the tokens too."""
+    once.  The fourth launches the second step and fetches the first, the
+    fifth has nothing to launch and fetches the second."""
     eng = _make_engine(model, prefill_chunk=8)
     try:
         eng.collect(eng.submit(list(range(1, 21)), max_new_tokens=2),
@@ -505,9 +528,12 @@ def test_chunk_iterations_launch_one_program_and_no_step(model):
     finally:
         eng.stop()
     ring = eng.phase_ring()         # whole: the engine thread has ended
-    assert [r["launches"] for r in ring] == [1, 1, len(_PROGRAMS), 1]
-    assert [r["chunks"] for r in ring] == [1, 1, 1, 0]
-    assert [r["waits"] for r in ring] == [1, 1, 2, 1]
+    assert [r["launches"] for r in ring] == [1, 1, len(_PROGRAMS), 1, 0]
+    assert [r["chunks"] for r in ring] == [1, 1, 1, 0, 0]
+    assert [r["waits"] for r in ring] == [1, 1, 1, 1, 1]
+    assert [r["stepped"] for r in ring] == [0, 0, 1, 1, 0]
+    assert [r["ahead"] for r in ring] == [0, 0, 0, 1, 0]
+    assert [r["active"] for r in ring] == [0, 0, 0, 1, 1]
     for r in ring[:2]:
         assert r["active"] == 0 and r["decode_s"] == 0
         assert r["step_dispatch_s"] == 0 and r["step_wait_s"] == 0
@@ -537,7 +563,7 @@ def test_iteration_nests_annotations_and_names_every_launch(
     assert [kw["program"] for n, kw, _ in seen[:first]
             if n == _D] == programs
     assert (ring[0]["launches"], ring[0]["waits"]) == (
-        len(programs), 2 + (temperature > 0))
+        len(programs), 1 + (temperature > 0))
     # nothing waits for the prefill between its launch and the step's:
     # its ready stamp is the first thing after the step's `dispatch`
     step = nest.index((_D, "serve.engine.step"))
@@ -548,9 +574,11 @@ def test_iteration_nests_annotations_and_names_every_launch(
     assert prefill == {"request_id": "req-7", "tokens": len(PROMPT),
                        "bucket": 8}
     # `admit` and `step` carry the ordinal of their iteration's record
-    for name in ("serve.engine.admit", "serve.engine.step"):
+    # (the last iteration fetches the last step and launches none)
+    for name, key in (("serve.engine.admit", "iter"),
+                      ("serve.engine.step", "stepped")):
         assert [kw for n, kw, _ in seen if n == name] == [
-            {"iter": r["iter"]} for r in ring]
+            {"iter": r["iter"]} for r in ring if r[key]]
     assert not any(kw for n, kw, _ in seen if n in (
         _W, "serve.engine.setrow", "serve.engine.keys",
         "serve.engine.fetch", "serve.engine.emit", "serve.engine.account"))
@@ -663,10 +691,12 @@ def test_a_failing_prefill_is_reported_at_the_wait_and_holds_nothing(
 
 
 def test_two_admissions_in_one_iteration_count_no_stretch_twice(model):
-    """Two requests admitted by ONE iteration: five launches, then three
-    waits (a stamp each, the tokens); the second's prefill counts from the
-    first's ready stamp, so the prefill seconds stay inside `swap_s`, and
-    its wait behind the first is `chunk_wait_s`."""
+    """Two requests admitted by ONE iteration of an idle engine: five
+    launches, then two waits (a stamp each; no step was in flight); the
+    second's prefill counts from the first's ready stamp, so the prefill
+    seconds stay inside `swap_s`, and its wait behind the first is
+    `chunk_wait_s`.  The next iteration launches their last step by count
+    — both slots are free from there — and emits their first tokens."""
     eng = _make_engine(model, max_slots=2)
     t = threading.Thread(target=lambda: None)
     t.start()
@@ -677,13 +707,22 @@ def test_two_admissions_in_one_iteration_count_no_stretch_twice(model):
                 for p in (PROMPT, list(range(20, 31)))]
         eng._iteration()
         (rec,) = eng.phase_ring()
-        assert rec["admitted"] == 2 and rec["active"] == 2
-        assert (rec["launches"], rec["waits"]) == (5, 3)
-        first, second = rec["requests"]
-        assert [q["rid"] for q in rec["requests"]] == [s.rid for s in seqs]
-        assert first["prefill_s"] + second["prefill_s"] == pytest.approx(
+        assert rec["admitted"] == 2 and rec["active"] == 0
+        assert (rec["stepped"], rec["ahead"]) == (1, 0)
+        assert (rec["launches"], rec["waits"]) == (5, 2)
+        assert rec["requests"] == [] and rec["decode_s"] == 0
+        assert seqs[0].prefill_s + seqs[1].prefill_s == pytest.approx(
             rec["prefill_s"])
         assert 0 < rec["prefill_s"] <= rec["swap_s"]
+        eng._iteration()
+        rec = eng.phase_ring()[1]
+        assert rec["admitted"] == 0 and rec["active"] == 2
+        assert (rec["stepped"], rec["ahead"]) == (1, 1)
+        assert (rec["launches"], rec["waits"]) == (1, 1)
+        first, second = rec["requests"]
+        assert [q["rid"] for q in rec["requests"]] == [s.rid for s in seqs]
+        assert [q["prefill_s"] for q in rec["requests"]] == [
+            s.prefill_s for s in seqs]
         assert first["chunk_wait_s"] == 0 < second["chunk_wait_s"]
         assert seqs[0].t_prefill < seqs[1].t_prefill < seqs[0].t_ready \
             <= seqs[1].t_ready < seqs[1].t_first
@@ -692,8 +731,12 @@ def test_two_admissions_in_one_iteration_count_no_stretch_twice(model):
                      + q["prefill_s"] + q["chunk_wait_s"]
                      + q["first_step_wait_s"])
             assert parts == pytest.approx(q["ttft_s"], abs=1e-9)
+        # their last token by count is in flight: the slots are free, the
+        # callers have not heard
+        assert eng._slots == [None, None] and len(eng._in_flight) == 1
+        assert not any(s.result.done() for s in seqs)
         eng._iteration()
-        assert all(s.result.done() for s in seqs)
+        assert all(s.result.done() for s in seqs) and not eng._in_flight
     finally:
         eng.stop()
     for s, p in zip(seqs, (PROMPT, list(range(20, 31)))):
@@ -764,9 +807,9 @@ def test_phase_histogram_observes_dispatch_once_an_iteration(model,
         eng.stop()
     ring = eng.phase_ring()
     assert [v for ph, v in seen if ph == "dispatch"] == [
-        r["dispatch_s"] for r in ring]
+        r["dispatch_s"] for r in ring if r["launches"]]
     assert [v for ph, v in seen if ph == "decode"] == [
-        r["decode_s"] for r in ring]
+        r["decode_s"] for r in ring if r["active"]]
     assert {ph for ph, _ in seen} == {"swap", "prefill", "decode",
                                       "dispatch"}
 

@@ -142,6 +142,12 @@ def test_engine_calls_consume_their_state_and_go_on(models, which):
             assert all(a.is_deleted() for a in given), n
             assert not any(a.is_deleted() for a in
                            jax.tree.leaves((eng._cache, eng._logits)))
+        # the fifth step's tokens are in flight; the iteration that
+        # fetches them launches nothing and consumes nothing
+        assert not seq.result.done()
+        given = jax.tree.leaves((eng._cache, eng._logits))
+        eng._iteration()
+        assert not any(a.is_deleted() for a in given)
         out = seq.result.result(timeout=0)["completion"]
     finally:
         eng.stop()

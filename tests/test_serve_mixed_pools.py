@@ -119,11 +119,12 @@ def test_chunked_prefill_then_decode_through_both_pools(model):
     for _ in range(200):
         eng._iteration()
         for i, s in enumerate(seqs):
-            # after an iteration a decoding slot's row is the one its NEXT
-            # token will be drawn from (the first token's row is used up
-            # inside the iteration that ends the prefill)
-            if s.t_ready and not s.result.done():
-                rows[i][len(s.generated)] = np.asarray(
+            # after an iteration a decoding slot's row is the one the NEXT
+            # step launched will draw from, as many steps in as were
+            # launched for it (the first token's row is used up inside
+            # the iteration that ends the prefill)
+            if s.t_ready and eng._slots[s.slot] is s:
+                rows[i][s.launched] = np.asarray(
                     eng._logits)[s.slot].copy()
         if all(s.result.done() for s in seqs):
             break
@@ -222,13 +223,15 @@ def test_sliding_pages_return_as_the_window_passes(model):
         a = eng._allocs["sliding"]
         held.append(a.used_pages)
         assert a.used_pages + a.reserved <= width
-        if seq.t_ready and not seq.result.done():
+        if seq.t_ready and eng._slots[seq.slot] is seq:
             live = sorted(seq.win["sliding"])
             pos = int(eng._pos[seq.slot])
             # just the pages a query at `pos` can still see
             assert live[0] == max(0, pos - 8 + 1) // PS, (live, pos)
             assert live[-1] >= (pos - 1) // PS
-    assert max(held) <= width and min(held[:-1]) >= 2
+    # (the pages go back where the last step by count is launched, an
+    # iteration before the one that fetches its token)
+    assert max(held) <= width and min(held[:-2]) >= 2 and held[-2:] == [0, 0]
     # 50 positions went through, the pool never held more than 5 pages
     assert eng._totals["window_pages_returned"] >= 50 // PS - width
     assert eng._allocs["full"].used_pages == 0

@@ -54,8 +54,12 @@ def _run(eng, seqs, rows=None, limit=400):
     for _ in range(limit):
         eng._iteration()
         for i, s in enumerate(seqs):
-            if rows is not None and s.t_ready and not s.result.done():
-                rows[i][len(s.generated)] = np.asarray(
+            # after an iteration a decoding slot's row is the one the NEXT
+            # step launched will draw from: as many steps in as were
+            # launched for it (their tokens come an iteration later)
+            if (rows is not None and s.t_ready
+                    and eng._slots[s.slot] is s):
+                rows[i][s.launched] = np.asarray(
                     eng._logits)[s.slot].copy()
         if all(s.result.done() for s in seqs):
             return
