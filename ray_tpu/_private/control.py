@@ -1698,10 +1698,32 @@ class ControlServer:
 
     # -- health / failure detection ---------------------------------------
 
+    def _credit_stall(self, late_s: float):
+        """The failure detector does not count time it was deaf itself.
+        `late_s` is how much later than asked the health loop woke: this
+        process stood still that long (stopped, starved, or the whole
+        machine frozen — on a TPU VM every process stops for 4-9 s when
+        a worker first reaches the chip), so no heartbeat could be taken
+        in meanwhile, and a node's silence over that stretch says nothing
+        about the node.  Every node's clock is moved on by it (reference
+        analog: Cassandra's FailureDetector skips a round after a local
+        pause); a node that is really gone is found that much later."""
+        if late_s <= HEARTBEAT_INTERVAL_S:
+            return
+        logger.warning("control stood still for %.1fs: not counted "
+                       "against any node's heartbeats", late_s)
+        now = time.monotonic()
+        with self.lock:
+            for rec in self.nodes.values():
+                rec.last_heartbeat = min(now, rec.last_heartbeat + late_s)
+
     def _health_loop(self):
+        tick = time.monotonic()
         while not self._stop.is_set():
             time.sleep(HEARTBEAT_INTERVAL_S)
             now = time.monotonic()
+            self._credit_stall(now - tick - HEARTBEAT_INTERVAL_S)
+            tick = now
             dead_nodes: List[NodeRecord] = []
             drain_expired: List[NodeRecord] = []
             quarantine_expired: List[NodeRecord] = []

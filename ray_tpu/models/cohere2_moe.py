@@ -53,8 +53,10 @@ __all__ = ["Cohere2MoEConfig", "init", "apply", "cache_kinds",
 
 # what a serve program returns beside logits and cache, in this order
 # (f32 scalars, summed over the layers): token-expert pairs that fell on
-# held experts, the largest load of a held expert, held experts touched
-STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched")
+# held experts, the largest load of a held expert, held experts touched,
+# held experts' visits by a trip of grouped products (over the touched:
+# how often a touched expert's weights were read; `ops.moe.held_load_stats`)
+STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "moe_reads")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,11 +81,12 @@ class Cohere2MoEConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
     kv_block: int = 512               # keys scored at once on the serve path
-    # sorted pairs per grouped product.  Only the pairs on held experts are
-    # computed (an eighth of them when 16 of 128 experts are held), a tile
-    # at a time: 1024 holds what a 512-token chunk brings here (512 +- 21
-    # pairs a layer) with room, so a chunk is one product; a longer program
-    # or a larger share takes as many as it needs
+    # at most this many sorted rows a product; the row block of `ops/moe`
+    # (ROW_BLOCK, 128: what the chip's grouped matmul takes before a visited
+    # expert's product outlasts its weights' read) is the usual bound, so
+    # this binds only where it is smaller (the tiny configuration's 16).
+    # Only the pairs on held experts are computed (an eighth of them when
+    # 16 of 128 experts are held), for as many trips as they need
     moe_tile: int = 1024
     # what gpt's shared helpers read off a config
     norm: str = "ln"
@@ -165,18 +168,20 @@ def init(key, cfg: Cohere2MoEConfig) -> Dict[str, Any]:
 
 def _ffn(h, layer, cfg: Cohere2MoEConfig, live=None):
     """Routed (held experts' part) + mean of the shared experts, on the
-    normed input h [N, D].  Returns ([N, D], loads [held])."""
+    normed input h [N, D].  Returns ([N, D], (loads [held], reads):
+    `held_expert_ffn`'s counts, for `held_load_stats`)."""
     with jax.named_scope("moe_router"):
         w, idx = route_sigmoid_topk(h, layer["router"], cfg.top_k)
     with jax.named_scope("moe_experts"):
-        routed, loads = held_expert_ffn(
+        routed, loads, reads = held_expert_ffn(
             h, w, idx, layer["wg"], layer["wu"], layer["wd"],
             first=cfg.experts_first, tile=cfg.moe_tile, live=live)
     with jax.named_scope("moe_shared"):
         shared = swiglu(h, layer["shared_gate"].astype(cfg.dtype),
                         layer["shared_up"].astype(cfg.dtype),
                         layer["shared_down"].astype(cfg.dtype))
-    return (routed + shared.astype(jnp.float32) / cfg.n_shared), loads
+    routed = routed + shared.astype(jnp.float32) / cfg.n_shared
+    return routed, (loads, reads)
 
 
 def _block(x, layer, kind: str, pos, attend, cfg: Cohere2MoEConfig,
@@ -195,10 +200,10 @@ def _block(x, layer, kind: str, pos, attend, cfg: Cohere2MoEConfig,
     with jax.named_scope("attn_window" if kind == "sliding" else "attn_full"):
         o = attend(q.reshape(B, cfg.n_kv_heads, G, T, cfg.d_head), k, v)
     att = attn_out(o.reshape(B, cfg.n_heads, T, cfg.d_head), layer, cfg)
-    ffn, loads = _ffn(h.reshape(B * T, D), layer, cfg,
-                      None if live is None else live.reshape(B * T))
+    ffn, held = _ffn(h.reshape(B * T, D), layer, cfg,
+                     None if live is None else live.reshape(B * T))
     x = x + att + ffn.reshape(B, T, D).astype(x.dtype)
-    return x, loads
+    return x, held
 
 
 def _window(kind: str, cfg: Cohere2MoEConfig) -> Optional[int]:
@@ -357,15 +362,15 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg):
     flat_pos = pos.reshape(B * T)
     per_kind = {kind: kind_io(kind, tab, pos, real, last, flat_pos, ps, npb)
                 for kind, tab in ptabs.items()}
-    new_cache, loads = [], []
+    new_cache, held = [], []
     for layer, kind, arena in zip(params["layers"], cfg.layer_types, cache):
         tabp, bases, write_at, n_blocks = per_kind[kind]
         attend, box = _paged_attend(kind, arena, tabp, bases, pos, write_at,
                                     n_blocks, cfg)
         x, ld = _block(x, layer, kind, pos, attend, cfg, live=real)
         new_cache.append(box["arena"])
-        loads.append(ld)
-    return x, new_cache, jnp.stack(held_load_stats(loads))
+        held.append(ld)
+    return x, new_cache, jnp.stack(held_load_stats(held))
 
 
 def paged_decode_step(params, cache, tokens, ptabs, pos, cfg):

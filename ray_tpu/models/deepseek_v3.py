@@ -82,14 +82,15 @@ __all__ = ["DeepSeekV3Config", "init", "apply", "cache_kinds",
 
 # what a serve program returns beside logits and cache, in this order (f32
 # scalars, summed over the layers): token-expert pairs that fell on held
-# experts, the largest load of a held expert, held experts touched (as
-# cohere2_moe); (query, visible key) pairs of one head, and keys visible
-# to the program — a step's live rows see their own contexts, a chunk's
-# rows one context; key positions its attention FETCHED (`mla_keys` is what
-# it needed: the block loop fetches every row of the batch every block up
-# to the longest context, the decode kernel each live slot's own pages)
-STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "mla_pairs",
-              "mla_keys", "mla_walked_keys")
+# experts, the largest load of a held expert, held experts touched, held
+# experts' visits by a trip of grouped products (as cohere2_moe); (query,
+# visible key) pairs of one head, and keys visible to the program — a
+# step's live rows see their own contexts, a chunk's rows one context; key
+# positions its attention FETCHED (`mla_keys` is what it needed: the block
+# loop fetches every row of the batch every block up to the longest
+# context, the decode kernel each live slot's own pages)
+STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "moe_reads",
+              "mla_pairs", "mla_keys", "mla_walked_keys")
 
 KIND = "full"
 
@@ -135,7 +136,9 @@ class DeepSeekV3Config:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
     kv_block: int = 512                # keys scored at once on the serve path
-    moe_tile: int = 512                # sorted pairs per grouped product
+    # at most this many sorted rows a product; the row block of `ops/moe`
+    # (ROW_BLOCK) is the usual bound, so this binds only where it is smaller
+    moe_tile: int = 512
     # what gpt's shared helpers and the engine read off a config
     pos: str = "rope"
     tie_embeddings: bool = False
@@ -351,7 +354,8 @@ def latent_attend(q_nope, q_pe, qpos, fetch, n_blocks, layer,
 
 def layer_ffn(h, layer, cfg: DeepSeekV3Config, live=None):
     """The layer's feed-forward on the normed input h [N, D] -> ([N, D]
-    f32, loads [held] or None for a dense layer)."""
+    f32, `held_expert_ffn`'s (loads [held], reads) or None for a dense
+    layer)."""
     dt = cfg.dtype
     if "router" not in layer:
         with jax.named_scope("mlp"):
@@ -364,14 +368,14 @@ def layer_ffn(h, layer, cfg: DeepSeekV3Config, live=None):
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             scale=cfg.routed_scale)
     with jax.named_scope("moe_experts"):
-        routed, loads = held_expert_ffn(
+        routed, loads, reads = held_expert_ffn(
             h, w, idx, layer["wg"], layer["wu"], layer["wd"],
             first=cfg.experts_first, tile=cfg.moe_tile, live=live)
     with jax.named_scope("moe_shared"):
         shared = swiglu(h, layer["shared_gate"].astype(dt),
                         layer["shared_up"].astype(dt),
                         layer["shared_down"].astype(dt))
-    return routed + shared.astype(jnp.float32), loads
+    return routed + shared.astype(jnp.float32), (loads, reads)
 
 
 def _block(x, layer, pos, write, fetch, n_blocks, absorbed: bool,
@@ -392,9 +396,9 @@ def _block(x, layer, pos, write, fetch, n_blocks, absorbed: bool,
         x = x + jnp.einsum("bhtv,hvd->btd", o.astype(dt),
                            layer["wo"].astype(dt)).astype(x.dtype)
     h2 = rms_norm(x, layer["mlp_norm"], cfg.rms_eps).astype(dt)
-    ffn, loads = layer_ffn(h2.reshape(B * T, D), layer, cfg,
-                           None if live is None else live.reshape(B * T))
-    return x + ffn.reshape(B, T, D).astype(x.dtype), loads
+    ffn, held = layer_ffn(h2.reshape(B * T, D), layer, cfg,
+                          None if live is None else live.reshape(B * T))
+    return x + ffn.reshape(B, T, D).astype(x.dtype), held
 
 
 def head_logits(params, x, cfg: DeepSeekV3Config):
@@ -405,15 +409,14 @@ def head_logits(params, x, cfg: DeepSeekV3Config):
                           preferred_element_type=jnp.float32)
 
 
-def _stats(loads: List[jax.Array], pos, real, walked,
-           cfg: DeepSeekV3Config):
-    """The STEP_STATS vector of one program: `loads` of its expert layers,
-    its rows' positions and which of them are real, the key positions its
-    layers fetched."""
+def _stats(held: list, pos, real, walked, cfg: DeepSeekV3Config):
+    """The STEP_STATS vector of one program: its expert layers' (loads,
+    reads), its rows' positions and which of them are real, the key
+    positions its layers fetched."""
     seen = jnp.where(real, pos + 1, 0).astype(jnp.float32)     # [B, T]
     mla = [seen.sum() * cfg.n_layers, seen.max(axis=1).sum() * cfg.n_layers,
            jnp.asarray(walked, jnp.float32)]
-    return jnp.stack(held_load_stats(loads) + mla)
+    return jnp.stack(held_load_stats(held) + mla)
 
 
 def apply(params, tokens, cfg: DeepSeekV3Config):
@@ -559,7 +562,7 @@ def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
     d, ps = cache[0].shape[1:]
     bind, n_blocks = page_io(ptab, pos, real, d, ps, cfg)
     x = slot_embed(params, toks, pos, cfg)
-    new_cache, loads, walked = [], [], 0
+    new_cache, held, walked = [], [], 0
     for layer, arena in zip(params["layers"], cache):
         write, fetch, box = bind(arena)
         x, ld = _block(x, layer, pos, write, fetch, n_blocks, absorbed, cfg,
@@ -567,8 +570,8 @@ def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
         new_cache.append(box["arena"])
         walked += box["walked"]
         if ld is not None:
-            loads.append(ld)
-    return x, new_cache, _stats(loads, pos, real, walked, cfg)
+            held.append(ld)
+    return x, new_cache, _stats(held, pos, real, walked, cfg)
 
 
 def paged_decode_step(params, cache, tokens, ptabs, pos, cfg, absorbed=None):

@@ -73,11 +73,13 @@ __all__ = ["Ling3Config", "init", "apply", "cache_kinds", "init_paged_cache",
 
 # what a serve program returns beside logits and cache, in this order (f32
 # scalars): token-expert pairs that fell on held experts, the largest load
-# of a held expert, held experts touched (summed over the expert layers,
-# as deepseek_v3), and the states ONE KDA layer's update moved — a step's
-# live slots where the kernel runs (it moves nothing for an empty slot),
-# every slot on the gather / scatter path; one for a chunk
-STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "kda_live")
+# of a held expert, held experts touched, held experts' visits by a trip of
+# grouped products (summed over the expert layers, as deepseek_v3), and the
+# states ONE KDA layer's update moved — a step's live slots where the
+# kernel runs (it moves nothing for an empty slot), every slot on the
+# gather / scatter path; one for a chunk
+STEP_STATS = ("moe_pairs", "moe_load_max", "moe_touched", "moe_reads",
+              "kda_live")
 
 FULL, KDA = "full", "kda"
 ABSORB_ROWS = _dm.ABSORB_ROWS
@@ -119,7 +121,9 @@ class Ling3Config:
     # ops.kda.kda_step's `impl` (None: by backend)
     kda_impl: Optional[str] = None
     kv_block: int = 512                # keys scored at once on the serve path
-    moe_tile: int = 512                # sorted pairs per grouped product
+    # at most this many sorted rows a product; the row block of `ops/moe`
+    # (ROW_BLOCK) is the usual bound, so this binds only where it is smaller
+    moe_tile: int = 512
     # what gpt's shared helpers and the engine read off a config
     pos: str = "rope"
     tie_embeddings: bool = False
@@ -355,10 +359,10 @@ def _feed_forward(x, layer, cfg: Ling3Config, live=None):
     """x [B, T, D] with the layer's feed-forward added (deepseek_v3's:
     dense, or routed over the held experts + the shared one)."""
     B, T, D = x.shape
-    ffn, loads = _dm.layer_ffn(
+    ffn, held = _dm.layer_ffn(
         _normed(x, layer, "mlp_norm", cfg).reshape(B * T, D), layer, cfg,
         None if live is None else live.reshape(B * T))
-    return x + ffn.reshape(B, T, D).astype(x.dtype), loads
+    return x + ffn.reshape(B, T, D).astype(x.dtype), held
 
 
 def apply(params, tokens, cfg: Ling3Config):
@@ -438,7 +442,7 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg: Ling3Config,
     layer meets its pages through deepseek_v3's `page_io`; a KDA layer is
     `kda_layer(j, x, h, layer, state, tail)` -> (x, state, tail) over the
     two state arenas, j its index among the KDA layers.  Returns
-    (x [B, T, D], cache, the expert layers' loads)."""
+    (x [B, T, D], cache, the expert layers' (loads, reads))."""
     T = toks.shape[1]
     if absorbed is None:
         absorbed = T <= ABSORB_ROWS
@@ -448,7 +452,7 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg: Ling3Config,
         d, ps = latent[0].shape[1:]
         bind, n_blocks = _dm.page_io(ptabs[FULL], pos, real, d, ps, cfg)
     x = slot_embed(params, toks, pos, cfg)
-    loads, n_kda, n_mla = [], 0, 0
+    held, n_kda, n_mla = [], 0, 0
     for l, layer in enumerate(params["layers"]):
         h = _normed(x, layer, "attn_norm", cfg)
         if cfg.is_mla(l):
@@ -461,8 +465,8 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg: Ling3Config,
             n_kda += 1
         x, ld = _feed_forward(x, layer, cfg, live=real)
         if ld is not None:
-            loads.append(ld)
-    return x, {"latent": latent, "state": state, "tail": tail}, loads
+            held.append(ld)
+    return x, {"latent": latent, "state": state, "tail": tail}, held
 
 
 def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: Ling3Config,
@@ -486,13 +490,13 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: Ling3Config,
                             live, impl=cfg.kda_impl)
         return _kda_out(x, o[:, None], gate, layer, cfg), state, tail
 
-    x, cache, loads = _paged_pass(params, cache, tokens[:, None], ptabs,
-                                  pos[:, None], live[:, None], cfg, kda_layer,
-                                  absorbed)
+    x, cache, held = _paged_pass(params, cache, tokens[:, None], ptabs,
+                                 pos[:, None], live[:, None], cfg, kda_layer,
+                                 absorbed)
     moved = (live.sum() if resolve_impl(cfg.kda_impl) != "xla"
              else jnp.asarray(B)).astype(jnp.float32)
     return (_dm.head_logits(params, x[:, 0], cfg), cache,
-            jnp.stack(held_load_stats(loads) + [moved]))
+            jnp.stack(held_load_stats(held) + [moved]))
 
 
 def _carried(first, arena, idx):
@@ -526,12 +530,12 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
         return (x, state.at[j, idx].set(s1.astype(state.dtype)),
                 tail.at[j, idx].set(t1))
 
-    x, cache, loads = _paged_pass(
+    x, cache, held = _paged_pass(
         params, cache, toks[None], {FULL: ptab_rows[FULL][None]},
         (start + t)[None], real[None], cfg, kda_layer, absorbed)
     x = jax.lax.dynamic_index_in_dim(x[0], last_idx, 0, keepdims=False)
     return (_dm.head_logits(params, x, cfg), cache,
-            jnp.stack(held_load_stats(loads) + [jnp.ones((), jnp.float32)]))
+            jnp.stack(held_load_stats(held) + [jnp.ones((), jnp.float32)]))
 
 
 # the leaves the programs cast to cfg.dtype where they use them; the norms,

@@ -14,6 +14,12 @@ natively here, TPU-first:
     device runs only its local experts, then the inverse on the way back.
   * Aux losses (load-balance, router z-loss) are returned to the caller —
     the trainer adds them to the objective.
+  * Serving runs a chip's SHARE of an expert layer without capacity
+    (`held_expert_ffn`, second half of the file): the pairs that fall on
+    the held experts, sorted, through `jax.lax.ragged_dot` at most
+    ROW_BLOCK sorted rows a product (a caller's `tile` may bound it lower;
+    the row block is the usual bound), a trip of products ending where an
+    expert ends.
 """
 
 from __future__ import annotations
@@ -187,22 +193,47 @@ def route_sigmoid_grouped(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     return w, idx.astype(jnp.int32)
 
 
+# The sorted rows ONE grouped product takes at most.  On a TPU
+# `jax.lax.ragged_dot` is a grouped matmul kernel tiled `tm, tk, tn` with
+# tm = min(rows of the call, 512): it visits every (expert, tm-row block)
+# that holds one of the expert's rows and puts the WHOLE block of rows
+# through the expert's tk x tn weight tile, keeping the expert's own.  A
+# share's experts own a few rows each (8-32 of a chunk's, one or two of a
+# step's), so at tm = 512 a visit is a 512-row product of which 500 rows
+# are thrown away, and it outlasts its tile's read from HBM whatever D and
+# F are.  The two meet at peak FLOP/s / peak bytes/s rows — 240 for bf16
+# on a v5e (197 TFLOP/s, 819 GB/s) — so a block at or under that makes a
+# touched expert cost its weights' read.  It depends on the chip alone,
+# not on a model: no configuration sets it.
+ROW_BLOCK = 128
+
+
 def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                     *, first: int, tile: int = 512,
                     live: Optional[jax.Array] = None
-                    ) -> Tuple[jax.Array, jax.Array]:
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The held experts' part of a routed SwiGLU layer, dropless.
 
     h [N, D]; (weights, idx) [N, k] from the router over all experts;
     w_gate / w_up [count, D, F], w_down [count, F, D] are experts
     first..first+count-1.  The N*k pairs are sorted by held expert (pairs
     on absent experts last) and go through grouped matrix products
-    (`jax.lax.ragged_dot`) `tile` sorted rows at a time, for as many tiles
-    as hold a pair of a held expert — a traced trip count, so a chunk whose
-    pairs mostly fall elsewhere costs what falls here.  `live` [N] bool
-    takes rows out of the routing (a slot batch's empty slots).
-    Returns (out [N, D] f32, loads [count] int32: pairs per held expert)."""
+    (`jax.lax.ragged_dot`) a trip at a time, for as many trips as the pairs
+    of held experts ask — a traced trip count, so a chunk whose pairs
+    mostly fall elsewhere costs what falls here.  A trip takes at most
+    M = min(`tile`, N*k, ROW_BLOCK) sorted rows and ENDS WHERE AN EXPERT
+    ENDS: rows [lo, hi) with hi the largest end of an expert's rows at or
+    under lo + M, so no expert's weights are read by two trips — but for
+    an expert with more than M rows left, which takes M of them and needed
+    the visits anyway.  `tile` is an upper bound a caller may set (the tiny
+    test configurations': several trips at toy sizes); ROW_BLOCK is the
+    usual one.  `live` [N] bool takes rows out of the routing (a slot
+    batch's empty slots).
+    Returns (out [N, D] f32, loads [count] int32: pairs per held expert,
+    reads int32: experts visited summed over the trips — an expert counts
+    once for each trip that holds a row of it, so `reads` over the experts
+    with a pair is how often a touched expert's weights were read)."""
     N, D = h.shape
     k = idx.shape[1]
     count = w_gate.shape[0]
@@ -216,18 +247,18 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
     ends = jnp.cumsum(loads)
     starts = ends - loads
     n_held = ends[-1]
-    M = min(int(tile), N * k)
-    n_tiles = -(-(N * k) // M)
-    pad = n_tiles * M - N * k
-    tok_of = jnp.pad(order // k, (0, pad))                 # sorted row -> token
-    w_of = jnp.pad(weights.reshape(N * k)[order], (0, pad))
+    M = min(int(tile), N * k, ROW_BLOCK)
+    tok_of = jnp.pad(order // k, (0, M))                # sorted row -> token
+    w_of = jnp.pad(weights.reshape(N * k)[order], (0, M))
     dt = h.dtype
 
-    def one_tile(t, out):
-        lo = t * M
+    def one_trip(carry):
+        lo, out, reads = carry
+        whole = jnp.max(jnp.where(ends <= lo + M, ends, 0))
+        hi = jnp.where(whole > lo, whole, lo + M)
         tok = jax.lax.dynamic_slice_in_dim(tok_of, lo, M)
         wt = jax.lax.dynamic_slice_in_dim(w_of, lo, M)
-        gs = (jnp.clip(ends, lo, lo + M) - jnp.clip(starts, lo, lo + M))
+        gs = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
         x = h[tok]
         g = jax.lax.ragged_dot(x, w_gate.astype(dt), gs,
                                preferred_element_type=jnp.float32)
@@ -236,24 +267,31 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
         y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(dt),
                                w_down.astype(dt), gs,
                                preferred_element_type=jnp.float32)
-        valid = (lo + jnp.arange(M)) < n_held      # rows of no group: zero
+        valid = jnp.arange(M) < hi - lo            # rows of no group: zero
         y = jnp.where(valid[:, None], y * wt[:, None], 0.0)
-        return out.at[tok].add(y)
+        return hi, out.at[tok].add(y), reads + jnp.sum(gs > 0)
 
-    out = jax.lax.fori_loop(0, -(-n_held // M), one_tile,
-                            jnp.zeros((N, D), jnp.float32))
-    return out, loads
+    _, out, reads = jax.lax.while_loop(
+        lambda carry: carry[0] < n_held, one_trip,
+        (jnp.zeros((), jnp.int32), jnp.zeros((N, D), jnp.float32),
+         jnp.zeros((), jnp.int32)))
+    return out, loads, reads
 
 
-def held_load_stats(loads) -> list:
-    """What a serve program reports of its expert layers' `loads` (a list
-    of `held_expert_ffn`'s, one a layer): [token-expert pairs that fell on
-    held experts, the largest load of a held expert summed over the layers,
-    held experts touched], f32 scalars in the order the models' STEP_STATS
-    name them (`moe_pairs`, `moe_load_max`, `moe_touched`); zeros where no
-    layer routes."""
-    if not loads:
-        return [jnp.zeros(())] * 3
+def held_load_stats(held) -> list:
+    """What a serve program reports of its expert layers (`held`: a list of
+    `held_expert_ffn`'s (loads, reads), one a layer): [token-expert pairs
+    that fell on held experts, the largest load of a held expert summed
+    over the layers, held experts touched, held experts' visits by a trip
+    of products], f32 scalars in the order the models' STEP_STATS name
+    them (`moe_pairs`, `moe_load_max`, `moe_touched`, `moe_reads`); zeros
+    where no layer routes.  `moe_reads` over `moe_touched` is how often a
+    touched expert's weights were read: 1.0 unless an expert had more rows
+    than a product takes."""
+    if not held:
+        return [jnp.zeros(())] * 4
+    loads, reads = zip(*held)
     ld = jnp.stack(loads).astype(jnp.float32)              # [L_moe, held]
     return [ld.sum(), ld.max(axis=1).sum(),
-            (ld > 0).sum().astype(jnp.float32)]
+            (ld > 0).sum().astype(jnp.float32),
+            jnp.stack(reads).astype(jnp.float32).sum()]

@@ -66,14 +66,15 @@ _QUICK_FILES = {
     "test_asyncio_api.py", "test_brumby.py", "test_chip_compile.py",
     "test_chip_ownership.py",
     "test_collective_compression.py", "test_collective_pipeline.py",
-    "test_config.py", "test_control_stats.py", "test_core_actors.py",
+    "test_config.py", "test_control_stall.py", "test_control_stats.py",
+    "test_core_actors.py",
     "test_core_objects.py", "test_core_tasks.py", "test_data.py",
     "test_data_remote_io.py", "test_deepseek_v3.py",
     "test_device_telemetry.py",
     "test_docs_paths.py", "test_elastic.py", "test_engine_mixed_state.py",
     "test_engine_three_kinds.py",
     "test_kda.py", "test_label_scheduling.py", "test_ling3.py",
-    "test_mamba.py", "test_phi4flash.py",
+    "test_mamba.py", "test_moe_held_products.py", "test_phi4flash.py",
     "test_mpmd.py",
     "test_native_sched.py", "test_native_store.py", "test_ops.py",
     "test_parallel.py", "test_partition.py", "test_podracer.py",

@@ -84,6 +84,25 @@ def _latent_kernels(compiled) -> int:
                           compiled.as_text()))
 
 
+def _assert_grouped_products_take_a_row_block(compiled, moe_layers: int):
+    """The held experts' grouped products (`jax.lax.ragged_dot`) as the
+    chip's compiler wrote them: a `ragged-dot-none` custom call each, three
+    a MoE layer (gate, up, down), whose `ragged_dot_tiling` "tm,tk,tn"
+    takes at most `ops/moe.ROW_BLOCK` rows a visited (expert, row block) —
+    at tm = 512 a touched expert costs a 512-row product and not its
+    weights' read (PR 53)."""
+    from ray_tpu.ops.moe import ROW_BLOCK
+
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if re.match(r"\s*%?ragged-dot-none[.\d]* = ", ln)]
+    assert len(calls) == 3 * moe_layers, len(calls)
+    tilings = [re.search(r'ragged_dot_tiling="(\d+),\d+,\d+"', c)
+               for c in calls]
+    assert all(tilings), calls[0][:400]
+    assert all(int(t.group(1)) <= ROW_BLOCK for t in tilings), [
+        t.group(0) for t in tilings]
+
+
 def _gathered_blocks(compiled, slots: int):
     """Instructions of a step program's attention that gather, copy or
     transpose a block of cached latents for every slot ([slots, 4 pages,
@@ -608,6 +627,7 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     # the grouped products are the chip's own kernel, not a dense fallback,
     # for a chunk's rows and a step's alike
     assert "ragged-dot" in compiled.as_text()
+    _assert_grouped_products_take_a_row_block(compiled, moe_layers=4)
     if key == "step":
         _assert_sampler_asks_its_operands(compiled, B, V)
 
@@ -772,6 +792,7 @@ def test_deepseek_v3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     assert "{2,1,0" in re.search(r"bf16\[4353,576,128\]\{[^}]*\}",
                                  text).group(0)
     assert "ragged-dot" in text
+    _assert_grouped_products_take_a_row_block(compiled, moe_layers=4)
     if key == "step":
         _assert_sampler_asks_its_operands(compiled, B, V)
     print(key, "total", total, "temp", m.temp_size_in_bytes)
@@ -853,6 +874,7 @@ def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
                           r"(copy|transpose)\(", ln)]
     assert not moved, moved
     assert "ragged-dot" in text
+    _assert_grouped_products_take_a_row_block(compiled, moe_layers=6)
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     if key == "step":       # one kernel a KDA layer, under its name, and

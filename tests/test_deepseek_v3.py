@@ -160,6 +160,7 @@ def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms,
     program's last counter is the key positions it fetched: every row of
     the batch every block of 16 to the longest context, or, walked, the
     live slot's own pages."""
+    at = dm.STEP_STATS.index
     cfg, params = model
     long_form, short_form = forms
     walked = short_form == "walked"
@@ -191,8 +192,10 @@ def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms,
         start += n
         np.testing.assert_allclose(row, want[start - 1], atol=TOL, rtol=0)
         pairs = sum(range(start - n + 1, start + 1)) * cfg.n_layers
-        assert (stats[3], stats[4]) == (pairs, start * cfg.n_layers)
-        assert stats[5] == -(-start // 16) * 16 * cfg.n_layers
+        assert (stats[at("mla_pairs")], stats[at("mla_keys")]) == (
+            pairs, start * cfg.n_layers)
+        assert stats[at("mla_walked_keys")] == (
+            -(-start // 16) * 16 * cfg.n_layers)
     ptab = np.zeros((2, maxp), np.int32)
     ptab[1] = tab
     for i in range(start, 40):
@@ -200,8 +203,9 @@ def test_chunked_prefill_then_decode_through_latent_pages(model, drawn, forms,
             view, cache, jnp.asarray([0, seq[i]], jnp.int32), {"full": ptab},
             jnp.asarray([0, i], jnp.int32), cfg=cfg, absorbed=short_form)
         np.testing.assert_allclose(lg[1], want[i], atol=TOL, rtol=0)
-        assert stats[3] == stats[4] == (i + 1) * cfg.n_layers
-        assert stats[5] == cfg.n_layers * (
+        assert (stats[at("mla_pairs")] == stats[at("mla_keys")]
+                == (i + 1) * cfg.n_layers)
+        assert stats[at("mla_walked_keys")] == cfg.n_layers * (
             (i // PS + 1) * PS if walked else 2 * (i // 16 + 1) * 16)
     # the null page is as it was made: pad rows and the empty slot wrote
     # nothing anywhere
@@ -275,7 +279,7 @@ def test_expert_shares_add_up_to_the_uncut_layer(model):
     layer["router_bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(6),
                                                     (16,))
     h = jax.random.normal(jax.random.PRNGKey(7), (24, cfg.d_model))
-    full, loads = dm.layer_ffn(h, layer, whole)
+    full, (loads, _) = dm.layer_ffn(h, layer, whole)
     assert int(loads.sum()) == 24 * cfg.top_k
     shared = dm.swiglu(h, layer["shared_gate"], layer["shared_up"],
                        layer["shared_down"])

@@ -162,7 +162,7 @@ def _serve(cfg, params, tokens, plen, chunk, impl, state_dtype=None,
         if not keep_tail:
             cache["tail"] = cache["tail"] * 0
         start += m
-    assert float(stats[3]) == 1.0
+    assert float(stats[lm.STEP_STATS.index("kda_live")]) == 1.0
     rows.append(lg)
     B = 3
     ptabs = {lm.FULL: jnp.zeros((B, R), jnp.int32).at[1].set(tab),
@@ -173,7 +173,8 @@ def _serve(cfg, params, tokens, plen, chunk, impl, state_dtype=None,
             view, cache, jnp.asarray([0, int(tokens[t]), 0], jnp.int32),
             ptabs, jnp.asarray([0, t, 0], jnp.int32))
         rows.append(lg[1])
-        assert float(stats[3]) == (3.0 if impl == "xla" else 1.0)
+        assert float(stats[lm.STEP_STATS.index("kda_live")]) == (
+            3.0 if impl == "xla" else 1.0)
     for k, before in null.items():       # empty slots left the null entry
         np.testing.assert_array_equal(np.asarray(cache[k][:, 0]), before)
     return np.asarray(jnp.stack(rows))
@@ -228,7 +229,7 @@ def test_four_chips_shares_add_up_to_the_uncut_layer(model):
                                    experts_held=held)
         mine = dict(lp, **{n: lp[n][chip * held:(chip + 1) * held]
                            for n in ("wg", "wu", "wd")})
-        out, loads = dm.layer_ffn(h, mine, part)
+        out, (loads, _) = dm.layer_ffn(h, mine, part)
         total = total + np.asarray(out)
         pairs += float(loads.sum())
     assert pairs == 24 * cfg.top_k            # every pair fell on one chip

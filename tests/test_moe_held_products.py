@@ -1,0 +1,128 @@
+"""`ops/moe.held_expert_ffn` against a plain per-pair loop: each pair through
+its expert's SwiGLU in float64, weighted, summed per token.  What the cases
+hold beside the sum: `loads` are the per-expert counts, and `reads` — an
+expert counted once for each trip of grouped products that holds a row of
+it — equal the experts touched unless an expert has more rows than a
+product takes (M = min(tile, N*k, ROW_BLOCK)).  A trip ends where an expert
+ends, so an expert of more than M rows always STARTS a trip and is visited
+ceil(rows / M) times wherever its first row falls (trips cut at fixed
+offsets would visit an expert of 300 rows 3 or 4 times under a block of
+128): reads = sum of ceil(load / M) over the held experts, in every case."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import moe
+from ray_tpu.ops.moe import held_expert_ffn, held_load_stats
+
+D, F = 16, 8
+
+
+def _plain(h, w, idx, wg, wu, wd, first, live):
+    h, w, wg, wu, wd = (np.asarray(a, np.float64) for a in (h, w, wg, wu, wd))
+    idx = np.asarray(idx)
+    count = wg.shape[0]
+    out = np.zeros_like(h)
+    loads = np.zeros(count, np.int64)
+    for n in range(h.shape[0]):
+        if live is not None and not live[n]:
+            continue
+        for j in range(idx.shape[1]):
+            e = idx[n, j] - first
+            if not 0 <= e < count:
+                continue
+            g, u = h[n] @ wg[e], h[n] @ wu[e]
+            out[n] += w[n, j] * ((g / (1 + np.exp(-g)) * u) @ wd[e])
+            loads[e] += 1
+    return out, loads
+
+
+def _routed(N, k, E, seed):
+    """Uniform top-k without replacement: [N, k] expert ids."""
+    scores = np.random.default_rng(seed).random((N, E))
+    return np.argsort(scores, axis=1)[:, :k].astype(np.int32)
+
+
+def _by_loads(loads, absent_from, N, k):
+    """[N, k] ids that give held expert e exactly loads[e] pairs (a token
+    names an expert at most once); every other pair names an absent one."""
+    idx = np.full((N, k), absent_from, np.int32)
+    idx += np.arange(k, dtype=np.int32)[None]      # distinct absent experts
+    col = np.zeros(N, np.int64)
+    for e, n in enumerate(loads):
+        rows = np.argsort(col, kind="stable")[:n]  # the emptiest tokens
+        assert n <= N and col[rows].max(initial=0) < k
+        idx[rows, col[rows]] = e
+        col[rows] += 1
+    return idx
+
+
+B = moe.ROW_BLOCK
+# name: (N, k, held, E, first, tile, idx (None: uniform), live rows
+#        (None: all), reads pinned by hand (None: the experts touched))
+CASES = {
+    "pairs_below_the_block": (B // 16, 4, 4, 8, 0, 512, None, None, None),
+    "pairs_equal_the_block": (B // 4, 4, 4, 8, 0, 512, None, None, None),
+    "pairs_above_the_block": (B, 4, 8, 16, 0, 512, None, None, None),
+    # trips: [0,50) e0 | [50,178) e1 | [178,306) e1 | [306,370) e1's last
+    # 44 rows and e2's 20: e1 is read 3 times, five visits in all
+    "an_expert_of_more_rows_than_a_block": (
+        400, 2, 4, 8, 0, 512, _by_loads([50, 300, 20, 0], 4, 400, 2), None,
+        5),
+    # e0's 300 rows take [0,128) [128,256) [256,300): the third trip ends
+    # where e0 ends, so e1's 200 start a trip too: [300,428) [428,500)+e2
+    "two_such_experts_in_a_row": (
+        400, 2, 4, 8, 0, 512, _by_loads([300, 200, 10, 0], 4, 400, 2), None,
+        3 + 2 + 1),
+    "every_pair_on_absent_experts": (
+        24, 4, 4, 12, 0, 512, 4 + _routed(24, 4, 8, 1), None, 0),
+    "live_takes_rows_out": (48, 4, 8, 16, 0, 512, None,
+                            np.arange(48) % 3 != 1, None),
+    # M = 16: experts of 17+ rows are read twice or more
+    "tile_below_the_block_binds": (
+        40, 4, 4, 8, 0, 16, _by_loads([40, 17, 16, 3], 4, 40, 4), None,
+        3 + 2 + 1 + 1),
+    "first_above_zero": (B // 2, 4, 4, 12, 4, 512, None, None, None),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_held_products_match_the_per_pair_loop(name):
+    N, k, held, E, first, tile, idx, live, reads_by_hand = CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 5)
+    h = jax.random.normal(ks[0], (N, D), jnp.float32)
+    w = jax.random.uniform(ks[1], (N, k), jnp.float32, 0.2, 1.0)
+    wg = jax.random.normal(ks[2], (held, D, F), jnp.float32) * D ** -0.5
+    wu = jax.random.normal(ks[3], (held, D, F), jnp.float32) * D ** -0.5
+    wd = jax.random.normal(ks[4], (held, F, D), jnp.float32) * F ** -0.5
+    if idx is None:
+        idx = _routed(N, k, E, seed=N + first)
+    out, loads, reads = jax.jit(
+        lambda *a: held_expert_ffn(
+            *a, first=first, tile=tile,
+            live=None if live is None else jnp.asarray(live)))(
+                h, w, jnp.asarray(idx), wg, wu, wd)
+    want, want_loads = _plain(h, w, idx, wg, wu, wd, first, live)
+    assert out.shape == (N, D) and out.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(loads), want_loads)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
+    M = min(tile, N * k, B)
+    touched = int((want_loads > 0).sum())
+    if reads_by_hand is None:
+        assert want_loads.max() <= M, "the case has an expert past a product"
+        reads_by_hand = touched
+    assert int(reads) == reads_by_hand == int(np.ceil(want_loads / M).sum())
+    if name == "every_pair_on_absent_experts":
+        assert not np.asarray(out).any() and touched == 0
+    else:
+        assert touched > 0
+    # what a program reports of one such layer
+    stats = [float(s) for s in held_load_stats([(loads, reads)])]
+    assert stats == [want_loads.sum(), want_loads.max(), touched,
+                     reads_by_hand]
+
+
+def test_load_stats_of_a_program_without_expert_layers():
+    assert [float(s) for s in held_load_stats([])] == [0.0] * 4

@@ -159,10 +159,10 @@ def test_expert_shares_add_up_to_the_uncut_layer(model):
         share = dict(full, **{k: full[k][first:first + 4]
                               for k in ("wg", "wu", "wd")})
         c = dataclasses.replace(cfg, experts_first=first, experts_held=4)
-        out, loads = cm._ffn(h, share, c)
+        out, (loads, _) = cm._ffn(h, share, c)
         w, idx = route_sigmoid_topk(h, full["router"], cfg.top_k)
-        routed, _ = held_expert_ffn(h, w, idx, share["wg"], share["wu"],
-                                         share["wd"], first=first, tile=16)
+        routed, _, _ = held_expert_ffn(h, w, idx, share["wg"], share["wu"],
+                                       share["wd"], first=first, tile=16)
         assert int(loads.sum()) == int(((idx >= first)
                                         & (idx < first + 4)).sum())
         shared = out - routed

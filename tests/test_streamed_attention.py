@@ -404,7 +404,9 @@ def _digest(text):
 # a program's `_digest`, lowered for the platform "tpu": every served
 # model's step and chunk program.  `deepseek-v3`'s two and `ling-3`'s step
 # were pinned at d07b063 (the parent of PR 50), the other seven at 67e7002
-# (the parent of PR 48), `phi-4-flash`'s two by PR 51, which added them.  A PR that moves or renames Python functions
+# (the parent of PR 48), `phi-4-flash`'s two by PR 51, which added them;
+# PR 53 pinned the six of the three MoE models anew (`ops/moe`'s trips of
+# grouped products).  A PR that moves or renames Python functions
 # leaves every digest alone (the text carries no source locations; their
 # kernels' source lines unmoved, the compile-cache keys stay too).  A PR
 # that edits one of these programs finds the new digest in the failure and
@@ -412,14 +414,14 @@ def _digest(text):
 PARENT_TEXT = {
     ("gpt2", "step"): "1921a8ec3c8503f9",
     ("gpt2", "chunk"): "74eee7fd9dc8f6cb",
-    ("command-a-plus", "step"): "30fc5bbb68f8c6c1",
-    ("command-a-plus", "chunk"): "f067a09b3c247b56",
+    ("command-a-plus", "step"): "7088563b12bffb20",
+    ("command-a-plus", "chunk"): "3860d574703a5237",
     ("brumby", "step"): "c95c739da7c26ef7",
     ("brumby", "chunk"): "634ac703009d25eb",
-    ("deepseek-v3", "step"): "567ab1b634a4d1ff",
-    ("deepseek-v3", "chunk"): "8fe296d520a4a6c7",
-    ("ling-3", "step"): "e01e8b44bc0ed1d5",
-    ("ling-3", "chunk"): "85bf7fa576820a74",
+    ("deepseek-v3", "step"): "1040750f233ad9df",
+    ("deepseek-v3", "chunk"): "972fd14b1ee67cfe",
+    ("ling-3", "step"): "1e1d7126f836888a",
+    ("ling-3", "chunk"): "8aaf5592fadd8980",
     ("phi-4-flash", "step"): "d0a2de26cb6e8b1b",
     ("phi-4-flash", "chunk"): "ce1a43a62b84b2a7",
 }
