@@ -66,7 +66,8 @@ def test_the_cell_resolves_with_every_metric_of_the_issue(cell):
         "ttft_p75_ms", "setup_s"}
     assert {m["moves"] for m in cell["per_layer"]} == {"ttft_p75_ms"}
     names = {m["name"]: m for m in cell["per_layer"]}
-    assert set(names) == {n + ".longctx" for n in TWINS + (
+    # at least these: a later PR may append a metric to the cell
+    assert set(names) >= {n + ".longctx" for n in TWINS + (
         "mla.time_share", "mla.step_roofline", "mla.chunk_roofline",
         "moe.experts_roofline")}
     assert all(m["workloads"] == [CELL] for m in names.values())

@@ -1,10 +1,10 @@
-"""The cell `serve-phi4flash-longgen`: its files resolve by name with every
-metric the issue names (and whatever a later PR appends), the configuration
-keeps every number of the catalog's row with nothing reduced, the traffic's
-cycle is the same for every seed, the scan's and the shared cache's costs
-agree with hand counts, and the roofline reader reads a fixture through
-them (and reads nothing, without raising, where a program lacks the
-counters)."""
+"""The cell `serve-phi4flash-longgen-loaded`: its files resolve by name with
+every metric the issue names (and whatever a later PR appends), the
+configuration keeps every number of the catalog's row with nothing reduced,
+the traffic's cycle is the same for every seed, the scan's and the shared
+cache's costs agree with hand counts, and the roofline reader reads a
+fixture through them (and reads nothing, without raising, where a program
+lacks the counters)."""
 
 import json
 import os
@@ -15,7 +15,7 @@ from benchmarks.lib import costs_mamba, costs_sharedkv, manifest, peaks
 from benchmarks.lib import traffic as T
 from benchmarks.metrics.readers import trace_scope_roofline
 
-CELL = "serve-phi4flash-longgen"
+CELL = "serve-phi4flash-longgen-loaded"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 ENGINE = ("decode_step_device_ms", "decode_step_ms", "host_share",
           "decode_blocked_share", "prefill_ms_per_token", "prefill_pad_share",

@@ -36,16 +36,38 @@ def test_repo_manifest_is_valid_and_every_cell_resolves(man):
             manifest.BENCH_DIR, "drivers", cell["traffic"]["kind"] + ".py"))
 
 
-def test_nothing_names_a_cell_or_a_traffic_file_that_is_gone():
+@pytest.mark.parametrize("cell,traffic", [
+    ("serve-large-chat", "open-chat"),                      # PR 39
+    ("serve-phi4flash-longgen", "open-longgen"),            # PR 55
+    ("serve-commandaplus-mixedctx", "open-mixedctx"),       # PR 55
+])
+def test_nothing_names_a_cell_or_a_traffic_file_that_is_gone(cell, traffic):
+    """Not BENCHMARK.json, and no file under benchmarks/ but this one: a
+    retired name is followed by `-loaded` wherever it still stands."""
+    import re
+
     man = manifest.load()
     files = {f[:-5] for f in os.listdir(os.path.join(manifest.BENCH_DIR,
                                                      "traffic"))}
     assert {w["traffic"] for w in man["workloads"]} == files
     for m in man["end_to_end"] + man["per_layer"]:   # load() checked the names
         assert m.get("workloads", True), m["name"]     # none left empty
-    text = json.dumps(man)
-    assert '"serve-large-chat"' not in text and '"open-chat"' not in text
-    assert "open-chat" not in files
+        assert cell not in m.get("workloads", ())
+    assert cell not in {w["name"] for w in man["workloads"]}
+    assert traffic not in files
+    gone = re.compile("(%s|%s)(?!-loaded)(?![a-z])" % (re.escape(cell),
+                                                       re.escape(traffic)))
+    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
+        assert not gone.search(f.read())
+    for top, _, names in os.walk(manifest.BENCH_DIR):
+        for n in names:
+            path = os.path.join(top, n)
+            if not n.endswith((".py", ".json", ".toml", ".txt", ".csv",
+                               ".jsonl", ".md")) or \
+                    os.path.samefile(path, __file__):
+                continue
+            with open(path) as f:
+                assert not gone.search(f.read()), path
 
 
 def test_contract_limits(man):

@@ -1,5 +1,5 @@
-"""The cell `serve-commandaplus-mixedctx`: its files resolve by name, the
-configuration keeps every published width, the traffic's cycle is the same
+"""The cell `serve-commandaplus-mixedctx-loaded`: its files resolve by name,
+the configuration keeps every published width, the traffic's cycle is the same
 for every seed, and each reader it brings reads a fixture."""
 
 import json
@@ -11,7 +11,7 @@ from benchmarks.lib import costs_moe, manifest, peaks
 from benchmarks.lib import traffic as T
 from benchmarks.metrics.readers import ring_ratio, trace_moe_roofline
 
-CELL = "serve-commandaplus-mixedctx"
+CELL = "serve-commandaplus-mixedctx-loaded"
 # the catalog row's widths (model-configs guide, architectures.jsonl)
 WIDTHS = {"hidden_size": 4096, "num_attention_heads": 128,
           "num_key_value_heads": 8, "head_dim": 128,
@@ -40,7 +40,8 @@ def test_the_cell_resolves_with_every_metric_of_the_issue(cell):
         "ttft_p75_ms", "setup_s"}
     assert {m["moves"] for m in cell["per_layer"]} == {"ttft_p75_ms"}
     names = {m["name"] for m in cell["per_layer"]}
-    assert names == {n + ".mixedctx" for n in (
+    # at least these: a later PR may append a metric to the cell
+    assert names >= {n + ".mixedctx" for n in (
         "moe.time_share", "moe.experts_roofline", "moe.load_max_over_mean",
         "attn.time_share", "cache.window_pages_share",
         "engine.chunk_blocked_share", "engine.prefill_ms_per_token",

@@ -1,5 +1,5 @@
-"""A traced rehearsal of `serve-phi4flash-longgen`, through the real
-cluster at toy size on the CPU: prompts of two to six chunks through full
+"""A traced rehearsal of `serve-phi4flash-longgen-loaded`, through the
+real cluster at toy size on the CPU: prompts of two to six chunks through full
 pages, a ring of window pages AND a state entry (chunks through the scan,
 steps by the gather / scatter body, the cross-decoder on a prompt's last
 chunk alone), the served tokens and the replayed logits held to the plain
@@ -26,7 +26,8 @@ RING_METRICS = ("prefill.cross_rows_share", "cache.state_bytes_share",
 def test_traced_rehearsal_of_the_longgen_cell():
     out = subprocess.run(
         [sys.executable, os.path.join(manifest.BENCH_DIR, "run.py"),
-         "--workload", "serve-phi4flash-longgen", "--seed", "2147483659",
+         "--workload", "serve-phi4flash-longgen-loaded",
+         "--seed", "2147483659",
          "--seconds", "3", "--trace", "1", "--rehearse"],
         capture_output=True, text=True, timeout=600, cwd=manifest.ROOT)
     assert out.returncode == 0, out.stderr[-2000:]

@@ -1,5 +1,5 @@
-"""A traced rehearsal of `serve-commandaplus-mixedctx`, through the real
-cluster at toy size on the CPU: chunked prompts through both page pools,
+"""A traced rehearsal of `serve-commandaplus-mixedctx-loaded`, through the
+real cluster at toy size on the CPU: chunked prompts through both page pools,
 the served tokens held to the plain reference, and the ring metrics that
 read what the engine and the model's programs count printed under
 `rehearsal.*` names; the device-trace metrics find no device plane and are
@@ -20,7 +20,8 @@ RING_METRICS = ("moe.load_max_over_mean", "cache.window_pages_share",
 def test_traced_rehearsal_of_the_mixed_context_cell():
     out = subprocess.run(
         [sys.executable, os.path.join(manifest.BENCH_DIR, "run.py"),
-         "--workload", "serve-commandaplus-mixedctx", "--seed", "2147483659",
+         "--workload", "serve-commandaplus-mixedctx-loaded",
+         "--seed", "2147483659",
          "--seconds", "3", "--trace", "1", "--rehearse"],
         capture_output=True, text=True, timeout=600, cwd=manifest.ROOT)
     assert out.returncode == 0, out.stderr[-2000:]

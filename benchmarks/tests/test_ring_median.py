@@ -8,7 +8,7 @@ import pytest
 from benchmarks.lib import manifest, result
 
 CELLS = {"chat": "serve-large-chat-loaded",
-         "mixedctx": "serve-commandaplus-mixedctx",
+         "mixedctx": "serve-commandaplus-mixedctx-loaded",
          "streams": "serve-brumby-streams"}
 NEW = ("engine.dispatch_share", "engine.step_dispatch_ms",
        "engine.step_wait_ms")

@@ -50,7 +50,8 @@ def ring_metrics():
 
 
 def test_ring_ratio_known_answers(ring_metrics):
-    assert {m["name"] for m in ring_metrics} == set(ANSWERS)
+    # at least these: a later PR may append a `ring_ratio` metric
+    assert {m["name"] for m in ring_metrics} >= set(ANSWERS)
     got = result.read_metrics(ring_metrics, {"serve": {"ring": RING}}, {})
     for name, want in ANSWERS.items():
         assert got[name]["value"] == pytest.approx(want), name

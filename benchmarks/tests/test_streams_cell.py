@@ -47,7 +47,8 @@ def test_the_cell_resolves_with_every_metric_of_the_issue(cell):
         "itl_p99_ms", "setup_s"}
     assert {m["moves"] for m in cell["per_layer"]} == {"itl_p99_ms"}
     names = {m["name"]: m for m in cell["per_layer"]}
-    assert set(names) == {n + ".streams" for n in TWINS + (
+    # at least these: a later PR may append a metric to the cell
+    assert set(names) >= {n + ".streams" for n in TWINS + (
         "retention.time_share", "retention.step_roofline",
         "retention.chunk_roofline", "retention.dead_state_share")}
     assert all(m["workloads"] == [CELL] for m in names.values())

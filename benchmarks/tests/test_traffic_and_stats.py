@@ -102,20 +102,32 @@ def test_output_lengths_are_multiples_of_eight_inside_the_clip():
 
 
 @pytest.mark.parametrize("name,seed,want", [
-    ("open-mixedctx", 1, "9aa2d4cbe5355d35"),
-    ("open-mixedctx", 3000000019, "4010113c000e8ae7"),
+    ("open-mixedctx-loaded", 1, "e7a071b496e25281"),
+    ("open-mixedctx-loaded", 3000000019, "432a2c47ec8a630a"),
+    ("open-longgen-loaded", 1, "86176d3419c03691"),
+    ("open-longgen-loaded", 3000000019, "50918199abf23f00"),
     ("packed-1024", 1, "896707878b440c2e"),
     ("packed-1024", 3000000019, "a414c38bc762d5b0"),
+    ("open-chat-loaded", 1, "aba23c20711a1e26"),
+    ("open-chat-loaded", 3000000019, "accbf5fc6304a326"),
+    ("open-streams", 1, "71a8de709717e0bc"),
+    ("open-streams", 3000000019, "d3e6fd710786e1e7"),
+    ("open-longctx", 1, "99e697dc27e37930"),
+    ("open-longctx", 3000000019, "a6750949688544c7"),
+    ("open-reasoning", 1, "e569245e20ae8483"),
+    ("open-reasoning", 3000000019, "579af2d52ea42993"),
 ])
 def test_the_kept_cells_schedules_are_the_parents_bit_for_bit(name, seed,
                                                               want):
-    """Digests taken with commit 4ec08fa's `lib/traffic.py` (before the
-    `multiple_of` key): neither kept traffic file carries the key, so the
-    generator gives their cells what it gave them."""
+    """`packed-1024`: digests taken with commit 4ec08fa's `lib/traffic.py`
+    (before the `multiple_of` key).  The four kept serve files (PR 55):
+    taken at commit cc97c58, the parent of the PR that restated the two
+    `-loaded` files below them; those two pin what PR 55 committed (a new
+    rate is a new schedule: the retired files' read 9aa2d4cbe5355d35 /
+    4010113c000e8ae7 and c7a260f898a291cb / 3b31f913bf2fbb21)."""
     import hashlib
 
     tr = _traffic(name)
-    assert "multiple_of" not in json.dumps(tr)
     if tr["kind"] == "train_packed":
         it, h = traffic.packed_batches(tr, seed, 8, 50304), hashlib.sha256()
         for _ in range(3):
@@ -127,6 +139,27 @@ def test_the_kept_cells_schedules_are_the_parents_bit_for_bit(name, seed,
         got = hashlib.sha256(json.dumps(
             traffic.open_schedule(tr, seed, 50, 32768),
             sort_keys=True).encode()).hexdigest()
+    assert got[:16] == want
+
+
+@pytest.mark.parametrize("name,old_rate,want", [
+    ("open-longgen-loaded", 0.64, "dd67203705351a37"),
+    ("open-mixedctx-loaded", 0.6, "81be949a1b49a29a"),
+])
+def test_a_restated_file_differs_from_the_retired_one_in_rate_and_why_only(
+        name, old_rate, want):
+    """PR 55 re-rated two cells as new files beside the old and retired
+    the old.  `want` is the retired file's digest (commit cc97c58) with
+    the rate and the `why` taken out: lengths, `population_seed`,
+    `entry_after_idle_s`, `max_in_flight`, `token_id_max`, the trace's
+    offsets and the whole `reference` block, its limits and its text, are
+    the retired file's letter for letter."""
+    import hashlib
+
+    tr = _traffic(name)
+    assert tr["arrivals"].pop("rate_per_s") >= old_rate   # never under it
+    assert len(tr.pop("why")) > 200
+    got = hashlib.sha256(json.dumps(tr, sort_keys=True).encode()).hexdigest()
     assert got[:16] == want
 
 
