@@ -307,48 +307,60 @@ def latent_rows(h, layer, pos, cfg: DeepSeekV3Config):
 
 
 def latent_attend(q_nope, q_pe, qpos, fetch, n_blocks, layer,
-                  absorbed: bool, cfg: DeepSeekV3Config):
+                  absorbed: bool, cfg: DeepSeekV3Config, window=None,
+                  scope: str = "mla_attend"):
     """Heads' outputs [B, H, T, dv] of queries at positions qpos [B, T]
     against cached latents: `fetch(i)` -> (block i's rows [B, S, rkv + dr],
-    their positions [B, S], negative where there is none).  Where the
-    latents lie in pages, `fetch.pages()` -> (arena, table [B, R], the
-    keys each slot sees [B]), and an absorbed step on a TPU (one row a
-    slot: `latent_decode_uses_kernel`) walks them with the decode kernel
-    and fetches no block."""
+    their positions [B, S], negative where there is none) and, where the
+    caller SELECTS keys, a third item, the keys of the block each query
+    keeps [B, T, S] (models/dots3.py: a learned indexer's choice).  Query
+    t sees key s iff 0 <= qpos - kpos (< `window`, where one is given) and
+    it keeps it.  Where the latents lie in pages, `fetch.pages()` ->
+    (arena, table [B, R], the positions of each slot's row to walk [B][,
+    which of them it keeps [B, R * ps]]), and an absorbed step on a TPU
+    (one row a slot: `latent_decode_uses_kernel`) walks them with the
+    decode kernel and fetches no block.  `scope` names the two forms'
+    `jax.named_scope`s (`<scope>_step`, `<scope>_chunk`)."""
     B, H, T, _ = q_nope.shape
     rkv = cfg.kv_rank
     w_uk, w_uv = kv_up(layer, cfg)
     if absorbed:
-        with jax.named_scope("mla_attend_step"):
+        with jax.named_scope(scope + "_step"):
             q_lat = jnp.einsum("bhtn,hnr->bhtr", q_nope, w_uk)
             q = jnp.concatenate([q_lat.astype(q_pe.dtype), q_pe], axis=-1)
             if hasattr(fetch, "pages") and latent_decode_uses_kernel(T):
+                q1 = q[:, :, 0]
+                arena, tab, ctx, *keep = fetch.pages()
                 o = latent_decode_attention(
-                    q[:, :, 0], *fetch.pages(), scale=cfg.softmax_scale,
-                    v_dim=rkv)[:, :, None]
+                    q1, arena, tab, ctx, scale=cfg.softmax_scale,
+                    v_dim=rkv, **({"keep": keep[0]} if keep else {})
+                    )[:, :, None]
                 return jnp.einsum("bhtr,hrv->bhtv", o, w_uv)
 
             def one_key(i):                 # the latent as it lies
-                rows, kpos = fetch(i)
-                return rows[:, None], rows[:, None, :, :rkv], kpos
+                rows, kpos, *keep = fetch(i)
+                return rows[:, None], rows[:, None, :, :rkv], kpos, *keep
 
             o = streamed_attention(q[:, None], qpos, one_key, n_blocks,
-                                   scale=cfg.softmax_scale, v_dim=rkv)
+                                   window=window, scale=cfg.softmax_scale,
+                                   v_dim=rkv)
             return jnp.einsum("bhtr,hrv->bhtv", o[:, 0], w_uv)
-    with jax.named_scope("mla_attend_chunk"):
+    with jax.named_scope(scope + "_chunk"):
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
 
         def per_head(i):                    # the published form
-            rows, kpos = fetch(i)
+            rows, kpos, *keep = fetch(i)
             c_kv, k_pe = rows[..., :rkv], rows[..., rkv:]
             k_nope = jnp.einsum("bsr,hnr->bhsn", c_kv, w_uk)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_pe[:, None],
                                           (B, H) + k_pe.shape[1:])], axis=-1)
-            return k, jnp.einsum("bsr,hrv->bhsv", c_kv, w_uv), kpos
+            return (k, jnp.einsum("bsr,hrv->bhsv", c_kv, w_uv), kpos,
+                    *keep)
 
         o = streamed_attention(q[:, :, None], qpos, per_head, n_blocks,
-                               scale=cfg.softmax_scale, v_dim=cfg.d_v)
+                               window=window, scale=cfg.softmax_scale,
+                               v_dim=cfg.d_v)
         return o[:, :, 0]
 
 
@@ -480,15 +492,25 @@ def init_paged_cache(cfg: DeepSeekV3Config, num_pages, page_size: int
     return latent_arenas(cfg, _only(num_pages), page_size, cfg.n_layers)
 
 
-def page_io(ptab, pos, real, d: int, ps: int, cfg):
+def page_io(ptab, pos, real, ps: int, cfg, window: Optional[int] = None):
     """How the rows of a program at CONSECUTIVE positions pos [B, T] (a
     row's are pos[b, 0] + t; `real` [B, T] marks the rows whose latent is
-    kept) meet the pages of ptab [B, R] whose positions are `d` values by
-    `ps` lanes: `bind(arena)` -> (`_block`'s write and fetch over that
-    layer's arena, the box whose "arena" the write leaves and whose
-    "walked" says how many key positions the layer's attention fetched,
-    through `fetch` or through `fetch.pages`), beside the number of key
-    blocks the live contexts reach.
+    kept) meet the pages of ptab [B, R], `ps` positions a page along the
+    lanes: `bind(arena)` -> (`_block`'s write and fetch over that arena —
+    any arena of the table's kind, whatever its rows' width: a layer may
+    keep more than one leaf under one table —, the box whose "arena" the
+    write leaves and whose "walked" says how many key positions the
+    layer's attention fetched, through `fetch` or through `fetch.pages`),
+    beside the number of key blocks the live contexts reach.
+
+    `window` None: the table is in sequence order (entry i holds positions
+    i * ps onward).  A window: the table is the engine's RING for a kind
+    that keeps `window` positions (logical page lp in entry lp % R:
+    `cohere2_moe.cache_kinds`): every block is fetched, an entry's
+    positions are its base's (negative where it holds nothing a query at
+    or before the row's last position may see), and `fetch.pages` hands
+    the decode kernel a fourth item — which positions of the walk the
+    slot's query sees.
 
     A layer's rows are written a whole page at a time: the pages the rows
     fall in are read, the rows laid over them, the pages written back.  A
@@ -504,6 +526,8 @@ def page_io(ptab, pos, real, d: int, ps: int, cfg):
     n_pg = 1 if T == 1 else -(-T // ps) + 1
     first = pos[:, 0]
     entry = first[:, None] // ps + jnp.arange(n_pg, dtype=jnp.int32)
+    if window is not None:
+        entry = entry % R
     pages = jnp.where(entry < R, jnp.take_along_axis(
         tabp, jnp.minimum(entry, width - 1), axis=1), 0)       # [B, n_pg]
     t_of = (jnp.arange(n_pg * ps, dtype=jnp.int32)[None]
@@ -512,8 +536,16 @@ def page_io(ptab, pos, real, d: int, ps: int, cfg):
     lay = ((t_of >= 0) & (t_of < T)
            & jnp.take_along_axis(real, row_of, axis=1))[..., None]
     last = jnp.max(jnp.where(real, pos, 0))
-    n_blocks = jnp.minimum(last // (npb * ps) + 1, width // npb)
-    block_pos = jnp.arange(npb * ps, dtype=jnp.int32)
+    if window is None:
+        n_blocks = jnp.minimum(last // (npb * ps) + 1, width // npb)
+        block_pos = jnp.arange(npb * ps, dtype=jnp.int32)
+        bases = None
+    else:
+        n_blocks = width // npb
+        e = jnp.arange(width, dtype=jnp.int32)[None]
+        hi = (pos[:, -1] // ps)[:, None]
+        lp = hi - (hi - e) % R
+        bases = jnp.where((e < R) & (lp >= 0), lp * ps, -1)    # [B, width]
     # the keys a slot's one row sees (0: an empty slot); the key positions
     # the block loop fetches (formed here: `fetch` is traced inside it)
     ctx = jnp.where(real[:, 0], first + 1, 0) if T == 1 else None
@@ -521,6 +553,7 @@ def page_io(ptab, pos, real, d: int, ps: int, cfg):
 
     def bind(arena):
         box = {}
+        d = arena.shape[1]
 
         def write(rows):
             old = jnp.swapaxes(arena[pages], 2, 3).reshape(B, n_pg * ps, d)
@@ -534,15 +567,30 @@ def page_io(ptab, pos, real, d: int, ps: int, cfg):
             t = jax.lax.dynamic_slice_in_dim(tabp, i * npb, npb, 1)
             rows = jnp.swapaxes(box["arena"][t], 2, 3).reshape(
                 B, npb * ps, d)
-            kpos = jnp.broadcast_to(i * npb * ps + block_pos, (B, npb * ps))
-            return rows, kpos
+            if bases is None:
+                return rows, jnp.broadcast_to(i * npb * ps + block_pos,
+                                              (B, npb * ps))
+            b = jax.lax.dynamic_slice_in_dim(bases, i * npb, npb, 1)
+            kpos = jnp.where(b[:, :, None] >= 0, b[:, :, None]
+                             + jnp.arange(ps, dtype=jnp.int32), -1)
+            return rows, kpos.reshape(B, npb * ps)
 
         def in_place():             # a step's keys, where they lie
             box["walked"] = latent_walked_keys(ctx, ps)
             return box["arena"], ptab, ctx
 
+        def ring_in_place():        # every entry of a live slot's ring
+            live = real[:, 0]
+            box["walked"] = jnp.sum(live) * R * ps
+            at = (bases[:, :R, None]
+                  + jnp.arange(ps, dtype=jnp.int32)).reshape(B, R * ps)
+            d_pos = first[:, None] - at
+            see = ((jnp.repeat(bases[:, :R], ps, axis=1) >= 0)
+                   & (d_pos >= 0) & (d_pos < window))
+            return (box["arena"], ptab, jnp.where(live, R * ps, 0), see)
+
         if T == 1:
-            fetch.pages = in_place
+            fetch.pages = in_place if window is None else ring_in_place
         return write, fetch, box
 
     return bind, n_blocks
@@ -559,8 +607,8 @@ def _paged_pass(params, cache, toks, ptab, pos, real, cfg, absorbed=None):
     T = toks.shape[1]
     if absorbed is None:
         absorbed = T <= ABSORB_ROWS
-    d, ps = cache[0].shape[1:]
-    bind, n_blocks = page_io(ptab, pos, real, d, ps, cfg)
+    ps = cache[0].shape[2]
+    bind, n_blocks = page_io(ptab, pos, real, ps, cfg)
     x = slot_embed(params, toks, pos, cfg)
     new_cache, held, walked = [], [], 0
     for layer, arena in zip(params["layers"], cache):

@@ -449,8 +449,8 @@ def _paged_pass(params, cache, toks, ptabs, pos, real, cfg: Ling3Config,
     latent = list(cache["latent"])
     state, tail = cache["state"], cache["tail"]
     if latent:
-        d, ps = latent[0].shape[1:]
-        bind, n_blocks = _dm.page_io(ptabs[FULL], pos, real, d, ps, cfg)
+        bind, n_blocks = _dm.page_io(ptabs[FULL], pos, real,
+                                     latent[0].shape[2], cfg)
     x = slot_embed(params, toks, pos, cfg)
     held, n_kda, n_mla = [], 0, 0
     for l, layer in enumerate(params["layers"]):

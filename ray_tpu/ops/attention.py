@@ -147,14 +147,14 @@ def _streamed_xla(q, qpos, fetch, n_blocks, window, scale, v_dim):
     # one key block: the flash recurrence on XLA's own products
     def body(i, carry):
         m, l, acc = carry
-        k, v, kpos = fetch(i)
+        k, v, kpos, *keep = fetch(i)
         s = jnp.einsum("bhgtd,bhsd->bhgts", q, k,
                        preferred_element_type=jnp.float32) * scale
         d = qpos[:, :, None] - kpos[:, None, :]             # [B, T, S]
         ok = (d >= 0) & (kpos[:, None, :] >= 0)
         if window is not None:
             ok = ok & (d < window)
-        ok = ok[:, None, None]
+        ok = (ok & keep[0] if keep else ok)[:, None, None]
         m_new = jnp.maximum(m, jnp.max(jnp.where(ok, s, DEFAULT_MASK_VALUE), axis=-1))
         p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
         corr = jnp.exp(m - m_new)
@@ -917,14 +917,17 @@ def streamed_attention_uses_kernel(rows: int, platform: Optional[str] = None
 
 
 def _streamed_block_kernel(scale_ref, window_ref, q_ref, k_ref, v_ref,
-                           qpos_ref, kpos_ref, m_ref, l_ref, acc_ref,
-                           m_out, l_out, acc_out):
+                           qpos_ref, kpos_ref, *rest):
     """One key block's online-softmax update for one K/V head's block of
     query rows.  Keys lie along the sublanes and queries along the lanes
     (scores [S, R]), so a row's statistics are [1, R] rows: they reduce
     over sublanes, broadcast over sublanes, and travel as they lie in
     [.., T] arrays, with no transpose and no lane-replicated plane.  The
-    accumulator is kept the same way round, [v_dim, R]."""
+    accumulator is kept the same way round, [v_dim, R].  `rest`: m, l,
+    acc in and out, behind `keep` [1, S, R] (above 0: a key its query
+    keeps) where the call selects keys."""
+    keep_ref = rest[0] if len(rest) == 7 else None
+    m_ref, l_ref, acc_ref, m_out, l_out, acc_out = rest[-6:]
     q = q_ref[0, 0]                                       # [R, dh]
     k = k_ref[0, 0]                                       # [S, dh]
     v = v_ref[0, 0]                                       # [S, dv]
@@ -935,6 +938,8 @@ def _streamed_block_kernel(scale_ref, window_ref, q_ref, k_ref, v_ref,
     d = qpos_ref[0] - jnp.where(kpos >= 0, kpos, _NO_WINDOW)   # [S, R]
     ok = (jax.lax.bitcast_convert_type(d, jnp.uint32)
           < window_ref[0].astype(jnp.uint32))
+    if keep_ref is not None:
+        ok = ok & (keep_ref[0] > 0)
     s = jnp.where(ok, s, DEFAULT_MASK_VALUE)
     m = m_ref[0, 0]                                       # [1, R]
     m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
@@ -954,10 +959,13 @@ def _streamed_block_kernel(scale_ref, window_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _streamed_block(scale, window, q, k, v, qpos, kpos, m, l, acc,
-                    interpret=False):
+                    keep=None, interpret=False):
     """(m, l, acc) after key block (k, v, kpos): q [B, H, R, dh] at qpos
     [B, R], k [B, H, S, dh], v [B, H, S, dv], kpos [B, S]; m, l [B, H, R]
-    and acc [B, H, dv, R] float32.  ONE jitted function, with the scale
+    and acc [B, H, dv, R] float32; `keep` [B, S, R] float32 (above 0: the
+    key a query keeps, beside what position and window allow) where the
+    call selects keys, one operand more of the same kernel.  ONE jitted
+    function, with the scale
     and the window as operands: every call site of a program whose
     shapes agree (a chunk program's layers, windowed or not) shares one
     traced jaxpr and one lowered function — a Pallas call is traced and
@@ -982,6 +990,10 @@ def _streamed_block(scale, window, q, k, v, qpos, kpos, m, l, acc,
 
     stat, acc_spec = per_rows(1), per_rows(dv)
     m, l = m[:, :, None], l[:, :, None]
+    # the selection's operand: a block [S, rows] the heads of a row block
+    # share (the grid's head axis turns inside it: fetched once)
+    kept = [] if keep is None else [keep]
+    first = 7 + len(kept)
     m, l, acc = pl.pallas_call(
         _streamed_block_kernel,
         grid=(B, H, R // rows),
@@ -991,16 +1003,19 @@ def _streamed_block(scale, window, q, k, v, qpos, kpos, m, l, acc,
             per_head(S, dh), per_head(S, dv),
             pl.BlockSpec((1, 1, rows), lambda b, h, r: (b, 0, r)),
             pl.BlockSpec((1, S, 128), lambda b, h, r: (b, 0, 0)),
+            *[pl.BlockSpec((1, S, rows), lambda b, h, r: (b, 0, r))
+              for _ in kept],
             stat, stat, acc_spec],
         out_specs=[stat, stat, acc_spec],
         out_shape=[jax.ShapeDtypeStruct(m.shape, m.dtype),
                    jax.ShapeDtypeStruct(l.shape, l.dtype),
                    jax.ShapeDtypeStruct(acc.shape, acc.dtype)],
-        input_output_aliases={7: 0, 8: 1, 9: 2},
+        input_output_aliases={first: 0, first + 1: 1, first + 2: 2},
         interpret=interpret,
         name="streamed_attention_block",
     )(scale, window, q, k, v, qpos[:, None],
-      jnp.broadcast_to(kpos[:, :, None], kpos.shape + (128,)), m, l, acc)
+      jnp.broadcast_to(kpos[:, :, None], kpos.shape + (128,)), *kept,
+      m, l, acc)
     return m[:, :, 0], l[:, :, 0], acc
 
 
@@ -1020,10 +1035,14 @@ def _streamed_kernel_loop(q, qpos, fetch, n_blocks, window, scale, v_dim,
 
     def body(carry):
         i, *stats = carry
-        k, v, kpos = fetch(i)
+        k, v, kpos, *keep = fetch(i)
+        # a selection [B, T, S] the way round the kernel scores: keys
+        # down, the G heads' rows side by side
+        kept = ([jnp.tile(jnp.swapaxes(keep[0], 1, 2).astype(jnp.float32),
+                          (1, 1, G))] if keep else [])
         return (i + 1, *_streamed_block(scale, window, q, k, v, qpos,
                                         kpos.astype(jnp.int32), *stats,
-                                        interpret=interpret))
+                                        *kept, interpret=interpret))
 
     init = (jnp.int32(0),
             jnp.full((B, Hkv, R), DEFAULT_MASK_VALUE, jnp.float32),
@@ -1049,6 +1068,9 @@ def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
     [B, T]; `fetch(i)` gives block i's keys and values [B, Hkv, S, dh]
     and their positions kpos [B, S] (negative: no key there).  Query t
     sees key s iff 0 <= qpos - kpos (< window, where a window is given).
+    A fetch may hand a fourth item, `keep` [B, T, S] bools: the keys of
+    the block each query KEEPS beside that (a learned selection:
+    ops/select.keep_top; the heads of a call share it).
     `n_blocks` may be traced: only blocks 0..n_blocks-1 are fetched.
     A row that sees no key at all comes out zero.  Returns [B, Hkv, G, T,
     dh] in q's dtype; scores and statistics are f32.
@@ -1117,16 +1139,19 @@ def latent_walked_keys(ctx, page_size: int):
 
 
 def _latent_decode_kernel(tab_ref, ctx_ref, scale_ref, q_ref, arena_ref,
-                          o_ref, buf, sem, acc_ref, *, v_dim: int,
-                          width: int):
+                          *rest, v_dim: int, width: int):
     """One slot's walk.  A page [d, ps] IS its keys transposed: a block
     of pages lies side by side along the lanes of `buf` [2, d, S], scores
     are q [H, d] . block [d, S] with no transposing copy, and the weighted
     sum contracts p [H, S] with the block's first `v_dim` rows over S.
-    `width`: entries of a slot's row of the (flattened) table."""
+    `width`: entries of a slot's row of the (flattened) table.  `rest`:
+    the output and the scratch, behind `keep` [1, blocks, S] (above 0: a
+    walked position the query keeps) where the call selects keys."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    keep_ref = rest[0] if len(rest) == 5 else None
+    o_ref, buf, sem, acc_ref = rest[-4:]
     b = pl.program_id(0)
     _, d, S = buf.shape
     ps = arena_ref.shape[-1]
@@ -1176,6 +1201,9 @@ def _latent_decode_kernel(tab_ref, ctx_ref, scale_ref, q_ref, arena_ref,
             kv = buf[half]                                    # [d, S]
             s = _dot(q, kv, _NN) * scale_ref[0]               # [H, S] f32
             kpos = blk * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+            if keep_ref is not None:
+                return selected(s, kpos, kv, keep_ref[0, pl.ds(blk, 1), :],
+                                m, l)
             s = jnp.where(kpos < ctx, s, DEFAULT_MASK_VALUE)
             # a block's first key is inside the context, so m_new is a
             # real score and a hidden key's p is exp(-huge) = 0
@@ -1186,29 +1214,52 @@ def _latent_decode_kernel(tab_ref, ctx_ref, scale_ref, q_ref, arena_ref,
                 p.astype(kv.dtype), kv[:v_dim], _NT)
             return m_new, l * corr + jnp.sum(p, axis=1, keepdims=True)
 
+        def selected(s, kpos, kv, keep, m, l):
+            """The block's update where the query keeps some of its keys
+            and a whole block may hold none: a row that has seen no key
+            yet (m_new still the mask's value: p = 1 throughout) is taken
+            out, as `_streamed_block_kernel` takes it."""
+            s = jnp.where((kpos < ctx) & (keep > 0), s, DEFAULT_MASK_VALUE)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            seen = (m_new > DEFAULT_MASK_VALUE).astype(jnp.float32)
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            acc_ref[...] = acc_ref[...] * corr + seen * _dot(
+                p.astype(kv.dtype), kv[:v_dim], _NT)
+            return m_new, l * corr + seen * jnp.sum(p, axis=1,
+                                                    keepdims=True)
+
         _, l = jax.lax.fori_loop(
             0, n_blocks, block,
             (jnp.full((H, 1), DEFAULT_MASK_VALUE, jnp.float32),
              jnp.zeros((H, 1), jnp.float32)))
+        if keep_ref is not None:
+            l = jnp.maximum(l, 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("v_dim", "interpret"))
-def _latent_decode(scale, q, arena, ptab, ctx, v_dim, interpret=False):
+def _latent_decode(scale, q, arena, ptab, ctx, v_dim, keep=None,
+                   interpret=False):
     """The kernel's one lowered function, the scale an operand: every
-    latent layer of a step program calls this one (see `_streamed_block`
-    for what a lowering a layer costs in warm set-up)."""
+    latent layer of a step program that reads arenas of one shape calls
+    this one (see `_streamed_block` for what a lowering a layer costs in
+    warm set-up).  `keep` [B, blocks, S]: one operand more (a block of
+    `_WALK_PAGES` pages a row, a slot's whole plane a grid turn)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, d = q.shape
     ps = arena.shape[-1]
     at_slot = lambda b, *_: (b, 0, 0)
+    kept = [] if keep is None else [keep]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(B,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec((1, H, d), at_slot),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  *[pl.BlockSpec((1,) + keep.shape[1:], at_slot)
+                    for _ in kept]],
         out_specs=pl.BlockSpec((1, H, v_dim), at_slot),
         scratch_shapes=[pltpu.VMEM((2, d, _WALK_PAGES * ps), arena.dtype),
                         pltpu.SemaphoreType.DMA((2,)),
@@ -1221,11 +1272,11 @@ def _latent_decode(scale, q, arena, ptab, ctx, v_dim, interpret=False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name="latent_decode_attention",
-    )(ptab.reshape(-1), ctx, scale, q, arena)
+    )(ptab.reshape(-1), ctx, scale, q, arena, *kept)
 
 
 def latent_decode_attention(q, arena, ptab, ctx, *, scale: float,
-                            v_dim: int, interpret: bool = False):
+                            v_dim: int, keep=None, interpret: bool = False):
     """The absorbed decode step's attention, as work in proportion to the
     live slots' own contexts: slot b's query heads q[b] [H, d] against
     the first ctx[b] positions of its own pages — page ptab[b, i] of
@@ -1246,13 +1297,30 @@ def latent_decode_attention(q, arena, ptab, ctx, *, scale: float,
     `_streamed_xla`'s: operands as they come, f32 scores, statistics and
     accumulator, p cast to the values' dtype for the second product.
 
+    `keep` [B, R * page_size] bools, where given, says which of the walked
+    positions — position j of the walk lies in entry j // page_size of the
+    slot's row — the query KEEPS: a learned selection over a full table
+    (ops/select.keep_top), or what a window leaves of a RING, whose
+    entries are in no order the kernel knows (`ctx` is then the positions
+    to walk, as many whole entries as hold something, and the caller's
+    mask says what each holds: `deepseek_v3.page_io`).  Every page a
+    context reaches is still read: the selection saves scores' weight in
+    the softmax and not a byte (what a gathering form would save is
+    `dsa_keys_walked` over `dsa_keys_selected`, models/dots3.py).  A slot
+    that keeps nothing comes out zero.
+
     What it replaces: the XLA body gives every slot of the batch, empty
     or not, every block up to the LONGEST live context, each block
     gathered and transposed first (26% of the chip's time in
     `serve-deepseekv3-longctx`, PERF.md section 6, PR 48)."""
+    if keep is not None:        # [B, blocks, S]: a block of the walk a row
+        S = _WALK_PAGES * arena.shape[-1]
+        B, n = keep.shape
+        keep = jnp.pad(keep.astype(jnp.float32),
+                       ((0, 0), (0, -n % S))).reshape(B, -1, S)
     return _latent_decode(jnp.full((1,), scale, jnp.float32), q, arena,
                           ptab.astype(jnp.int32), ctx.astype(jnp.int32),
-                          v_dim=v_dim, interpret=interpret)
+                          v_dim=v_dim, keep=keep, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -1442,3 +1510,37 @@ def paged_decode_attention(q, k_arena, v_arena, ptab, bases, qpos, walk, *,
         jnp.full((1,), _NO_WINDOW if window is None else window, jnp.int32),
         q, k_arena, v_arena, i32(ptab), i32(bases), i32(qpos), i32(walk),
         interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# a learned indexer's scoring pass: which keys a query is to keep, before
+# any key a head wide (or a latent wide) is scored
+# ---------------------------------------------------------------------------
+
+
+def indexer_scores(q, w, fetch, n_blocks, block: int, width: int):
+    """The index scores of queries against an indexer's own cached keys,
+    block by block: q [B, T, Hi, di] (Hi small heads), w [B, T, Hi]
+    float32 (a head's weight, a query's own), `fetch(i)` -> (block i's
+    index keys [B, block, di], their positions) as `streamed_attention`'s
+    fetch hands latents.  Returns I [B, T, width] float32, position s of
+    the table's order:
+
+        I[t, s] = sum_j w[t, j] * relu(q[t, j] . k[s])
+
+    for s in blocks 0..n_blocks-1 (`n_blocks` may be traced) and -inf past
+    them.  Nothing is masked here: what a query may see is the caller's to
+    say when it selects (ops/select.keep_top).  The products are formed on
+    the operands as they come, the scores and their sum in float32."""
+    B, T = q.shape[:2]
+
+    def body(i, out):
+        k, _ = fetch(i)
+        s = jnp.einsum("bthd,bsd->bths", q, k,
+                       preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.einsum("bths,bth->bts", jax.nn.relu(s), w),
+            i * block, 2)
+
+    return jax.lax.fori_loop(
+        0, n_blocks, body, jnp.full((B, T, width), -jnp.inf, jnp.float32))

@@ -9,8 +9,10 @@ categorical — decided on the device from `temps` and `topks`:
   nothing else (`lax.cond` on `any(temps > 0)`: the chip runs one
   branch, so no scale, no threshold, no noise and no key is read);
 * a row's top-k threshold, the k-th largest of its scaled logits, is
-  found by `kth_largest` without sorting the row: selection over the
-  bits of the values, one compare-and-count over `[slots, V]` a bit.
+  found by `kth_largest` (ops/select.py, where the selected latent
+  attention of models/dots3.py finds its own) without sorting the row:
+  selection over the bits of the values, one compare-and-count over
+  `[slots, V]` a bit.
   The value is the one `jnp.sort(row)[V - k]` holds, so the filter, the
   draw and seed parity with `gpt.generate` are what a sort would give.
 
@@ -22,53 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-_UINT = {16: jnp.uint16, 32: jnp.uint32}
-
-
-def _float_bits(dtype) -> int:
-    return jnp.finfo(dtype).bits
-
-
-def ordered_bits(x):
-    """`x`'s bits as unsigned integers (32 wide, whatever `x`'s width)
-    ordered as the floats are: all bits of a negative flipped, the sign
-    bit of the others set.  -0.0 comes out one below +0.0."""
-    nbits = _float_bits(x.dtype)
-    b = lax.bitcast_convert_type(x, _UINT[nbits]).astype(jnp.uint32)
-    top = jnp.uint32(1 << (nbits - 1))
-    return jnp.where(b >= top, b ^ jnp.uint32((1 << nbits) - 1), b | top)
-
-
-def from_ordered_bits(u, dtype):
-    """`ordered_bits`' inverse: the float of `dtype` whose key `u` is."""
-    nbits = _float_bits(dtype)
-    top = jnp.uint32(1 << (nbits - 1))
-    b = jnp.where(u >= top, u ^ top, u ^ jnp.uint32((1 << nbits) - 1))
-    return lax.bitcast_convert_type(b.astype(_UINT[nbits]), dtype)
-
-
-def kth_largest(x, k):
-    """The k-th largest value of each row: `x` [N, V] floats, `k` [N]
-    ints in [1, V] -> [N], each the value `jnp.sort(row)[V - k]` holds
-    (ties counted as a sort counts them; a zero's sign is the one thing
-    that may differ, and no comparison sees it).
-
-    The threshold's bits are fixed from the highest down: a bit stays
-    set where at least k of the row's keys reach the candidate.  As many
-    turns as the dtype has bits, each one compare-and-count over the
-    row; no sort, and no cap on k."""
-    nbits = _float_bits(x.dtype)
-    keys = ordered_bits(x)
-    k = k.astype(jnp.int32)
-
-    def turn(i, t):
-        cand = t | lax.shift_right_logical(jnp.uint32(1 << (nbits - 1)),
-                                           i.astype(jnp.uint32))
-        reach = jnp.sum(keys >= cand[:, None], axis=-1, dtype=jnp.int32)
-        return jnp.where(reach >= k, cand, t)
-
-    t = lax.fori_loop(0, nbits, turn, jnp.zeros(x.shape[0], jnp.uint32))
-    return from_ordered_bits(t, x.dtype)
+from .select import kth_largest
 
 
 def sample(logits, keys, temps, topks, dtype):

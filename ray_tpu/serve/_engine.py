@@ -66,7 +66,14 @@ with no K side, no V side and no head axis (deepseek_v3: 576 values, from
 which its programs form every head's keys and values, or which they score
 as it lies); the engine counts pages and hands tables over, and never
 looks inside the arena `init_paged_cache` gave it — a latent kind is a
-full kind, shared and copied on write like any other.  A windowed kind's table is a ring as wide
+full kind, shared and copied on write like any other.  A kind's layer may
+hold MORE THAN ONE LEAF under the kind's one table (dots3: a full layer's
+latent rows and its indexer's key rows, arenas of different widths that a
+page number indexes alike), and two paged kinds may both be latent with
+rows of different widths (dots3: 576 and 1,088 values a position): a page
+of a kind is whatever the cache grows by when the kind's pool has one page
+more (`_page_bytes`; an iteration's record carries `bytes_<kind>` beside
+`pages_<kind>` where a model has several kinds).  A windowed kind's table is a ring as wide
 as the window plus the longest prefill program: pages are taken as the
 sequence grows, out of a reservation made at admission, and returned once
 every position in them is a window or more behind the next query.  A kind
@@ -1082,6 +1089,11 @@ class ContinuousEngine:
                    "pages_returned": self._returned,
                    **{"pages_" + k: a.used_pages
                       for k, a in self._allocs.items()},
+                   # a model with several kinds of pages: a kind's used
+                   # pages as bytes (its pages' layers and widths differ)
+                   **({"bytes_" + k: self._allocs[k].used_pages * n
+                       for k, n in self._page_bytes.items()}
+                      if len(self._page_bytes) > 1 else {}),
                    **({"states_live": self._states_live(),
                        **self._live_bytes()}
                       if self._state_kinds else {}),
@@ -1648,21 +1660,25 @@ class ContinuousEngine:
             self._cfg, self._pool_pages, self.page_size)
         self._logits = jnp.zeros(
             (self.max_slots, self._cfg.vocab_size), jnp.float32)
+        nbytes = lambda leaves: sum(
+            int(a.size) * a.dtype.itemsize for a in leaves)
         # a model with a state kind says which leaves of its cache are
         # that kind's arena; the others hold its pages
         if self._state_kinds:
-            nbytes = lambda leaves: sum(
-                int(a.size) * a.dtype.itemsize for a in leaves)
             self._state_bytes = nbytes(self._gpt.state_leaves(self._cache))
             self._entry_bytes = self._state_bytes // max(1, sum(
                 self._pool_pages[k] for k in self._state_kinds))
-
+        if len(self._kinds) > 1:
             def with_pages(pools):      # the cache's bytes, as shapes
                 return nbytes(self._jax.tree_util.tree_leaves(
                     self._jax.eval_shape(lambda: self._gpt.init_paged_cache(
                         self._cfg, pools, self.page_size))))
 
-            # kinds of pages may differ in layers, so in a page's bytes
+            # kinds of pages may differ in layers, in a row's width and in
+            # the leaves a layer keeps under the kind's table (dots3: a
+            # latent row and an indexer row), so in a page's bytes: a
+            # kind's page costs what the cache grows by when its pool has
+            # one page more
             whole = with_pages(self._pool_pages)
             self._page_bytes = {
                 k: with_pages({**self._pool_pages, k: n + 1}) - whole

@@ -71,7 +71,7 @@ _QUICK_FILES = {
     "test_core_objects.py", "test_core_tasks.py", "test_data.py",
     "test_data_remote_io.py", "test_deepseek_v3.py",
     "test_device_telemetry.py",
-    "test_docs_paths.py", "test_elastic.py", "test_engine_mixed_state.py",
+    "test_docs_paths.py", "test_dots3.py", "test_elastic.py", "test_engine_mixed_state.py",
     "test_engine_three_kinds.py",
     "test_kda.py", "test_label_scheduling.py", "test_ling3.py",
     "test_mamba.py", "test_moe_held_products.py", "test_phi4flash.py",

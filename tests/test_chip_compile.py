@@ -293,20 +293,26 @@ def test_serve_step_compiles(serve_engine, serve_step_compiled):
     _assert_arena_in_place(serve_step_compiled, serve_engine[3])
 
 
-def _assert_sampler_asks_its_operands(compiled, slots: int, vocab: int):
+def _assert_sampler_asks_its_operands(compiled, slots: int, vocab: int,
+                                      switches: int = 0):
     """The step's sampler as the chip's compiler leaves it (PR 49,
     `ops/sampling.py`): no instruction sorts the vocabulary, in either
     branch (what sorts are left are a router's, over its experts, under
-    the model's `moe_*` scopes), and ONE `conditional`, whose predicate
-    is computed from the `temps` operand and nothing else, so a batch in
-    which nobody samples runs the branch that reads the logits alone (its
-    argmax); the other takes all four operands."""
+    the model's `moe_*` scopes), and ONE two-way `conditional`, whose
+    predicate is computed from the `temps` operand and nothing else, so a
+    batch in which nobody samples runs the branch that reads the logits
+    alone (its argmax); the other takes all four operands.  `switches`:
+    how many conditionals of MORE branches the model's own step brings."""
     text = compiled.as_text()
     sorts = [ln.strip()[:160] for ln in text.splitlines()
              if " sort(" in ln and (re.search(r"\[(\d+,)*%d\]" % vocab, ln)
                                     or not re.search(r'op_name="[^"]*/moe_', ln))]
     assert not sorts, sorts
+    ways = lambda ln: re.search(r"branch_computations=\{([^}]*)\}",
+                                ln).group(1).split(",")
     conds = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert len(conds) == 1 + switches, [ln[:160] for ln in conds]
+    conds = [ln for ln in conds if len(ways(ln)) == 2]
     assert len(conds) == 1, [ln[:160] for ln in conds]
     defs = {m.group(1): ln for ln in text.splitlines()
             if (m := re.match(r"\s*(?:ROOT )?(%\S+) = ", ln))}
@@ -318,10 +324,9 @@ def _assert_sampler_asks_its_operands(compiled, slots: int, vocab: int):
     assert re.search(r"= f32\[%d\]\S* parameter\(" % slots, defs[name])
     assert 'op_name="temps"' in defs[name], defs[name][:300]
     # the branches: one takes the logits alone, the other all four operands
-    branches = re.search(r"branch_computations=\{([^}]*)\}", conds[0]).group(1)
     heads = [next(ln for ln in text.splitlines()
                   if ln.startswith(b.strip() + " "))
-             for b in branches.split(",")]
+             for b in ways(conds[0])]
     takes = sorted(len(re.findall(r"\w+\[[\d,]*\]", h.split("->")[0]))
                    for h in heads)
     assert takes == [1, 4], heads
@@ -992,3 +997,90 @@ def test_phi4flash_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
         assert _streamed_kernels(compiled) >= 1
         assert "conditional" in text
     print(key, "total", total, "temp", m.temp_size_in_bytes)
+
+
+# -- the seventh served model at its published widths: dots3-note-prev's share
+# -- of benchmarks/configs/dots3-note-prev-l5-e32.json ------------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512)],
+                         ids=["step", "prefill512"])
+def test_dots3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
+    """The serve programs over TWO pools of latent pages (a full kind that
+    keeps a 576-wide latent row and a 128-wide indexer row under one
+    table, a sliding kind of 1,088-wide rows in a ring) from the
+    benchmark's own `engine_kwargs`, 32 slots: the chip's compiler takes
+    them — the block kernel and the decode walk each with the selection's
+    (or the ring's) mask as one operand more —; weights + both arenas +
+    temporaries stay under the chip's 15.75 GiB; every arena is donated
+    and held once, and no instruction copies or re-lays one."""
+    import json
+
+    from benchmarks.lib.dots3cfg import model_config
+    from ray_tpu.models import dots3 as m3
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "dots3-note-prev-l5-e32.json")) as f:
+        conf = json.load(f)
+    cfg = model_config(conf)
+    view = _on(jax.eval_shape(
+        lambda k: m3.serve_view(m3.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one_chip)
+    eng = ContinuousEngine(m3, cfg, view, **conf["serve"]["engine_kwargs"])
+    try:
+        cache = _on(jax.eval_shape(functools.partial(
+            m3.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B, V = eng.max_slots, cfg.vocab_size
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (view, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32),
+                    {k: s((B, w), jnp.int32)
+                     for k, w in eng._widths.items()}, s((B,), jnp.int32))
+        else:
+            args = (view, cache, s((key[1],), jnp.int32),
+                    {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
+                    i32, i32)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    # five latent layers: the chunk's through the block kernel, the step's
+    # a walk of the slots' own pages (two selected, three over a ring)
+    assert _streamed_kernels(compiled) == (0 if key == "step" else 5)
+    assert _latent_kernels(compiled) == (5 if key == "step" else 0)
+    assert (B, eng._pool_pages, eng.max_total, eng._widths, eng._share) == (
+        32, {"full": 8385, "sliding": 321}, 33536,
+        {"full": 262, "sliding": 10}, False)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(view))
+    assert [a.shape for a in jax.tree.leaves(cache["full"])] == [
+        (8385, 128, 128), (8385, 576, 128)] * 2
+    assert [a.shape for a in cache["sliding"]] == [(321, 1088, 128)] * 3
+    assert arena == 8385 * 360448 + 321 * 835584
+    assert 8.17e9 < weights < 8.19e9
+    assert all("wkv_b" not in layer for layer in view["layers"])
+    assert [layer["w_uk"].shape for layer in view["layers"]] == [
+        (128, 128, 512)] * 2 + [(64, 192, 1024)] * 3
+    assert mem.alias_size_in_bytes >= arena
+    assert total < 15.75 * 1024 ** 3, total
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"= bf16\[(8385,(576|128)|321,1088),128\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)]
+    assert not moved, moved
+    assert "ragged-dot" in text
+    _assert_grouped_products_take_a_row_block(compiled, moe_layers=4)
+    if key == "step":   # + a full layer's choice of how much of a table
+        # its selection counts over (`dots3._select`), two full layers
+        _assert_sampler_asks_its_operands(compiled, B, V, switches=2)
+    print(key, "total", total, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes, "weights", weights, "arena", arena)

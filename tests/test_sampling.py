@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import gpt
-from ray_tpu.ops.sampling import (from_ordered_bits, kth_largest,
-                                  ordered_bits, sample)
+from ray_tpu.ops.sampling import sample
+from ray_tpu.ops.select import from_ordered_bits, kth_largest, ordered_bits
 
 _ROWS = ("random", "ties", "zeros", "one_value", "filtered", "wide")
 

@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, gpt, ling3,
-                            phi4flash)
+from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3, gpt,
+                            ling3, phi4flash)
 from ray_tpu.serve._engine import ContinuousEngine, _check_interface
 
 MODELS = {
@@ -21,6 +21,7 @@ MODELS = {
     "brumby": (brumby, brumby.BrumbyConfig.nano()),
     "deepseek-v3": (deepseek_v3, deepseek_v3.DeepSeekV3Config.nano()),
     "ling-3": (ling3, ling3.Ling3Config.nano()),
+    "dots3-note": (dots3, dots3.Dots3Config.nano()),
     "phi-4-flash": (phi4flash, phi4flash.Phi4FlashConfig.nano()),
 }
 REQUIRED = ("cache_kinds", "init_paged_cache", "paged_decode_step",
