@@ -77,7 +77,7 @@ class BrumbyConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
     state_dtype: Any = jnp.float32
-    # ops.retention.retention_step's `impl` (None: by backend)
+    # ops.retention's `impl`, of the step and the chunk (None: by backend)
     retention_impl: Optional[str] = None
     pos: str = "rope"                 # what the engine reads off a config
 
@@ -208,7 +208,7 @@ def _chunk_pass(params, toks, pos, real, states, cfg: BrumbyConfig):
         log_g = jnp.where(real[None], log_g[0], 0.0)
         o, state = retention_chunk(_grouped(q[0], cfg), k, v[0], log_g,
                                    state.astype(jnp.float32),
-                                   dtype=cfg.dtype)
+                                   impl=cfg.retention_impl, dtype=cfg.dtype)
         x = _mix(x, o.reshape((1, cfg.n_heads) + o.shape[2:]), layer, cfg)
         return x, state
 

@@ -19,14 +19,14 @@ is half empty), so F = (dh/2 + 1) dh features of which dh(dh+1)/2 are
 used — 8,320 for 8,256 at dh 128, whole lanes.  A state is ONE array
 [.., R, F] float32, features along the lanes: rows 0..dh-1 are S^T (one
 row a value dimension), row dh is z (the value 1 appended to v), and R is
-dh + 1 rounded up to whole sublanes (136 at dh 128; the chip would pad a
-[.., 129, 8256] array to exactly this, and a [.., 8256, 129] one to twice
-it).  An update is then a column (v, 1) times a row phi(k), a read-out a
-product that contracts the lanes of both operands.
+dh + 1 rounded up to whole sublanes (136 at dh 128; a [.., 8256, 129]
+array would pad to twice it).
 
-The state and the normaliser are float32; products take bf16 operands and
-accumulate in float32 (the chunk's products and the step's read-out; the
-step's update is elementwise, in float32).
+Both forms are a Pallas kernel on the chip and XLA elsewhere
+(`resolve_impl`).  The state and the normaliser are float32; products take
+bf16 operands and accumulate in float32.  The chunk's kernel keeps the XLA
+body's roundings (features float32, then cast; the state cast for the
+read-out alone): only the order of float32 sums differs.
 """
 
 from __future__ import annotations
@@ -85,14 +85,14 @@ def _quotient(o, dh: int):
     return o[..., :dh] / (o[..., dh:dh + 1] + EPS)
 
 
-def retention_chunk(q, k, v, log_g, state, dtype=jnp.bfloat16):
-    """One chunk of one sequence.  q [Hkv, G, C, dh], k, v [Hkv, C, dh],
-    log_g [Hkv, C] float32, state [Hkv, R, F] float32 (zeros for a
-    sequence's first chunk).  A pad row carries k = 0 and log_g = 0: it
-    adds nothing and forgets nothing.  Returns (o [Hkv, G, C, dh] float32,
-    the state after the chunk).  Products take `dtype` operands.  One K/V
-    head a loop turn: the features of a head's queries ([G, C, F]) are
-    the largest thing alive, not those of all heads."""
+def _chunk_xla(q, k, v, log_g, state, dtype):
+    """`retention_chunk` in XLA: the CPU's path, and what the kernel
+    (`_chunk_kernel`, below the step's) is held to, rounding for
+    rounding.  One K/V head a loop turn: the features of a head's
+    queries ([G, C, F] float32, then again in `dtype`) are the largest
+    thing alive, not those of all heads; they pass through memory, which
+    is what the kernel is there to spare (a chunk's 64 turns at the
+    published widths: 85 MB + 43 MB a turn)."""
     G, C, dh = q.shape[1:]
     R = state.shape[-2]
     i = jnp.arange(C)
@@ -226,7 +226,9 @@ def _step_xla(fq, fk, v1, g, state, layer, idx, live, dtype):
 
 
 def resolve_impl(impl: Optional[str]) -> str:
-    """The path `retention_step(impl=...)` takes: None picks by backend."""
+    """The path `retention_step(impl=...)` and `retention_chunk(impl=...)`
+    take: None picks by backend, the kernels ("pallas") on a TPU and the
+    XLA bodies ("xla") elsewhere."""
     if impl is None:
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
@@ -268,3 +270,155 @@ def retention_step(q, k, v, log_g, state, layer, idx, live,
         else:
             raise ValueError(f"unknown retention impl {impl!r}")
         return _quotient(o, dh), state
+
+
+# -- the chunk as one kernel ---------------------------------------------------
+
+_TILE_PIECES = 13   # at most so many of phi's pieces a grid turn
+_ROW_BLOCK = 128    # rows whose features are formed at once
+
+
+def _chunk_kernel(q_ref, k_ref, v1_ref, v1t_ref, b_ref, bt_ref, w_ref, s_ref,
+                  o_ref, s_out_ref, acc_ref, f_ref, sd_ref, *, dtype):
+    """A grid turn is one K/V head's tile of `phi`'s pieces (whole pieces,
+    dh lanes each).  The head's G x C query rows and C keys stay in VMEM
+    for all its turns.  A turn forms the tile's features of C rows at a
+    time (piece t = u x u rolled by t x its weight, float32, cast to
+    `dtype`), multiplies the queries' against the same lanes of the
+    carried state into a float32 accumulator, and the keys', decayed,
+    into the tile of the new state.  The accumulator lies transposed,
+    [R, G C]: the state's 136 rows stream through the MXU against
+    features held still, where [G C, R] would give the 8 columns past 128
+    a pass of their own (a 512-row program 6.7 -> 5.4 ms, PERF.md section
+    6, PR 57).  The head's last turn adds the quadratic form inside the
+    chunk and divides."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    j, last = pl.program_id(1), pl.num_programs(1) - 1
+    C, dh = k_ref.shape
+    G, W = q_ref.shape[0] // C, s_ref.shape[1]
+    block = _ROW_BLOCK if C % _ROW_BLOCK == 0 else C
+    f32 = jnp.float32
+    lanes = (((1,), (1,)), ((), ()))        # a . b^T
+
+    def features(src, r0, scale=None):
+        """The tile's features of rows r0.. of `src` into f_ref."""
+        for i in range(C // block):
+            u = src[pl.ds(r0 + i * block, block), :].astype(f32)
+            for p in range(W // dh):
+                t = j * (W // dh) + p
+                f = (pltpu.roll(u, jax.lax.rem(dh - t, dh), 1) * u
+                     * w_ref[:, p * dh:(p + 1) * dh])
+                if scale is not None:
+                    f = f * scale[i * block:(i + 1) * block]
+                f_ref[i * block:(i + 1) * block,
+                      p * dh:(p + 1) * dh] = f.astype(dtype)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # before the chunk: the queries' features against the carried state
+    sd_ref[...] = s_ref[...].astype(dtype)
+
+    def against_state(g, _):
+        rows = pl.ds(pl.multiple_of(g * C, C), C)
+        features(q_ref, pl.multiple_of(g * C, block))
+        acc_ref[:, rows] += jax.lax.dot_general(
+            sd_ref[...], f_ref[...], lanes, preferred_element_type=f32)
+
+    jax.lax.fori_loop(0, G, against_state, None)
+
+    # the state after it: decayed, plus every key's column x row
+    b, b_last = b_ref[...], b_ref[C - 1:C, :]              # [C, 1], [1, 1]
+    features(k_ref, 0, jnp.exp(b_last - b))
+    # (a [1, 1] is not broadcast both ways at once: down the rows first)
+    forget = jnp.exp(jnp.broadcast_to(b_last, (s_ref.shape[0], 1)))
+    s_out_ref[...] = forget * s_ref[...] + jnp.dot(
+        v1t_ref[...], f_ref[...], preferred_element_type=f32)
+
+    @pl.when(j == last)
+    def _():
+        # inside the chunk: the quadratic form, causal, decayed
+        i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+        see = i >= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+        decay = jnp.exp(jnp.where(see, b - bt_ref[...], -jnp.inf))
+        grow = jnp.exp(b)
+        kd = k_ref[...].astype(dtype)
+
+        def inside(g, _):
+            rows = pl.ds(pl.multiple_of(g * C, C), C)
+            s = jax.lax.dot_general(q_ref[rows, :].astype(dtype), kd, lanes,
+                                    preferred_element_type=f32)
+            a = s * s * decay
+            intra = jnp.dot(a.astype(dtype), v1_ref[...],
+                            preferred_element_type=f32)
+            inter = acc_ref[:, rows].T
+            # the normaliser from the float32 weights themselves
+            z = a.sum(-1, keepdims=True) + grow * inter[:, dh:dh + 1]
+            o_ref[rows, :] = (intra[:, :dh] + grow * inter[:, :dh]) / (z + EPS)
+
+        jax.lax.fori_loop(0, G, inside, None)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+def _chunk_pallas(q, k, v, log_g, state, dtype, interpret: bool):
+    """The chunk as one kernel.  What is F wide — a head's features, the
+    [2560, 8320] float32 of the XLA body — exists a tile of pieces at a
+    time in VMEM; the state is read once and written once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Hkv, G, C, dh = q.shape
+    R, F = state.shape[-2:]
+    n = F // dh
+    tile = dh * max(p for p in range(1, _TILE_PIECES + 1) if n % p == 0)
+    b = jnp.cumsum(log_g.astype(jnp.float32), axis=-1)          # [Hkv, C]
+    v1 = _with_one(v, R).astype(dtype)                          # [Hkv, C, R]
+    # (`phi`'s weights [1, F], the kernel's seventh operand, are phi(1))
+    # a head's own block, kept for all its turns; a turn's tile of lanes
+    head = lambda *shape: pl.BlockSpec((None,) + shape,
+                                       lambda h, j: (h, 0, 0))
+    tile_of_state = pl.BlockSpec((None, R, tile), lambda h, j: (h, 0, j))
+    tile_of_weights = pl.BlockSpec((1, tile), lambda h, j: (0, j))
+    o, state = pl.pallas_call(
+        functools.partial(_chunk_kernel, dtype=dtype),
+        grid=(Hkv, F // tile),
+        in_specs=[head(G * C, dh), head(C, dh), head(C, R), head(R, C),
+                  head(C, 1), head(1, C), tile_of_weights, tile_of_state],
+        out_specs=[head(G * C, dh), tile_of_state],
+        out_shape=[jax.ShapeDtypeStruct((Hkv, G * C, dh), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((R, G * C), jnp.float32),
+                        pltpu.VMEM((C, tile), dtype),
+                        pltpu.VMEM((R, tile), dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret, name="retention_chunk",
+    )(q.reshape(Hkv, G * C, dh), k, v1, jnp.swapaxes(v1, 1, 2),
+      b[..., None], b[:, None, :], phi(jnp.ones((1, dh))), state)
+    return o.reshape(Hkv, G, C, dh), state
+
+
+def retention_chunk(q, k, v, log_g, state, impl: Optional[str] = None,
+                    dtype=jnp.bfloat16):
+    """One chunk of one sequence.  q [Hkv, G, C, dh], k, v [Hkv, C, dh],
+    log_g [Hkv, C] float32, state [Hkv, R, F] float32 (zeros for a
+    sequence's first chunk).  A pad row carries k = 0 and log_g = 0: it
+    adds nothing and forgets nothing.  Returns (o [Hkv, G, C, dh] float32,
+    the state after the chunk).  Products take `dtype` operands.
+
+    `impl`: "pallas" (the chip's path: `_chunk_kernel`),
+    "pallas_interpret", or "xla" (`_chunk_xla`); None picks by backend."""
+    impl = resolve_impl(impl)
+    if impl == "xla":
+        return _chunk_xla(q, k, v, log_g, state, dtype)
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown retention impl {impl!r}")
+    if state.dtype != jnp.float32:
+        raise ValueError("the kernel keeps its state in float32")
+    with jax.named_scope("retention_chunk"):
+        return _chunk_pallas(q, k, v, log_g, state, dtype=dtype,
+                             interpret=impl == "pallas_interpret")

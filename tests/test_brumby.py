@@ -4,6 +4,8 @@ quadratic form, float32, written from the layer equations) on LOGITS.
 Both sides compute in float32 here: 2e-6 apart on logits of size 1.  The
 tolerance is 1e-4; the mutations below move a logit by more than 1e-3."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -43,8 +45,12 @@ def ref_logits(model, toks):
     return np.asarray(ref.forward(params, jnp.asarray(toks), shape_of(cfg)))
 
 
-def test_full_forward_matches_reference(model):
+@pytest.mark.parametrize("impl", [None, "pallas_interpret"])
+def test_full_forward_matches_reference(model, impl):
+    """`apply` maps one sequence's pass over the batch: under that map the
+    chunk's kernel gains a grid axis."""
     cfg, params = model
+    cfg = dataclasses.replace(cfg, retention_impl=impl)
     toks = np.stack([tokens(40), tokens(40, seed=3)])
     got = np.asarray(bm.apply(params, jnp.asarray(toks), cfg))
     for row, t in zip(got, toks):
@@ -66,13 +72,17 @@ def test_interface_and_sizes():
     assert 330.2e6 < n < 330.5e6
 
 
+@pytest.mark.parametrize("impl", [None, "pallas_interpret"])
 @pytest.mark.parametrize("chunk", [8, 16, 64])
-def test_chunked_prefill_then_decode_matches_reference(model, chunk):
+def test_chunked_prefill_then_decode_matches_reference(model, chunk, impl):
     """The serve programs by hand: a 29-token prompt in chunks (the last
     one ragged, padded to the program's length), then 12 decode steps in
     slot 1 of three beside two empty slots, teacher-forced; every logits
-    row against the reference's full forward."""
+    row against the reference's full forward — through the XLA bodies and
+    through both kernels (interpret mode)."""
     cfg, params = model
+    cfg = dataclasses.replace(cfg, retention_impl=impl)
+    moved = 3.0 if impl is None else 1.0        # the step's kernel skips
     toks = tokens(41, seed=5)
     want = ref_logits(model, toks)
     plen = 29
@@ -93,7 +103,7 @@ def test_chunked_prefill_then_decode_matches_reference(model, chunk):
             params, cache, jnp.array([0, toks[p], 0], jnp.int32), ptabs,
             jnp.array([0, p, 0], jnp.int32), cfg)
         assert np.abs(np.asarray(lg)[1] - want[p]).max() < TOL, p
-        assert float(stats[0]) == 3.0
+        assert float(stats[0]) == moved
     # nothing but entry 2 was written
     assert float(jnp.abs(cache[:, jnp.array([0, 1, 3])]).max()) == 0.0
 
@@ -102,8 +112,6 @@ def test_the_kernel_path_decodes_what_the_gather_path_decodes(model):
     """`retention_impl="pallas_interpret"`: the step's kernel inside the
     layer scan, arena carried and aliased, against the gather / scatter
     path; it counts the live slot's state alone as moved."""
-    import dataclasses
-
     cfg, params = model
     kern = dataclasses.replace(cfg, retention_impl="pallas_interpret")
     toks = tokens(12, seed=4)

@@ -645,7 +645,7 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
                          ids=["step", "prefill512"])
 def test_brumby_serve_programs_fit_one_chip(one_chip, key):
     """The state-kind serve programs (the retention step kernel, the
-    chunked retention) at the benchmark configuration's sizes, 16 slots:
+    chunk's kernel) at the benchmark configuration's sizes, 16 slots:
     the chip's compiler takes them, weights + the state arena +
     temporaries stay under the chip's 15.75 GB, and the arena is held
     once: the programs are given it to keep, the step's kernel and the
@@ -702,12 +702,29 @@ def test_brumby_serve_programs_fit_one_chip(one_chip, key):
              if f"= f32[{dims}]" in ln
              and any(f" {op}(" in ln for op in ("copy", "transpose"))]
     assert not moved, moved
-    if key == "step":       # one kernel for every layer, under its name
-        calls = [ln for ln in text.splitlines()
-                 if 'custom_call_target="tpu_custom_call"' in ln]
-        assert len(calls) == 1
-        assert "retention_step" in calls[0].split(" = ", 1)[0]
+    # one kernel for every layer, under its name
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    name = calls[0].split(" = ", 1)[0].split()[-1].lstrip("%")
+    if key == "step":
+        assert name.startswith("retention_step")
         _assert_sampler_asks_its_operands(compiled, B, V)
+    else:
+        # the chunk's kernel (PR 57), charged to the scope the cell's
+        # roofline reads: `replica_brumby.bench_program_scopes`' own call
+        from benchmarks.drivers.replica_brumby import SCOPES
+        from benchmarks.trace.scopes import scope_map
+
+        assert name.startswith("retention_chunk")
+        scopes = scope_map(text, SCOPES, {"retention_step": "retention_step"})
+        assert scopes[name] == "retention_chunk"
+        # nothing is F wide but a state and `phi`'s row of weights: the
+        # XLA body kept a head's query features, bf16[5,512,8320], and
+        # the keys' f32[512,8320] (907,330,560 B of temporaries)
+        wide = set(re.findall(r"\w+\[([\d,]*),8320\]", text))
+        assert wide and all(d.split(",")[-1] in ("136", "1") for d in wide), wide
+        assert m.temp_size_in_bytes < 700e6, m.temp_size_in_bytes
     print(key, "total", total, "temp", m.temp_size_in_bytes)
 
 

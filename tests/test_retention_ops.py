@@ -167,3 +167,105 @@ def test_kernel_moves_only_live_slots_blocks(live):
              for b in range(3) for h in range(HKV)]
     moved = 1 + sum(a != b for a, b in zip(turns, turns[1:]))
     assert moved == max(n * HKV, 1)
+
+
+@jax.jit
+def _recur(q, k, v, lg, state):
+    """Token by token from `state` [Hkv, R, F]: q [T, Hkv, G, dh], k, v
+    [T, Hkv, dh], lg [T, Hkv] -> (o [T, Hkv, G, dh], the state after)."""
+    arena = jnp.zeros((1, 2) + state.shape).at[0, 1].set(state)
+    idx, live = jnp.array([1]), jnp.array([1])
+
+    def step(arena, x):
+        o, arena = R.retention_step(*(a[None] for a in x), arena, 0, idx,
+                                    live, impl="xla", dtype=jnp.float32)
+        return arena, o[0]
+
+    arena, o = jax.lax.scan(step, arena, (q, k, v, lg))
+    return o, arena[0, 1]
+
+
+def _as_chunk(q, k, v, lg, C):
+    """[T, ..] rows -> `retention_chunk`'s operands, padded to C rows (a
+    pad row: k = 0, log g = 0; its q and v are whatever came)."""
+    n = q.shape[0]
+    real = (jnp.arange(C) < n)
+    pad = lambda x: jnp.pad(x, ((0, C - n),) + ((0, 0),) * (x.ndim - 1),
+                            constant_values=0.5)
+    k = jnp.where(real[:, None, None], pad(k), 0)
+    lg = jnp.where(real[:, None], pad(lg), 0.0)
+    return (jnp.moveaxis(pad(q), 0, 2), jnp.moveaxis(k, 0, 1),
+            jnp.moveaxis(pad(v), 0, 1), lg.T)
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["zero", "carried"])
+@pytest.mark.parametrize("hkv,g,dh", [(2, 3, 16), (1, 5, 128)],
+                         ids=["dh16", "g5dh128"])
+@pytest.mark.parametrize("C", [128, 256, 384, 512])
+def test_chunk_kernel_is_the_xla_chunk_and_the_recurrence(C, hkv, g, dh,
+                                                          carried):
+    """The chunk's kernel (interpret mode) at the engine's four program
+    lengths, the last 5 rows padding: its outputs and its float32 state
+    against the XLA body's and against the token-by-token form, from an
+    empty and from a carried state; then 4 steps from the state it left
+    against one longer chunk (the XLA body: any length)."""
+    n, more = C - 5, 4
+    ks = jax.random.split(jax.random.PRNGKey(C + dh), 5)
+    q = jax.random.normal(ks[0], (n + more, hkv, g, dh))
+    k = jax.random.normal(ks[1], (n + more, hkv, dh))
+    v = jax.random.normal(ks[2], (n + more, hkv, dh))
+    lg = jax.nn.log_sigmoid(3 + jax.random.normal(ks[3], (n + more, hkv)))
+    state = jnp.zeros((hkv,) + R.state_shape(dh))
+    if carried:
+        # what some earlier keys left: a state is not any array
+        _, state = _recur(*(a[:8] for a in (q, k[::-1], v[::-1], lg)), state)
+    ops = _as_chunk(q[:n], k[:n], v[:n], lg[:n], C)
+    got_o, got_s = R.retention_chunk(*ops, state, impl="pallas_interpret",
+                                     dtype=jnp.float32)
+    xla_o, xla_s = R.retention_chunk(*ops, state, impl="xla",
+                                     dtype=jnp.float32)
+    want_o, want_s = _recur(q[:n], k[:n], v[:n], lg[:n], state)
+    rows = lambda o: np.asarray(jnp.moveaxis(o, 2, 0))[:n]
+    assert _rel(rows(got_o), rows(xla_o)) < TOL
+    # (a first row's weight is ONE square, which may lie near EPS: the sum
+    # over features resolves it to 1e-5 and the quotient shows it)
+    first = 0 if carried else 1
+    assert _rel(rows(got_o)[first:], np.asarray(want_o)[first:]) < TOL
+    assert _rel(got_s, np.asarray(xla_s)) < TOL
+    assert _rel(got_s, np.asarray(want_s)) < TOL
+    # a chunk followed by steps = one longer chunk
+    then_o, then_s = _recur(q[n:], k[n:], v[n:], lg[n:], got_s)
+    long_o, long_s = R.retention_chunk(
+        *_as_chunk(q, k, v, lg, n + more), state, impl="xla",
+        dtype=jnp.float32)
+    assert _rel(then_o, np.asarray(jnp.moveaxis(long_o, 2, 0))[n:]) < TOL
+    assert _rel(then_s, np.asarray(long_s)) < TOL
+
+
+def test_chunk_kernel_rounds_where_the_xla_chunk_rounds():
+    """bf16 operands: the kernel casts what the XLA body casts (features
+    after they are formed in float32, the carried state for the read-out
+    alone), so the two differ by the order of float32 sums — far inside
+    what one bf16 rounding moves (2^-9)."""
+    hkv, g, C, dh = 2, 5, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    bf = lambda key, *shape: jax.random.normal(key, shape, jnp.bfloat16)
+    q, k, v = bf(ks[0], hkv, g, C, dh), bf(ks[1], hkv, C, dh), bf(
+        ks[2], hkv, C, dh)
+    lg = jax.nn.log_sigmoid(3 + jax.random.normal(ks[3], (hkv, C)))
+    state = jax.random.normal(ks[4], (hkv,) + R.state_shape(dh))
+    got = R.retention_chunk(q, k, v, lg, state, impl="pallas_interpret")
+    want = R.retention_chunk(q, k, v, lg, state, impl="xla")
+    assert got[1].dtype == jnp.float32
+    for a, b in zip(got, want):
+        assert _rel(a, np.asarray(b)) < 1e-5
+
+
+def test_chunk_rejects_an_unknown_impl_and_a_bf16_state():
+    z = jnp.zeros
+    args = (z((1, 1, 8, 16)), z((1, 8, 16)), z((1, 8, 16)), z((1, 8)))
+    with pytest.raises(ValueError, match="unknown retention impl"):
+        R.retention_chunk(*args, z((1,) + R.state_shape(16)), impl="mosaic")
+    with pytest.raises(ValueError, match="float32"):
+        R.retention_chunk(*args, z((1,) + R.state_shape(16), jnp.bfloat16),
+                          impl="pallas_interpret")
