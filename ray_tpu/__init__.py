@@ -59,6 +59,7 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[float] = None,
     worker.py:1260).
     """
     global _core, _owned_cluster
+    t_entered = time.time()
     with _lock:
         if _core is not None:
             if ignore_reinit_error:
@@ -131,6 +132,11 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[float] = None,
         _core = CoreWorker(control_addr, raylet_addr, mode="driver",
                            namespace=namespace, log_to_driver=log_to_driver,
                            node_id=node_id, store_root=store_root)
+        # the driver's side of the boot timeline: control plane and raylet
+        # answering, this driver connected
+        _common.boot_part("cluster_start", t_entered)
+        logger.info("driver boot: cluster start %.1f s",
+                    _common.BOOT.get("cluster_start_s", 0.0))
         atexit.register(shutdown)
         # metrics created before a previous shutdown() flush again
         _metrics = sys.modules.get("ray_tpu.util.metrics")

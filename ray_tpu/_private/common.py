@@ -12,6 +12,8 @@ from __future__ import annotations
 import os
 import pickle
 import threading
+import time
+from collections import deque
 from dataclasses import dataclass, field, fields as dataclass_fields
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -320,3 +322,102 @@ def read_addr_file(path: Optional[str]) -> Optional[Tuple[str, int]]:
         return (host, int(port))
     except Exception:
         return None
+
+
+# -- how this process came up, and when it stood still -----------------------
+# Kept here because every process imports this module at its start and the
+# device snapshot (`telemetry/device.device_snapshot`) ships both.  Stamps
+# are `time.time()`: one clock for the driver, the raylet, a worker and
+# whoever lays their timelines side by side.
+
+#: the boot's parts in wall order, `<part>_wall` (where it began) and
+#: `<part>_s`.  A worker: start (the raylet's Popen -> `main()` entered:
+#: fork, the interpreter, every import), connect (-> `WorkerMain` built:
+#: the core worker, its connections and store), register (-> registered
+#: with the raylet) and, an actor's process, pool (a prestarted worker
+#: only: -> the raylet made it an actor's), actor_wait (-> its constructor
+#: called: the spec fetched, the class and arguments unpickled and waited
+#: for) and actor_init (the constructor).  A driver: cluster_start (`init()` entered
+#: -> control plane and raylet answering, this driver connected).
+BOOT: Dict[str, float] = {}
+
+
+def boot_part(part: str, t_wall: Optional[float] = None) -> None:
+    """Close `part` of this process's boot: it began at `t_wall` — where
+    the part before it ended, if not given — and ends now.  One clock
+    read; `BOOT["until_wall"]` is where the last closed part ended."""
+    now = time.time()
+    if t_wall is None:
+        t_wall = BOOT.get("until_wall", now)
+    BOOT[f"{part}_wall"] = t_wall
+    BOOT[f"{part}_s"] = now - t_wall
+    BOOT["until_wall"] = now
+
+
+def spawn_wall() -> Optional[float]:
+    """The raylet's clock at this worker's `Popen`, which it passes among
+    the worker's variables beside the startup token -> None where it is
+    missing or unreadable (a process nobody spawned: `start` is 0 s)."""
+    try:
+        return float(os.environ["RAY_TPU_SPAWN_WALL"]) or None
+    except (KeyError, ValueError):
+        return None
+
+
+def log_boot(log) -> None:
+    """One line when an actor's constructor has returned: the boot's parts
+    in seconds, from the spawn's wall stamp.  A part this process never
+    closed reads 0; never raises into the constructor's caller."""
+    try:
+        log.info("worker boot: start %.2f connect %.2f register %.2f pool "
+                 "%.2f wait %.2f init %.2f s from %.3f" % (
+                     *(BOOT.get(f"{part}_s", 0.0) for part in (
+                         "start", "connect", "register", "pool",
+                         "actor_wait", "actor_init")),
+                     BOOT.get("start_wall", 0.0)))
+    except Exception:
+        pass
+
+
+#: the last wakes that came late, a loop's own last 64 (`by` names the
+#: loop that noticed: the engine's ticker notes 80 ms wakes by the dozen
+#: and must not push out the one 4-9 s freeze the worker's main loop saw):
+#: `{t_wall, late_s, by}` — the wake was due at `t_wall` and came `late_s`
+#: after it; nothing of this process ran in between (stopped, starved of
+#: its core or of the interpreter, or the whole machine frozen).
+STALLS: Dict[str, "deque[Dict[str, Any]]"] = {}
+
+#: a loop that sleeps says so when it wakes this much later than it asked
+STOOD_STILL_S = 1.0
+
+
+def note_stall(late_s: float, by: str) -> float:
+    """One entry of `STALLS`: a wake that was due `late_s` ago came now ->
+    now."""
+    now = time.time()
+    STALLS.setdefault(by, deque(maxlen=64)).append({"t_wall": now - late_s, "late_s": late_s, "by": by})
+    return now
+
+
+def stalls() -> List[Dict[str, Any]]:
+    """Every loop's kept late wakes, in wall order."""
+    return sorted((s for kept in list(STALLS.values()) for s in list(kept)),
+                  key=lambda s: s["t_wall"])
+
+
+def note_late_wake(log, late_s: float, by: str) -> None:
+    """One line, the same words in every process, so that `grep 'stood
+    still'` over a session's logs lines the processes up on the wall
+    clock; and one entry of `STALLS`."""
+    if late_s > STOOD_STILL_S:
+        log.warning("stood still %.1f s until %.3f", late_s,
+                    note_stall(late_s, by))
+
+
+def sleep_watched(log, seconds: float, by: str) -> float:
+    """`time.sleep(seconds)` that notes a late wake -> how late it came."""
+    t = time.monotonic()
+    time.sleep(seconds)
+    late_s = time.monotonic() - t - seconds
+    note_late_wake(log, late_s, by)
+    return late_s

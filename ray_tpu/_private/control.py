@@ -1722,7 +1722,9 @@ class ControlServer:
         while not self._stop.is_set():
             time.sleep(HEARTBEAT_INTERVAL_S)
             now = time.monotonic()
-            self._credit_stall(now - tick - HEARTBEAT_INTERVAL_S)
+            late_s = now - tick - HEARTBEAT_INTERVAL_S
+            common.note_late_wake(logger, late_s, "control-health")
+            self._credit_stall(late_s)
             tick = now
             dead_nodes: List[NodeRecord] = []
             drain_expired: List[NodeRecord] = []

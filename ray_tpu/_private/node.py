@@ -629,6 +629,9 @@ class Raylet:
         worker_vars = {
             "PYTHONPATH": _package_pythonpath(),
             "RAY_TPU_STARTUP_TOKEN": str(token),
+            # this clock's reading at the spawn: the worker's boot
+            # timeline counts from it (`common.BOOT`)
+            "RAY_TPU_SPAWN_WALL": repr(time.time()),
             "RAY_TPU_WORKER_ID": wid,
             # line-buffered stdout so task prints reach the log tailer
             # (and the driver) promptly, not on buffer flushes
@@ -2135,7 +2138,8 @@ class Raylet:
             except Exception:
                 if not self._stop.is_set():
                     logger.warning("heartbeat to control failed")
-            time.sleep(HEARTBEAT_INTERVAL_S)
+            common.sleep_watched(logger, HEARTBEAT_INTERVAL_S,
+                                 "raylet-heartbeat")
 
 
 def main():

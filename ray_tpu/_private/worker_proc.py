@@ -225,11 +225,13 @@ class WorkerMain:
         self.core.raylet._on_push = self._on_raylet_push
         self.core.raylet._on_disconnect = self._exit_soon
 
+        common.boot_part("connect")
         r = self.core.raylet.call("register_worker", {
             "token": self.token, "addr": self.core.addr,
         }, timeout=30.0)
         if not r.get("ok"):
             raise RuntimeError(f"worker registration rejected: {r}")
+        common.boot_part("register")
 
         # apply the driver-registered tracing startup hook, if any
         # (reference: tracing_helper.py hook runs in every worker)
@@ -282,7 +284,10 @@ class WorkerMain:
                     for a in args]
             kwargs = {k: self.core.get(v) if isinstance(v, ObjectRef) else v
                       for k, v in kwargs.items()}
+            common.boot_part("actor_wait")
             self.actor_instance = cls(*args, **kwargs)
+            common.boot_part("actor_init")
+            common.log_boot(logger)
             # async actors (any coroutine method) run ALL their methods on
             # the event-loop thread — the reference's async-actor model:
             # cooperative concurrency on one thread, sync methods block the
@@ -452,6 +457,7 @@ class WorkerMain:
             # prestarted-worker reuse (reference: worker_pool.h PopWorker):
             # a warm idle worker becomes this actor's dedicated process,
             # skipping the interpreter + jax import cost of a fresh spawn
+            common.boot_part("pool")    # how long it idled there
             self.actor_id = payload["actor_id"]
             self.incarnation = payload.get("incarnation", 0)
             threading.Thread(target=self._init_actor, daemon=True).start()
@@ -870,6 +876,9 @@ class WorkerMain:
 
 
 def main():
+    # the boot's first part: from the raylet's stamp of the Popen (among
+    # the variables it passes, beside the startup token) to here
+    common.boot_part("start", common.spawn_wall())
     ap = argparse.ArgumentParser()
     ap.add_argument("--raylet", required=True)
     ap.add_argument("--control", required=True)
@@ -898,7 +907,7 @@ def main():
     w = WorkerMain((ch, int(cp)), (rh, int(rp)))
     try:
         while not w._stop.is_set():
-            time.sleep(0.5)
+            common.sleep_watched(logger, 0.5, "worker-main")
     except KeyboardInterrupt:
         pass
 

@@ -22,6 +22,7 @@ import traceback
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private import common
 
 from ._common import (APP_RUNNING, DEPLOY_FAILED, DEPLOYING, RUNNING,
                       STARTING, ApplicationStatus, AutoscalingConfig,
@@ -483,7 +484,31 @@ class ServeController:
                 self._publish_status()
             except Exception:
                 logger.debug("serve status publish failed", exc_info=True)
-            time.sleep(RECONCILE_PERIOD_S)
+            # the loop's own sleep, watched: a wake that comes late is
+            # time this prober did not run
+            self._credit_stall(common.sleep_watched(
+                logger, RECONCILE_PERIOD_S, "serve-controller"))
+
+    def _credit_stall(self, late_s: float):
+        """The prober does not count time it stood still itself against a
+        replica (as `control._credit_stall` for the nodes' heartbeats):
+        `late_s` is how much later than asked this loop woke — the
+        process stopped or starved, or the whole machine frozen, as it
+        is for 4-9 s when a worker first reaches the chip — so an answer
+        could not have been taken in meanwhile, most likely not given
+        either, and every replica's probe clocks move on by it.  A
+        replica that is wedged while this loop runs is found as before."""
+        if late_s <= RECONCILE_PERIOD_S:
+            return
+        now = time.time()
+        with self._lock:
+            for app in self._apps.values():
+                for ds in app["deployments"].values():
+                    for r in ds.replicas.values():
+                        r.last_health_ts = min(
+                            now, r.last_health_ts + late_s)
+                        r.health_fired_ts = min(
+                            now, r.health_fired_ts + late_s)
 
     def _publish_status(self):
         """Push a plain-dict snapshot to the control-plane KV (ns

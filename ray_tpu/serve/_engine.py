@@ -173,6 +173,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from .._private import common
 from ..util import tracing
 
 __all__ = ["AdmissionRejected", "ContinuousEngine", "PageAllocator"]
@@ -186,6 +187,17 @@ class AdmissionRejected(Exception):
     def __init__(self, msg: str, retry_after_s: float = 1.0):
         super().__init__(msg)
         self.retry_after_s = retry_after_s
+
+
+# the stood-still ticker beside the engine thread (`_tick`): it sleeps this
+# long, and a wake later than this is a stall.  Constants, not fields: the
+# counter means the same in every engine.  A wake costs the thread that
+# holds the interpreter: at 50 ms six untraced pairs of
+# `serve-large-chat-loaded` (3.5 ms steps) read TTFT -0.55% and ITL -0.27%
+# with mixed signs (PR 59; at 20 ms an earlier builder read TTFT +1.6%),
+# and a stall of 70 ms or more is still always seen.
+_TICK_S = 0.05
+_TICK_LATE_S = 0.02
 
 
 # ---------------------------------------------------------------------------
@@ -598,7 +610,9 @@ class ContinuousEngine:
         self._draining = False        # guarded-by: _lock
         self._rid = 0
         self.stall_s = float(stall_s)
-        self._health_snap: Optional[Tuple[int, float]] = None
+        # (steps, the clock, what the ticker had counted) at the last probe
+        # that saw a step taken
+        self._health_snap: Optional[Tuple[int, float, float]] = None
 
         # device state (built lazily on the engine thread)
         self._cache = None
@@ -639,9 +653,25 @@ class ContinuousEngine:
                         # garbage collections of this process while the
                         # engine thread lived (_on_gc alone writes them)
                         "gc_s": 0.0, "gc_collections": 0, "gc_max_s": 0.0,
+                        # wakes of the ticker beside the engine thread that
+                        # came late, and by how much: nothing of this
+                        # process ran meanwhile (`_tick` alone writes them)
+                        "stalls": 0, "stall_s": 0.0, "stall_max_s": 0.0,
                         # the model's own counters (its STEP_STATS names)
                         **dict.fromkeys(self._stat_keys, 0.0)}
         self._gc_t0: Optional[float] = None   # a collection under way
+        # `stall_s` at the last ring record (or where the loop last idled)
+        self._stall_seen = 0.0
+        self._ticker: Optional[threading.Thread] = None
+        # the ticker's clock and when its sleep is due (None: no ticker)
+        self._tick_due: Optional[Tuple[Any, float]] = None
+        # when the engine thread started and each program was first
+        # launched, on the wall clock (`engine_stats()["ready"]`: a
+        # replica's warm-up, between its boot and the window).  A launch's
+        # stamp is its `_t_call` moved onto the wall clock: no clock read
+        self._thread_start_wall: Optional[float] = None
+        self._first_launch_wall: Dict[str, float] = {}
+        self._wall_of_perf = time.time() - time.perf_counter()
         self._iter = 0               # iterations since the engine started
         self._t_call = 0.0           # the last launch was entered
         self._t_free = 0.0           # the last launch returned, or wait ended
@@ -764,7 +794,8 @@ class ContinuousEngine:
         where bf16 is served, whatever the caller's tree is kept in),
         plus the running totals — counters and the ring's cumulative
         sums, so two snapshots give rates and phase shares over any
-        interval."""
+        interval — and `ready`: when the engine thread started and each
+        program was first launched, on the wall clock."""
         now = time.perf_counter()
         with self._lock:
             active = sum(1 for s in self._slots if s is not None)
@@ -773,6 +804,8 @@ class ContinuousEngine:
             window = [(t, n) for t, n in self._t_window if now - t <= 10.0]
             draining = self._draining
             totals = dict(self._totals)
+            ready = {"thread_start_wall": self._thread_start_wall,
+                     "first_launch_wall": dict(self._first_launch_wall)}
         toks = sum(n for _, n in window)
         span = (now - window[0][0]) if window else 0.0
         return {
@@ -788,6 +821,7 @@ class ContinuousEngine:
             **self._param_stats,
             **self._state_stats(),
             **totals,
+            "ready": ready,
         }
 
     def _state_stats(self) -> Dict[str, int]:
@@ -892,17 +926,33 @@ class ContinuousEngine:
         # process from answering one for 9 s of a cold `serve.prefill:512`
         # of 32 layers, and the next probe read 11.3 s without a step: PR
         # 51), so it cannot be the probes that remember it
+        # Nor is the engine's bring-up: its first admission takes a slot,
+        # THEN builds the device state (the arena's fills are programs of
+        # jax's own that compile too) and the first operands, and on an
+        # empty cache a replica's other threads trace beside it (a
+        # benchmark's reference: 11.4 s with a slot taken and nothing
+        # launched in `serve-ling3flash-reasoning`, PR 59) — until the
+        # first launch there is no step to miss
         fns = list(self._fns.values())
-        compiling = any(getattr(fn, "first_compile_in_flight", False)
-                        for fn in fns)
+        compiling = not self._first_launch_wall or any(
+            getattr(fn, "first_compile_in_flight", False) for fn in fns)
+        stood = self._stood_still_s()
         if active == 0 or snap is None or snap[0] != steps or compiling:
-            self._health_snap = snap = (steps, now)
+            self._health_snap = snap = (steps, now, stood)
         since = max([snap[1]] + [getattr(fn, "first_compile_ended", 0.0)
                                  for fn in fns])
-        if now - since > self.stall_s:
+        # ... nor is a stretch in which nothing of this process ran (the
+        # machine frozen 4-9 s while another process reaches the chip, the
+        # process stopped or starved): the engine could not have stepped,
+        # the ticker beside it saw as much, and that time is taken off —
+        # as the control plane credits its own late wakes to every node
+        # (`control._credit_stall`).  An engine that is wedged while the
+        # process runs gives the ticker nothing to count
+        silent_s = now - since - (stood - snap[2])
+        if silent_s > self.stall_s:
             raise RuntimeError(
                 f"engine stalled: {active} active slots but no decode "
-                f"step for {now - since:.1f}s (> {self.stall_s:g}s)")
+                f"step for {silent_s:.1f}s (> {self.stall_s:g}s)")
         for a in self._allocs.values():
             in_use = len(a._refs)
             if len(a._free) + in_use != a.num_pages - 1:
@@ -912,6 +962,18 @@ class ContinuousEngine:
             if any(n <= 0 for n in a._refs.values()):
                 raise RuntimeError("page refcount <= 0 in allocator")
         return True
+
+    def _stood_still_s(self) -> float:
+        """What the ticker has counted, and the wake it is still owed: a
+        probe that runs first after a freeze must not read it as the
+        engine's before the ticker has said so."""
+        owed = 0.0
+        due = self._tick_due
+        if due is not None:
+            clock, t_due = due
+            owed = clock() - t_due
+        return self._totals["stall_s"] + (owed if owed > _TICK_LATE_S
+                                          else 0.0)
 
     def stop(self):
         try:
@@ -949,10 +1011,20 @@ class ContinuousEngine:
         # the interpreter tells this hook of every collection, whichever
         # thread triggers it, for as long as the engine thread lives
         gc.callbacks.append(self._on_gc)
+        # ... and the ticker beside it tells "this process was not run"
+        # from "the program took longer": both lengthen an iteration
+        done = threading.Event()
+        self._ticker = threading.Thread(
+            target=self._tick, args=(done,), name="serve-engine-tick",
+            daemon=True)
+        self._ticker.start()
+        self._thread_start_wall = time.time()
         try:
             self._run()
         finally:
             gc.callbacks.remove(self._on_gc)
+            done.set()
+            self._ticker.join(timeout=1.0)
 
     def _on_gc(self, phase: str, info: Dict[str, int]):
         """`gc.callbacks` hook: two clock reads a collection.  It runs on
@@ -968,6 +1040,33 @@ class ContinuousEngine:
             tot["gc_collections"] += 1
             tot["gc_max_s"] = max(tot["gc_max_s"], dt)
 
+    def _tick(self, done: threading.Event, clock=time.perf_counter,
+              sleep=time.sleep):
+        """The stood-still counter: sleep `_TICK_S`, note every wake that
+        comes more than `_TICK_LATE_S` late.  An independent witness: it
+        waits for nothing but its own sleep, so a late wake means nothing
+        of this process ran (stopped, starved of its core or of the
+        interpreter, the machine frozen) — where the engine thread's own
+        clock reads cannot tell that from a program that took longer.  Two
+        clock reads a wake, none on the engine thread; like `_on_gc` it
+        alone writes its sums and takes no lock."""
+        tot = self._totals
+        t = clock()
+        try:
+            while not done.is_set():
+                self._tick_due = (clock, t + _TICK_S)
+                sleep(_TICK_S)
+                now = clock()
+                late_s = now - t - _TICK_S
+                t = now
+                if late_s > _TICK_LATE_S:
+                    tot["stalls"] += 1
+                    tot["stall_s"] += late_s
+                    tot["stall_max_s"] = max(tot["stall_max_s"], late_s)
+                    common.note_stall(late_s, "serve-engine-tick")
+        finally:
+            self._tick_due = None
+
     def _run(self):
         while True:
             with self._lock:
@@ -979,6 +1078,8 @@ class ContinuousEngine:
                 with self._jax.profiler.TraceAnnotation("serve.engine.idle"):
                     self._wake.wait(timeout=0.2)
                 self._wake.clear()
+                # a stall of the idle stretch is no iteration's
+                self._stall_seen = self._totals["stall_s"]
                 continue
             try:
                 self._iteration()
@@ -1119,6 +1220,11 @@ class ContinuousEngine:
                     g.set(val)
             # the record closes here: only its append comes after
             rec["gc_s"] = self._totals["gc_s"] - gc0
+            # what the ticker counted since the record before this one,
+            # or since the idle stretch before this iteration ended
+            stall_s = self._totals["stall_s"]
+            rec["stall_s"], self._stall_seen = (stall_s - self._stall_seen,
+                                                stall_s)
             rec["iter_s"] = time.perf_counter() - t0
             rec["host_s"] = rec["iter_s"] - rec["device_wait_s"]
             tot = self._totals
@@ -1157,6 +1263,9 @@ class ContinuousEngine:
             self._t_free = time.perf_counter()
         self._launched["dispatch_s"] += self._t_free - self._t_call
         self._launched["launches"] += 1
+        if program not in self._first_launch_wall:
+            self._first_launch_wall[program] = (self._wall_of_perf
+                                                + self._t_call)
         return out
 
     def _wait(self, name: str, *fetch, ready=None) -> Tuple[float, list]:

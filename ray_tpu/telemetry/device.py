@@ -14,8 +14,14 @@ and HBM occupancy is *the* capacity signal for TPU serving
     ``device.instrument``) plus ``jax.monitoring`` duration hooks.
     Every compile is detected per call via the executable-cache size
     delta (``_cache_size()`` grows exactly when a new input signature
-    compiles, and is stable on a cache hit), stamped with trace / lower
-    / backend-compile wall times from the monitooring events, a
+    compiles, and is stable on a cache hit), stamped with where the
+    compiling call was entered on the wall clock (``t_call_wall``) and
+    how long it took (``call_s``) by exclusive parts — trace / lower /
+    backend-compile from the monitoring events, a nested ``jit``'s trace
+    counted once, inside its caller's; ``run_s`` the rest (the
+    executable's load, the first execution); ``cache_load_s`` the part
+    of the backend's that read the persistent cache, and ``cache_hit``
+    whether it served the program —, a
     fingerprint of the triggering signature, optional executable
     cost/memory analysis (``argument_bytes`` / ``output_bytes`` /
     ``alias_bytes``: what of the output lives in a donated argument's
@@ -40,7 +46,10 @@ Snapshots flush to control-plane KV namespace ``_device`` (keyed
 as PR-4 telemetry, and surface through ``ray-tpu device-stats``,
 ``GET /api/device/stats``, Prometheus series (``ray_tpu_compile_seconds``,
 ``ray_tpu_recompiles_total``, ``ray_tpu_hbm_live_bytes``,
-``ray_tpu_kv_pages{state=…}``) and Chrome-trace compile slices.
+``ray_tpu_kv_pages{state=…}``) and Chrome-trace compile slices.  The
+snapshot also carries how the process came up (``boot``) and when it
+stood still (``stalls``; both kept in ``_private/common``), drawn on the
+same worker row in wall order.
 """
 
 from __future__ import annotations
@@ -61,12 +70,20 @@ from ..util import metrics as metrics_mod
 DEVICE_NS = "_device"
 DEVICE_KEY_PREFIX = "device:"
 
-#: jax.monitoring duration events -> ledger duration keys (jax 0.4.x)
+#: jax.monitoring duration events -> the parts of a compiling call.  jax
+#: announces each (a scalar event, on entry) and times it (a duration
+#: event, on exit); `jaxpr_trace_duration` NESTS — every `jit` and every
+#: jitted `jnp` function traced inside a program fires its own, inside its
+#: caller's — so a part counts only where no other part is open around it
 _DURATION_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
     "/jax/core/compile/backend_compile_duration": "backend_s",
 }
+#: fired inside `backend_compile_duration` where the persistent cache
+#: served the executable: the read + deserialise
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_PARTS = tuple(_DURATION_EVENTS.values())
 
 _COMPILE_BOUNDARIES = [0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1, 2.5,
                        5, 10, 30, 60, 120, 300]
@@ -269,45 +286,90 @@ def _frame_stack() -> List[Dict[str, Any]]:
     return st
 
 
+def _new_frame() -> Dict[str, Any]:
+    """What the listeners note of one instrumented call: the parts'
+    seconds, how many parts are open (entered, not yet timed), and the
+    persistent cache's answers."""
+    return {"durations": {}, "open": 0, "hits": 0, "misses": 0}
+
+
+def _on_scalar(event: str, value: float, **kw) -> None:
+    """jax entered a part (`log_elapsed_time.__enter__`)."""
+    if event in _DURATION_EVENTS:
+        st = _frame_stack()
+        if st:
+            st[-1]["open"] += 1
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    """jax timed a part, or a read of the persistent cache.  A part that
+    ends inside another (a nested `jit`'s trace inside its caller's) is
+    its caller's time already and is dropped: the parts of a record are
+    exclusive and sum to no more than the call."""
+    st = _frame_stack()
+    frame = st[-1] if st else None
+    if event == _CACHE_LOAD_EVENT:
+        key = "cache_load_s"
+        led = get_ledger()         # the process's sum: every program's
+        with led._lock:
+            led._persistent_cache["load_s"] += float(duration)
+    else:
+        key = _DURATION_EVENTS.get(event)
+        if key is None:
+            return
+        if frame is not None:
+            frame["open"] = max(0, frame["open"] - 1)
+            if frame["open"]:
+                return
+    if frame is not None:
+        d = frame["durations"]
+        d[key] = d.get(key, 0.0) + float(duration)
+
+
+def _on_event(event: str, **kw) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is None:
+        return
+    st = _frame_stack()
+    if st:
+        st[-1][key] += 1
+    led = get_ledger()
+    with led._lock:
+        led._persistent_cache[key] += 1
+
+
 def _install_monitoring() -> None:
-    """Attach duration listeners once per process.  The listener fires
-    *during* the instrumented call while the compile happens, so the
-    durations attach to the innermost open call frame."""
+    """Attach the listeners once per process.  They fire *during* the
+    instrumented call, on the calling thread, while the compile happens,
+    so what they hear attaches to the innermost open call frame."""
     global _monitoring_installed
     with _monitoring_lock:
         if _monitoring_installed:
             return
+        _monitoring_installed = True
         try:
             from jax import monitoring
 
-            def on_duration(event: str, duration: float, **kw) -> None:
-                key = _DURATION_EVENTS.get(event)
-                if key is None:
-                    return
-                st = _frame_stack()
-                if st:
-                    d = st[-1]["durations"]
-                    d[key] = d.get(key, 0.0) + float(duration)
-
-            def on_event(event: str, **kw) -> None:
-                key = _CACHE_EVENTS.get(event)
-                if key is not None:
-                    led = get_ledger()
-                    with led._lock:
-                        led._persistent_cache[key] += 1
-
-            monitoring.register_event_duration_secs_listener(on_duration)
-            monitoring.register_event_listener(on_event)
-            _monitoring_installed = True
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+            # (a jax that announces no part: every duration counts, a
+            # nested trace twice, as before PR 58)
+            monitoring.register_scalar_listener(_on_scalar)
         except Exception:
             # jax absent or too old: cache-size deltas still detect
             # compiles, records just carry no phase durations
-            _monitoring_installed = True
+            pass
 
 
 # ---------------------------------------------------------------------------
 # The ledger
 # ---------------------------------------------------------------------------
+
+
+def _no_cache_traffic() -> Dict[str, float]:
+    """The persistent cache's counts of a process: entries found and
+    written, and the seconds spent loading the found ones."""
+    return {"hits": 0, "misses": 0, "load_s": 0.0}
 
 
 class _ProgramState:
@@ -317,7 +379,7 @@ class _ProgramState:
     __slots__ = ("name", "compiles", "recompiles", "last_signature",
                  "last_cause", "last_compile_wall", "last_compile_mono",
                  "compile_times", "storm_open", "storm_episodes",
-                 "durations_total")
+                 "durations_total", "t_first_call_wall")
 
     def __init__(self, name: str):
         self.name = name
@@ -331,6 +393,7 @@ class _ProgramState:
         self.storm_open = False
         self.storm_episodes = 0
         self.durations_total: Dict[str, float] = {}
+        self.t_first_call_wall = 0.0   # its first compiling call entered
 
 
 class CompilationLedger:
@@ -369,7 +432,7 @@ class CompilationLedger:
         self._advisories: List[Dict[str, Any]] = []  # guarded-by: _lock
         self._total_compiles = 0   # guarded-by: _lock
         self._total_recompiles = 0  # guarded-by: _lock
-        self._persistent_cache = {"hits": 0, "misses": 0}  # guarded-by: _lock
+        self._persistent_cache = _no_cache_traffic()  # guarded-by: _lock
         self._drain_idx = 0  # guarded-by: _lock
         self._last_flush = 0.0  # rate limiter state (monotonic)
 
@@ -402,21 +465,32 @@ class CompilationLedger:
 
     def _record_compile(self, prog: "InstrumentedProgram", args: Tuple,
                         kwargs: Dict[str, Any], call_s: float,
-                        durations: Dict[str, float]) -> None:
-        """One detected compile.  Never raises (observability must not
-        take down the workload)."""
+                        frame: Dict[str, Any], t_call_wall: float) -> None:
+        """One detected compile: the call took `call_s` from
+        `t_call_wall`, and `frame` is what the listeners heard inside it.
+        Never raises (observability must not take down the workload)."""
         try:
             self._record_compile_inner(prog, args, kwargs, call_s,
-                                       durations)
+                                       frame, t_call_wall)
         except Exception:
             pass
 
     def _record_compile_inner(self, prog: "InstrumentedProgram",
                               args: Tuple, kwargs: Dict[str, Any],
-                              call_s: float,
-                              durations: Dict[str, float]) -> None:
+                              call_s: float, frame: Dict[str, Any],
+                              t_call_wall: float) -> None:
         signature = _fingerprint(prog._sig, args, kwargs)
+        heard = frame["durations"]
+        durations = {k: heard[k] for k in _PARTS if k in heard}
         compile_s = sum(durations.values()) if durations else call_s
+        # what of the call no part covers: the executable's load and the
+        # first execution's dispatch (and jax's own Python between parts)
+        run_s = max(0.0, call_s - compile_s) if durations else 0.0
+        cache_load_s = heard.get("cache_load_s", 0.0)
+        # served by the persistent cache / compiled and written to it /
+        # neither (no cache, or an entry under jax's size and time floors)
+        cache_hit = (True if frame["hits"] and not frame["misses"]
+                     else False if frame["misses"] else None)
         analysis = None
         if prog._analysis if prog._analysis is not None else self.analysis:
             analysis = _analyze_executable(prog._fn, args, kwargs)
@@ -450,16 +524,27 @@ class CompilationLedger:
             st.last_cause = cause
             st.last_compile_wall = now_wall
             st.last_compile_mono = now_mono
-            for k, v in durations.items():
+            if not st.t_first_call_wall:
+                st.t_first_call_wall = t_call_wall
+            for k, v in (*durations.items(), ("call_s", call_s),
+                         ("cache_load_s", cache_load_s), ("run_s", run_s)):
                 st.durations_total[k] = st.durations_total.get(k, 0.0) + v
             rec = {
                 "program": prog.name,
                 "ts": now_wall,
+                # the call: entered at `t_call_wall`, `call_s` long, of
+                # which `durations` are jax's parts (exclusive), `run_s`
+                # the rest, and `cache_load_s` the part of `backend_s`
+                # that read the persistent cache
+                "t_call_wall": t_call_wall,
                 "nth_compile": st.compiles,
                 "call_s": round(call_s, 6),
                 "compile_s": round(compile_s, 6),
                 "durations": {k: round(v, 6)
                               for k, v in durations.items()},
+                "run_s": round(run_s, 6),
+                "cache_hit": cache_hit,
+                "cache_load_s": round(cache_load_s, 6),
                 "signature": signature,
                 "cause": cause,
             }
@@ -573,9 +658,14 @@ class CompilationLedger:
                     "last_cause": st.last_cause,
                     "storm_open": st.storm_open,
                     "storm_episodes": st.storm_episodes,
+                    # sums over its compiles — and, a stamp among them,
+                    # when its first compiling call was entered (this is
+                    # the one dict of a program the benchmark's drivers
+                    # print)
                     "durations_total_s": {
-                        k: round(v, 6)
-                        for k, v in st.durations_total.items()},
+                        **{k: round(v, 6)
+                           for k, v in st.durations_total.items()},
+                        "t_first_call_wall": st.t_first_call_wall},
                 }
             return {
                 "total_compiles": self._total_compiles,
@@ -597,7 +687,7 @@ class CompilationLedger:
             self._drain_idx = 0
             self._total_compiles = 0
             self._total_recompiles = 0
-            self._persistent_cache = {"hits": 0, "misses": 0}
+            self._persistent_cache = _no_cache_traffic()
 
 
 def _analyze_executable(jitted: Any, args: Tuple,
@@ -680,13 +770,14 @@ class InstrumentedProgram:
             before = self._fn._cache_size()
         except Exception:
             before = None
-        frame = {"durations": {}}
+        frame = _new_frame()
         stack = _frame_stack()
         stack.append(frame)
         cold = before == 0
         if cold:
             with self._cold_lock:
                 self._cold_calls += 1
+        t_wall = time.time()
         t0 = time.perf_counter()
         try:
             out = self._fn(*args, **kwargs)
@@ -703,8 +794,7 @@ class InstrumentedProgram:
                 compiled_new = False
             if compiled_new:
                 led._record_compile(self, args, kwargs,
-                                    time.perf_counter() - t0,
-                                    frame["durations"])
+                                    time.perf_counter() - t0, frame, t_wall)
         return out
 
     @property
@@ -932,12 +1022,18 @@ def backend_identity() -> Dict[str, Any]:
 
 
 def device_snapshot() -> Dict[str, Any]:
-    """The local process's full device-observability snapshot."""
+    """The local process's full device-observability snapshot — with how
+    the process came up (`boot`) and when it stood still (`stalls`), so
+    that both ship wherever the ledger ships."""
+    from ray_tpu._private import common
+
     return {
         "ts": time.time(),
         **backend_identity(),
         "ledger": get_ledger().snapshot(),
         "memory": get_census().census(),
+        "boot": dict(common.BOOT),
+        "stalls": common.stalls(),
     }
 
 
@@ -1039,39 +1135,61 @@ def collect_device_stats(control_client) -> Dict[str, Any]:
     }
 
 
+#: a process's boot parts in wall order (`_private/common.BOOT`)
+_BOOT_PARTS = ("cluster_start", "start", "connect", "register", "pool",
+               "actor_wait", "actor_init")
+
+
+def _slice(name: str, cat: str, pid: int, tid: int, t_wall: float,
+           dur_s: float, args: Dict[str, Any]) -> Dict[str, Any]:
+    return {"name": name, "ph": "X", "pid": pid, "tid": tid, "cat": cat,
+            "ts": t_wall * 1e6, "dur": max(1.0, dur_s * 1e6), "args": args}
+
+
 def compile_trace_events(workers: Dict[str, Dict[str, Any]],
                          pid: int = 90) -> List[Dict[str, Any]]:
-    """Chrome-trace complete slices for every recorded compile (one
-    thread row per worker; ``timeline.chrome_trace`` appends these)."""
+    """Chrome-trace complete slices, one thread row per worker, on the
+    wall clock: the process's boot parts, every recorded compile — the
+    whole compiling call from where it was entered, its parts as args —
+    and the stalls its tickers noted (``timeline.chrome_trace`` appends
+    these)."""
     events: List[Dict[str, Any]] = []
     for tid, (wid, snap) in enumerate(sorted(workers.items())):
         slices: List[Dict[str, Any]] = []
+        boot = snap.get("boot") or {}
+        for part in _BOOT_PARTS:
+            if f"{part}_s" in boot:
+                slices.append(_slice(
+                    f"boot {part}", "boot", pid, tid,
+                    boot.get(f"{part}_wall", 0.0), boot[f"{part}_s"],
+                    {"seconds": boot[f"{part}_s"]}))
         for rec in (snap.get("ledger") or {}).get("records", []):
-            dur_s = rec.get("compile_s") or rec.get("call_s") or 0.0
-            ev = {
-                "name": f"compile {rec.get('program')}",
-                "ph": "X", "pid": pid, "tid": tid,
-                "ts": rec.get("ts", 0.0) * 1e6 - dur_s * 1e6,
-                "dur": max(1.0, dur_s * 1e6),
-                "cat": "compile",
-                "args": {
-                    "program": rec.get("program"),
-                    "nth_compile": rec.get("nth_compile"),
-                    "durations": rec.get("durations"),
-                },
-            }
+            dur_s = rec.get("call_s") or rec.get("compile_s") or 0.0
+            ev = _slice(
+                f"compile {rec.get('program')}", "compile", pid, tid,
+                rec.get("t_call_wall", rec.get("ts", 0.0) - dur_s), dur_s,
+                {"program": rec.get("program"),
+                 "nth_compile": rec.get("nth_compile"),
+                 "durations": rec.get("durations"),
+                 "run_s": rec.get("run_s"),
+                 "cache_hit": rec.get("cache_hit"),
+                 "cache_load_s": rec.get("cache_load_s")})
             cause = rec.get("cause")
             if cause and cause.get("arg") is not None:
                 ev["args"]["cause"] = (f"{cause['arg']}: {cause['kind']} "
                                        f"{cause['old']} -> {cause['new']}")
             slices.append(ev)
+        for st in snap.get("stalls") or []:
+            slices.append(_slice(
+                "stood still", "stall", pid, tid, st.get("t_wall", 0.0),
+                st.get("late_s", 0.0), {"by": st.get("by")}))
         if slices:
-            # meta rows only for workers that actually compiled, so an
-            # empty (e.g. trial-filtered) timeline stays truly empty
+            # meta rows only for workers that have something to draw, so
+            # an empty (e.g. trial-filtered) timeline stays truly empty
             events.append({
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
                 "args": {"name": f"xla-compile {wid[:12]}"}})
-            events.extend(slices)
+            events.extend(sorted(slices, key=lambda e: e["ts"]))
     if events:
         events.insert(0, {"name": "process_name", "ph": "M", "pid": pid,
                           "args": {"name": "xla compiles"}})

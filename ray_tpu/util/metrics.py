@@ -308,6 +308,9 @@ class Metric:
         self._default_tags: Dict[str, str] = {}
         self._lock = threading.Lock()
         self._series: Dict[str, object] = {}  # json(tags) -> value
+        # sorted tag items -> the series key they serialise to: a caller
+        # that observes under the same few tags pays a lookup, not a dump
+        self._keys: Dict[tuple, str] = {}
         _registry.register(self)
 
     def deregister(self):
@@ -316,15 +319,27 @@ class Metric:
 
     def set_default_tags(self, default_tags: Dict[str, str]):
         self._default_tags = dict(default_tags)
+        self._keys = {}
         return self
 
     def _key(self, tags: Optional[Dict[str, str]]) -> str:
+        seen = self._keys
+        try:
+            ident = tuple(sorted(tags.items())) if tags else ()
+            return seen[ident]
+        except KeyError:
+            pass
+        except TypeError:        # an unhashable or unorderable tag value
+            ident = None
         merged = {**self._default_tags, **(tags or {})}
         extra = set(merged) - set(self._tag_keys)
         if extra:
             raise ValueError(f"tags {extra} not in tag_keys "
                              f"{self._tag_keys} of metric {self._name}")
-        return json.dumps(merged, sort_keys=True)
+        key = json.dumps(merged, sort_keys=True)
+        if ident is not None:
+            seen[ident] = key
+        return key
 
     @property
     def info(self) -> Dict:

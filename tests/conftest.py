@@ -63,7 +63,8 @@ def _collect_cycles_after_test(request):
 # config/runtime-env basics — for surfacing regressions before the full
 # ~20-minute run.  Files not listed get `slow`.
 _QUICK_FILES = {
-    "test_asyncio_api.py", "test_brumby.py", "test_chip_compile.py",
+    "test_asyncio_api.py", "test_boot_timeline.py", "test_brumby.py",
+    "test_chip_compile.py",
     "test_chip_ownership.py",
     "test_collective_compression.py", "test_collective_pipeline.py",
     "test_config.py", "test_control_stall.py", "test_control_stats.py",

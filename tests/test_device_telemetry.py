@@ -94,6 +94,175 @@ def test_shape_unstable_workload_records_cause_diffs():
     assert recs[0]["compile_s"] > 0
 
 
+# -- a compiling call by exclusive parts (PR 58) ------------------------------
+
+TRACE, LOWER, BACKEND = devtel._DURATION_EVENTS
+CACHE_LOAD = devtel._CACHE_LOAD_EVENT
+CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"  # unheard
+CACHE_HIT, CACHE_MISS = devtel._CACHE_EVENTS
+
+
+class ScriptedProgram:
+    """What the ledger sees of a jitted function — `_cache_size()` grows
+    when a call compiles — saying through `jax.monitoring` what jax says
+    on the calling thread while it compiles: ("enter", event) where
+    `log_elapsed_time` is entered, ("exit", event, seconds) where it
+    ends, ("event", name), ("tick", seconds) while the clock runs."""
+
+    def __init__(self, script, clock):
+        self.script, self.clock, self.size = script, clock, 0
+
+    def _cache_size(self):
+        return self.size
+
+    def __call__(self, x):
+        from jax import monitoring
+
+        for step in self.script:
+            if step[0] == "enter":
+                monitoring.record_scalar(step[1], self.clock.t)
+            elif step[0] == "exit":
+                monitoring.record_event_duration_secs(step[1], step[2])
+            elif step[0] == "event":
+                monitoring.record_event(step[1])
+            else:
+                self.clock.advance(step[1])
+        self.size += 1
+        return x
+
+
+def _scripted_record(monkeypatch, script, wall0=5000.0):
+    """The record of ONE compiling call that hears `script`, the ledger's
+    module on a clock that only the script's ticks move."""
+    import types
+
+    clock = FakeClock(wall0)
+    monkeypatch.setattr(devtel, "time", types.SimpleNamespace(
+        time=clock, perf_counter=clock, monotonic=clock))
+    led, _ = _ledger(clock=clock, wall=clock)
+    prog = led.instrument(ScriptedProgram(script, clock), name="scripted")
+    prog(1)
+    snap = led.snapshot()
+    (rec,) = snap["records"]
+    return rec, snap
+
+
+def test_a_nested_jits_trace_counts_once(monkeypatch):
+    """`jaxpr_trace_duration` nests: a jitted function traced inside a
+    program fires its own inside its caller's, and both land in the
+    calling program's frame.  The record keeps the outermost alone, so
+    its parts are exclusive and sum to no more than the call."""
+    script = [
+        ("enter", TRACE),                          # the program
+        ("tick", 0.25),
+        ("enter", TRACE),                          # a jit called inside
+        ("enter", TRACE), ("tick", 0.125), ("exit", TRACE, 0.125),  # jnp.sin
+        ("tick", 0.125),
+        ("exit", TRACE, 0.25),
+        ("tick", 0.5),
+        ("exit", TRACE, 1.0),                      # ... contains them all
+        ("enter", LOWER), ("tick", 0.5), ("exit", LOWER, 0.5),
+        ("enter", BACKEND), ("event", CACHE_MISS), ("tick", 2.0),
+        ("exit", BACKEND, 2.0),
+        ("tick", 0.25),                            # load + first execution
+    ]
+    rec, snap = _scripted_record(monkeypatch, script)
+    d = rec["durations"]
+    assert d == {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 2.0}
+    assert rec["call_s"] == 3.75 and rec["compile_s"] == 3.5
+    assert d["trace_s"] + d["lower_s"] + d["backend_s"] <= rec["call_s"]
+    assert rec["run_s"] == 0.25
+    assert rec["cache_hit"] is False and rec["cache_load_s"] == 0.0
+    # the call was entered at the wall stamp the record carries, and the
+    # record was made when it returned
+    assert rec["t_call_wall"] == 5000.0 and rec["ts"] == 5003.75
+    tot = snap["programs"]["scripted"]["durations_total_s"]
+    assert tot == {"trace_s": 1.0, "lower_s": 0.5, "backend_s": 2.0,
+                   "call_s": 3.75, "cache_load_s": 0.0, "run_s": 0.25,
+                   "t_first_call_wall": 5000.0}
+
+
+def test_a_call_the_persistent_cache_served_says_so(monkeypatch):
+    """What jax says where `compile_or_get_cached` finds the executable:
+    a hit and the seconds the read took, inside
+    `backend_compile_duration` (the compile seconds it saved are jax's
+    to say: no reader here, so the ledger keeps none)."""
+    script = [
+        ("enter", TRACE), ("tick", 0.5), ("exit", TRACE, 0.5),
+        ("enter", LOWER), ("tick", 0.25), ("exit", LOWER, 0.25),
+        ("enter", BACKEND), ("event", CACHE_HIT), ("tick", 0.75),
+        ("exit", CACHE_SAVED, 9.25), ("exit", CACHE_LOAD, 0.75),
+        ("tick", 0.25), ("exit", BACKEND, 1.0),
+        ("tick", 0.5),
+    ]
+    rec, snap = _scripted_record(monkeypatch, script)
+    assert rec["cache_hit"] is True
+    assert 0 < rec["cache_load_s"] == 0.75 <= rec["durations"]["backend_s"]
+    assert "cache_saved_s" not in rec
+    assert rec["run_s"] == 0.5 and rec["call_s"] == 2.25
+    assert snap["programs"]["scripted"]["durations_total_s"][
+        "cache_load_s"] == 0.75
+    # the process's own count hears it too (the global ledger's)
+    assert devtel.get_ledger().snapshot()["persistent_cache"] == {
+        "hits": 1, "misses": 0, "load_s": 0.75}
+
+
+def test_a_real_nested_program_keeps_its_parts_inside_the_call():
+    """The same on jax's own events: a program that calls a jitted
+    function that calls jitted `jnp` functions."""
+    led, _ = _ledger()
+    inner = jax.jit(lambda x: jnp.sin(x) * 2.0)
+
+    def outer(x):
+        return inner(inner(x)).sum() + jnp.cos(x).sum()
+
+    before = time.time()
+    led.jit(outer, name="nested.outer")(jnp.ones((4, 4), jnp.float32))
+    (rec,) = led.snapshot()["records"]
+    d = rec["durations"]
+    assert set(d) == {"trace_s", "lower_s", "backend_s"}
+    assert sum(d.values()) <= rec["call_s"]
+    assert rec["run_s"] == pytest.approx(rec["call_s"] - sum(d.values()),
+                                         abs=2e-6)
+    assert rec["cache_load_s"] <= d["backend_s"]
+    assert before <= rec["t_call_wall"] <= rec["ts"]
+    assert rec["t_call_wall"] + rec["call_s"] <= rec["ts"] + 0.05
+
+
+def test_a_warm_persistent_cache_is_read_per_program(tmp_path):
+    """A fresh function of the same text, with the first one's executable
+    in a persistent cache directory: where this backend's cache serves it,
+    the record says `cache_hit` and how long the read took."""
+    from jax._src import compilation_cache as cc
+
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs")}
+    led, _ = _ledger()
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        cc.reset_cache()
+        x = jnp.ones((3, 5), jnp.float32)
+        for name in ("pc.first", "pc.second"):
+            led.jit(lambda x: jnp.tanh(x) * 3.0 + 1.0, name=name)(x)
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    first, second = led.snapshot()["records"]
+    assert first["cache_hit"] is False          # compiled, and written
+    if second["cache_hit"] is None:
+        pytest.skip("this backend's persistent cache served nothing")
+    assert second["cache_hit"] is True
+    assert 0 < second["cache_load_s"] <= second["durations"]["backend_s"]
+    assert first["cache_load_s"] == 0.0
+    pc = devtel.get_ledger().snapshot()["persistent_cache"]
+    assert pc["hits"] >= 1 and pc["misses"] >= 1 and pc["load_s"] > 0
+
+
 def test_cause_diff_dtype_static_and_pytree():
     led, _ = _ledger()
 
@@ -270,6 +439,9 @@ def test_engine_stall_probe_exempts_first_compile():
     try:
         eng._slots[0] = object()          # an active slot, no step yet
         eng._fns["step"] = Prog()
+        # (past its bring-up — until an engine's first launch nothing is
+        # a stall, PR 59: `tests/test_control_stall.py`)
+        eng._first_launch_wall["serve.prefill:8"] = time.time()
         assert eng.check_health()
         time.sleep(0.12)
         assert eng.check_health()         # compiling: the clock restarts
@@ -298,7 +470,7 @@ def test_snapshot_names_the_device_without_opening_one(monkeypatch):
     snap = devtel.device_snapshot()
     assert snap["platform"] == "cpu" and snap["device_kind"] == "cpu"
     assert snap["device_count"] == jax.device_count()
-    assert set(snap["ledger"]["persistent_cache"]) == {"hits", "misses"}
+    assert set(snap["ledger"]["persistent_cache"]) >= {"hits", "misses"}
 
     monkeypatch.setattr(devtel, "backend_initialized", lambda: False)
     monkeypatch.setattr(jax, "devices", lambda *a: pytest.fail("opened"))
@@ -447,6 +619,45 @@ def test_compile_trace_events():
     from ray_tpu.telemetry import validate_chrome_trace
 
     assert validate_chrome_trace({"traceEvents": events})
+    # a slice is the compiling CALL, from where it was entered
+    recs = workers["w1"]["ledger"]["records"]
+    assert [e["ts"] for e in slices] == [r["t_call_wall"] * 1e6
+                                         for r in recs]
+    assert [e["dur"] for e in slices] == [r["call_s"] * 1e6 for r in recs]
+    assert all(set(e["args"]["durations"]) <= {"trace_s", "lower_s",
+                                               "backend_s"}
+               and "run_s" in e["args"] and "cache_hit" in e["args"]
+               for e in slices)
+
+
+def test_trace_events_draw_boot_compiles_and_stalls_in_wall_order():
+    """One worker row: the boot's parts, then its compiles, a stall where
+    it fell — by their wall stamps, whatever order the snapshot lists."""
+    rec = {"program": "p", "ts": 112.0, "t_call_wall": 110.0,
+           "call_s": 2.0, "compile_s": 1.5, "nth_compile": 1,
+           "durations": {"trace_s": 1.0, "backend_s": 0.5}, "run_s": 0.5,
+           "cache_hit": True, "cache_load_s": 0.25, "cause": None}
+    boot = {"start_wall": 100.0, "start_s": 0.5, "connect_wall": 100.5,
+            "connect_s": 2.5, "register_wall": 103.0, "register_s": 0.0,
+            "pool_wall": 103.0, "pool_s": 0.0,
+            "actor_wait_wall": 103.0, "actor_wait_s": 1.0,
+            "actor_init_wall": 104.0, "actor_init_s": 5.0,
+            "until_wall": 109.0}
+    stalls = [{"t_wall": 105.0, "late_s": 3.5, "by": "worker-main"}]
+    events = devtel.compile_trace_events(
+        {"w1": {"ledger": {"records": [rec]}, "boot": boot,
+                "stalls": stalls},
+         "w0": {"ledger": {"records": []}, "boot": {}, "stalls": []}})
+    slices = [e for e in events if e.get("ph") == "X"]
+    assert {e["tid"] for e in slices} == {1}       # w0 has nothing to draw
+    assert [e["name"] for e in slices] == [
+        "boot start", "boot connect", "boot register", "boot pool",
+        "boot actor_wait", "boot actor_init", "stood still", "compile p"]
+    assert [e["ts"] for e in slices] == sorted(e["ts"] for e in slices)
+    init, stall = slices[5], slices[6]
+    assert (init["ts"], init["dur"]) == (104.0e6, 5.0e6)
+    assert (stall["ts"], stall["dur"]) == (105.0e6, 3.5e6)
+    assert stall["args"] == {"by": "worker-main"}
 
 
 # ---------------------------------------------------------------------------
@@ -493,6 +704,7 @@ def test_device_flush_collect_cli_and_http(cluster, capsys):
     assert merged["live_bytes"] >= 0
     (wid, wsnap), = merged["workers"].items()
     assert wsnap["memory"]["live"]["count"] >= 1
+    assert wsnap["boot"]["cluster_start_s"] > 0 and "stalls" in wsnap
 
     # state API mirrors the merge
     via_api = state.device_stats()
@@ -532,3 +744,38 @@ def test_device_flush_collect_cli_and_http(cluster, capsys):
     args.fn(args)
     out = capsys.readouterr().out
     assert json.loads(out)["total_recompiles"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# what an observation costs: a series' key is serialised once (PR 58)
+# ---------------------------------------------------------------------------
+
+
+def test_a_series_key_is_serialised_once_per_tags(monkeypatch):
+    from ray_tpu.util import metrics as mm
+
+    h = mm.Histogram("test_key_cache_hist", boundaries=[1.0],
+                     tag_keys=("phase", "node"))
+    dumps = []
+    real = mm.json.dumps
+    monkeypatch.setattr(mm.json, "dumps",
+                        lambda *a, **k: dumps.append(a) or real(*a, **k))
+    try:
+        for _ in range(5):
+            h.observe(0.5, tags={"phase": "decode"})
+            h.observe(0.5, tags={"phase": "swap"})
+        assert len(dumps) == 2
+        assert set(h._snapshot()["series"]) == {
+            '{"phase": "decode"}', '{"phase": "swap"}'}
+        assert h._snapshot()["series"]['{"phase": "decode"}'][2] == 5
+        # default tags are part of a key: changing them forgets the keys
+        h.set_default_tags({"node": "n1"})
+        h.observe(0.5, tags={"phase": "decode"})
+        assert '{"node": "n1", "phase": "decode"}' in h._snapshot()["series"]
+        assert len(dumps) == 3
+        # a tag the metric does not declare is refused every time
+        for _ in range(2):
+            with pytest.raises(ValueError, match="not in tag_keys"):
+                h.observe(0.5, tags={"nope": "x"})
+    finally:
+        h.deregister()
