@@ -27,18 +27,27 @@ block of C rows and E_tj = exp(G_t - G_j) (j <= t, per channel):
 
     A_tj = b_t sum_c k_t[c] k_j[c] E_tj[c]   (j <  t)
     B_tj =     sum_c q_t[c] k_j[c] E_tj[c]   (j <= t)
-    U    = (I + A)^-1 b (V - (K exp G) S_0)
+    U    = W - Kbar S_0,  (W, Kbar) = (I + A)^-1 b (V, K exp G)
     O    = (Q exp G) S_0 + B U
     S_C  = Diag(exp G_C) S_0 + (K exp(G_C - G))^T U
 
-The exponent G_t - G_j is formed as a DIFFERENCE before the exponential:
-at the gate's lower bound (log a = -5) a block of 64 rows reaches -320,
-and the two factors exp(G_t), exp(-G_j) formed apart leave float32.
-(I + A)^-1 is the product (I - A)(I + A^2)(I + A^4).. — A is strictly
-lower triangular, so the series ends at A^C.  Everything is float32 and
-the products take float32 operands at the highest precision: a layer's
-chunk is 2 GFLOP, and bf16 operands would round the carried state at
-every block's read.
+exp(G_t) and exp(-G_j) formed apart leave float32 (log a = -5 over 64
+rows reaches -320), so a block is cut in SUB-BLOCKS of 16 rows.  Inside
+one, E is the exponential of the DIFFERENCE (15 rows reach -75).  For t in
+sub-block i and j in an earlier one, with R_i = G at the last row before
+sub-block i, E_tj = exp(G_t - R_i) exp(R_i - G_j): G only falls, so both
+exponents are <= 0 (the first reaches 16 x -5 = -80, short of float32's
+-87): both factors are <= 1 and neither is smaller than E_tj — a factor
+leaves float32 only where E_tj does — and A, B below the diagonal
+sub-blocks are MATRIX PRODUCTS of rows scaled by the one and keys scaled
+by the other.  (A block that sub-blocks do not tile, a short chunk's, is
+one diagonal piece: the difference holds at any length.)  (I + A)^-1 is
+the product (I - A)(I + A^2)(I + A^4).. — A is strictly lower triangular,
+so the series ends at A^C.  A, B, the inverse, W and Kbar read no state:
+they are formed for all of a chunk's blocks at once (`_state_free`), and
+the scan over the blocks keeps the three products that do.  Everything is
+float32 and the products take float32 operands at the highest precision:
+bf16 operands would round the carried state at every block's read.
 """
 
 from __future__ import annotations
@@ -55,6 +64,7 @@ __all__ = ["kda_chunk", "kda_step", "conv_chunk", "conv_step", "BLOCK",
            "resolve_impl"]
 
 BLOCK = 64          # rows of one WY block of a chunk
+SUB_BLOCK = 16      # rows of a sub-block of it (its bound: the docstring)
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -72,27 +82,17 @@ def _inverse_unit_lower(a):
 
 
 def _block(state, xs):
-    """One WY block of every head: state [H, dv, dk]; q, k [H, C, dk],
-    v [H, C, dv], log_a [H, C, dk], beta [H, C] -> (state, o [H, C, dv])."""
-    q, k, v, log_a, beta = xs
-    C = q.shape[1]
+    """One WY block's turn of the scan — the three products that read the
+    state, their operands scaled on the way in: state [H, dv, dk];
+    `_state_free`'s wk [H, C, dv + dk] (W beside Kbar), b [H, C, C], and
+    the block's q, k, g [H, C, dk] -> (state, o [H, C, dv])."""
+    wk, b, q, k, g = xs
     es = functools.partial(jnp.einsum, precision=_HI)
-    g = jnp.cumsum(log_a, axis=1)
-    t = jnp.arange(C)
-    see = (t[:, None] >= t[None, :])[None, :, :, None]
-    e = jnp.exp(jnp.where(see, g[:, :, None, :] - g[:, None, :, :], -jnp.inf))
-    ke = k[:, None, :, :] * e                                   # [H, t, j, dk]
-    a = (beta[:, :, None] * (k[:, :, None, :] * ke).sum(-1)
-         * (t[:, None] > t[None, :]))
-    b = (q[:, :, None, :] * ke).sum(-1)
-    into = jnp.exp(g)
-    u = jnp.matmul(_inverse_unit_lower(a), beta[..., None] * (
-        v - es("htc,hvc->htv", k * into, state)), precision=_HI)
-    o = es("htc,hvc->htv", q * into, state) + jnp.matmul(b, u, precision=_HI)
-    out_of = jnp.exp(g[:, -1:, :] - g)
-    state = (state * jnp.exp(g[:, -1])[:, None, :]
-             + es("htv,htc->hvc", u, k * out_of))
-    return state, o
+    dv, last = state.shape[1], g[:, -1:]
+    u = wk[..., :dv] - es("htc,hvc->htv", wk[..., dv:], state)
+    o = es("htc,hvc->htv", q * jnp.exp(g), state) + es("htj,hjv->htv", b, u)
+    return state * jnp.exp(last) + es(
+        "htv,htc->hvc", u, k * jnp.exp(last - g)), o
 
 
 @functools.partial(jax.jit, static_argnames="block")
@@ -115,8 +115,8 @@ def kda_chunk(q, k, v, log_a, beta, state, block: int = BLOCK):
         return jnp.moveaxis(x, 1, 0)
 
     with jax.named_scope("kda_chunk"):
-        state, o = jax.lax.scan(
-            _block, f32(state), tuple(map(blocks, (q, k, v, log_a, beta))))
+        state, o = jax.lax.scan(_block, f32(state), _state_free(
+            *map(blocks, (q, k, v, log_a, beta))))
         o = jnp.moveaxis(o, 0, 1).reshape(H, T + pad, -1)
         return o[:, :T], state
 
@@ -232,6 +232,46 @@ def kda_step(q, k, v, log_a, beta, state, layer, idx, live,
                              "width: dk must equal dv")
         return _step_pallas(rows, state, layer, idx, live,
                             impl == "pallas_interpret")
+
+
+def _state_free(q, k, v, log_a, beta):
+    """The half of a chunk that reads no state, for all of its WY blocks at
+    once (the module docstring's A, B, W, Kbar): q, k, log_a [.., C, dk],
+    v [.., C, dv], beta [.., C] -> `_block`'s (wk, b, q, k, g).  It stands
+    below the lines `kda_step`'s kernel is serialised with (ROADMAP S11)."""
+    C = q.shape[-2]
+    s = SUB_BLOCK if C % SUB_BLOCK == 0 else C
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    g = jnp.cumsum(log_a, axis=-2)
+    bk = beta[..., None] * k
+    # the diagonal sub-blocks, by the difference: [.., i, t, j, dk] summed
+    # over dk for A's rows and for B's (two sums: the compiler forms the
+    # exponentials in each and lays them nowhere)
+    subs = lambda a: a.reshape(a.shape[:-2] + (C // s, s, a.shape[-1]))
+    gs, t = subs(g), jnp.arange(s)
+    ke = subs(k)[..., None, :, :] * jnp.exp(jnp.where(
+        (t[:, None] >= t[None, :])[..., None],
+        gs[..., :, None, :] - gs[..., None, :, :], -jnp.inf))
+    diag = jnp.stack([(subs(y)[..., :, None, :] * ke).sum(-1)
+                      for y in (bk, q)], axis=-4)          # [.., 2, i, t, j]
+    # below them, sub-block i's rows (A's over B's) times the keys before
+    # it, each side scaled to R_i
+    x, rows = jnp.stack([bk, q], axis=-3), []
+    for i in range(C // s):
+        lo, piece = i * s, diag[..., i, :, :]
+        if i:
+            r = g[..., lo - 1:lo, :]
+            later = x[..., lo:lo + s, :] * jnp.exp(
+                g[..., lo:lo + s, :] - r)[..., None, :, :]
+            earlier = k[..., :lo, :] * jnp.exp(r - g[..., :lo, :])
+            piece = jnp.concatenate([mm(later, jnp.swapaxes(
+                earlier, -1, -2)[..., None, :, :]), piece], axis=-1)
+        rows.append(jnp.pad(
+            piece, ((0, 0),) * (x.ndim - 1) + ((0, C - lo - s),)))
+    ab = jnp.concatenate(rows, axis=-2)                    # [.., 2, C, C]
+    wk = mm(_inverse_unit_lower(jnp.tril(ab[..., 0, :, :], -1)), beta[
+        ..., None] * jnp.concatenate([v, k * jnp.exp(g)], axis=-1))
+    return wk, ab[..., 1, :, :], q, k, g
 
 
 # ---------------------------------------------------------------------------

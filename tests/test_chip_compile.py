@@ -909,6 +909,17 @@ def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     else:                   # the chunk: the latent layer's block kernel
         assert (_latent_kernels(compiled), _streamed_kernels(compiled)) == (
             0, 1)
+        # no WY block's square of exponentials, alone or batched over the
+        # chunk's blocks (PR 60: sub-blocks of 16 rows), no copy of a
+        # layer's whole part of the state arena to read one entry of it,
+        # and fewer temporaries than the program had with them
+        squares = sorted(set(re.findall(r"f32\[(?:\d+,)*64,64,128\]", text)))
+        assert not squares, squares
+        parts = [ln.strip()[:120] for ln in text.splitlines()
+                 if re.search(r"= f32\[1,65,32,128,128\]\{[^}]*\} "
+                              r"(slice|copy)\(", ln)]
+        assert not parts, parts
+        assert m.temp_size_in_bytes <= 234_823_680, m.temp_size_in_bytes
     print(key, "total", total, "temp", m.temp_size_in_bytes)
 
 

@@ -1,7 +1,8 @@
 """ops/kda.py against the recurrence written token by token: the chunked WY
-form over several chunks, the step in both of its bodies (with an empty
-slot in the batch), every gate at its lower bound for a whole chunk, and
-the short convolution's tail across chunk boundaries."""
+form over several chunks (blocks of four sub-blocks, of one, and padded),
+the step in both of its bodies (with an empty slot in the batch), every
+gate at its lower bound for a whole chunk, and the short convolution's
+tail across chunk boundaries."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -13,15 +14,17 @@ H, DK, DV = 3, 16, 16
 LOWER = -5.0
 
 
-def _inputs(T, seed=0, gate=None):
+def _inputs(T, seed=0, gate=None, heads=H, d=DK):
+    """`gate`: None (uniform down to the lower bound), a number, or a row
+    of `d` numbers, a channel each."""
     r = np.random.default_rng(seed)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(r.standard_normal((H, T, DK))) * DK ** -0.5
-    k = unit(r.standard_normal((H, T, DK)))
-    v = r.standard_normal((H, T, DV))
-    log_a = (LOWER * r.uniform(0.0, 1.0, (H, T, DK)) if gate is None
-             else np.full((H, T, DK), gate))
-    beta = r.uniform(0.05, 0.95, (H, T))
+    q = unit(r.standard_normal((heads, T, d))) * d ** -0.5
+    k = unit(r.standard_normal((heads, T, d)))
+    v = r.standard_normal((heads, T, d))
+    log_a = (LOWER * r.uniform(0.0, 1.0, (heads, T, d)) if gate is None
+             else np.broadcast_to(gate, (heads, T, d)))
+    beta = r.uniform(0.05, 0.95, (heads, T))
     return tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, log_a, beta))
 
 
@@ -33,7 +36,7 @@ def _recurrence(q, k, v, log_a, beta, state):
     s = np.swapaxes(np.asarray(state, np.float64), 1, 2)       # [H, dk, dv]
     out = np.zeros(v.shape)
     for t in range(q.shape[1]):
-        for h in range(H):
+        for h in range(q.shape[0]):
             sp = np.exp(log_a[h, t])[:, None] * s[h]
             u = beta[h, t] * (v[h, t] - sp.T @ k[h, t])
             s[h] = sp + np.outer(k[h, t], u)
@@ -46,30 +49,45 @@ def _close(got, want, tol):
     assert np.abs(got - want).max() <= tol * max(1.0, np.abs(want).max())
 
 
-@pytest.mark.parametrize("T,chunk,block", [(48, 16, 8), (40, 40, 16),
-                                           (96, 32, 64)],
-                         ids=["3x16by8", "1x40by16", "3x32whole"])
-def test_chunks_carry_the_state_like_the_recurrence(T, chunk, block):
-    """Several chunks, each in WY blocks (one of the cases pads its last
-    block), against the token-by-token recurrence: float32 throughout, so
-    1e-5 of the largest value."""
-    xs = _inputs(T)
-    state = jnp.zeros((H, DV, DK), jnp.float32)
+@pytest.mark.parametrize("T,chunk,block,heads,d", [
+    (48, 16, 8, H, DK), (40, 40, 16, H, DK), (96, 32, 64, H, DK),
+    (128, 64, 64, 2, 32), (200, 100, 64, 2, 32), (40, 40, 64, H, DK),
+    (36, 12, 12, H, DK)],
+    ids=["3x16by8", "1x40by16", "3x32whole", "2x64in4subs", "2x100padded",
+         "1x40untiled", "3x12by12"])
+def test_chunks_carry_the_state_like_the_recurrence(T, chunk, block, heads,
+                                                    d):
+    """Several chunks, each in WY blocks, against the token-by-token
+    recurrence: float32 throughout, so 1e-5 of the largest value.  Blocks
+    of 64 rows in four sub-blocks (the products below the diagonal
+    sub-blocks exist from the second on), a chunk whose last block is
+    padded (two cases; one's pad fills sub-blocks whole), blocks shorter
+    than a sub-block, and a block of 40 rows, which sub-blocks do not
+    tile."""
+    assert kda.SUB_BLOCK == 16 and kda.BLOCK == 64
+    xs = _inputs(T, heads=heads, d=d)
+    state = jnp.zeros((heads, d, d), jnp.float32)
     outs = []
     for c in range(0, T, chunk):
         o, state = kda.kda_chunk(*(x[:, c:c + chunk] for x in xs), state,
                                  block=block)
         outs.append(o)
-    want, s_want = _recurrence(*xs, np.zeros((H, DV, DK)))
+    want, s_want = _recurrence(*xs, np.zeros((heads, d, d)))
     _close(jnp.concatenate(outs, 1), want, 1e-5)
     _close(state, s_want, 1e-5)
 
 
-def test_every_gate_at_the_lower_bound_for_a_whole_chunk():
+@pytest.mark.parametrize("gate", [
+    LOWER, np.where(np.arange(DK) % 2, LOWER, -0.001)], ids=["every", "mixed"])
+def test_every_gate_at_the_lower_bound_for_a_whole_chunk(gate):
     """log a = -5 in every channel of every row of a 64-row block: the
     running sum reaches -320, whose exponential and its inverse both
-    leave float32; the differences do not."""
-    xs = _inputs(128, seed=1, gate=LOWER)
+    leave float32; the differences inside a sub-block and the two factors
+    between sub-blocks do not, or only where their product does.  Mixed:
+    every other channel at -0.001, so that a product of keys holds
+    channels whose factor has gone to zero beside channels that have
+    hardly decayed."""
+    xs = _inputs(128, seed=1, gate=gate)
     start = jnp.asarray(np.random.default_rng(2).standard_normal(
         (H, DV, DK)), jnp.float32)
     o, state = kda.kda_chunk(*xs, start)

@@ -408,7 +408,9 @@ def _digest(text):
 # PR 53 pinned the six of the three MoE models anew (`ops/moe`'s trips of
 # grouped products), PR 57 `brumby`'s chunk (its retention a kernel; the
 # digest leaves a Mosaic module out, so a change INSIDE a kernel moves
-# none).  A PR that moves or renames Python functions
+# none), PR 60 `ling-3`'s chunk (`ops/kda.kda_chunk` by sub-blocks and the
+# carried entry read where it stands; its step stays).  A PR that moves or
+# renames Python functions
 # leaves every digest alone (the text carries no source locations; their
 # kernels' source lines unmoved, the compile-cache keys stay too).  A PR
 # that edits one of these programs finds the new digest in the failure and
@@ -423,7 +425,7 @@ PARENT_TEXT = {
     ("deepseek-v3", "step"): "1040750f233ad9df",
     ("deepseek-v3", "chunk"): "972fd14b1ee67cfe",
     ("ling-3", "step"): "1e1d7126f836888a",
-    ("ling-3", "chunk"): "8aaf5592fadd8980",
+    ("ling-3", "chunk"): "55ee27a885e72e12",
     ("phi-4-flash", "step"): "d0a2de26cb6e8b1b",
     ("phi-4-flash", "chunk"): "ce1a43a62b84b2a7",
 }
