@@ -11,6 +11,7 @@ from .quantize import (dequantize_blockwise, quantization_error,
                        quantize_blockwise)
 from .retention import retention_chunk, retention_step
 from .ring_attention import ring_attention, ring_attention_sharded
+from .ssd import ssd_chunk, ssd_step
 from .ulysses import ulysses_attention, ulysses_attention_sharded
 
 __all__ = [
@@ -21,7 +22,7 @@ __all__ = [
     "ring_attention", "ring_attention_sharded",
     "ulysses_attention", "ulysses_attention_sharded",
     "retention_chunk", "retention_step", "kda_chunk", "kda_step",
-    "selective_scan_chunk", "selective_step",
+    "selective_scan_chunk", "selective_step", "ssd_chunk", "ssd_step",
     "rms_norm", "layer_norm", "rope_table", "apply_rope", "apply_rope_halves",
     "apply_rope_interleaved", "yarn_frequencies", "swiglu",
     "gelu_mlp", "softmax_cross_entropy", "fused_softmax_cross_entropy",

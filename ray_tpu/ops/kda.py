@@ -58,7 +58,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .retention import _visit, resolve_impl
+from .retention import resolve_impl, visit
 
 __all__ = ["kda_chunk", "kda_step", "conv_chunk", "conv_step", "BLOCK",
            "resolve_impl"]
@@ -146,7 +146,7 @@ def _step_kernel(layer_ref, ent_ref, flag_ref, r_ref, s_ref, o_ref,
     @pl.when(flag_ref[b] <= 0)
     def _():
         # an empty slot's turn points at a live neighbour's block
-        # (retention._visit) and must leave it alone; with no live slot at
+        # (retention.visit) and must leave it alone; with no live slot at
         # all it points at the null entry, which goes back as it came
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -167,7 +167,7 @@ def _step_pallas(rows, state, layer, idx, live, interpret: bool):
 
     B, _, H, dk = rows.shape
     dv = state.shape[-2]
-    entry, _, flag = _visit(idx, live, 1)
+    entry, _, flag = visit(idx, live, 1)
     at_slot = lambda b, *_: (b, 0, 0, 0)
     at_entry = lambda b, layer, entry, flag: (layer[0], entry[b], 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(

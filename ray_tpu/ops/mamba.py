@@ -37,7 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .retention import _visit, resolve_impl
+from .retention import resolve_impl, visit
 
 __all__ = ["selective_scan_chunk", "selective_step", "resolve_impl"]
 
@@ -177,7 +177,7 @@ def _step_kernel(layer_ref, ent_ref, flag_ref, r_ref, bc_ref, a_ref, h_ref,
     @pl.when(flag_ref[s] <= 0)
     def _():
         # an empty slot's turn points at a live neighbour's block
-        # (retention._visit) and must leave it alone; with no live slot at
+        # (retention.visit) and must leave it alone; with no live slot at
         # all it points at the null entry, which goes back as it came
         y_ref[...] = jnp.zeros_like(y_ref)
 
@@ -198,7 +198,7 @@ def _step_pallas(rows, cols, a, state, layer, idx, live, interpret: bool):
 
     B, _, C = rows.shape
     N = a.shape[0]
-    entry, _, flag = _visit(idx, live, 1)
+    entry, _, flag = visit(idx, live, 1)
     at_entry = lambda s, layer, entry, flag: (layer[0], entry[s], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B,),

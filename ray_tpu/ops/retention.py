@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["phi", "state_shape", "retention_chunk", "retention_step",
-           "resolve_impl", "EPS"]
+           "resolve_impl", "visit", "EPS"]
 
 EPS = 1e-6          # the normaliser's floor (sum_j a_ij + EPS)
 _GP = 8             # query heads of a group, padded to whole sublanes
@@ -422,3 +422,9 @@ def retention_chunk(q, k, v, log_g, state, impl: Optional[str] = None,
     with jax.named_scope("retention_chunk"):
         return _chunk_pallas(q, k, v, log_g, state, dtype=dtype,
                              interpret=impl == "pallas_interpret")
+
+
+# the public name of `_visit`, which stands above the step's kernel (whose
+# lines may not move): ops/kda.py, ops/mamba.py and ops/ssd.py lay their
+# steps' grids over an arena by it, and take `resolve_impl` from here too
+visit = _visit

@@ -1112,3 +1112,98 @@ def test_dots3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
         _assert_sampler_asks_its_operands(compiled, B, V, switches=2)
     print(key, "total", total, "temp", mem.temp_size_in_bytes, "args",
           mem.argument_size_in_bytes, "weights", weights, "arena", arena)
+
+
+# -- the eighth served model at its published widths: Falcon-H1-34B's six
+# -- layers of benchmarks/configs/falcon-h1-34b-l6.json -----------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512), ("prefill", 256)],
+                         ids=["step", "prefill512", "prefill256"])
+def test_falcon_h1_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
+    """The serve programs of a model whose EVERY layer writes pages and a
+    state entry (six layers: the SSD step's kernel over the state arena
+    and a walk of the slots' own K/V pages with FIVE query rows a key
+    head; the SSD chunk's kernel and the block kernel in both prefill
+    programs; 261,120 logits a slot) from the benchmark's own
+    `engine_kwargs`, 64 slots: the chip's compiler takes them; weights +
+    pages + states + tails + temporaries stay under 15.0e9 B (the issue's
+    line: 96 slots read 15,012,200,448); the cache is donated and held
+    once, no arena moved by a copy."""
+    import json
+
+    from benchmarks.lib.falconh1cfg import model_config
+    from ray_tpu.models import falcon_h1 as fm
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "falcon-h1-34b-l6.json")) as f:
+        conf = json.load(f)
+    assert conf["reduced"] == ["num_hidden_layers"]
+    cfg = model_config(conf, ssd_impl="pallas")
+    view = _on(jax.eval_shape(
+        lambda k: fm.serve_view(fm.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one_chip)
+    eng = ContinuousEngine(fm, cfg, view, **conf["serve"]["engine_kwargs"])
+    try:
+        cache = _on(jax.eval_shape(functools.partial(
+            fm.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B, V = eng.max_slots, cfg.vocab_size
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (view, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32),
+                    {k: s((B, w), jnp.int32)
+                     for k, w in eng._widths.items()}, s((B,), jnp.int32))
+        else:
+            args = (view, cache, s((key[1],), jnp.int32),
+                    {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
+                    i32, i32)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    assert (B, eng._pool_pages, eng._widths, eng._share, eng._main,
+            eng._state_kinds, eng.queue_cap) == (
+        64, {"full": 769, "ssm": 65}, {"full": 12, "ssm": 1}, False, "full",
+        ["ssm"], 256)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(view))
+    assert [a.shape for a in cache["k"]] == [(769, 128, 512)] * 6
+    assert cache["state"].shape == (6, 65, 32, 128, 256)
+    # the conv tail as whole tiles: laid [.., 3, 5120] its three rows of
+    # an 8-row tile were re-laid by ten arena-wide copies a program
+    assert cache["tail"].shape == (6, 65, 3, 40, 128)
+    assert arena == 769 * 1572864 + 65 * 6 * (4194304 + 61440)
+    assert weights == 10509189376
+    assert all("wq" not in layer and layer["w_qkv"].shape == (5120, 3584)
+               for layer in view["layers"])
+    assert m.alias_size_in_bytes >= arena
+    assert total < 15.0e9, total
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"= f32\[6,65,(32,128,256|3,40,128)\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)
+             or re.search(r"= bf16\[769,128,512\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)]
+    assert not moved, moved
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    named = lambda n: sum(n in c.split(" = ", 1)[0] for c in calls)
+    if key == "step":       # a kernel a layer each, under its name
+        assert named("ssd_step") == 6 and named("ssd_chunk") == 0
+        assert named("paged_decode_attention") == 6
+        assert _streamed_kernels(compiled) == 0
+        _assert_sampler_asks_its_operands(compiled, B, V)
+    else:
+        assert named("ssd_chunk") == 6 and named("ssd_step") == 0
+        assert named("paged_decode_attention") == 0
+        assert _streamed_kernels(compiled) >= 1
+    print(key, "total", total, "temp", m.temp_size_in_bytes)

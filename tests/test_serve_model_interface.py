@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3, gpt,
-                            ling3, phi4flash)
+from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3,
+                            falcon_h1, gpt, ling3, phi4flash)
 from ray_tpu.serve._engine import ContinuousEngine, _check_interface
 
 MODELS = {
@@ -23,6 +23,7 @@ MODELS = {
     "ling-3": (ling3, ling3.Ling3Config.nano()),
     "dots3-note": (dots3, dots3.Dots3Config.nano()),
     "phi-4-flash": (phi4flash, phi4flash.Phi4FlashConfig.nano()),
+    "falcon-h1": (falcon_h1, falcon_h1.FalconH1Config.nano()),
 }
 REQUIRED = ("cache_kinds", "init_paged_cache", "paged_decode_step",
             "paged_prefill", "serve_view")
