@@ -173,6 +173,10 @@ def _ffn(h, layer, cfg: Cohere2MoEConfig, live=None):
     with jax.named_scope("moe_router"):
         w, idx = route_sigmoid_topk(h, layer["router"], cfg.top_k)
     with jax.named_scope("moe_experts"):
+        # gate and up APART, three products a trip (`deepseek_v3` lays them
+        # in one leaf, two products): the benchmark's check reads `wg` and
+        # `wu` from the tree `init` returns, and F = 4,096 tiles whole as
+        # it is
         routed, loads, reads = held_expert_ffn(
             h, w, idx, layer["wg"], layer["wu"], layer["wd"],
             first=cfg.experts_first, tile=cfg.moe_tile, live=live)

@@ -191,7 +191,9 @@ class DeepSeekV3Config:
 # layer, each with its own threefry over up to 235M values, took the chip's
 # compiler minutes on a cold start).  The two vocabulary tables are places
 # 0 and 1 of "layer" -1; norms are ones and the correction bias zeros (a
-# fresh router's).
+# fresh router's).  `wg` and `wu` keep their places in the recipe (the
+# benchmark's reference draws them there) and lie side by side in ONE leaf
+# of the tree, `wgu` [held, D, 2F]: `held_experts_leaf`.
 LEAVES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down",
           "router", "wg", "wu", "wd", "shared_gate", "shared_up",
           "shared_down")
@@ -213,6 +215,18 @@ def _draw(key, layer: int, place: int, shape, std: float, dtype):
                     jnp.dtype(dtype)) for i in range(-(-size // DRAW_PIECE))]
     flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
     return flat[:size].reshape(shape)
+
+
+def held_experts_leaf(wg, wu):
+    """The held experts' gate and up matrices [held, D, F] as the one leaf
+    `ops/moe.held_expert_ffn` multiplies by, [held, D, 2F]: gate in columns
+    [:F], up in [F:].  Laid where the layer is drawn and not in a view
+    beside the tree, which would hold both twice — and waited for: the
+    host runs ahead of the draw, and every layer's `wg` and `wu` would
+    stand beside its leaf until the device got to them (Ling's loader
+    peaked at 13.11e9 B so, 11.99e9 waiting, 12.10e9 with the two
+    apart)."""
+    return jax.block_until_ready(jnp.concatenate([wg, wu], axis=-1))
 
 
 def init_layer(key, cfg: DeepSeekV3Config, l: int) -> Dict[str, Any]:
@@ -245,7 +259,7 @@ def init_layer(key, cfg: DeepSeekV3Config, l: int) -> Dict[str, Any]:
         # the router and its bias are kept and applied in f32
         router=w("router", (D, cfg.n_experts), D, dtype=jnp.float32),
         router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-        wg=w("wg", (C, D, F), D), wu=w("wu", (C, D, F), D),
+        wgu=held_experts_leaf(w("wg", (C, D, F), D), w("wu", (C, D, F), D)),
         wd=w("wd", (C, F, D), F, out),
         # the shared experts side by side: one SwiGLU of width S*F whose
         # output is the sum of theirs
@@ -381,7 +395,7 @@ def layer_ffn(h, layer, cfg: DeepSeekV3Config, live=None):
             scale=cfg.routed_scale)
     with jax.named_scope("moe_experts"):
         routed, loads, reads = held_expert_ffn(
-            h, w, idx, layer["wg"], layer["wu"], layer["wd"],
+            h, w, idx, layer["wgu"], None, layer["wd"],
             first=cfg.experts_first, tile=cfg.moe_tile, live=live)
     with jax.named_scope("moe_shared"):
         shared = swiglu(h, layer["shared_gate"].astype(dt),
@@ -657,8 +671,8 @@ def copy_page(cache, dst, src):
 # the leaves the programs cast to cfg.dtype where they use them; the norms,
 # the router and its bias are used as they are kept
 _SERVE_CAST = frozenset({"embed", "unembed", "wq_a", "wq_b", "wkv_a", "w_uk",
-                         "w_uv", "wo", "w_gate", "w_up", "w_down", "wg", "wu",
-                         "wd", "shared_gate", "shared_up", "shared_down"})
+                         "w_uv", "wo", "w_gate", "w_up", "w_down", "wgu", "wd",
+                         "shared_gate", "shared_up", "shared_down"})
 
 
 @functools.partial(jax.jit, static_argnames="cfg")

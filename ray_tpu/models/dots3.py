@@ -220,7 +220,9 @@ class Dots3Config:
 # place), i), times the leaf's std, rounded to its dtype, end to end, cut to
 # the leaf's size), a leaf's place its index here.  Norms are ones, the
 # indexer's LayerNorm bias and the correction bias zeros (a benchmark's
-# loader draws what a checkpoint would hold there).
+# loader draws what a checkpoint would hold there).  `wg` and `wu` keep
+# their places in the recipe and lie in ONE leaf of the tree, `wgu`
+# (`deepseek_v3.held_experts_leaf`).
 LEAVES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_head_gate", "wi_q",
           "wi_k", "wi_w", "w_gate", "w_up", "w_down", "router", "wg", "wu",
           "wd", "shared_gate", "shared_up", "shared_down")
@@ -263,7 +265,8 @@ def init_layer(key, cfg: Dots3Config, l: int) -> Dict[str, Any]:
     layer.update(
         router=w("router", (D, cfg.n_experts), D, dtype=jnp.float32),
         router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-        wg=w("wg", (C, D, F), D), wu=w("wu", (C, D, F), D),
+        wgu=_dm.held_experts_leaf(w("wg", (C, D, F), D),
+                                  w("wu", (C, D, F), D)),
         wd=w("wd", (C, F, D), F, out),
         shared_gate=w("shared_gate", (D, S * F), D),
         shared_up=w("shared_up", (D, S * F), D),
@@ -595,8 +598,8 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
 # the router and its bias are used as they are kept
 _SERVE_CAST = frozenset({
     "embed", "unembed", "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "wo",
-    "w_head_gate", "wi_q", "wi_k", "wi_w", "w_gate", "w_up", "w_down", "wg",
-    "wu", "wd", "shared_gate", "shared_up", "shared_down"})
+    "w_head_gate", "wi_q", "wi_k", "wi_w", "w_gate", "w_up", "w_down", "wgu",
+    "wd", "shared_gate", "shared_up", "shared_down"})
 
 
 def serve_view(params, cfg: Dots3Config):

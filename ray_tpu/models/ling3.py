@@ -180,7 +180,9 @@ class Ling3Config:
 # leaf's std, rounded to its dtype; the pieces laid end to end and cut to
 # the leaf's size), a leaf's place its index here.  Norms are ones; the
 # conv's bias, the correction bias, A_log and dt_bias zeros (a benchmark's
-# loader draws what a checkpoint would hold there).
+# loader draws what a checkpoint would hold there).  `wg` and `wu` keep
+# their places in the recipe and lie in ONE leaf of the tree, `wgu`
+# (`deepseek_v3.held_experts_leaf`).
 LEAVES = ("w_qkv", "conv_w", "w_f", "w_b", "w_g", "wo", "wq", "wkv_a",
           "wkv_b", "w_head_gate", "w_gate", "w_up", "w_down", "router", "wg",
           "wu", "wd", "shared_gate", "shared_up", "shared_down")
@@ -246,7 +248,8 @@ def init_layer(key, cfg: Ling3Config, l: int) -> Dict[str, Any]:
     layer.update(
         router=w("router", (D, cfg.n_experts), D, dtype=jnp.float32),
         router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-        wg=w("wg", (C, D, F), D), wu=w("wu", (C, D, F), D),
+        wgu=_dm.held_experts_leaf(w("wg", (C, D, F), D),
+                                  w("wu", (C, D, F), D)),
         wd=w("wd", (C, F, D), F, out),
         shared_gate=w("shared_gate", (D, S), D),
         shared_up=w("shared_up", (D, S), D),
@@ -556,7 +559,7 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
 _SERVE_CAST = frozenset({
     "embed", "unembed", "w_qkv", "conv_w", "conv_b", "w_f", "w_b", "w_g",
     "wo", "wq", "wkv_a", "w_uk", "w_uv", "w_head_gate", "w_gate", "w_up",
-    "w_down", "wg", "wu", "wd", "shared_gate", "shared_up", "shared_down"})
+    "w_down", "wgu", "wd", "shared_gate", "shared_up", "shared_down"})
 
 
 def serve_view(params, cfg: Ling3Config):

@@ -205,23 +205,46 @@ def route_sigmoid_grouped(h: jax.Array, router_w: jax.Array, bias: jax.Array,
 # on a v5e (197 TFLOP/s, 819 GB/s) — so a block at or under that makes a
 # touched expert cost its weights' read.  It depends on the chip alone,
 # not on a model: no configuration sets it.
+#
+# tk and tn are the compiler's: each the LARGEST of 512 / 256 / 128 that
+# divides the weights' K and N (read in the compiled text PR 60 kept,
+# `chiprun_out/pr60/prefill_tree.hlo.txt`: K 2,560 x N 768 tiled
+# "128,512,256", K 768 x N 2,560 "128,256,512"; `tests/test_chip_compile.py`
+# holds K 2,560 x N 1,536 at "128,512,512").  A grid step costs ~0.26
+# us beside its tile's bytes, so a 512 x 256 tile (0.32 us of reading)
+# stands at 55% of the memory's speed where a 512 x 512 one stands at
+# ~73%.  An F that is 3 x 256 (Ling's 768) halves every gate and up tile;
+# gate and up side by side, N = 2F = 3 x 512, tile whole — which is why
+# `held_expert_ffn` takes them as ONE leaf `[count, D, 2F]` where a model
+# lays them so.
 ROW_BLOCK = 128
 
 
 def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
-                    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                    *, first: int, tile: int = 512,
+                    w_gate: jax.Array, w_up: Optional[jax.Array],
+                    w_down: jax.Array, *, first: int, tile: int = 512,
                     live: Optional[jax.Array] = None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The held experts' part of a routed SwiGLU layer, dropless.
 
     h [N, D]; (weights, idx) [N, k] from the router over all experts;
-    w_gate / w_up [count, D, F], w_down [count, F, D] are experts
-    first..first+count-1.  The N*k pairs are sorted by held expert (pairs
-    on absent experts last) and go through grouped matrix products
-    (`jax.lax.ragged_dot`) a trip at a time, for as many trips as the pairs
-    of held experts ask — a traced trip count, so a chunk whose pairs
-    mostly fall elsewhere costs what falls here.  A trip takes at most
+    w_down [count, F, D] and the gate and up matrices are experts
+    first..first+count-1.  Gate and up come in one of two layouts, told
+    apart by the operands alone: `w_up` None and `w_gate` ONE leaf
+    [count, D, 2F], gate in columns [:F] and up in [F:] (F is
+    w_down.shape[1]) — a trip is then TWO grouped products, the gathered
+    rows are read once and N = 2F tiles whole where F alone does not (the
+    comment above ROW_BLOCK) — or `w_gate` / `w_up` [count, D, F] apart,
+    three products a trip.  The second stays for `cohere2_moe` only, whose
+    tree the benchmark's own check reads `wg` / `wu` from (and whose
+    F = 4,096 tiles whole either way); `deepseek_v3.layer_ffn`'s three
+    models lay the leaf.  Both are the same sums: operands in h's dtype,
+    f32 accumulation over D.
+    The N*k pairs are sorted by held expert (pairs on absent experts last)
+    and go through grouped matrix products (`jax.lax.ragged_dot`) a trip at
+    a time, for as many trips as the pairs of held experts ask — a traced
+    trip count, so a chunk whose pairs mostly fall elsewhere costs what
+    falls here.  A trip takes at most
     M = min(`tile`, N*k, ROW_BLOCK) sorted rows and ENDS WHERE AN EXPERT
     ENDS: rows [lo, hi) with hi the largest end of an expert's rows at or
     under lo + M, so no expert's weights are read by two trips — but for
@@ -236,7 +259,7 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
     with a pair is how often a touched expert's weights were read)."""
     N, D = h.shape
     k = idx.shape[1]
-    count = w_gate.shape[0]
+    count, F = w_down.shape[:2]
     local = idx - first
     held = (local >= 0) & (local < count)
     if live is not None:
@@ -262,8 +285,11 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
         x = h[tok]
         g = jax.lax.ragged_dot(x, w_gate.astype(dt), gs,
                                preferred_element_type=jnp.float32)
-        u = jax.lax.ragged_dot(x, w_up.astype(dt), gs,
-                               preferred_element_type=jnp.float32)
+        if w_up is None:
+            g, u = g[:, :F], g[:, F:]
+        else:
+            u = jax.lax.ragged_dot(x, w_up.astype(dt), gs,
+                                   preferred_element_type=jnp.float32)
         y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(dt),
                                w_down.astype(dt), gs,
                                preferred_element_type=jnp.float32)

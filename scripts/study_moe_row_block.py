@@ -16,13 +16,17 @@ A side is a module and the rows it gives a product: a module WITHOUT a
 rows each) is read at `--tiles`; a module WITH one (trips end where an
 expert ends) at `--blocks`, the constant patched, under the cells' own
 `tile` of 512.  `--parent DIR` adds the `ray_tpu/ops/moe.py` of another
-checkout beside the tree's.  Every side's result is held to the first's.
+checkout beside the tree's.  A module whose `held_expert_ffn` takes gate and
+up as ONE leaf [held, D, 2F] with no up operand (PR 62: its `w_up` is
+Optional) is read in both layouts, `<label>` with the two apart (three
+products a trip) and `<label>+one_leaf` (two).  Every side's result is
+held to the first's.
 
     python scripts/study_moe_row_block.py [--parent _parent] [--iters 10]
         [--tiles 64 128 256 512] [--blocks 64 128 256] [--only ling_chunk]
 
 `--toy` runs the control flow at toy sizes on the CPU (no times).  Writes
-chiprun_out/pr53/study_moe_row_block[.<tag>].json.  Not wired into the
+chiprun_out/<--out, pr53>/study_moe_row_block[.<tag>].json.  Not wired into the
 benchmark.
 """
 import argparse
@@ -33,6 +37,7 @@ import shutil
 import sys
 import tempfile
 import time
+import typing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -87,15 +92,22 @@ def inputs(shape, seed, dtype):
     return h, w, idx.astype(jnp.int32), wg, wu, wd, live
 
 
+def takes_one_leaf(mod) -> bool:
+    hint = typing.get_type_hints(mod.held_expert_ffn).get("w_up")
+    return type(None) in typing.get_args(hint)
+
+
 def sides_of(mods, tiles, blocks):
-    """[(label, module, tile, block or None)]."""
+    """[(label, module, tile, block or None, gate and up in one leaf)]."""
     out = []
     for label, mod in mods:
         if hasattr(mod, "ROW_BLOCK"):
-            out += [(f"{label}@block={b}", mod, CELL_TILE, b)
-                    for b in blocks]
+            layouts = (False, True) if takes_one_leaf(mod) else (False,)
+            out += [(f"{label}{'+one_leaf' if one else ''}@block={b}", mod,
+                     CELL_TILE, b, one) for one in layouts for b in blocks]
         else:
-            out += [(f"{label}@tile={t}", mod, t, None) for t in tiles]
+            out += [(f"{label}@tile={t}", mod, t, None, False)
+                    for t in tiles]
     return out
 
 
@@ -140,6 +152,7 @@ def main():
     ap.add_argument("--blocks", type=int, nargs="*", default=[64, 128, 256])
     ap.add_argument("--only", nargs="*")
     ap.add_argument("--tag", default="")
+    ap.add_argument("--out", default="pr53")
     ap.add_argument("--toy", action="store_true")
     a = ap.parse_args()
 
@@ -153,7 +166,7 @@ def main():
     rows = []
     record = {"device": {"platform": dev.platform, "kind": dev.device_kind},
               "iters": a.iters, "seed": a.seed, "rows": rows}
-    d = os.path.join(ROOT, "chiprun_out", "pr53")
+    d = os.path.join(ROOT, "chiprun_out", a.out)
     os.makedirs(d, exist_ok=True)
     path = os.path.join(d, "study_moe_row_block"
                         + (f".{a.tag}" if a.tag else "") + ".json")
@@ -162,14 +175,19 @@ def main():
             continue
         args = inputs(shape, a.seed, jnp.float32 if a.toy else jnp.bfloat16)
         *tensors, live = args
+        one_leaf = None
         ref = None
-        for label, mod, tile, block in sides_of(mods, a.tiles, a.blocks):
+        for label, mod, tile, block, one in sides_of(mods, a.tiles, a.blocks):
             if block is not None:
                 mod.ROW_BLOCK = block
+            if one and one_leaf is None:
+                one_leaf = tensors[:3] + [
+                    jnp.concatenate(tensors[3:5], axis=-1), None, tensors[5]]
             # a new jit a side: the constant is read while tracing
             fn = jax.jit(lambda *t, mod=mod, tile=tile: mod.held_expert_ffn(
                 *t, first=0, tile=tile, live=live))
-            got, ms = measure(fn, tensors, a.iters, a.toy)
+            got, ms = measure(fn, one_leaf if one else tensors, a.iters,
+                              a.toy)
             out, loads = np.asarray(got[0]), np.asarray(got[1])
             if ref is None:
                 ref = out
@@ -191,7 +209,7 @@ def main():
             print(json.dumps(row), flush=True)
             with open(path, "w") as f:
                 json.dump(record, f, indent=1)
-        del args, tensors
+        del args, tensors, one_leaf
     print(json.dumps({"ok": True, "device": record["device"]}))
 
 

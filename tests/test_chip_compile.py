@@ -84,23 +84,28 @@ def _latent_kernels(compiled) -> int:
                           compiled.as_text()))
 
 
-def _assert_grouped_products_take_a_row_block(compiled, moe_layers: int):
+def _assert_grouped_products_take_a_row_block(compiled, moe_layers: int,
+                                              a_layer: int = 2):
     """The held experts' grouped products (`jax.lax.ragged_dot`) as the
-    chip's compiler wrote them: a `ragged-dot-none` custom call each, three
-    a MoE layer (gate, up, down), whose `ragged_dot_tiling` "tm,tk,tn"
-    takes at most `ops/moe.ROW_BLOCK` rows a visited (expert, row block) —
-    at tm = 512 a touched expert costs a 512-row product and not its
-    weights' read (PR 53)."""
+    chip's compiler wrote them: a `ragged-dot-none` custom call each,
+    `a_layer` a MoE layer — two where gate and up lie in one leaf (gate|up,
+    down: `deepseek_v3.layer_ffn`'s three models, PR 62), three where they
+    lie apart (Command A+) — whose `ragged_dot_tiling` "tm,tk,tn" takes at
+    most `ops/moe.ROW_BLOCK` rows a visited (expert, row block) — at
+    tm = 512 a touched expert costs a 512-row product and not its weights'
+    read (PR 53).  -> the tilings, {"tm,tk,tn": calls}."""
     from ray_tpu.ops.moe import ROW_BLOCK
 
     calls = [ln for ln in compiled.as_text().splitlines()
              if re.match(r"\s*%?ragged-dot-none[.\d]* = ", ln)]
-    assert len(calls) == 3 * moe_layers, len(calls)
+    assert len(calls) == a_layer * moe_layers, len(calls)
     tilings = [re.search(r'ragged_dot_tiling="(\d+),\d+,\d+"', c)
                for c in calls]
     assert all(tilings), calls[0][:400]
     assert all(int(t.group(1)) <= ROW_BLOCK for t in tilings), [
         t.group(0) for t in tilings]
+    found = [t.group(0).split('"')[1] for t in tilings]
+    return {t: found.count(t) for t in set(found)}
 
 
 def _gathered_blocks(compiled, slots: int):
@@ -632,7 +637,8 @@ def test_cohere2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
     # the grouped products are the chip's own kernel, not a dense fallback,
     # for a chunk's rows and a step's alike
     assert "ragged-dot" in compiled.as_text()
-    _assert_grouped_products_take_a_row_block(compiled, moe_layers=4)
+    _assert_grouped_products_take_a_row_block(compiled, moe_layers=4,
+                                              a_layer=3)
     if key == "step":
         _assert_sampler_asks_its_operands(compiled, B, V)
 
@@ -896,7 +902,11 @@ def test_ling3_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
                           r"(copy|transpose)\(", ln)]
     assert not moved, moved
     assert "ragged-dot" in text
-    _assert_grouped_products_take_a_row_block(compiled, moe_layers=6)
+    # gate|up, N = 2F = 1,536 = 3 x 512, on whole 512 x 512 tiles — apart,
+    # N = F = 768 = 3 x 256 held each to "128,512,256" (PR 62); the down
+    # product's K = 768 keeps tk 256
+    assert _assert_grouped_products_take_a_row_block(
+        compiled, moe_layers=6) == {"128,512,512": 6, "128,256,512": 6}
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     if key == "step":       # one kernel a KDA layer, under its name, and

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from benchmarks.drivers.replica_deepseek_v3 import shape_weights
 from benchmarks.reference import deepseek_v3_plain as ref
+from held_leaf import apart, laid
 from ray_tpu.models import deepseek_v3 as dm
 from ray_tpu.ops.layers import yarn_frequencies
 from ray_tpu.ops.moe import route_sigmoid_grouped
@@ -90,7 +91,7 @@ def test_the_reference_draws_the_loaders_weights(model, drawn):
     cfg, params = model
     for name in ("embed", "unembed"):
         np.testing.assert_array_equal(params[name], drawn[name])
-    for mine, theirs in zip(params["layers"], drawn["layers"]):
+    for mine, theirs in zip(params["layers"], map(laid, drawn["layers"])):
         assert set(mine) == set(theirs)
         for name in mine:
             np.testing.assert_array_equal(mine[name], theirs[name], name)
@@ -287,11 +288,11 @@ def test_expert_shares_add_up_to_the_uncut_layer(model):
     for first in range(0, 16, 4):
         share = dataclasses.replace(cfg, experts_first=first, experts_held=4)
         part = dict(layer, **{k: layer[k][first:first + 4]
-                              for k in ("wg", "wu", "wd")})
+                              for k in ("wgu", "wd")})
         out, _ = dm.layer_ffn(h, part, share)
         total = total + (out - shared)
     np.testing.assert_allclose(total, full, atol=TOL, rtol=0)
-    want = ref.feed_forward(h, layer, _sizes(whole))
+    want = ref.feed_forward(h, apart(layer), _sizes(whole))
     np.testing.assert_allclose(full, want, atol=TOL, rtol=0)
 
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from benchmarks.drivers.replica_ling3 import shape_weights
 from benchmarks.reference import deepseek_v3_plain as dsp
 from benchmarks.reference import ling3_plain as ref
+from held_leaf import laid
 from ray_tpu.models import deepseek_v3 as dm
 from ray_tpu.models import ling3 as lm
 
@@ -113,7 +114,8 @@ def test_the_two_draws_agree_leaf_for_leaf(model, drawn):
     for name in ("embed", "unembed", "final_norm"):
         np.testing.assert_array_equal(np.asarray(params[name]),
                                       np.asarray(drawn[name]), err_msg=name)
-    for l, (a, b) in enumerate(zip(params["layers"], drawn["layers"])):
+    for l, (a, b) in enumerate(zip(params["layers"],
+                                   map(laid, drawn["layers"]))):
         assert sorted(a) == sorted(b), l
         for name in a:
             np.testing.assert_array_equal(
@@ -227,8 +229,8 @@ def test_four_chips_shares_add_up_to_the_uncut_layer(model):
         held = E // 4
         part = dataclasses.replace(cfg, experts_first=chip * held,
                                    experts_held=held)
-        mine = dict(lp, **{n: lp[n][chip * held:(chip + 1) * held]
-                           for n in ("wg", "wu", "wd")})
+        mine = laid(dict(lp, **{n: lp[n][chip * held:(chip + 1) * held]
+                                 for n in ("wg", "wu", "wd")}))
         out, (loads, _) = dm.layer_ffn(h, mine, part)
         total = total + np.asarray(out)
         pairs += float(loads.sum())

@@ -7,13 +7,18 @@ product takes (M = min(tile, N*k, ROW_BLOCK)).  A trip ends where an expert
 ends, so an expert of more than M rows always STARTS a trip and is visited
 ceil(rows / M) times wherever its first row falls (trips cut at fixed
 offsets would visit an expert of 300 rows 3 or 4 times under a block of
-128): reads = sum of ceil(load / M) over the held experts, in every case."""
+128): reads = sum of ceil(load / M) over the held experts, in every case.
+Every case runs in both layouts of the gate and up matrices — apart (three
+products a trip) and side by side in one leaf [held, D, 2F] with no up
+operand (two) — and the three models that lay the leaf hold the recipe's
+`wg` and `wu` in it bit for bit."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from held_leaf import apart
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import held_expert_ffn, held_load_stats
 
@@ -88,8 +93,9 @@ CASES = {
 }
 
 
+@pytest.mark.parametrize("form", ["apart", "one_leaf"])
 @pytest.mark.parametrize("name", list(CASES))
-def test_held_products_match_the_per_pair_loop(name):
+def test_held_products_match_the_per_pair_loop(name, form):
     N, k, held, E, first, tile, idx, live, reads_by_hand = CASES[name]
     ks = jax.random.split(jax.random.PRNGKey(len(name)), 5)
     h = jax.random.normal(ks[0], (N, D), jnp.float32)
@@ -103,7 +109,9 @@ def test_held_products_match_the_per_pair_loop(name):
         lambda *a: held_expert_ffn(
             *a, first=first, tile=tile,
             live=None if live is None else jnp.asarray(live)))(
-                h, w, jnp.asarray(idx), wg, wu, wd)
+                h, w, jnp.asarray(idx),
+                *((wg, wu) if form == "apart"
+                  else (jnp.concatenate([wg, wu], axis=-1), None)), wd)
     want, want_loads = _plain(h, w, idx, wg, wu, wd, first, live)
     assert out.shape == (N, D) and out.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(loads), want_loads)
@@ -122,6 +130,39 @@ def test_held_products_match_the_per_pair_loop(name):
     stats = [float(s) for s in held_load_stats([(loads, reads)])]
     assert stats == [want_loads.sum(), want_loads.max(), touched,
                      reads_by_hand]
+
+
+@pytest.mark.parametrize("model,config", [("deepseek_v3", "DeepSeekV3Config"),
+                                          ("ling3", "Ling3Config"),
+                                          ("dots3", "Dots3Config")])
+def test_a_models_leaf_holds_the_recipes_gate_and_up(model, config):
+    """`init`'s `wgu` [held, D, 2F] is, bit for bit, the `wg` the recipe
+    draws at its place beside the `wu` at its own (the benchmark's
+    reference draws the two apart, by the same recipe), and the tree
+    holds neither apart; the serve view casts the leaf like any other."""
+    import importlib
+    import math
+
+    mod = importlib.import_module("ray_tpu.models." + model)
+    cfg = getattr(mod, config).nano(param_dtype=jnp.float32)
+    key = jax.random.PRNGKey(11)
+    C, Dm, Fe = cfg.experts_held, cfg.d_model, cfg.d_expert
+    params = mod.init(key, cfg)
+    layers, view = params["layers"], mod.serve_view(params, cfg)["layers"]
+    assert cfg.param_dtype != cfg.dtype
+    routed = [l for l, layer in enumerate(layers) if "router" in layer]
+    assert routed and "wgu" not in layers[0]
+    for l in routed:
+        layer = layers[l]
+        assert "wg" not in layer and "wu" not in layer
+        assert layer["wgu"].shape == (C, Dm, 2 * Fe)
+        assert layer["wd"].shape == (C, Fe, Dm)
+        for name in ("wg", "wu"):
+            want = mod._draw(key, l, mod.LEAVES.index(name), (C, Dm, Fe),
+                             1.0 / math.sqrt(Dm), cfg.param_dtype)
+            np.testing.assert_array_equal(np.asarray(apart(layer)[name]),
+                                          np.asarray(want), err_msg=(l, name))
+        assert view[l]["wgu"].dtype == jnp.dtype(cfg.dtype)
 
 
 def test_load_stats_of_a_program_without_expert_layers():

@@ -349,8 +349,8 @@ def _toy_programs(mod, cfg, rows=None, slots=4, page=16):
 def _toy(name):
     """(module, config) of a served model at toy size, the widths its
     kernels need on a TPU (128 positions a page's lanes)."""
-    from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, gpt, ling3,
-                                phi4flash)
+    from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3, gpt,
+                                ling3, phi4flash)
     return {
         "phi-4-flash": lambda: (phi4flash, phi4flash.Phi4FlashConfig.nano(
             max_seq=512, kv_block=128, sliding_window=128, d_head=64)),
@@ -363,6 +363,8 @@ def _toy(name):
                                 .nano(max_seq=512, kv_block=128)),
         "ling-3": lambda: (ling3, ling3.Ling3Config.nano(max_seq=512,
                                                          kv_block=128)),
+        "dots3": lambda: (dots3, dots3.Dots3Config.nano(
+            max_seq=512, kv_block=128, window=128)),
     }[name]()
 
 
@@ -409,8 +411,11 @@ def _digest(text):
 # grouped products), PR 57 `brumby`'s chunk (its retention a kernel; the
 # digest leaves a Mosaic module out, so a change INSIDE a kernel moves
 # none), PR 60 `ling-3`'s chunk (`ops/kda.kda_chunk` by sub-blocks and the
-# carried entry read where it stands; its step stays).  A PR that moves or
-# renames Python functions
+# carried entry read where it stands; its step stays), PR 62 the four of
+# `deepseek-v3` and `ling-3` (the held experts' gate and up in one leaf: two
+# grouped products a trip) and, new with it, `dots3`'s two, which run the
+# same `layer_ffn`; `command-a-plus` keeps gate and up apart and its two
+# digests.  A PR that moves or renames Python functions
 # leaves every digest alone (the text carries no source locations; their
 # kernels' source lines unmoved, the compile-cache keys stay too).  A PR
 # that edits one of these programs finds the new digest in the failure and
@@ -422,12 +427,14 @@ PARENT_TEXT = {
     ("command-a-plus", "chunk"): "3860d574703a5237",
     ("brumby", "step"): "c95c739da7c26ef7",
     ("brumby", "chunk"): "3890e9f178cbbf96",
-    ("deepseek-v3", "step"): "1040750f233ad9df",
-    ("deepseek-v3", "chunk"): "972fd14b1ee67cfe",
-    ("ling-3", "step"): "1e1d7126f836888a",
-    ("ling-3", "chunk"): "55ee27a885e72e12",
+    ("deepseek-v3", "step"): "df713cecbeb34064",
+    ("deepseek-v3", "chunk"): "e475b0816eeeb1d3",
+    ("ling-3", "step"): "5b7267d6cbbfb455",
+    ("ling-3", "chunk"): "91e1eba2c001a798",
     ("phi-4-flash", "step"): "d0a2de26cb6e8b1b",
     ("phi-4-flash", "chunk"): "ce1a43a62b84b2a7",
+    ("dots3", "step"): "68e0d3c34b2b3495",
+    ("dots3", "chunk"): "4fef0a8a279a133f",
 }
 
 
