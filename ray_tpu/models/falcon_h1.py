@@ -15,7 +15,7 @@ The block, with n = RMSNorm(h) and the configuration's µP multipliers m_*:
       softmax at d_head^-1/2; W_o.
     SSM: [z | x B C | dt] = (u W_in) * mup, mup the five `ssm_multipliers`
       spread over the segments z, x (d_ssm each), B, C (G N each), dt (Hs);
-      x B C <- SiLU(conv4(x B C) + b)                    ops/kda.conv_*
+      x B C <- SiLU(conv4(x B C) + b)                    ops/shortconv.py
       dt = softplus(dt + dt_bias);  a = -exp(A_log), a head
       S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
                                                           ops/ssd.py
@@ -54,8 +54,8 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (latent_decode_uses_kernel,
                                    paged_decode_attention, streamed_attention)
-from ray_tpu.ops.kda import conv_chunk, conv_step
 from ray_tpu.ops.layers import apply_rope_halves, rms_norm
+from ray_tpu.ops.shortconv import conv_chunk, conv_step
 from ray_tpu.ops.ssd import resolve_impl, ssd_chunk, ssd_step
 
 from . import deepseek_v3 as _dm
@@ -379,9 +379,10 @@ def _conv(pre, tail, layer, cfg: FalconH1Config, step: bool):
         w = layer["conv_w"].reshape((CONV_TAPS,) + tile)
         b = layer["conv_b"].reshape(tile)
         if step:
-            u, kept = conv_step(rows, tail, w, b)
+            u, kept = conv_step(rows, tail, w, b, jax.nn.silu, "kda_conv")
         else:
-            u, kept = conv_chunk(rows, tail, w, b), rows
+            u = conv_chunk(rows, tail, w, b, jax.nn.silu, "kda_conv")
+            kept = rows
         return u.reshape(pre.shape), kept
 
 

@@ -59,9 +59,9 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.kda import (conv_chunk, conv_step, kda_chunk, kda_step,
-                             resolve_impl)
+from ray_tpu.ops.kda import kda_chunk, kda_step, resolve_impl
 from ray_tpu.ops.layers import apply_rope_interleaved, rms_norm
+from ray_tpu.ops.shortconv import conv_chunk, conv_step
 from ray_tpu.ops.moe import held_load_stats
 
 from . import deepseek_v3 as _dm
@@ -322,7 +322,8 @@ def _kda_sequence(x, h, layer, real, state, tail, cfg: Ling3Config):
     [T, 3H, dh])."""
     pre, log_a, beta, gate = _kda_project(h, layer, cfg)
     q, k, v = _kda_qkv(conv_chunk(pre[0], tail, layer["conv_w"],
-                                  layer["conv_b"]), cfg)
+                                  layer["conv_b"], jax.nn.silu, "kda_conv"),
+                       cfg)
     heads_first = lambda a: jnp.swapaxes(a, 0, 1)
     o, state = kda_chunk(
         heads_first(q), heads_first(jnp.where(real[:, None, None], k, 0.0)),
@@ -485,7 +486,8 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: Ling3Config,
     def kda_layer(j, x, h, layer, state, tail):
         pre, log_a, beta, gate = _kda_project(h, layer, cfg)
         old = tail[j][idx]
-        u, new = conv_step(pre[:, 0], old, layer["conv_w"], layer["conv_b"])
+        u, new = conv_step(pre[:, 0], old, layer["conv_w"], layer["conv_b"],
+                           jax.nn.silu, "kda_conv")
         tail = tail.at[j, idx].set(
             jnp.where(live[:, None, None, None], new, old))
         q, k, v = _kda_qkv(u, cfg)

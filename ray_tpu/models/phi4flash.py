@@ -20,7 +20,7 @@ recurrent one:
 
 A Mamba layer (C = expand * d_model channels, N states, R = dt_rank):
 
-    u~ | z = n W_in;   u = SiLU(conv4(u~) + b_c)        ops/kda.conv_*
+    u~ | z = n W_in;   u = SiLU(conv4(u~) + b_c)        ops/shortconv.py
     r | B | C = u W_x;   d = softplus(r W_dt + b_dt)
     h_t = exp(d_t (x) A) h_{t-1} + (d_t u_t) (x) B_t     ops/mamba.py
     y = h_t C_t + D u;   out = (y * SiLU(z)) W_out
@@ -72,7 +72,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (latent_decode_uses_kernel,
                                    paged_decode_attention, streamed_attention)
-from ray_tpu.ops.kda import conv_chunk, conv_step
+from ray_tpu.ops.shortconv import conv_chunk, conv_step
 from ray_tpu.ops.layers import layer_norm, rms_norm
 from ray_tpu.ops.mamba import (resolve_impl, selective_scan_chunk,
                                selective_step)
@@ -319,7 +319,8 @@ def _mamba_sequence(x, h, layer, real, state, tail, cfg: Phi4FlashConfig):
     tail [3, C] -> (x, m [1, T, C], the state after, the pre-conv rows
     [T, C])."""
     pre, z = _mamba_project(h, layer, cfg)
-    u = conv_chunk(pre[0], tail, layer["conv_w"], layer["conv_b"])
+    u = conv_chunk(pre[0], tail, layer["conv_w"], layer["conv_b"],
+                   jax.nn.silu, "kda_conv")
     d, bm, cm = _mamba_gates(u, layer, cfg)
     y, state = selective_scan_chunk(
         u, jnp.where(real[:, None], d, 0.0), -jnp.exp(layer["a_log"]), bm, cm,
@@ -632,7 +633,8 @@ def paged_decode_step(params, cache, tokens, ptabs, pos,
     def mamba_layer(j, x, h, layer, state, tail):
         pre, z = _mamba_project(h, layer, cfg)
         old = tail[j][idx]
-        u, new = conv_step(pre[:, 0], old, layer["conv_w"], layer["conv_b"])
+        u, new = conv_step(pre[:, 0], old, layer["conv_w"], layer["conv_b"],
+                           jax.nn.silu, "kda_conv")
         tail = tail.at[j, idx].set(jnp.where(live[:, None, None], new, old))
         d, bm, cm = _mamba_gates(u, layer, cfg)
         y, state = selective_step(u, d, -jnp.exp(layer["a_log"]), bm, cm,

@@ -275,31 +275,24 @@ def _state_free(q, k, v, log_a, beta):
 
 
 # ---------------------------------------------------------------------------
-# the short convolution before it: depthwise, causal, `W` taps, then SiLU
+# the short convolution before it: depthwise, causal, `W` taps, then SiLU.
+# The form lives in `ops/shortconv.py` (the activation, the bias and the
+# scope its arguments: `models/lfm2_moe.py` runs it bare, as a mixer); these
+# are its two functions bound to what stands before a recurrent mixer —
+# SiLU, under the scope `kda_conv` — for the callers that take them from
+# here.  (Imported HERE and not at the top: nothing above `_step_kernel` may
+# gain or lose a line.)
+
+from . import shortconv as _sc  # noqa: E402
 
 
 def conv_chunk(rows, tail, w, b):
-    """rows [T, ..] one sequence's pre-conv rows in order, tail [W-1, ..]
-    the W-1 rows before them (zeros before a sequence's start), w [W, ..]
-    (tap W-1 meets the row itself), b [..] -> SiLU(conv) [T, ..] float32:
-    a sum over W shifted copies."""
-    with jax.named_scope("kda_conv"):
-        T, W = rows.shape[0], w.shape[0]
-        ext = jnp.concatenate([tail.astype(rows.dtype), rows], axis=0)
-        acc = b.astype(jnp.float32) + sum(
-            w[i].astype(jnp.float32)
-            * jax.lax.slice_in_dim(ext, i, i + T, axis=0).astype(jnp.float32)
-            for i in range(W))
-        return jax.nn.silu(acc)
+    """`shortconv.conv_chunk`: rows [T, ..], tail [W-1, ..], w [W, ..],
+    b [..] -> SiLU(conv) [T, ..] float32."""
+    return _sc.conv_chunk(rows, tail, w, b, jax.nn.silu, "kda_conv")
 
 
 def conv_step(row, tail, w, b):
-    """One token of every slot: row [B, ..] the new pre-conv rows, tail
-    [B, W-1, ..] each slot's last W-1 -> (SiLU(conv) [B, ..] float32, the
-    tails after: the oldest row out, the new one in)."""
-    with jax.named_scope("kda_conv"):
-        ext = jnp.concatenate([tail, row[:, None].astype(tail.dtype)], axis=1)
-        acc = b.astype(jnp.float32) + jnp.einsum(
-            "bw...,w...->b...", ext.astype(jnp.float32),
-            w.astype(jnp.float32))
-        return jax.nn.silu(acc), ext[:, 1:]
+    """`shortconv.conv_step`: row [B, ..], tail [B, W-1, ..] -> (SiLU(conv)
+    [B, ..] float32, the tails after)."""
+    return _sc.conv_step(row, tail, w, b, jax.nn.silu, "kda_conv")

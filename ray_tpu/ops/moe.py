@@ -151,16 +151,31 @@ def moe_ffn_sharded(x: jax.Array, router_w: jax.Array, w_in_local: jax.Array,
 # drops pairs past C and scores by softmax, so it is not used here).
 
 
-def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, k: int
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid scores over ALL experts in f32, top-k, weights normalised
-    over the chosen k.  h [N, D], router_w [D, E] -> (weights [N, k] f32,
-    expert ids [N, k] int32)."""
-    s = jax.nn.sigmoid(jnp.einsum(
+def _sigmoid_scores(h: jax.Array, router_w: jax.Array) -> jax.Array:
+    """Sigmoid scores over ALL experts in f32: h [N, D], router_w [D, E] ->
+    [N, E]."""
+    return jax.nn.sigmoid(jnp.einsum(
         "nd,de->ne", h.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    w, idx = jax.lax.top_k(s, k)
-    return w / jnp.sum(w, axis=-1, keepdims=True), idx.astype(jnp.int32)
+
+
+def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, k: int, *,
+                       bias: Optional[jax.Array] = None, eps: float = 0.0
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores over ALL experts in f32, top-k, weights normalised
+    over the chosen k (their sum plus `eps`).  With `bias` [E] (a
+    correction bias: auxiliary-loss-free balancing) the scores plus the
+    bias CHOOSE and the scores alone WEIGH; a tie goes to the lower index.
+    h [N, D], router_w [D, E] -> (weights [N, k] f32, expert ids [N, k]
+    int32)."""
+    s = _sigmoid_scores(h, router_w)
+    if bias is None:
+        w, idx = jax.lax.top_k(s, k)
+    else:
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    return w / (total + eps if eps else total), idx.astype(jnp.int32)
 
 
 def route_sigmoid_grouped(h: jax.Array, router_w: jax.Array, bias: jax.Array,
@@ -176,9 +191,7 @@ def route_sigmoid_grouped(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     goes to the lower index, in both rankings).  Weights are s over the
     chosen k, normalised (+1e-20) and multiplied by `scale`.
     h [N, D], router_w [D, E] -> (weights [N, k] f32, ids [N, k] int32)."""
-    s = jax.nn.sigmoid(jnp.einsum(
-        "nd,de->ne", h.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+    s = _sigmoid_scores(h, router_w)
     N, E = s.shape
     c = s + bias.astype(jnp.float32)
     by_group = c.reshape(N, n_group, E // n_group)

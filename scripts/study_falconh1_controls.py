@@ -76,8 +76,8 @@ def faulty(loader, variant):
             fm.ssd_chunk, fm.ssd_step = chunk_half, step_half
         elif variant == "tail_dropped":
             conv, prefill = fm.conv_chunk, fm.paged_prefill
-            fm.conv_chunk = lambda rows, tail, w, b: conv(
-                rows, jnp.zeros_like(tail), w, b)
+            fm.conv_chunk = lambda rows, tail, *rest: conv(
+                rows, jnp.zeros_like(tail), *rest)
 
             def tailless(*a, **kw):
                 logits, cache, stats = prefill(*a, **kw)

@@ -94,8 +94,8 @@ def faulty(loader, variant):
                 half(q), half(k), half(v), *a, **kw)
         elif variant == "tail_dropped":
             conv, prefill = lm.conv_chunk, lm.paged_prefill
-            lm.conv_chunk = lambda rows, tail, w, b: conv(
-                rows, jnp.zeros_like(tail), w, b)
+            lm.conv_chunk = lambda rows, tail, *rest: conv(
+                rows, jnp.zeros_like(tail), *rest)
 
             def tailless(*a, **kw):
                 logits, cache, stats = prefill(*a, **kw)
@@ -187,6 +187,9 @@ def cell_runs(variants, seed: int):
              "state_rel_rms": ref.get("state_rel_rms"),
              "state_rel_rms_by_layer": ref.get("state_rel_rms_by_layer"),
              "state_half_share": ref.get("state_half_share"),
+             "tail_rel_rms": ref.get("tail_rel_rms"),
+             "first_keys_max": ref.get("first_keys_max"),
+             "second_keys_q25": ref.get("second_keys_q25"),
              "checked": ref.get("checked"),
              "tokens_checked": ref.get("tokens_checked"),
              "per_request": [(p["context"], p["n_argmax"] / p["n"],

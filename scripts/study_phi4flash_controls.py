@@ -71,8 +71,8 @@ def faulty(loader, variant):
             pm.selective_scan_chunk, pm.selective_step = chunk_half, step_half
         elif variant == "tail_dropped":
             conv, prefill = pm.conv_chunk, pm.paged_prefill
-            pm.conv_chunk = lambda rows, tail, w, b: conv(
-                rows, jnp.zeros_like(tail), w, b)
+            pm.conv_chunk = lambda rows, tail, *rest: conv(
+                rows, jnp.zeros_like(tail), *rest)
 
             def tailless(*a, **kw):
                 logits, cache, stats = prefill(*a, **kw)

@@ -76,7 +76,8 @@ _QUICK_FILES = {
     "test_engine_three_kinds.py",
     "test_kda.py", "test_label_scheduling.py", "test_ling3.py",
     "test_mamba.py", "test_moe_held_products.py", "test_phi4flash.py",
-    "test_ssd.py", "test_falcon_h1.py",
+    "test_ssd.py", "test_falcon_h1.py", "test_shortconv.py",
+    "test_lfm2_moe.py",
     "test_mpmd.py",
     "test_native_sched.py", "test_native_store.py", "test_ops.py",
     "test_parallel.py", "test_partition.py", "test_podracer.py",

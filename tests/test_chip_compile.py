@@ -1217,3 +1217,97 @@ def test_falcon_h1_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
         assert named("paged_decode_attention") == 0
         assert _streamed_kernels(compiled) >= 1
     print(key, "total", total, "temp", m.temp_size_in_bytes)
+
+
+# -- the ninth served model at its published widths: LFM2-8B-A1B's thirteen
+# -- layers of benchmarks/configs/lfm2-8b-a1b-l13.json ------------------------
+
+
+@pytest.mark.parametrize("key", ["step", ("prefill", 512), ("prefill", 256)],
+                         ids=["step", "prefill512", "prefill256"])
+def test_lfm2_moe_serve_programs_fit_one_chip(one_chip, key, monkeypatch):
+    """The serve programs of a model whose mixer is a gated short
+    convolution in ten layers of thirteen (a tail entry of two rows a
+    layer) and grouped-query attention with normed heads in three (pages),
+    every one of 32 experts held in twelve, from the benchmark's own
+    `engine_kwargs`, 64 slots of 72 pages: the chip's compiler takes them;
+    weights + pages + tails + temporaries stay under 14.5e9 B; the cache is
+    donated and held once, no arena moved by a copy; two grouped products
+    an expert layer on 128-row blocks, gate|up tiled whole."""
+    import json
+
+    from benchmarks.lib.lfm2moecfg import model_config
+    from ray_tpu.models import lfm2_moe as lm
+    from ray_tpu.serve._engine import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "lfm2-8b-a1b-l13.json")) as f:
+        conf = json.load(f)
+    assert conf["reduced"] == ["num_hidden_layers", "num_dense_layers",
+                               "layer_types"]
+    cfg = model_config(conf)
+    view = _on(jax.eval_shape(
+        lambda k: lm.serve_view(lm.init(k, cfg), cfg),
+        jax.random.PRNGKey(0)), one_chip)
+    eng = ContinuousEngine(lm, cfg, view, **conf["serve"]["engine_kwargs"])
+    try:
+        cache = _on(jax.eval_shape(functools.partial(
+            lm.init_paged_cache, cfg, eng._pool_pages, eng.page_size)),
+            one_chip)
+        B, V = eng.max_slots, cfg.vocab_size
+        s = lambda shape, dt: _sds(shape, dt, one_chip)
+        i32 = s((), jnp.int32)
+        if key == "step":
+            args = (view, cache, s((B, V), jnp.float32),
+                    s((B, 2), jnp.uint32), s((B,), jnp.float32),
+                    s((B,), jnp.int32),
+                    {k: s((B, w), jnp.int32)
+                     for k, w in eng._widths.items()}, s((B,), jnp.int32))
+        else:
+            args = (view, cache, s((key[1],), jnp.int32),
+                    {k: s((w,), jnp.int32) for k, w in eng._widths.items()},
+                    i32, i32)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = eng._fn(key).lower(*args).compile()
+    finally:
+        eng.stop()
+    assert (B, eng._pool_pages, eng._widths, eng._share, eng._main,
+            eng._state_kinds, eng.queue_cap) == (
+        64, {"full": 4609, "conv": 65}, {"full": 72, "conv": 1}, False,
+        "full", ["conv"], 256)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    arena = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(view))
+    assert [a.shape for a in cache["k"]] == [(4609, 128, 512)] * 3
+    # the tails as whole tiles (two rows of an 8-row tile are re-laid)
+    assert cache["tail"].shape == (10, 65, 2, 16, 128)
+    assert arena == 4609 * 786432 + 65 * 163840
+    assert 9.21e9 < weights < 9.22e9, weights
+    assert m.alias_size_in_bytes >= arena
+    assert total < 14.5e9, total
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"= f32\[10,65,2,16,128\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)
+             or re.search(r"= bf16\[4609,128,512\]\{[^}]*\} "
+                          r"(copy|transpose)\(", ln)]
+    assert not moved, moved
+    tilings = _assert_grouped_products_take_a_row_block(compiled,
+                                                        moe_layers=12)
+    # gate|up K 2,048 x N 3,584 = 7 x 512; down K 1,792 = 7 x 256
+    assert tilings == {"128,512,512": 12, "128,256,512": 12}, tilings
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    named = lambda n: sum(n in c.split(" = ", 1)[0] for c in calls)
+    if key == "step":       # a walk of the slots' own pages a layer
+        assert named("paged_decode_attention") == 3
+        assert _streamed_kernels(compiled) == 0
+        _assert_sampler_asks_its_operands(compiled, B, V)
+    else:
+        assert named("paged_decode_attention") == 0
+        assert _streamed_kernels(compiled) >= 1
+    print(key, "total", total, "temp", m.temp_size_in_bytes, "weights",
+          weights, "arena", arena)

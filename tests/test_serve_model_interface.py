@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3,
-                            falcon_h1, gpt, ling3, phi4flash)
+                            falcon_h1, gpt, lfm2_moe, ling3, phi4flash)
 from ray_tpu.serve._engine import ContinuousEngine, _check_interface
 
 MODELS = {
@@ -24,6 +24,7 @@ MODELS = {
     "dots3-note": (dots3, dots3.Dots3Config.nano()),
     "phi-4-flash": (phi4flash, phi4flash.Phi4FlashConfig.nano()),
     "falcon-h1": (falcon_h1, falcon_h1.FalconH1Config.nano()),
+    "lfm2-moe": (lfm2_moe, lfm2_moe.Lfm2MoeConfig.nano()),
 }
 REQUIRED = ("cache_kinds", "init_paged_cache", "paged_decode_step",
             "paged_prefill", "serve_view")
