@@ -230,6 +230,22 @@ def route_sigmoid_grouped(h: jax.Array, router_w: jax.Array, bias: jax.Array,
 # gate and up side by side, N = 2F = 3 x 512, tile whole — which is why
 # `held_expert_ffn` takes them as ONE leaf `[count, D, 2F]` where a model
 # lays them so.
+#
+# What a trip costs beside its products (PR 64, read by instruction with
+# `scripts/study_moe_row_block.py`, `chiprun_out/pr64/`; LFM2's chunk: 512
+# rows, top-4, 32 of 32 held, D 2,048, 22 trips a layer): 15.9 us, of
+# which 7.6 are its scatter-add into the [N, D] sum, 2.7 its gather of
+# h's rows, ~4 the two products' `ragged-dot-metadata` and 1.5 the loop's
+# own turn.  Gather and scatter-add are priced A ROW (21 ns a gathered
+# bf16 row of 2,048, 59-74 ns a scatter-added f32 one, in a 128-row call
+# as in a 640-row one), so ONE gather and ONE scatter-add over a block of
+# trips cost what the trips' own did and more for the buffers between
+# (measured: 0.349 -> 0.498 ms a layer outside the products), and products
+# with a block's 0/1 matrix on the MXU cost 16 + 42 ns a row at N = 512
+# but GROW WITH N (a 2,048-row program +17%).  The trip therefore keeps
+# its gather and its scatter-add; what a layer paid ONCE before its trips
+# (an argsort, a gather of the weights, a scatter of N*k ones: 0.045 ms at
+# that shape, 0.078 at Ling's) is one sort and a comparison now.
 ROW_BLOCK = 128
 
 
@@ -253,7 +269,8 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
     F = 4,096 tiles whole either way); `deepseek_v3.layer_ffn`'s three
     models lay the leaf.  Both are the same sums: operands in h's dtype,
     f32 accumulation over D.
-    The N*k pairs are sorted by held expert (pairs on absent experts last)
+    The N*k pairs are sorted by held expert (pairs on absent experts last;
+    one stable sort that carries each pair's token and weight along)
     and go through grouped matrix products (`jax.lax.ragged_dot`) a trip at
     a time, for as many trips as the pairs of held experts ask — a traced
     trip count, so a chunk whose pairs mostly fall elsewhere costs what
@@ -278,14 +295,20 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
     if live is not None:
         held = held & live[:, None]
     key = jnp.where(held, local, count).reshape(N * k)
-    order = jnp.argsort(key, stable=True)                  # held pairs first
-    loads = jnp.zeros(count + 1, jnp.int32).at[key].add(1)[:count]
+    # held pairs first, by expert, a token's in its own order: ONE stable
+    # sort carries each pair's token and weight to its sorted row, and the
+    # loads are counted by comparison (the comment above ROW_BLOCK)
+    _, tok_of, w_of = jax.lax.sort(
+        (key, jnp.arange(N * k, dtype=jnp.int32) // k,
+         weights.reshape(N * k)), num_keys=1)
+    loads = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
     ends = jnp.cumsum(loads)
     starts = ends - loads
     n_held = ends[-1]
     M = min(int(tile), N * k, ROW_BLOCK)
-    tok_of = jnp.pad(order // k, (0, M))                # sorted row -> token
-    w_of = jnp.pad(weights.reshape(N * k)[order], (0, M))
+    tok_of = jnp.pad(tok_of, (0, M))                    # sorted row -> token
+    w_of = jnp.pad(w_of, (0, M))
     dt = h.dtype
 
     def one_trip(carry):

@@ -1,38 +1,48 @@
-"""`ops/moe.held_expert_ffn` alone at the three MoE cells' shapes, by the
-sorted rows one grouped product takes (PERF.md section 6, PR 53).
+"""`ops/moe.held_expert_ffn` alone at the MoE cells' shapes, by the sorted
+rows one grouped product takes (PERF.md section 6, PR 53) and by what a
+trip costs outside its products (PR 64).
 
 On the chip, one process: one expert layer's held part, jitted, bf16,
 seeded uniform top-k routing at the cell's held share, for a Ling chunk
-and step, a DeepSeek chunk and step and a Command A+ chunk (SHAPES).  Each
+and step, a DeepSeek chunk and step, a Command A+ chunk and an LFM2 chunk
+and step (SHAPES).  Each
 side is called `--iters` times under a profiler trace; the `ragged-dot`
 self time a call comes from the trace by the benchmark's own reduction
 (`benchmarks/trace/reduce.py`, the pattern of `moe.experts_roofline.*`),
 the whole program's device time from the trace's `XLA Modules` line, the
 least time from `benchmarks/lib/costs_moe.least_seconds` on the call's own
-pairs and touched experts.
+pairs and touched experts.  `outside_ms` is the program less its
+`ragged-dot*` ops, `outside_us_a_trip` that over the call's trips (walked
+on the host from the loads, by the function's own rule: a trip ends where
+an expert ends) and `outside_ops` the instructions that make it, each
+with its count a call, its ms a call and the head of its HLO text.
 
 A side is a module and the rows it gives a product: a module WITHOUT a
 `ROW_BLOCK` (the function before PR 53: trips cut at fixed offsets, `tile`
 rows each) is read at `--tiles`; a module WITH one (trips end where an
 expert ends) at `--blocks`, the constant patched, under the cells' own
 `tile` of 512.  `--parent DIR` adds the `ray_tpu/ops/moe.py` of another
-checkout beside the tree's.  A module whose `held_expert_ffn` takes gate and
+checkout beside the tree's, `--module LABEL=PATH` (repeatable) a variant's
+`moe.py`.  A module whose `held_expert_ffn` takes gate and
 up as ONE leaf [held, D, 2F] with no up operand (PR 62: its `w_up` is
 Optional) is read in both layouts, `<label>` with the two apart (three
 products a trip) and `<label>+one_leaf` (two).  Every side's result is
 held to the first's.
 
-    python scripts/study_moe_row_block.py [--parent _parent] [--iters 10]
+    python scripts/study_moe_row_block.py [--parent _parent [--no-tree]]
+        [--module cand=/path/moe.py] [--iters 10]
         [--tiles 64 128 256 512] [--blocks 64 128 256] [--only ling_chunk]
+        [--hlo]
 
 `--toy` runs the control flow at toy sizes on the CPU (no times).  Writes
-chiprun_out/<--out, pr53>/study_moe_row_block[.<tag>].json.  Not wired into the
+chiprun_out/<--out, pr64>/study_moe_row_block[.<tag>].json.  Not wired into the
 benchmark.
 """
 import argparse
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -58,6 +68,8 @@ SHAPES = (
     ("deepseek_chunk", 512, 8, 16, 256, 7168, 2048, None),
     ("deepseek_step", 32, 8, 16, 256, 7168, 2048, 8),
     ("commandaplus_chunk", 512, 8, 16, 128, 4096, 4096, None),
+    ("lfm2_chunk", 512, 4, 32, 32, 2048, 1792, None),
+    ("lfm2_step", 64, 4, 32, 32, 2048, 1792, 13),
 )
 TOY = (("toy_chunk", 64, 4, 8, 16, 32, 16, None),
        ("toy_step", 8, 4, 8, 16, 32, 16, 3))
@@ -111,6 +123,28 @@ def sides_of(mods, tiles, blocks):
     return out
 
 
+def trips_of(loads, rows):
+    """The trips `held_expert_ffn` makes over sorted pairs of these loads
+    at `rows` sorted rows a trip, ending where an expert ends."""
+    ends = np.cumsum(loads)
+    lo = trips = 0
+    while lo < ends[-1]:
+        whole = ends[ends <= lo + rows].max(initial=0)
+        lo = whole if whole > lo else lo + rows
+        trips += 1
+    return trips
+
+
+def outside_ops(red, iters, top=14):
+    """The instructions that are no grouped product, longest first: [name,
+    executions a call, ms a call, the head of the HLO text]."""
+    rx = re.compile(OPS)
+    rest = [(o["s"], nm, o) for nm, o in red["ops"].items()
+            if not rx.search(o.get("text") or nm)]
+    return [[nm, o["n"] / iters, 1e3 * s / iters, o["text"][:160]]
+            for s, nm, o in sorted(rest, key=lambda r: -r[0])[:top]]
+
+
 def measure(fn, args, iters, toy):
     out = jax.block_until_ready(fn(*args))         # compile + warm
     if toy:
@@ -139,12 +173,18 @@ def measure(fn, args, iters, toy):
                  "ragged_dot_ops": n / iters,
                  "program_ms": 1e3 * whole / max(runs, 1),
                  "top_ops_ms": [[k, 1e3 * v / iters]
-                                for k, v in top_ops(red, 6)]}
+                                for k, v in top_ops(red, 8)],
+                 "outside_ops": outside_ops(red, iters)}
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent")
+    ap.add_argument("--no-tree", action="store_true",
+                    help="read --parent's module alone")
+    ap.add_argument("--module", action="append", default=[],
+                    metavar="LABEL=PATH",
+                    help="a variant's moe.py beside them (repeatable)")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--seed", type=int, default=53)
     ap.add_argument("--tiles", type=int, nargs="*",
@@ -152,7 +192,9 @@ def main():
     ap.add_argument("--blocks", type=int, nargs="*", default=[64, 128, 256])
     ap.add_argument("--only", nargs="*")
     ap.add_argument("--tag", default="")
-    ap.add_argument("--out", default="pr53")
+    ap.add_argument("--out", default="pr64")
+    ap.add_argument("--hlo", action="store_true",
+                    help="keep each side's compiled text beside the json")
     ap.add_argument("--toy", action="store_true")
     a = ap.parse_args()
 
@@ -163,6 +205,11 @@ def main():
     if a.parent:
         mods.insert(0, ("parent", load_moe(os.path.join(
             a.parent, "ray_tpu", "ops", "moe.py"), "moe_parent")))
+        if a.no_tree:
+            del mods[1:]
+    for i, spec in enumerate(a.module):
+        label, path = spec.split("=", 1)
+        mods.append((label, load_moe(path, f"moe_variant{i}")))
     rows = []
     record = {"device": {"platform": dev.platform, "kind": dev.device_kind},
               "iters": a.iters, "seed": a.seed, "rows": rows}
@@ -186,8 +233,12 @@ def main():
             # a new jit a side: the constant is read while tracing
             fn = jax.jit(lambda *t, mod=mod, tile=tile: mod.held_expert_ffn(
                 *t, first=0, tile=tile, live=live))
-            got, ms = measure(fn, one_leaf if one else tensors, a.iters,
-                              a.toy)
+            operands = one_leaf if one else tensors
+            got, ms = measure(fn, operands, a.iters, a.toy)
+            if a.hlo:
+                with open(os.path.join(
+                        d, f"{shape[0]}.{label}.hlo.txt"), "w") as f:
+                    f.write(fn.lower(*operands).compile().as_text())
             out, loads = np.asarray(got[0]), np.asarray(got[1])
             if ref is None:
                 ref = out
@@ -198,7 +249,13 @@ def main():
                                 / max(np.max(np.abs(ref)), 1e-30))}
             if len(got) > 2:
                 row["reads"] = int(got[2])
+            row["trips"] = (
+                trips_of(loads, min(tile, shape[1] * shape[2], block))
+                if block is not None else -(-pairs // tile))
             if ms:
+                row["outside_ms"] = ms["program_ms"] - ms["ragged_dot_ms"]
+                row["outside_us_a_trip"] = (1e3 * row["outside_ms"]
+                                            / max(row["trips"], 1))
                 least = costs_moe.least_seconds(
                     pairs, touched, {"hidden_size": shape[5],
                                      "intermediate_size": shape[6]}, pk)

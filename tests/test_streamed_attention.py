@@ -414,8 +414,12 @@ def _digest(text):
 # carried entry read where it stands; its step stays), PR 62 the four of
 # `deepseek-v3` and `ling-3` (the held experts' gate and up in one leaf: two
 # grouped products a trip) and, new with it, `dots3`'s two, which run the
-# same `layer_ffn`; `command-a-plus` keeps gate and up apart and its two
-# digests.  A PR that moves or renames Python functions
+# same `layer_ffn`; `command-a-plus` keeps gate and up apart and kept its
+# two digests; PR 64 the eight of the four models with routed experts
+# (`command-a-plus`, `deepseek-v3`, `ling-3`, `dots3`: what `ops/moe`'s
+# held experts do once a layer before their trips — one sort that carries
+# each pair's token and weight, the loads by comparison; the other six
+# stand).  A PR that moves or renames Python functions
 # leaves every digest alone (the text carries no source locations; their
 # kernels' source lines unmoved, the compile-cache keys stay too).  A PR
 # that edits one of these programs finds the new digest in the failure and
@@ -423,18 +427,18 @@ def _digest(text):
 PARENT_TEXT = {
     ("gpt2", "step"): "1921a8ec3c8503f9",
     ("gpt2", "chunk"): "74eee7fd9dc8f6cb",
-    ("command-a-plus", "step"): "7088563b12bffb20",
-    ("command-a-plus", "chunk"): "3860d574703a5237",
+    ("command-a-plus", "step"): "a994456c5c44057b",
+    ("command-a-plus", "chunk"): "00d18a2a1d712aba",
     ("brumby", "step"): "c95c739da7c26ef7",
     ("brumby", "chunk"): "3890e9f178cbbf96",
-    ("deepseek-v3", "step"): "df713cecbeb34064",
-    ("deepseek-v3", "chunk"): "e475b0816eeeb1d3",
-    ("ling-3", "step"): "5b7267d6cbbfb455",
-    ("ling-3", "chunk"): "91e1eba2c001a798",
+    ("deepseek-v3", "step"): "3f921a3e5c3478de",
+    ("deepseek-v3", "chunk"): "b0ba0dd299ec3dbc",
+    ("ling-3", "step"): "3b7d18ab33f98ee0",
+    ("ling-3", "chunk"): "0cced1068f44c019",
     ("phi-4-flash", "step"): "d0a2de26cb6e8b1b",
     ("phi-4-flash", "chunk"): "ce1a43a62b84b2a7",
-    ("dots3", "step"): "68e0d3c34b2b3495",
-    ("dots3", "chunk"): "4fef0a8a279a133f",
+    ("dots3", "step"): "761cd07b8a152eca",
+    ("dots3", "chunk"): "2d9a40e82f47d611",
 }
 
 
