@@ -44,10 +44,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.layers import apply_rope_halves, rms_norm, swiglu
-from ray_tpu.ops.retention import (resolve_impl, retention_chunk,
-                                   retention_step, state_shape)
+from ray_tpu.ops.retention import (retention_chunk, retention_step,
+                                   state_shape)
 
 from .gpt import cast_leaves
+from .served import states_moved
 
 __all__ = ["BrumbyConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
@@ -275,8 +276,7 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: BrumbyConfig):
     (x, cache), _ = jax.lax.scan(
         layer_of, (_embed(params, tokens, cfg)[:, None], cache),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    moved = (live.sum() if resolve_impl(cfg.retention_impl) != "xla"
-             else jnp.asarray(B))
+    moved = states_moved(live, cfg.retention_impl)
     return (_logits(params, x[:, 0], cfg), cache,
             moved.astype(jnp.float32).reshape(1))
 

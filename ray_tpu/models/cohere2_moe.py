@@ -46,6 +46,7 @@ from ray_tpu.ops.moe import (held_expert_ffn, held_load_stats,
 
 from .gpt import (apply_norm, attn_out, cast_leaves, qkv_of_normed,
                   slot_embed, unembed_table)
+from .served import kind_io, page_blocks
 
 __all__ = ["Cohere2MoEConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
@@ -259,9 +260,8 @@ def apply(params, tokens, cfg: Cohere2MoEConfig):
 
 def cache_kinds(cfg: Cohere2MoEConfig) -> Dict[str, Optional[int]]:
     """name -> window of the pools the engine keeps for this model (see
-    gpt.cache_kinds).  A windowed kind's table is a RING: logical page lp
-    (positions lp*ps ..) sits in entry lp % R, R the table's width, which
-    the engine sizes to window + its longest prefill chunk."""
+    gpt.cache_kinds).  A windowed kind's table is a RING (`served.kind_io`)
+    that the engine sizes to window + its longest prefill chunk."""
     return {k: _window(k, cfg) for k in ("full", "sliding")
             if k in cfg.layer_types}
 
@@ -282,20 +282,6 @@ def init_paged_cache(cfg: Cohere2MoEConfig, num_pages: Dict[str, int],
     return [arena(kind) for kind in cfg.layer_types]
 
 
-def _entry_bases(kind: str, tab, last_pos, ps: int, width: int):
-    """First position held by each entry of a page table tab [B, R],
-    padded to `width` entries: [B, width], negative where the entry holds
-    nothing a query at or before last_pos [B] may see.  A full table is
-    in sequence order; a windowed one is the ring of cache_kinds."""
-    B, R = tab.shape
-    e = jnp.arange(width, dtype=jnp.int32)[None]
-    if kind == "full":
-        return jnp.broadcast_to(e * ps, (B, width))
-    hi = (last_pos // ps)[:, None]
-    lp = hi - (hi - e) % R
-    return jnp.where((e < R) & (lp >= 0), lp * ps, -1)
-
-
 def _paged_attend(kind, arena, tab, bases, qpos, write_at, n_blocks,
                   cfg: Cohere2MoEConfig):
     """attend() of _block against one layer's arena: write this call's K
@@ -314,43 +300,12 @@ def _paged_attend(kind, arena, tab, bases, qpos, write_at, n_blocks,
         kc = arena["k"].at[pidx, poff].set(rows(k).astype(cfg.dtype))
         vc = arena["v"].at[pidx, poff].set(rows(v).astype(cfg.dtype))
         box["arena"] = {"k": kc, "v": vc}
-
-        def fetch(i):
-            t = jax.lax.dynamic_slice_in_dim(tab, i * npb, npb, 1)
-            b = jax.lax.dynamic_slice_in_dim(bases, i * npb, npb, 1)
-            gather = lambda c: jnp.moveaxis(c[t].reshape(
-                B, npb * ps, cfg.n_kv_heads, cfg.d_head), 2, 1)
-            kpos = jnp.where(
-                b[:, :, None] >= 0,
-                b[:, :, None] + jnp.arange(ps, dtype=jnp.int32), -1)
-            return gather(kc), gather(vc), kpos.reshape(B, npb * ps)
-
+        fetch = page_blocks(tab, bases, kc, vc, npb, lambda c: jnp.moveaxis(
+            c.reshape(B, npb * ps, cfg.n_kv_heads, cfg.d_head), 2, 1))
         return streamed_attention(q, qpos, fetch, n_blocks,
                                   window=_window(kind, cfg))
 
     return attend, box
-
-
-def kind_io(kind: str, tab, pos, real, last, flat_pos, ps: int, npb: int):
-    """How rows at positions pos [B, T] (`real` marks those whose K and V
-    are kept; `last` [B] their greatest, `flat_pos` [B * T] themselves)
-    meet the page table tab [B, R] of one kind ("full", or a ring): (the
-    table padded to whole blocks of `npb` pages, its entries' bases, the
-    (page, offset) each row is written at — the null page for a row that
-    is not kept —, the key blocks to stream)."""
-    R = tab.shape[1]
-    width = -(-R // npb) * npb
-    tabp = jnp.pad(tab, ((0, 0), (0, width - R)))
-    lp = pos // ps
-    entry = lp if kind == "full" else lp % R
-    page = jnp.take_along_axis(
-        tabp, jnp.minimum(entry, width - 1), axis=1)
-    page = jnp.where(real & (entry < R), page, 0).reshape(flat_pos.shape)
-    n_blocks = (width // npb if kind != "full" else
-                jnp.minimum(jnp.max(last) // (npb * ps) + 1,
-                            width // npb))
-    return (tabp, _entry_bases(kind, tab, last, ps, width),
-            (page, flat_pos % ps), n_blocks)
 
 
 def _paged_pass(params, cache, toks, ptabs, pos, real, cfg):

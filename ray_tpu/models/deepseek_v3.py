@@ -65,9 +65,10 @@ from ray_tpu.ops.attention import (latent_decode_attention,
                                    latent_walked_keys, streamed_attention)
 from ray_tpu.ops.layers import (apply_rope_interleaved, rms_norm, swiglu,
                                 yarn_frequencies)
-from ray_tpu.ops.moe import (held_expert_ffn, held_load_stats,
-                             route_sigmoid_grouped)
+from ray_tpu.ops.moe import (held_expert_ffn, held_experts_leaf,
+                             held_load_stats, route_sigmoid_grouped)
 
+from . import served
 from .gpt import cast_leaves, slot_embed, unembed_table
 
 __all__ = ["DeepSeekV3Config", "init", "apply", "cache_kinds",
@@ -100,10 +101,6 @@ KIND = "full"
 # 512-row chunk costs 6.7 ms a thousand keys of context expanded and 10.4
 # absorbed (PERF.md section 6, PR 44).
 ABSORB_ROWS = 128
-
-# Values one program of `init` draws: part of the draw's recipe (a leaf's
-# VALUES depend on it), so a constant and no setting.
-DRAW_PIECE = 1 << 22
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,51 +179,23 @@ class DeepSeekV3Config:
         return cls(**base)
 
 
-# the draw, leaf by leaf.  A leaf's values are `DRAW_PIECE` standard normals
-# at a time — piece i of the leaf at `place` of layer l from the key
-# fold_in(fold_in(fold_in(root, 1 + l), place), i) (root: the caller's two
-# key words as an "rbg" key), times the leaf's std in
-# f32, rounded to its dtype — laid end to end and cut to the leaf's size:
-# ONE small program makes every leaf of every layer (a program a leaf and
-# layer, each with its own threefry over up to 235M values, took the chip's
-# compiler minutes on a cold start).  The two vocabulary tables are places
-# 0 and 1 of "layer" -1; norms are ones and the correction bias zeros (a
+# the draw is `served.draw`'s recipe (it began here; `_draw` below), a leaf's
+# place its index here; norms are ones and the correction bias zeros (a
 # fresh router's).  `wg` and `wu` keep their places in the recipe (the
 # benchmark's reference draws them there) and lie side by side in ONE leaf
-# of the tree, `wgu` [held, D, 2F]: `held_experts_leaf`.
+# of the tree, `wgu` [held, D, 2F]: `ops.moe.held_experts_leaf`.
 LEAVES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down",
           "router", "wg", "wu", "wd", "shared_gate", "shared_up",
           "shared_down")
 
 
-@functools.partial(jax.jit, static_argnames=("n", "dtype"))
-def _piece(key, layer, place, i, std, n, dtype):
-    # the chip's own bit generator ("rbg": the key's two words twice over):
-    # threefry's arithmetic over 4.6e9 values is 1.5 s of a replica's start
-    k = jax.random.wrap_key_data(jnp.concatenate([key, key]), impl="rbg")
-    k = jax.random.fold_in(jax.random.fold_in(jax.random.fold_in(
-        k, 1 + layer), place), i)
-    return (jax.random.normal(k, (n,), jnp.float32) * std).astype(dtype)
-
-
 def _draw(key, layer: int, place: int, shape, std: float, dtype):
-    size = math.prod(shape)
-    parts = [_piece(key, layer, place, i, jnp.float32(std), DRAW_PIECE,
-                    jnp.dtype(dtype)) for i in range(-(-size // DRAW_PIECE))]
+    # `served.draw`'s values, a dispatch a piece (PERF.md section 6, PR 65)
+    size, n = math.prod(shape), served.DRAW_PIECE
+    parts = [served.piece(key, layer, place, i, jnp.float32(std), n,
+                          jnp.dtype(dtype)) for i in range(-(-size // n))]
     flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
     return flat[:size].reshape(shape)
-
-
-def held_experts_leaf(wg, wu):
-    """The held experts' gate and up matrices [held, D, F] as the one leaf
-    `ops/moe.held_expert_ffn` multiplies by, [held, D, 2F]: gate in columns
-    [:F], up in [F:].  Laid where the layer is drawn and not in a view
-    beside the tree, which would hold both twice — and waited for: the
-    host runs ahead of the draw, and every layer's `wg` and `wu` would
-    stand beside its leaf until the device got to them (Ling's loader
-    peaked at 13.11e9 B so, 11.99e9 waiting, 12.10e9 with the two
-    apart)."""
-    return jax.block_until_ready(jnp.concatenate([wg, wu], axis=-1))
 
 
 def init_layer(key, cfg: DeepSeekV3Config, l: int) -> Dict[str, Any]:

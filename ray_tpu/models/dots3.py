@@ -71,12 +71,13 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import indexer_scores
 from ray_tpu.ops.layers import apply_rope_interleaved, layer_norm, rms_norm
-from ray_tpu.ops.moe import held_load_stats
+from ray_tpu.ops.moe import held_experts_leaf, held_load_stats
 from ray_tpu.ops.select import keep_top
 
 from . import deepseek_v3 as _dm
 from .gpt import cast_leaves, slot_embed
-from .ling3 import _draw
+# benchmarks/drivers/replica_dots3.py:23 imports `_draw` (ROADMAP D17)
+from .served import draw as _draw
 
 __all__ = ["Dots3Config", "init", "apply", "cache_kinds", "init_paged_cache",
            "paged_decode_step", "paged_prefill", "serve_view",
@@ -215,14 +216,11 @@ class Dots3Config:
         return cls(**base)
 
 
-# the draw is deepseek_v3's recipe as ling3 runs it (`ling3._draw`: pieces of
-# DRAW_PIECE standard normals from fold_in(fold_in(fold_in(root, 1 + layer),
-# place), i), times the leaf's std, rounded to its dtype, end to end, cut to
-# the leaf's size), a leaf's place its index here.  Norms are ones, the
-# indexer's LayerNorm bias and the correction bias zeros (a benchmark's
-# loader draws what a checkpoint would hold there).  `wg` and `wu` keep
-# their places in the recipe and lie in ONE leaf of the tree, `wgu`
-# (`deepseek_v3.held_experts_leaf`).
+# the draw is `served.draw`, a leaf's place its index here.  Norms are
+# ones, the indexer's LayerNorm bias and the correction bias zeros (a
+# benchmark's loader draws what a checkpoint would hold there).  `wg` and
+# `wu` keep their places in the recipe and lie in ONE leaf of the tree,
+# `wgu` (`ops.moe.held_experts_leaf`).
 LEAVES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_head_gate", "wi_q",
           "wi_k", "wi_w", "w_gate", "w_up", "w_down", "router", "wg", "wu",
           "wd", "shared_gate", "shared_up", "shared_down")
@@ -265,8 +263,7 @@ def init_layer(key, cfg: Dots3Config, l: int) -> Dict[str, Any]:
     layer.update(
         router=w("router", (D, cfg.n_experts), D, dtype=jnp.float32),
         router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-        wgu=_dm.held_experts_leaf(w("wg", (C, D, F), D),
-                                  w("wu", (C, D, F), D)),
+        wgu=held_experts_leaf(w("wg", (C, D, F), D), w("wu", (C, D, F), D)),
         wd=w("wd", (C, F, D), F, out),
         shared_gate=w("shared_gate", (D, S * F), D),
         shared_up=w("shared_up", (D, S * F), D),

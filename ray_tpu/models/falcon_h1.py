@@ -52,16 +52,14 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import (latent_decode_uses_kernel,
-                                   paged_decode_attention, streamed_attention)
+from ray_tpu.ops.attention import streamed_attention
 from ray_tpu.ops.layers import apply_rope_halves, rms_norm
 from ray_tpu.ops.shortconv import conv_chunk, conv_step
-from ray_tpu.ops.ssd import resolve_impl, ssd_chunk, ssd_step
+from ray_tpu.ops.ssd import ssd_chunk, ssd_step
 
-from . import deepseek_v3 as _dm
-from .cohere2_moe import kind_io
+from . import served
 from .gpt import cast_leaves
-from .ling3 import _carried_at, _draw
+from .served import attend_pages, carried_at, draw, kind_io, states_moved
 
 __all__ = ["FalconH1Config", "init", "init_layer", "init_top", "table_rows",
            "fold_layer", "fold_table", "fold_top", "apply", "cache_kinds",
@@ -173,14 +171,11 @@ class FalconH1Config:
         return cls(**base)
 
 
-# the draw is deepseek_v3's recipe through `ling3._draw` (standard normals
-# a piece at a time from the key, the layer and the leaf's place, times the
-# leaf's std), a leaf's place its index here; the two vocabulary tables
-# are places 0 and 1 of layer -1.  Norm weights are ones, the conv's bias
-# zeros; A, D and the step-size bias take Mamba-2's own initialisation
-# (float32): A uniform in [1, 16] a head, D = 1, softplus(dt_bias)
-# log-uniform in [DT_MIN, DT_MAX] (a uniform is the normal draw through
-# its own distribution function).
+# the draw is `served.draw`, a leaf's place its index here.  Norm weights
+# are ones, the conv's bias zeros; A, D and the step-size bias take
+# Mamba-2's own initialisation (float32): A uniform in [1, 16] a head,
+# D = 1, softplus(dt_bias) log-uniform in [DT_MIN, DT_MAX] (a uniform is
+# the normal draw through its own distribution function).
 LEAVES = ("w_in", "conv_w", "a_log", "dt_bias", "w_out", "wq", "wk", "wv",
           "wo", "w_gate_up", "w_down")
 DT_MIN, DT_MAX = 1e-3, 1e-1
@@ -198,7 +193,7 @@ def init_layer(key, cfg: FalconH1Config, l: int) -> Dict[str, Any]:
     f32 = jnp.float32
 
     def w(name, shape, fan_in, scale=1.0, dtype=pd):
-        return _draw(key, l, LEAVES.index(name), shape,
+        return draw(key, l, LEAVES.index(name), shape,
                      scale / math.sqrt(fan_in), dtype)
 
     uniform = lambda name: jax.scipy.special.ndtr(
@@ -225,10 +220,9 @@ def init_layer(key, cfg: FalconH1Config, l: int) -> Dict[str, Any]:
 
 @functools.partial(jax.jit, static_argnames=("count", "n", "dtype"))
 def _piece_run(key, place, std, first, count, n, dtype):
-    """Pieces first..first+count-1 of a vocabulary table, end to end
-    (`ling3._pieces` from a piece other than the first)."""
+    """Pieces first..first+count-1 of a table (`served.pieces`, offset)."""
     return jax.lax.map(
-        lambda i: _dm._piece(key, -1, place, first + i, std, n, dtype),
+        lambda i: served.piece(key, -1, place, first + i, std, n, dtype),
         jnp.arange(count)).reshape(-1)
 
 
@@ -242,7 +236,7 @@ def table_rows(key, cfg: FalconH1Config, name: str, i: int = 0,
     `lm_head` at 1/sqrt(D) — from the pieces of the draw that hold those
     rows and no others (whole, a table is 2.67e9 B at the published
     sizes: a caller short of memory makes it a slice at a time)."""
-    V, D, n = cfg.vocab_size, cfg.d_model, _dm.DRAW_PIECE
+    V, D, n = cfg.vocab_size, cfg.d_model, served.DRAW_PIECE
     std = 0.02 if name == "embed" else 1.0 / math.sqrt(D)
     rows = V // parts
     lo, hi = i * rows * D, (i + 1) * rows * D
@@ -562,40 +556,6 @@ def state_leaves(cache) -> List[jax.Array]:
     return [cache["state"], cache["tail"]]
 
 
-def _attend_pages(q, k, v, kc, vc, io, qpos, cfg: FalconH1Config, ctx=None):
-    """Write this call's K and V rows ([B, Hkv, T, dh]) at the (page,
-    offset) of `io`, then attend: a row a slot on a TPU (`ctx` [B]: the
-    keys each slot's row sees, 0 for an empty slot) walks each slot's own
-    pages where they lie (`ops.attention.paged_decode_attention`, five
-    query rows a key head); a chunk, and the CPU, stream the table's pages
-    `kv_block` keys at a time.  -> (o [B, Hkv, G, T, dh], kc, vc)."""
-    tab, bases, (pidx, poff), n_blocks = io
-    ps = kc.shape[1]
-    npb = max(1, cfg.kv_block // ps)
-    B, Hkv, T, dh = k.shape
-    rows = lambda a: jnp.moveaxis(a, 1, 2).reshape(B * T, Hkv * dh).astype(
-        cfg.dtype)
-    kc = kc.at[pidx, poff].set(rows(k))
-    vc = vc.at[pidx, poff].set(rows(v))
-    scale = cfg.d_head ** -0.5
-    if ctx is not None and latent_decode_uses_kernel(T):
-        o = paged_decode_attention(q[:, :, :, 0], kc, vc, tab, bases,
-                                   qpos[:, 0], -(-ctx // ps), scale=scale)
-        return o[:, :, :, None], kc, vc
-
-    def fetch(i):
-        t = jax.lax.dynamic_slice_in_dim(tab, i * npb, npb, 1)
-        b = jax.lax.dynamic_slice_in_dim(bases, i * npb, npb, 1)
-        gather = lambda c: jnp.moveaxis(
-            c[t].reshape(B, npb * ps, Hkv, dh), 2, 1)
-        kpos = jnp.where(b[:, :, None] >= 0,
-                         b[:, :, None] + jnp.arange(ps, dtype=jnp.int32), -1)
-        return gather(kc), gather(vc), kpos.reshape(B, npb * ps)
-
-    return (streamed_attention(q, qpos, fetch, n_blocks, scale=scale),
-            kc, vc)
-
-
 def _paged_pass(params, cache, toks, tab, pos, real, ssd_layer, scope: str,
                 cfg: FalconH1Config, ctx=None):
     """Tokens toks [B, T] at CONSECUTIVE positions pos [B, T] through the
@@ -616,8 +576,8 @@ def _paged_pass(params, cache, toks, tab, pos, real, ssd_layer, scope: str,
         ssm, state, tail = ssd_layer(l, n, layer, state, tail)
         q, k, v = _qkv(n, layer, pos, cfg)
         with jax.named_scope(scope):
-            o, ks[l], vs[l] = _attend_pages(q, k, v, ks[l], vs[l], io, pos,
-                                            cfg, ctx)
+            o, ks[l], vs[l] = attend_pages(q, k, v, ks[l], vs[l], io, pos,
+                                           cfg, ctx)
         x = x + (ssm + _attn_out(o, layer, cfg)).astype(x.dtype)
         x = _mlp(x, layer, cfg)
     return x, {"k": ks, "v": vs, "state": state, "tail": tail}
@@ -636,7 +596,6 @@ def paged_decode_step(params, cache, tokens, ptabs, pos,
     A slot at position 0 is empty (a prompt has at least one token): it
     writes to the null page and leaves the null entry as it is.  Returns
     (logits [B, V] f32, cache, stats)."""
-    B = tokens.shape[0]
     idx, live = ptabs[SSM][:, 0], pos > 0
 
     def ssd_layer(l, n, layer, state, tail):
@@ -656,8 +615,7 @@ def paged_decode_step(params, cache, tokens, ptabs, pos,
     x, cache = _paged_pass(params, cache, tokens[:, None], ptabs[FULL],
                            pos[:, None], live[:, None], ssd_layer,
                            "attn_step", cfg, ctx)
-    moved = (live.sum() if resolve_impl(cfg.ssd_impl) != "xla"
-             else jnp.asarray(B))
+    moved = states_moved(live, cfg.ssd_impl)
     return _head(params, x[:, 0], cfg), cache, _stats(moved, ctx.sum(), cfg)
 
 
@@ -677,8 +635,8 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
     first = start == 0
 
     def ssd_layer(l, n, layer, state, tail):
-        s0 = _carried_at(first, state, l, idx)
-        t0 = _carried_at(first, tail, l, idx)
+        s0 = carried_at(first, state, l, idx)
+        t0 = carried_at(first, tail, l, idx)
         y, s1, pre = _ssd_sequence(n[0], layer, real, s0, t0, cfg)
         t1 = jax.lax.dynamic_slice_in_dim(
             jnp.concatenate([t0, pre.astype(tail.dtype)]), last_idx + 1,

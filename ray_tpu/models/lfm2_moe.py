@@ -53,15 +53,12 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.layers import apply_rope_halves, rms_norm
-from ray_tpu.ops.moe import (held_expert_ffn, held_load_stats,
-                             route_sigmoid_topk)
+from ray_tpu.ops.moe import (held_expert_ffn, held_experts_leaf,
+                             held_load_stats, route_sigmoid_topk)
 from ray_tpu.ops.shortconv import conv_chunk, conv_step
 
-from .cohere2_moe import kind_io
-from .deepseek_v3 import held_experts_leaf
-from .falcon_h1 import _attend_pages
 from .gpt import cast_leaves, slot_embed
-from .ling3 import _carried_at, _draw
+from .served import attend_pages, carried_at, draw, kind_io
 
 __all__ = ["Lfm2MoeConfig", "init", "init_layer", "init_top", "apply",
            "cache_kinds", "init_paged_cache", "paged_decode_step", "paged_prefill",
@@ -162,13 +159,11 @@ class Lfm2MoeConfig:
         return cls(**base)
 
 
-# the draw is deepseek_v3's recipe through `ling3._draw` (standard normals
-# a piece at a time from the key, the layer and the leaf's place, times the
-# leaf's std), a leaf's place its index here; the embedding is place 0 of
-# layer -1.  Norm weights are ones and the expert bias zeros (a fresh
-# router's).  `wg` and `wu` keep their places in the recipe (the
-# benchmark's reference draws them apart) and lie side by side in ONE leaf
-# of the tree, `wgu` [held, D, 2F] (`deepseek_v3.held_experts_leaf`).
+# the draw is `served.draw`, a leaf's place its index here.  Norm weights
+# are ones and the expert bias zeros (a fresh router's).  `wg` and `wu`
+# keep their places in the recipe (the benchmark's reference draws them
+# apart) and lie side by side in ONE leaf of the tree, `wgu` [held, D, 2F]
+# (`ops.moe.held_experts_leaf`).
 LEAVES = ("w_in", "conv_w", "w_out", "w_qkv", "wo", "w_gate_up", "w_down",
           "router", "wg", "wu", "wd")
 
@@ -181,7 +176,7 @@ def init_layer(key, cfg: Lfm2MoeConfig, l: int) -> Dict[str, Any]:
     out = 1.0 / math.sqrt(2 * cfg.n_layers)
 
     def w(name, shape, fan_in, scale=1.0, dtype=pd):
-        return _draw(key, l, LEAVES.index(name), shape,
+        return draw(key, l, LEAVES.index(name), shape,
                      scale / math.sqrt(fan_in), dtype)
 
     layer = {"norm": jnp.ones((D,), pd), "ffn_norm": jnp.ones((D,), pd)}
@@ -213,7 +208,7 @@ def init_layer(key, cfg: Lfm2MoeConfig, l: int) -> Dict[str, Any]:
 def init_top(key, cfg: Lfm2MoeConfig) -> Dict[str, Any]:
     """What stands outside the layers: the embedding (the head too: tied)
     and the last norm."""
-    return {"embed": _draw(key, -1, 0, (cfg.vocab_size, cfg.d_model), 0.02,
+    return {"embed": draw(key, -1, 0, (cfg.vocab_size, cfg.d_model), 0.02,
                            cfg.param_dtype),
             "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype)}
 
@@ -440,8 +435,8 @@ def _paged_pass(params, cache, toks, tab, pos, real, conv_layer, scope: str,
         else:
             q, k, v = _qkv(n, layer, pos, cfg)
             with jax.named_scope(scope):
-                o, ks[a], vs[a] = _attend_pages(q, k, v, ks[a], vs[a], io,
-                                                pos, cfg, ctx)
+                o, ks[a], vs[a] = attend_pages(q, k, v, ks[a], vs[a], io,
+                                               pos, cfg, ctx)
             mix = _attn_out(o, layer, cfg)
             a += 1
         x, ld = _ffn(x + mix.astype(x.dtype), layer, cfg, live=real)
@@ -491,7 +486,7 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
     first = start == 0
 
     def conv_layer(j, n, layer, tail):
-        t0 = _carried_at(first, tail, j, idx)
+        t0 = carried_at(first, tail, j, idx)
         y, z = _conv_sequence(n[0], layer, t0, cfg)
         with jax.named_scope("short_conv"):
             t1 = jax.lax.dynamic_slice_in_dim(

@@ -52,20 +52,23 @@ vocabulary, `n_layers` layers of which the first `n_dense` are dense.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.kda import kda_chunk, kda_step, resolve_impl
+from ray_tpu.ops.kda import kda_chunk, kda_step
 from ray_tpu.ops.layers import apply_rope_interleaved, rms_norm
 from ray_tpu.ops.shortconv import conv_chunk, conv_step
-from ray_tpu.ops.moe import held_load_stats
+from ray_tpu.ops.moe import held_experts_leaf, held_load_stats
 
 from . import deepseek_v3 as _dm
 from .gpt import cast_leaves, slot_embed
+# benchmarks/drivers/replica_ling3.py:28, replica_lfm2_moe.py:39 and
+# replica_falcon_h1.py:40 import `_draw` (ROADMAP D17)
+from .served import draw as _draw
+from .served import carried_at, states_moved
 
 __all__ = ["Ling3Config", "init", "apply", "cache_kinds", "init_paged_cache",
            "paged_decode_step", "paged_prefill", "serve_view", "state_leaves",
@@ -175,36 +178,14 @@ class Ling3Config:
         return cls(**base)
 
 
-# the draw is deepseek_v3's recipe (`_piece`: DRAW_PIECE standard normals
-# from fold_in(fold_in(fold_in(root, 1 + layer), place), i), times the
-# leaf's std, rounded to its dtype; the pieces laid end to end and cut to
-# the leaf's size), a leaf's place its index here.  Norms are ones; the
-# conv's bias, the correction bias, A_log and dt_bias zeros (a benchmark's
-# loader draws what a checkpoint would hold there).  `wg` and `wu` keep
-# their places in the recipe and lie in ONE leaf of the tree, `wgu`
-# (`deepseek_v3.held_experts_leaf`).
+# the draw is `served.draw`, a leaf's place its index here.  Norms are
+# ones; the conv's bias, the correction bias, A_log and dt_bias zeros (a
+# benchmark's loader draws what a checkpoint would hold there).  `wg` and
+# `wu` keep their places in the recipe and lie in ONE leaf of the tree,
+# `wgu` (`ops.moe.held_experts_leaf`).
 LEAVES = ("w_qkv", "conv_w", "w_f", "w_b", "w_g", "wo", "wq", "wkv_a",
           "wkv_b", "w_head_gate", "w_gate", "w_up", "w_down", "router", "wg",
           "wu", "wd", "shared_gate", "shared_up", "shared_down")
-
-
-@functools.partial(jax.jit, static_argnames=("count", "n", "dtype"))
-def _pieces(key, layer, place, std, count, n, dtype):
-    """Pieces 0..count-1 of a leaf, end to end: `deepseek_v3._piece`'s
-    values one after the other inside ONE program (a 128-expert stack is
-    180 pieces and this tree 3,600: a dispatch each was 3 s of a start).
-    A loop and not a `vmap`: the generator's batched draws are other
-    draws."""
-    return jax.lax.map(
-        lambda i: _dm._piece(key, layer, place, i, std, n, dtype),
-        jnp.arange(count)).reshape(-1)
-
-
-def _draw(key, layer: int, place: int, shape, std: float, dtype):
-    size, n = math.prod(shape), _dm.DRAW_PIECE
-    flat = _pieces(key, layer, place, jnp.float32(std), -(-size // n), n,
-                   jnp.dtype(dtype))
-    return flat[:size].reshape(shape)
 
 
 def init_layer(key, cfg: Ling3Config, l: int) -> Dict[str, Any]:
@@ -248,8 +229,7 @@ def init_layer(key, cfg: Ling3Config, l: int) -> Dict[str, Any]:
     layer.update(
         router=w("router", (D, cfg.n_experts), D, dtype=jnp.float32),
         router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-        wgu=_dm.held_experts_leaf(w("wg", (C, D, F), D),
-                                  w("wu", (C, D, F), D)),
+        wgu=held_experts_leaf(w("wg", (C, D, F), D), w("wu", (C, D, F), D)),
         wd=w("wd", (C, F, D), F, out),
         shared_gate=w("shared_gate", (D, S), D),
         shared_up=w("shared_up", (D, S), D),
@@ -480,7 +460,6 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: Ling3Config,
     A slot at position 0 is empty (a prompt has at least one token): it
     writes to the null page, routes nowhere and leaves the null entry as
     it is.  Returns (logits [B, V] f32, cache, stats)."""
-    B = tokens.shape[0]
     idx, live = ptabs[KDA][:, 0], pos > 0
 
     def kda_layer(j, x, h, layer, state, tail):
@@ -498,29 +477,9 @@ def paged_decode_step(params, cache, tokens, ptabs, pos, cfg: Ling3Config,
     x, cache, held = _paged_pass(params, cache, tokens[:, None], ptabs,
                                  pos[:, None], live[:, None], cfg, kda_layer,
                                  absorbed)
-    moved = (live.sum() if resolve_impl(cfg.kda_impl) != "xla"
-             else jnp.asarray(B)).astype(jnp.float32)
+    moved = states_moved(live, cfg.kda_impl).astype(jnp.float32)
     return (_dm.head_logits(params, x[:, 0], cfg), cache,
             jnp.stack(held_load_stats(held) + [moved]))
-
-
-def _carried(first, arena, idx):
-    """What entry `idx` of one layer's arena hands a chunk: zeros to a
-    sequence's FIRST chunk, whatever the entry's last holder left."""
-    held = jax.lax.dynamic_index_in_dim(arena, idx, 0, keepdims=False)
-    return jnp.where(first, jnp.zeros_like(held), held)
-
-
-def _carried_at(first, arena, j, idx):
-    """`_carried` of layer j's part of a whole arena [layers, entries,
-    ..], the entry read where it stands: `arena[j]` first is a copy of the
-    layer's whole part — 136 MB of states at the published widths, 2.6 ms
-    of a chunk program's six (PR 60; `phi4flash`, whose part is 11 MB,
-    keeps `_carried`)."""
-    held = jax.lax.dynamic_slice(
-        arena, (j, idx) + (0,) * (arena.ndim - 2),
-        (1, 1) + arena.shape[2:])[0, 0]
-    return jnp.where(first, jnp.zeros_like(held), held)
 
 
 def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
@@ -538,8 +497,8 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx,
     first = start == 0
 
     def kda_layer(j, x, h, layer, state, tail):
-        s0 = _carried_at(first, state, j, idx)
-        t0 = _carried_at(first, tail, j, idx)
+        s0 = carried_at(first, state, j, idx)
+        t0 = carried_at(first, tail, j, idx)
         x, s1, pre = _kda_sequence(x, h, layer, real, s0, t0, cfg)
         t1 = jax.lax.dynamic_slice_in_dim(
             jnp.concatenate([t0, pre.astype(tail.dtype)]), last_idx + 1,

@@ -50,7 +50,7 @@ What a sequence keeps, and `cache_kinds` says so with THREE kinds:
 
   * `full`: layer h + 1's keys and values, pages [pages, ps, Hkv d_head] a
     side — ONE layer of pages, read by that layer and every `cross` one;
-  * `swa`: the windowed layers', a ring of pages (cohere2_moe's);
+  * `swa`: the windowed layers', a ring of pages (`served.kind_io`'s);
   * `mamba`, a `"state"`: ONE entry holding every Mamba layer's h ([N, C]
     float32: ops/mamba.py says why this way round) and conv tail ([3, C]).
 
@@ -74,12 +74,10 @@ from ray_tpu.ops.attention import (latent_decode_uses_kernel,
                                    paged_decode_attention, streamed_attention)
 from ray_tpu.ops.shortconv import conv_chunk, conv_step
 from ray_tpu.ops.layers import layer_norm, rms_norm
-from ray_tpu.ops.mamba import (resolve_impl, selective_scan_chunk,
-                               selective_step)
+from ray_tpu.ops.mamba import selective_scan_chunk, selective_step
 
-from .cohere2_moe import kind_io
 from .gpt import cast_leaves, slot_embed, unembed_table
-from .ling3 import _carried, _draw
+from .served import carried_at, draw, kind_io, page_blocks, states_moved
 
 __all__ = ["Phi4FlashConfig", "init", "apply", "cache_kinds",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
@@ -178,13 +176,12 @@ class Phi4FlashConfig:
         return cls(**base)
 
 
-# the draw is deepseek_v3's recipe through `ling3._draw` (standard normals
-# a piece at a time from the key, the layer and the leaf's place, times
-# the leaf's std), a leaf's place its index here.  Norm weights are ones
-# and biases zeros; a Mamba layer's A, D and step-size bias take Mamba's
-# own initialisation — they decide what a state remembers: A = -(1..N)
-# along the states, D = 1, softplus(b_dt) log-uniform in [DT_MIN, DT_MAX]
-# (the uniform is the normal draw through its own distribution function).
+# the draw is `served.draw`, a leaf's place its index here.  Norm weights
+# are ones and biases zeros; a Mamba layer's A, D and step-size bias take
+# Mamba's own initialisation — they decide what a state remembers:
+# A = -(1..N) along the states, D = 1, softplus(b_dt) log-uniform in
+# [DT_MIN, DT_MAX] (the uniform is the normal draw through its own
+# distribution function).
 LEAVES = ("w_in", "conv_w", "w_x", "w_dt", "b_dt", "w_out", "w_qkv", "wq",
           "wo", "lam_q1", "lam_k1", "lam_q2", "lam_k2", "w1", "w2",
           "w_gate_up", "w_down")
@@ -200,7 +197,7 @@ def init_layer(key, cfg: Phi4FlashConfig, l: int) -> Dict[str, Any]:
     f32 = jnp.float32
 
     def w(name, shape, fan_in, scale=1.0, dtype=pd):
-        return _draw(key, l, LEAVES.index(name), shape,
+        return draw(key, l, LEAVES.index(name), shape,
                      scale / math.sqrt(fan_in), dtype)
 
     layer = {"attn_norm": jnp.ones((D,), pd), "attn_norm_b": jnp.zeros((D,), pd),
@@ -246,7 +243,7 @@ def init(key, cfg: Phi4FlashConfig) -> Dict[str, Any]:
     mixer's kind); the embedding is the head's table too."""
     D, pd = cfg.d_model, cfg.param_dtype
     return {
-        "embed": _draw(key, -1, 0, (cfg.vocab_size, D), 0.02, pd),
+        "embed": draw(key, -1, 0, (cfg.vocab_size, D), 0.02, pd),
         "final_norm": jnp.ones((D,), pd), "final_norm_b": jnp.zeros((D,), pd),
         "layers": [init_layer(key, cfg, l) for l in range(cfg.n_layers)],
     }
@@ -540,16 +537,8 @@ def _paged_attend(kind: str, arena, io, qpos, cfg: Phi4FlashConfig, ctx=None):
                 scale=cfg.d_head ** -0.5,
                 window=_window(kind, cfg))[:, :, :, None]
 
-        def fetch(i):
-            t = jax.lax.dynamic_slice_in_dim(tab, i * npb, npb, 1)
-            b = jax.lax.dynamic_slice_in_dim(bases, i * npb, npb, 1)
-            gather = lambda c: _heads_first(
-                c[t].reshape(B, npb * ps, c.shape[-1]), cfg)
-            kpos = jnp.where(
-                b[:, :, None] >= 0,
-                b[:, :, None] + jnp.arange(ps, dtype=jnp.int32), -1)
-            return gather(kc), gather(vc), kpos.reshape(B, npb * ps)
-
+        fetch = page_blocks(tab, bases, kc, vc, npb, lambda c: _heads_first(
+            c.reshape(B, npb * ps, c.shape[-1]), cfg))
         return streamed_attention(q, qpos, fetch, n_blocks,
                                   window=_window(kind, cfg),
                                   scale=cfg.d_head ** -0.5)
@@ -559,8 +548,7 @@ def _paged_attend(kind: str, arena, io, qpos, cfg: Phi4FlashConfig, ctx=None):
 
 def _tables(ptabs, pos, real, ps: int, cfg: Phi4FlashConfig):
     """How rows at positions pos [B, T] (`real` marks those whose K and V
-    are kept) meet the page tables of the two paged kinds
-    (cohere2_moe.kind_io)."""
+    are kept) meet the two paged kinds' tables (`served.kind_io`)."""
     npb = max(1, cfg.kv_block // ps)
     last, flat_pos = jnp.max(pos, axis=1), pos.reshape(-1)
     return {k: kind_io("full" if k == FULL else "sliding", ptabs[k], pos,
@@ -627,7 +615,6 @@ def paged_decode_step(params, cache, tokens, ptabs, pos,
     empty (a prompt has at least one token): it writes to the null pages
     and leaves the null entry as it is.  Returns (logits [B, V] f32,
     cache, stats)."""
-    B = tokens.shape[0]
     idx, live = ptabs[MAMBA][:, 0], pos > 0
 
     def mamba_layer(j, x, h, layer, state, tail):
@@ -651,9 +638,8 @@ def paged_decode_step(params, cache, tokens, ptabs, pos,
                                 mamba_layer, scopes, cfg, ctx)
     logits = _cross_decoder(params, cache["full"], x, m, pos2, io[FULL], ctx,
                             scopes[FULL], cfg)
-    moved = (live.sum() if resolve_impl(cfg.mamba_impl) != "xla"
-             else jnp.asarray(B))
-    return logits, cache, _stats(moved, ctx.sum(), live.sum())
+    return logits, cache, _stats(states_moved(live, cfg.mamba_impl),
+                                 ctx.sum(), live.sum())
 
 
 def paged_prefill(params, cache, toks, ptab_rows, start, last_idx, is_last,
@@ -674,8 +660,8 @@ def paged_prefill(params, cache, toks, ptab_rows, start, last_idx, is_last,
     first = start == 0
 
     def mamba_layer(j, x, h, layer, state, tail):
-        s0 = _carried(first, state[j], idx)
-        t0 = _carried(first, tail[j], idx)
+        s0 = carried_at(first, state, j, idx)
+        t0 = carried_at(first, tail, j, idx)
         x, m, s1, pre = _mamba_sequence(x, h, layer, real, s0, t0, cfg)
         t1 = jax.lax.dynamic_slice_in_dim(
             jnp.concatenate([t0, pre.astype(tail.dtype)]), last_idx + 1,

@@ -1088,13 +1088,13 @@ def streamed_attention(q, qpos, fetch, n_blocks, *, window=None, scale=None,
     live in VMEM only, where the XLA body (`_streamed_xla`) sends f32
     scores [heads, T, S] through memory between its two products (134 MB
     a block of a 512-row chunk of 128 heads: 40% of the chip's time in
-    `serve-commandaplus-mixedctx` and 55% in `serve-deepseekv3-longctx`
-    before, PERF.md section 6, PR 46).  A decode step that comes here
-    (T = 1, slots in B) and every other platform take the XLA body: a
-    row a head gives THIS kernel nothing to keep on the chip.  Not every
-    decode step comes here: DeepSeek's absorbed step over latent pages
-    walks them with `latent_decode_attention` (below) on a TPU and
-    fetches no block at all.
+    `serve-commandaplus-mixedctx` (retired at PR 55) and 55% in
+    `serve-deepseekv3-longctx` before, PERF.md section 6, PR 46).  A
+    decode step that comes here (T = 1, slots in B) and every other
+    platform take the XLA body: a row a head gives THIS kernel nothing to
+    keep on the chip.  Not every decode step comes here: DeepSeek's
+    absorbed step over latent pages walks them on a TPU with
+    `latent_decode_attention` (below) and fetches no block at all.
     The mathematics and the precision are the same: products on operands
     as they come, f32 scores, statistics and accumulator, p cast to the
     values' dtype."""

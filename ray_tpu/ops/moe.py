@@ -340,6 +340,16 @@ def held_expert_ffn(h: jax.Array, weights: jax.Array, idx: jax.Array,
     return out, loads, reads
 
 
+def held_experts_leaf(wg, wu):
+    """The held experts' gate and up matrices [held, D, F] as the leaf
+    `held_expert_ffn` multiplies by, [held, D, 2F]: gate in columns [:F], up in
+    [F:].  Laid where the layer is drawn and not in a view beside the tree,
+    which would hold both twice — and waited for: the host runs ahead of the
+    draw, and every layer's `wg` and `wu` would stand beside its leaf until the
+    device got to them (+2% of peak memory, PR 62)."""
+    return jax.block_until_ready(jnp.concatenate([wg, wu], axis=-1))
+
+
 def held_load_stats(held) -> list:
     """What a serve program reports of its expert layers (`held`: a list of
     `held_expert_ffn`'s (loads, reads), one a layer): [token-expert pairs

@@ -1,5 +1,7 @@
-"""Where the limits of `serve-commandaplus-mixedctx`'s reference check come
-from, and what that check can and cannot see (PERF.md section 6, PR 27).
+"""Where the limits of `serve-commandaplus-mixedctx-loaded`'s reference
+check come from, and what that check can and cannot see (PERF.md section 6,
+PR 27, read on `serve-commandaplus-mixedctx`, retired at PR 55: the same
+check and limits).
 
 At the configuration's published widths, on the CPU, with the weights the
 benchmark's loader makes (`weights.scales` applied), S random tokens go

@@ -86,8 +86,8 @@ def faulty(loader, variant):
 
             fm.paged_prefill = tailless
         elif variant == "stale_entry":
-            carried = fm._carried_at
-            fm._carried_at = lambda first, arena, j, idx: carried(
+            carried = fm.carried_at
+            fm.carried_at = lambda first, arena, j, idx: carried(
                 jnp.bool_(False), arena, j, idx)
         elif variant == "no_key_mult":
             # the loader folds a layer at a time (`fold_layer`, which

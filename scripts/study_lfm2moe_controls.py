@@ -92,8 +92,8 @@ def faulty(loader, variant):
 
             lm.paged_prefill = tailless
         elif variant == "stale_entry":
-            carried = lm._carried_at
-            lm._carried_at = lambda first, arena, j, idx: carried(
+            carried = lm.carried_at
+            lm.carried_at = lambda first, arena, j, idx: carried(
                 jnp.bool_(False), arena, j, idx)
         elif variant == "no_head_norms":
             norm = lm.rms_norm          # q and k come [B, heads, T, dh]

@@ -104,9 +104,9 @@ def faulty(loader, variant):
 
             lm.paged_prefill = tailless
         elif variant == "stale_entry":
-            carried = lm._carried
-            lm._carried = lambda first, arena, idx: carried(
-                jnp.bool_(False), arena, idx)
+            carried = lm.carried_at
+            lm.carried_at = lambda first, arena, j, idx: carried(
+                jnp.bool_(False), arena, j, idx)
         else:
             prefill = lm.paged_prefill
 

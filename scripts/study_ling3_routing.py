@@ -65,6 +65,7 @@ import numpy as np                                  # noqa: E402
 from benchmarks.drivers.replica_ling3 import shape_weights   # noqa: E402
 from ray_tpu.models import deepseek_v3 as dm        # noqa: E402
 from ray_tpu.models import ling3 as lm              # noqa: E402
+from ray_tpu.models import served                   # noqa: E402
 
 BASE = {"scales": {"w_f": 0.25}, "router_bias_std": 0.05,
         "a_range": [1.0, 16.0], "fresh_log_a": [0.002, 1.0]}
@@ -82,7 +83,7 @@ HELD, STREAMS = 128, 15
 
 
 def main():
-    dm.DRAW_PIECE = 1 << 16
+    served.DRAW_PIECE = 1 << 16
     picked, rho = [], []
     route = dm.route_sigmoid_grouped
 

@@ -1,6 +1,7 @@
-"""Where the limits of `serve-phi4flash-longgen`'s reference check come
-from, and what that check sees (PERF.md section 6, PR 51; the readings
-stand in benchmarks/traffic/open-longgen.json).
+"""Where the limits of `serve-phi4flash-longgen-loaded`'s reference check
+come from, and what that check sees (PERF.md section 6, PR 51, read on
+`serve-phi4flash-longgen`, retired at PR 55: the same check and limits;
+the readings stand in benchmarks/traffic/open-longgen.json).
 
 scripts/study_ling3_controls.py's scheme and its code (`cell_run`,
 `cell_runs`, `main`: imported, this cell's names set on that module): every
@@ -101,15 +102,15 @@ def faulty(loader, variant):
         elif variant == "window_unmasked":
             pm._window = lambda kind, cfg: None
         else:
-            carried = pm._carried
-            pm._carried = lambda first, arena, idx: carried(
-                jnp.bool_(False), arena, idx)
+            carried = pm.carried_at
+            pm.carried_at = lambda first, arena, j, idx: carried(
+                jnp.bool_(False), arena, j, idx)
         return loader()
 
     return load
 
 
-base.CELL = "serve-phi4flash-longgen"
+base.CELL = "serve-phi4flash-longgen-loaded"
 base.REFERENCE_SIDE = ("fp8_weights",)
 base.PROGRAM_SIDE = ("state_bf16", "tail_dropped", "one_softmax",
                      "cross_null_page", "gmu_other_row", "window_unmasked",

@@ -4,11 +4,12 @@ process would spend 35 s of set-up a rate; PERF.md section 4 has the table,
 PR 47).
 
     python scripts/sweep_ling3_knee.py <rate>[,<rate>...] [seed] [--toy]
-        [--cell serve-phi4flash-longgen --out pr51]
+        [--cell serve-phi4flash-longgen-loaded --out pr51]
 
 `--cell` names another cell whose driver has this one's `start_cluster`,
-`warm_up` and `window` (`serve-phi4flash-longgen`, PR 51: the driver is
-found by the traffic file's `kind`), `--out` the directory under
+`warm_up` and `window` (PR 51 swept `serve-phi4flash-longgen`, retired at
+PR 55: today's cell is `-loaded`; the driver is found by the traffic
+file's `kind`), `--out` the directory under
 chiprun_out/ its table goes to.
 
 What `benchmarks/run.py`'s child does up to the warm-up, then

@@ -1,9 +1,9 @@
 """The held experts' gate and up matrices between the two trees the tests
 of `deepseek_v3`, `ling3` and `dots3` compare: the benchmark's references
 draw `wg` and `wu` [held, D, F] apart, the programs' `init` lays them side
-by side in one leaf `wgu` [held, D, 2F] (`deepseek_v3.held_experts_leaf`).
+by side in one leaf `wgu` [held, D, 2F] (`ops.moe.held_experts_leaf`).
 A layer without routed experts comes back as it is."""
-from ray_tpu.models.deepseek_v3 import held_experts_leaf
+from ray_tpu.ops.moe import held_experts_leaf
 
 
 def laid(layer):

@@ -25,6 +25,7 @@ from benchmarks.drivers.replica_deepseek_v3 import shape_weights
 from benchmarks.reference import deepseek_v3_plain as ref
 from held_leaf import apart, laid
 from ray_tpu.models import deepseek_v3 as dm
+from ray_tpu.models import served
 from ray_tpu.ops.layers import yarn_frequencies
 from ray_tpu.ops.moe import route_sigmoid_grouped
 
@@ -65,7 +66,7 @@ def small_pieces():
     at 4,096 while this file's tests run: toy leaves of up to 6,144
     values then span two pieces, so the joins are crossed."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(dm, "DRAW_PIECE", 4096)
+    mp.setattr(served, "DRAW_PIECE", 4096)
     mp.setattr(ref, "DRAW_PIECE", 4096)
     yield
     mp.undo()

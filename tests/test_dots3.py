@@ -32,6 +32,7 @@ from benchmarks.reference import dots3_plain as ref
 from held_leaf import apart, laid
 from ray_tpu.models import deepseek_v3 as dm
 from ray_tpu.models import dots3 as m3
+from ray_tpu.models import served
 from ray_tpu.ops.select import keep_top
 
 TOL = 1e-4
@@ -59,7 +60,7 @@ def small_pieces():
     """The draw's piece at 4,096 values while this file's tests run: toy
     leaves then span two pieces, so the joins are crossed."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(dm, "DRAW_PIECE", 4096)
+    mp.setattr(served, "DRAW_PIECE", 4096)
     mp.setattr(ref, "DRAW_PIECE", 4096)
     yield
     mp.undo()

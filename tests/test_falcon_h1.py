@@ -24,8 +24,8 @@ import jax.numpy as jnp
 
 from benchmarks.reference import deepseek_v3_plain as dsp
 from benchmarks.reference import falcon_h1_plain as ref
-from ray_tpu.models import deepseek_v3 as dm
 from ray_tpu.models import falcon_h1 as fm
+from ray_tpu.models import served
 
 TOL = 3e-5
 SEED = 2147483659            # past 2**31: both draws fold it
@@ -64,7 +64,7 @@ def small_pieces():
     """The draw's piece at 4,096 values while this file's tests run (both
     writings of the recipe): toy leaves then span several pieces."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(dm, "DRAW_PIECE", 4096)
+    mp.setattr(served, "DRAW_PIECE", 4096)
     mp.setattr(dsp, "DRAW_PIECE", 4096)
     yield
     mp.undo()

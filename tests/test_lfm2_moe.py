@@ -24,8 +24,8 @@ import jax.numpy as jnp
 
 from benchmarks.reference import deepseek_v3_plain as dsp
 from benchmarks.reference import lfm2_moe_plain as ref
-from ray_tpu.models import deepseek_v3 as dm
 from ray_tpu.models import lfm2_moe as lm
+from ray_tpu.models import served
 from ray_tpu.ops.moe import route_sigmoid_topk
 
 TOL = 3e-5
@@ -56,7 +56,7 @@ def small_pieces():
     """The draw's piece at 4,096 values while this file's tests run (both
     writings of the recipe): toy leaves then span several pieces."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(dm, "DRAW_PIECE", 4096)
+    mp.setattr(served, "DRAW_PIECE", 4096)
     mp.setattr(dsp, "DRAW_PIECE", 4096)
     yield
     mp.undo()
@@ -217,7 +217,7 @@ def test_the_kept_tail_is_the_references_z_rows(model, drawn, tokens):
 FAULTS = {
     "tail_dropped": ("conv_chunk", lambda f: lambda rows, tail, *a: f(
         rows, jnp.zeros_like(tail), *a)),
-    "stale_entry": ("_carried_at", lambda f: lambda first, *a: f(
+    "stale_entry": ("carried_at", lambda f: lambda first, *a: f(
         jnp.bool_(False), *a)),
     "no_out_gate": ("_conv_out", lambda f: lambda y, c, *a: f(
         y, jnp.ones_like(c), *a)),

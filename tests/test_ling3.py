@@ -27,6 +27,7 @@ from benchmarks.reference import ling3_plain as ref
 from held_leaf import laid
 from ray_tpu.models import deepseek_v3 as dm
 from ray_tpu.models import ling3 as lm
+from ray_tpu.models import served
 
 TOL = 2e-4
 SEED = 2147483659            # past 2**31: the loader folds it
@@ -68,7 +69,7 @@ def small_pieces():
     """The draw's piece at 4,096 values while this file's tests run (both
     writings of the recipe): toy leaves then span two pieces."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(dm, "DRAW_PIECE", 4096)
+    mp.setattr(served, "DRAW_PIECE", 4096)
     mp.setattr(dsp, "DRAW_PIECE", 4096)
     yield
     mp.undo()

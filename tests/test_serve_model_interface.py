@@ -5,6 +5,8 @@ before a weight is cast or a request admitted.
 
 Shapes alone (`jax.eval_shape` at the `nano` sizes): nothing is compiled.
 """
+import ast
+import pathlib
 import types
 
 import jax
@@ -12,7 +14,8 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3,
-                            falcon_h1, gpt, lfm2_moe, ling3, phi4flash)
+                            falcon_h1, gpt, lfm2_moe, ling3, phi4flash,
+                            served)
 from ray_tpu.serve._engine import ContinuousEngine, _check_interface
 
 MODELS = {
@@ -160,3 +163,101 @@ def test_the_view_of_a_view_is_the_view(name):
         mod.init(jax.random.PRNGKey(0), cfg), cfg), cfg))
     assert jax.tree.structure(again) == jax.tree.structure(view)
     assert _shapes(again) == _shapes(view)
+
+
+# -- where a served module may take code from (PR 65) -------------------------
+
+# what GPT-2 defines and a later model reuses
+FROM_GPT = {"cast_leaves", "slot_embed", "unembed_table", "apply_norm",
+            "qkv_of_normed", "attn_out"}
+# a family's base: latent attention is deepseek_v3's
+FAMILY = {"ray_tpu.models.ling3": "deepseek_v3",
+          "ray_tpu.models.dots3": "deepseek_v3"}
+# the one `_`-name a module may bind from another, `served.draw` as
+# `_draw`, and only while the benchmark's drivers import it so (ROADMAP
+# D17): module -> the driver lines that read it.  (`deepseek_v3._draw`,
+# which replica_deepseek_v3.py:24 imports, is that module's own function:
+# the recipe a dispatch a piece, kept for its first run's compiles.)
+DRAW_BINDINGS = {
+    "ray_tpu.models.ling3": {
+        "replica_ling3.py": "from ray_tpu.models.ling3 import LEAVES, _draw",
+        "replica_lfm2_moe.py": "from ray_tpu.models.ling3 import _draw",
+        "replica_falcon_h1.py": "from ray_tpu.models.ling3 import _draw"},
+    "ray_tpu.models.dots3": {
+        "replica_dots3.py": "from ray_tpu.models.dots3 import LEAVES, _draw"},
+}
+REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+def _tree(mod):
+    return ast.parse(pathlib.Path(mod.__file__).read_text())
+
+
+def _sibling_imports(tree):
+    """[(the `ray_tpu.models` module a line of a module's source imports
+    from — None: the package itself —, name, asname)]."""
+    out = []
+    for node in tree.body:
+        if not isinstance(node, ast.ImportFrom):
+            assert not (isinstance(node, ast.Import) and any(
+                a.name.startswith("ray_tpu.models") for a in node.names))
+            continue
+        if node.level == 1:
+            src = node.module
+        elif (node.module or "").startswith("ray_tpu.models"):
+            src = node.module[len("ray_tpu.models"):].lstrip(".") or None
+        else:
+            continue
+        out += [(src, a.name, a.asname) for a in node.names]
+    return out
+
+
+@model
+def test_a_served_module_takes_code_from_below_it_only(name):
+    """A served module imports from `ray_tpu.ops`, from `models/served.py`,
+    from `gpt` what GPT-2 defines and from its family's base — from no
+    other sibling, and no name that starts with `_`; through a module's
+    alias it reads public names only."""
+    mod = MODELS[name][0]
+    me, tree = mod.__name__, _tree(mod)
+    allowed = {"served", "gpt", FAMILY.get(me)} - {None}
+    aliases, draws = {}, 0
+    for src, what, asname in _sibling_imports(tree):
+        if src is None:                     # from . import <module> [as ..]
+            assert what in allowed, (me, what)
+            aliases[asname or what] = what
+            continue
+        assert src in allowed, (me, src)
+        assert not what.startswith("_"), (me, src, what)
+        if src == "gpt":
+            assert what in FROM_GPT, (me, what)
+        if (asname or what).startswith("_"):
+            assert (src, what, asname) == ("served", "draw", "_draw"), (
+                me, src, what, asname)
+            draws += 1
+    assert draws == (me in DRAW_BINDINGS), me
+    for driver, line in DRAW_BINDINGS.get(me, {}).items():
+        assert line in (REPO / "benchmarks" / "drivers" / driver).read_text()
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Attribute)
+                and isinstance(node.value, ast.Name)
+                and node.value.id in aliases):
+            assert not node.attr.startswith("_"), (me, node.attr)
+
+
+def test_what_the_models_share_stands_on_the_ops_alone():
+    """`models/served.py` imports the standard library, `jax` and
+    `ray_tpu.ops`; what it offers is public and in `__all__`."""
+    tree = _tree(served)
+    assert not _sibling_imports(tree)
+    for node in tree.body:
+        if isinstance(node, ast.ImportFrom) and node.module != "__future__":
+            assert node.module.split(".")[0] in ("jax", "ray_tpu"), node.module
+            assert not node.module.startswith("ray_tpu") or \
+                node.module.startswith("ray_tpu.ops"), node.module
+    assert all(not n.startswith("_") and hasattr(served, n)
+               for n in served.__all__)
+    taken = {what for mod, _ in MODELS.values()
+             for src, what, _ in _sibling_imports(_tree(mod))
+             if src == "served"}
+    assert taken <= set(served.__all__), taken - set(served.__all__)

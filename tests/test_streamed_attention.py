@@ -349,8 +349,8 @@ def _toy_programs(mod, cfg, rows=None, slots=4, page=16):
 def _toy(name):
     """(module, config) of a served model at toy size, the widths its
     kernels need on a TPU (128 positions a page's lanes)."""
-    from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3, gpt,
-                                ling3, phi4flash)
+    from ray_tpu.models import (brumby, cohere2_moe, deepseek_v3, dots3,
+                                falcon_h1, gpt, lfm2_moe, ling3, phi4flash)
     return {
         "phi-4-flash": lambda: (phi4flash, phi4flash.Phi4FlashConfig.nano(
             max_seq=512, kv_block=128, sliding_window=128, d_head=64)),
@@ -365,6 +365,11 @@ def _toy(name):
                                                          kv_block=128)),
         "dots3": lambda: (dots3, dots3.Dots3Config.nano(
             max_seq=512, kv_block=128, window=128)),
+        "falcon-h1": lambda: (falcon_h1, falcon_h1.FalconH1Config.nano(
+            max_seq=512, kv_block=128, d_head=128, ssm_head_dim=128,
+            d_state=128, ssm_chunk=128)),
+        "lfm2-moe": lambda: (lfm2_moe, lfm2_moe.Lfm2MoeConfig.nano(
+            max_seq=512, kv_block=128, d_head=128)),
     }[name]()
 
 
@@ -419,7 +424,12 @@ def _digest(text):
 # (`command-a-plus`, `deepseek-v3`, `ling-3`, `dots3`: what `ops/moe`'s
 # held experts do once a layer before their trips — one sort that carries
 # each pair's token and weight, the loads by comparison; the other six
-# stand).  A PR that moves or renames Python functions
+# stand).  PR 65 pinned `falcon-h1`'s and `lfm2-moe`'s four on its parent
+# (4322a48), then moved what the models share into `models/served.py`: all
+# eighteen stood, and `phi-4-flash`'s chunk alone was pinned anew
+# (ce1a43a62b84b2a7 before) where `paged_prefill` took `served.carried_at`
+# (a `dynamic_slice` of the entry for `state[j]`'s copy of a layer's part
+# of the arena, as the other three state models).  A PR that moves or renames Python functions
 # leaves every digest alone (the text carries no source locations; their
 # kernels' source lines unmoved, the compile-cache keys stay too).  A PR
 # that edits one of these programs finds the new digest in the failure and
@@ -436,9 +446,13 @@ PARENT_TEXT = {
     ("ling-3", "step"): "3b7d18ab33f98ee0",
     ("ling-3", "chunk"): "0cced1068f44c019",
     ("phi-4-flash", "step"): "d0a2de26cb6e8b1b",
-    ("phi-4-flash", "chunk"): "ce1a43a62b84b2a7",
+    ("phi-4-flash", "chunk"): "84cb688d77c6767c",
     ("dots3", "step"): "761cd07b8a152eca",
     ("dots3", "chunk"): "2d9a40e82f47d611",
+    ("falcon-h1", "step"): "5643c706bd336a98",
+    ("falcon-h1", "chunk"): "0692b98e74ad86d6",
+    ("lfm2-moe", "step"): "b4c5b5a07ecac816",
+    ("lfm2-moe", "chunk"): "00e99d960a685919",
 }
 
 
